@@ -23,6 +23,10 @@ import bench  # noqa: E402
 
 
 def main() -> int:
+    from tpu_render_cluster.utils.accelerator import configure_compile_cache
+
+    configure_compile_cache()
+
     import jax
 
     platform = jax.devices()[0].platform

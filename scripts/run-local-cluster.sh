@@ -7,6 +7,10 @@
 #   scripts/run-local-cluster.sh <job.toml> <n_workers> [backend] [results_dir]
 #
 #   backend: mock | tpu-raytrace | blender   (default: mock)
+#
+# The master runs on the host CPU (it pins itself). With tpu-raytrace,
+# worker i is confined to local chip i-1 — a chip belongs to one process,
+# so n_workers must not exceed the chips present.
 set -euo pipefail
 
 JOB_FILE="${1:?usage: run-local-cluster.sh <job.toml> <n_workers> [backend] [results_dir]}"
@@ -32,7 +36,12 @@ trap cleanup EXIT
 sleep 1
 WORKER_PIDS=""
 for i in $(seq 1 "$N_WORKERS"); do
-  python -m tpu_render_cluster.worker.main \
+  CHIP_ENV=""
+  if [ "$BACKEND" = "tpu-raytrace" ]; then
+    CHIP_ENV="$(python -m tpu_render_cluster.utils.accelerator "$((i - 1))")"
+  fi
+  # shellcheck disable=SC2086  # CHIP_ENV is a list of KEY=VALUE words
+  env $CHIP_ENV python -m tpu_render_cluster.worker.main \
     --masterServerHost 127.0.0.1 --masterServerPort "$PORT" \
     --baseDirectory "$BASE_DIR" --backend "$BACKEND" &
   WORKER_PIDS="$WORKER_PIDS $!"

@@ -1,0 +1,317 @@
+"""Process start-up, device ownership, and the TPU cross-lowering guard.
+
+What stands between this repo and a chip (ISSUE 22), checked on the CPU:
+
+1. ``utils/accelerator.py``: the compile cache is placed from outside
+   (``JAX_COMPILATION_CACHE_DIR``) or at ``<checkout>/.jax_cache``; a
+   ``tpu-raytrace`` worker refuses a non-TPU backend unless
+   ``JAX_PLATFORMS`` asks for the CPU, and stamps its device; the master CLI
+   leaves JAX on ``cpu``; chips 0-3 get disjoint child environments.
+2. ``obs/profiling.py``: one peak table, an unknown ``device_kind`` raises.
+3. Every default render program LOWERS for TPU from here
+   (``.trace().lower(lowering_platforms=("tpu",))`` with interpret off):
+   the Python-side Pallas -> Mosaic lowering, which is where illegal block
+   specs are refused.
+4. The per-bounce kernels also COMPILE, through libtpu's compile-only
+   client for a v5e (the real XLA:TPU + Mosaic compilers, no chip needed):
+   where operations Mosaic cannot legalize are refused. Whether a kernel
+   computes the right thing only a chip can say — ``chip_smoke.py``.
+"""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tests.conftest import REPO_ROOT
+
+# The served frame shape: block-spec legality depends on the array shapes
+# (a (1, 1) block over a one-block row is legal, over a 64-block row not),
+# so the guard lowers at the real width, never a toy one.
+WIDTH = HEIGHT = 512
+SAMPLES, BOUNCES = 8, 4
+
+
+def _run(code: str, **env_overrides) -> subprocess.CompletedProcess:
+    env = {**os.environ, "PYTHONPATH": str(REPO_ROOT)}
+    for key, value in env_overrides.items():
+        if value is None:
+            env.pop(key, None)
+        else:
+            env[key] = value
+    return subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True, timeout=120, cwd=REPO_ROOT,
+    )
+
+
+# -- compile cache -----------------------------------------------------------
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(monkeypatch, tmp_path):
+    from tpu_render_cluster.utils.accelerator import configure_compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update", lambda k, v: updates.append(k))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert configure_compile_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in updates
+
+
+def test_compile_cache_default_is_the_checkout_in_every_process(monkeypatch):
+    from tpu_render_cluster.utils.accelerator import configure_compile_cache
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    expected = str(REPO_ROOT / ".jax_cache")
+    assert configure_compile_cache() == expected
+    assert updates["jax_compilation_cache_dir"] == expected
+    # A second process (any cwd) resolves the same directory for real.
+    result = _run(
+        "import os; os.chdir('/'); import jax\n"
+        "from tpu_render_cluster.utils.accelerator import configure_compile_cache\n"
+        "configure_compile_cache(); print(jax.config.jax_compilation_cache_dir)",
+        JAX_COMPILATION_CACHE_DIR=None,
+    )
+    assert result.stdout.strip().splitlines()[-1] == expected, result.stderr
+
+
+# -- device ownership --------------------------------------------------------
+
+
+def test_tpu_raytrace_worker_refuses_a_non_tpu_backend(tmp_path):
+    """JAX_PLATFORMS unset on a host without a chip: JAX falls back to the
+    CPU and the worker must exit non-zero instead of rendering there."""
+    result = subprocess.run(
+        [sys.executable, "-m", "tpu_render_cluster.worker.main",
+         "--masterServerHost", "127.0.0.1", "--masterServerPort", "9",
+         "--baseDirectory", str(tmp_path), "--backend", "tpu-raytrace"],
+        env={k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+        | {"PYTHONPATH": str(REPO_ROOT), "TPU_LOG_DIR": str(tmp_path)},
+        capture_output=True, text=True, timeout=120, cwd=REPO_ROOT,
+    )
+    assert result.returncode != 0
+    assert "tpu-raytrace needs a TPU" in result.stderr
+
+
+def test_tpu_raytrace_backend_stamps_its_device():
+    """An explicit JAX_PLATFORMS=cpu (conftest) is allowed, and stamped."""
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    stamp = TpuRaytraceBackend().device
+    assert stamp["platform"] == "cpu" and stamp["device_kind"] == "cpu"
+    assert stamp["count"] == len(jax.devices())
+
+
+def test_master_cli_leaves_jax_on_the_host_cpu():
+    """Even with JAX imported first and the environment asking for a TPU."""
+    result = _run(
+        "import jax\n"
+        "from tpu_render_cluster.master.main import main\n"
+        "try:\n    main(['run-job', '/nonexistent/job.toml'])\n"
+        "except OSError:\n    pass\n"
+        "print(jax.config.jax_platforms, jax.default_backend())",
+        JAX_PLATFORMS="tpu",
+    )
+    assert result.stdout.strip().splitlines()[-1] == "cpu cpu", result.stderr
+
+
+def test_chip_environments_are_disjoint():
+    from tpu_render_cluster.utils.accelerator import chip_environment
+
+    environments = [chip_environment(chip) for chip in range(4)]
+    assert [env["TPU_VISIBLE_CHIPS"] for env in environments] == list("0123")
+    for env in environments:  # one chip each, never a rank of a shared mesh
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    with pytest.raises(ValueError):
+        chip_environment(-1)
+
+
+def test_peak_table_raises_on_an_unknown_device_kind():
+    from tpu_render_cluster.obs.profiling import chip_peaks
+
+    assert chip_peaks("TPU v5 lite") == (197e12, 819e9)
+    with pytest.raises(ValueError, match="TPU v9"):
+        chip_peaks("TPU v9")
+
+
+# -- cross-lowering for TPU --------------------------------------------------
+
+
+@pytest.fixture
+def lower_for_tpu(monkeypatch):
+    """Lower a jitted callable for TPU with the Pallas kernels compiled
+    (interpret off), from this CPU process."""
+    from tpu_render_cluster.render import pallas_kernels as pk
+
+    monkeypatch.setenv("TRC_PALLAS", "1")
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def lower(jitted, *args, **kwargs):
+        text = jitted.trace(*args, **kwargs).lower(
+            lowering_platforms=("tpu",)
+        ).as_text()
+        assert "tpu_custom_call" in text  # a Mosaic kernel, not interpret
+        return text
+
+    return lower
+
+
+def _f32():
+    return jax.ShapeDtypeStruct((), jnp.float32)
+
+
+def _i32():
+    return jax.ShapeDtypeStruct((), jnp.int32)
+
+
+SCENES = ("04_very-simple", "02_physics-mesh", "03_physics-2-mesh")
+
+
+@pytest.mark.parametrize("scene_name", SCENES)
+def test_masked_frame_and_region_lower_for_tpu(lower_for_tpu, scene_name):
+    from tpu_render_cluster.render.integrator import (
+        fused_frame_renderer,
+        fused_region_renderer,
+    )
+
+    frame = fused_frame_renderer(scene_name, WIDTH, HEIGHT, SAMPLES, BOUNCES)
+    lower_for_tpu(frame.__wrapped__, _f32())
+    region = fused_region_renderer(
+        scene_name, WIDTH, HEIGHT, HEIGHT // 2, WIDTH // 2, SAMPLES, BOUNCES
+    )
+    lower_for_tpu(region.__wrapped__, _f32(), _i32(), _i32())
+    fused_frame_renderer.cache_clear()
+    fused_region_renderer.cache_clear()
+
+
+@pytest.mark.parametrize("scene_name", ("04_very-simple", "03_physics-2-mesh"))
+def test_wavefront_step_lowers_for_tpu(lower_for_tpu, scene_name):
+    from tpu_render_cluster.render import compaction
+    from tpu_render_cluster.render import pallas_kernels as pk
+    from tpu_render_cluster.render.mesh import scene_mesh_set
+    from tpu_render_cluster.render.scene import build_scene
+
+    def spec(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree
+        )
+
+    rays = WIDTH * HEIGHT * SAMPLES
+    vec = jax.ShapeDtypeStruct((rays, 3), jnp.float32)
+    alive = jax.ShapeDtypeStruct((rays,), jnp.bool_)
+    lane = jax.ShapeDtypeStruct((rays,), jnp.int32)
+    scene = spec(build_scene(scene_name, 1))
+    mesh = scene_mesh_set(scene_name, 1)
+    if mesh is None:
+        lower_for_tpu(
+            compaction._sphere_step, scene, vec, vec, vec, alive, lane, lane,
+            _i32(), _i32(), _i32(), vec, total_bounces=BOUNCES,
+        )
+    else:
+        lower_for_tpu(
+            compaction._mesh_step, scene, spec(mesh), vec, vec, vec, alive,
+            lane, lane, _i32(), _i32(), _i32(), vec, total_bounces=BOUNCES,
+            use_tlas=True, tlas_block=pk.tlas_block_r(),
+        )
+
+
+@pytest.mark.parametrize("scene_name", ("04_very-simple", "03_physics-2-mesh"))
+def test_raypool_batch_lowers_for_tpu(lower_for_tpu, scene_name):
+    """The mesh program is the one a TPU refused until ISSUE 22: (1, 1)
+    SMEM blocks over the [1, n_blocks] frame-window rows."""
+    from tpu_render_cluster.render import pallas_kernels as pk
+    from tpu_render_cluster.render import raypool
+    from tpu_render_cluster.render.integrator import resolve_bvh_config
+    from tpu_render_cluster.render.scene import mesh_kind_for_scene
+
+    block = (
+        pk.BVH_BLOCK_R if mesh_kind_for_scene(scene_name) is not None
+        else pk.SPHERE_BOUNCE_BLOCK_R
+    )
+    use_tlas, quant, builder, wide = resolve_bvh_config()
+    lower_for_tpu(
+        raypool._raypool_batch, scene_name,
+        jax.ShapeDtypeStruct((raypool.raypool_frame_cap(),), jnp.float32),
+        _i32(), _i32(), _i32(),
+        width=WIDTH, height=HEIGHT, samples=SAMPLES, max_bounces=BOUNCES,
+        pool_width=raypool.raypool_width(SAMPLES * HEIGHT * WIDTH, block),
+        use_tlas=use_tlas, tlas_leaf=pk.tlas_leaf_size(),
+        tlas_block=pk.tlas_block_r(), quant=quant, builder=builder, wide=wide,
+    )
+
+
+def test_tile_sharded_frame_lowers_for_tpu(lower_for_tpu):
+    from tpu_render_cluster.parallel.sharded_render import render_frame_sharded
+
+    lower_for_tpu(
+        jax.jit(
+            lambda: render_frame_sharded(
+                "03_physics-2-mesh", 1, width=WIDTH, height=HEIGHT,
+                samples=SAMPLES, max_bounces=BOUNCES, mode="tile", n_devices=4,
+            )
+        )
+    )
+
+
+# -- Mosaic compile, without a chip ------------------------------------------
+
+_COMPILE_BOUNCE_KERNELS = """
+import sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+try:
+    topology = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+except Exception as error:  # no usable libtpu here: nothing to compile with
+    print("NO_TOPOLOGY", error)
+    sys.exit(0)
+from tpu_render_cluster.render import compaction, pallas_kernels as pk
+from tpu_render_cluster.render.mesh import scene_mesh_set
+from tpu_render_cluster.render.scene import build_scene
+
+pk._interpret = lambda: False
+on_chip = SingleDeviceSharding(topology.devices[0])
+
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+def tree(value):
+    return jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), value)
+
+rays = 512 * 512 * 8
+vec, alive, lane = spec((rays, 3), jnp.float32), spec((rays,), jnp.bool_), spec((rays,), jnp.int32)
+i32 = spec((), jnp.int32)
+for scene_name in ("04_very-simple", "03_physics-2-mesh"):
+    scene, mesh = tree(build_scene(scene_name, 1)), scene_mesh_set(scene_name, 1)
+    if mesh is None:
+        traced = compaction._sphere_step.trace(
+            scene, vec, vec, vec, alive, lane, lane, i32, i32, i32, vec, total_bounces=4)
+    else:
+        traced = compaction._mesh_step.trace(
+            scene, tree(mesh), vec, vec, vec, alive, lane, lane, i32, i32, i32, vec,
+            total_bounces=4, use_tlas=True, tlas_block=pk.tlas_block_r())
+    traced.lower(lowering_platforms=("tpu",)).compile()
+    print("COMPILED", scene_name)
+"""
+
+
+def test_bounce_kernels_compile_with_mosaic(tmp_path):
+    """The sphere state-IO bounce kernel and the TLAS mesh bounce kernel
+    (the key epilogue whose unsigned min Mosaic refused until ISSUE 22)
+    through the real compiler. A subprocess: it loads libtpu."""
+    result = _run(
+        _COMPILE_BOUNCE_KERNELS, TRC_PALLAS="1", TPU_LOG_DIR=str(tmp_path)
+    )
+    if "NO_TOPOLOGY" in result.stdout:
+        pytest.skip(result.stdout.strip())
+    assert result.returncode == 0, result.stderr[-3000:]
+    assert result.stdout.count("COMPILED") == 2

@@ -165,7 +165,7 @@ def test_cpp_worker_cli_backend_renders_real_pixels(tmp_path):
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
         env["TRC_PALLAS"] = "0"
-        env.setdefault("TRC_COMPILE_CACHE", str(tmp_path / "jit-cache"))
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jit-cache")
         process = subprocess.Popen(
             [
                 str(daemon),

@@ -864,6 +864,7 @@ def test_replication_torn_midstream_record_refetched_never_applied(
                     )
             await writer.drain()
             await reader.read()  # hold the stream open until the follower stops
+            writer.close()  # 3.12: Server.wait_closed() waits for open connections
 
         fake = await asyncio.start_server(fake_primary, "127.0.0.1", 0)
         port = fake.sockets[0].getsockname()[1]
@@ -959,6 +960,7 @@ def test_promotion_race_revived_primary_refused_both_ends(
             )
             await writer.drain()
             await reader.read()
+            writer.close()  # 3.12: Server.wait_closed() waits for open connections
 
         fake = await asyncio.start_server(stale_primary, "127.0.0.1", 0)
         fake_port = fake.sockets[0].getsockname()[1]

@@ -19,8 +19,7 @@ Contracts pinned here:
    obs_events summary, and the worker backend batches its queued frames
    through the pool, serving rendered-ahead frames from cache.
 
-CPU interpret mode is slow, so shapes are tiny; the on-chip three-way
-sweep is marked ``slow``.
+CPU interpret mode is slow, so shapes are tiny.
 """
 
 from __future__ import annotations
@@ -149,14 +148,10 @@ def test_raypool_recompile_bound_across_batch_sizes(monkeypatch):
     assert compile_counter().value() - before == 1, (
         "raypool compile key grew with batch size"
     )
-    try:
-        cache_size = raypool._raypool_batch._cache_size()
-    except AttributeError:
-        cache_size = None  # private jit API moved; the tracker assertion holds
-    if cache_size is not None:
-        assert cache_size == 1, (
-            f"pool program traced {cache_size} times across batch sizes"
-        )
+    cache_size = raypool._raypool_batch._cache_size()
+    assert cache_size == 1, (
+        f"pool program traced {cache_size} times across batch sizes"
+    )
     jax.clear_caches()
 
 
@@ -365,19 +360,3 @@ def test_raypool_active_dispatch_tiers(monkeypatch):
     # pallas off => never
     monkeypatch.setenv("TRC_PALLAS", "0")
     assert not raypool_active("04_very-simple", backend_flag="force")
-
-
-@pytest.mark.slow
-def test_raypool_onchip_sweep():
-    """On-chip three-way: the acceptance measurement behind
-    results/RAYPOOL_BENCH.json — the pool must beat masked by >= 1.3x
-    with < 0.25 wasted launched lanes on the deep-mesh config. Excluded
-    from tier-1 (the CPU interpret proxy can't see the sync/launch
-    structure the pool removes; see the committed record's note)."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("on-chip sweep needs a real TPU")
-    import bench
-
-    record = bench.raypool_compare("03_physics-2-mesh", frames=8)
-    assert record["raypool_speedup"] >= 1.3, record
-    assert record["wasted_lane_fraction"]["raypool"] < 0.25, record

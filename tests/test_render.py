@@ -108,10 +108,12 @@ def test_sharded_tile_mesh_render_matches_single_device():
 def test_sharded_spp_render_matches_single_device():
     # VERDICT round-3 weak #4: the psum-average must be asserted against a
     # single-device reference, not just for shape. The spp mode gives each
-    # device the RNG tag x0 = device_index * 131071 and psum-averages;
+    # device the RNG tag frame + device_index * 131071 and psum-averages;
     # computing the identical per-device decomposition serially on one
     # device must reproduce it to numerical tolerance — this isolates the
-    # shard_map + psum machinery from Monte Carlo noise.
+    # shard_map + psum machinery from Monte Carlo noise. Every device must
+    # also render the SAME pixels: the average agrees with the plain
+    # single-device frame statistically.
     import jax
 
     from tpu_render_cluster.render.camera import scene_camera
@@ -136,7 +138,7 @@ def test_sharded_spp_render_matches_single_device():
     per_device = [
         np.asarray(
             render_tile(
-                scene, camera, 1.0, 0, device_index * 131071,
+                scene, camera, 1.0 + device_index * 131071.0, 0, 0,
                 width=width, height=height,
                 tile_height=height, tile_width=width,
                 samples=samples // n, max_bounces=bounces,
@@ -146,6 +148,14 @@ def test_sharded_spp_render_matches_single_device():
     ]
     reference = np.mean(per_device, axis=0)
     np.testing.assert_allclose(image, reference, rtol=1e-4, atol=1e-4)
+    single = np.asarray(
+        render_frame(
+            "04_very-simple", 1, width=width, height=height,
+            samples=samples, max_bounces=bounces,
+        )
+    )
+    assert abs(single.mean() - image.mean()) < 0.05 * single.mean()
+    assert abs(single.std() - image.std()) < 0.15 * single.std()
 
 
 def test_frame_batch_sharded_across_devices():

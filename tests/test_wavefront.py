@@ -15,9 +15,7 @@ Three contracts pinned here:
 3. The occupancy series flow end to end: driver -> registry ->
    metrics snapshot -> ``analysis/obs_events.summarize_obs``.
 
-Interpret mode on CPU is slow, so shapes are tiny. The on-chip
-masked-vs-wavefront throughput sweep is marked ``slow`` (excluded from
-tier-1; run on a real TPU with ``pytest -m slow``).
+Interpret mode on CPU is slow, so shapes are tiny.
 """
 
 from __future__ import annotations
@@ -257,18 +255,3 @@ def test_wavefront_spans_render_on_dedicated_stable_track(tmp_path):
     assert validate_trace_file(second) == []
     assert wavefront_tid(second) == first_tid
     tracer.clear()
-
-
-@pytest.mark.slow
-def test_wavefront_onchip_sweep():
-    """On-chip throughput: wavefront must beat the masked per-bounce path
-    on the committed deep/mesh config (the acceptance measurement behind
-    results/WAVEFRONT_BENCH.json). Excluded from tier-1 (CPU interpret
-    would take hours); run on a TPU with ``pytest -m slow``.
-    """
-    if jax.default_backend() != "tpu":
-        pytest.skip("on-chip sweep needs a real TPU")
-    import bench
-
-    record = bench.wavefront_compare("03_physics-2-mesh", frames=8)
-    assert record["wavefront_speedup"] > 1.0, record

@@ -28,13 +28,10 @@ from dataclasses import asdict, dataclass, field, fields
 from pathlib import Path
 from typing import Any
 
+import tomllib
+
 import numpy as np
 from tpu_render_cluster.utils.env import env_int, env_str
-
-try:
-    import tomllib
-except ImportError:  # Python 3.10
-    import tomli as tomllib  # type: ignore[no-redef]
 
 # -- fault vocabulary --------------------------------------------------------
 

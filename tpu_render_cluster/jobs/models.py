@@ -16,10 +16,7 @@ reference strategies is byte-identical in structure.
 
 from __future__ import annotations
 
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11
-    import tomli as tomllib  # type: ignore[no-redef]
+import tomllib
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any

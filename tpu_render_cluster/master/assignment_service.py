@@ -1,9 +1,10 @@
 """Line-JSON assignment service for the C++ master daemon (trc-master).
 
-Keeps the tpu-batch scheduler's *math* in JAX on the accelerator while the
-control plane is native: the C++ master (native/master_daemon.cpp) launches
-this module as a persistent subprocess and streams one JSON object per line
-on stdin, receiving one per line on stdout:
+Keeps the tpu-batch scheduler's *math* in JAX (on the host CPU: the chips
+belong to the workers) while the control plane is native: the C++ master
+(native/master_daemon.cpp) launches this module as a persistent subprocess
+and streams one JSON object per line on stdin, receiving one per line on
+stdout:
 
     -> {"id": N, "cost": [[...], ...]}            an [items, slots] cost matrix
     <- {"id": N, "assignment": [s0, s1, ...]}     slot index per item
@@ -15,13 +16,12 @@ the next request (the same correlation idea as the wire protocol's
 ``message_request_context_id``).
 
 On startup the service warms the auction solver across the power-of-two
-shape buckets real clusters hit (XLA compiles once per bucket; a cold
-compile can take tens of seconds) and then prints ``{"ready": true}``;
-until that line arrives the C++ side uses its greedy host fallback,
+shape buckets real clusters hit (XLA compiles once per bucket) and then
+prints ``{"ready": true}``; until that line arrives the C++ side uses its greedy host fallback,
 mirroring how tpu_render_cluster/master/tpu_batch.py degrades.
 
 This replaces the reference's in-process scheduler math (reference:
-master/src/cluster/strategies.rs:16-405) with an out-of-process TPU solve;
+master/src/cluster/strategies.rs:16-405) with an out-of-process JAX solve;
 only frame->worker assignments travel back over the pipe (SURVEY.md §5.8).
 """
 
@@ -32,6 +32,14 @@ import sys
 
 
 def main() -> int:
+    from tpu_render_cluster.utils.accelerator import (
+        configure_compile_cache,
+        pin_jax_to_host_cpu,
+    )
+
+    pin_jax_to_host_cpu()
+    configure_compile_cache()
+
     import numpy as np
 
     from tpu_render_cluster.ops.assignment import (

@@ -477,6 +477,16 @@ async def run_job_command(args: argparse.Namespace) -> int:
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
     initialize_console_and_file_logging(args.log_file_path)
+    # The master never opens the TPU: a chip belongs to one process, and
+    # the chips are the workers'. The tpu-batch auction (<= 128x128)
+    # solves on the host CPU.
+    from tpu_render_cluster.utils.accelerator import (
+        configure_compile_cache,
+        pin_jax_to_host_cpu,
+    )
+
+    pin_jax_to_host_cpu()
+    configure_compile_cache()
     if args.command == "run-job":
         return asyncio.run(run_job_command(args))
     if args.command == "serve":

@@ -16,7 +16,8 @@ memory-bound. This module makes every execution tier report that:
   measured seconds, compared against ``min(peak_flops,
   arithmetic_intensity x peak_bytes_per_second)`` — the classic roofline
   attainable bound. Peaks come from ``TRC_PEAK_FLOPS`` /
-  ``TRC_PEAK_BYTES_PER_SECOND`` or per-backend defaults.
+  ``TRC_PEAK_BYTES_PER_SECOND`` or the ``CHIP_PEAKS`` row of the device's
+  ``device_kind`` (an unknown kind raises).
 
 Exposed three ways: registry gauges (``render_kernel_flops`` /
 ``render_kernel_bytes`` / ``render_kernel_achieved_flops_per_second``,
@@ -34,13 +35,15 @@ import logging
 import threading
 import time
 from typing import Any, Callable
-from tpu_render_cluster.utils.env import env_str
+from tpu_render_cluster.utils.env import env_float, env_str
 
 logger = logging.getLogger(__name__)
 
 __all__ = [
     "KernelProfiler",
     "bvh_dims",
+    "CHIP_PEAKS",
+    "chip_peaks",
     "get_profiler",
     "kernel_key",
     "profiling_enabled",
@@ -79,17 +82,29 @@ def bvh_dims(
     }
 
 
-# Conservative per-backend peak defaults, overridable via TRC_PEAK_*.
-# TPU: a single modern TPU core's VPU-adjusted vector peak (the renderer
-# is VPU-bound — NORTHSTAR.md round 5 measured against this basis) and
-# HBM bandwidth. CPU: a few-core host's vector peak and DRAM bandwidth —
-# deliberately round numbers; on-chip runs should set TRC_PEAK_* from the
-# part's datasheet.
-_DEFAULT_PEAKS = {
-    "tpu": (3.0e12, 1.2e12),
+# Peak rates of one chip, keyed by JAX ``device_kind``: (FLOP/s, HBM
+# bytes/s). THE one table (bench.py imports it). Basis of the TPU row: the
+# part's published dense bf16 MXU peak and HBM bandwidth (Google Cloud
+# "TPU v5e": 197 TFLOP/s, 819 GB/s). The path tracer is f32 VPU work, not
+# MXU matmuls, so a share of this peak says how far a kernel is from the
+# chip's headline number, not how well the VPU is used. The ``cpu`` row is
+# nominal: CPU runs are correctness runs and their shares are not device
+# metrics. A kind that is not here is an error, not a default.
+CHIP_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
     "cpu": (5.0e10, 2.0e10),
-    "gpu": (1.0e13, 1.0e12),
 }
+
+
+def chip_peaks(device_kind: str) -> tuple[float, float]:
+    """(peak FLOP/s, peak bytes/s) for a ``device_kind``; unknown raises."""
+    if device_kind not in CHIP_PEAKS:
+        raise ValueError(
+            f"no peak rates for device_kind {device_kind!r}: add a row to "
+            "obs/profiling.CHIP_PEAKS from the part's datasheet, or set "
+            "TRC_PEAK_FLOPS and TRC_PEAK_BYTES_PER_SECOND"
+        )
+    return CHIP_PEAKS[device_kind]
 
 
 def profiling_enabled() -> bool:
@@ -97,31 +112,21 @@ def profiling_enabled() -> bool:
 
 
 def device_peaks() -> dict[str, float]:
-    """{peak_flops, peak_bytes_per_second, source} for the active backend."""
-    source = "default"
-    backend = "cpu"
-    try:
-        import jax
+    """{peak_flops, peak_bytes_per_second, source} for the active device:
+    its ``CHIP_PEAKS`` row, each rate overridable by its ``TRC_PEAK_*``."""
+    import jax
 
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 - peaks must resolve even without jax
-        pass
-    flops, bandwidth = _DEFAULT_PEAKS.get(backend, _DEFAULT_PEAKS["cpu"])
-    raw_flops = env_str("TRC_PEAK_FLOPS")
-    raw_bw = env_str("TRC_PEAK_BYTES_PER_SECOND")
-    try:
-        if raw_flops:
-            flops = float(raw_flops)
-            source = "env"
-        if raw_bw:
-            bandwidth = float(raw_bw)
-            source = "env"
-    except ValueError:
-        logger.warning(
-            "Ignoring non-numeric TRC_PEAK_FLOPS/TRC_PEAK_BYTES_PER_SECOND"
-        )
+    kind = jax.devices()[0].device_kind
+    flops = env_float("TRC_PEAK_FLOPS", 0.0)
+    bandwidth = env_float("TRC_PEAK_BYTES_PER_SECOND", 0.0)
+    source = "env" if flops or bandwidth else "table"
+    if not (flops and bandwidth):
+        table_flops, table_bandwidth = chip_peaks(kind)
+        flops = flops or table_flops
+        bandwidth = bandwidth or table_bandwidth
     return {
-        "backend": backend,
+        "backend": jax.default_backend(),
+        "device_kind": kind,
         "peak_flops": flops,
         "peak_bytes_per_second": bandwidth,
         "source": source,

@@ -1,8 +1,9 @@
-"""Batched assignment solvers on TPU (the `tpu-batch` scheduler's core).
+"""Batched assignment solvers in JAX (the `tpu-batch` scheduler's core).
 
 Solves min-cost frame->slot assignment with a synchronous (Jacobi) auction
 algorithm (Bertsekas) expressed with ``lax`` control flow so the whole solve
-stays on device. Shapes are padded to fixed buckets so XLA compiles once per
+is one compiled program. It runs on the master's host CPU: the chips belong
+to the workers, and the matrices are at most 128x128. Shapes are padded to fixed buckets so XLA compiles once per
 bucket, and ``vmap`` batches independent solves.
 
 This replaces the reference's sequential greedy bin-packing loops

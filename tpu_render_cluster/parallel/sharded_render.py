@@ -43,16 +43,8 @@ from tpu_render_cluster.render.scene import build_scene
 def _shard_map(fn, mesh, in_specs, out_specs):
     # check_vma=False: the integrator's scan carries start replicated and
     # become device-varying when axis_index feeds the RNG — intended here.
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    # jax < 0.5: shard_map lives in jax.experimental and the replication
-    # check is spelled check_rep.
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    return _experimental_shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
@@ -120,14 +112,16 @@ def render_frame_sharded(
 
         def render_subset(scene, camera, frame):
             device_index = jax.lax.axis_index("d")
-            # Decorrelate: fold the device index into the frame-derived seed
-            # by offsetting the y0 RNG ingredient with a device-unique tag.
+            # Decorrelate through the frame ingredient of the RNG key:
+            # scene and camera are built outside, so in render_tile the
+            # frame feeds ONLY the key. (x0 cannot carry the tag: it is
+            # also the pixel offset, and moved devices 1.. off screen.)
             image = render_tile(
                 scene,
                 camera,
-                frame,
+                frame + device_index * 131071.0,
                 0,
-                device_index * 131071,  # x0 only feeds the RNG here
+                0,
                 width=width,
                 height=height,
                 tile_height=height,

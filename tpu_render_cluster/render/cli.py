@@ -31,6 +31,10 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--out", required=True)
     args = parser.parse_args(argv)
 
+    from tpu_render_cluster.utils.accelerator import configure_compile_cache
+
+    configure_compile_cache()
+
     import json
 
     import numpy as np

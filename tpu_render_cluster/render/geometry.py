@@ -27,9 +27,7 @@ def _ray_barrier(origins, directions):
     below: the v5e TpuPriorityFusionQueue cost model SIGILLs on that
     producer pattern (libtpu crash observed 2026-07; also materializes the
     rays once instead of recomputing them in all three contractions). On
-    non-TPU backends the barrier buys nothing and older JAX releases have
-    no batching rule for it (it breaks under the pre-0.5 shard_map), so
-    it is skipped.
+    non-TPU backends the barrier buys nothing, so it is skipped.
     """
     if jax.default_backend() != "tpu":
         return origins, directions

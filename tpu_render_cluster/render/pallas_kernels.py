@@ -15,8 +15,8 @@ Layout choices (see /opt/skills/guides/pallas_guide.md):
   reduction producing [1, BLOCK_R];
 - sphere data ([3, N] centers, [N, 1] radius^2 / |c|^2) is small enough to
   sit whole in VMEM for every grid step;
-- the two contractions (d.c and o.c) are K=3 dot_generals on the MXU with
-  ``preferred_element_type=float32``.
+- the two contractions (d.c and o.c) are K=3 dot_generals on the MXU at
+  full f32 precision (``_dot_f32``).
 
 On non-TPU backends the kernel runs in interpret mode, so the same code
 path is exercised by CPU tests.
@@ -78,6 +78,28 @@ def pallas_enabled() -> bool:
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _center_dot_sun(c_t, sun_direction):
+    """[Np, 1] c . sun, elementwise: an XLA matmul of f32 operands also
+    defaults to one bf16 pass on the TPU."""
+    return jnp.sum(c_t * sun_direction[:, None], axis=0)[:, None]
+
+
+def _dot_f32(a, b, dimension_numbers):
+    """In-kernel f32 contraction at full f32 precision.
+
+    Mosaic's default for f32 operands is one bf16 MXU pass — three
+    significant digits — which interpret mode (exact f32 on the CPU)
+    never shows: on the chip it put ~0.4% error on sphere hit distances
+    and on the centers the one-hot gathers read back, and chip frames
+    disagreed with interpret frames on half their pixels. HIGHEST is
+    Mosaic's fp32 contract precision; elsewhere it changes nothing.
+    """
+    return jax.lax.dot_general(
+        a, b, dimension_numbers, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 # Ray block for the per-bounce STATE-IO sphere kernel (the wavefront
@@ -281,8 +303,11 @@ def coherence_key_u32(
         | (jnp.where(dy > 0, one, zero) << jnp.uint32(1))
         | (jnp.where(dz > 0, one, zero) << jnp.uint32(2))
     )
-    cand_bits = jnp.minimum(candidate.astype(jnp.uint32), jnp.uint32(63))
-    fid_bits = jnp.minimum(fid.astype(jnp.uint32), jnp.uint32(31))
+    # Clamp in int32, then cast: Mosaic has no unsigned vector min
+    # (arith.minui fails to legalize on the chip). Both inputs are
+    # non-negative slot / frame indices, so the bits are the same.
+    cand_bits = jnp.minimum(candidate, 63).astype(jnp.uint32)
+    fid_bits = jnp.minimum(fid, 31).astype(jnp.uint32)
     dead_bit = jnp.where(dead, one, zero) << jnp.uint32(KEY_DEAD_BIT)
     return (
         octant
@@ -369,11 +394,20 @@ def initial_mesh_sort_keys(mesh, origins, directions, alive):
 
 
 def pack_throughput_bf16(throughput):
-    """[R, 3] f32 -> [R, 2] f32 words carrying 4 bf16 lanes (one pad)."""
+    """[R, 3] f32 -> [R, 2] f32 words carrying 4 bf16 lanes (one pad).
+
+    The pad lane is 1.0, not 0: it is the HIGH half of the second word,
+    and a word whose high half is zero is a denormal f32, which the TPU
+    flushes to zero as the word rides the drivers' float gathers. With a
+    zero pad, wavefront/raypool frames under TRC_BVH_QUANT>=1 came out a
+    third darker on the chip (mean 104 vs 160, interpret mode unaffected);
+    with 1.0 they match the masked frame there. The first word has the
+    same weakness only where green is exactly 0.
+    """
     half = jnp.concatenate(
         [
             throughput.astype(jnp.bfloat16),
-            jnp.zeros((throughput.shape[0], 1), jnp.bfloat16),
+            jnp.ones((throughput.shape[0], 1), jnp.bfloat16),
         ],
         axis=1,
     )
@@ -418,8 +452,8 @@ def _nearest_hit_kernel(o_ref, d_ref, c_ref, r2_ref, csq_ref, t_ref, idx_ref):
     c = c_ref[:, :]  # [3, N]
     contract_first = (((0,), (0,)), ((), ()))
     # [N, BR] contractions on the MXU.
-    dc = jax.lax.dot_general(c, d, contract_first, preferred_element_type=jnp.float32)
-    oc = jax.lax.dot_general(c, o, contract_first, preferred_element_type=jnp.float32)
+    dc = _dot_f32(c, d, contract_first)
+    oc = _dot_f32(c, o, contract_first)
     od = jnp.sum(o * d, axis=0, keepdims=True)  # [1, BR]
     o_sq = jnp.sum(o * o, axis=0, keepdims=True)  # [1, BR]
 
@@ -509,8 +543,8 @@ def _any_hit_kernel(o_ref, d_ref, c_ref, r2_ref, csq_ref, hit_ref):
     d = d_ref[:, :]  # [3, BR]
     c = c_ref[:, :]  # [3, N]
     contract_first = (((0,), (0,)), ((), ()))
-    dc = jax.lax.dot_general(c, d, contract_first, preferred_element_type=jnp.float32)
-    oc = jax.lax.dot_general(c, o, contract_first, preferred_element_type=jnp.float32)
+    dc = _dot_f32(c, d, contract_first)
+    oc = _dot_f32(c, o, contract_first)
     od = jnp.sum(o * d, axis=0, keepdims=True)
     o_sq = jnp.sum(o * o, axis=0, keepdims=True)
 
@@ -715,12 +749,8 @@ def _trace_kernel_factory(
         def bounce_step(bounce, carry):
             o, d, throughput, radiance, alive = carry
             # -- nearest sphere hit (same math as _nearest_hit_kernel) ----
-            dc = jax.lax.dot_general(
-                c, d, contract_first, preferred_element_type=jnp.float32
-            )
-            oc = jax.lax.dot_general(
-                c, o, contract_first, preferred_element_type=jnp.float32
-            )
+            dc = _dot_f32(c, d, contract_first)
+            oc = _dot_f32(c, o, contract_first)
             od = jnp.sum(o * d, axis=0, keepdims=True)
             o_sq = jnp.sum(o * o, axis=0, keepdims=True)
             oc_dot_d = dc - od
@@ -767,19 +797,11 @@ def _trace_kernel_factory(
 
             # -- gathers as one-hot matmuls (N is small, MXU-friendly) ----
             one_hot = (sphere_iota == idx).astype(jnp.float32)  # [N, BR]
-            c_hit = jax.lax.dot_general(
-                c, one_hot, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [3, BR]
+            gather = (((1,), (0,)), ((), ()))
+            c_hit = _dot_f32(c, one_hot, gather)  # [3, BR]
             r_hit = jnp.sum(radius * one_hot, axis=0, keepdims=True)  # [1, BR]
-            albedo_hit = jax.lax.dot_general(
-                albedo_t, one_hot, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            emission_hit = jax.lax.dot_general(
-                emission_t, one_hot, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            albedo_hit = _dot_f32(albedo_t, one_hot, gather)
+            emission_hit = _dot_f32(emission_t, one_hot, gather)
 
             sphere_normal = (p - c_hit) / jnp.maximum(r_hit, 1e-6)
             plane_normal = jnp.concatenate(
@@ -803,9 +825,7 @@ def _trace_kernel_factory(
 
             # -- sun NEE: one any-hit shadow dot (sun dir is uniform) -----
             shadow_o = p + normal * (EPS * 4.0)
-            oc_s = jax.lax.dot_general(
-                c, shadow_o, contract_first, preferred_element_type=jnp.float32
-            )
+            oc_s = _dot_f32(c, shadow_o, contract_first)
             od_s = jnp.sum(shadow_o * sun, axis=0, keepdims=True)
             osq_s = jnp.sum(shadow_o * shadow_o, axis=0, keepdims=True)
             ocd_s = dc_sun - od_s
@@ -925,7 +945,7 @@ def _trace_fused(
     rad = radii_p[:, None]
     albedo_t = jnp.pad(albedo, ((0, sphere_pad), (0, 0))).T
     emission_t = jnp.pad(emission, ((0, sphere_pad), (0, 0))).T
-    dc_sun = (c_t.T @ sun_direction)[:, None]  # [Np, 1]
+    dc_sun = _center_dot_sun(c_t, sun_direction)  # [Np, 1]
 
     params = jnp.zeros((8, 3), jnp.float32)
     params = params.at[0].set(sun_direction)
@@ -1031,7 +1051,7 @@ def _sphere_bounce(
     rad = radii_p[:, None]
     albedo_t = jnp.pad(albedo, ((0, sphere_pad), (0, 0))).T
     emission_t = jnp.pad(emission, ((0, sphere_pad), (0, 0))).T
-    dc_sun = (c_t.T @ sun_direction)[:, None]
+    dc_sun = _center_dot_sun(c_t, sun_direction)
 
     params = jnp.zeros((8, 3), jnp.float32)
     params = params.at[0].set(sun_direction)
@@ -2231,10 +2251,10 @@ def _mesh_trace_kernel_factory(
             # ALL lanes, stale dead ones included): a too-wide window
             # only walks instances whose matching lanes are dead, and
             # their -INF limits exit those walks at the first node.
-            k_sweep_lo = fid_lo_ref[0, 0] * k_per_frame
-            k_sweep_hi = jnp.minimum(
-                (fid_hi_ref[0, 0] + 1) * k_per_frame, k_count
-            )
+            fid_lo = fid_lo_ref[0, pl.program_id(0)]
+            fid_hi = fid_hi_ref[0, pl.program_id(0)]
+            k_sweep_lo = fid_lo * k_per_frame
+            k_sweep_hi = jnp.minimum((fid_hi + 1) * k_per_frame, k_count)
         else:
             seed = seed_ref[0, 0].astype(jnp.uint32)
             fid_row = None
@@ -2583,7 +2603,7 @@ def _mesh_trace_kernel_factory(
                         )
 
                     walked = jax.lax.fori_loop(
-                        fid_lo_ref[0, 0], fid_hi_ref[0, 0] + 1,
+                        fid_lo, fid_hi + 1,
                         per_frame, init,
                     )
                 else:
@@ -2741,7 +2761,7 @@ def _mesh_trace_kernel_factory(
                         )
 
                     return jax.lax.fori_loop(
-                        fid_lo_ref[0, 0], fid_hi_ref[0, 0] + 1,
+                        fid_lo, fid_hi + 1,
                         per_frame, occluded0,
                     )
                 return tlas_walk_occluded(
@@ -2760,12 +2780,8 @@ def _mesh_trace_kernel_factory(
         def bounce_step(bounce, carry):
             o, d, throughput, radiance, alive = carry
             # -- nearest sphere hit (same math as _trace_kernel_factory) --
-            dc = jax.lax.dot_general(
-                c, d, contract_first, preferred_element_type=jnp.float32
-            )
-            oc = jax.lax.dot_general(
-                c, o, contract_first, preferred_element_type=jnp.float32
-            )
+            dc = _dot_f32(c, d, contract_first)
+            oc = _dot_f32(c, o, contract_first)
             od = jnp.sum(o * d, axis=0, keepdims=True)
             o_sq = jnp.sum(o * o, axis=0, keepdims=True)
             oc_dot_d = dc - od
@@ -2826,19 +2842,11 @@ def _mesh_trace_kernel_factory(
             p = o + d * t
 
             one_hot = (sphere_iota == idx).astype(jnp.float32)
-            c_hit = jax.lax.dot_general(
-                c, one_hot, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            gather = (((1,), (0,)), ((), ()))
+            c_hit = _dot_f32(c, one_hot, gather)
             r_hit = jnp.sum(radius * one_hot, axis=0, keepdims=True)
-            albedo_hit = jax.lax.dot_general(
-                albedo_t, one_hot, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            emission_hit = jax.lax.dot_general(
-                emission_t, one_hot, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            albedo_hit = _dot_f32(albedo_t, one_hot, gather)
+            emission_hit = _dot_f32(emission_t, one_hot, gather)
 
             sphere_normal = (p - c_hit) / jnp.maximum(r_hit, 1e-6)
             plane_normal = jnp.concatenate(
@@ -2872,9 +2880,7 @@ def _mesh_trace_kernel_factory(
 
             # -- sun NEE: sphere any-hit + mesh any-hit -------------------
             shadow_o = p + normal * (EPS * 4.0)
-            oc_s = jax.lax.dot_general(
-                c, shadow_o, contract_first, preferred_element_type=jnp.float32
-            )
+            oc_s = _dot_f32(c, shadow_o, contract_first)
             od_s = jnp.sum(shadow_o * sun, axis=0, keepdims=True)
             osq_s = jnp.sum(shadow_o * shadow_o, axis=0, keepdims=True)
             ocd_s = dc_sun - od_s
@@ -3062,7 +3068,7 @@ def _mesh_trace_kernel_factory(
                             )
 
                         return jax.lax.fori_loop(
-                            fid_lo_ref[0, 0], fid_hi_ref[0, 0] + 1,
+                            fid_lo, fid_hi + 1,
                             per_frame_entry, entry_init,
                         )
                     return entry_walk(
@@ -3235,7 +3241,7 @@ def _trace_fused_mesh(
     rad = radii_p[:, None]
     albedo_t = jnp.pad(albedo, ((0, sphere_pad), (0, 0))).T
     emission_t = jnp.pad(emission, ((0, sphere_pad), (0, 0))).T
-    dc_sun = (c_t.T @ sun_direction)[:, None]
+    dc_sun = _center_dot_sun(c_t, sun_direction)
 
     params = jnp.zeros((8, 3), jnp.float32)
     params = params.at[0].set(sun_direction)
@@ -3378,7 +3384,7 @@ def _mesh_bounce_io(
     rad = radii_p[:, None]
     albedo_t = jnp.pad(albedo, ((0, sphere_pad), (0, 0))).T
     emission_t = jnp.pad(emission, ((0, sphere_pad), (0, 0))).T
-    dc_sun = (c_t.T @ sun_direction)[:, None]
+    dc_sun = _center_dot_sun(c_t, sun_direction)
 
     params = jnp.zeros((8, 3), jnp.float32)
     params = params.at[0].set(sun_direction)
@@ -3737,7 +3743,7 @@ def pool_sphere_operands(
         rad=radii_p[:, None],
         albedo_t=albedo_t,
         emission_t=emission_t,
-        dc_sun=(c_t.T @ sun_direction)[:, None],
+        dc_sun=_center_dot_sun(c_t, sun_direction),
         sfid=sfid,
         params=params,
     )
@@ -4042,8 +4048,11 @@ def pool_mesh_bounce(
             row_block,
             row_block,
             row_block,
-            pl.BlockSpec((1, 1), lambda i: (0, i), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, i), memory_space=pltpu.SMEM),
+            # Whole [1, n_blocks] rows in SMEM, indexed by program_id in
+            # the kernel (a (1, 1) block over the row is not a legal TPU
+            # tiling; same form as _bvh_nearest_instanced's candidates).
+            pl.BlockSpec(fid_lo.shape, whole, memory_space=pltpu.SMEM),
+            pl.BlockSpec(fid_hi.shape, whole, memory_space=pltpu.SMEM),
             pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
             pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
             pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),

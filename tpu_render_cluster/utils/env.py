@@ -75,7 +75,6 @@ declare("TRC_TLAS_BLOCK", "int", 256, "Ray-block width of the TLAS kernel varian
 declare("TRC_BVH_QUANT", "int", 0, "Quantized BVH/TLAS node tier: 0 off, 1 16-bit, 2 8-bit slabs (+ packed carried ray state)")
 declare("TRC_BVH_BUILDER", "spec", "sah", "BLAS build strategy: sah (binned) | median")
 declare("TRC_BVH_WIDE", "int", 4, "BLAS branching factor after wide collapse (1 = binary, clamped 1..8)")
-declare("TRC_COMPILE_CACHE", "path", None, "Persistent XLA compile cache directory")
 # -- jobs / tiles ------------------------------------------------------------
 declare("TRC_TILE_GRID", "spec", None, "Default RxC tile grid applied at job load time")
 # -- logging / analysis paths ------------------------------------------------

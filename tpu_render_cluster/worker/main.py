@@ -22,7 +22,6 @@ from tpu_render_cluster.obs import (
 )
 from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.utils.logging import initialize_console_and_file_logging
-from tpu_render_cluster.utils.env import env_str
 from tpu_render_cluster.worker.backends import create_backend
 from tpu_render_cluster.worker.runtime import Worker
 
@@ -149,24 +148,14 @@ def make_backend(args: argparse.Namespace):
         )
     if args.backend == "tpu-raytrace":
         from tpu_render_cluster.parallel.mesh import initialize_multihost
+        from tpu_render_cluster.utils.accelerator import configure_compile_cache
 
+        configure_compile_cache()
         # Must happen before any other JAX use: afterwards jax.devices()
         # is the global (cross-host) set and sharded rendering spans DCN.
         initialize_multihost(
             args.coordinator_address, args.num_processes, args.process_id
         )
-        cache_dir = env_str("TRC_COMPILE_CACHE")
-        if cache_dir:
-            # Persistent XLA compilation cache: the first worker process
-            # pays the 20-40 s compile, later ones deserialize in ~1 s.
-            try:
-                import jax
-
-                jax.config.update("jax_compilation_cache_dir", cache_dir)
-                jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-                jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            except Exception:  # noqa: BLE001 - cache is an optimization only
-                pass
         try:
             width, height = (int(v) for v in args.render_size.lower().split("x"))
         except ValueError as e:
@@ -324,11 +313,19 @@ def main(argv: list[str] | None = None) -> int:
             # statistics.json fold consumes.
             from tpu_render_cluster.obs.profiling import get_profiler
 
+            extra = {}
             roofline = get_profiler().view()
+            if roofline:
+                extra["roofline"] = roofline
+            # Which device rendered (tpu-raytrace only): platform and
+            # device_kind, stamped once when the backend was built.
+            device = getattr(backend, "device", None)
+            if device:
+                extra["device"] = device
             write_metrics_snapshot(
                 obs_directory / f"{worker_name}_metrics.json",
                 worker.metrics,
-                extra={"roofline": roofline} if roofline else None,
+                extra=extra,
             )
         except Exception as e:  # noqa: BLE001 - obs must not mask the run error
             print(f"warning: obs artifact export failed: {e}", file=sys.stderr)
