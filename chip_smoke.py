@@ -21,8 +21,11 @@ This parent never imports JAX: a process that has touched JAX holds the
 chip. It learns platform, device_kind and chip count from a probe child
 that exits before anything else starts. It exits non-zero, printing no
 result, unless the probe reports a TPU. One JSON line per stage goes to
-stdout; the last line is the result object. No gain is claimed from any
-number here: seconds are counts, cold or warm as labelled.
+stdout, then a summary line (seconds cold/warm, ending `"claim": null`);
+the last line is the result object, exactly `{"ok": ..., "device":
+{"platform", "kind", "count"}}` with the device as the probe's JAX
+reported it — `"ok": false` and exit 1 if any stage failed. No gain is
+claimed from any number here: seconds are counts, cold or warm as labelled.
 """
 
 from __future__ import annotations
@@ -36,6 +39,7 @@ import socket
 import subprocess
 import sys
 import time
+import traceback
 from pathlib import Path
 
 import numpy as np
@@ -311,8 +315,7 @@ def run_cli_pairs() -> dict:
     }
 
 
-def main() -> int:
-    device = probe()
+def run_stages(device: dict) -> None:
     if RUN_DIR.exists():
         shutil.rmtree(RUN_DIR)
     RUN_DIR.mkdir(parents=True)
@@ -340,20 +343,35 @@ def main() -> int:
     if start == "cold":
         record_path.write_text(json.dumps(seconds))
     print(json.dumps({
-        "ok": True,
-        "device": {"platform": device["platform"], "kind": device["kind"], "count": device["count"]},
-        "start": start, "seconds": round(time.monotonic() - _started, 1),
+        "stage": "summary", "ok": True, **common, "start": start,
+        "seconds": round(time.monotonic() - _started, 1),
         "seconds_by_stage": seconds, "seconds_by_stage_cold": cold or None,
         "claim": None,
     }), flush=True)
-    return 0
+
+
+def main() -> int:
+    try:
+        device = probe()  # no accelerator: no result line
+    except SmokeFailure as failure:
+        print(f"chip_smoke: FAILED: {failure}", file=sys.stderr)
+        return 1
+    ok = True
+    try:
+        run_stages(device)
+    except Exception as failure:  # any failed stage fails the smoke
+        ok = False
+        if not isinstance(failure, SmokeFailure):
+            traceback.print_exc()
+        print(f"chip_smoke: FAILED: {failure}", file=sys.stderr)
+    finally:
+        _kill_all()
+    # The contract's last line: these keys and no others.
+    print(json.dumps({"ok": ok, "device": {
+        "platform": device["platform"], "kind": device["kind"], "count": device["count"],
+    }}), flush=True)
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":
-    try:
-        sys.exit(main())
-    except SmokeFailure as failure:
-        print(f"chip_smoke: FAILED: {failure}", file=sys.stderr)
-        sys.exit(1)
-    finally:
-        _kill_all()
+    sys.exit(main())
