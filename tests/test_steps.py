@@ -1,0 +1,578 @@
+"""The steps of a frame (obs.step) and the render loop's states.
+
+The helper alone (exclusivity, nothing kept outside a frame, no JAX where
+there was none, a `trc:<step>` annotation in a profile), the three
+execution tiers of the tpu-raytrace backend on a 64x64 CPU frame (all six
+steps, adding up to the phases), `write_image`'s split (the file on disk is
+the parent's, byte for byte), and the worker queue (steps enter the
+registry and the timeline with the phases; the loop counter's three states
+add up to the loop's wall time).
+"""
+
+from __future__ import annotations
+
+import asyncio
+import os
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from tpu_render_cluster.jobs.models import BlenderJob, DistributionStrategy
+from tpu_render_cluster.obs import (
+    FRAME_STEPS,
+    MetricsRegistry,
+    Tracer,
+    frame_steps,
+    step,
+)
+from tpu_render_cluster.obs import tracer as tracer_module
+from tpu_render_cluster.traces.worker_trace import FrameRenderTime, WorkerTraceBuilder
+from tpu_render_cluster.utils.cancellation import CancellationToken
+from tpu_render_cluster.worker.backends.mock import MockBackend
+from tpu_render_cluster.worker.queue import LOOP_STATES, WorkerAutomaticQueue
+
+REPO_ROOT = Path(__file__).resolve().parent.parent
+
+
+def make_job(name: str, frames: int, output: str = "%BASE%/out", file_format: str = "JPEG") -> BlenderJob:
+    return BlenderJob(
+        job_name=name,
+        job_description=None,
+        project_file_path="%BASE%/p.blend",
+        render_script_path="%BASE%/s.py",
+        frame_range_from=1,
+        frame_range_to=frames,
+        wait_for_number_of_workers=1,
+        frame_distribution_strategy=DistributionStrategy.naive_fine(),
+        output_directory_path=output,
+        output_file_name_format="rendered-#####",
+        output_file_format=file_format,
+    )
+
+
+def seconds_by_step(steps) -> dict[str, float]:
+    totals: dict[str, float] = {}
+    for name, _start, seconds in steps:
+        totals[name] = totals.get(name, 0.0) + seconds
+    return totals
+
+
+# -- the helper ----------------------------------------------------------------
+
+
+def test_the_vocabulary_is_six_fixed_names():
+    assert FRAME_STEPS == (
+        "resolve", "dispatch", "device_wait", "readback", "encode", "file_write"
+    )
+
+
+def test_an_unknown_step_is_refused():
+    with pytest.raises(ValueError, match="unknown frame step"):
+        with step("compositing"):
+            pass
+
+
+def test_an_inner_step_suspends_the_outer_one():
+    with frame_steps() as steps:
+        with step("dispatch"):
+            time.sleep(0.02)
+            with step("device_wait"):
+                time.sleep(0.05)
+            time.sleep(0.01)
+    assert [name for name, _, _ in steps] == ["dispatch", "device_wait", "dispatch"]
+    totals = seconds_by_step(steps)
+    # the inner step's 50 ms are not in the outer's 30
+    assert 0.03 <= totals["dispatch"] < 0.045
+    assert 0.05 <= totals["device_wait"] < 0.065
+
+
+def test_a_frames_steps_add_up_to_its_wall_time():
+    start = time.perf_counter()
+    with frame_steps() as steps:
+        with step("resolve"):
+            time.sleep(0.003)
+        with step("dispatch"):
+            for _ in range(4):
+                time.sleep(0.002)
+                with step("device_wait"):
+                    time.sleep(0.004)
+        with step("device_wait"):
+            time.sleep(0.002)
+        with step("readback"):
+            time.sleep(0.001)
+        with step("encode"):
+            time.sleep(0.003)
+        with step("file_write"):
+            time.sleep(0.002)
+    wall = time.perf_counter() - start
+    assert abs(sum(seconds for _, _, seconds in steps) - wall) < 0.001
+    assert sum(1 for name, _, _ in steps if name == "device_wait") == 5
+
+
+def test_segments_do_not_overlap_and_are_in_the_order_they_ended():
+    with frame_steps() as steps:
+        with step("dispatch"):
+            with step("device_wait"):
+                time.sleep(0.002)
+            with step("readback"):
+                time.sleep(0.002)
+    ends = [start + seconds for _, start, seconds in steps]
+    assert ends == sorted(ends)
+    for (_, _, _), (_, next_start, _), end in zip(steps, steps[1:], ends):
+        assert next_start >= end - 1e-4  # wall clock against the monotonic one
+
+
+def test_nothing_is_kept_outside_a_frame():
+    with step("dispatch"):  # render.cli, warm(), bench.py: no frame in hand
+        pass
+    with frame_steps() as steps:
+        pass
+    assert steps == []
+
+
+def test_a_frames_steps_are_the_threads_own():
+    seen = {}
+
+    def other():
+        with frame_steps() as steps:
+            with step("encode"):
+                time.sleep(0.002)
+        seen["other"] = steps
+
+    with frame_steps() as mine:
+        thread = threading.Thread(target=other)
+        thread.start()
+        with step("dispatch"):
+            time.sleep(0.004)
+        thread.join()
+    assert [name for name, _, _ in mine] == ["dispatch"]
+    assert [name for name, _, _ in seen["other"]] == ["encode"]
+
+
+def test_a_failed_step_is_closed_and_the_outer_one_resumes():
+    with frame_steps() as steps:
+        with step("dispatch"):
+            with pytest.raises(RuntimeError):
+                with step("device_wait"):
+                    raise RuntimeError("device lost")
+            time.sleep(0.001)
+    assert [name for name, _, _ in steps] == ["dispatch", "device_wait", "dispatch"]
+    assert not tracer_module._steps_local.stack
+
+
+def test_a_step_imports_no_jax_where_there_was_none():
+    code = (
+        "import sys\n"
+        "from tpu_render_cluster.obs import frame_steps, step\n"
+        "with frame_steps() as steps:\n"
+        "    with step('encode'):\n"
+        "        with step('file_write'):\n"
+        "            pass\n"
+        "assert len(steps) == 3, steps\n"
+        "assert 'jax' not in sys.modules\n"
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+def test_the_master_still_imports_no_jax():
+    """Importing the master (and the worker runtime's queue, which feeds the
+    step series) pulls in the step helper and must not pull in JAX."""
+    code = (
+        "import sys\n"
+        "import tpu_render_cluster.master.main\n"
+        "import tpu_render_cluster.master.assembly\n"
+        "import tpu_render_cluster.worker.queue\n"
+        "from tpu_render_cluster.obs import step\n"
+        "with step('file_write'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'the master imported JAX'\n"
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+def test_a_profile_carries_the_steps_on_its_own_clock(tmp_path):
+    """A jax.profiler trace taken on the CPU holds a `trc:<step>` host
+    event for every step opened while it ran."""
+    import jax
+
+    jax.numpy.zeros(1).block_until_ready()  # backend start-up stays out of the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with step("dispatch"):
+            with step("device_wait"):
+                time.sleep(0.002)
+        with step("file_write"):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    files = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert files
+    data = jax.profiler.ProfileData.from_file(str(files[-1]))
+    found: dict[str, list[float]] = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("trc:"):
+                    found.setdefault(event.name, []).append(event.duration_ns / 1e9)
+    assert set(found) == {"trc:dispatch", "trc:device_wait", "trc:file_write"}
+    assert len(found["trc:dispatch"]) == 2  # suspended, then resumed
+    assert 0.002 <= max(found["trc:device_wait"]) < 0.05
+
+
+# -- the backend's three tiers ---------------------------------------------------
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    import jax
+
+    monkeypatch.setenv("TRC_PALLAS", "1")
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+TIERS = {
+    "masked": {"wavefront": "off", "raypool": "off"},
+    "wavefront": {"wavefront": "force", "raypool": "off"},
+    "raypool": {"wavefront": "off", "raypool": "force"},
+}
+BOUNCES = 3
+
+
+def render_one(tier: str, tmp_path: Path, frames_ahead: tuple[int, ...] = ()):
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    job = make_job("04_very-simple_steps", 4)
+    backend = TpuRaytraceBackend(
+        base_directory=tmp_path, width=64, height=64, samples=2,
+        max_bounces=BOUNCES, **TIERS[tier],
+    )
+    if frames_ahead:
+        backend.note_upcoming_frames(job, frames_ahead)
+    backend._render_sync(job, 1)  # compiles; the frame below is the measured one
+    if frames_ahead:
+        backend._raypool_cache.clear()
+        backend.note_upcoming_frames(job, frames_ahead)
+    return backend, job, backend._render_sync(job, 1)
+
+
+@pytest.mark.parametrize("tier", sorted(TIERS))
+def test_every_tier_names_all_six_steps_and_they_add_up_to_the_phases(
+    tier, tmp_path, interpreted_kernels
+):
+    _backend, _job, timing = render_one(tier, tmp_path)
+    totals = seconds_by_step(timing.steps)
+    assert set(totals) == set(FRAME_STEPS)
+    read_render = (
+        timing.finished_loading_at - timing.started_process_at
+        + timing.finished_rendering_at - timing.started_rendering_at
+    )
+    write = timing.file_saving_finished_at - timing.file_saving_started_at
+    in_render = sum(totals[name] for name in ("resolve", "dispatch", "device_wait", "readback"))
+    in_write = totals["encode"] + totals["file_write"]
+    # Within 2%, or within half a millisecond where the phase is so short
+    # (a 64x64 file is written in under a millisecond) that entering and
+    # leaving three context managers on a loaded machine is more than 2%.
+    assert in_render == pytest.approx(read_render, rel=0.02, abs=5e-4)
+    assert in_write == pytest.approx(write, rel=0.02, abs=5e-4)
+    assert in_render <= read_render and in_write <= write  # steps lie inside the phases
+    # and to the frame: what has no step is the bookkeeping after the file
+    frame = timing.exited_process_at - timing.started_process_at
+    assert frame - (in_render + in_write) < 0.002
+    ends = [start + seconds for _, start, seconds in timing.steps]
+    assert ends == sorted(ends)
+    assert (tmp_path / "out" / "rendered-00001.jpg").is_file()
+
+
+def test_the_one_program_tier_waits_for_the_device_once(tmp_path, interpreted_kernels):
+    _backend, _job, timing = render_one("masked", tmp_path)
+    names = [name for name, _, _ in timing.steps]
+    assert names == list(FRAME_STEPS[:4]) + ["file_write", "encode", "file_write"]
+
+
+def test_the_wavefront_waits_once_per_bounce_and_once_for_the_pixels(tmp_path, interpreted_kernels):
+    from tpu_render_cluster.obs import get_tracer
+
+    get_tracer().clear()
+    _backend, _job, timing = render_one("wavefront", tmp_path)
+    bounces = [
+        e for e in get_tracer().events()
+        if e["name"] == "wavefront_bounce" and e["ts"] >= timing.started_process_at * 1e6
+    ]
+    waits = [s for s in timing.steps if s[0] == "device_wait"]
+    assert 1 <= len(bounces) <= BOUNCES
+    assert len(waits) == len(bounces) + 1
+    # every wait lies inside the render phase, and dispatch resumes around each
+    names = [name for name, _, _ in timing.steps]
+    assert names.count("dispatch") == len(bounces) + 1
+
+
+def test_a_raypool_batch_waits_and_copies_inside_the_trigger_frame(tmp_path, interpreted_kernels):
+    backend, job, timing = render_one("raypool", tmp_path, frames_ahead=(2, 3))
+    names = [name for name, _, _ in timing.steps]
+    # the batch's wait and copy, then the frame's own
+    assert names.count("device_wait") == 2 and names.count("readback") == 2
+    assert set(backend._raypool_cache) == {(job.job_name, 2, None), (job.job_name, 3, None)}
+    backend.note_upcoming_frames(job, (3,))
+    cached = backend._render_sync(job, 2)  # served from the cache: tonemap only
+    cached_names = [name for name, _, _ in cached.steps]
+    assert cached_names.count("device_wait") == 1 and set(cached_names) == set(FRAME_STEPS)
+
+
+def test_the_steps_stay_off_the_wire_and_out_of_the_raw_trace(tmp_path, interpreted_kernels):
+    _backend, _job, timing = render_one("masked", tmp_path)
+    assert timing.steps
+    assert set(timing.to_dict()) == {
+        "started_process_at", "finished_loading_at", "started_rendering_at",
+        "finished_rendering_at", "file_saving_started_at", "file_saving_finished_at",
+        "exited_process_at",
+    }
+    assert FrameRenderTime.from_dict(timing.to_dict()) == timing  # steps do not compare
+    assert FrameRenderTime.from_dict(timing.to_dict()).steps == ()
+
+
+# -- write_image -----------------------------------------------------------------
+
+
+def parents_write_image(path: Path, pixels: np.ndarray, image_format: str) -> None:
+    """`write_image` as the parent commit had it: encoded straight into the
+    temporary file."""
+    from PIL import Image
+
+    path.parent.mkdir(parents=True, exist_ok=True)
+    image = Image.fromarray(np.asarray(pixels))
+    fd, tmp_name = tempfile.mkstemp(prefix=f".{path.name}.", suffix=".tmp", dir=path.parent)
+    with os.fdopen(fd, "wb") as f:
+        if image_format == "JPEG":
+            image.save(f, image_format, quality=90)
+        else:
+            image.save(f, image_format)
+    os.replace(tmp_name, path)
+
+
+@pytest.mark.parametrize("file_format,image_format,extension", [
+    ("JPEG", "JPEG", ".jpg"), ("JPG", "JPEG", ".jpg"), ("PNG", "PNG", ".png"), ("EXR", "PNG", ".png"),
+])
+def test_write_image_writes_the_parents_bytes(tmp_path, file_format, image_format, extension):
+    from tpu_render_cluster.render.image_io import write_image
+
+    rng = np.random.default_rng(7)
+    gradient = np.linspace(0, 255, 512 * 512 * 3).reshape(512, 512, 3)
+    pixels = np.clip(gradient + rng.normal(0, 20, gradient.shape), 0, 255).astype(np.uint8)
+    ours, theirs = tmp_path / "ours" / f"f{extension}", tmp_path / "theirs" / f"f{extension}"
+    with frame_steps() as steps:
+        write_image(ours, pixels, file_format)
+    parents_write_image(theirs, pixels, image_format)
+    assert ours.read_bytes() == theirs.read_bytes()
+    assert [name for name, _, _ in steps] == ["encode", "file_write"]
+    assert [p.name for p in ours.parent.iterdir()] == [ours.name]  # no temporary file left
+
+
+def test_write_image_leaves_no_temporary_file_when_the_write_fails(tmp_path, monkeypatch):
+    from tpu_render_cluster.render import image_io
+
+    def refuse(*_args, **_kwargs):
+        raise OSError("disk full")
+
+    monkeypatch.setattr(os, "replace", refuse)
+    with pytest.raises(OSError, match="disk full"):
+        image_io.write_image(tmp_path / "f.png", np.zeros((8, 8, 3), np.uint8), "PNG")
+    assert list(tmp_path.iterdir()) == []
+
+
+# -- the worker queue ------------------------------------------------------------
+
+
+class SenderStub:
+    def __init__(self, seconds: float = 0.0) -> None:
+        self.sent = []
+        self.seconds = seconds
+
+    async def send_message(self, message) -> None:
+        if self.seconds:
+            await asyncio.sleep(self.seconds)
+        self.sent.append(message)
+
+
+class SteppedMockBackend(MockBackend):
+    """A mock frame that also names its steps, as the tpu-raytrace backend does."""
+
+    async def render_frame(self, job, frame_index, tile=None):
+        import dataclasses
+
+        timing = await super().render_frame(job, frame_index, tile)
+        at = timing.started_process_at
+        steps = []
+        for name, seconds in (
+            ("resolve", 0.001), ("dispatch", 0.002), ("device_wait", 0.004), ("dispatch", 0.001),
+            ("device_wait", 0.003), ("readback", 0.001), ("encode", 0.002), ("file_write", 0.001),
+        ):
+            steps.append((name, at, seconds))
+            at += seconds
+        return dataclasses.replace(timing, steps=tuple(steps))
+
+
+def run_queue(backend, frames: int, *, idle_seconds: float = 0.0, sender_seconds: float = 0.0):
+    metrics, span_tracer = MetricsRegistry(), Tracer("worker-test")
+
+    async def drive():
+        queue = WorkerAutomaticQueue(
+            backend, SenderStub(sender_seconds), WorkerTraceBuilder(), CancellationToken(),
+            metrics=metrics, span_tracer=span_tracer,
+        )
+        job = make_job("steps-mock", frames)
+        started = time.perf_counter()
+        queue.start()
+        await asyncio.sleep(idle_seconds)  # nothing queued yet: the loop starves
+        for frame in range(1, frames + 1):
+            queue.queue_frame(job, frame)
+        while len(backend.rendered_frames) < frames or queue.queue_size():
+            await asyncio.sleep(0.005)
+        await asyncio.sleep(idle_seconds)
+        await queue.join()
+        return time.perf_counter() - started
+
+    return asyncio.run(drive()), metrics, span_tracer
+
+
+def test_the_loops_three_states_add_up_to_its_wall_time():
+    backend = MockBackend(load_seconds=0.002, render_seconds=0.02, save_seconds=0.002)
+    wall, metrics, _ = run_queue(backend, 6, idle_seconds=0.25, sender_seconds=0.003)
+    counter = metrics.counter("worker_loop_seconds_total", labels=("state",))
+    by_state = {state: counter.value(state=state) for state in LOOP_STATES}
+    assert sum(by_state.values()) == pytest.approx(wall, abs=0.02)
+    assert by_state["render_call"] == pytest.approx(6 * 0.024, abs=0.03)
+    assert by_state["no_work"] == pytest.approx(0.5, abs=0.06)
+    assert by_state["report"] >= 6 * 2 * 0.003  # two events a frame through the sender
+
+
+def test_a_failed_frame_is_charged_to_the_render_call_and_the_loop_goes_on():
+    backend = MockBackend(load_seconds=0.001, render_seconds=0.005, save_seconds=0.001, fail_frames={2})
+
+    async def drive():
+        metrics = MetricsRegistry()
+        queue = WorkerAutomaticQueue(
+            backend, SenderStub(), WorkerTraceBuilder(), CancellationToken(), metrics=metrics,
+        )
+        job = make_job("steps-mock-fail", 3)
+        queue.start()
+        for frame in (1, 2, 3):
+            queue.queue_frame(job, frame)
+        while queue.queue_size():
+            await asyncio.sleep(0.005)
+        await queue.join()
+        return metrics
+
+    metrics = asyncio.run(drive())
+    counter = metrics.counter("worker_loop_seconds_total", labels=("state",))
+    assert backend.rendered_frames == [1, 3]
+    assert counter.value(state="render_call") > 0.01 and counter.value(state="report") > 0
+
+
+def test_draining_time_is_nobodys():
+    async def drive():
+        metrics = MetricsRegistry()
+        queue = WorkerAutomaticQueue(
+            MockBackend(), SenderStub(), WorkerTraceBuilder(), CancellationToken(), metrics=metrics,
+        )
+        queue.start()
+        await asyncio.sleep(0.05)
+        await queue.drain()
+        starved = metrics.counter("worker_loop_seconds_total", labels=("state",)).value(state="no_work")
+        await asyncio.sleep(0.3)
+        await queue.join()
+        return starved, metrics.counter("worker_loop_seconds_total", labels=("state",)).value(state="no_work")
+
+    at_drain, at_end = asyncio.run(drive())
+    assert at_end - at_drain < 0.11  # at most the poll that was under way
+
+
+def test_steps_enter_the_registry_and_the_timeline_with_the_phases():
+    _, metrics, span_tracer = run_queue(SteppedMockBackend(render_seconds=0.005), 3)
+    histogram = metrics.histogram("worker_frame_step_seconds", labels=("step",))
+    phases = metrics.histogram("worker_frame_phase_seconds", labels=("phase",))
+    assert histogram.buckets == phases.buckets
+    snapshot = metrics.snapshot()["worker_frame_step_seconds"]["series"]
+    by_step = {key.removeprefix("step="): entry for key, entry in snapshot.items()}
+    assert set(by_step) == set(FRAME_STEPS)
+    assert by_step["device_wait"]["count"] == 6  # twice a frame: the host syncs
+    assert by_step["device_wait"]["sum"] == pytest.approx(3 * 0.007)
+    assert by_step["dispatch"]["sum"] == pytest.approx(3 * 0.003)
+    events = span_tracer.events()
+    steps = [e for e in events if e.get("cat") == "worker.step"]
+    assert len(steps) == 3 * 8 and {e["name"] for e in steps} == set(FRAME_STEPS)
+    assert {e["args"]["frame"] for e in steps} == {1, 2, 3}
+    phase_events = [e for e in events if e.get("cat") == "worker"]
+    assert {e["tid"] for e in steps}.isdisjoint({e["tid"] for e in phase_events})
+    names = {m["args"]["name"] for m in span_tracer.metadata_events() if m["name"] == "thread_name"}
+    assert {"frames", "steps"} <= names
+
+
+def test_the_timeline_with_steps_passes_the_trace_validator(tmp_path):
+    from tpu_render_cluster.obs import validate_trace_file
+
+    _, _, span_tracer = run_queue(SteppedMockBackend(render_seconds=0.005), 4)
+    path = span_tracer.export(tmp_path / "worker-test_trace-events.json")
+    assert validate_trace_file(path) == []
+
+
+def test_a_backend_without_steps_feeds_the_phases_alone():
+    _, metrics, span_tracer = run_queue(MockBackend(render_seconds=0.005), 2)
+    assert "worker_frame_step_seconds" not in {
+        name for name, family in metrics.snapshot().items() if family["series"]
+    }
+    assert not [e for e in span_tracer.events() if e.get("cat") == "worker.step"]
+    assert metrics.counter("worker_frames_rendered_total").value() == 2
+
+
+def test_the_event_buffer_holds_the_sources_largest_job_on_one_worker():
+    """14,400 frames, each with four phase spans, four flow steps and the
+    raypool's nine step segments, fit under the default cap."""
+    per_frame = 4 + 4 + 9
+    assert tracer_module.MAX_EVENTS >= 14_400 * per_frame
+    assert Tracer("worker-full")._max_events == tracer_module.MAX_EVENTS
+
+
+# -- which timeline is which chip's ---------------------------------------------
+
+
+@pytest.mark.parametrize("visible,chip", [(None, None), ("2", 2), ("0,1", None)])
+def test_the_device_stamp_carries_the_chips_index(monkeypatch, visible, chip):
+    from tpu_render_cluster.utils.accelerator import chip_environment, require_tpu_device
+
+    if visible is None:
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    else:
+        monkeypatch.setenv("TPU_VISIBLE_CHIPS", visible)
+    stamp = require_tpu_device()
+    assert stamp["chip"] == chip
+    assert stamp["device_id"] == 0 and stamp["platform"] == "cpu"
+    assert chip_environment(2)["TPU_VISIBLE_CHIPS"] == "2"  # what a launcher sets is what is read
+
+
+def test_process_labels_ride_the_timelines_metadata(tmp_path):
+    from tpu_render_cluster.obs import validate_trace_file
+
+    tracer = Tracer("worker-abc")
+    assert [m["name"] for m in tracer.metadata_events()] == ["process_name"]
+    tracer.process_labels = {"platform": "tpu", "device_id": 0, "chip": 3}
+    tracer.complete("render", cat="worker", start_wall=1.0, duration=0.5, track="frames")
+    (labels,) = [m for m in tracer.metadata_events() if m["name"] == "process_labels"]
+    assert labels["ph"] == "M" and labels["pid"] == tracer.pid
+    assert labels["args"] == {
+        "labels": "platform=tpu, device_id=0, chip=3", "platform": "tpu", "device_id": 0, "chip": 3,
+    }
+    assert validate_trace_file(tracer.export(tmp_path / "t.json")) == []

@@ -43,7 +43,13 @@ from tpu_render_cluster.obs.timeline import (
     merge_timeline,
     tracer_process,
 )
-from tpu_render_cluster.obs.tracer import Tracer, export_chrome_trace
+from tpu_render_cluster.obs.tracer import (
+    FRAME_STEPS,
+    Tracer,
+    export_chrome_trace,
+    frame_steps,
+    step,
+)
 from tpu_render_cluster.obs.validate import (
     validate_trace_document,
     validate_trace_file,
@@ -51,6 +57,7 @@ from tpu_render_cluster.obs.validate import (
 
 __all__ = [
     "DEFAULT_BUCKETS",
+    "FRAME_STEPS",
     "ClockOffsetEstimator",
     "Counter",
     "FlightRecorder",
@@ -65,6 +72,7 @@ __all__ = [
     "Tracer",
     "export_chrome_trace",
     "export_cluster_trace",
+    "frame_steps",
     "get_registry",
     "get_tracer",
     "log_buckets",
@@ -72,6 +80,7 @@ __all__ = [
     "merge_wire",
     "render_fps_gauge",
     "resolve_flight_directory",
+    "step",
     "tracer_process",
     "validate_trace_document",
     "validate_trace_file",

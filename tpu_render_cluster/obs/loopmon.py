@@ -72,6 +72,9 @@ class LoopLagMonitor:
         self._episodes = metrics.counter(
             EPISODES_METRIC, _EPISODES_HELP, labels=("role",)
         )
+        # The role's series is exposed from the start, at 0: a scrape that
+        # finds no series cannot tell "never blocked" from "not monitored".
+        self._episodes.inc(0.0, role=role)
         self._task: asyncio.Task | None = None
 
     def start(self) -> None:

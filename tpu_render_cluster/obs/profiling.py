@@ -9,8 +9,7 @@ memory-bound. This module makes every execution tier report that:
   analysis (``jax.stages.Lowered.cost_analysis()`` — FLOPs + bytes
   accessed, estimated from the lowered HLO without a second backend
   compile) is recorded once per (kernel key, arg shapes);
-- **execute pairing**: the same drivers that feed the
-  ``render_execute_seconds`` histograms report each kernel's measured
+- **execute pairing**: the render drivers report each kernel's measured
   wall time (device-fenced where the tier syncs);
 - **roofline placement**: achieved FLOP/s = FLOPs x executions / total
   measured seconds, compared against ``min(peak_flops,

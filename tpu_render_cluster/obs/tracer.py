@@ -20,21 +20,112 @@ from __future__ import annotations
 import itertools
 import json
 import logging
+import sys
 import threading
 import time
 from contextlib import contextmanager
 from pathlib import Path
-from typing import Any, Iterable, Mapping
+from typing import Any, Iterable, Iterator, Mapping
 
-__all__ = ["Tracer", "export_chrome_trace"]
+__all__ = ["FRAME_STEPS", "Tracer", "export_chrome_trace", "frame_steps", "step"]
 
 logger = logging.getLogger(__name__)
 
 _pid_counter = itertools.count(1)
 
-# Bounded event buffers: a 14400-frame job emits ~5 events per frame; the
-# cap keeps a runaway instrumentation site from eating the master's heap.
-MAX_EVENTS = 200_000
+# Bounded event buffers: the cap keeps a runaway instrumentation site from
+# eating the process's heap; once full, the NEWEST events are dropped (and
+# counted). Sized so that the source's largest job on ONE worker drops
+# nothing: 14400 frames x (4 phase spans + 4 flow steps + 6 step segments)
+# is 201,600 events, 9 segments a frame under the raypool would be 244,800,
+# and a wavefront frame's ~22 (a dispatch and a device_wait segment per
+# bounce) stays far below either at that tier's frame counts.
+MAX_EVENTS = 300_000
+
+# The steps of one frame on the worker's render thread, the same in every
+# execution tier. At every instant of a frame exactly one is open:
+#   resolve      scene name, tile region, tier choice, compiled-renderer fetch
+#   dispatch     host time issuing device work that does not block (asking
+#                for the copy back included)
+#   device_wait  host blocked until the device has a result
+#   readback     what is left of the device-to-host copy after the wait
+#   encode       pixels to the output format's bytes, in memory
+#   file_write   output path, mkdir, temporary file, write, close, rename
+FRAME_STEPS = ("resolve", "dispatch", "device_wait", "readback", "encode", "file_write")
+
+_steps_local = threading.local()
+
+
+class _Segment:
+    """One uninterrupted stretch of a step on this thread."""
+
+    __slots__ = ("name", "start_wall", "start_mono", "annotation")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        # The clocks are read first and last, so that a frame's segments
+        # leave none of its time between them.
+        self.start_wall = time.time()
+        self.start_mono = time.perf_counter()
+        # Only a process that already imported JAX gets the annotation (the
+        # master never imports it). Outside a profiler session a
+        # TraceAnnotation is a check of one atomic.
+        jax = sys.modules.get("jax")
+        self.annotation = (
+            jax.profiler.TraceAnnotation("trc:" + name) if jax is not None else None
+        )
+        if self.annotation is not None:
+            self.annotation.__enter__()
+
+    def close(self) -> None:
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
+        sink = getattr(_steps_local, "sink", None)
+        if sink is not None:
+            sink.append(
+                (self.name, self.start_wall, time.perf_counter() - self.start_mono)
+            )
+
+
+@contextmanager
+def step(name: str) -> Iterator[None]:
+    """One of FRAME_STEPS, timed where it happens, EXCLUSIVELY.
+
+    A step opened inside another suspends the outer one until it ends, so
+    a frame's steps never overlap and add up to the frame. Each
+    uninterrupted stretch (a suspended step resumes as a new one) is
+    remembered as ``(name, start_wall, seconds)`` for the frame in hand
+    (``frame_steps``; nothing is kept outside one), and lies inside a
+    ``jax.profiler.TraceAnnotation("trc:<name>")`` so a profile taken by
+    anyone carries the program's steps on the profile's own clock.
+    """
+    if name not in FRAME_STEPS:
+        raise ValueError(f"unknown frame step {name!r} (have: {FRAME_STEPS})")
+    stack = getattr(_steps_local, "stack", None)
+    if stack is None:
+        stack = _steps_local.stack = []
+    if stack:
+        stack[-1].close()
+    stack.append(_Segment(name))
+    try:
+        yield
+    finally:
+        stack.pop().close()
+        if stack:
+            stack[-1] = _Segment(stack[-1].name)
+
+
+@contextmanager
+def frame_steps() -> Iterator[list[tuple[str, float, float]]]:
+    """Collect this thread's steps for one frame; yields the list they
+    land in, in the order they ended."""
+    previous = getattr(_steps_local, "sink", None)
+    sink: list[tuple[str, float, float]] = []
+    _steps_local.sink = sink
+    try:
+        yield sink
+    finally:
+        _steps_local.sink = previous
 
 
 class Tracer:
@@ -50,6 +141,10 @@ class Tracer:
         self._events: list[dict[str, Any]] = []
         self._dropped = 0
         self._tracks: dict[str, int] = {}
+        # What tells this process row from its siblings beyond its name
+        # (a worker: which chip it held); exported as the viewer's
+        # ``process_labels`` metadata, keys kept beside the label text.
+        self.process_labels: dict[str, Any] = {}
 
     # -- recording -----------------------------------------------------------
 
@@ -217,6 +312,17 @@ class Tracer:
                 "args": {"name": self.process_name},
             }
         ]
+        if self.process_labels:
+            labels = ", ".join(f"{k}={v}" for k, v in self.process_labels.items())
+            out.append(
+                {
+                    "name": "process_labels",
+                    "ph": "M",
+                    "pid": self.pid,
+                    "tid": 0,
+                    "args": {"labels": labels, **self.process_labels},
+                }
+            )
         with self._lock:
             tracks = dict(self._tracks)
         for track, tid in tracks.items():

@@ -329,7 +329,7 @@ def trace_paths_wavefront(
     variant; with it, each bounce's compaction reads the key column the
     previous bounce kernel emitted instead of re-deriving keys.
     """
-    from tpu_render_cluster.obs import get_tracer
+    from tpu_render_cluster.obs import get_tracer, step
 
     n0 = origins.shape[0]
     kind = "mesh" if mesh is not None else "sphere"
@@ -390,7 +390,10 @@ def trace_paths_wavefront(
                     origins, directions, throughput, alive, lane, rng
                 )
             )
-        live = int(live_dev)
+        # The bounce's host sync, a frame step of its own: it suspends the
+        # caller's dispatch step while the host waits for the live count.
+        with step("device_wait"):
+            live = int(live_dev)
         survival.observe(live / n0, bounce=bounce)
         if live == 0:
             occupancy.set(0.0)

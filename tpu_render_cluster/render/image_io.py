@@ -75,38 +75,48 @@ def output_path_for_tile(
 def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> None:
     """Write a [H, W, 3] uint8 array; falls back to PNG for unknown formats.
 
+    Two frame steps (obs.step): ``encode`` turns the pixels into the
+    format's bytes in memory, ``file_write`` puts them on disk.
+
     Atomic (write-temp-then-rename): a reader never sees a torn file.
     Load-bearing for tile assembly — a duplicate assignment of the same
     tile (queue-add ack timeout races) can still be writing the tile path
     when the master's stitcher reads it; both copies carry identical
     pixels, so with the rename either complete version is correct.
     """
+    import io
     import os
     import tempfile
 
     from PIL import Image
+
+    from tpu_render_cluster.obs import step
 
     image_format = file_format.upper()
     if image_format == "JPG":
         image_format = "JPEG"
     if image_format not in _FORMAT_EXTENSIONS:
         image_format = "PNG"
-    path.parent.mkdir(parents=True, exist_ok=True)
-    image = Image.fromarray(np.asarray(pixels))
-    fd, tmp_name = tempfile.mkstemp(
-        prefix=f".{path.name}.", suffix=".tmp", dir=path.parent
-    )
-    try:
-        with os.fdopen(fd, "wb") as f:
-            if image_format == "JPEG":
-                # reference script: quality=90
-                image.save(f, image_format, quality=90)
-            else:
-                image.save(f, image_format)
-        os.replace(tmp_name, path)
-    except BaseException:
+    with step("encode"):
+        image = Image.fromarray(np.asarray(pixels))
+        encoded = io.BytesIO()
+        if image_format == "JPEG":
+            # reference script: quality=90
+            image.save(encoded, image_format, quality=90)
+        else:
+            image.save(encoded, image_format)
+    with step("file_write"):
+        path.parent.mkdir(parents=True, exist_ok=True)
+        fd, tmp_name = tempfile.mkstemp(
+            prefix=f".{path.name}.", suffix=".tmp", dir=path.parent
+        )
         try:
-            os.unlink(tmp_name)
-        except OSError:
-            pass
-        raise
+            with os.fdopen(fd, "wb") as f:
+                f.write(encoded.getbuffer())
+            os.replace(tmp_name, path)
+        except BaseException:
+            try:
+                os.unlink(tmp_name)
+            except OSError:
+                pass
+            raise

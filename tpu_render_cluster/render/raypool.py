@@ -779,6 +779,7 @@ def render_batch_raypool(
     """
     import numpy as np
 
+    from tpu_render_cluster.obs import step
     from tpu_render_cluster.render.scene import mesh_kind_for_scene
 
     frames = [int(f) for f in frame_indices]
@@ -841,14 +842,20 @@ def render_batch_raypool(
             wide=wide,
         )
         # THE host sync of the batch: everything before this line is one
-        # dispatched XLA program.
-        linear = np.asarray(linear)
-        (iterations, served, refilled, live_sum, launched_sum, occ_log,
-         refill_log) = (
-            int(stats[0]), int(stats[1]), int(stats[2]),
-            float(stats[3]), float(stats[4]),
-            np.asarray(stats[5]), np.asarray(stats[6]),
-        )
+        # dispatched XLA program. The wait and the copy are frame steps of
+        # their own (they suspend the caller's dispatch step); the images
+        # are asked for behind the program, as np.asarray alone would.
+        linear.copy_to_host_async()
+        with step("device_wait"):
+            jax.block_until_ready((linear, stats))
+        with step("readback"):
+            linear = np.asarray(linear)
+            (iterations, served, refilled, live_sum, launched_sum, occ_log,
+             refill_log) = (
+                int(stats[0]), int(stats[1]), int(stats[2]),
+                float(stats[3]), float(stats[4]),
+                np.asarray(stats[5]), np.asarray(stats[6]),
+            )
         duration = time.perf_counter() - start_mono
         # Roofline profiling: capture the pool program's cost analysis
         # once per pool config (the same identity note_compile tracks;

@@ -10,7 +10,7 @@ analysis/core/models.py:46-131).
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any
 
 
@@ -29,6 +29,12 @@ class FrameRenderTime:
     file_saving_started_at: float
     file_saving_finished_at: float
     exited_process_at: float
+    # The frame's exclusive steps as (name, start_wall, seconds), in the
+    # order they ended (obs.step / obs.frame_steps). Worker-local: beside
+    # the seven points, never on the wire or in the raw trace.
+    steps: tuple[tuple[str, float, float], ...] = field(
+        default=(), compare=False, repr=False
+    )
 
     def total_execution_time(self) -> float:
         duration = self.exited_process_at - self.started_process_at

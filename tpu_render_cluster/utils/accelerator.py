@@ -104,12 +104,18 @@ def require_tpu_device() -> dict:
             continue
         if re.fullmatch(r"/dev/(accel|vfio/)\d+", target):
             held.add(target)
+    # Which chip of the host: a pinned process sees its chip as device 0,
+    # so the index is the one its launcher confined it to
+    # (chip_environment); None where the process was not pinned to one.
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "")
     return {
         "platform": platform,
         "device_kind": devices[0].device_kind,
         "count": len(devices),
         "devices": [str(device) for device in devices],
         "device_files": sorted(held),
+        "device_id": devices[0].id,
+        "chip": int(visible) if visible.isdigit() else None,
     }
 
 

@@ -221,40 +221,29 @@ class TpuRaytraceBackend(RenderBackend):
 
     @staticmethod
     def _observe_render_obs(
-        *, compile_seconds: float, execute_seconds: float,
-        from_cache: bool = False, kernel: str | None = None,
+        *, execute_seconds: float, from_cache: bool = False,
+        kernel: str | None = None,
     ) -> None:
         """Feed the process-global obs registry (one TPU per process).
 
-        ``render_compile_seconds`` is the loading phase (fetching — or
-        first building — the compiled renderer); ``render_execute_seconds``
-        is fenced device compute + readback. The frames/s gauge uses the
-        same device-time accounting bench.py reports (frames per second of
-        synced device execution), so the live gauge and the headline bench
-        number are directly comparable.
+        The frame's own times are the phase and step histograms the
+        worker queue feeds; what is left here is what those cannot say:
+        cache hits, the frames/s gauge bench.py shares, and the roofline
+        pairing.
         """
         from tpu_render_cluster.obs import get_registry, render_fps_gauge
 
         registry = get_registry()
-        registry.histogram(
-            "render_compile_seconds",
-            "Per-frame compiled-renderer fetch/build (the 'loading' phase)",
-        ).observe(max(0.0, compile_seconds))
         if from_cache:
             # A ray-pool cache hit: this frame's device time was amortized
             # into the batch that rendered it ahead — its ~tonemap-only
-            # execute time belongs in neither the per-frame execute
-            # histogram nor the fps gauge (both would report fantasy
-            # per-frame device rates under batching).
+            # execute time does not belong in the fps gauge (it would
+            # report fantasy per-frame device rates under batching).
             registry.counter(
                 "render_raypool_cache_hits_total",
                 "Frames served from the ray-pool rendered-ahead cache",
             ).inc()
             return
-        registry.histogram(
-            "render_execute_seconds",
-            "Per-frame device render + readback (block-until-ready fenced)",
-        ).observe(max(0.0, execute_seconds))
         if execute_seconds > 0:
             render_fps_gauge(registry).set(1.0 / execute_seconds)
         if kernel is not None and execute_seconds > 0:
@@ -268,8 +257,20 @@ class TpuRaytraceBackend(RenderBackend):
     def _render_sync(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
     ) -> FrameRenderTime:
+        """One frame on the render thread; its exclusive steps (obs.step)
+        ride the timing beside the seven points."""
+        from tpu_render_cluster.obs import frame_steps
+
+        with frame_steps() as steps:
+            return self._render_timed(job, frame_index, tile, steps)
+
+    def _render_timed(
+        self, job: BlenderJob, frame_index: int, tile: int | None,
+        steps: list[tuple[str, float, float]],
+    ) -> FrameRenderTime:
         import numpy as np
 
+        from tpu_render_cluster.obs import step
         from tpu_render_cluster.render.image_io import (
             output_path_for_frame,
             output_path_for_tile,
@@ -280,131 +281,156 @@ class TpuRaytraceBackend(RenderBackend):
 
         started_process_at = time.time()
 
-        scene_name = scene_for_job_name(job.job_name)
-        # Tiled work unit: resolve the tile's pixel region once. All three
-        # execution tiers below serve it through their region paths, which
-        # trace the FULL frame's rays/RNG restricted to these pixels — a
-        # master-assembled grid of tiles is pixel-identical to the
-        # whole-frame render (render/integrator.region_rays_and_seed).
-        region = None
-        if tile is not None:
-            from tpu_render_cluster.jobs.tiles import tile_bounds
+        with step("resolve"):
+            scene_name = scene_for_job_name(job.job_name)
+            # Tiled work unit: resolve the tile's pixel region once. All three
+            # execution tiers below serve it through their region paths, which
+            # trace the FULL frame's rays/RNG restricted to these pixels — a
+            # master-assembled grid of tiles is pixel-identical to the
+            # whole-frame render (render/integrator.region_rays_and_seed).
+            region = None
+            if tile is not None:
+                from tpu_render_cluster.jobs.tiles import tile_bounds
 
-            if job.tile_grid is None:
-                raise RuntimeError(
-                    f"Tile {tile} requested but job {job.job_name!r} "
-                    "carries no tile grid."
+                if job.tile_grid is None:
+                    raise RuntimeError(
+                        f"Tile {tile} requested but job {job.job_name!r} "
+                        "carries no tile grid."
+                    )
+                region = tile_bounds(
+                    tile, job.tile_grid, width=self.width, height=self.height
                 )
-            region = tile_bounds(
-                tile, job.tile_grid, width=self.width, height=self.height
+            # "Loading" = fetching (or first-building) the compiled renderer for
+            # this scene/config — the analog of Blender's .blend load phase.
+            # Scene construction itself is fused into the XLA program: one
+            # device dispatch per frame instead of dozens of eager array ops.
+            # Wavefront mode has no single cached renderer (its per-bucket
+            # programs compile lazily inside the render — warm() pre-visits
+            # them), so its loading phase is just scene-name resolution; same
+            # for the ray-pool path (one pool program per config, warmed).
+            cache_key = (job.job_name, frame_index, tile)
+            cached_linear = self._raypool_cache.pop(cache_key, None)
+            # Work-ahead for a pool batch: same-job units still queued HERE
+            # with the SAME tile (a pool batch spans frames, not regions).
+            upcoming = [
+                u.frame_index
+                for u in self._upcoming.get(job.job_name, ())
+                if u.tile == tile
+                and u.frame_index != frame_index
+                and (job.job_name, u.frame_index, tile) not in self._raypool_cache
+            ]
+            use_raypool = cached_linear is None and self._use_raypool(
+                scene_name, frames_ahead=len(upcoming)
             )
-        # "Loading" = fetching (or first-building) the compiled renderer for
-        # this scene/config — the analog of Blender's .blend load phase.
-        # Scene construction itself is fused into the XLA program: one
-        # device dispatch per frame instead of dozens of eager array ops.
-        # Wavefront mode has no single cached renderer (its per-bucket
-        # programs compile lazily inside the render — warm() pre-visits
-        # them), so its loading phase is just scene-name resolution; same
-        # for the ray-pool path (one pool program per config, warmed).
-        cache_key = (job.job_name, frame_index, tile)
-        cached_linear = self._raypool_cache.pop(cache_key, None)
-        # Work-ahead for a pool batch: same-job units still queued HERE
-        # with the SAME tile (a pool batch spans frames, not regions).
-        upcoming = [
-            u.frame_index
-            for u in self._upcoming.get(job.job_name, ())
-            if u.tile == tile
-            and u.frame_index != frame_index
-            and (job.job_name, u.frame_index, tile) not in self._raypool_cache
-        ]
-        use_raypool = cached_linear is None and self._use_raypool(
-            scene_name, frames_ahead=len(upcoming)
-        )
-        use_wavefront = (
-            cached_linear is None
-            and not use_raypool
-            and self._use_wavefront(scene_name)
-        )
-        use_sharded = self.sharding in ("tile", "spp") and region is None
-        if (
-            not use_sharded
-            and cached_linear is None
-            and not use_wavefront
-            and not use_raypool
-            and region is None
-        ):
-            renderer = fused_frame_renderer(
-                scene_name,
-                self.width,
-                self.height,
-                self.samples,
-                self.max_bounces,
+            use_wavefront = (
+                cached_linear is None
+                and not use_raypool
+                and self._use_wavefront(scene_name)
             )
+            use_sharded = self.sharding in ("tile", "spp") and region is None
+            if (
+                not use_sharded
+                and cached_linear is None
+                and not use_wavefront
+                and not use_raypool
+                and region is None
+            ):
+                renderer = fused_frame_renderer(
+                    scene_name,
+                    self.width,
+                    self.height,
+                    self.samples,
+                    self.max_bounces,
+                )
         finished_loading_at = time.time()
 
         started_rendering_at = time.time()
-        if cached_linear is not None:
-            # Rendered ahead by an earlier pool batch of this job: only
-            # the tonemap + readback run now. The batch's device time was
-            # carried by the frame that triggered it — per-frame phase
-            # timings under batching reflect that amortization.
-            display = tonemap(cached_linear)
-        elif use_sharded:
-            from tpu_render_cluster.parallel.sharded_render import render_frame_sharded
+        # Issuing the device's work; the wavefront and raypool drivers open
+        # their own device_wait / readback steps inside, which suspend it.
+        with step("dispatch"):
+            if cached_linear is not None:
+                # Rendered ahead by an earlier pool batch of this job: only
+                # the tonemap + readback run now. The batch's device time was
+                # carried by the frame that triggered it — per-frame phase
+                # timings under batching reflect that amortization.
+                display = tonemap(cached_linear)
+            elif use_sharded:
+                from tpu_render_cluster.parallel.sharded_render import render_frame_sharded
 
-            linear = render_frame_sharded(
-                scene_name,
-                frame_index,
-                width=self.width,
-                height=self.height,
-                samples=self.samples,
-                max_bounces=self.max_bounces,
-                mode=self.sharding,
-            )
-            display = tonemap(linear)
-        elif use_raypool:
-            from tpu_render_cluster.render.raypool import (
-                raypool_frame_cap,
-                render_batch_raypool,
-            )
-
-            # One pool window: this unit plus the next queued same-tile
-            # frames of the same job (the queue's hint — all assigned to
-            # THIS worker, so nothing is rendered speculatively). Units
-            # rendered ahead are served from the cache on their own
-            # requests.
-            batch = [frame_index] + upcoming[: raypool_frame_cap() - 1]
-            images = render_batch_raypool(
-                scene_name,
-                batch,
-                width=self.width,
-                height=self.height,
-                samples=self.samples,
-                max_bounces=self.max_bounces,
-                region=region,
-            )
-            for ahead_frame, image in zip(batch[1:], images[1:]):
-                self._raypool_cache[(job.job_name, ahead_frame, tile)] = image
-            self._trim_raypool_cache()
-            display = tonemap(images[0])
-        elif use_wavefront:
-            from tpu_render_cluster.render.compaction import (
-                render_frame_wavefront,
-                render_region_wavefront,
-            )
-
-            if region is None:
-                linear = render_frame_wavefront(
+                linear = render_frame_sharded(
                     scene_name,
                     frame_index,
                     width=self.width,
                     height=self.height,
                     samples=self.samples,
                     max_bounces=self.max_bounces,
+                    mode=self.sharding,
                 )
-            else:
+                display = tonemap(linear)
+            elif use_raypool:
+                from tpu_render_cluster.render.raypool import (
+                    raypool_frame_cap,
+                    render_batch_raypool,
+                )
+
+                # One pool window: this unit plus the next queued same-tile
+                # frames of the same job (the queue's hint — all assigned to
+                # THIS worker, so nothing is rendered speculatively). Units
+                # rendered ahead are served from the cache on their own
+                # requests.
+                batch = [frame_index] + upcoming[: raypool_frame_cap() - 1]
+                images = render_batch_raypool(
+                    scene_name,
+                    batch,
+                    width=self.width,
+                    height=self.height,
+                    samples=self.samples,
+                    max_bounces=self.max_bounces,
+                    region=region,
+                )
+                for ahead_frame, image in zip(batch[1:], images[1:]):
+                    self._raypool_cache[(job.job_name, ahead_frame, tile)] = image
+                self._trim_raypool_cache()
+                display = tonemap(images[0])
+            elif use_wavefront:
+                from tpu_render_cluster.render.compaction import (
+                    render_frame_wavefront,
+                    render_region_wavefront,
+                )
+
+                if region is None:
+                    linear = render_frame_wavefront(
+                        scene_name,
+                        frame_index,
+                        width=self.width,
+                        height=self.height,
+                        samples=self.samples,
+                        max_bounces=self.max_bounces,
+                    )
+                else:
+                    y0, x0, tile_height, tile_width = region
+                    linear = render_region_wavefront(
+                        scene_name,
+                        frame_index,
+                        y0=y0,
+                        x0=x0,
+                        tile_height=tile_height,
+                        tile_width=tile_width,
+                        width=self.width,
+                        height=self.height,
+                        samples=self.samples,
+                        max_bounces=self.max_bounces,
+                    )
+                display = tonemap(linear)
+            elif region is not None:
+                # Masked tier, one tile: the jitted region program (one
+                # compile per tile shape; y0/x0/frame are traced). Local
+                # tile/spp sharding is bypassed for cluster-tile units — the
+                # unit is already sub-frame work.
+                from tpu_render_cluster.render.integrator import render_frame_region
+
                 y0, x0, tile_height, tile_width = region
-                linear = render_region_wavefront(
+                linear = render_frame_region(
                     scene_name,
                     frame_index,
                     y0=y0,
@@ -416,61 +442,48 @@ class TpuRaytraceBackend(RenderBackend):
                     samples=self.samples,
                     max_bounces=self.max_bounces,
                 )
-            display = tonemap(linear)
-        elif region is not None:
-            # Masked tier, one tile: the jitted region program (one
-            # compile per tile shape; y0/x0/frame are traced). Local
-            # tile/spp sharding is bypassed for cluster-tile units — the
-            # unit is already sub-frame work.
-            from tpu_render_cluster.render.integrator import render_frame_region
-
-            y0, x0, tile_height, tile_width = region
-            linear = render_frame_region(
-                scene_name,
-                frame_index,
-                y0=y0,
-                x0=x0,
-                tile_height=tile_height,
-                tile_width=tile_width,
-                width=self.width,
-                height=self.height,
-                samples=self.samples,
-                max_bounces=self.max_bounces,
-            )
-            display = tonemap(linear)
-        else:
-            display = renderer(frame_index)
-        # One device sync per frame: np.asarray blocks on completion AND
-        # reads the image back. Readback counts as rendering, like
-        # Blender's in-process compositing; "saving" below is encode +
-        # disk only.
-        pixels = np.asarray(display)
+                display = tonemap(linear)
+            else:
+                display = renderer(frame_index)
+            # Ask for the pixels now, behind the frame's work in the
+            # device's queue, as np.asarray on an unfinished array does:
+            # a copy first asked for after the wait below would cost the
+            # frame a second host round trip.
+            display.copy_to_host_async()
+        # One device sync per frame, then (what is left of) the copy.
+        # Readback counts as rendering, like Blender's in-process
+        # compositing; "saving" below is encode + disk only.
+        with step("device_wait"):
+            display.block_until_ready()
+        with step("readback"):
+            pixels = np.asarray(display)
         finished_rendering_at = time.time()
 
         file_saving_started_at = time.time()
-        output_directory = parse_with_base_directory_prefix(
-            job.output_directory_path, self.base_directory
-        )
-        if tile is None:
-            path = output_path_for_frame(
-                output_directory,
-                job.output_file_name_format,
-                job.output_file_format,
-                frame_index,
+        with step("file_write"):
+            output_directory = parse_with_base_directory_prefix(
+                job.output_directory_path, self.base_directory
             )
-        else:
-            # One tile file per unit; the master's assembly service
-            # stitches the grid into the frame file and removes these.
-            # Always PNG (lossless — see image_io.output_path_for_tile);
-            # the assembler encodes the final frame in the job's format.
-            path = output_path_for_tile(
-                output_directory,
-                job.output_file_name_format,
-                job.output_file_format,
-                frame_index,
-                tile,
-                job.tile_grid,
-            )
+            if tile is None:
+                path = output_path_for_frame(
+                    output_directory,
+                    job.output_file_name_format,
+                    job.output_file_format,
+                    frame_index,
+                )
+            else:
+                # One tile file per unit; the master's assembly service
+                # stitches the grid into the frame file and removes these.
+                # Always PNG (lossless — see image_io.output_path_for_tile);
+                # the assembler encodes the final frame in the job's format.
+                path = output_path_for_tile(
+                    output_directory,
+                    job.output_file_name_format,
+                    job.output_file_format,
+                    frame_index,
+                    tile,
+                    job.tile_grid,
+                )
         write_image(
             path, pixels, "PNG" if tile is not None else job.output_file_format
         )
@@ -501,7 +514,6 @@ class TpuRaytraceBackend(RenderBackend):
                     s=self.samples, b=self.max_bounces,
                 )
         self._observe_render_obs(
-            compile_seconds=finished_loading_at - started_process_at,
             execute_seconds=finished_rendering_at - started_rendering_at,
             from_cache=cached_linear is not None,
             kernel=kernel,
@@ -514,4 +526,5 @@ class TpuRaytraceBackend(RenderBackend):
             file_saving_started_at=file_saving_started_at,
             file_saving_finished_at=file_saving_finished_at,
             exited_process_at=time.time(),
+            steps=tuple(steps),
         )

@@ -279,6 +279,13 @@ def main(argv: list[str] | None = None) -> int:
     if args.warm_scene and args.backend == "tpu-raytrace":
         backend.warm(args.warm_scene)
     worker = Worker(args.master_host, args.master_port, backend)
+    # Which timeline is which chip's: the device stamp's index rides the
+    # exported timeline's process metadata as well as the snapshot.
+    device = getattr(backend, "device", None)
+    if device:
+        worker.span_tracer.process_labels = {
+            key: device[key] for key in ("platform", "device_id", "chip")
+        }
     from tpu_render_cluster.obs.http import resolve_telemetry_port
 
     telemetry_port = resolve_telemetry_port(
@@ -317,9 +324,8 @@ def main(argv: list[str] | None = None) -> int:
             roofline = get_profiler().view()
             if roofline:
                 extra["roofline"] = roofline
-            # Which device rendered (tpu-raytrace only): platform and
-            # device_kind, stamped once when the backend was built.
-            device = getattr(backend, "device", None)
+            # Which device rendered (tpu-raytrace only): platform, kind and
+            # index, stamped once when the backend was built.
             if device:
                 extra["device"] = device
             write_metrics_snapshot(

@@ -38,6 +38,15 @@ QUEUE_POLL_SECONDS = 0.1  # reference: worker/src/rendering/queue.rs:74-96
 # post-hoc from trace gaps — here measured directly.
 FRAME_PHASES = ("queue_wait", "read", "render", "write")
 
+# The render loop's wall time, partitioned (worker_loop_seconds_total):
+#   no_work      no queued frame; waiting for the master (draining excluded)
+#   render_call  inside backend.render_frame, thread hop included — the
+#                frame's steps (obs.FRAME_STEPS) lie in it
+#   report       the rest of a frame's turn: the upcoming-frames hint, the
+#                rendering/finished events, trace bookkeeping, feeding the
+#                phase and step series
+LOOP_STATES = ("no_work", "render_call", "report")
+
 
 class FrameState(enum.Enum):
     QUEUED = "queued"
@@ -101,6 +110,29 @@ class WorkerAutomaticQueue:
             if metrics is not None
             else None
         )
+        self._step_histogram = (
+            metrics.histogram(
+                "worker_frame_step_seconds",
+                "Exclusive steps of a frame on the render thread "
+                "(resolve/dispatch/device_wait/readback/encode/file_write), "
+                "one observation per uninterrupted stretch",
+                labels=("step",),
+            )
+            if metrics is not None
+            else None
+        )
+        self._loop_seconds = (
+            metrics.counter(
+                "worker_loop_seconds_total",
+                "Wall time of the render loop by state "
+                "(no_work/render_call/report)",
+                labels=("state",),
+            )
+            if metrics is not None
+            else None
+        )
+        self._loop_state: str | None = None
+        self._loop_state_since = time.perf_counter()
         self._frames: list[QueuedFrame] = []
         self._finished_indices: set[tuple[str, int, int | None]] = set()
         # Bumped by reset_session(): a frame queued under a previous
@@ -236,10 +268,30 @@ class WorkerAutomaticQueue:
                 return frame
         return None
 
+    def _enter_loop_state(self, state: str | None) -> None:
+        """Charge the time since the last call to the state the loop was
+        in, then move to ``state`` (None: time nobody is charged for)."""
+        now = time.perf_counter()
+        if self._loop_seconds is not None and self._loop_state is not None:
+            self._loop_seconds.inc(
+                now - self._loop_state_since, state=self._loop_state
+            )
+        self._loop_state = state
+        self._loop_state_since = now
+
     async def _run(self) -> None:
+        try:
+            await self._run_loop()
+        finally:
+            self._enter_loop_state(None)
+
+    async def _run_loop(self) -> None:
         while not self._cancellation.is_cancelled():
             frame = None if self._draining else self._next_queued()
             if frame is None:
+                # Fed at every poll, so a scrape is never more than one
+                # poll interval behind on a starved worker.
+                self._enter_loop_state(None if self._draining else "no_work")
                 self._work_available.clear()
                 try:
                     await asyncio.wait_for(
@@ -251,6 +303,7 @@ class WorkerAutomaticQueue:
             await self._render_frame_and_report(frame)
 
     async def _render_frame_and_report(self, frame: QueuedFrame) -> None:
+        self._enter_loop_state("report")
         frame.state = FrameState.RENDERING
         job_name = frame.job.job_name
         # Backends that batch internally (ray-pool mode) get the same-job
@@ -274,11 +327,13 @@ class WorkerAutomaticQueue:
                 job_id=frame.job_id, tile=frame.tile, epoch=frame.epoch,
             )
         )
+        self._enter_loop_state("render_call")
         try:
             timing = await self._backend.render_frame(
                 frame.job, frame.frame_index, tile=frame.tile
             )
         except Exception as e:  # noqa: BLE001 - report, don't hang the master
+            self._enter_loop_state("report")
             logger.error("Unit %s render failed: %s", frame.unit.label, e)
             if self._metrics is not None:
                 self._metrics.counter(
@@ -295,6 +350,7 @@ class WorkerAutomaticQueue:
                 )
             )
             return
+        self._enter_loop_state("report")
         self._tracer.trace_new_rendered_frame(frame.frame_index, timing)
         self._observe_frame_phases(frame, timing)
         self._remove(frame)
@@ -363,6 +419,22 @@ class WorkerAutomaticQueue:
                         track="frames",
                         args=flow_args,
                     )
+        # The frame's steps enter the registry and the timeline together
+        # with its phases, so a scrape never sees half a frame. A category
+        # and a track of their own: readers of the phase spans
+        # (cat "worker", one at a time) see the timeline they always saw.
+        for name, start_wall, seconds in timing.steps:
+            if self._step_histogram is not None:
+                self._step_histogram.observe(seconds, step=name)
+            if self._span_tracer is not None:
+                self._span_tracer.complete(
+                    name,
+                    cat="worker.step",
+                    start_wall=start_wall,
+                    duration=seconds,
+                    track="steps",
+                    args={"frame": frame.frame_index},
+                )
         if self._metrics is not None:
             self._metrics.counter(
                 "worker_frames_rendered_total", "Frames rendered successfully"
