@@ -337,15 +337,18 @@ def test_worker_backend_batches_queue_and_serves_cache(monkeypatch, tmp_path):
 
 
 def test_raypool_active_dispatch_tiers(monkeypatch):
-    """Env tier + backend flag + auto heuristic (multi-frame deep-walk)."""
+    """Env tier + backend flag; auto never engages the pool."""
     from tpu_render_cluster.render.raypool import raypool_active
 
     monkeypatch.setenv("TRC_PALLAS", "1")
     monkeypatch.delenv("TRC_RAYPOOL", raising=False)
-    # auto: deep-walk mesh scene AND multi-frame lookahead only.
-    assert raypool_active("03_physics-2-mesh", frames_ahead=2)
+    # auto: off for every scene, whatever is queued ahead.
+    assert not raypool_active("03_physics-2-mesh", frames_ahead=2)
     assert not raypool_active("03_physics-2-mesh", frames_ahead=0)
     assert not raypool_active("04_very-simple", frames_ahead=4)
+    assert not raypool_active(
+        "03_physics-2-mesh", backend_flag="auto", frames_ahead=4
+    )
     # env tiers
     monkeypatch.setenv("TRC_RAYPOOL", "0")
     assert not raypool_active("03_physics-2-mesh", frames_ahead=4)

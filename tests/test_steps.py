@@ -332,6 +332,99 @@ def test_a_raypool_batch_waits_and_copies_inside_the_trigger_frame(tmp_path, int
     assert cached_names.count("device_wait") == 1 and set(cached_names) == set(FRAME_STEPS)
 
 
+@pytest.mark.parametrize("tier", sorted(TIERS))
+def test_every_frame_counts_once_under_the_tier_that_rendered_it(
+    tier, tmp_path, interpreted_kernels
+):
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    counter = TpuRaytraceBackend._tier_frames_counter()
+    tiers = ("masked", "wavefront", "raypool", "region", "sharded")
+
+    def read() -> dict[str, float]:
+        return {name: counter.value(tier=name) for name in tiers}
+
+    before = read()
+    backend, job, _timing = render_one(
+        tier, tmp_path, frames_ahead=(2, 3) if tier == "raypool" else ()
+    )
+    rendered = 2  # render_one renders frame 1 twice
+    if tier == "raypool":
+        backend.note_upcoming_frames(job, (3,))
+        backend._render_sync(job, 2)  # from the rendered-ahead cache: the pool's frame
+        rendered = 3
+    after = read()
+    assert {name: after[name] - before[name] for name in tiers} == {
+        name: (rendered if name == tier else 0) for name in tiers
+    }
+
+
+@pytest.mark.parametrize("scene,launches", [("03_physics-2-mesh", BOUNCES), ("04_very-simple", 0)])
+def test_the_one_program_tier_reports_the_occupancy_of_its_bounce_launches(
+    scene, launches, tmp_path, interpreted_kernels
+):
+    """A deep mesh frame is one launch per bounce over the whole frame's
+    rays: their live counts come back with the image (still one sync) and
+    feed the series the other two tiers feed for their launches. A scene
+    whose program launches no per-bounce kernel feeds nothing."""
+    from tpu_render_cluster.render.compaction import launch_occupancy_histogram
+    from tpu_render_cluster.render.raypool import (
+        pool_launched_lanes_counter,
+        pool_live_lanes_counter,
+    )
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    def read() -> tuple[float, float, float, float]:
+        occupancy = launch_occupancy_histogram().series()
+        return (
+            occupancy.count if occupancy else 0, occupancy.sum if occupancy else 0.0,
+            pool_launched_lanes_counter().value(), pool_live_lanes_counter().value(),
+        )
+
+    backend = TpuRaytraceBackend(
+        base_directory=tmp_path, width=32, height=32, samples=2,
+        max_bounces=BOUNCES, **TIERS["masked"],
+    )
+    job = make_job(f"{scene}_steps", 4)
+    before = read()
+    timing = backend._render_sync(job, 1)
+    count, total, launched, live = (b - a for a, b in zip(before, read()))
+    rays = 32 * 32 * 2
+    assert [name for name, _, _ in timing.steps].count("device_wait") == 1
+    assert count == launches and launched == rays * launches
+    if launches:
+        assert rays <= live < launched  # the first bounce is all live, then rays die
+        assert total == pytest.approx(live / rays)
+    else:
+        assert live == 0 and total == 0
+
+
+def test_the_live_counts_ride_the_same_image(interpreted_kernels):
+    from tpu_render_cluster.render.integrator import fused_frame_renderer
+
+    plain = np.asarray(fused_frame_renderer("03_physics-2-mesh", 32, 32, 2, BOUNCES)(3))
+    image, live = fused_frame_renderer("03_physics-2-mesh", 32, 32, 2, BOUNCES, with_live=True)(3)
+    live = np.asarray(live)
+    assert np.array_equal(np.asarray(image), plain)
+    assert live.shape == (BOUNCES,) and live[0] == 32 * 32 * 2
+    assert (np.diff(live) <= 0).all() and live[-1] > 0
+    image, live = fused_frame_renderer("04_very-simple", 32, 32, 2, BOUNCES, with_live=True)(3)
+    assert live is None and image.shape == (32, 32, 3)
+
+
+def test_the_tier_counter_is_exposed_at_zero_before_any_frame(monkeypatch):
+    from tpu_render_cluster import obs
+    from tpu_render_cluster.obs.prometheus import lint_metric, render_prometheus
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    monkeypatch.setattr(obs, "_global_registry", MetricsRegistry())
+    TpuRaytraceBackend(width=8, height=8, samples=1, max_bounces=2)
+    text = render_prometheus(obs.get_registry().snapshot())  # refuses a name that fails the lint
+    for tier in ("masked", "wavefront", "raypool"):
+        assert f'render_tier_frames_total{{tier="{tier}"}} 0' in text
+    assert lint_metric("render_tier_frames_total", "counter", ("tier",)) == []
+
+
 def test_the_steps_stay_off_the_wire_and_out_of_the_raw_trace(tmp_path, interpreted_kernels):
     _backend, _job, timing = render_one("masked", tmp_path)
     assert timing.steps

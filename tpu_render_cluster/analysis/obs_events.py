@@ -336,8 +336,11 @@ def summarize_raypool(metrics: list[dict[str, Any]]) -> dict[str, Any] | None:
     family handling as summarize_wavefront: registry-snapshot form
     first (newest per pid for the cumulative process_metrics), compact
     wire form only when no registry snapshot covered that file. None
-    when no snapshot carries the series (job never used the pool).
+    when no snapshot carries the series (job never used the pool). The
+    two lane counters alone do not count as the pool: the one-program
+    tier feeds them too for a deep mesh frame.
     """
+    shared = ("render_pool_launched_lanes_total", "render_pool_live_lanes_total")
     found = False
     live_count = 0
     live_sum = 0.0
@@ -366,7 +369,8 @@ def summarize_raypool(metrics: list[dict[str, Any]]) -> dict[str, Any] | None:
         for name in counters:
             counter = names.get(name)
             if counter:
-                found = took = True
+                took = True
+                found = found or name not in shared
                 counters[name] += sum(
                     float(v) for v in counter.get("series", {}).values()
                 )
@@ -386,7 +390,7 @@ def summarize_raypool(metrics: list[dict[str, Any]]) -> dict[str, Any] | None:
         for key, value in (wire.get("c") or {}).items():
             name = key.partition("|")[0]
             if name in counters:
-                found = True
+                found = found or name not in shared
                 counters[name] += float(value)
 
     _consume_metric_snapshots(metrics, take_registry, take_wire)

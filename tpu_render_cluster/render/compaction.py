@@ -86,16 +86,18 @@ def launch_occupancy_histogram(registry=None):
     the DRIVER (how much of what it actually launched was live), which
     is what the bucketed reclaim improves and what the ray pool's
     render_pool_live_fraction is compared against in bench.py's
-    three-way record.
+    three-way record. The one-program tier feeds it too for a deep mesh
+    scene (worker/backends/tpu_raytrace.py: per bounce, live / the
+    frame's whole ray set, which is what each of its launches is handed).
     """
     from tpu_render_cluster.obs import get_registry
 
     registry = registry if registry is not None else get_registry()
     return registry.histogram(
         "render_launch_occupancy",
-        "Per-bounce live fraction of the launched wavefront bucket "
-        "(1 - this, averaged, is the wavefront driver's own "
-        "wasted_lane_fraction)",
+        "Per-bounce live fraction of the launched width: the wavefront "
+        "driver's bucket (1 - this, averaged, is its own "
+        "wasted_lane_fraction), the whole frame in the one-program tier",
         buckets=ALIVE_FRACTION_BUCKETS,
     )
 
@@ -617,27 +619,18 @@ def render_region_wavefront(
     )
 
 
-def wavefront_active(
-    scene_name: str, *, backend_flag: str | None = None, frame=1
-) -> bool:
+def wavefront_active(scene_name: str, *, backend_flag: str | None = None) -> bool:
     """Whether the wavefront driver should render this scene.
 
     ``backend_flag`` (the worker's ``--wavefront`` / constructor knob)
-    overrides the ``TRC_WAVEFRONT`` env tier; ``auto`` defers to the
-    per-scene heuristic (deep-walk mesh scenes — exactly the scenes the
-    per-bounce dispatch already routes away from the megakernel).
+    overrides the ``TRC_WAVEFRONT`` env tier. Only ``force`` turns the
+    driver on: ``auto`` is the one-program tier for every scene
+    (``pk.wavefront_mode`` says why), so the answer never depends on the
+    scene's geometry and nothing is built to give it.
     """
     if not pk.pallas_enabled():
         return False
-    mode = backend_flag if backend_flag is not None else pk.wavefront_mode()
-    mode = str(mode).lower()
-    if mode in ("0", "false", "off", "no"):
-        return False
-    if mode not in ("auto", ""):
-        return True
-    from tpu_render_cluster.render.mesh import scene_mesh_set
-
-    return pk.wavefront_eligible(scene_mesh_set(scene_name, frame))
+    return pk.tier_forced(backend_flag if backend_flag is not None else pk.wavefront_mode())
 
 
 def _mean_complement(histogram) -> float | None:

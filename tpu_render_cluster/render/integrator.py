@@ -394,7 +394,7 @@ def region_rays_and_seed(
 
 def trace_paths(
     scene: Scene, origins, directions, key, *, max_bounces: int = 4, mesh=None,
-    rng_lanes=None, use_tlas=None, quant=None,
+    rng_lanes=None, use_tlas=None, quant=None, live_counts=None,
 ) -> jnp.ndarray:
     """Trace one sample per ray; returns radiance [R, 3].
 
@@ -421,6 +421,13 @@ def trace_paths(
     TLAS kernels additionally emit the next bounce's coherence sort key
     from their epilogue, so the re-sort below reads one precomputed
     column instead of re-deriving keys from the full ray state.
+
+    ``live_counts`` (optional list) collects, on the deep per-bounce path
+    only, each bounce launch's live-ray count (a traced int32 scalar the
+    launch already computes for its tail skip), so the caller can return
+    them from the same program — the one-program tier's launch occupancy
+    at no extra sync. Other paths launch no per-bounce kernel and leave
+    the list empty.
     """
     from tpu_render_cluster.render import pallas_kernels
 
@@ -514,6 +521,8 @@ def trace_paths(
             # the wavefront driver's compaction, which shares this
             # kernel).
             live = jnp.sum(alive.astype(jnp.int32))
+            if live_counts is not None:
+                live_counts.append(live)
             contribution, origins, directions, throughput, alive, keys = (
                 pallas_kernels.mesh_bounce_pallas(
                     scene, mesh, origins, directions, throughput, alive,
@@ -546,7 +555,7 @@ def trace_paths(
     jax.jit,
     static_argnames=(
         "width", "height", "tile_height", "tile_width", "samples",
-        "max_bounces", "use_tlas", "quant",
+        "max_bounces", "use_tlas", "quant", "with_live",
     ),
 )
 def render_tile(
@@ -565,6 +574,7 @@ def render_tile(
     mesh=None,
     use_tlas=None,
     quant=None,
+    with_live: bool = False,
 ) -> jnp.ndarray:
     """Render a tile; returns [tile_height, tile_width, 3] linear radiance.
 
@@ -573,9 +583,15 @@ def render_tile(
     (static; None = env tier) selects the two-level mesh kernel variant
     — a distinct value is a distinct compiled program, which is what
     lets the interleaved A/B bench run both variants in one process.
+
+    ``with_live`` (static) returns ``(radiance, live)`` instead, ``live``
+    the int32 [max_bounces] live-ray count of each per-bounce launch
+    (trace_paths' ``live_counts``), or None where the scene's path
+    launches no per-bounce kernel.
     """
     n = tile_height * tile_width
     base_key = tile_base_key(frame, y0, x0)
+    live_counts = [] if with_live else None
 
     from tpu_render_cluster.render import pallas_kernels
 
@@ -608,6 +624,7 @@ def render_tile(
             mesh=mesh,
             use_tlas=use_tlas,
             quant=quant,
+            live_counts=live_counts,
         )
         image = radiance.reshape(samples, n, 3).mean(axis=0)
     else:
@@ -635,7 +652,10 @@ def render_tile(
             sample_step, jnp.zeros((n, 3), jnp.float32), sample_keys
         )
         image = total / samples
-    return image.reshape(tile_height, tile_width, 3)
+    image = image.reshape(tile_height, tile_width, 3)
+    if with_live:
+        return image, (jnp.stack(live_counts) if live_counts else None)
+    return image
 
 
 def render_frame(
@@ -737,6 +757,7 @@ def _fused_frame_renderer(
     quant: int,
     builder: str,
     wide: int,
+    with_live: bool = False,
 ):
     from tpu_render_cluster.render.camera import scene_camera
     from tpu_render_cluster.render.scene import build_scene
@@ -748,7 +769,7 @@ def _fused_frame_renderer(
         scene = build_scene(scene_name, frame)
         camera = scene_camera(scene_name, frame)
         mesh = scene_mesh_set(scene_name, frame, builder, wide)
-        linear = render_tile(
+        rendered = render_tile(
             scene,
             camera,
             jnp.asarray(frame, jnp.float32),
@@ -763,8 +784,12 @@ def _fused_frame_renderer(
             mesh=mesh,
             use_tlas=use_tlas,
             quant=quant,
+            with_live=with_live,
         )
-        return tonemap(linear)
+        if with_live:
+            linear, live = rendered
+            return tonemap(linear), live
+        return tonemap(rendered)
 
     # Roofline profiling (obs/profiling.py): the first call captures the
     # program's XLA cost analysis (FLOPs/bytes) under the masked tier's
@@ -799,6 +824,7 @@ def fused_frame_renderer(
     quant: int | None = None,
     builder: str | None = None,
     wide: int | None = None,
+    with_live: bool = False,
 ):
     """A jitted ``frame -> uint8 [H, W, 3]`` closure for one scene/config.
 
@@ -814,10 +840,16 @@ def fused_frame_renderer(
     --bvh-compare`` holds one renderer per node-format variant in the
     same process, and an env toggle between calls gets a fresh renderer
     with a matching tree instead of a stale cache hit.
+
+    ``with_live`` makes the closure return ``(image, live)`` from the
+    same program: ``live`` is render_tile's per-bounce live-ray counts
+    (int32 [max_bounces]) for a deep mesh scene and None for every other
+    scene, whose program is then the one without it.
     """
     return _fused_frame_renderer(
         scene_name, width, height, samples, max_bounces,
         *resolve_bvh_config(use_tlas, quant, builder, wide),
+        with_live,
     )
 
 

@@ -113,15 +113,20 @@ SPHERE_BOUNCE_BLOCK_R = 1024
 def wavefront_mode() -> str:
     """The ``TRC_WAVEFRONT`` env tier: ``off`` / ``auto`` / ``force``.
 
-    - unset (``auto``): wavefront execution is used where it measured
-      faster — deep-walk mesh scenes already on the per-bounce dispatch
-      (``wavefront_eligible``);
+    - unset (``auto``): never — every scene renders in the one-program
+      tier. On the chip the deep mesh scene's settled frames read 1.368
+      frames/s there against 0.856 under this driver, which costs five
+      host syncs and an eager scene build per frame (builder's runs of
+      ``03ph2mesh-1w-queued``, PERF.md §6, PR 27; the ledger's PR 25
+      line of ``03ph2mesh-1w-fine`` has this driver at 0.8415);
     - ``TRC_WAVEFRONT=0`` (also ``false``/``off``): never;
     - ``TRC_WAVEFRONT=1`` (anything else truthy): force it for every
       Pallas-rendered scene, spheres included.
 
     Like ``TRC_PALLAS`` this is read when the dispatch decision is made
     (the wavefront driver runs outside jit, so per-frame, not per-trace).
+    The decision itself is ``render/compaction.wavefront_active``, the
+    single dispatch site for the env tier and a backend's override.
     """
     value = (env_str("TRC_WAVEFRONT") or "").strip().lower()
     if value in ("", "auto"):
@@ -131,19 +136,11 @@ def wavefront_mode() -> str:
     return "force"
 
 
-def wavefront_eligible(mesh) -> bool:
-    """Auto-tier heuristic: scenes already on the per-bounce deep-walk
-    dispatch — exactly where masked dead lanes still pay for BVH packet
-    walks, which is the waste compaction removes. Shallow/megakernel
-    scenes keep path state VMEM-resident across bounces; breaking the
-    loop per bounce there costs more than compaction recovers."""
-    return mesh is not None and not mesh_megakernel_eligible(mesh)
-
-
-# (The combined should-this-scene-go-wavefront decision lives in
-# render/compaction.wavefront_active — the single dispatch site, so the
-# env tier, a backend override, and this heuristic can't be recombined
-# differently by two callers.)
+def tier_forced(mode: str) -> bool:
+    """Whether an execution tier's mode — ``wavefront_mode()`` /
+    ``raypool_mode()`` or a backend's flag — turns its driver on. Only
+    ``force`` (any spelling that is neither ``auto`` nor ``off``) does."""
+    return str(mode).lower() not in ("auto", "", "0", "false", "off", "no")
 
 
 def tlas_enabled() -> bool:

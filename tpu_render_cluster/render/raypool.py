@@ -82,10 +82,11 @@ RAYPOOL_MAX_FRAMES = 32
 def raypool_mode() -> str:
     """The ``TRC_RAYPOOL`` env tier: ``off`` / ``auto`` / ``force``.
 
-    - unset (``auto``): the pool driver is used where it pays — multi-
-      frame batches of deep-walk mesh scenes (the wavefront-eligible
-      set, which is exactly where masked dead lanes still fund BVH
-      packet walks);
+    - unset (``auto``): never, whatever the queue holds. With four deep
+      mesh frames queued the pool read 0.7674 frames/s on the chip
+      (ledger PR 25, ``03ph2mesh-1w-queued``) where the one-program
+      tier reads 1.368 on the same frames (builder's runs, PERF.md §6,
+      PR 27);
     - ``TRC_RAYPOOL=0`` (also ``false``/``off``/``no``): never;
     - anything else truthy: force it for every Pallas-rendered scene,
       single frames and spheres included.
@@ -125,31 +126,19 @@ def raypool_active(
     *,
     backend_flag: str | None = None,
     frames_ahead: int = 0,
-    frame=1,
 ) -> bool:
     """Whether the ray-pool driver should render this workload.
 
     ``backend_flag`` (the worker's ``--raypool`` / constructor knob)
-    overrides the ``TRC_RAYPOOL`` env tier; ``auto`` selects multi-frame
-    deep-walk mesh jobs (``frames_ahead`` >= 1 more frames queued beyond
-    the current one, scene in the wavefront-eligible set) — single-frame
-    work keeps the per-frame dispatch, where the pool cannot refill
-    across frames and degenerates into the wavefront driver minus its
-    shrinking launches.
+    overrides the ``TRC_RAYPOOL`` env tier. Only ``force`` turns the pool
+    on: under ``auto`` it is off for every scene and every
+    ``frames_ahead`` — frames queued ahead no longer engage it
+    (``raypool_mode`` says why) — so nothing of the scene is built to
+    answer.
     """
     if not pk.pallas_enabled():
         return False
-    mode = backend_flag if backend_flag is not None else raypool_mode()
-    mode = str(mode).lower()
-    if mode in ("0", "false", "off", "no"):
-        return False
-    if mode not in ("auto", ""):
-        return True
-    if frames_ahead < 1:
-        return False
-    from tpu_render_cluster.render.mesh import scene_mesh_set
-
-    return pk.wavefront_eligible(scene_mesh_set(scene_name, frame))
+    return pk.tier_forced(backend_flag if backend_flag is not None else raypool_mode())
 
 
 # -- obs ---------------------------------------------------------------------
@@ -200,9 +189,10 @@ def pool_launched_lanes_counter(registry=None):
     registry = registry if registry is not None else get_registry()
     return registry.counter(
         "render_pool_launched_lanes_total",
-        "Pool lanes launched (live prefix rounded up to whole blocks, "
-        "summed over iterations) — the denominator of the lane-weighted "
-        "raypool wasted_lane_fraction",
+        "Lanes launched: the pool's live prefix rounded up to whole "
+        "blocks, summed over iterations (the denominator of the "
+        "lane-weighted raypool wasted_lane_fraction); in the one-program "
+        "tier, a deep mesh frame's whole ray set per bounce",
     )
 
 
@@ -212,8 +202,9 @@ def pool_live_lanes_counter(registry=None):
     registry = registry if registry is not None else get_registry()
     return registry.counter(
         "render_pool_live_lanes_total",
-        "Live lanes at launch, summed over iterations — the numerator "
-        "of the lane-weighted raypool occupancy",
+        "Live lanes at launch, summed over iterations (one-program "
+        "tier: over bounces) — the numerator of the lane-weighted "
+        "occupancy",
     )
 
 

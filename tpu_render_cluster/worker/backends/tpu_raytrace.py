@@ -57,17 +57,18 @@ class TpuRaytraceBackend(RenderBackend):
         self.sharding = sharding
         # Wavefront (compact + bucketed relaunch) execution: None defers
         # to the TRC_WAVEFRONT env tier; "off"/"auto"/"force" override it
-        # per backend (render/compaction.py). Only applies to the
-        # single-device path — tile/spp sharding gets the IN-JIT
+        # per backend (render/compaction.py). Only "force" turns it on;
+        # auto is the one-program tier for every scene. Only applies to
+        # the single-device path — tile/spp sharding gets the IN-JIT
         # compaction (live-count tail skip) instead, which composes with
         # shard_map.
         self.wavefront = wavefront
         # Device-resident ray pool (render/raypool.py): None defers to the
         # TRC_RAYPOOL env tier; "off"/"auto"/"force" override per backend.
-        # Auto fires for multi-frame deep-walk jobs — the queue's
-        # note_upcoming_frames hint supplies the work-ahead — and the
-        # backend then renders several of ITS OWN queued frames in one
-        # pool batch, serving later requests from the cache below.
+        # Only "force" turns it on (frames queued ahead do not). The
+        # queue's note_upcoming_frames hint supplies the work-ahead, and
+        # the backend then renders several of ITS OWN queued frames in
+        # one pool batch, serving later requests from the cache below.
         # Worker-internal only: one frame per request on the wire.
         self.raypool = raypool
         # Work units (jobs.tiles.WorkUnit) of each job still queued here.
@@ -76,6 +77,12 @@ class TpuRaytraceBackend(RenderBackend):
         # a pool batch. Bounded BY BYTES: stale entries (stolen/removed
         # units we rendered ahead of) are evicted oldest-first.
         self._raypool_cache: dict[tuple[str, int, int | None], object] = {}
+        # The three whole-frame tiers are exposed from the start, at 0: a
+        # scrape that finds no series could not tell "no frame went that
+        # way" from "not counted".
+        self._tier_frames = self._tier_frames_counter()
+        for tier in ("masked", "wavefront", "raypool"):
+            self._tier_frames.inc(0.0, tier=tier)
 
     # Staleness backstop, not a working-set budget: live entries drain
     # within one pool window of requests, so anything pushing the cache
@@ -188,15 +195,16 @@ class TpuRaytraceBackend(RenderBackend):
         else:
             from tpu_render_cluster.render.integrator import fused_frame_renderer
 
-            np.asarray(
-                fused_frame_renderer(
-                    scene_name,
-                    self.width,
-                    self.height,
-                    self.samples,
-                    self.max_bounces,
-                )(1)
-            )
+            # The program _render_timed runs: with the live counts.
+            display, _ = fused_frame_renderer(
+                scene_name,
+                self.width,
+                self.height,
+                self.samples,
+                self.max_bounces,
+                with_live=True,
+            )(1)
+            np.asarray(display)
 
     async def render_frame(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
@@ -218,6 +226,37 @@ class TpuRaytraceBackend(RenderBackend):
         while self._raypool_cache and excess > 0:
             victim = self._raypool_cache.pop(next(iter(self._raypool_cache)))
             excess -= getattr(victim, "nbytes", 0)
+
+    @staticmethod
+    def _tier_frames_counter():
+        from tpu_render_cluster.obs import get_registry
+
+        return get_registry().counter(
+            "render_tier_frames_total",
+            "Frames rendered, by the execution tier that rendered them",
+            labels=("tier",),
+        )
+
+    @staticmethod
+    def _observe_launches(live, *, rays: int) -> None:
+        """Launch occupancy of a one-program frame of a deep mesh scene:
+        every bounce is one kernel launch over the frame's whole ray set
+        (``rays`` lanes, dead ones sorted to the tail and skipped by
+        blocks), ``live[b]`` of them live. Fed into the series the
+        wavefront driver (per relaunch, live / bucket) and the raypool
+        (per iteration, live / launched lanes) feed for their launches,
+        so the tier that renders is the one the occupancy describes."""
+        from tpu_render_cluster.render.compaction import launch_occupancy_histogram
+        from tpu_render_cluster.render.raypool import (
+            pool_launched_lanes_counter,
+            pool_live_lanes_counter,
+        )
+
+        occupancy = launch_occupancy_histogram()
+        for count in live:
+            occupancy.observe(int(count) / rays)
+        pool_launched_lanes_counter().inc(float(rays * len(live)))
+        pool_live_lanes_counter().inc(float(live.sum()))
 
     @staticmethod
     def _observe_render_obs(
@@ -328,23 +367,33 @@ class TpuRaytraceBackend(RenderBackend):
                 and self._use_wavefront(scene_name)
             )
             use_sharded = self.sharding in ("tile", "spp") and region is None
-            if (
-                not use_sharded
-                and cached_linear is None
-                and not use_wavefront
-                and not use_raypool
-                and region is None
-            ):
+            # The tier that renders this frame (render_tier_frames_total's
+            # label; a frame served from the rendered-ahead cache was
+            # rendered by the pool).
+            if cached_linear is not None or use_raypool:
+                tier = "raypool"
+            elif use_sharded:
+                tier = "sharded"
+            elif use_wavefront:
+                tier = "wavefront"
+            elif region is not None:
+                tier = "region"
+            else:
+                tier = "masked"
                 renderer = fused_frame_renderer(
                     scene_name,
                     self.width,
                     self.height,
                     self.samples,
                     self.max_bounces,
+                    with_live=True,
                 )
         finished_loading_at = time.time()
 
         started_rendering_at = time.time()
+        # The one-program tier's per-bounce live-ray counts (deep mesh
+        # scenes only), an output of the frame's own program.
+        live = None
         # Issuing the device's work; the wavefront and raypool drivers open
         # their own device_wait / readback steps inside, which suspend it.
         with step("dispatch"):
@@ -444,7 +493,9 @@ class TpuRaytraceBackend(RenderBackend):
                 )
                 display = tonemap(linear)
             else:
-                display = renderer(frame_index)
+                display, live = renderer(frame_index)
+                if live is not None:
+                    live.copy_to_host_async()
             # Ask for the pixels now, behind the frame's work in the
             # device's queue, as np.asarray on an unfinished array does:
             # a copy first asked for after the wait below would cost the
@@ -457,6 +508,8 @@ class TpuRaytraceBackend(RenderBackend):
             display.block_until_ready()
         with step("readback"):
             pixels = np.asarray(display)
+            if live is not None:
+                live = np.asarray(live)
         finished_rendering_at = time.time()
 
         file_saving_started_at = time.time()
@@ -492,27 +545,21 @@ class TpuRaytraceBackend(RenderBackend):
         # Which roofline kernel this frame's fenced execute time pairs
         # with: only tiers whose frame is ONE program execution keyed by
         # a factory-side cost capture (the wavefront/raypool drivers pair
-        # their own launches internally; cache hits executed nothing).
+        # their own launches internally; cache hits executed nothing;
+        # sharded programs are per-device and not cost-captured).
         kernel = None
-        if cached_linear is None and not use_raypool and not use_wavefront:
+        if tier in ("region", "masked"):
             from tpu_render_cluster.obs.profiling import kernel_key
 
-            if use_sharded:
-                pass  # sharded programs are not cost-captured (per-device)
-            elif region is not None:
-                y0, x0, tile_height, tile_width = region
-                kernel = kernel_key(
-                    "region", scene_name,
-                    w=self.width, h=self.height,
-                    th=tile_height, tw=tile_width,
-                    s=self.samples, b=self.max_bounces,
-                )
-            else:
-                kernel = kernel_key(
-                    "masked", scene_name,
-                    w=self.width, h=self.height,
-                    s=self.samples, b=self.max_bounces,
-                )
+            dims = dict(w=self.width, h=self.height, s=self.samples, b=self.max_bounces)
+            if region is not None:
+                dims.update(th=region[2], tw=region[3])
+            kernel = kernel_key(tier, scene_name, **dims)
+        self._tier_frames.inc(tier=tier)
+        if live is not None:
+            self._observe_launches(
+                live, rays=self.width * self.height * self.samples
+            )
         self._observe_render_obs(
             execute_seconds=finished_rendering_at - started_rendering_at,
             from_cache=cached_linear is not None,

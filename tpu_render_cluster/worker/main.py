@@ -84,8 +84,9 @@ def build_parser() -> argparse.ArgumentParser:
         default=None,
         help="tpu-raytrace only: wavefront execution (per-bounce active-ray "
         "compaction + bucketed relaunch; render/compaction.py). Default "
-        "defers to the TRC_WAVEFRONT env tier; auto enables it for "
-        "deep-walk mesh scenes where it measured faster.",
+        "defers to the TRC_WAVEFRONT env tier; only force turns it on: "
+        "auto renders every scene in the one-program tier (PERF.md §6, "
+        "PR 27; ledger PR 25, 03ph2mesh-1w-fine).",
     )
     parser.add_argument(
         "--raypool",
@@ -94,10 +95,12 @@ def build_parser() -> argparse.ArgumentParser:
         help="tpu-raytrace only: device-resident ray-pool execution "
         "(cross-frame wavefront batching with in-jit compaction; "
         "render/raypool.py). Default defers to the TRC_RAYPOOL env tier; "
-        "auto enables it for multi-frame deep-walk mesh jobs, where the "
-        "worker batches its queued frames into one pool internally (wire "
-        "format unchanged). Takes precedence over --wavefront when both "
-        "would fire.",
+        "only force turns it on (the worker then batches its queued "
+        "frames into one pool internally, wire format unchanged): under "
+        "auto frames queued ahead do not engage it (ledger PR 25: "
+        "03ph2mesh-1w-queued 0.7674 frames/s under the pool, "
+        "03ph2mesh-1w-fine 0.8415 without). Takes precedence over "
+        "--wavefront when both are forced.",
     )
     parser.add_argument(
         "--telemetryPort",
