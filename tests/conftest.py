@@ -16,7 +16,10 @@ if "xla_force_host_platform_device_count" not in xla_flags:
         xla_flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+import signal
+import subprocess
 import sys
+import threading
 from pathlib import Path
 
 import pytest
@@ -25,6 +28,65 @@ import pytest
 REPO_ROOT = Path(__file__).resolve().parent.parent
 if str(REPO_ROOT) not in sys.path:
     sys.path.insert(0, str(REPO_ROOT))
+
+# Every test's own time limit: a hang (or a coroutine that spins without
+# ever yielding) costs that one test, not the suite's whole clock.
+# ``@pytest.mark.time_limit(seconds)`` gives a test another.
+TEST_TIME_LIMIT_SECONDS = 300.0
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    """Fail the test from a SIGALRM handler when its limit runs out.
+
+    Tests run in the main thread of their process (xdist workers too), so
+    the handler's exception lands in whatever line that thread is running
+    — the failure's traceback names it — even inside an event loop that
+    never gets a turn. Children a test started are its own cleanup's to
+    kill (``subprocess.run`` does on any exception). The alarm is cleared
+    and the previous handler restored after every test, pass or fail.
+    """
+    if (
+        not hasattr(signal, "SIGALRM")
+        or threading.current_thread() is not threading.main_thread()
+    ):
+        return (yield)
+    marker = item.get_closest_marker("time_limit")
+    limit = float(marker.args[0]) if marker else TEST_TIME_LIMIT_SECONDS
+
+    def on_alarm(signum, frame):
+        pytest.fail(f"{item.nodeid} ran past its time limit of {limit:g} s")
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.fixture
+def kill_leftover_children(monkeypatch):
+    """Kill what the test started and did not reap, however it ended.
+
+    For tests that hold daemons in bare ``subprocess.Popen`` objects: a
+    failed assertion or the time limit above would otherwise leave them
+    running (and holding their ports) under the rest of the run.
+    """
+    started = []
+
+    class TrackedPopen(subprocess.Popen):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            started.append(self)
+
+    monkeypatch.setattr(subprocess, "Popen", TrackedPopen)
+    yield
+    for process in started:
+        if process.poll() is None:
+            process.kill()
+            process.wait()
 
 
 @pytest.fixture(autouse=True)

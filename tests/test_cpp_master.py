@@ -26,9 +26,12 @@ import pytest
 from tpu_render_cluster.analysis.models import JobTrace
 from tpu_render_cluster.native import build_master_daemon, build_worker_daemon
 
-pytestmark = pytest.mark.skipif(
-    shutil.which("g++") is None, reason="g++ unavailable"
-)
+pytestmark = [
+    pytest.mark.skipif(shutil.which("g++") is None, reason="g++ unavailable"),
+    # The daemons below are bare Popen objects: whatever ends a test early
+    # (an assertion, the time limit) must not leave them running.
+    pytest.mark.usefixtures("kill_leftover_children"),
+]
 
 
 def test_master_daemon_builds():

@@ -119,6 +119,7 @@ min_seconds_before_resteal_to_original_worker = 2
 
 
 @requires_gxx
+@pytest.mark.usefixtures("kill_leftover_children")
 @pytest.mark.parametrize("sanitize", ["thread", "address"])
 def test_sanitized_cluster_run(tmp_path, sanitize):
     if not _sanitizer_works(sanitize):

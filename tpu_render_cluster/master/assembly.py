@@ -34,26 +34,10 @@ from pathlib import Path
 
 from tpu_render_cluster.jobs.models import BlenderJob
 from tpu_render_cluster.master.state import ClusterManagerState
+from tpu_render_cluster.utils.background import BackgroundTasks
 from tpu_render_cluster.utils.paths import parse_with_base_directory_prefix
 
 logger = logging.getLogger(__name__)
-
-
-def tile_file_path(
-    output_directory: Path,
-    name_format: str,
-    file_format: str,
-    frame_index: int,
-    tile: int,
-    grid: tuple[int, int],
-) -> Path:
-    """Alias of ``render.image_io.output_path_for_tile`` (the single
-    naming definition workers write through)."""
-    from tpu_render_cluster.render.image_io import output_path_for_tile
-
-    return output_path_for_tile(
-        output_directory, name_format, file_format, frame_index, tile, grid
-    )
 
 
 def assemble_frame_files(
@@ -74,6 +58,7 @@ def assemble_frame_files(
 
     from tpu_render_cluster.render.image_io import (
         output_path_for_frame,
+        output_path_for_tile,
         write_image,
     )
 
@@ -89,7 +74,7 @@ def assemble_frame_files(
         # clusters land here; the "no-tiles" outcome keeps it visible.
         return None
     tile_paths = [
-        tile_file_path(
+        output_path_for_tile(
             output_directory,
             job.output_file_name_format,
             job.output_file_format,
@@ -134,7 +119,9 @@ class FrameAssemblyService:
 
     ``schedule`` is the sync hook WorkerHandle fires from the finished-
     event path (exactly once per frame); ``drain`` is the completion
-    barrier the job/scheduler awaits before declaring a tiled job done.
+    barrier the job/scheduler awaits before declaring a tiled job done:
+    no stitch is pending when it returns (``has_pending`` is false), and
+    it always gives the loop a turn (``utils/background.py``).
     """
 
     def __init__(
@@ -147,40 +134,34 @@ class FrameAssemblyService:
         self.metrics = metrics
         self.span_tracer = span_tracer
         self.base_directory = base_directory
-        # task -> owning job_name, so per-job completion (the scheduler's
-        # finalize gate) can be answered without touching other jobs'
-        # in-flight stitches.
-        self._tasks: dict[asyncio.Task, str] = {}
+        # Stitches in flight, keyed by owning job_name, so per-job
+        # completion (the scheduler's finalize gate) can be answered
+        # without touching other jobs' stitches.
+        self._tasks = BackgroundTasks()
 
     def schedule(self, state: ClusterManagerState, frame_index: int) -> None:
         """All tiles of ``frame_index`` landed: stitch it in the background."""
-        task = asyncio.create_task(
+        self._tasks.spawn(
             self._assemble(state, frame_index),
             name=f"assemble-{state.job.job_name}-{frame_index}",
+            key=state.job.job_name,
         )
-        self._tasks[task] = state.job.job_name
-        task.add_done_callback(lambda t: self._tasks.pop(t, None))
 
     def has_pending(self, job_name: str) -> bool:
         """Stitches of ``job_name`` still in flight — a job must not be
         declared FINISHED (nor its name released for reuse) before they
         land."""
-        return any(name == job_name for name in self._tasks.values())
+        return bool(self._tasks.pending(job_name))
 
     async def drain(self) -> None:
         """Await every scheduled assembly (the tiled-job completion barrier)."""
-        while self._tasks:
-            await asyncio.gather(*list(self._tasks), return_exceptions=True)
+        await self._tasks.drain()
 
     async def drain_job(self, job_name: str) -> None:
         """Await one job's in-flight stitches (the cancel path: the job's
         name must not be released for reuse while its stitcher can still
         read/write/unlink files under the shared output path)."""
-        while True:
-            tasks = [t for t, name in self._tasks.items() if name == job_name]
-            if not tasks:
-                return
-            await asyncio.gather(*tasks, return_exceptions=True)
+        await self._tasks.drain(job_name)
 
     async def _assemble(
         self, state: ClusterManagerState, frame_index: int

@@ -53,6 +53,7 @@ from collections import deque
 from pathlib import Path
 from typing import TYPE_CHECKING, Any
 
+from tpu_render_cluster.utils.background import BackgroundTasks
 from tpu_render_cluster.utils.env import env_float, env_str
 
 if TYPE_CHECKING:
@@ -136,7 +137,7 @@ class FlightRecorder:
         self.triggers: dict[str, int] = {}
         self.dumps: deque[dict[str, Any]] = deque(maxlen=256)
         # Deferred bundle writes in flight (loop contexts only).
-        self._pending: set = set()
+        self._pending = BackgroundTasks()
         self._last_write_ok = True
 
     # -- recording -----------------------------------------------------------
@@ -282,12 +283,10 @@ class FlightRecorder:
         except RuntimeError:
             loop = None
         if loop is not None:
-            task = loop.create_task(
-                self._write_deferred(path, bundle),
+            self._pending.spawn(
+                asyncio.to_thread(self._write_checked, path, bundle),
                 name=f"flightrec-dump-{path.name}",
             )
-            self._pending.add(task)
-            task.add_done_callback(self._pending.discard)
             return True
         worker = threading.Thread(
             target=self._write_checked, args=(path, bundle), daemon=True
@@ -295,9 +294,6 @@ class FlightRecorder:
         worker.start()
         worker.join()
         return self._last_write_ok
-
-    async def _write_deferred(self, path: Path, bundle: dict[str, Any]) -> None:
-        await asyncio.to_thread(self._write_checked, path, bundle)
 
     def _write_checked(self, path: Path, bundle: dict[str, Any]) -> None:
         try:
@@ -308,9 +304,9 @@ class FlightRecorder:
             logger.error("Flight-recorder dump to %s failed: %s", path, e)
 
     async def drain(self) -> None:
-        """Await every deferred bundle write (call before loop teardown)."""
-        while self._pending:
-            await asyncio.gather(*list(self._pending), return_exceptions=True)
+        """Await every deferred bundle write (call before loop teardown):
+        none is pending on return, and the wait always yields to the loop."""
+        await self._pending.drain()
 
     @staticmethod
     def _write_atomic(path: Path, bundle: dict[str, Any]) -> None:

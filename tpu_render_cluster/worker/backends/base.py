@@ -19,7 +19,7 @@ class RenderBackend(abc.ABC):
     Tiled jobs: when the job carries a tile grid, ``render_frame`` is
     called once per ``(frame, tile)`` work unit with ``tile`` set — the
     backend renders only that tile's pixel region and writes the tile
-    file (master/assembly.tile_file_path naming); the master stitches
+    file (render/image_io.output_path_for_tile naming); the master stitches
     the frame. Backends that cannot render sub-frame regions (the
     Blender subprocess backend) must raise a clear error instead of
     silently rendering the whole frame under a tile's name.
