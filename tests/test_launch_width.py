@@ -1,0 +1,195 @@
+"""The deep per-bounce path picks each bounce's width from its live count.
+
+The ladder and the rung (pure functions), and the program: whatever widths
+it runs its bounces at, the linear image is the full-width program's. The
+full-width reference is the same code with the ladder function patched to
+the one rung ``n``; every program here is traced afresh inside its own
+``jax.jit`` (through ``render_tile.__wrapped__`` and an uncached region
+renderer), so the patch is what the trace reads. Pallas interpreter on the
+CPU, tiny frames.
+
+On the chip the two programs' images are equal bit for bit (PERF.md §6,
+PR 29: 0 of 262,144 linear pixels differ on four frames): a width changes
+the kernel's grid, not its code. Under the interpreter the kernel is XLA:CPU
+code, whose multiply-adds are contracted or not by what surrounds them, so
+two differently shaped programs may give one ray in thousands another last
+bit in the SAME full-width bounce (seen on frame 30 at 32x32x2: one pixel,
+2e-6). ``assert_the_same_image`` allows two such pixels and nothing more.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+DEEP_SCENE = "03_physics-2-mesh"
+BOUNCES = 4
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    monkeypatch.setenv("TRC_PALLAS", "1")
+
+
+def one_rung(n: int) -> tuple[int, ...]:
+    return (n,)
+
+
+def assert_the_same_image(image, reference):
+    assert image.shape == reference.shape and image.dtype == reference.dtype == np.float32
+    differing = (image != reference).any(axis=-1)
+    assert differing.sum() <= 2, f"{differing.sum()} pixels differ"
+    np.testing.assert_allclose(image, reference, rtol=1e-4, atol=1e-5)
+
+
+# -- the ladder ------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n", [1, 700, 1024, 2048, 8192, 30000, 512 * 512 * 8])
+def test_the_ladder_is_descending_multiples_of_the_block_under_n(n):
+    from tpu_render_cluster.render.integrator import launch_width_ladder
+    from tpu_render_cluster.render.pallas_kernels import BVH_BLOCK_R, tlas_block_r
+
+    widths = launch_width_ladder(n)
+    assert widths[0] == n and len(widths) <= 4
+    assert list(widths) == sorted(set(widths), reverse=True)
+    assert BVH_BLOCK_R % tlas_block_r() == 0  # a rung is whole blocks of either kernel variant
+    for width in widths[1:]:
+        assert width % BVH_BLOCK_R == 0 and n // 16 <= width <= -(-n // 4 // BVH_BLOCK_R) * BVH_BLOCK_R
+    if n <= BVH_BLOCK_R:
+        assert widths == (n,)
+    if n == 512 * 512 * 8:
+        assert widths == (n, n // 4, n // 8, n // 16)
+
+
+@pytest.mark.parametrize("n", [2048, 8192, 30000])
+def test_the_rung_holds_the_live_rays_and_never_widens(n):
+    from tpu_render_cluster.render.integrator import launch_rung, launch_width_ladder
+
+    widths = launch_width_ladder(n)
+    counts = sorted({0, 1, n, *widths, *(w + 1 for w in widths[1:]), *(w - 1 for w in widths)}, reverse=True)
+    picked = [widths[int(launch_rung(np.int32(live), widths))] for live in counts]
+    for live, width in zip(counts, picked):
+        assert width >= live
+        narrower = [w for w in widths if w < width]
+        assert all(w < live for w in narrower)  # the narrowest that holds them
+    assert picked == sorted(picked, reverse=True)  # rays only die: widths only shrink
+    assert picked[0] == n and picked[-1] == widths[-1]  # live = 0: the narrowest rung
+
+
+# -- the program -----------------------------------------------------------------
+
+
+def frame_program(scene_name, frame_index, *, size, samples, bounces):
+    """(linear image, launches) of a whole frame, traced now."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+    from tpu_render_cluster.render.camera import scene_camera
+    from tpu_render_cluster.render.mesh import scene_mesh_set
+    from tpu_render_cluster.render.scene import build_scene
+
+    use_tlas, quant, builder, wide = integrator.resolve_bvh_config()
+
+    @jax.jit
+    def render(frame):
+        return integrator.render_tile.__wrapped__(
+            build_scene(scene_name, frame), scene_camera(scene_name, frame),
+            frame, 0, 0, width=size, height=size, tile_height=size,
+            tile_width=size, samples=samples, max_bounces=bounces,
+            mesh=scene_mesh_set(scene_name, frame, builder, wide),
+            use_tlas=use_tlas, quant=quant, with_live=True,
+        )
+
+    image, launches = render(jnp.asarray(frame_index, jnp.float32))
+    return np.asarray(image), np.asarray(launches)
+
+
+def region_program(scene_name, frame_index, *, size, samples, bounces):
+    """Linear image of the lower right quarter, rendered as a region (its
+    rays carry their full-frame RNG lanes), traced now."""
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+
+    half = size // 2
+    render = integrator._fused_region_renderer.__wrapped__(
+        scene_name, size, size, half, half, samples, bounces,
+        *integrator.resolve_bvh_config(),
+    )
+    return np.asarray(render(jnp.asarray(frame_index, jnp.float32), half, half)), None
+
+
+def two_steps_down(n: int) -> tuple[int, ...]:
+    """The rungs a 512x512x8 settled frame takes (n, n/8, n/16), at a size
+    the interpreter can afford."""
+    return (n, n // 8, n // 16)
+
+
+CASES = {
+    # name: (program, frame, size, bounces, ladder or None for the real one, widths expected or None)
+    "settled": (frame_program, 295, 32, BOUNCES, None, [2048, 2048, 1024, 1024]),
+    "falling": (frame_program, 30, 32, BOUNCES, None, [2048, 2048, 1024, 1024]),
+    "region_with_rng_lanes": (region_program, 295, 64, BOUNCES, None, None),
+    "no_ray_dies_early": (frame_program, 295, 32, 2, None, [2048, 2048]),
+    "narrow_then_narrower": (frame_program, 295, 64, BOUNCES, two_steps_down, [8192, 8192, 1024, 512]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_narrowed_program_renders_the_full_width_image(case, monkeypatch, interpreted_kernels):
+    from tpu_render_cluster.render import integrator
+
+    program, frame, size, bounces, ladder, expected = CASES[case]
+    if ladder is not None:
+        monkeypatch.setattr(integrator, "launch_width_ladder", ladder)
+    image, launches = program(DEEP_SCENE, frame, size=size, samples=2, bounces=bounces)
+    monkeypatch.setattr(integrator, "launch_width_ladder", one_rung)
+    reference, full = program(DEEP_SCENE, frame, size=size, samples=2, bounces=bounces)
+    assert_the_same_image(image, reference)
+    assert image.max() > 0.1 and image.std() > 0.01  # a picture, not a constant
+    if launches is not None:
+        rays = size * size * 2
+        assert launches.shape == (bounces, 2) and (full[:, 1] == rays).all()
+        assert np.array_equal(launches[:, 0], full[:, 0])  # the same rays live and die
+        assert launches[:, 1].tolist() == expected
+        assert (launches[:, 0] <= launches[:, 1]).all()
+
+
+def test_with_no_ray_left_the_narrowest_rung_returns_what_was_gathered(monkeypatch, interpreted_kernels):
+    """Every ray leaves for the sky at the first bounce: live is 0 from the
+    second on, the program takes the narrowest rung there, and the radiance
+    of the first bounce comes back in place."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+    from tpu_render_cluster.render.mesh import scene_mesh_set
+    from tpu_render_cluster.render.scene import build_scene
+
+    n = 4096
+    _tlas, quant, builder, wide = integrator.resolve_bvh_config()
+    scene = build_scene(DEEP_SCENE, 295)
+    mesh = scene_mesh_set(DEEP_SCENE, 295, builder, wide)
+    spread = jax.random.uniform(jax.random.PRNGKey(5), (n, 3), minval=-0.3, maxval=0.3)
+    directions = spread.at[:, 1].set(1.0)
+    directions = directions / jnp.linalg.norm(directions, axis=1, keepdims=True)
+    origins = jnp.tile(jnp.asarray([[0.0, 30.0, 0.0]], jnp.float32), (n, 1))
+
+    def trace():
+        launches = []
+        radiance = integrator.trace_paths(
+            scene, origins, directions, jax.random.PRNGKey(3), max_bounces=3,
+            mesh=mesh, quant=quant, live_counts=launches,
+        )
+        return radiance, jnp.stack(launches)
+
+    narrowest = integrator.launch_width_ladder(n)[-1]
+    radiance, launches = jax.jit(trace)()
+    monkeypatch.setattr(integrator, "launch_width_ladder", one_rung)
+    reference, full = jax.jit(lambda: trace())()
+    assert np.asarray(launches).tolist() == [[n, n], [0, narrowest], [0, narrowest]]
+    assert np.asarray(full).tolist() == [[n, n], [0, n], [0, n]]
+    assert_the_same_image(np.asarray(radiance), np.asarray(reference))
+    assert float(jnp.min(radiance)) > 0.0  # the sky's, on every lane

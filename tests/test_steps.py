@@ -363,11 +363,14 @@ def test_every_frame_counts_once_under_the_tier_that_rendered_it(
 def test_the_one_program_tier_reports_the_occupancy_of_its_bounce_launches(
     scene, launches, tmp_path, interpreted_kernels
 ):
-    """A deep mesh frame is one launch per bounce over the whole frame's
-    rays: their live counts come back with the image (still one sync) and
-    feed the series the other two tiers feed for their launches. A scene
-    whose program launches no per-bounce kernel feeds nothing."""
+    """A deep mesh frame is one launch per bounce, each at the width the
+    program picked from its live count: live counts and widths come back
+    with the image (still one sync) and feed the series the other two tiers
+    feed for their launches — launched lanes are the sum of the widths, so
+    live / launched lies above what the frame's full width would give. A
+    scene whose program launches no per-bounce kernel feeds nothing."""
     from tpu_render_cluster.render.compaction import launch_occupancy_histogram
+    from tpu_render_cluster.render.integrator import fused_frame_renderer
     from tpu_render_cluster.render.raypool import (
         pool_launched_lanes_counter,
         pool_live_lanes_counter,
@@ -391,25 +394,34 @@ def test_the_one_program_tier_reports_the_occupancy_of_its_bounce_launches(
     count, total, launched, live = (b - a for a, b in zip(before, read()))
     rays = 32 * 32 * 2
     assert [name for name, _, _ in timing.steps].count("device_wait") == 1
-    assert count == launches and launched == rays * launches
+    assert count == launches
     if launches:
-        assert rays <= live < launched  # the first bounce is all live, then rays die
-        assert total == pytest.approx(live / rays)
+        # The backend's own (cached) program, asked again for the same frame.
+        _image, reported = fused_frame_renderer(scene, 32, 32, 2, BOUNCES, with_live=True)(1)
+        reported = np.asarray(reported)
+        assert launched == reported[:, 1].sum() and live == reported[:, 0].sum()
+        assert total == pytest.approx((reported[:, 0] / reported[:, 1]).sum())
+        # all live at first, then rays die; a bounce ran narrow, so live / launched
+        # lies above what launches of the frame's full width would give
+        assert rays <= live < launched < rays * launches
     else:
-        assert live == 0 and total == 0
+        assert (launched, live, total) == (0, 0, 0)
 
 
 def test_the_live_counts_ride_the_same_image(interpreted_kernels):
-    from tpu_render_cluster.render.integrator import fused_frame_renderer
+    from tpu_render_cluster.render.integrator import fused_frame_renderer, launch_width_ladder
 
     plain = np.asarray(fused_frame_renderer("03_physics-2-mesh", 32, 32, 2, BOUNCES)(3))
-    image, live = fused_frame_renderer("03_physics-2-mesh", 32, 32, 2, BOUNCES, with_live=True)(3)
-    live = np.asarray(live)
+    image, launches = fused_frame_renderer("03_physics-2-mesh", 32, 32, 2, BOUNCES, with_live=True)(3)
+    live, widths = np.asarray(launches).T
     assert np.array_equal(np.asarray(image), plain)
-    assert live.shape == (BOUNCES,) and live[0] == 32 * 32 * 2
+    assert live.shape == (BOUNCES,) and live[0] == widths[0] == 32 * 32 * 2
     assert (np.diff(live) <= 0).all() and live[-1] > 0
-    image, live = fused_frame_renderer("04_very-simple", 32, 32, 2, BOUNCES, with_live=True)(3)
-    assert live is None and image.shape == (32, 32, 3)
+    # every launch at a rung that holds its live rays, never wider than the last
+    assert set(widths) <= set(launch_width_ladder(32 * 32 * 2))
+    assert (live <= widths).all() and (np.diff(widths) <= 0).all() and widths[-1] < widths[0]
+    image, launches = fused_frame_renderer("04_very-simple", 32, 32, 2, BOUNCES, with_live=True)(3)
+    assert launches is None and image.shape == (32, 32, 3)
 
 
 def test_the_tier_counter_is_exposed_at_zero_before_any_frame(monkeypatch):

@@ -392,6 +392,203 @@ def region_rays_and_seed(
     )
 
 
+# The deep per-bounce path's narrow launch widths: the ray set halved this
+# many times. Each rung is one more instance of the bounce kernel in the
+# frame's program (on a v5e host 1.5 s of tracing on every start and 2.7 s
+# of compiling on a cold one, PERF.md §6 PR 29), so the ladder names only
+# the steps a deep frame takes: a quarter for the bounce after which most
+# rays are gone, an eighth and a sixteenth for the ones that follow.
+_LADDER_HALVINGS = (2, 3, 4)
+
+
+def launch_width_ladder(n: int) -> tuple[int, ...]:
+    """The static widths a deep trace of ``n`` rays may run a bounce at,
+    widest first: n, n/4, n/8, n/16, each rounded up to the bounce
+    kernels' widest ray block (every block width divides it). A ray set
+    of one block or less has the one rung ``n``."""
+    from tpu_render_cluster.render.pallas_kernels import BVH_BLOCK_R
+
+    widths = [n]
+    for halving in _LADDER_HALVINGS:
+        width = -(-max(n >> halving, 1) // BVH_BLOCK_R) * BVH_BLOCK_R
+        if width < widths[-1]:
+            widths.append(width)
+    return tuple(widths)
+
+
+def launch_rung(live, widths):
+    """Index of the narrowest of ``widths`` (widest first) that holds
+    ``live`` rays; ``live`` may be traced."""
+    return jnp.sum(live <= jnp.asarray(widths[1:], jnp.int32), dtype=jnp.int32)
+
+
+def _trace_paths_deep(
+    scene, mesh, origins, directions, seed, *, max_bounces, rng_lanes,
+    use_tlas, quant, live_counts,
+):
+    """trace_paths for deep-walk mesh scenes: the megakernel's
+    bounce_step as ONE fused launch per bounce (sphere/plane/mesh
+    nearest, NEE with both any-hits, shading, in-kernel PCG resample —
+    pallas_kernels.mesh_bounce_pallas) with an XLA re-sort between
+    bounces: rays re-pack by (candidate instance, Morton cell, octant)
+    with dead lanes compacted to the tail, so the walks cull on tight
+    coherent packets.
+
+    The width of a bounce — of its gathers, layout changes and launch,
+    and of the sort that follows it — is the narrowest rung of
+    ``launch_width_ladder`` that holds the bounce's live rays, picked
+    inside the program by ``lax.switch`` on the live count (every rung a
+    static shape, compiled once with the frame's program; no host sync).
+    The sort key's dead flag puts every live ray before every dead one,
+    so the first ``width`` entries of the sort order hold them all.
+
+    A full-width bounce permutes everything in ONE packed [n, 12] gather
+    incl. the accumulated radiance (separate [n, 3] gathers measured ~3x
+    slower: random-access cost is per-row, so packing amortizes it) and
+    carries the unsort lane with it. A narrow bounce leaves radiance and
+    the unsort lane where the last full-width permutation put them — a
+    ray that died keeps its place, its radiance is final — and moves
+    only the travelling state ([width, 9]) with ``slot``, each row's
+    place in that order, through which its contribution is added and its
+    RNG lane read. Widths never grow again (rays only die), and a scene
+    whose rays do not die takes the widest rung on every bounce. Per-ray
+    arithmetic is the same at every width: same kernel, same RNG lane,
+    the same four additions in the same order.
+    """
+    from tpu_render_cluster.render import pallas_kernels
+
+    n = origins.shape[0]
+    if max_bounces < 1:
+        return jnp.zeros((n, 3), jnp.float32)
+    tlas = pallas_kernels.use_tlas_for(
+        mesh.instances.translation.shape[0], use_tlas
+    )
+    quant = pallas_kernels.bvh_quant_mode() if quant is None else int(quant)
+    widths = launch_width_ladder(n)
+
+    def sort_order(origins, directions, alive, keys):
+        if tlas:
+            return jnp.argsort(keys)
+        return _ray_sort_order(origins, directions, alive, mesh=mesh)
+
+    # One traced kernel per width, whatever the bounce (its index is a
+    # scalar operand): the bounces that may run at a width share it. Made
+    # here, so it lives and dies with this trace.
+    launch = jax.jit(
+        pallas_kernels.mesh_bounce_pallas,
+        static_argnames=("total_bounces", "use_tlas", "quant"),
+    )
+
+    def bounce_at(width, bounce, state):
+        full = width == n
+        with jax.named_scope("resort"):
+            order = state["order"][:width]
+            columns = [state[k] for k in ("origins", "directions", "throughput")]
+            if full:
+                columns.append(state["radiance"])
+            packed = jnp.concatenate(columns, axis=1)[order]
+            if full:
+                # The RNG counter rides separately from the unsort index
+                # when the caller supplies full-frame lane ids (region
+                # rendering); with positional lanes the two are one array.
+                lane = state["lane"][order]
+                rng = state["rng"][order] if "rng" in state else lane
+            else:
+                lane = state["lane"]
+                slot = state["slot"][order]
+                rng = state.get("rng", lane)[slot]
+            # Lanes >= live are exactly the dead tail: the kernel's
+            # live-count prefetch skips those blocks outright
+            # (behavior-preserving — dead lanes pass through a masked
+            # bounce unchanged anyway). The carried ORIGINAL lane id is
+            # the RNG counter, so a ray's stream survives every
+            # permutation (and composes with the wavefront driver's
+            # compaction, which shares this kernel).
+            alive = jnp.arange(width, dtype=jnp.int32) < state["live"]
+        with jax.named_scope("bounce"):
+            contribution, origins, directions, throughput, alive, keys = launch(
+                scene, mesh, packed[:, 0:3], packed[:, 3:6], packed[:, 6:9],
+                alive, seed, jnp.int32(bounce), total_bounces=max_bounces,
+                lane=rng, live_count=state["live"], use_tlas=tlas,
+                quant=quant,
+            )
+        with jax.named_scope("accumulate"):
+            if full:
+                radiance = packed[:, 9:12] + contribution
+            else:
+                radiance = state["radiance"].at[slot].add(
+                    contribution, unique_indices=True
+                )
+        if bounce + 1 == max_bounces:
+            # The last bounce hands back the image's rays in place: the
+            # unsort, inside the branch that ran the bounce.
+            with jax.named_scope("unsort"):
+                return jnp.zeros_like(radiance).at[lane].set(radiance)
+        new = dict(state, radiance=radiance, lane=lane)
+        with jax.named_scope("resort"):
+            # The next bounce's sort, over this bounce's width: its live
+            # rays are among these rows and nowhere else.
+            order = sort_order(origins, directions, alive, keys)
+            new["live"] = jnp.sum(alive, dtype=jnp.int32)
+            if full:
+                new.update(
+                    origins=origins, directions=directions,
+                    throughput=throughput, order=order,
+                )
+                if "rng" in state:
+                    new["rng"] = rng
+            else:
+                for name, rows in (
+                    ("origins", origins), ("directions", directions),
+                    ("throughput", throughput), ("slot", slot),
+                    ("order", order),
+                ):
+                    new[name] = state[name].at[:width].set(rows)
+        return new
+
+    lane = jnp.arange(n, dtype=jnp.int32)
+    alive = jnp.ones((n,), bool)
+    keys = None
+    with jax.named_scope("resort"):
+        if tlas:
+            # Bounce 0 has no kernel-emitted key column yet: derive the
+            # initial keys through the XLA twin of the kernels' fused
+            # epilogue, via the SAME shared site the wavefront driver
+            # uses (bit-identical derivation, pinned by
+            # tests/test_tlas.py). Later bounces read the key column the
+            # bounce kernel wrote while the state was still VMEM-resident.
+            keys = pallas_kernels.initial_mesh_sort_keys(
+                mesh, origins, directions, alive
+            )
+        order = sort_order(origins, directions, alive, keys)
+    state = dict(
+        origins=origins, directions=directions,
+        throughput=jnp.ones((n, 3), jnp.float32),
+        radiance=jnp.zeros((n, 3), jnp.float32),
+        lane=lane, slot=lane, order=order, live=jnp.int32(n),
+    )
+    if rng_lanes is not None:
+        state["rng"] = jnp.asarray(rng_lanes, jnp.int32)
+    for bounce in range(max_bounces):
+        # Every ray is live at the first bounce, and a one-rung ladder
+        # leaves nothing to pick: no switch in the program.
+        pick = bounce > 0 and len(widths) > 1
+        rung = launch_rung(state["live"], widths) if pick else jnp.int32(0)
+        if live_counts is not None:
+            live_counts.append(
+                jnp.stack([state["live"], jnp.asarray(widths, jnp.int32)[rung]])
+            )
+        if pick:
+            state = jax.lax.switch(
+                rung,
+                [functools.partial(bounce_at, w, bounce) for w in widths],
+                state,
+            )
+        else:
+            state = bounce_at(n, bounce, state)
+    return state  # the last bounce's: the radiance, unsorted
+
+
 def trace_paths(
     scene: Scene, origins, directions, key, *, max_bounces: int = 4, mesh=None,
     rng_lanes=None, use_tlas=None, quant=None, live_counts=None,
@@ -423,11 +620,12 @@ def trace_paths(
     column instead of re-deriving keys from the full ray state.
 
     ``live_counts`` (optional list) collects, on the deep per-bounce path
-    only, each bounce launch's live-ray count (a traced int32 scalar the
-    launch already computes for its tail skip), so the caller can return
-    them from the same program — the one-program tier's launch occupancy
-    at no extra sync. Other paths launch no per-bounce kernel and leave
-    the list empty.
+    only, each bounce launch's (live rays, width) — a traced int32 [2]:
+    the count the launch already computes for its tail skip and the rung
+    of ``launch_width_ladder`` the program ran the bounce at — so the
+    caller can return them from the same program: the one-program tier's
+    launch occupancy at no extra sync. Other paths launch no per-bounce
+    kernel and leave the list empty.
     """
     from tpu_render_cluster.render import pallas_kernels
 
@@ -458,80 +656,11 @@ def trace_paths(
                 scene, mesh, origins, directions, seed,
                 max_bounces=max_bounces, use_tlas=use_tlas, quant=quant,
             )
-        # Deep scenes: the megakernel's bounce_step as ONE fused launch
-        # per bounce (sphere/plane/mesh nearest, NEE with both any-hits,
-        # shading, in-kernel PCG resample — pallas_kernels
-        # mesh_bounce_pallas) with an XLA re-sort between bounces: rays
-        # re-pack by (candidate instance, Morton cell, octant) with dead
-        # lanes compacted to the tail, so the walks cull on tight
-        # coherent packets. Travelling state rides ONE packed [n, 12]
-        # gather incl. the accumulated radiance (separate [n, 3] gathers
-        # measured ~3x slower: random-access cost is per-row, so packing
-        # amortizes it); the carried lane index unsorts the radiance once
-        # at the end.
-        n = origins.shape[0]
-        throughput = jnp.ones((n, 3), jnp.float32)
-        radiance = jnp.zeros((n, 3), jnp.float32)
-        alive = jnp.ones((n,), bool)
-        lane = jnp.arange(n, dtype=jnp.int32)
-        # The RNG counter rides separately from the unsort index when the
-        # caller supplies full-frame lane ids (region rendering); with
-        # positional lanes the two arrays are identical and XLA CSEs the
-        # duplicate gathers away.
-        rng = lane if rng_lanes is None else jnp.asarray(rng_lanes, jnp.int32)
-        tlas = pallas_kernels.use_tlas_for(
-            mesh.instances.translation.shape[0], use_tlas
+        return _trace_paths_deep(
+            scene, mesh, origins, directions, seed, max_bounces=max_bounces,
+            rng_lanes=rng_lanes, use_tlas=use_tlas, quant=quant,
+            live_counts=live_counts,
         )
-        quant = (
-            pallas_kernels.bvh_quant_mode() if quant is None else int(quant)
-        )
-        keys = None
-        if tlas:
-            # Bounce 0 has no kernel-emitted key column yet: derive the
-            # initial keys through the XLA twin of the kernels' fused
-            # epilogue, via the SAME shared site the wavefront driver
-            # uses (bit-identical derivation, pinned by
-            # tests/test_tlas.py). Later bounces read the key column the
-            # bounce kernel wrote while the state was still VMEM-resident.
-            keys = pallas_kernels.initial_mesh_sort_keys(
-                mesh, origins, directions, alive
-            )
-        for bounce in range(max_bounces):
-            order = (
-                jnp.argsort(keys) if tlas
-                else _ray_sort_order(origins, directions, alive, mesh=mesh)
-            )
-            packed = jnp.concatenate(
-                [origins, directions, throughput, radiance], axis=1
-            )[order]
-            origins = packed[:, 0:3]
-            directions = packed[:, 3:6]
-            throughput = packed[:, 6:9]
-            radiance = packed[:, 9:12]
-            alive = alive[order]
-            lane = lane[order]
-            rng = rng[order]
-            # The sort key's dead flag (bit 31 flat, bit 29 fused) puts
-            # every dead lane after every live one, so lanes >= live are
-            # exactly the dead tail: the kernel's live-count prefetch
-            # skips those blocks outright (behavior-preserving — dead
-            # lanes pass through a masked bounce unchanged anyway). The
-            # carried ORIGINAL lane id doubles as the RNG counter, so a
-            # ray's stream survives the permutation (and composes with
-            # the wavefront driver's compaction, which shares this
-            # kernel).
-            live = jnp.sum(alive.astype(jnp.int32))
-            if live_counts is not None:
-                live_counts.append(live)
-            contribution, origins, directions, throughput, alive, keys = (
-                pallas_kernels.mesh_bounce_pallas(
-                    scene, mesh, origins, directions, throughput, alive,
-                    seed, bounce, total_bounces=max_bounces,
-                    lane=rng, live_count=live, use_tlas=tlas, quant=quant,
-                )
-            )
-            radiance = radiance + contribution
-        return jnp.zeros_like(radiance).at[lane].set(radiance)
     # Non-Pallas reference path: the plain XLA bounce loop. Order-invariant
     # per lane, so no sort machinery.
     n = origins.shape[0]
@@ -585,9 +714,9 @@ def render_tile(
     lets the interleaved A/B bench run both variants in one process.
 
     ``with_live`` (static) returns ``(radiance, live)`` instead, ``live``
-    the int32 [max_bounces] live-ray count of each per-bounce launch
-    (trace_paths' ``live_counts``), or None where the scene's path
-    launches no per-bounce kernel.
+    the int32 [max_bounces, 2] (live rays, width) of each per-bounce
+    launch (trace_paths' ``live_counts``), or None where the scene's
+    path launches no per-bounce kernel.
     """
     n = tile_height * tile_width
     base_key = tile_base_key(frame, y0, x0)
@@ -611,10 +740,12 @@ def render_tile(
         # total work — a measured ~1.9x on a single chip. Safe here because
         # the fused kernel blocks rays at BLOCK_R; its VMEM working set is
         # independent of the flattened ray count.
-        origins, directions = flat_sample_rays(
-            camera, base_key, width=width, height=height, y0=y0, x0=x0,
-            tile_height=tile_height, tile_width=tile_width, samples=samples,
-        )
+        with jax.named_scope("raygen"):
+            origins, directions = flat_sample_rays(
+                camera, base_key, width=width, height=height, y0=y0, x0=x0,
+                tile_height=tile_height, tile_width=tile_width,
+                samples=samples,
+            )
         radiance = trace_paths(
             scene,
             origins,
@@ -842,9 +973,9 @@ def fused_frame_renderer(
     with a matching tree instead of a stale cache hit.
 
     ``with_live`` makes the closure return ``(image, live)`` from the
-    same program: ``live`` is render_tile's per-bounce live-ray counts
-    (int32 [max_bounces]) for a deep mesh scene and None for every other
-    scene, whose program is then the one without it.
+    same program: ``live`` is render_tile's per-bounce (live rays,
+    launch width) (int32 [max_bounces, 2]) for a deep mesh scene and None
+    for every other scene, whose program is then the one without it.
     """
     return _fused_frame_renderer(
         scene_name, width, height, samples, max_bounces,
@@ -880,11 +1011,12 @@ def _fused_region_renderer(
         scene = build_scene(scene_name, frame)
         camera = scene_camera(scene_name, frame)
         mesh = scene_mesh_set(scene_name, frame, builder, wide)
-        origins, directions, lanes, seed = region_rays_and_seed(
-            camera, jnp.asarray(frame, jnp.float32),
-            width=width, height=height, samples=samples,
-            y0=y0, x0=x0, tile_height=tile_height, tile_width=tile_width,
-        )
+        with jax.named_scope("raygen"):
+            origins, directions, lanes, seed = region_rays_and_seed(
+                camera, jnp.asarray(frame, jnp.float32),
+                width=width, height=height, samples=samples,
+                y0=y0, x0=x0, tile_height=tile_height, tile_width=tile_width,
+            )
         base_key = tile_base_key(jnp.asarray(frame, jnp.float32), 0, 0)
         n = tile_height * tile_width
         from tpu_render_cluster.render import pallas_kernels

@@ -238,11 +238,12 @@ class TpuRaytraceBackend(RenderBackend):
         )
 
     @staticmethod
-    def _observe_launches(live, *, rays: int) -> None:
+    def _observe_launches(launches) -> None:
         """Launch occupancy of a one-program frame of a deep mesh scene:
-        every bounce is one kernel launch over the frame's whole ray set
-        (``rays`` lanes, dead ones sorted to the tail and skipped by
-        blocks), ``live[b]`` of them live. Fed into the series the
+        every bounce is one kernel launch, ``launches[b]`` its (live
+        rays, width) — the width the program picked for that bounce from
+        its live count (integrator.launch_width_ladder), dead lanes
+        sorted to the tail and skipped by blocks. Fed into the series the
         wavefront driver (per relaunch, live / bucket) and the raypool
         (per iteration, live / launched lanes) feed for their launches,
         so the tier that renders is the one the occupancy describes."""
@@ -253,10 +254,10 @@ class TpuRaytraceBackend(RenderBackend):
         )
 
         occupancy = launch_occupancy_histogram()
-        for count in live:
-            occupancy.observe(int(count) / rays)
-        pool_launched_lanes_counter().inc(float(rays * len(live)))
-        pool_live_lanes_counter().inc(float(live.sum()))
+        for live, width in launches:
+            occupancy.observe(int(live) / int(width))
+        pool_launched_lanes_counter().inc(float(launches[:, 1].sum()))
+        pool_live_lanes_counter().inc(float(launches[:, 0].sum()))
 
     @staticmethod
     def _observe_render_obs(
@@ -391,9 +392,9 @@ class TpuRaytraceBackend(RenderBackend):
         finished_loading_at = time.time()
 
         started_rendering_at = time.time()
-        # The one-program tier's per-bounce live-ray counts (deep mesh
-        # scenes only), an output of the frame's own program.
-        live = None
+        # The one-program tier's per-bounce (live rays, launch width) (deep
+        # mesh scenes only), an output of the frame's own program.
+        launches = None
         # Issuing the device's work; the wavefront and raypool drivers open
         # their own device_wait / readback steps inside, which suspend it.
         with step("dispatch"):
@@ -493,9 +494,9 @@ class TpuRaytraceBackend(RenderBackend):
                 )
                 display = tonemap(linear)
             else:
-                display, live = renderer(frame_index)
-                if live is not None:
-                    live.copy_to_host_async()
+                display, launches = renderer(frame_index)
+                if launches is not None:
+                    launches.copy_to_host_async()
             # Ask for the pixels now, behind the frame's work in the
             # device's queue, as np.asarray on an unfinished array does:
             # a copy first asked for after the wait below would cost the
@@ -508,8 +509,8 @@ class TpuRaytraceBackend(RenderBackend):
             display.block_until_ready()
         with step("readback"):
             pixels = np.asarray(display)
-            if live is not None:
-                live = np.asarray(live)
+            if launches is not None:
+                launches = np.asarray(launches)
         finished_rendering_at = time.time()
 
         file_saving_started_at = time.time()
@@ -556,10 +557,8 @@ class TpuRaytraceBackend(RenderBackend):
                 dims.update(th=region[2], tw=region[3])
             kernel = kernel_key(tier, scene_name, **dims)
         self._tier_frames.inc(tier=tier)
-        if live is not None:
-            self._observe_launches(
-                live, rays=self.width * self.height * self.samples
-            )
+        if launches is not None:
+            self._observe_launches(launches)
         self._observe_render_obs(
             execute_seconds=finished_rendering_at - started_rendering_at,
             from_cache=cached_linear is not None,
