@@ -203,176 +203,6 @@ def chip_efficiency(fps: float, chunks: int, scene_name: str) -> dict:
     }
 
 
-def occupancy_probe(scene_name: str) -> float | None:
-    """Record the scene's per-bounce survival curve; returns the wasted
-    lane fraction (1 - mean alive fraction over bounces).
-
-    One frame through the wavefront driver (render/compaction.py) — the
-    survival curve is scene physics, independent of which execution mode
-    the timed windows used, and the probe feeds the same
-    ``render_alive_fraction`` histogram the analysis suite folds into
-    statistics.json. Probe size matches the bench workload on a real
-    chip; on interpret-mode backends it shrinks so the probe stays a
-    footnote next to the timed windows.
-    """
-    import jax
-
-    from tpu_render_cluster.render import compaction
-
-    on_tpu = jax.default_backend() == "tpu"
-    compaction.render_frame_wavefront(
-        scene_name,
-        1,
-        width=WIDTH if on_tpu else 64,
-        height=HEIGHT if on_tpu else 64,
-        samples=SAMPLES if on_tpu else 1,
-        max_bounces=BOUNCES,
-    )
-    return compaction.wasted_lane_fraction()
-
-
-def _bvh_format_note() -> dict:
-    """The BVH node-format env tiers a record was taken under (method
-    stamp for WAVEFRONT/RAYPOOL/BVH records): resolved exactly as the
-    render drivers resolve them."""
-    from tpu_render_cluster.render.integrator import resolve_bvh_config
-
-    tlas, quant, builder, wide = resolve_bvh_config()
-    return {"tlas": tlas, "quant": quant, "builder": builder, "wide": wide}
-
-
-def wavefront_compare(
-    scene_name: str, frames: int = 8, reps: int = 5, bounces: int = BOUNCES
-) -> dict:
-    """Masked per-frame dispatch vs the wavefront driver, same workload.
-
-    ``reps`` interleaved repetitions of (``frames`` masked frames,
-    ``frames`` wavefront frames) after a warm frame apiece — per-frame
-    host sync both sides, the production dispatch shape of the worker
-    backend — reporting the MEDIAN frames/s per mode (interleaving
-    cancels machine-load drift; a single back-to-back pair measured
-    ±30% run-to-run on a shared host). The committed record lives at
-    results/WAVEFRONT_BENCH.json; run with
-    ``python bench.py --wavefront-compare [scene]`` on the target device
-    class.
-    """
-    import statistics
-
-    import jax
-    import numpy as np
-
-    from tpu_render_cluster.render import compaction
-    from tpu_render_cluster.render.integrator import fused_frame_renderer
-
-    on_tpu = jax.default_backend() == "tpu"
-    # Pin the masked tier to the Pallas (interpret) path off-chip, same
-    # rationale as raypool_compare/bvh_compare: the wavefront driver
-    # always runs the Pallas bounce kernels, while the masked renderer's
-    # CPU default is the XLA fallback — a cross-suite comparison would
-    # measure kernel dialects, not dispatch modes.
-    pallas_pinned = False
-    if not on_tpu and os.environ.get("TRC_PALLAS") is None:
-        os.environ["TRC_PALLAS"] = "1"
-        pallas_pinned = True
-        jax.clear_caches()
-        fused_frame_renderer.cache_clear()
-    try:
-        # The CPU (interpret) config must still span MANY kernel blocks —
-        # compaction only shrinks launches in units of the bucket quantum
-        # (the kernel ray block), so a frame of a few blocks measures
-        # mostly driver overhead instead of the mode. (Pre-TLAS
-        # idle-machine sweep, this scene: 32x32 -> 0.75x, 64x64 -> 1.01x,
-        # 128x128 -> 1.13x wavefront speedup; on the TLAS kernels the
-        # masked tier resorts/tail-skips on the same key column, so the
-        # committed 128x128 record is ~parity — the mode win is the
-        # wasted_lane_fraction row and the on-chip launch shrink, not a
-        # CPU-proxy frames/s delta.)
-        width = height = WIDTH if on_tpu else 128
-        samples = SAMPLES if on_tpu else 1
-        renderer = fused_frame_renderer(
-            scene_name, width, height, samples, bounces
-        )
-
-        def masked_frame(frame: int):
-            np.asarray(renderer(frame))
-
-        def wavefront_frame(frame: int):
-            from tpu_render_cluster.render.integrator import tonemap
-
-            # tonemap on BOTH sides: the fused renderer's program ends in
-            # tonemap, and the worker backend's wavefront branch tonemaps
-            # too — an asymmetric comparison would hand wavefront the
-            # display-transform cost for free.
-            np.asarray(
-                tonemap(
-                    compaction.render_frame_wavefront(
-                        scene_name, frame, width=width, height=height,
-                        samples=samples, max_bounces=bounces,
-                    )
-                )
-            )
-
-        from tpu_render_cluster.render import pallas_kernels as pk
-
-        record: dict = {
-            "metric": f"{scene_name} masked vs wavefront "
-            f"({width}x{height}, {samples}spp, {bounces}b, "
-            f"{jax.devices()[0].platform})",
-            "unit": "frames/s/chip",
-            "frames": frames,
-            "reps": reps,
-            # Method: which kernel generation BOTH modes ran (TRC_TLAS
-            # env tier at record time) — the masked tier is pinned to
-            # the Pallas path off-chip so the modes share one suite.
-            "tlas_kernels": pk.tlas_enabled(),
-            "bvh_node_format": _bvh_format_note(),
-        }
-        modes = (("masked", masked_frame), ("wavefront", wavefront_frame))
-        for _name, render_one in modes:
-            render_one(1)  # compile + warm
-        fps: dict[str, list[float]] = {"masked": [], "wavefront": []}
-        for rep in range(reps):
-            # Both modes render the SAME frame window per rep: the scenes
-            # are physics-animated, so disjoint frame ranges would compare
-            # different geometry/survival curves (and hand one mode the
-            # bucket recompiles a first-seen live count triggers).
-            rep_frames = range(2 + rep * frames, 2 + (rep + 1) * frames)
-            for name, render_one in modes:
-                t0 = time.perf_counter()
-                for frame in rep_frames:
-                    render_one(frame)
-                fps[name].append(frames / (time.perf_counter() - t0))
-        for name, values in fps.items():
-            record[f"{name}_fps"] = round(statistics.median(values), 3)
-        record["wavefront_speedup"] = round(
-            record["wavefront_fps"] / record["masked_fps"], 3
-        )
-        wasted = compaction.wasted_lane_fraction()
-        if wasted is not None:
-            record["wasted_lane_fraction"] = round(wasted, 4)
-        return record
-    finally:
-        if pallas_pinned:
-            os.environ.pop("TRC_PALLAS", None)
-            jax.clear_caches()
-            fused_frame_renderer.cache_clear()
-
-
-# The node-format variants bvh_compare prices (ISSUE 15): each is a
-# DISTINCT compiled program in one process (the knobs are part of the
-# renderer cache key and every jit identity). "flat"/"tlas" keep the
-# PR-10 hierarchy axis alive; the quant/SAH axis measures the new node
-# formats against the PR-10 config ("tlas": median-split binary BLAS,
-# fp32 nodes).
-BVH_VARIANTS: dict[str, dict] = {
-    "flat": dict(use_tlas=False, quant=0, builder="median", wide=1),
-    "tlas": dict(use_tlas=True, quant=0, builder="median", wide=1),
-    "tlas_sah": dict(use_tlas=True, quant=0, builder="sah", wide=4),
-    "tlas_quant": dict(use_tlas=True, quant=1, builder="median", wide=1),
-    "tlas_quant_sah": dict(use_tlas=True, quant=1, builder="sah", wide=4),
-}
-
-
 def _node_table_footprint(scene_name: str, cfg: dict) -> dict:
     """Bytes of the node tables a variant's kernels actually LOAD:
     fp32 nodes cost 36 B (6 f32 slabs + 3 int32 links), quant tier 1
@@ -478,9 +308,9 @@ def bvh_compare(
         jax.clear_caches()
         fused_frame_renderer.cache_clear()
     try:
-        # Same CPU shrink rationale as wavefront_compare: the workload
-        # must span many kernel blocks or the measurement is driver
-        # overhead, but interpret mode caps what is affordable.
+        # CPU shrink: the workload must span many kernel blocks or the
+        # measurement is dispatch overhead, but interpret mode caps what
+        # is affordable.
         width = height = WIDTH if on_tpu else 128
         samples = SAMPLES if on_tpu else 1
         rays_per_frame = width * height * samples
@@ -636,172 +466,6 @@ def bvh_compare(
             os.environ.pop("TRC_PALLAS", None)
             jax.clear_caches()
             fused_frame_renderer.cache_clear()
-
-
-def raypool_compare(
-    scene_name: str, frames: int = 8, reps: int = 5, bounces: int = BOUNCES
-) -> dict:
-    """Three-way masked / wavefront / device-raypool A/B, same workload.
-
-    Same interleaved median-of-reps discipline as wavefront_compare
-    (sequential timings are invalid at this host's ±30% drift): each rep
-    renders the SAME ``frames``-frame window once per mode, modes
-    interleaved, median frames/s per mode reported. The raypool mode
-    renders the window as ONE multi-frame pool batch — the production
-    shape of the worker backend's batching. Per-mode waste accounting:
-
-    - masked: 1 - mean per-bounce survival (full-width launches pay the
-      whole dead fraction — the 0.7366 recorded in WAVEFRONT_BENCH);
-    - wavefront: 1 - mean(live / launched bucket) (what bucketed
-      reclaim still leaves on the table);
-    - raypool: 1 - mean per-iteration pool live fraction (cross-frame
-      refill keeps the pool full until the batch drains).
-
-    ``pool_occupancy`` per mode is the complement — the mean live
-    fraction of LAUNCHED lanes. The committed record lives at
-    results/RAYPOOL_BENCH.json.
-
-    On non-TPU hosts the masked reference is pinned to the Pallas
-    interpret path (``TRC_PALLAS=1`` for the duration): all three modes
-    then run the SAME kernel suite, which is what the comparison means
-    on the target device class — the XLA fallback loop is a different
-    renderer entirely (50x slower on deep-mesh CPU) and comparing the
-    pool against it would manufacture a fantasy speedup.
-    """
-    import statistics
-
-    import jax
-    import numpy as np
-
-    from tpu_render_cluster.render import compaction, raypool
-    from tpu_render_cluster.render.integrator import (
-        fused_frame_renderer,
-        tonemap,
-    )
-
-    on_tpu = jax.default_backend() == "tpu"
-    pallas_pinned = False
-    if not on_tpu and os.environ.get("TRC_PALLAS") is None:
-        os.environ["TRC_PALLAS"] = "1"
-        pallas_pinned = True
-        jax.clear_caches()
-        fused_frame_renderer.cache_clear()
-    try:
-        return _raypool_compare_inner(
-            scene_name, frames, reps, bounces, on_tpu=on_tpu,
-            statistics=statistics, jax=jax, np=np,
-            compaction=compaction, raypool=raypool,
-            fused_frame_renderer=fused_frame_renderer, tonemap=tonemap,
-        )
-    finally:
-        if pallas_pinned:
-            os.environ.pop("TRC_PALLAS", None)
-            jax.clear_caches()
-            fused_frame_renderer.cache_clear()
-
-
-def _raypool_compare_inner(
-    scene_name, frames, reps, bounces, *, on_tpu, statistics, jax, np,
-    compaction, raypool, fused_frame_renderer, tonemap,
-):
-    from tpu_render_cluster.render import pallas_kernels as pk
-    # Same CPU shrink rationale as wavefront_compare: the workload must
-    # span many kernel blocks or the measurement is driver overhead.
-    width = height = WIDTH if on_tpu else 128
-    samples = SAMPLES if on_tpu else 1
-    renderer = fused_frame_renderer(scene_name, width, height, samples, bounces)
-
-    def masked_window(window):
-        for frame in window:
-            np.asarray(renderer(frame))
-
-    def wavefront_window(window):
-        for frame in window:
-            np.asarray(
-                tonemap(
-                    compaction.render_frame_wavefront(
-                        scene_name, frame, width=width, height=height,
-                        samples=samples, max_bounces=bounces,
-                    )
-                )
-            )
-
-    def raypool_window(window):
-        images = raypool.render_batch_raypool(
-            scene_name, list(window), width=width, height=height,
-            samples=samples, max_bounces=bounces,
-        )
-        for image in images:
-            np.asarray(tonemap(image))
-
-    record: dict = {
-        "metric": f"{scene_name} masked vs wavefront vs raypool "
-        f"({width}x{height}, {samples}spp, {bounces}b, "
-        f"{jax.devices()[0].platform})",
-        "unit": "frames/s/chip",
-        "frames": frames,
-        "reps": reps,
-        "raypool_frame_cap": raypool.raypool_frame_cap(),
-        # Method: which kernel generation ALL THREE modes ran (TRC_TLAS
-        # env tier at record time; the masked tier is already pinned to
-        # the Pallas path off-chip).
-        "tlas_kernels": pk.tlas_enabled(),
-        "bvh_node_format": _bvh_format_note(),
-    }
-    modes = (
-        ("masked", masked_window),
-        ("wavefront", wavefront_window),
-        ("raypool", raypool_window),
-    )
-    for _name, render_window in modes:
-        render_window(range(1, 2))  # compile + warm
-    fps: dict[str, list[float]] = {name: [] for name, _ in modes}
-    for rep in range(reps):
-        # All modes render the SAME frame window per rep (animated
-        # scenes: disjoint ranges would compare different geometry).
-        window = range(2 + rep * frames, 2 + (rep + 1) * frames)
-        for name, render_window in modes:
-            t0 = time.perf_counter()
-            render_window(window)
-            fps[name].append(frames / (time.perf_counter() - t0))
-    for name, values in fps.items():
-        record[f"{name}_fps"] = round(statistics.median(values), 3)
-    record["raypool_speedup"] = round(
-        record["raypool_fps"] / record["masked_fps"], 3
-    )
-    record["raypool_vs_wavefront"] = round(
-        record["raypool_fps"] / record["wavefront_fps"], 3
-    )
-    if not on_tpu:
-        # What the CPU interpret proxy CAN'T see: the pool's structural
-        # wins are eliminating the wavefront driver's per-bounce host
-        # sync and the per-frame launch/drain floor — on this host a
-        # sync is ~free and every mode's kernels run as compiled XLA, so
-        # the three modes measure within noise of each other while the
-        # occupancy numbers (the mechanism) separate cleanly. Same
-        # caveat as the committed WAVEFRONT_BENCH CPU record.
-        record["note"] = (
-            "CPU interpret proxy — sync/launch-structure wins are "
-            "on-chip; re-record on TPU (acceptance: raypool >= 1.3x "
-            "masked). The wasted_lane_fraction row is the load-"
-            "invariant mechanism measurement."
-        )
-    wasted = {
-        "masked": compaction.wasted_lane_fraction(),
-        "wavefront": compaction.launched_wasted_lane_fraction(),
-        "raypool": raypool.raypool_wasted_lane_fraction(),
-    }
-    record["wasted_lane_fraction"] = {
-        name: round(value, 4)
-        for name, value in wasted.items()
-        if value is not None
-    }
-    record["pool_occupancy"] = {
-        name: round(1.0 - value, 4)
-        for name, value in wasted.items()
-        if value is not None
-    }
-    return record
 
 
 def multi_job_bench(
@@ -2134,63 +1798,6 @@ def main() -> int:
             f.write("\n")
         return 0
 
-    if "--raypool-compare" in sys.argv:
-        index = sys.argv.index("--raypool-compare")
-        scene = (
-            sys.argv[index + 1]
-            if index + 1 < len(sys.argv) and not sys.argv[index + 1].startswith("-")
-            else "03_physics-2-mesh"
-        )
-
-        frames = _int_flag("--frames", 8)
-        reps = _int_flag("--reps", 5)
-        bounces = _int_flag("--bounces", BOUNCES)
-        record = raypool_compare(scene, frames=frames, reps=reps, bounces=bounces)
-        record["command"] = (
-            f"python bench.py --raypool-compare {scene} "
-            f"--frames {frames} --reps {reps} --bounces {bounces}"
-        )
-        print(json.dumps(record))
-        out_path = os.path.join(
-            os.path.dirname(os.path.abspath(__file__)),
-            "results",
-            "RAYPOOL_BENCH.json",
-        )
-        with open(out_path, "w", encoding="utf-8") as f:
-            json.dump(record, f, indent=1)
-            f.write("\n")
-        return 0
-
-    if "--wavefront-compare" in sys.argv:
-        index = sys.argv.index("--wavefront-compare")
-        scene = (
-            sys.argv[index + 1]
-            if index + 1 < len(sys.argv) and not sys.argv[index + 1].startswith("-")
-            else "03_physics-2-mesh"
-        )
-
-        frames = _int_flag("--frames", 8)
-        reps = _int_flag("--reps", 5)
-        bounces = _int_flag("--bounces", BOUNCES)
-        record = wavefront_compare(scene, frames=frames, reps=reps, bounces=bounces)
-        # Self-documenting: the exact invocation that reproduces this
-        # record (the committed artifact must not be silently replaced by
-        # a different workload's measurement).
-        record["command"] = (
-            f"python bench.py --wavefront-compare {scene} "
-            f"--frames {frames} --reps {reps} --bounces {bounces}"
-        )
-        print(json.dumps(record))
-        out_path = os.path.join(
-            os.path.dirname(os.path.abspath(__file__)),
-            "results",
-            "WAVEFRONT_BENCH.json",
-        )
-        with open(out_path, "w", encoding="utf-8") as f:
-            json.dump(record, f, indent=1)
-            f.write("\n")
-        return 0
-
     import jax
 
     headline_started = time.perf_counter()
@@ -2212,16 +1819,10 @@ def main() -> int:
         record.update(chip_efficiency(fps, CHUNKS, "04_very-simple"))
     except Exception as e:  # noqa: BLE001 - accounting must not kill the bench
         print(f"warning: chip efficiency accounting failed: {e}", file=sys.stderr)
-    try:
-        wasted = occupancy_probe("04_very-simple")
-        if wasted is not None:
-            record["wasted_lane_fraction"] = round(wasted, 4)
-    except Exception as e:  # noqa: BLE001 - the probe must not kill the bench
-        print(f"warning: lane occupancy probe failed: {e}", file=sys.stderr)
-    # Per-kernel roofline placements captured during this run (the
-    # occupancy probe's wavefront launches and any instrumented renderer
-    # the timed windows exercised) — obs/profiling.py's view, the same
-    # section statistics.json folds from run artifacts.
+    # Per-kernel roofline placements captured during this run (any
+    # instrumented renderer the timed windows exercised) —
+    # obs/profiling.py's view, the same section statistics.json folds
+    # from run artifacts.
     from tpu_render_cluster.obs.profiling import get_profiler
 
     roofline = get_profiler().view()

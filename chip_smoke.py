@@ -7,8 +7,8 @@ separate OS processes at the only width the repo serves (512x512, 8 spp,
 4 bounces) — through two jobs derived from committed job files:
 
 - job A: `04_very-simple`, 64 frames, tpu-batch (the sphere megakernel);
-- job B: 16 frames of `03_physics-2-mesh` under default tiers (the TLAS
-  bounce kernel under masked / wavefront / raypool).
+- job B: 16 frames of `03_physics-2-mesh` (the TLAS bounce kernel, one
+  launch per bounce inside the frame's program).
 
 Then frame 1 of three scenes is rendered at 64x64 2 spp through
 `render.cli` twice, on the chip and in a CPU child running the same

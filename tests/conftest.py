@@ -90,26 +90,16 @@ def kill_leftover_children(monkeypatch):
 
 
 @pytest.fixture(autouse=True)
-def _isolated_render_compile_tracking():
-    """Reset the render drivers' compile first-sighting tracker per test.
-
-    render/compaction._seen_shapes is process-global (it mirrors the
-    process-lifetime jit cache the ``render_compiles_total`` counter
-    describes), so without this reset a test's compile-delta assertions
-    would depend on which shapes EARLIER tests happened to launch. The
-    obs counter itself stays monotonic — only the dedup memory is
-    cleared, so each test observes fresh first-sightings.
-    """
-    compaction = sys.modules.get("tpu_render_cluster.render.compaction")
-    if compaction is not None:
-        compaction.reset_compile_tracking()
-    # Same reasoning for the kernel roofline profiler (obs/profiling.py):
-    # its capture/execution store is process-global and cumulative, so
-    # per-kernel assertions must start from a clean slate each test.
+def _isolated_process_global_stores():
+    """Reset process-global stores whose contents would otherwise depend
+    on which tests ran earlier in this worker."""
+    # The kernel roofline profiler (obs/profiling.py): its capture/
+    # execution store is process-global and cumulative, so per-kernel
+    # assertions must start from a clean slate each test.
     profiling = sys.modules.get("tpu_render_cluster.obs.profiling")
     if profiling is not None:
         profiling.get_profiler().reset()
-    # And for the host-side geometry-build memo (render/mesh.py): BVH/
+    # The host-side geometry-build memo (render/mesh.py): BVH/
     # TLAS builds are pure, but per-test build-count assertions (e.g.
     # render_tlas_builds_total deltas) must not depend on which
     # hierarchies earlier tests already built.

@@ -192,73 +192,68 @@ def test_masked_frame_and_region_lower_for_tpu(lower_for_tpu, scene_name):
     fused_region_renderer.cache_clear()
 
 
-@pytest.mark.parametrize("scene_name", ("04_very-simple", "03_physics-2-mesh"))
-def test_wavefront_step_lowers_for_tpu(lower_for_tpu, scene_name):
-    from tpu_render_cluster.render import compaction
+def test_the_backends_deep_program_lowers_for_tpu(lower_for_tpu):
+    """The program the backend runs for a deep mesh frame: with the live
+    counts as a second output, every rung of the ladder inside it."""
+    from tpu_render_cluster.render.integrator import fused_frame_renderer
+
+    frame = fused_frame_renderer(
+        "03_physics-2-mesh", WIDTH, HEIGHT, SAMPLES, BOUNCES, with_live=True
+    )
+    text = lower_for_tpu(frame.__wrapped__, _f32())
+    fused_frame_renderer.cache_clear()
+    assert text.count("tpu_custom_call") >= BOUNCES
+
+
+def _tree_spec(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree
+    )
+
+
+@pytest.mark.parametrize("rung", range(4), ids=["n", "n/4", "n/8", "n/16"])
+def test_deep_bounce_kernel_lowers_for_tpu_at_every_launch_width(lower_for_tpu, rung):
+    """One bounce launch of the deep scene at each width the frame's program
+    may pick (integrator.launch_width_ladder): block-spec legality depends on
+    the row length, and three of the four are narrower than the frame."""
     from tpu_render_cluster.render import pallas_kernels as pk
+    from tpu_render_cluster.render.integrator import launch_width_ladder
     from tpu_render_cluster.render.mesh import scene_mesh_set
     from tpu_render_cluster.render.scene import build_scene
 
-    def spec(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree
-        )
-
-    rays = WIDTH * HEIGHT * SAMPLES
-    vec = jax.ShapeDtypeStruct((rays, 3), jnp.float32)
-    alive = jax.ShapeDtypeStruct((rays,), jnp.bool_)
-    lane = jax.ShapeDtypeStruct((rays,), jnp.int32)
-    scene = spec(build_scene(scene_name, 1))
-    mesh = scene_mesh_set(scene_name, 1)
-    if mesh is None:
-        lower_for_tpu(
-            compaction._sphere_step, scene, vec, vec, vec, alive, lane, lane,
-            _i32(), _i32(), _i32(), vec, total_bounces=BOUNCES,
-        )
-    else:
-        lower_for_tpu(
-            compaction._mesh_step, scene, spec(mesh), vec, vec, vec, alive,
-            lane, lane, _i32(), _i32(), _i32(), vec, total_bounces=BOUNCES,
-            use_tlas=True, tlas_block=pk.tlas_block_r(),
-        )
-
-
-@pytest.mark.parametrize("scene_name", ("04_very-simple", "03_physics-2-mesh"))
-def test_raypool_batch_lowers_for_tpu(lower_for_tpu, scene_name):
-    """The mesh program is the one a TPU refused until ISSUE 22: (1, 1)
-    SMEM blocks over the [1, n_blocks] frame-window rows."""
-    from tpu_render_cluster.render import pallas_kernels as pk
-    from tpu_render_cluster.render import raypool
-    from tpu_render_cluster.render.integrator import resolve_bvh_config
-    from tpu_render_cluster.render.scene import mesh_kind_for_scene
-
-    block = (
-        pk.BVH_BLOCK_R if mesh_kind_for_scene(scene_name) is not None
-        else pk.SPHERE_BOUNCE_BLOCK_R
-    )
-    use_tlas, quant, builder, wide = resolve_bvh_config()
+    widths = launch_width_ladder(WIDTH * HEIGHT * SAMPLES)
+    assert len(widths) == 4
+    width = widths[rung]
+    vec = jax.ShapeDtypeStruct((width, 3), jnp.float32)
     lower_for_tpu(
-        raypool._raypool_batch, scene_name,
-        jax.ShapeDtypeStruct((raypool.raypool_frame_cap(),), jnp.float32),
-        _i32(), _i32(), _i32(),
-        width=WIDTH, height=HEIGHT, samples=SAMPLES, max_bounces=BOUNCES,
-        pool_width=raypool.raypool_width(SAMPLES * HEIGHT * WIDTH, block),
-        use_tlas=use_tlas, tlas_leaf=pk.tlas_leaf_size(),
-        tlas_block=pk.tlas_block_r(), quant=quant, builder=builder, wide=wide,
+        jax.jit(
+            pk.mesh_bounce_pallas,
+            static_argnames=("total_bounces", "use_tlas", "quant"),
+        ),
+        _tree_spec(build_scene("03_physics-2-mesh", 1)),
+        _tree_spec(scene_mesh_set("03_physics-2-mesh", 1)),
+        vec, vec, vec, jax.ShapeDtypeStruct((width,), jnp.bool_), _i32(), _i32(),
+        total_bounces=BOUNCES, lane=jax.ShapeDtypeStruct((width,), jnp.int32),
+        live_count=_i32(), use_tlas=True,
     )
 
 
-def test_tile_sharded_frame_lowers_for_tpu(lower_for_tpu):
-    from tpu_render_cluster.parallel.sharded_render import render_frame_sharded
+@pytest.mark.parametrize("mode", ["tile", "spp"])
+def test_sharded_frame_lowers_for_tpu(lower_for_tpu, mode):
+    from tpu_render_cluster.parallel.sharded_render import (
+        render_frame_sharded,
+        sharded_frame_renderer,
+    )
 
     lower_for_tpu(
         jax.jit(
             lambda: render_frame_sharded(
                 "03_physics-2-mesh", 1, width=WIDTH, height=HEIGHT,
-                samples=SAMPLES, max_bounces=BOUNCES, mode="tile", n_devices=4,
+                samples=SAMPLES, max_bounces=BOUNCES, mode=mode, n_devices=4,
             )
         )
     )
+    sharded_frame_renderer.cache_clear()
 
 
 # -- Mosaic compile, without a chip ------------------------------------------
@@ -274,7 +269,8 @@ try:
 except Exception as error:  # no usable libtpu here: nothing to compile with
     print("NO_TOPOLOGY", error)
     sys.exit(0)
-from tpu_render_cluster.render import compaction, pallas_kernels as pk
+from tpu_render_cluster.render import pallas_kernels as pk
+from tpu_render_cluster.render.integrator import launch_width_ladder
 from tpu_render_cluster.render.mesh import scene_mesh_set
 from tpu_render_cluster.render.scene import build_scene
 
@@ -288,30 +284,34 @@ def tree(value):
     return jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), value)
 
 rays = 512 * 512 * 8
-vec, alive, lane = spec((rays, 3), jnp.float32), spec((rays,), jnp.bool_), spec((rays,), jnp.int32)
 i32 = spec((), jnp.int32)
-for scene_name in ("04_very-simple", "03_physics-2-mesh"):
-    scene, mesh = tree(build_scene(scene_name, 1)), scene_mesh_set(scene_name, 1)
-    if mesh is None:
-        traced = compaction._sphere_step.trace(
-            scene, vec, vec, vec, alive, lane, lane, i32, i32, i32, vec, total_bounces=4)
-    else:
-        traced = compaction._mesh_step.trace(
-            scene, tree(mesh), vec, vec, vec, alive, lane, lane, i32, i32, i32, vec,
-            total_bounces=4, use_tlas=True, tlas_block=pk.tlas_block_r())
-    traced.lower(lowering_platforms=("tpu",)).compile()
-    print("COMPILED", scene_name)
+vec = spec((rays, 3), jnp.float32)
+jax.jit(pk.trace_paths_fused, static_argnames=("max_bounces",)).trace(
+    tree(build_scene("04_very-simple", 1)), vec, vec, i32, max_bounces=4,
+).lower(lowering_platforms=("tpu",)).compile()
+print("COMPILED sphere megakernel")
+scene, mesh = tree(build_scene("03_physics-2-mesh", 1)), tree(scene_mesh_set("03_physics-2-mesh", 1))
+bounce = jax.jit(pk.mesh_bounce_pallas, static_argnames=("total_bounces", "use_tlas", "quant"))
+widths = launch_width_ladder(rays)
+for width in (widths[0], widths[-1]):
+    vec = spec((width, 3), jnp.float32)
+    bounce.trace(
+        scene, mesh, vec, vec, vec, spec((width,), jnp.bool_), i32, i32,
+        total_bounces=4, lane=spec((width,), jnp.int32), live_count=i32, use_tlas=True,
+    ).lower(lowering_platforms=("tpu",)).compile()
+    print("COMPILED deep bounce", width)
 """
 
 
 def test_bounce_kernels_compile_with_mosaic(tmp_path):
-    """The sphere state-IO bounce kernel and the TLAS mesh bounce kernel
-    (the key epilogue whose unsigned min Mosaic refused until ISSUE 22)
-    through the real compiler. A subprocess: it loads libtpu."""
+    """The sphere megakernel and the TLAS mesh bounce kernel (the key
+    epilogue whose unsigned min Mosaic refused until ISSUE 22), at the
+    widest and the narrowest rung of the launch-width ladder, through the
+    real compiler. A subprocess: it loads libtpu."""
     result = _run(
         _COMPILE_BOUNCE_KERNELS, TRC_PALLAS="1", TPU_LOG_DIR=str(tmp_path)
     )
     if "NO_TOPOLOGY" in result.stdout:
         pytest.skip(result.stdout.strip())
     assert result.returncode == 0, result.stderr[-3000:]
-    assert result.stdout.count("COMPILED") == 2
+    assert result.stdout.count("COMPILED") == 3

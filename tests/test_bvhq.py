@@ -17,11 +17,7 @@ Contracts pinned here:
    the preorder/skip invariants at any arity, traversal equals the
    brute-force reference, and the masked-tier image is uint8-identical
    to the median build's (per-lane results are visit-order invariant).
-4. PACKED CARRIED STATE — bf16 throughput pack/unpack is an exact
-   round-trip at bf16 resolution; the pool meta word is exact; the
-   wavefront/raypool tiers under quant >= 1 stay within an asserted
-   divergence budget of their fp32-carried selves (masked stays exact).
-5. Recompile/caching bounds: one compile per (tier, quant, builder)
+4. Recompile/caching bounds: one compile per (quant, builder)
    config — frames 2..3 add nothing (the test_tlas idiom) — and the
    geometry cache / renderer caches key on the build knobs so an env
    toggle can never serve a stale tree.
@@ -436,115 +432,33 @@ def test_masked_images_identical_across_node_formats(monkeypatch, scene_name):
         )
 
 
-# -- packed carried state -----------------------------------------------------
-
-
-def test_throughput_bf16_pack_roundtrip():
-    from tpu_render_cluster.render import pallas_kernels as pk
-
-    rng = np.random.default_rng(3)
-    thr = jnp.asarray(rng.uniform(0, 1.5, (257, 3)).astype(np.float32))
-    packed = pk.pack_throughput_bf16(thr)
-    assert packed.shape == (257, 2)
-    assert packed.dtype == jnp.float32
-    unpacked = pk.unpack_throughput_bf16(packed)
-    # Exact at bf16 resolution: the round-trip IS the bf16 cast.
-    expect = np.asarray(thr.astype(jnp.bfloat16).astype(jnp.float32))
-    np.testing.assert_array_equal(np.asarray(unpacked), expect)
-    # bf16-representable values survive bit-exactly.
-    exact = jnp.asarray([[1.0, 0.5, 0.25], [0.0, 2.0, 0.125]], jnp.float32)
-    np.testing.assert_array_equal(
-        np.asarray(pk.unpack_throughput_bf16(pk.pack_throughput_bf16(exact))),
-        np.asarray(exact),
-    )
-
-
-def test_pool_meta_word_roundtrip():
-    from tpu_render_cluster.render import pallas_kernels as pk
-
-    fid = jnp.asarray([0, 3, 31, 7], jnp.int32)
-    bounce = jnp.asarray([0, 1, 15, 255], jnp.int32)
-    alive = jnp.asarray([True, False, True, False])
-    meta = pk.pack_pool_meta(fid, bounce, alive)
-    f2, b2, a2 = pk.unpack_pool_meta(meta)
-    np.testing.assert_array_equal(np.asarray(f2), np.asarray(fid))
-    np.testing.assert_array_equal(np.asarray(b2), np.asarray(bounce))
-    np.testing.assert_array_equal(np.asarray(a2), np.asarray(alive))
-
-
-def test_wavefront_packed_state_divergence_budget(monkeypatch):
-    """The masked-vs-packed budget of the tentpole: with quant >= 1 the
-    wavefront driver carries bf16 throughput (one rounding per bounce),
-    so its image may diverge from the fp32-carried wavefront (which
-    equals the masked tier) by at most the asserted budget — linear MAE
-    < 1e-3 and tonemapped uint8 within +-2."""
-    from tpu_render_cluster.render.compaction import render_frame_wavefront
-    from tpu_render_cluster.render.integrator import tonemap
-
-    monkeypatch.setenv("TRC_PALLAS", "1")
-    kwargs = dict(width=12, height=12, samples=1, max_bounces=3)
-    base = np.asarray(
-        render_frame_wavefront(DEEP_SCENE, 30, quant=0, **kwargs)
-    )
-    for quant in (1, 2):
-        packed = np.asarray(
-            render_frame_wavefront(DEEP_SCENE, 30, quant=quant, **kwargs)
-        )
-        mae = np.abs(packed - base).mean()
-        assert mae < 1e-3, f"quant={quant}: MAE {mae} over budget"
-        delta = np.abs(
-            np.asarray(tonemap(jnp.asarray(packed))).astype(np.int32)
-            - np.asarray(tonemap(jnp.asarray(base))).astype(np.int32)
-        )
-        assert delta.max() <= 2, f"quant={quant}: uint8 delta {delta.max()}"
-
-
-def test_raypool_packed_state_divergence_budget(monkeypatch):
-    """Raypool under quant >= 1: bf16-packed throughput + the meta word
-    replacing the alive/fid/bounce columns — images stay within the same
-    budget vs the fp32-carried pool, and the batch still serves every
-    frame (the lifecycle survives the packed representation)."""
-    from tpu_render_cluster.render.raypool import render_batch_raypool
-
-    monkeypatch.setenv("TRC_PALLAS", "1")
-    kwargs = dict(
-        width=8, height=8, samples=1, max_bounces=2, pool_width=1024,
-        frame_cap=2,
-    )
-    base = render_batch_raypool(DEEP_SCENE, [30, 31], quant=0, **kwargs)
-    packed = render_batch_raypool(DEEP_SCENE, [30, 31], quant=1, **kwargs)
-    assert len(base) == len(packed) == 2
-    for a, b in zip(base, packed):
-        mae = np.abs(np.asarray(a) - np.asarray(b)).mean()
-        assert mae < 1e-3, f"raypool packed MAE {mae} over budget"
-
-
 # -- recompile bounds ---------------------------------------------------------
 
 
 def test_one_compile_per_quant_builder_config(monkeypatch):
-    """Three wavefront frames per (quant, builder) config: every compile
-    key is first-sighted on frame 1 — frames 2..3 add nothing, and a
-    SECOND config adds its own sightings (distinct programs), extending
-    the test_tlas.py idiom to the node-format axis."""
-    from tpu_render_cluster.render import compaction
-    from tpu_render_cluster.render.compaction import render_frame_wavefront
+    """Three frames per (quant, builder) config: the renderer factory
+    builds one program on frame 1 — frames 2..3 add nothing to
+    render_compiles_total, and a SECOND config adds its own (a distinct
+    program), extending the test_tlas.py idiom to the node-format axis."""
+    from tpu_render_cluster.obs import render_compile_counter
+    from tpu_render_cluster.render.integrator import fused_frame_renderer
 
     monkeypatch.setenv("TRC_PALLAS", "1")
-    kwargs = dict(width=8, height=8, samples=1, max_bounces=2)
-    counter = compaction.compile_counter()
-    render_frame_wavefront(DEEP_SCENE, 30, quant=1, **kwargs)
-    after_first = counter.value()
-    for frame in (31, 32):
-        render_frame_wavefront(DEEP_SCENE, frame, quant=1, **kwargs)
-    assert counter.value() == after_first
-    # The other tier is a distinct compiled config (new sightings once),
-    # then stable again.
-    render_frame_wavefront(DEEP_SCENE, 30, quant=0, **kwargs)
-    after_second = counter.value()
-    assert after_second > after_first
-    render_frame_wavefront(DEEP_SCENE, 31, quant=0, **kwargs)
-    assert counter.value() == after_second
+    fused_frame_renderer.cache_clear()
+    counter = render_compile_counter()
+
+    def frames(quant, builder, indices):
+        before = counter.value()
+        for frame in indices:
+            renderer = fused_frame_renderer(
+                DEEP_SCENE, 8, 8, 1, 2, None, quant, builder, 4, with_live=True
+            )
+            np.asarray(renderer(frame)[0])
+        return counter.value() - before
+
+    assert frames(1, "sah", (30, 31, 32)) == 1
+    assert frames(0, "sah", (30, 31)) == 1  # the other quant tier: its own program
+    assert frames(1, "sah", (33,)) == 0  # the first config is still there
 
 
 # -- on-chip sweep ------------------------------------------------------------

@@ -3,9 +3,9 @@
 The ladder and the rung (pure functions), and the program: whatever widths
 it runs its bounces at, the linear image is the full-width program's. The
 full-width reference is the same code with the ladder function patched to
-the one rung ``n``; every program here is traced afresh inside its own
-``jax.jit`` (through ``render_tile.__wrapped__`` and an uncached region
-renderer), so the patch is what the trace reads. Pallas interpreter on the
+the one rung ``n``; every program here is traced inside a ``jax.jit`` of
+this file's own (through ``render_tile.__wrapped__`` and an uncached region
+renderer), keyed by the ladder in force, so the patch is what the trace reads. Pallas interpreter on the
 CPU, tiny frames.
 
 On the chip the two programs' images are equal bit for bit (PERF.md §6,
@@ -80,8 +80,15 @@ def test_the_rung_holds_the_live_rays_and_never_widens(n):
 # -- the program -----------------------------------------------------------------
 
 
+# frame_program's jitted closures, by what shapes the trace: the frame is an
+# operand, so the cases that differ by the frame alone share a program. The
+# ladder function in force is part of the key: a patched ladder is another
+# program, traced when first asked for, under that patch.
+_FRAME_PROGRAMS: dict[tuple, object] = {}
+
+
 def frame_program(scene_name, frame_index, *, size, samples, bounces):
-    """(linear image, launches) of a whole frame, traced now."""
+    """(linear image, launches) of a whole frame."""
     import jax
     import jax.numpy as jnp
 
@@ -92,7 +99,6 @@ def frame_program(scene_name, frame_index, *, size, samples, bounces):
 
     use_tlas, quant, builder, wide = integrator.resolve_bvh_config()
 
-    @jax.jit
     def render(frame):
         return integrator.render_tile.__wrapped__(
             build_scene(scene_name, frame), scene_camera(scene_name, frame),
@@ -102,8 +108,10 @@ def frame_program(scene_name, frame_index, *, size, samples, bounces):
             use_tlas=use_tlas, quant=quant, with_live=True,
         )
 
-    image, launches = render(jnp.asarray(frame_index, jnp.float32))
-    return np.asarray(image), np.asarray(launches)
+    key = (scene_name, size, samples, bounces, integrator.launch_width_ladder)
+    program = _FRAME_PROGRAMS.setdefault(key, jax.jit(render))
+    image, launches = program(jnp.asarray(frame_index, jnp.float32))
+    return np.asarray(image), None if launches is None else np.asarray(launches)
 
 
 def region_program(scene_name, frame_index, *, size, samples, bounces):
@@ -121,6 +129,21 @@ def region_program(scene_name, frame_index, *, size, samples, bounces):
     return np.asarray(render(jnp.asarray(frame_index, jnp.float32), half, half)), None
 
 
+def tile_sharded_program(scene_name, frame_index, *, size, samples, bounces):
+    """Linear image of a frame of ``size`` x ``size // 2`` pixels rendered
+    in two bands across the local mesh (each band its own ray set and
+    ladder), built and traced now."""
+    from tpu_render_cluster.parallel.sharded_render import sharded_frame_renderer
+
+    sharded_frame_renderer.cache_clear()
+    render = sharded_frame_renderer(
+        scene_name, size, size // 2, samples, bounces, "tile", n_devices=2
+    )
+    image = np.asarray(render(frame_index))
+    sharded_frame_renderer.cache_clear()
+    return image, None
+
+
 def two_steps_down(n: int) -> tuple[int, ...]:
     """The rungs a 512x512x8 settled frame takes (n, n/8, n/16), at a size
     the interpreter can afford."""
@@ -128,12 +151,18 @@ def two_steps_down(n: int) -> tuple[int, ...]:
 
 
 CASES = {
-    # name: (program, frame, size, bounces, ladder or None for the real one, widths expected or None)
-    "settled": (frame_program, 295, 32, BOUNCES, None, [2048, 2048, 1024, 1024]),
-    "falling": (frame_program, 30, 32, BOUNCES, None, [2048, 2048, 1024, 1024]),
-    "region_with_rng_lanes": (region_program, 295, 64, BOUNCES, None, None),
-    "no_ray_dies_early": (frame_program, 295, 32, 2, None, [2048, 2048]),
-    "narrow_then_narrower": (frame_program, 295, 64, BOUNCES, two_steps_down, [8192, 8192, 1024, 512]),
+    # name: (scene, program, frame, size, bounces, ladder or None for the real one, widths expected or None)
+    "settled": (DEEP_SCENE, frame_program, 295, 32, BOUNCES, None, [2048, 2048, 1024, 1024]),
+    "falling": (DEEP_SCENE, frame_program, 30, 32, BOUNCES, None, [2048, 2048, 1024, 1024]),
+    "falling_late": (DEEP_SCENE, frame_program, 150, 32, BOUNCES, None, [2048, 2048, 1024, 1024]),
+    "region_with_rng_lanes": (DEEP_SCENE, region_program, 295, 64, BOUNCES, None, None),
+    "no_ray_dies_early": (DEEP_SCENE, frame_program, 295, 32, 2, None, [2048, 2048]),
+    "narrow_then_narrower": (DEEP_SCENE, frame_program, 295, 64, BOUNCES, two_steps_down, [8192, 8192, 1024, 512]),
+    # two bands of 2048 rays, each narrowing by itself
+    "tile_sharded": (DEEP_SCENE, tile_sharded_program, 295, 64, 3, None, None),
+    # scenes whose program launches no per-bounce kernel: no ladder to read
+    "shallow_mesh_has_no_ladder": ("02_physics-mesh", frame_program, 30, 32, BOUNCES, None, None),
+    "sphere_scene_has_no_ladder": ("04_very-simple", frame_program, 104, 32, BOUNCES, None, None),
 }
 
 
@@ -141,14 +170,15 @@ CASES = {
 def test_the_narrowed_program_renders_the_full_width_image(case, monkeypatch, interpreted_kernels):
     from tpu_render_cluster.render import integrator
 
-    program, frame, size, bounces, ladder, expected = CASES[case]
+    scene, program, frame, size, bounces, ladder, expected = CASES[case]
     if ladder is not None:
         monkeypatch.setattr(integrator, "launch_width_ladder", ladder)
-    image, launches = program(DEEP_SCENE, frame, size=size, samples=2, bounces=bounces)
+    image, launches = program(scene, frame, size=size, samples=2, bounces=bounces)
     monkeypatch.setattr(integrator, "launch_width_ladder", one_rung)
-    reference, full = program(DEEP_SCENE, frame, size=size, samples=2, bounces=bounces)
+    reference, full = program(scene, frame, size=size, samples=2, bounces=bounces)
     assert_the_same_image(image, reference)
     assert image.max() > 0.1 and image.std() > 0.01  # a picture, not a constant
+    assert (launches is None) == (expected is None)
     if launches is not None:
         rays = size * size * 2
         assert launches.shape == (bounces, 2) and (full[:, 1] == rays).all()

@@ -525,6 +525,13 @@ def test_env_registry_declares_every_helper_default():
         assert var.doc
 
 
+def test_no_option_selects_an_execution_tier():
+    """72 declared options, none of them a way to choose how a frame is
+    rendered: that is decided by the work unit and the worker's sharding."""
+    assert len(ENV_VARS) == 72
+    assert not [name for name in ENV_VARS if "WAVEFRONT" in name or "RAYPOOL" in name]
+
+
 def test_repo_is_lint_clean():
     """THE gate: the four passes + pragma meta-pass over the whole package,
     cross-checked against the real README.md / PROTOCOL.md. Every real

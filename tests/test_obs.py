@@ -794,3 +794,73 @@ def test_cluster_timeline_and_critical_path_end_to_end(tmp_path):
     assert len(cluster_traces) == 1
     summary = summarize_obs([], [], cluster_traces)
     assert next(iter(summary["critical_path"].values()))["frames"] == frames
+
+
+# ---------------------------------------------------------------------------
+# Launch occupancy of deep mesh frames: backend -> registry -> statistics.json
+
+
+@pytest.fixture
+def launch_registry(monkeypatch):
+    """A fresh process-global registry, fed two deep frames' bounce launches
+    through the backend's own feeder: (live rays, width) per launch."""
+    import numpy as np
+
+    from tpu_render_cluster import obs
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    monkeypatch.setattr(obs, "_global_registry", MetricsRegistry())
+    for launches in ([[2048, 2048], [1500, 2048], [300, 1024]], [[2048, 2048], [256, 1024]]):
+        TpuRaytraceBackend._observe_launches(np.asarray(launches, np.int32))
+    return obs.get_registry()
+
+
+LAUNCHES = 5
+LAUNCHED = 2048 + 2048 + 1024 + 2048 + 1024
+LIVE = 2048 + 1500 + 300 + 2048 + 256
+OCCUPANCY_MEAN = (1 + 1500 / 2048 + 300 / 1024 + 1 + 256 / 1024) / 5
+
+
+@pytest.mark.parametrize("form", ["registry_snapshot", "heartbeat_wire", "no_deep_frame"])
+def test_summarize_launch_occupancy(form, launch_registry):
+    from tpu_render_cluster.analysis.obs_events import summarize_launch_occupancy
+
+    if form == "registry_snapshot":
+        # the harness's shape: the process registry under its pid, newest copy only
+        older = {"written_at": 1.0, "process_metrics": {"pid": 7, "metrics": {}}}
+        newest = {
+            "written_at": 2.0,
+            "process_metrics": {"pid": 7, "metrics": launch_registry.snapshot()},
+        }
+        snapshots = [newest, older]
+    elif form == "heartbeat_wire":
+        # the master CLI's shape: two workers' heartbeat payloads, merged
+        wire = merge_wire([launch_registry.to_wire(), launch_registry.to_wire()])
+        snapshots = [{"metrics": {}, "cluster_metrics": wire}]
+    else:
+        # a sphere job: frames were rendered, no bounce launch was counted
+        other = MetricsRegistry()
+        other.counter("render_tier_frames_total", labels=("tier",)).inc(4, tier="masked")
+        snapshots = [{"metrics": other.snapshot()}, {"cluster_metrics": other.to_wire()}]
+    summary = summarize_launch_occupancy(snapshots)
+    if form == "no_deep_frame":
+        assert summary is None
+        return
+    workers = 2 if form == "heartbeat_wire" else 1
+    assert summary["launches"] == workers * LAUNCHES
+    assert summary["launched_lanes_total"] == workers * LAUNCHED
+    assert summary["live_lanes_total"] == workers * LIVE
+    assert summary["launch_occupancy_mean"] == pytest.approx(OCCUPANCY_MEAN)
+    assert summary["live_lane_share"] == pytest.approx(LIVE / LAUNCHED)
+
+
+def test_launch_occupancy_series_flow_into_statistics(tmp_path, launch_registry):
+    """Backend -> registry -> snapshot file -> one section of the summary."""
+    write_metrics_snapshot(tmp_path / "run_metrics.json", launch_registry)
+    traces, metrics = load_obs_artifacts(tmp_path)
+    summary = summarize_obs(traces, metrics)
+    assert "wavefront" not in summary and "raypool" not in summary
+    section = summary["launch_occupancy"]
+    assert section["launches"] == LAUNCHES
+    assert section["live_lane_share"] == pytest.approx(LIVE / LAUNCHED)
+    assert 0.0 < section["launch_occupancy_mean"] <= 1.0

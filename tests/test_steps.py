@@ -1,9 +1,9 @@
 """The steps of a frame (obs.step) and the render loop's states.
 
 The helper alone (exclusivity, nothing kept outside a frame, no JAX where
-there was none, a `trc:<step>` annotation in a profile), the three
-execution tiers of the tpu-raytrace backend on a 64x64 CPU frame (all six
-steps, adding up to the phases), `write_image`'s split (the file on disk is
+there was none, a `trc:<step>` annotation in a profile), the three unit
+shapes of the tpu-raytrace backend on a 64x64 CPU frame (all six steps,
+adding up to the phases), `write_image`'s split (the file on disk is
 the parent's, byte for byte), and the worker queue (steps enter the
 registry and the timeline with the phases; the loop counter's three states
 add up to the loop's wall time).
@@ -231,7 +231,7 @@ def test_a_profile_carries_the_steps_on_its_own_clock(tmp_path):
     assert 0.002 <= max(found["trc:device_wait"]) < 0.05
 
 
-# -- the backend's three tiers ---------------------------------------------------
+# -- the backend's three unit shapes ----------------------------------------------
 
 
 @pytest.fixture
@@ -244,29 +244,31 @@ def interpreted_kernels(monkeypatch):
     jax.clear_caches()
 
 
+# What the backend is given, by the tier that must render it: a whole frame
+# on one device, a tile of a 2x2 grid, a whole frame across the local mesh.
 TIERS = {
-    "masked": {"wavefront": "off", "raypool": "off"},
-    "wavefront": {"wavefront": "force", "raypool": "off"},
-    "raypool": {"wavefront": "off", "raypool": "force"},
+    "masked": {"sharding": None, "tile": None},
+    "region": {"sharding": None, "tile": 3},
+    "sharded": {"sharding": "tile", "tile": None},
 }
 BOUNCES = 3
 
 
-def render_one(tier: str, tmp_path: Path, frames_ahead: tuple[int, ...] = ()):
+def render_one(tier: str, tmp_path: Path):
+    import dataclasses
+
     from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
 
     job = make_job("04_very-simple_steps", 4)
+    tile = TIERS[tier]["tile"]
+    if tile is not None:
+        job = dataclasses.replace(job, tile_grid=(2, 2))
     backend = TpuRaytraceBackend(
         base_directory=tmp_path, width=64, height=64, samples=2,
-        max_bounces=BOUNCES, **TIERS[tier],
+        max_bounces=BOUNCES, sharding=TIERS[tier]["sharding"],
     )
-    if frames_ahead:
-        backend.note_upcoming_frames(job, frames_ahead)
-    backend._render_sync(job, 1)  # compiles; the frame below is the measured one
-    if frames_ahead:
-        backend._raypool_cache.clear()
-        backend.note_upcoming_frames(job, frames_ahead)
-    return backend, job, backend._render_sync(job, 1)
+    backend._render_sync(job, 1, tile)  # compiles; the frame below is the measured one
+    return backend, job, backend._render_sync(job, 1, tile)
 
 
 @pytest.mark.parametrize("tier", sorted(TIERS))
@@ -294,42 +296,19 @@ def test_every_tier_names_all_six_steps_and_they_add_up_to_the_phases(
     assert frame - (in_render + in_write) < 0.002
     ends = [start + seconds for _, start, seconds in timing.steps]
     assert ends == sorted(ends)
-    assert (tmp_path / "out" / "rendered-00001.jpg").is_file()
+    # a whole frame in the job's format; a tile always as PNG, for the master to stitch
+    written = sorted(path.name for path in (tmp_path / "out").iterdir())
+    assert len(written) == 1
+    assert written[0].endswith(".png" if tier == "region" else ".jpg")
 
 
-def test_the_one_program_tier_waits_for_the_device_once(tmp_path, interpreted_kernels):
-    _backend, _job, timing = render_one("masked", tmp_path)
+@pytest.mark.parametrize("tier", sorted(TIERS))
+def test_every_tier_waits_for_the_device_once(tier, tmp_path, interpreted_kernels):
+    """One program a frame, whatever the unit: the render thread blocks on
+    the device once (host_syncs_per_frame 1.0), then copies, then writes."""
+    _backend, _job, timing = render_one(tier, tmp_path)
     names = [name for name, _, _ in timing.steps]
     assert names == list(FRAME_STEPS[:4]) + ["file_write", "encode", "file_write"]
-
-
-def test_the_wavefront_waits_once_per_bounce_and_once_for_the_pixels(tmp_path, interpreted_kernels):
-    from tpu_render_cluster.obs import get_tracer
-
-    get_tracer().clear()
-    _backend, _job, timing = render_one("wavefront", tmp_path)
-    bounces = [
-        e for e in get_tracer().events()
-        if e["name"] == "wavefront_bounce" and e["ts"] >= timing.started_process_at * 1e6
-    ]
-    waits = [s for s in timing.steps if s[0] == "device_wait"]
-    assert 1 <= len(bounces) <= BOUNCES
-    assert len(waits) == len(bounces) + 1
-    # every wait lies inside the render phase, and dispatch resumes around each
-    names = [name for name, _, _ in timing.steps]
-    assert names.count("dispatch") == len(bounces) + 1
-
-
-def test_a_raypool_batch_waits_and_copies_inside_the_trigger_frame(tmp_path, interpreted_kernels):
-    backend, job, timing = render_one("raypool", tmp_path, frames_ahead=(2, 3))
-    names = [name for name, _, _ in timing.steps]
-    # the batch's wait and copy, then the frame's own
-    assert names.count("device_wait") == 2 and names.count("readback") == 2
-    assert set(backend._raypool_cache) == {(job.job_name, 2, None), (job.job_name, 3, None)}
-    backend.note_upcoming_frames(job, (3,))
-    cached = backend._render_sync(job, 2)  # served from the cache: tonemap only
-    cached_names = [name for name, _, _ in cached.steps]
-    assert cached_names.count("device_wait") == 1 and set(cached_names) == set(FRAME_STEPS)
 
 
 @pytest.mark.parametrize("tier", sorted(TIERS))
@@ -339,24 +318,54 @@ def test_every_frame_counts_once_under_the_tier_that_rendered_it(
     from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
 
     counter = TpuRaytraceBackend._tier_frames_counter()
-    tiers = ("masked", "wavefront", "raypool", "region", "sharded")
 
     def read() -> dict[str, float]:
-        return {name: counter.value(tier=name) for name in tiers}
+        return {name: counter.value(tier=name) for name in TIERS}
 
     before = read()
-    backend, job, _timing = render_one(
-        tier, tmp_path, frames_ahead=(2, 3) if tier == "raypool" else ()
-    )
-    rendered = 2  # render_one renders frame 1 twice
-    if tier == "raypool":
-        backend.note_upcoming_frames(job, (3,))
-        backend._render_sync(job, 2)  # from the rendered-ahead cache: the pool's frame
-        rendered = 3
+    render_one(tier, tmp_path)  # renders its unit twice
     after = read()
-    assert {name: after[name] - before[name] for name in tiers} == {
-        name: (rendered if name == tier else 0) for name in tiers
+    assert {name: after[name] - before[name] for name in TIERS} == {
+        name: (2 if name == tier else 0) for name in TIERS
     }
+
+
+@pytest.mark.parametrize("tier", ["masked", "region"])
+def test_a_program_is_counted_when_it_is_built_and_not_per_frame(
+    tier, tmp_path, interpreted_kernels
+):
+    """render_compiles_total rises on the renderer factory's cache miss: once
+    for a shape it has not seen, never again for that shape's later frames,
+    once more for another shape."""
+    import dataclasses
+
+    from tpu_render_cluster.obs import render_compile_counter
+    from tpu_render_cluster.render.integrator import (
+        fused_frame_renderer,
+        fused_region_renderer,
+    )
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    fused_frame_renderer.cache_clear()
+    fused_region_renderer.cache_clear()
+    counter = render_compile_counter()
+    tile = TIERS[tier]["tile"]
+    job = make_job("04_very-simple_steps", 4)
+    if tile is not None:
+        job = dataclasses.replace(job, tile_grid=(2, 2))
+
+    def frames(width: int) -> float:
+        backend = TpuRaytraceBackend(
+            base_directory=tmp_path, width=width, height=32, samples=1, max_bounces=2,
+        )
+        before = counter.value()
+        for frame in (1, 2, 3):
+            backend._render_sync(job, frame, tile)
+        return counter.value() - before
+
+    assert frames(32) == 1  # three frames, one program
+    assert frames(32) == 0  # a second backend of the same shape: the same program
+    assert frames(48) == 1  # another shape, another program
 
 
 @pytest.mark.parametrize("scene,launches", [("03_physics-2-mesh", BOUNCES), ("04_very-simple", 0)])
@@ -365,28 +374,24 @@ def test_the_one_program_tier_reports_the_occupancy_of_its_bounce_launches(
 ):
     """A deep mesh frame is one launch per bounce, each at the width the
     program picked from its live count: live counts and widths come back
-    with the image (still one sync) and feed the series the other two tiers
-    feed for their launches — launched lanes are the sum of the widths, so
-    live / launched lies above what the frame's full width would give. A
+    with the image (still one sync) and feed the three launch series —
+    launched lanes are the sum of the widths, so live / launched lies above
+    what the frame's full width would give. A
     scene whose program launches no per-bounce kernel feeds nothing."""
-    from tpu_render_cluster.render.compaction import launch_occupancy_histogram
     from tpu_render_cluster.render.integrator import fused_frame_renderer
-    from tpu_render_cluster.render.raypool import (
-        pool_launched_lanes_counter,
-        pool_live_lanes_counter,
-    )
     from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
 
     def read() -> tuple[float, float, float, float]:
-        occupancy = launch_occupancy_histogram().series()
+        occupancy = TpuRaytraceBackend._launch_occupancy_histogram().series()
         return (
             occupancy.count if occupancy else 0, occupancy.sum if occupancy else 0.0,
-            pool_launched_lanes_counter().value(), pool_live_lanes_counter().value(),
+            TpuRaytraceBackend._launched_lanes_counter().value(),
+            TpuRaytraceBackend._live_lanes_counter().value(),
         )
 
     backend = TpuRaytraceBackend(
         base_directory=tmp_path, width=32, height=32, samples=2,
-        max_bounces=BOUNCES, **TIERS["masked"],
+        max_bounces=BOUNCES,
     )
     job = make_job(f"{scene}_steps", 4)
     before = read()
@@ -432,7 +437,7 @@ def test_the_tier_counter_is_exposed_at_zero_before_any_frame(monkeypatch):
     monkeypatch.setattr(obs, "_global_registry", MetricsRegistry())
     TpuRaytraceBackend(width=8, height=8, samples=1, max_bounces=2)
     text = render_prometheus(obs.get_registry().snapshot())  # refuses a name that fails the lint
-    for tier in ("masked", "wavefront", "raypool"):
+    for tier in ("masked", "region", "sharded"):
         assert f'render_tier_frames_total{{tier="{tier}"}} 0' in text
     assert lint_metric("render_tier_frames_total", "counter", ("tier",)) == []
 
@@ -644,9 +649,10 @@ def test_a_backend_without_steps_feeds_the_phases_alone():
 
 
 def test_the_event_buffer_holds_the_sources_largest_job_on_one_worker():
-    """14,400 frames, each with four phase spans, four flow steps and the
-    raypool's nine step segments, fit under the default cap."""
-    per_frame = 4 + 4 + 9
+    """14,400 frames, each with four phase spans, four flow steps and a
+    frame's seven step segments (`file_write` twice, round `encode`), fit
+    under the default cap."""
+    per_frame = 4 + 4 + 7
     assert tracer_module.MAX_EVENTS >= 14_400 * per_frame
     assert Tracer("worker-full")._max_events == tracer_module.MAX_EVENTS
 

@@ -1,37 +1,40 @@
-"""Which execution tier the tpu-raytrace backend chooses.
+"""Which program the tpu-raytrace backend runs for a work unit.
 
-Under `auto` every scene renders in the one-program tier, whatever the
-queue holds; the wavefront and raypool drivers run only when forced. The
-choice never builds the scene's mesh set on the host.
+There is one way to render; the backend chooses the program's shape from
+what it can observe: a tile unit is a `region`, a whole frame is `sharded`
+where the worker shards across its local mesh and `masked` everywhere
+else. Neither the scene nor what else the worker's queue holds changes
+that, and the choice never builds the scene's mesh set on the host.
 """
 
 from __future__ import annotations
 
+import asyncio
+import dataclasses
+
 import pytest
 
-from tests.test_steps import make_job
+from tests.test_steps import SenderStub, make_job
 
 SCENES = {
     "sphere": "04_very-simple",
     "shallow": "02_physics-mesh",
     "deep": "03_physics-2-mesh",
 }
-FLAGS = {"unset": None, "off": "off", "force": "force"}
+TIERS = ("masked", "region", "sharded")
 
 
 @pytest.fixture
 def routed(monkeypatch):
-    """Pallas on, no tier variable, the three tiers' renderers replaced by
-    recorders, and every host-side build of a mesh set counted."""
+    """Pallas on, the three renderer factories replaced by recorders, and
+    every host-side build of a mesh set counted."""
     import jax.numpy as jnp
 
-    from tpu_render_cluster.render import compaction, integrator, mesh, raypool
+    from tpu_render_cluster.parallel import sharded_render
+    from tpu_render_cluster.render import integrator, mesh
 
     monkeypatch.setenv("TRC_PALLAS", "1")
-    monkeypatch.delenv("TRC_WAVEFRONT", raising=False)
-    monkeypatch.delenv("TRC_RAYPOOL", raising=False)
     seen = {"rendered": [], "mesh_sets": 0}
-    image = jnp.zeros((8, 8, 3), jnp.float32)
 
     def mesh_set(*_args, **_kwargs):
         seen["mesh_sets"] += 1
@@ -42,61 +45,100 @@ def routed(monkeypatch):
             return jnp.zeros((8, 8, 3), jnp.uint8), None  # the image, no live counts
         return render
 
-    def wavefront(*_args, **_kwargs):
-        seen["rendered"].append("wavefront")
-        return image
+    def region(_scene, _width, _height, tile_height, tile_width, *_args, **_kwargs):
+        def render(_frame, _y0, _x0):
+            seen["rendered"].append("region")
+            return jnp.zeros((tile_height, tile_width, 3), jnp.float32)
+        return render
 
-    def pool(_scene, frames, **_kwargs):
-        seen["rendered"].append("raypool")
-        return [image for _ in frames]
+    def sharded(*_args, **_kwargs):
+        def render(_frame):
+            seen["rendered"].append("sharded")
+            return jnp.zeros((8, 8, 3), jnp.float32)
+        return render
 
     monkeypatch.setattr(mesh, "scene_mesh_set", mesh_set)
     monkeypatch.setattr(integrator, "fused_frame_renderer", masked)
-    monkeypatch.setattr(compaction, "render_frame_wavefront", wavefront)
-    monkeypatch.setattr(raypool, "render_batch_raypool", pool)
+    monkeypatch.setattr(integrator, "fused_region_renderer", region)
+    monkeypatch.setattr(sharded_render, "sharded_frame_renderer", sharded)
     return seen
 
 
-def backend_with(flag: str, tmp_path=None):
+def backend_with(tmp_path, sharding=None):
     from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
 
     return TpuRaytraceBackend(
         base_directory=tmp_path, width=8, height=8, samples=1, max_bounces=2,
-        wavefront=FLAGS[flag], raypool=FLAGS[flag],
+        sharding=sharding,
     )
 
 
-@pytest.mark.parametrize("flag", sorted(FLAGS))
+def tier_counts(backend) -> dict[str, float]:
+    return {tier: backend._tier_frames.value(tier=tier) for tier in TIERS}
+
+
+def serve(backend, job, units: list[tuple[int, int | None]]) -> None:
+    """The units through a worker's own queue, all queued before the first
+    is rendered: the later ones wait behind it, as under a batching strategy."""
+    from tpu_render_cluster.obs import MetricsRegistry
+    from tpu_render_cluster.traces.worker_trace import WorkerTraceBuilder
+    from tpu_render_cluster.utils.cancellation import CancellationToken
+    from tpu_render_cluster.worker.queue import WorkerAutomaticQueue
+
+    async def drive():
+        queue = WorkerAutomaticQueue(
+            backend, SenderStub(), WorkerTraceBuilder(), CancellationToken(),
+            metrics=MetricsRegistry(),
+        )
+        for frame, tile in units:
+            queue.queue_frame(job, frame, tile=tile)
+        queue.start()
+        while queue.queue_size():
+            await asyncio.sleep(0.005)
+        await queue.join()
+
+    asyncio.run(drive())
+
+
+@pytest.mark.parametrize("unit", ["frame", "tile"])
 @pytest.mark.parametrize("frames_ahead", [0, 4])
 @pytest.mark.parametrize("scene", sorted(SCENES))
-def test_the_tier_follows_the_flag_and_never_the_scene_or_the_queue(
-    scene, frames_ahead, flag, routed
+def test_the_tier_follows_the_unit_and_never_the_scene_or_the_queue(
+    scene, frames_ahead, unit, routed, tmp_path
 ):
-    backend = backend_with(flag)
-    forced = flag == "force"
-    assert backend._use_raypool(SCENES[scene], frames_ahead) is forced
-    assert backend._use_wavefront(SCENES[scene]) is forced
-    backend.warm(SCENES[scene])
-    # a forced worker warms both drivers; any other warms the one program
-    assert routed["rendered"] == (["raypool", "wavefront"] if forced else ["masked"])
-    assert routed["mesh_sets"] == 0
-
-
-@pytest.mark.parametrize("scene", sorted(SCENES))
-def test_under_auto_a_queued_frame_renders_in_the_one_program_tier(
-    scene, routed, tmp_path
-):
-    """Four frames queued ahead of a deep scene used to engage the pool."""
-    backend = backend_with("unset", tmp_path)
+    backend = backend_with(tmp_path)
     job = make_job(f"{SCENES[scene]}_routing", 8)
-    counter = backend._tier_frames
-    before = {tier: counter.value(tier=tier) for tier in ("masked", "wavefront", "raypool")}
-    for frame in (1, 2):
-        backend.note_upcoming_frames(job, tuple(range(frame + 1, frame + 5)))
-        backend._render_sync(job, frame)
-    after = {tier: counter.value(tier=tier) for tier in before}
-    assert after["masked"] - before["masked"] == 2
-    assert after["wavefront"] == before["wavefront"] and after["raypool"] == before["raypool"]
-    assert routed["rendered"] == ["masked", "masked"]
-    assert routed["mesh_sets"] == 0 and not backend._raypool_cache
-    assert len(list((tmp_path / "out").iterdir())) == 2
+    tile = None
+    if unit == "tile":
+        job, tile = dataclasses.replace(job, tile_grid=(2, 2)), 1
+    expected = "region" if unit == "tile" else "masked"
+    backend.warm(SCENES[scene])  # the whole-frame program, whatever units follow
+    assert routed["rendered"] == ["masked"]
+    before = tier_counts(backend)
+    units = [(frame, tile) for frame in range(1, frames_ahead + 2)]
+    serve(backend, job, units)
+    assert routed["rendered"][1:] == [expected] * len(units)
+    after = tier_counts(backend)
+    assert {tier: after[tier] - before[tier] for tier in TIERS} == {
+        tier: (len(units) if tier == expected else 0) for tier in TIERS
+    }
+    assert routed["mesh_sets"] == 0
+    assert len(list((tmp_path / "out").iterdir())) == len(units)
+
+
+@pytest.mark.parametrize("sharding", ["spp", "tile"])
+@pytest.mark.parametrize("scene", sorted(SCENES))
+def test_a_sharding_worker_shards_whole_frames_and_only_those(scene, sharding, routed, tmp_path):
+    """Local tile/spp sharding serves a whole frame; a cluster tile is
+    already sub-frame work and takes the region program there too."""
+    backend = backend_with(tmp_path, sharding=sharding)
+    job = dataclasses.replace(make_job(f"{SCENES[scene]}_routing", 8), tile_grid=(2, 2))
+    backend.warm(SCENES[scene])
+    before = tier_counts(backend)
+    serve(backend, job, [(1, None), (1, 2), (2, None)])
+    assert routed["rendered"] == ["sharded", "sharded", "region", "sharded"]
+    after = tier_counts(backend)
+    assert {tier: after[tier] - before[tier] for tier in TIERS} == {
+        "masked": 0, "region": 1, "sharded": 2,
+    }
+    assert routed["mesh_sets"] == 0

@@ -3,12 +3,10 @@
 Five contract families, all fast and deterministic (tier-1):
 
 1. **Pixel equivalence** — a master-assembled grid of tile renders equals
-   the whole-frame render across all three execution tiers (masked
-   megakernel via the lane_io fused kernel, wavefront, ray pool), on the
-   CPU interpret path with TRC_PALLAS forced on (the same idiom as
-   tests/test_wavefront.py). Wavefront/raypool are BITWISE; the masked
-   tier is compared at the uint8 output level against the production
-   fused whole-frame renderer.
+   the whole-frame render (sphere megakernel via the lane_io fused
+   kernel, both mesh scenes via the per-bounce kernel), on the CPU
+   interpret path with TRC_PALLAS forced on, compared at the uint8
+   output level against the production fused whole-frame renderer.
 2. **Assembly exactly-once** — the frame-complete transition fires once
    per frame regardless of duplicate/late copies of the final tile, and
    the stitcher reproduces the frame from tile files (removing them).
@@ -394,7 +392,7 @@ class TestTileStealPreempt:
 
 
 # ---------------------------------------------------------------------------
-# Pixel equivalence across the three execution tiers (Pallas interpret)
+# Pixel equivalence, tiles against the whole frame (Pallas interpret)
 
 
 def _clear_jax_caches():
@@ -420,97 +418,80 @@ def _pallas_interpret(monkeypatch):
 
 SPHERE_KW = dict(width=16, height=16, samples=2, max_bounces=3)
 MESH_KW = dict(width=12, height=12, samples=1, max_bounces=2)
+# 3 does not divide 14 rows: the 3x2 grid has a ragged edge (tiles of 4, 5
+# and 5 rows: two region programs); the other grids compile one.
+RAGGED_MESH_KW = dict(width=16, height=14, samples=1, max_bounces=2)
+
+# The whole frame of a case, kept for the other grids of its scene: the
+# fixture below drops every compiled program between tests.
+_WHOLE_FRAMES: dict[tuple, np.ndarray] = {}
+
+
+def _stitched_and_whole(scene, kw, grid, frame):
+    """The grid's fused-region tiles stitched, and the production
+    whole-frame renderer's output, both as the worker writes them (u8)."""
+    from tpu_render_cluster.render.integrator import (
+        fused_frame_renderer,
+        render_frame_region,
+        tonemap,
+    )
+
+    height, width = kw["height"], kw["width"]
+    key = (scene, frame, *sorted(kw.items()))
+    if key not in _WHOLE_FRAMES:
+        _WHOLE_FRAMES[key] = np.asarray(
+            fused_frame_renderer(
+                scene, width, height, kw["samples"], kw["max_bounces"]
+            )(frame)
+        )
+    whole = _WHOLE_FRAMES[key]
+    stitched = np.zeros_like(whole)
+    for tile in range(grid[0] * grid[1]):
+        y0, x0, th, tw = tile_bounds(tile, grid, width=width, height=height)
+        stitched[y0 : y0 + th, x0 : x0 + tw] = np.asarray(
+            tonemap(
+                render_frame_region(
+                    scene, frame, y0=y0, x0=x0, tile_height=th,
+                    tile_width=tw, width=width, height=height,
+                    samples=kw["samples"], max_bounces=kw["max_bounces"],
+                )
+            )
+        )
+    return stitched, whole
 
 
 class TestTileEquivalence:
+    @pytest.mark.parametrize("grid", [(2, 2), (1, 4), (3, 2)], ids=["2x2", "1x4", "3x2"])
     @pytest.mark.parametrize(
         "scene,kw",
-        [("04_very-simple", SPHERE_KW), ("03_physics-2-mesh", MESH_KW)],
-        ids=["sphere", "deep-mesh"],
+        [
+            ("04_very-simple", SPHERE_KW),
+            ("02_physics-mesh", RAGGED_MESH_KW),
+            ("03_physics-2-mesh", RAGGED_MESH_KW),
+        ],
+        ids=["sphere", "shallow-mesh", "deep-mesh"],
     )
     def test_masked_tier_assembles_identically(
-        self, _pallas_interpret, scene, kw
+        self, _pallas_interpret, scene, kw, grid
     ):
         """Stitched fused-region tiles == the production whole-frame
-        renderer's uint8 output (the worker's masked tier)."""
-        from tpu_render_cluster.render.integrator import (
-            fused_frame_renderer,
-            render_frame_region,
-            tonemap,
-        )
-
-        height, width = kw["height"], kw["width"]
-        whole = np.asarray(
-            fused_frame_renderer(
-                scene, width, height, kw["samples"], kw["max_bounces"]
-            )(30)
-        )
-        stitched = np.zeros_like(whole)
-        for tile in range(4):
-            y0, x0, th, tw = tile_bounds(tile, (2, 2), width=width, height=height)
-            stitched[y0 : y0 + th, x0 : x0 + tw] = np.asarray(
-                tonemap(
-                    render_frame_region(
-                        scene, 30, y0=y0, x0=x0, tile_height=th,
-                        tile_width=tw, width=width, height=height,
-                        samples=kw["samples"], max_bounces=kw["max_bounces"],
-                    )
-                )
-            )
+        renderer's uint8 output, whatever the grid: the region program is
+        the only way a tile is rendered. (The shallow mesh's whole frame is
+        the megakernel and its tiles the per-bounce kernel: same streams.)"""
+        stitched, whole = _stitched_and_whole(scene, kw, grid, 30)
         assert np.array_equal(stitched, whole)
 
-    @pytest.mark.parametrize(
-        "scene,kw",
-        [("04_very-simple", SPHERE_KW), ("03_physics-2-mesh", MESH_KW)],
-        ids=["sphere", "deep-mesh"],
-    )
-    def test_wavefront_tier_assembles_bitwise(
-        self, _pallas_interpret, scene, kw
-    ):
-        from tpu_render_cluster.render.compaction import (
-            render_frame_wavefront,
-            render_region_wavefront,
-        )
-
-        height, width = kw["height"], kw["width"]
-        whole = np.asarray(render_frame_wavefront(scene, 30, **kw))
-        stitched = np.zeros_like(whole)
-        for tile in range(4):
-            y0, x0, th, tw = tile_bounds(tile, (2, 2), width=width, height=height)
-            stitched[y0 : y0 + th, x0 : x0 + tw] = np.asarray(
-                render_region_wavefront(
-                    scene, 30, y0=y0, x0=x0, tile_height=th, tile_width=tw,
-                    **kw,
-                )
-            )
-        assert np.array_equal(stitched, whole)
-
-    def test_raypool_tier_assembles_bitwise_multi_frame(
+    def test_masked_tier_assembles_identically_as_the_scene_settles(
         self, _pallas_interpret
     ):
-        """A tiled pool batch (same tile across frames — the backend's
-        batching shape) scatters back bitwise-identically to the
-        whole-frame pool render, for every frame of the batch."""
-        from tpu_render_cluster.render.raypool import render_batch_raypool
-
-        kw = MESH_KW
-        scene = "03_physics-2-mesh"
-        height, width = kw["height"], kw["width"]
-        frames = [30, 31]
-        wholes = [
-            np.asarray(img)
-            for img in render_batch_raypool(scene, frames, **kw)
-        ]
-        stitched = [np.zeros_like(w) for w in wholes]
-        for tile in range(4):
-            y0, x0, th, tw = tile_bounds(tile, (2, 2), width=width, height=height)
-            tiles = render_batch_raypool(
-                scene, frames, region=(y0, x0, th, tw), **kw
+        """One compiled region program per tile shape serves every frame:
+        a falling frame and a settled one of the deep scene, whose bounces
+        run at different rungs of the launch-width ladder."""
+        for frame in (30, 295):
+            stitched, whole = _stitched_and_whole(
+                "03_physics-2-mesh", MESH_KW, (2, 2), frame
             )
-            for i in range(len(frames)):
-                stitched[i][y0 : y0 + th, x0 : x0 + tw] = np.asarray(tiles[i])
-        for whole, out in zip(wholes, stitched):
-            assert np.array_equal(out, whole)
+            assert np.array_equal(stitched, whole), frame
 
 
 # ---------------------------------------------------------------------------

@@ -10,16 +10,15 @@ Contracts pinned here:
    instance fields (one fused bounce = nearest walk + NEE shadow
    any-hits + shading), incl. a degenerate all-overlapping field and a
    1-instance field (which auto-degrades to the flat sweep).
-3. Per-tier image equivalence: masked tier uint8-identical, wavefront
-   and raypool tiers bitwise-identical, TLAS vs flat — per-lane results
-   are instance-visit-order invariant, so the hierarchy may only change
-   packet-cull efficiency, never pixels.
+3. Image equivalence: the frame is uint8-identical TLAS vs flat —
+   per-lane results are instance-visit-order invariant, so the
+   hierarchy may only change packet-cull efficiency, never pixels.
 4. The fused coherence-key epilogue is bit-identical to its XLA twin
    (``mesh_sort_keys``) — the one-derivation contract that lets bounce
    0 key through XLA and bounces 1+ read the kernel's column.
 5. Compile/build bounds: TLAS topologies are memoized per
    (instance count, leaf size) — never rebuilt per frame — and the
-   TLAS kernels add no per-frame compiles over the flat ladder.
+   TLAS kernels add no per-frame compiles.
 
 Interpret mode on CPU is slow, so shapes are tiny (every kernel launch
 still spans real blocks — ray counts pad to BVH_BLOCK_R internally).
@@ -378,7 +377,7 @@ def test_kernel_key_epilogue_matches_xla_twin(monkeypatch):
         assert keys[~live].min() > keys[live].max()
 
 
-# -- per-tier image equivalence ----------------------------------------------
+# -- image equivalence ----------------------------------------------
 
 
 def _masked_uint8(scene_name, use_tlas, **kwargs):
@@ -405,69 +404,33 @@ def test_masked_image_tlas_vs_flat_uint8_identical(monkeypatch, scene_name):
     np.testing.assert_array_equal(flat, tlas)
 
 
-def test_wavefront_image_tlas_vs_flat_bitwise(monkeypatch):
-    from tpu_render_cluster.render.compaction import render_frame_wavefront
-
-    monkeypatch.setenv("TRC_PALLAS", "1")
-    kwargs = dict(width=12, height=12, samples=1, max_bounces=2)
-    flat = np.asarray(
-        render_frame_wavefront(DEEP_SCENE, 30, use_tlas=False, **kwargs)
-    )
-    tlas = np.asarray(
-        render_frame_wavefront(DEEP_SCENE, 30, use_tlas=True, **kwargs)
-    )
-    np.testing.assert_array_equal(flat, tlas)
-
-
-def test_raypool_images_tlas_vs_flat(monkeypatch):
-    """Raypool tier TLAS vs flat: per-lane paths are identical, but the
-    two pool programs are distinct XLA compilations and the whole batch
-    (sort + refill + bounce + scatter) is ONE fused program — CPU XLA's
-    fusion/FMA choices differ between them, leaving ulp-level noise
-    (measured: 2/192 elements off by 6e-8). The bound here is the same
-    2e-6 the existing raypool service-order-independence pin uses; the
-    bitwise TLAS-vs-flat contracts live on the masked/wavefront tiers,
-    where each kernel launch is its own program."""
-    from tpu_render_cluster.render.raypool import render_batch_raypool
-
-    monkeypatch.setenv("TRC_PALLAS", "1")
-    kwargs = dict(
-        width=8, height=8, samples=1, max_bounces=2, pool_width=1024,
-        frame_cap=2,
-    )
-    flat = render_batch_raypool(
-        DEEP_SCENE, [30, 31], use_tlas=False, **kwargs
-    )
-    tlas = render_batch_raypool(
-        DEEP_SCENE, [30, 31], use_tlas=True, **kwargs
-    )
-    for a, b in zip(flat, tlas):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=0, atol=2e-6
-        )
-
-
 # -- compile/build bounds ----------------------------------------------------
 
 
 def test_tlas_adds_no_per_frame_compiles_or_builds(monkeypatch):
-    """Three wavefront frames through the TLAS kernels: every compile
-    key (compact + bounce buckets) and the one TLAS topology build are
+    """Three frames through the TLAS kernels: the one program
+    (render_compiles_total) and the one TLAS topology build are
     first-sighted on frame 1 — frames 2..3 add nothing. The topology is
     memoized per (instance count, leaf size); per-frame work is only
-    the traced bounds refresh inside the already-compiled programs."""
-    from tpu_render_cluster.render import compaction
+    the traced bounds refresh inside the already-compiled program."""
+    from tpu_render_cluster.obs import render_compile_counter
+    from tpu_render_cluster.render.integrator import fused_frame_renderer
     from tpu_render_cluster.render.mesh import tlas_build_counter
-    from tpu_render_cluster.render.compaction import render_frame_wavefront
 
     monkeypatch.setenv("TRC_PALLAS", "1")
-    kwargs = dict(width=8, height=8, samples=1, max_bounces=2)
-    counter = compaction.compile_counter()
+    fused_frame_renderer.cache_clear()
+    counter = render_compile_counter()
     builds = tlas_build_counter()
-    render_frame_wavefront(DEEP_SCENE, 30, use_tlas=True, **kwargs)
-    after_first = counter.value()
-    builds_after_first = builds.value()
+
+    def render(frame):
+        renderer = fused_frame_renderer(DEEP_SCENE, 8, 8, 1, 2, True, with_live=True)
+        np.asarray(renderer(frame)[0])
+
+    before, builds_before = counter.value(), builds.value()
+    render(30)
+    assert counter.value() == before + 1
+    assert builds.value() == builds_before + 1
     for frame in (31, 32):
-        render_frame_wavefront(DEEP_SCENE, frame, use_tlas=True, **kwargs)
-    assert counter.value() == after_first
-    assert builds.value() == builds_after_first
+        render(frame)
+    assert counter.value() == before + 1
+    assert builds.value() == builds_before + 1

@@ -242,179 +242,70 @@ def _consume_metric_snapshots(
         take_registry(registry)
 
 
-def summarize_wavefront(metrics: list[dict[str, Any]]) -> dict[str, Any] | None:
-    """Roll the wavefront occupancy series (render/compaction.py) up.
+def summarize_launch_occupancy(metrics: list[dict[str, Any]]) -> dict[str, Any] | None:
+    """Roll up the bounce-launch series of deep mesh frames (fed by
+    worker/backends/tpu_raytrace.py): ``render_launch_occupancy`` (per
+    launch, live rays / the width the program ran it at) and the lane
+    counters ``render_pool_launched_lanes_total`` /
+    ``render_pool_live_lanes_total``.
 
-    Extracts ``render_alive_fraction`` (per-bounce survival histogram),
-    ``render_lane_occupancy`` (live/launch-width gauge) and
-    ``render_compiles_total`` (bucket-ladder compile counter) from
-    metrics snapshots — both shapes the snapshot families carry:
-    registry-snapshot form (the snapshot's own ``metrics`` and the
-    harness's per-worker ``workers``) and the compact heartbeat wire
-    form (the master CLI's merged ``cluster_metrics``, consumed only
-    when no per-worker registry snapshots are present, so nothing is
-    double-counted). None when no snapshot carries the series (job
-    never rendered wavefront-style).
+    Both shapes the snapshot families carry: registry-snapshot form (the
+    snapshot's own ``metrics``, the harness's per-worker ``workers``, the
+    newest ``process_metrics`` per pid) and the compact heartbeat wire
+    form (the master CLI's merged ``cluster_metrics``, consumed only when
+    no registry snapshot covered that file, so nothing is counted twice).
+    None when no deep frame was rendered.
     """
-    found = False
-    alive_count = 0
-    alive_sum = 0.0
-    by_bounce: dict[str, dict[str, float]] = {}
-    occupancy: float | None = None
-    compiles = 0.0
-
-    def take_alive(label: str, count: int, total: float) -> None:
-        nonlocal found, alive_count, alive_sum
-        found = True
-        alive_count += count
-        alive_sum += total
-        entry = by_bounce.setdefault(label, {"count": 0, "sum": 0.0})
-        entry["count"] += count
-        entry["sum"] += total
-
-    def take_registry(names: dict[str, Any]) -> bool:
-        nonlocal found, occupancy, compiles
-        took = False
-        histogram = names.get("render_alive_fraction")
-        if histogram:
-            took = True
-            for label, series in histogram.get("series", {}).items():
-                take_alive(
-                    label,
-                    int(series.get("count", 0)),
-                    float(series.get("sum", 0.0)),
-                )
-        gauge = names.get("render_lane_occupancy")
-        if gauge and gauge.get("series"):
-            found = took = True
-            occupancy = float(list(gauge["series"].values())[-1])
-        counter = names.get("render_compiles_total")
-        if counter:
-            found = took = True
-            compiles += sum(float(v) for v in counter.get("series", {}).values())
-        return took
-
-    def take_wire(wire: dict[str, Any]) -> None:
-        nonlocal found, occupancy, compiles
-        for key, entry in (wire.get("h") or {}).items():
-            name, _, label = key.partition("|")
-            if name == "render_alive_fraction":
-                take_alive(label, int(entry.get("n", 0)), float(entry.get("s", 0.0)))
-        for key, value in (wire.get("g") or {}).items():
-            if key.partition("|")[0] == "render_lane_occupancy":
-                found = True
-                occupancy = float(value)
-        for key, value in (wire.get("c") or {}).items():
-            if key.partition("|")[0] == "render_compiles_total":
-                found = True
-                compiles += float(value)
-
-    _consume_metric_snapshots(metrics, take_registry, take_wire)
-    if not found:
-        return None
-    out: dict[str, Any] = {"compiles_total": compiles}
-    if occupancy is not None:
-        out["lane_occupancy_last"] = occupancy
-    if alive_count:
-        out["wasted_lane_fraction"] = 1.0 - alive_sum / alive_count
-        out["alive_fraction_mean_by_bounce"] = {
-            label: entry["sum"] / entry["count"]
-            for label, entry in sorted(by_bounce.items())
-            if entry["count"]
-        }
-    return out
-
-
-def summarize_raypool(metrics: list[dict[str, Any]]) -> dict[str, Any] | None:
-    """Roll the device ray-pool series (render/raypool.py) up.
-
-    Extracts ``render_pool_live_fraction`` (per-iteration pool
-    occupancy histogram — its complement is the raypool
-    wasted_lane_fraction), ``render_pool_occupancy`` (last batch's mean
-    gauge), the refill/iteration counters, and the worker backend's
-    rendered-ahead ``render_raypool_cache_hits_total``. Same snapshot-
-    family handling as summarize_wavefront: registry-snapshot form
-    first (newest per pid for the cumulative process_metrics), compact
-    wire form only when no registry snapshot covered that file. None
-    when no snapshot carries the series (job never used the pool). The
-    two lane counters alone do not count as the pool: the one-program
-    tier feeds them too for a deep mesh frame.
-    """
-    shared = ("render_pool_launched_lanes_total", "render_pool_live_lanes_total")
-    found = False
-    live_count = 0
-    live_sum = 0.0
-    occupancy: float | None = None
-    counters = {
-        "render_pool_refill_rays_total": 0.0,
-        "render_pool_iterations_total": 0.0,
-        "render_raypool_cache_hits_total": 0.0,
+    launches = 0
+    occupancy_sum = 0.0
+    lanes = {
         "render_pool_launched_lanes_total": 0.0,
         "render_pool_live_lanes_total": 0.0,
     }
 
     def take_registry(names: dict[str, Any]) -> bool:
-        nonlocal found, live_count, live_sum, occupancy
+        nonlocal launches, occupancy_sum
         took = False
-        histogram = names.get("render_pool_live_fraction")
+        histogram = names.get("render_launch_occupancy")
         if histogram:
-            found = took = True
+            took = True
             for series in histogram.get("series", {}).values():
-                live_count += int(series.get("count", 0))
-                live_sum += float(series.get("sum", 0.0))
-        gauge = names.get("render_pool_occupancy")
-        if gauge and gauge.get("series"):
-            found = took = True
-            occupancy = float(list(gauge["series"].values())[-1])
-        for name in counters:
+                launches += int(series.get("count", 0))
+                occupancy_sum += float(series.get("sum", 0.0))
+        for name in lanes:
             counter = names.get(name)
             if counter:
                 took = True
-                found = found or name not in shared
-                counters[name] += sum(
+                lanes[name] += sum(
                     float(v) for v in counter.get("series", {}).values()
                 )
         return took
 
     def take_wire(wire: dict[str, Any]) -> None:
-        nonlocal found, live_count, live_sum, occupancy
+        nonlocal launches, occupancy_sum
         for key, entry in (wire.get("h") or {}).items():
-            if key.partition("|")[0] == "render_pool_live_fraction":
-                found = True
-                live_count += int(entry.get("n", 0))
-                live_sum += float(entry.get("s", 0.0))
-        for key, value in (wire.get("g") or {}).items():
-            if key.partition("|")[0] == "render_pool_occupancy":
-                found = True
-                occupancy = float(value)
+            if key.partition("|")[0] == "render_launch_occupancy":
+                launches += int(entry.get("n", 0))
+                occupancy_sum += float(entry.get("s", 0.0))
         for key, value in (wire.get("c") or {}).items():
             name = key.partition("|")[0]
-            if name in counters:
-                found = found or name not in shared
-                counters[name] += float(value)
+            if name in lanes:
+                lanes[name] += float(value)
 
     _consume_metric_snapshots(metrics, take_registry, take_wire)
-    if not found:
+    launched = lanes["render_pool_launched_lanes_total"]
+    if not launches or launched <= 0:
         return None
-    out: dict[str, Any] = {
-        "refill_rays_total": counters["render_pool_refill_rays_total"],
-        "iterations_total": counters["render_pool_iterations_total"],
-        "cache_hits_total": counters["render_raypool_cache_hits_total"],
+    live = lanes["render_pool_live_lanes_total"]
+    return {
+        "launches": launches,
+        # Per launch: every bounce weighs the same, however narrow.
+        "launch_occupancy_mean": occupancy_sum / launches,
+        "launched_lanes_total": launched,
+        "live_lanes_total": live,
+        # Lane-weighted: what share of the launched lanes carried a ray.
+        "live_lane_share": live / launched,
     }
-    if occupancy is not None:
-        out["pool_occupancy_last_batch"] = occupancy
-    launched = counters["render_pool_launched_lanes_total"]
-    if launched > 0:
-        # Lane-weighted (the true launched-lane fraction; the per-
-        # iteration histogram below would overweight the drain tail's
-        # tiny launches).
-        live_lanes = counters["render_pool_live_lanes_total"]
-        out["wasted_lane_fraction"] = 1.0 - live_lanes / launched
-        out["pool_occupancy_mean"] = live_lanes / launched
-    elif live_count:
-        out["wasted_lane_fraction"] = 1.0 - live_sum / live_count
-        out["pool_occupancy_mean"] = live_sum / live_count
-    return out
 
 
 def summarize_sched(metrics: list[dict[str, Any]]) -> dict[str, Any] | None:
@@ -1161,12 +1052,9 @@ def summarize_obs(
         "spans_by_category": span_counts,
         "span_duration_stats": span_stats,
     }
-    wavefront = summarize_wavefront(metrics)
-    if wavefront is not None:
-        out["wavefront"] = wavefront
-    raypool = summarize_raypool(metrics)
-    if raypool is not None:
-        out["raypool"] = raypool
+    launch_occupancy = summarize_launch_occupancy(metrics)
+    if launch_occupancy is not None:
+        out["launch_occupancy"] = launch_occupancy
     chaos = summarize_chaos(metrics)
     if chaos is not None:
         out["chaos"] = chaos

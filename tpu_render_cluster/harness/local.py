@@ -252,19 +252,12 @@ def save_obs_artifacts(
     clock-offset estimates, pids deduplicated, with flow arrows linking
     every frame's assign span to its worker phases and result span.
     """
-    from tpu_render_cluster.obs import get_registry, get_tracer
+    from tpu_render_cluster.obs import get_registry
 
-    # The process-global tracer rides along: render-path spans (e.g. the
-    # wavefront driver's per-bounce wavefront_bounce spans with live
-    # count / bucket / alive-fraction args) land in the same Perfetto
-    # file as the master/worker rows. It is process-scoped and the
-    # harness runs many jobs per process, so drain it after the export —
-    # otherwise job N's file would re-export jobs 1..N-1's render spans.
     trace_path = export_chrome_trace(
         prefix_path.with_name(prefix_path.name + "_trace-events.json"),
-        [manager.span_tracer] + [w.span_tracer for w in workers] + [get_tracer()],
+        [manager.span_tracer] + [w.span_tracer for w in workers],
     )
-    get_tracer().clear()
     # The merged causal timeline goes through the same collection path a
     # multi-host master uses (span events shipped on job-finished, offsets
     # from the heartbeat estimator) — in-process the offsets are near zero,
@@ -287,13 +280,13 @@ def save_obs_artifacts(
                 [w.metrics.to_wire() for w in workers]
             ),
             # Harness workers run with fresh per-run registries, but the
-            # RENDER path (backend phase histograms, the wavefront
-            # driver's occupancy series) reports into the process-global
+            # RENDER path (the backend's tier counter and launch
+            # occupancy series) reports into the process-global
             # registry — snapshot it too or those series never reach the
             # artifact. Process-scoped and CUMULATIVE across runs in one
             # harness process, so it is tagged with the pid: consumers
-            # (analysis/obs_events.summarize_wavefront) keep only the
-            # newest snapshot per pid instead of summing every file's
+            # (analysis/obs_events.summarize_launch_occupancy) keep only
+            # the newest snapshot per pid instead of summing every file's
             # copy of the same counters.
             "process_metrics": {
                 "pid": os.getpid(),

@@ -8,8 +8,8 @@ values are threaded into jit identities as STATIC arguments, renderer
 cache keys, and geometry-build memo keys. Reading one of their tier
 helpers from inside a traced function would bake the first trace's
 environment into the executable — the toggle-mid-process staleness bug
-the resolved-outside contract (integrator.resolve_bvh_config and the
-driver-level reads) exists to prevent, and exactly what lets the
+the resolved-outside contract (integrator.resolve_bvh_config) exists
+to prevent, and exactly what lets the
 interleaved ``bench.py --bvh-compare`` hold every variant in one
 process.
 
@@ -18,14 +18,13 @@ This pass finds the traced functions with the same static analysis as
 ``shard_map``, factory-returned closures) and flags any call to a
 declared tier-reader helper inside one. Like ``jit-purity``, the scan
 is BODY-LOCAL — a tier read buried one plain-function call below a
-traced def is not reachable statically, so the drivers additionally
-thread the resolved values as explicit (static) arguments all the way
-down (``tlas_block``/``quant``/``builder``/``wide`` parameters on the
-bounce/pool drivers); the pass catches the direct regressions, the
-threading convention covers the rest. Helpers that are *dispatch*
-tiers read per call by documented design (``pallas_enabled``,
-``wavefront_mode``, ``raypool_mode``) are not in the set — they select
-a driver, not a compiled program's static configuration.
+traced def is not reachable statically, so the renderer factories
+additionally thread the resolved values as explicit (static) arguments
+all the way down (``use_tlas``/``quant``/``builder``/``wide``
+parameters); the pass catches the direct regressions, the threading
+convention covers the rest. ``pallas_enabled``, a *dispatch* tier read
+per call by documented design, is not in the set — it selects a code
+path, not a compiled program's static configuration.
 """
 
 from __future__ import annotations
@@ -75,7 +74,7 @@ class _TierCallScanner(ast.NodeVisitor):
                     f"traced function {self.qualname!r} reads the static "
                     f"jit-arg env tier via {name}() — the value would be "
                     "baked at first trace; resolve it in the untraced "
-                    "driver/factory (integrator.resolve_bvh_config) and "
+                    "renderer factory (integrator.resolve_bvh_config) and "
                     "thread it in as a static argument",
                 )
             )

@@ -11,8 +11,8 @@
   the master clock by the estimated offsets, pids deduplicated.
 - ``validate`` — trace-invariant checker backing scripts/validate_trace.py.
 
-``get_registry()`` / ``get_tracer()`` return the process-global instances
-used by process-scoped subsystems (the render path, ``ops/assignment``,
+``get_registry()`` returns the process-global registry used by
+process-scoped subsystems (the render path, ``ops/assignment``,
 bench.py). Cluster components that can be colocated in one process (the
 harness runs a master and N workers on one loop) create their OWN
 instances so per-component views stay separable.
@@ -74,10 +74,10 @@ __all__ = [
     "export_cluster_trace",
     "frame_steps",
     "get_registry",
-    "get_tracer",
     "log_buckets",
     "merge_timeline",
     "merge_wire",
+    "render_compile_counter",
     "render_fps_gauge",
     "resolve_flight_directory",
     "step",
@@ -88,17 +88,11 @@ __all__ = [
 ]
 
 _global_registry = MetricsRegistry()
-_global_tracer = Tracer("process", pid=0)
 
 
 def get_registry() -> MetricsRegistry:
     """The process-global registry (render path, ops, bench)."""
     return _global_registry
-
-
-def get_tracer() -> Tracer:
-    """The process-global tracer."""
-    return _global_tracer
 
 
 def render_fps_gauge(registry: MetricsRegistry | None = None) -> Gauge:
@@ -111,4 +105,16 @@ def render_fps_gauge(registry: MetricsRegistry | None = None) -> Gauge:
     return registry.gauge(
         "render_frames_per_second",
         "Instantaneous device throughput (1 / execute_seconds)",
+    )
+
+
+def render_compile_counter(registry: MetricsRegistry | None = None) -> Counter:
+    """Render programs built by this process: the renderer factories
+    (render/integrator.py, parallel/sharded_render.py) count the miss of
+    their own ``lru_cache``, so it grows with configs, not frames."""
+    registry = registry if registry is not None else get_registry()
+    return registry.counter(
+        "render_compiles_total",
+        "Render programs built (first sighting of a scene/shape/config by "
+        "a renderer factory) — grows with configs, not frames",
     )

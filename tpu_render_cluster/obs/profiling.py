@@ -67,9 +67,9 @@ def bvh_dims(
 ) -> dict:
     """The BVH node-format dims every mesh-kernel key carries.
 
-    One definition site (like ``kernel_key``) so the masked, region,
-    wavefront, and raypool capture sites can never attribute two node
-    formats to one roofline row: a distinct (tlas, quant, builder, wide)
+    One definition site (like ``kernel_key``) so the masked and region
+    capture sites can never attribute two node formats to one roofline
+    row: a distinct (tlas, quant, builder, wide)
     is a distinct kernel identity — exactly the set of knobs that change
     the compiled program (``TRC_TLAS``/``TRC_BVH_QUANT``/
     ``TRC_BVH_BUILDER``/``TRC_BVH_WIDE``).

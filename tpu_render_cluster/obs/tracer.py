@@ -37,14 +37,12 @@ _pid_counter = itertools.count(1)
 # eating the process's heap; once full, the NEWEST events are dropped (and
 # counted). Sized so that the source's largest job on ONE worker drops
 # nothing: 14400 frames x (4 phase spans + 4 flow steps + 6 step segments)
-# is 201,600 events, 9 segments a frame under the raypool would be 244,800,
-# and a wavefront frame's ~22 (a dispatch and a device_wait segment per
-# bounce) stays far below either at that tier's frame counts.
+# is 201,600 events.
 MAX_EVENTS = 300_000
 
-# The steps of one frame on the worker's render thread, the same in every
-# execution tier. At every instant of a frame exactly one is open:
-#   resolve      scene name, tile region, tier choice, compiled-renderer fetch
+# The steps of one frame on the worker's render thread, the same for every
+# unit shape. At every instant of a frame exactly one is open:
+#   resolve      scene name, tile region, unit shape, compiled-renderer fetch
 #   dispatch     host time issuing device work that does not block (asking
 #                for the copy back included)
 #   device_wait  host blocked until the device has a result

@@ -16,11 +16,8 @@ work (the SP/DP analogs called for by SURVEY.md §2.7 / §5.7):
   throughput for animation). This is a separate function, not a
   ``render_frame_sharded`` mode, because its unit of work is a batch.
 
-Wavefront composition: every mode here traces ``render_tile`` under
-``shard_map``, so the HOST-DRIVEN wavefront driver (per-bounce device
-sync + dynamically shrinking launch widths; render/compaction.py) cannot
-run inside it. What composes instead is the IN-JIT half of compaction:
-per shard, the integrator's deep-scene bounce loop sorts its OWN rays
+Dead rays: every mode here traces ``render_tile`` under ``shard_map``.
+Per shard, the integrator's deep-scene bounce loop sorts its OWN rays
 dead-to-tail and hands the bounce kernel a live-count scalar, so each
 device skips its all-dead tail blocks with static shapes — no
 cross-device coordination, no recompiles, works under tile bands, spp
@@ -29,6 +26,8 @@ rays are spatially coherent, so their live sets collapse together.)
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -48,37 +47,33 @@ def _shard_map(fn, mesh, in_specs, out_specs):
     )
 
 
-def render_frame_sharded(
+@functools.lru_cache(maxsize=16)
+def _sharded_frame_program(
     scene_name: str,
-    frame_index: int,
-    *,
-    width: int = 512,
-    height: int = 512,
-    samples: int = 8,
-    max_bounces: int = 4,
-    mode: str = "tile",
-    n_devices: int | None = None,
-) -> jnp.ndarray:
-    """Render one frame across the local mesh; returns [H, W, 3] linear."""
+    width: int,
+    height: int,
+    samples: int,
+    max_bounces: int,
+    mode: str,
+    n_devices: int | None,
+    quant: int,
+):
+    """The jitted shard_map program of one scene/config, ``(scene,
+    camera, mesh set, frame) -> image``; built once (the cache miss is
+    the compile ``render_compiles_total`` counts — ``scene_name`` is in
+    the key because each scene's arrays have their own shapes, so each
+    compiles its own program)."""
+    from tpu_render_cluster.obs import render_compile_counter
+
     mesh = device_mesh(n_devices)
     n = mesh.devices.size
-    scene = build_scene(scene_name, frame_index)
-    camera = scene_camera(scene_name, frame_index)
-    from tpu_render_cluster.render.integrator import resolve_bvh_config
-    from tpu_render_cluster.render.mesh import scene_mesh_set
-
-    # BVH env tiers resolve HERE (untraced) and ride the traced closures
-    # as captured statics — the env-tiers contract.
-    _tlas, bvh_quant, bvh_builder, bvh_wide = resolve_bvh_config()
-    mesh_set = scene_mesh_set(scene_name, frame_index, bvh_builder, bvh_wide)
-    frame = jnp.asarray(frame_index, jnp.float32)
 
     if mode == "tile":
         if height % n != 0:
             raise ValueError(f"height {height} not divisible by {n} devices.")
         rows_per_device = height // n
 
-        def render_band(scene, camera, frame):
+        def render_shard(scene, camera, mesh_set, frame):
             band_index = jax.lax.axis_index("d")
             y0 = band_index * rows_per_device
             return render_tile(
@@ -94,23 +89,16 @@ def render_frame_sharded(
                 samples=samples,
                 max_bounces=max_bounces,
                 mesh=mesh_set,
-                quant=bvh_quant,
+                quant=quant,
             )
 
-        sharded = _shard_map(
-            render_band,
-            mesh=mesh,
-            in_specs=(P(), P(), P()),
-            out_specs=P("d", None, None),
-        )
-        return sharded(scene, camera, frame)
-
-    if mode == "spp":
+        out_specs = P("d", None, None)
+    elif mode == "spp":
         if samples % n != 0:
             raise ValueError(f"samples {samples} not divisible by {n} devices.")
         samples_per_device = samples // n
 
-        def render_subset(scene, camera, frame):
+        def render_shard(scene, camera, mesh_set, frame):
             device_index = jax.lax.axis_index("d")
             # Decorrelate through the frame ingredient of the RNG key:
             # scene and camera are built outside, so in render_tile the
@@ -129,19 +117,78 @@ def render_frame_sharded(
                 samples=samples_per_device,
                 max_bounces=max_bounces,
                 mesh=mesh_set,
-                quant=bvh_quant,
+                quant=quant,
             )
             return jax.lax.psum(image, "d") / n
 
-        sharded = _shard_map(
-            render_subset,
-            mesh=mesh,
-            in_specs=(P(), P(), P()),
-            out_specs=P(),
-        )
-        return sharded(scene, camera, frame)
+        out_specs = P()
+    else:
+        raise ValueError(f"Unknown sharding mode: {mode!r}")
 
-    raise ValueError(f"Unknown sharding mode: {mode!r}")
+    render_compile_counter().inc()
+    return jax.jit(
+        _shard_map(
+            render_shard,
+            mesh=mesh,
+            in_specs=(P(), P(), P(), P()),
+            out_specs=out_specs,
+        )
+    )
+
+
+def sharded_frame_renderer(
+    scene_name: str,
+    width: int,
+    height: int,
+    samples: int,
+    max_bounces: int,
+    mode: str,
+    n_devices: int | None = None,
+):
+    """A ``frame_index -> [H, W, 3] linear`` closure that renders one
+    frame across the local mesh: one compiled program per config, fed
+    the frame's scene, camera and mesh set."""
+    from tpu_render_cluster.render.integrator import resolve_bvh_config
+    from tpu_render_cluster.render.mesh import scene_mesh_set
+
+    # BVH env tiers resolve HERE (untraced): the node format keys the
+    # cached program, the build picks the tree it is handed — the
+    # env-tiers contract.
+    _tlas, quant, builder, wide = resolve_bvh_config()
+    program = _sharded_frame_program(
+        scene_name, width, height, samples, max_bounces, mode, n_devices,
+        quant,
+    )
+
+    def render(frame_index):
+        return program(
+            build_scene(scene_name, frame_index),
+            scene_camera(scene_name, frame_index),
+            scene_mesh_set(scene_name, frame_index, builder, wide),
+            jnp.asarray(frame_index, jnp.float32),
+        )
+
+    return render
+
+
+sharded_frame_renderer.cache_clear = _sharded_frame_program.cache_clear
+
+
+def render_frame_sharded(
+    scene_name: str,
+    frame_index: int,
+    *,
+    width: int = 512,
+    height: int = 512,
+    samples: int = 8,
+    max_bounces: int = 4,
+    mode: str = "tile",
+    n_devices: int | None = None,
+) -> jnp.ndarray:
+    """Render one frame across the local mesh; returns [H, W, 3] linear."""
+    return sharded_frame_renderer(
+        scene_name, width, height, samples, max_bounces, mode, n_devices
+    )(frame_index)
 
 
 def render_frames_batched(

@@ -19,7 +19,7 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument(
         "--obj",
         default=None,
-        help="render this Wavefront OBJ on a turntable stage instead of a "
+        help="render this .obj mesh file on a turntable stage instead of a "
         "named procedural scene (normalized to stage scale; rotates with "
         "--frame)",
     )

@@ -264,11 +264,8 @@ def flat_sample_rays(
 ):
     """All samples' rays flattened onto the ray axis ([S * n, 3] x 2).
 
-    ONE definition shared by render_tile's flattened branch and the
-    wavefront driver (render/compaction._frame_rays): the masked-vs-
-    wavefront equivalence rests on both tracing byte-identical rays with
-    byte-identical RNG derivation, so the key schedule must not be able
-    to drift between them.
+    What render_tile traces for a tile; ``region_rays_and_seed`` derives
+    the same rays for a region of the full frame.
     """
     n = tile_height * tile_width
     sample_keys = jax.vmap(lambda s: jax.random.fold_in(base_key, s))(
@@ -281,24 +278,6 @@ def flat_sample_rays(
         )
     )(sample_keys)
     return origins.reshape(samples * n, 3), directions.reshape(samples * n, 3)
-
-
-def frame_rays_and_seed(camera: Camera, frame, *, width, height, samples):
-    """A full frame's flattened primary rays + its kernel trace seed.
-
-    ONE definition (built on tile_base_key / flat_sample_rays /
-    tile_trace_key / trace_seed) shared by the masked renderer's
-    full-frame tile, the wavefront driver (compaction._frame_rays), and
-    the ray-pool driver's vmapped multi-frame batch — all three provably
-    trace the same physical rays with the same RNG derivation, so the
-    cross-mode equivalence contracts cannot drift.
-    """
-    base_key = tile_base_key(frame, 0, 0)
-    origins, directions = flat_sample_rays(
-        camera, base_key, width=width, height=height, y0=0, x0=0,
-        tile_height=height, tile_width=width, samples=samples,
-    )
-    return origins, directions, trace_seed(tile_trace_key(base_key))
 
 
 def region_pixel_indices(*, y0, x0, tile_height, tile_width, width):
@@ -319,9 +298,7 @@ def region_lane_map(
     """Local region-ray index -> FULL-frame lane id ([samples*th*tw] int32).
 
     THE lane-layout definition (sample-major over row-major pixels:
-    ``s*H*W + y*W + x``) the cross-tier tiled-equals-untiled contract
-    rests on — shared by ``region_rays_and_seed`` and the ray pool's
-    region glane map so the two cannot drift.
+    ``s*H*W + y*W + x``) the tiled-equals-untiled contract rests on.
     """
     pix = region_pixel_indices(
         y0=y0, x0=x0, tile_height=tile_height, tile_width=tile_width,
@@ -340,7 +317,7 @@ def region_rays_and_seed(
     """One REGION's rows of the full frame's flattened primary rays, plus
     their GLOBAL lane ids and the frame's kernel trace seed.
 
-    The cluster-tiling counterpart of ``frame_rays_and_seed``: instead of
+    The cluster-tiling counterpart of ``flat_sample_rays``: instead of
     deriving a fresh RNG root from the tile coordinates (what
     ``render_tile(y0, x0)`` does — a different image per tiling), the
     region inherits the FULL frame's derivation. Per sample the whole
@@ -351,8 +328,7 @@ def region_rays_and_seed(
     streams on. Tracing these rays with these lane ids reproduces the
     whole-frame render's radiance at the region's pixels exactly, which
     is what makes a master-assembled tiled frame pixel-identical to the
-    untiled render (tests/test_tiles.py pins it across all three
-    execution tiers).
+    untiled render (tests/test_tiles.py pins it).
 
     ``y0``/``x0`` may be traced scalars (one compiled region program per
     tile SHAPE serves every tile position and frame).
@@ -502,8 +478,7 @@ def _trace_paths_deep(
             # (behavior-preserving — dead lanes pass through a masked
             # bounce unchanged anyway). The carried ORIGINAL lane id is
             # the RNG counter, so a ray's stream survives every
-            # permutation (and composes with the wavefront driver's
-            # compaction, which shares this kernel).
+            # permutation.
             alive = jnp.arange(width, dtype=jnp.int32) < state["live"]
         with jax.named_scope("bounce"):
             contribution, origins, directions, throughput, alive, keys = launch(
@@ -553,8 +528,7 @@ def _trace_paths_deep(
         if tlas:
             # Bounce 0 has no kernel-emitted key column yet: derive the
             # initial keys through the XLA twin of the kernels' fused
-            # epilogue, via the SAME shared site the wavefront driver
-            # uses (bit-identical derivation, pinned by
+            # epilogue (bit-identical derivation, pinned by
             # tests/test_tlas.py). Later bounces read the key column the
             # bounce kernel wrote while the state was still VMEM-resident.
             keys = pallas_kernels.initial_mesh_sort_keys(
@@ -604,12 +578,13 @@ def trace_paths(
     ``rng_lanes`` (optional [R] int32) overrides the RNG counter per ray:
     the region render path (cluster tiling) passes each ray's FULL-frame
     lane id so a cropped trace reproduces the whole-frame streams. Only
-    meaningful on the Pallas paths — with it set, sphere and
-    megakernel-eligible mesh scenes route through the per-bounce state-io
-    kernels (which accept explicit lane ids; per-lane streams match the
-    megakernels', pinned by tests/test_wavefront.py), and the XLA
-    fallback ignores it (shape-derived RNG cannot be cropped — region
-    renders there are statistically, not bitwise, consistent).
+    meaningful on the Pallas paths — with it set, a sphere scene's
+    megakernel reads its RNG counters from the lane row, every mesh
+    scene routes through the per-bounce kernel (which accepts explicit
+    lane ids; per-lane streams match the megakernels', pinned by
+    tests/test_tiles.py), and the XLA fallback ignores it (shape-derived
+    RNG cannot be cropped — region renders there are statistically, not
+    bitwise, consistent).
 
     ``use_tlas`` (None = the ``TRC_TLAS`` env tier, default on) selects
     the two-level TLAS kernel variants for mesh scenes. Per-lane results
@@ -623,8 +598,8 @@ def trace_paths(
     only, each bounce launch's (live rays, width) — a traced int32 [2]:
     the count the launch already computes for its tail skip and the rung
     of ``launch_width_ladder`` the program ran the bounce at — so the
-    caller can return them from the same program: the one-program tier's
-    launch occupancy at no extra sync. Other paths launch no per-bounce
+    caller can return them from the same program: the frame's launch
+    occupancy at no extra sync. Other paths launch no per-bounce
     kernel and leave the list empty.
     """
     from tpu_render_cluster.render import pallas_kernels
@@ -890,8 +865,12 @@ def _fused_frame_renderer(
     wide: int,
     with_live: bool = False,
 ):
+    from tpu_render_cluster.obs import render_compile_counter
     from tpu_render_cluster.render.camera import scene_camera
     from tpu_render_cluster.render.scene import build_scene
+
+    # This body runs on the lru_cache's miss only: one program built.
+    render_compile_counter().inc()
 
     @jax.jit
     def render(frame: jnp.ndarray) -> jnp.ndarray:
@@ -1001,8 +980,11 @@ def _fused_region_renderer(
     builder: str,
     wide: int,
 ):
+    from tpu_render_cluster.obs import render_compile_counter
     from tpu_render_cluster.render.camera import scene_camera
     from tpu_render_cluster.render.scene import build_scene
+
+    render_compile_counter().inc()
 
     @jax.jit
     def render(frame: jnp.ndarray, y0, x0) -> jnp.ndarray:
@@ -1075,7 +1057,7 @@ def fused_region_renderer(
 ):
     """A jitted ``(frame, y0, x0) -> [th, tw, 3] LINEAR`` region closure.
 
-    The masked execution tier's cluster-tile path: one compiled program
+    The cluster-tile path: one compiled program
     per tile SHAPE (``y0``/``x0`` are traced), so every tile of a grid —
     and every frame — reuses the same executable. The region traces the
     full frame's rays-and-RNG restricted to its pixels

@@ -438,8 +438,8 @@ def build_bvh(
     # ensure_compile_time_eval: the first build may happen INSIDE a jit
     # trace (fused_frame_renderer -> scene_mesh_set -> cached_mesh_bvh),
     # where bare jnp.asarray would return trace-local tracers — which the
-    # lru_cache would then hand to later EAGER callers (the wavefront
-    # driver) as leaked tracers. This forces concrete, cache-safe arrays
+    # lru_cache would then hand to later EAGER callers (render_frame,
+    # the sharded renderer) as leaked tracers. This forces concrete, cache-safe arrays
     # regardless of the first caller's context.
     with jax.ensure_compile_time_eval():
         if octant_tables is None and builder == "sah":
@@ -471,10 +471,10 @@ def build_bvh(
 # Process-wide geometry-build memo: host-side BVH/TLAS builds keyed by
 # every parameter that shapes the result — (kind, leaf_size) for BLAS
 # builds, (k_count, tlas_leaf_size) for TLAS topologies — so the test
-# suite and the bucket-ladder recompiles never rebuild a hierarchy they
-# have already built this process. An explicit dict (not lru_cache) so
-# tests can reset it: tests/conftest.py wires ``reset_geometry_cache``
-# into the autouse fixture alongside ``compaction.reset_compile_tracking``.
+# suite and a renderer rebuilt for another shape never rebuild a
+# hierarchy they have already built this process. An explicit dict (not
+# lru_cache) so tests can reset it: tests/conftest.py wires
+# ``reset_geometry_cache`` into its autouse fixture.
 _geometry_cache: dict[tuple, object] = {}
 
 

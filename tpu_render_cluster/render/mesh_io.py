@@ -1,4 +1,4 @@
-"""Wavefront OBJ ingest for the TPU mesh path.
+"""OBJ (.obj) mesh ingest for the TPU mesh path.
 
 The reference's workers render arbitrary user content by shelling out to
 Blender (reference: worker/src/rendering/runner/mod.rs:165-176 — whatever

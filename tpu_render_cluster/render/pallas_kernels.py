@@ -25,7 +25,6 @@ path is exercised by CPU tests.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
 from tpu_render_cluster.utils.env import env_int, env_str
 
 import jax
@@ -102,47 +101,6 @@ def _dot_f32(a, b, dimension_numbers):
     )
 
 
-# Ray block for the per-bounce STATE-IO sphere kernel (the wavefront
-# driver's sphere bounce). Deliberately smaller than BLOCK_R: the block
-# size is also the bucket quantum of the wavefront compacted relaunch
-# (render/compaction.py), so a 4096 block would make compaction a no-op
-# below 4096 live rays; 1024 matches BVH_BLOCK_R's granularity.
-SPHERE_BOUNCE_BLOCK_R = 1024
-
-
-def wavefront_mode() -> str:
-    """The ``TRC_WAVEFRONT`` env tier: ``off`` / ``auto`` / ``force``.
-
-    - unset (``auto``): never — every scene renders in the one-program
-      tier. On the chip the deep mesh scene's settled frames read 1.368
-      frames/s there against 0.856 under this driver, which costs five
-      host syncs and an eager scene build per frame (builder's runs of
-      ``03ph2mesh-1w-queued``, PERF.md §6, PR 27; the ledger's PR 25
-      line of ``03ph2mesh-1w-fine`` has this driver at 0.8415);
-    - ``TRC_WAVEFRONT=0`` (also ``false``/``off``): never;
-    - ``TRC_WAVEFRONT=1`` (anything else truthy): force it for every
-      Pallas-rendered scene, spheres included.
-
-    Like ``TRC_PALLAS`` this is read when the dispatch decision is made
-    (the wavefront driver runs outside jit, so per-frame, not per-trace).
-    The decision itself is ``render/compaction.wavefront_active``, the
-    single dispatch site for the env tier and a backend's override.
-    """
-    value = (env_str("TRC_WAVEFRONT") or "").strip().lower()
-    if value in ("", "auto"):
-        return "auto"
-    if value in ("0", "false", "off", "no"):
-        return "off"
-    return "force"
-
-
-def tier_forced(mode: str) -> bool:
-    """Whether an execution tier's mode — ``wavefront_mode()`` /
-    ``raypool_mode()`` or a backend's flag — turns its driver on. Only
-    ``force`` (any spelling that is neither ``auto`` nor ``off``) does."""
-    return str(mode).lower() not in ("auto", "", "0", "false", "off", "no")
-
-
 def tlas_enabled() -> bool:
     """Whether the mesh kernels traverse a two-level TLAS/BLAS hierarchy
     (default) or the flat per-instance sweep (``TRC_TLAS=0`` — the A/B
@@ -150,9 +108,9 @@ def tlas_enabled() -> bool:
     triage kill switch).
 
     Read at *trace* time like ``TRC_PALLAS``: jitted renderers bake the
-    decision, and the drivers additionally thread it as a static jit
-    argument so both kernel variants can coexist in one process (the
-    interleaved A/B bench relies on that).
+    decision, and the renderer factories additionally thread it as a
+    static jit argument so both kernel variants can coexist in one
+    process (the interleaved A/B bench relies on that).
     """
     value = env_str("TRC_TLAS")
     if value is None:
@@ -181,9 +139,9 @@ def tlas_block_r() -> int:
     and prune nothing (0.95x vs flat), 512 -> ~1.7x, 256 -> ~2x,
     128 -> ~2.3x but with more per-block overhead headroom on chip —
     256 is the default; re-tune on chip via the env knob. Snapped to a
-    power of two in [128, BVH_BLOCK_R] so it always divides the pool
-    width / bucket quanta the drivers round to, and read at trace time
-    like the other TLAS knobs (part of each compiled kernel's shape).
+    power of two in [128, BVH_BLOCK_R] so it always divides the rungs
+    of integrator.launch_width_ladder, and read at trace time like the
+    other TLAS knobs (part of each compiled kernel's shape).
     """
     raw = env_int("TRC_TLAS_BLOCK", 256)
     block = 128
@@ -204,19 +162,17 @@ def use_tlas_for(k_count: int, use_tlas: bool | None = None) -> bool:
 
 def bvh_quant_mode() -> int:
     """The ``TRC_BVH_QUANT`` env tier (default 0 = off): quantized node
-    tables + packed carried ray state.
+    tables.
 
-    - 0: fp32 slabs, int32 links, f32 carried state (the exact baseline);
+    - 0: fp32 slabs, int32 links (the exact baseline);
     - 1: 16-bit fixed-point slabs (two per int32 word) + one packed meta
-      word per node, bf16-packed carried throughput;
-    - 2: 8-bit slabs (six per two words), same meta/state packing.
+      word per node;
+    - 2: 8-bit slabs (six per two words), same meta packing.
 
     Conservative outward rounding keeps every tier's IMAGES bit-identical
-    on the masked tier (the quantized walk visits a superset of nodes;
-    triangle tests stay exact f32 — see mesh.quantize_node_tables);
-    wavefront/raypool additionally carry bf16 throughput, whose
-    divergence budget tests/test_bvhq.py asserts. A static jit arg like
-    ``TRC_TLAS``: read by untraced drivers/factories only (the
+    (the quantized walk visits a superset of nodes; triangle tests stay
+    exact f32 — see mesh.quantize_node_tables). A static jit arg like
+    ``TRC_TLAS``: read by untraced renderer factories only (the
     ``env-tiers`` lint pass pins this) and threaded into every kernel
     identity, so distinct tiers coexist as distinct compiled programs in
     one process (the interleaved A/B bench).
@@ -255,14 +211,15 @@ def resolve_bvh_quant(quant: int, *tables: tuple[int, int, int]) -> int:
 # ---------------------------------------------------------------------------
 # Fused coherence sort key (ISSUE 10): the per-bounce re-sort key is
 # computed in the mesh bounce kernels' EPILOGUE from the post-bounce ray
-# state — one extra [1, BR] int32 output row — so the TLAS drivers'
+# state — one extra [1, BR] int32 output row — so the deep path's
 # re-sort is a single argsort over a precomputed column instead of a
 # separate XLA pass (candidate broadphase + quantization + dilation)
 # over the full ray state. Layout (LSB -> MSB): direction octant [0:3),
 # 5-bit/axis Morton cell of origin+direction [3:18), first-overlap
 # candidate instance [18:24) (6 bits, clamped — packets that want the
 # SAME instance first walk straight to its leaf and seed tight best-t),
-# frame id [24:29) (pool kernels only; 0 elsewhere), dead flag bit 29.
+# frame id [24:29) (always 0: no caller mixes frames in one launch;
+# ROADMAP D2), dead flag bit 29.
 # Always < 2^30, so the uint32 bit pattern bitcasts to a POSITIVE int32
 # and a plain ascending argsort orders it exactly like the uint32 would.
 
@@ -331,7 +288,7 @@ def mesh_sort_keys(
     origins, directions, alive, key_lo, key_inv, fid=None, candidate=None,
 ):
     """XLA twin of the kernel epilogue's key ([R] int32): the INITIAL
-    keys of a wavefront/deep-path/pool launch, before any bounce kernel
+    keys of a deep-path launch, before any bounce kernel
     has run to produce the fused column. ``candidate`` (optional [R]
     int32) is the nearest-entry overlapped instance from
     ``instance_entry_candidates``; None packs a neutral 0 (grouping by
@@ -354,12 +311,10 @@ def mesh_sort_keys(
 def initial_mesh_sort_keys(mesh, origins, directions, alive):
     """Bounce-0 coherence keys for a TLAS launch, derived from the
     MeshSet: instance world AABBs -> quantization window + nearest-entry
-    candidates -> ``mesh_sort_keys``. THE one site both the deep
-    per-bounce path (integrator.trace_paths) and the wavefront driver
-    (compaction._initial_mesh_keys) key bounce 0 through, so the two
-    tiers' initial sorts cannot drift from each other or from the kernel
-    epilogue's fused column (bit-identical on live lanes, pinned by
-    tests/test_tlas.py)."""
+    candidates -> ``mesh_sort_keys``. The site the deep per-bounce path
+    (integrator.trace_paths) keys bounce 0 through; it cannot drift from
+    the kernel epilogue's fused column (bit-identical on live lanes,
+    pinned by tests/test_tlas.py)."""
     from tpu_render_cluster.render.mesh import instance_morton_order
 
     table = _instance_table(
@@ -377,68 +332,6 @@ def initial_mesh_sort_keys(mesh, origins, directions, alive):
     return mesh_sort_keys(
         origins, directions, alive, key_lo, key_inv,
         candidate=instance_entry_candidates(origins, directions, lo_s, hi_s),
-    )
-
-
-# ---------------------------------------------------------------------------
-# Packed carried ray state (ISSUE 15, quant tiers >= 1): the wavefront
-# driver re-buckets and the ray pool permutes the FULL carried tuple every
-# bounce/iteration — the throughput column is pure shading state with no
-# traversal role, so it rides as bf16 packed two-per-f32-word (12 -> 8
-# carried bytes per lane, one fewer gather column). The pack/unpack pair
-# must be exact inverses; the f32->bf16 round-trip per carry step is the
-# divergence the masked-vs-packed budget in tests/test_bvhq.py bounds.
-
-
-def pack_throughput_bf16(throughput):
-    """[R, 3] f32 -> [R, 2] f32 words carrying 4 bf16 lanes (one pad).
-
-    The pad lane is 1.0, not 0: it is the HIGH half of the second word,
-    and a word whose high half is zero is a denormal f32, which the TPU
-    flushes to zero as the word rides the drivers' float gathers. With a
-    zero pad, wavefront/raypool frames under TRC_BVH_QUANT>=1 came out a
-    third darker on the chip (mean 104 vs 160, interpret mode unaffected);
-    with 1.0 they match the masked frame there. The first word has the
-    same weakness only where green is exactly 0.
-    """
-    half = jnp.concatenate(
-        [
-            throughput.astype(jnp.bfloat16),
-            jnp.ones((throughput.shape[0], 1), jnp.bfloat16),
-        ],
-        axis=1,
-    )
-    return jax.lax.bitcast_convert_type(
-        half.reshape(-1, 2, 2), jnp.float32
-    )
-
-
-def unpack_throughput_bf16(packed):
-    """Inverse of ``pack_throughput_bf16``: [R, 2] f32 -> [R, 3] f32."""
-    half = jax.lax.bitcast_convert_type(packed, jnp.bfloat16)
-    return half.reshape(packed.shape[0], 4)[:, :3].astype(jnp.float32)
-
-
-# Pool meta word (quant tiers >= 1): fid [0:8), bounce [8:16), dead bit 16
-# — one int32 column replacing the pool's separate alive/fid/bounce
-# carried columns (the alive column is DROPPED: it is the meta dead bit).
-POOL_META_DEAD_BIT = 16
-
-
-def pack_pool_meta(fid, bounce, alive):
-    return (
-        fid.astype(jnp.int32)
-        | (bounce.astype(jnp.int32) << 8)
-        | jnp.where(alive, 0, 1 << POOL_META_DEAD_BIT)
-    )
-
-
-def unpack_pool_meta(meta):
-    """(fid, bounce, alive) from the packed pool meta column."""
-    return (
-        meta & 0xFF,
-        (meta >> 8) & 0xFF,
-        (meta >> POOL_META_DEAD_BIT) & 1 == 0,
     )
 
 
@@ -638,48 +531,15 @@ def _uniform_from_hash(h):
 
 
 def _trace_kernel_factory(
-    max_bounces: int, n_padded: int, state_io: bool = False,
-    pool_io: bool = False, lane_io: bool = False,
+    max_bounces: int, n_padded: int, lane_io: bool = False,
 ):
-    """Sphere path-trace kernel. Three shapes share one bounce_step (same
-    split as _mesh_trace_kernel_factory):
-
-    - state_io=False: the whole-bounce-loop MEGAKERNEL (state
-      VMEM-resident across all bounces, radiance out);
-    - state_io=True: ONE bounce per launch with path state streamed
-      in/out plus a per-lane ORIGINAL lane id (the RNG counter, so
-      streams survive compaction/re-sorting) and a live-count scalar
-      (blocks whose first lane is past it — all dead by the compaction
-      contract — skip the bounce entirely). ``max_bounces`` still names
-      the TOTAL bounce count so RNG counters match the megakernel.
-    - pool_io=True: the device-resident ray-pool shape
-      (render/raypool.py). Like state_io but lanes from DIFFERENT
-      frames share one launch, so the scalar seed/bounce become
-      per-lane rows (seed = the lane's frame seed, bounce = the lane's
-      own depth — together with the original lane id they reproduce the
-      masked loop's (frame, lane, bounce) RNG stream exactly), the
-      sphere arrays are a multi-frame STACK with a per-sphere frame-id
-      column, and every intersection (nearest + shadow) is masked to
-      spheres whose frame id matches the lane's carried frame id — a
-      lane only ever sees its own frame's geometry.
-    """
+    """Sphere path-trace MEGAKERNEL: the whole bounce loop in one launch,
+    state VMEM-resident across all bounces, radiance out. ``lane_io``
+    adds a per-lane ORIGINAL lane id row as the RNG counter's source."""
     contract_first = (((0,), (0,)), ((), ()))
 
     def kernel(*refs):
-        if pool_io:
-            (live_ref, o_ref, d_ref, thr_ref, alive_ref, lane_ref,
-             seed_row_ref, bounce_row_ref, fid_row_ref,
-             c_ref, r2_ref, csq_ref, rad_ref,
-             albedo_ref, emission_ref, dcsun_ref, sfid_ref, params_ref,
-             out_ref, o_out_ref, d_out_ref, thr_out_ref,
-             alive_out_ref) = refs
-        elif state_io:
-            (seed_ref, bounce_ref, live_ref, o_ref, d_ref, thr_ref,
-             alive_ref, lane_ref, c_ref, r2_ref, csq_ref, rad_ref,
-             albedo_ref, emission_ref, dcsun_ref, params_ref,
-             out_ref, o_out_ref, d_out_ref, thr_out_ref,
-             alive_out_ref) = refs
-        elif lane_io:
+        if lane_io:
             # The megakernel with an EXPLICIT lane row: the cluster-tile
             # region path feeds each ray its full-frame lane id, so a
             # cropped launch runs bitwise-identical per-lane math to the
@@ -712,31 +572,18 @@ def _trace_kernel_factory(
         plane_b = params[5:6, :].T
 
         block = o.shape[1]
-        if pool_io:
-            # Per-lane seed: lanes carry their FRAME's trace seed, so a
-            # ray's stream matches the masked single-frame loop bit for
-            # bit wherever the pool's permutation/refill lands it.
-            seed = seed_row_ref[:, :].astype(jnp.uint32)  # [1, BR]
+        seed = seed_ref[0, 0].astype(jnp.uint32)
+        if lane_io:
+            # RNG counters follow the region path's full-frame lane map,
+            # not the current position.
             ray_index = lane_ref[:, :].astype(jnp.uint32)
-            # Frame mask: a lane only intersects spheres whose stacked
-            # frame id matches its own ([N, 1] == [1, BR] -> [N, BR]).
-            fid_match = sfid_ref[:, :] == fid_row_ref[:, :]
         else:
-            seed = seed_ref[0, 0].astype(jnp.uint32)
-            fid_match = None
-            if state_io or lane_io:
-                # RNG counters follow the ORIGINAL lane id the caller
-                # threads through compaction/re-sorts (or the region
-                # path's full-frame lane map), not the current position:
-                # a ray keeps its stream wherever it lands.
-                ray_index = lane_ref[:, :].astype(jnp.uint32)
-            else:
-                ray_index = (
-                    jax.lax.broadcasted_iota(
-                        jnp.int32, (1, block), 1
-                    ).astype(jnp.uint32)
-                    + jnp.uint32(pl.program_id(0) * block)
-                )
+            ray_index = (
+                jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block), 1
+                ).astype(jnp.uint32)
+                + jnp.uint32(pl.program_id(0) * block)
+            )
         sphere_iota = jax.lax.broadcasted_iota(jnp.int32, (n_padded, block), 0)
 
         throughput = jnp.ones((3, block), jnp.float32)
@@ -754,8 +601,6 @@ def _trace_kernel_factory(
             oc_sq = o_sq - 2.0 * oc + csq
             disc = oc_dot_d * oc_dot_d - (oc_sq - r2)
             valid = (disc > 0.0) & (r2 > 0.0)
-            if fid_match is not None:
-                valid = valid & fid_match
             sqrt_disc = jnp.sqrt(jnp.maximum(disc, 0.0))
             t0 = oc_dot_d - sqrt_disc
             t1 = oc_dot_d + sqrt_disc
@@ -829,8 +674,6 @@ def _trace_kernel_factory(
             ocsq_s = osq_s - 2.0 * oc_s + csq
             disc_s = ocd_s * ocd_s - (ocsq_s - r2)
             valid_s = (disc_s > 0.0) & (r2 > 0.0)
-            if fid_match is not None:
-                valid_s = valid_s & fid_match
             t1_s = ocd_s + jnp.sqrt(jnp.maximum(disc_s, 0.0))
             shadowed = jnp.max(
                 jnp.where(valid_s & (t1_s > EPS), 1.0, 0.0),
@@ -878,38 +721,11 @@ def _trace_kernel_factory(
             d = jnp.where(live, new_d, d)
             return (o, d, throughput, radiance, alive)
 
-        if state_io or pool_io:
-            # ONE bounce with streamed state. Blocks entirely past the
-            # live count are all-dead (the compaction contract sorts dead
-            # lanes to the tail) and pass their state through untouched —
-            # exactly what the masked bounce computes for dead lanes, for
-            # free. In pool mode the bounce index is a per-lane row (the
-            # pool mixes depths); it only feeds the RNG counter, which is
-            # per-lane arithmetic either way.
-            throughput = thr_ref[:, :]
-            alive = alive_ref[:, :]
-            bounce_value = (
-                bounce_row_ref[:, :] if pool_io else bounce_ref[0, 0]
-            )
-            block_start = pl.program_id(0) * block
-            o, d, throughput, radiance, alive = jax.lax.cond(
-                block_start < live_ref[0, 0],
-                lambda: bounce_step(
-                    bounce_value, (o, d, throughput, radiance, alive)
-                ),
-                lambda: (o, d, throughput, radiance, alive),
-            )
-            out_ref[:, :] = radiance
-            o_out_ref[:, :] = o
-            d_out_ref[:, :] = d
-            thr_out_ref[:, :] = throughput
-            alive_out_ref[:, :] = alive
-        else:
-            _, _, _, radiance, _ = jax.lax.fori_loop(
-                0, max_bounces, bounce_step,
-                (o, d, throughput, radiance, alive),
-            )
-            out_ref[:, :] = radiance
+        _, _, _, radiance, _ = jax.lax.fori_loop(
+            0, max_bounces, bounce_step,
+            (o, d, throughput, radiance, alive),
+        )
+        out_ref[:, :] = radiance
 
     return kernel
 
@@ -1014,126 +830,6 @@ def trace_paths_fused(
         max_bounces=max_bounces,
         interpret=_interpret(),
         lane=lane,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("total_bounces", "interpret"))
-def _sphere_bounce(
-    origins, directions, throughput, alive, lane, live_count, seed, bounce,
-    centers, radii, albedo, emission,
-    sun_direction, sun_color, sky_horizon, sky_zenith,
-    plane_albedo_a, plane_albedo_b,
-    *, total_bounces: int, interpret: bool,
-):
-    rays = origins.shape[0]
-    block = SPHERE_BOUNCE_BLOCK_R
-    padded_rays = -(-rays // block) * block
-    ray_pad = padded_rays - rays
-    # Zero pad is fine here (unlike the BVH kernels): the sphere pass has
-    # no cross-lane packet culling, and pad lanes arrive DEAD (alive pad
-    # 0) so their garbage t never reaches an output.
-    o_t = jnp.pad(origins, ((0, ray_pad), (0, 0))).T
-    d_t = jnp.pad(directions, ((0, ray_pad), (0, 0))).T
-    thr_t = jnp.pad(throughput, ((0, ray_pad), (0, 0))).T
-    alive_t = jnp.pad(alive.astype(jnp.float32), (0, ray_pad))[None, :]
-    lane_t = jnp.pad(lane.astype(jnp.int32), (0, ray_pad))[None, :]
-
-    n = centers.shape[0]
-    padded_n = -(-n // _SUBLANE) * _SUBLANE
-    sphere_pad = padded_n - n
-    c_t = jnp.pad(centers, ((0, sphere_pad), (0, 0))).T
-    radii_p = jnp.pad(radii, (0, sphere_pad))
-    r2 = (radii_p * radii_p)[:, None]
-    csq = jnp.sum(c_t * c_t, axis=0)[:, None]
-    rad = radii_p[:, None]
-    albedo_t = jnp.pad(albedo, ((0, sphere_pad), (0, 0))).T
-    emission_t = jnp.pad(emission, ((0, sphere_pad), (0, 0))).T
-    dc_sun = _center_dot_sun(c_t, sun_direction)
-
-    params = jnp.zeros((8, 3), jnp.float32)
-    params = params.at[0].set(sun_direction)
-    params = params.at[1].set(sun_color)
-    params = params.at[2].set(sky_horizon)
-    params = params.at[3].set(sky_zenith)
-    params = params.at[4].set(plane_albedo_a)
-    params = params.at[5].set(plane_albedo_b)
-    seed_arr = jnp.asarray(seed, jnp.int32).reshape(1, 1)
-    bounce_arr = jnp.asarray(bounce, jnp.int32).reshape(1, 1)
-    live_arr = jnp.asarray(live_count, jnp.int32).reshape(1, 1)
-
-    grid = (padded_rays // block,)
-    whole = lambda i: (0, 0)  # noqa: E731
-    ray_block = pl.BlockSpec((3, block), lambda i: (0, i), memory_space=pltpu.VMEM)
-    row_block = pl.BlockSpec((1, block), lambda i: (0, i), memory_space=pltpu.VMEM)
-    contrib, o2, d2, thr2, alive2 = pl.pallas_call(
-        _trace_kernel_factory(total_bounces, padded_n, state_io=True),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM),
-            ray_block,
-            ray_block,
-            ray_block,
-            row_block,
-            row_block,
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((8, 3), whole, memory_space=pltpu.VMEM),
-        ],
-        out_specs=[ray_block, ray_block, ray_block, ray_block, row_block],
-        out_shape=[
-            jax.ShapeDtypeStruct((3, padded_rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, padded_rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, padded_rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, padded_rays), jnp.float32),
-            jax.ShapeDtypeStruct((1, padded_rays), jnp.float32),
-        ],
-        interpret=interpret,
-    )(seed_arr, bounce_arr, live_arr, o_t, d_t, thr_t, alive_t, lane_t,
-      c_t, r2, csq, rad, albedo_t, emission_t, dc_sun, params)
-    return (
-        contrib.T[:rays],
-        o2.T[:rays],
-        d2.T[:rays],
-        thr2.T[:rays],
-        alive2[0, :rays] > 0.5,
-    )
-
-
-def sphere_bounce_pallas(
-    scene, origins, directions, throughput, alive, seed, bounce,
-    *, total_bounces: int, lane=None, live_count=None,
-):
-    """One fused path-trace bounce for sphere-only scenes.
-
-    The sphere megakernel's bounce_step as a single launch with path
-    state streamed in/out — the sphere twin of ``mesh_bounce_pallas``,
-    built for the wavefront driver (render/compaction.py): ``lane``
-    carries each ray's ORIGINAL lane id (the RNG counter, so streams
-    survive compaction) and ``live_count`` lets blocks entirely inside
-    the compacted dead tail skip the bounce. Defaults reproduce the
-    megakernel's full-width behavior (positional lanes, nothing
-    skipped). Returns (radiance contribution [R, 3], new origins, new
-    directions, new throughput, new alive).
-    """
-    n = origins.shape[0]
-    if lane is None:
-        lane = jnp.arange(n, dtype=jnp.int32)
-    if live_count is None:
-        live_count = jnp.int32(n)
-    return _sphere_bounce(
-        origins, directions, throughput, alive, lane, live_count, seed,
-        bounce,
-        scene.centers, scene.radii, scene.albedo, scene.emission,
-        scene.sun_direction, scene.sun_color, scene.sky_horizon,
-        scene.sky_zenith, scene.plane_albedo_a, scene.plane_albedo_b,
-        total_bounces=total_bounces, interpret=_interpret(),
     )
 
 
@@ -2007,12 +1703,11 @@ def _bvh_anyhit_instanced(
 
 def _mesh_trace_kernel_factory(
     max_bounces: int, n_padded: int, n_nodes: int, leaf_size: int,
-    k_count: int, state_io: bool = False, pool_io: bool = False,
-    k_per_frame: int = 0, use_tlas: bool = False, tlas_nodes: int = 0,
-    tlas_per_frame: int = 0, quant: int = 0, ordered: bool = False,
+    k_count: int, state_io: bool = False, use_tlas: bool = False,
+    tlas_nodes: int = 0, quant: int = 0, ordered: bool = False,
     tlas_ordered: bool = False,
 ):
-    """Mesh path-trace kernel. Three shapes share one bounce_step:
+    """Mesh path-trace kernel. Two shapes share one bounce_step:
 
     - state_io=False: the whole-bounce-loop MEGAKERNEL (state VMEM-resident
       across all bounces, radiance out) — shallow-walk scenes.
@@ -2023,16 +1718,6 @@ def _mesh_trace_kernel_factory(
       any-hits, shading, in-kernel PCG resample) stays fused — deep-walk
       scenes. ``max_bounces`` still names the TOTAL bounce count so the
       per-(ray, bounce) RNG counters match the megakernel's stream layout.
-    - pool_io=True: the device-resident ray-pool shape
-      (render/raypool.py): per-lane seed/bounce rows (lanes from
-      different frames at different depths share one launch; the
-      carried (frame seed, original lane, bounce) triple reproduces the
-      masked loop's RNG streams), a multi-frame sphere STACK with a
-      per-sphere frame-id column, and a 23rd instance-table column
-      carrying each instance's frame id — lanes whose frame id doesn't
-      match an instance are packet-culled from its walk (their slab
-      limit is -INF) and can neither update best-t nor be shadowed by
-      it, so every lane sees exactly its own frame's geometry.
     """
     contract_first = (((0,), (0,)), ((), ()))
 
@@ -2049,14 +1734,7 @@ def _mesh_trace_kernel_factory(
             out, refs[:n] = tuple(refs[:n]), []
             return out
 
-        if pool_io:
-            (live_ref, o_ref, d_ref, thr_ref, alive_ref, lane_ref,
-             seed_row_ref, bounce_row_ref, fid_row_ref,
-             fid_lo_ref, fid_hi_ref,
-             c_ref, r2_ref, csq_ref, rad_ref, albedo_ref, emission_ref,
-             dcsun_ref, sfid_ref, params_ref, sunsm_ref, inst_ref,
-             v0_ref, e1_ref, e2_ref, nrm_ref) = take(26)
-        elif state_io:
+        if state_io:
             (seed_ref, bounce_ref, live_ref, o_ref, d_ref, thr_ref,
              alive_ref, lane_ref,
              c_ref, r2_ref, csq_ref, rad_ref, albedo_ref, emission_ref,
@@ -2076,11 +1754,11 @@ def _mesh_trace_kernel_factory(
             else:
                 (tbmin_ref, tbmax_ref, tskip_ref, tfirst_ref,
                  tcount_ref) = take(5)
-        if (state_io or pool_io) and use_tlas:
+        if state_io and use_tlas:
             (keysm_ref,) = take(1)
             (out_ref, o_out_ref, d_out_ref, thr_out_ref, alive_out_ref,
              key_out_ref) = refs
-        elif state_io or pool_io:
+        elif state_io:
             (out_ref, o_out_ref, d_out_ref, thr_out_ref,
              alive_out_ref) = refs
         else:
@@ -2232,44 +1910,21 @@ def _mesh_trace_kernel_factory(
         plane_b = params[5:6, :].T
 
         block = o.shape[1]
-        if pool_io:
-            # Per-lane frame seed + frame-id row (see the factory doc).
-            seed = seed_row_ref[:, :].astype(jnp.uint32)  # [1, BR]
+        seed = seed_ref[0, 0].astype(jnp.uint32)
+        if state_io:
+            # RNG counters follow the ORIGINAL lane id the integrator
+            # threads through its re-sorts — a ray keeps its stream
+            # wherever the permutation lands it (the megakernel's
+            # positional index IS the original lane there, since it
+            # never reorders).
             ray_index = lane_ref[:, :].astype(jnp.uint32)
-            fid_row = fid_row_ref[:, :]  # [1, BR] float32 frame ids
-            fid_match = sfid_ref[:, :] == fid_row  # [N, BR]
-            # This block's frame-id RANGE (true scalars, SMEM): the
-            # instance table is FID-MAJOR with exactly k_per_frame rows
-            # per frame, so the in-kernel sweeps iterate only the
-            # contiguous [fid_lo * K, (fid_hi + 1) * K) slice — the
-            # fid-major pool sort makes blocks frame-pure, and the
-            # stacked multi-frame sweep then costs exactly one frame's
-            # instances. Conservative by construction (the range covers
-            # ALL lanes, stale dead ones included): a too-wide window
-            # only walks instances whose matching lanes are dead, and
-            # their -INF limits exit those walks at the first node.
-            fid_lo = fid_lo_ref[0, pl.program_id(0)]
-            fid_hi = fid_hi_ref[0, pl.program_id(0)]
-            k_sweep_lo = fid_lo * k_per_frame
-            k_sweep_hi = jnp.minimum((fid_hi + 1) * k_per_frame, k_count)
         else:
-            seed = seed_ref[0, 0].astype(jnp.uint32)
-            fid_row = None
-            fid_match = None
-            if state_io:
-                # RNG counters follow the ORIGINAL lane id the integrator
-                # / wavefront driver threads through its re-sorts and
-                # compaction — a ray keeps its stream wherever the
-                # permutation lands it (the megakernel's positional index
-                # IS the original lane there, since it never reorders).
-                ray_index = lane_ref[:, :].astype(jnp.uint32)
-            else:
-                ray_index = (
-                    jax.lax.broadcasted_iota(
-                        jnp.int32, (1, block), 1
-                    ).astype(jnp.uint32)
-                    + jnp.uint32(pl.program_id(0) * block)
-                )
+            ray_index = (
+                jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block), 1
+                ).astype(jnp.uint32)
+                + jnp.uint32(pl.program_id(0) * block)
+            )
         sphere_iota = jax.lax.broadcasted_iota(jnp.int32, (n_padded, block), 0)
         lanes = jax.lax.broadcasted_iota(jnp.int32, (leaf_size, block), 0)
 
@@ -2429,36 +2084,20 @@ def _mesh_trace_kernel_factory(
             wix, wiy, wiz = winv(wdx), winv(wdy), winv(wdz)
 
             def per_instance(k, carry):
-                # Pool mode: the sweep bounds below already restrict k to
-                # the block's frame window (the table is fid-major), so
-                # only window instances get here; lanes from the OTHER
-                # frame of a mixed window are packet-culled from this
-                # instance's walk (slab limit -INF, like dead lanes) and
-                # barred from the best-t update.
-                match = (fid_row == inst_ref[k, 22]) if pool_io else None
                 best_t, bnx, bny, bnz, bar, bag, bab, bslot = carry
-                # The winning instance's SLOT label (within-frame in pool
-                # mode): the quant tiers' packed-key candidate — a lane
-                # that hit instance X bounces off X's surface, so X IS
-                # the next ray's nearest-entry overlapped instance.
-                if pool_io:
-                    slot_of_k = k.astype(jnp.float32) - fid_row * jnp.float32(
-                        k_per_frame
-                    )
-                else:
-                    slot_of_k = k.astype(jnp.float32)
+                # The winning instance's SLOT label: the quant tiers'
+                # packed-key candidate — a lane that hit instance X
+                # bounces off X's surface, so X IS the next ray's
+                # nearest-entry overlapped instance.
+                slot_of_k = k.astype(jnp.float32)
                 r00, r01, r02 = inst_ref[k, 0], inst_ref[k, 1], inst_ref[k, 2]
                 r10, r11, r12 = inst_ref[k, 3], inst_ref[k, 4], inst_ref[k, 5]
                 r20, r21, r22 = inst_ref[k, 6], inst_ref[k, 7], inst_ref[k, 8]
                 tx, ty, tz = inst_ref[k, 9], inst_ref[k, 10], inst_ref[k, 11]
                 inv_s = inst_ref[k, 12]
                 ar, ag, ab = inst_ref[k, 19], inst_ref[k, 20], inst_ref[k, 21]
-                limit0 = (
-                    jnp.where(match, best_t, -INF)
-                    if pool_io else best_t
-                )
                 touch = world_cull(
-                    k, wox, woy, woz, wix, wiy, wiz, limit0
+                    k, wox, woy, woz, wix, wiy, wiz, best_t
                 )
 
                 sx, sy, sz = wox - tx, woy - ty, woz - tz
@@ -2477,13 +2116,9 @@ def _mesh_trace_kernel_factory(
                 def body(walk):
                     (node, best_t, bnx, bny, bnz, bar_, bag_, bab_,
                      bslot_) = walk
-                    walk_limit = (
-                        jnp.where(match, best_t, -INF)
-                        if match is not None else best_t
-                    )
                     next_node, start, count, do_leaf = walk_step(
                         node, obase, ox, oy, oz, dx, dy, dz, invx, invy,
-                        invz, walk_limit,
+                        invz, best_t,
                     )
 
                     def leaf_pass():
@@ -2522,11 +2157,6 @@ def _mesh_trace_kernel_factory(
                         do_leaf, leaf_pass, leaf_skip
                     )
                     closer = t_leaf < best_t
-                    if match is not None:
-                        # leaf_tcand is limit-agnostic, so a mismatched
-                        # lane can produce a finite t_leaf off another
-                        # frame's geometry — bar it here.
-                        closer = closer & match
                     # Object -> world (rigid): w_i = sum_j R[i][j] n_j.
                     wnx = r00 * nox + r01 * noy + r02 * noz
                     wny = r10 * nox + r11 * noy + r12 * noz
@@ -2557,7 +2187,7 @@ def _mesh_trace_kernel_factory(
             # Slot sentinel = "no mesh hit": matches the entry walk's
             # no-overlap sentinel, and stays put for dead lanes (their
             # -INF seed admits no update).
-            slot_sentinel = jnp.float32(k_per_frame if pool_io else k_count)
+            slot_sentinel = jnp.float32(k_count)
             init = (
                 seed_t,
                 jnp.zeros((1, block), jnp.float32),
@@ -2575,45 +2205,17 @@ def _mesh_trace_kernel_factory(
                 # subtree's union AABB (or whose per-lane best-t already
                 # beats its entry) jumps the whole subtree — the flat
                 # K-cull sweep this replaces paid every instance every
-                # block. Pool mode walks one frame's node window per
-                # fori step; lanes of OTHER frames in a mixed block are
-                # barred from driving nodes (limit -INF, like dead
-                # lanes) exactly as they are barred from the instances.
-                def tlas_walk_nearest(node0, node_end, frame_match, carry):
-                    limit_of = (
-                        (lambda c: jnp.where(frame_match, c[0], -INF))
-                        if frame_match is not None
-                        else (lambda c: c[0])
-                    )
-                    return tlas_walk(
-                        node0, node_end, tlas_base(wdx, wdy, wdz),
-                        wox, woy, woz, wix, wiy, wiz,
-                        limit_of, per_instance, carry,
-                    )
-
-                if pool_io:
-                    def per_frame(f, carry):
-                        node0 = f * tlas_per_frame
-                        return tlas_walk_nearest(
-                            node0, node0 + tlas_per_frame,
-                            fid_row == f.astype(jnp.float32), carry,
-                        )
-
-                    walked = jax.lax.fori_loop(
-                        fid_lo, fid_hi + 1,
-                        per_frame, init,
-                    )
-                else:
-                    walked = tlas_walk_nearest(
-                        jnp.int32(0), jnp.int32(tlas_nodes), None, init
-                    )
-                best_t, bnx, bny, bnz, bar, bag, bab, bslot = walked
+                # block.
+                best_t, bnx, bny, bnz, bar, bag, bab, bslot = tlas_walk(
+                    jnp.int32(0), jnp.int32(tlas_nodes),
+                    tlas_base(wdx, wdy, wdz),
+                    wox, woy, woz, wix, wiy, wiz,
+                    lambda c: c[0], per_instance, init,
+                )
             else:
                 (best_t, bnx, bny, bnz, bar, bag, bab,
                  bslot) = jax.lax.fori_loop(
-                    k_sweep_lo if pool_io else 0,
-                    k_sweep_hi if pool_io else k_count,
-                    per_instance, init,
+                    0, k_count, per_instance, init,
                 )
             # Flip toward the incoming ray (matches mesh.intersect_instances).
             facing = (
@@ -2644,27 +2246,12 @@ def _mesh_trace_kernel_factory(
             wix, wiy, wiz = winv(sunx), winv(suny), winv(sunz)
 
             def per_instance(k, occluded):
-                # Pool mode: the sweep bounds restrict k to the block's
-                # frame window; a mixed window's other-frame lanes behave
-                # like already-occluded ones for the WALK (limit -INF:
-                # they never drive a packet) and their spurious leaf hits
-                # are masked out of the occlusion result.
-                if pool_io:
-                    match_f = (fid_row == inst_ref[k, 22]).astype(
-                        jnp.float32
-                    )
-                else:
-                    match_f = None
                 r00, r01, r02 = inst_ref[k, 0], inst_ref[k, 1], inst_ref[k, 2]
                 r10, r11, r12 = inst_ref[k, 3], inst_ref[k, 4], inst_ref[k, 5]
                 r20, r21, r22 = inst_ref[k, 6], inst_ref[k, 7], inst_ref[k, 8]
                 tx, ty, tz = inst_ref[k, 9], inst_ref[k, 10], inst_ref[k, 11]
                 inv_s = inst_ref[k, 12]
-                blocked = (
-                    jnp.maximum(occluded, 1.0 - match_f)
-                    if pool_io else occluded
-                )
-                limit = jnp.where(blocked > 0.0, -INF, INF)
+                limit = jnp.where(occluded > 0.0, -INF, INF)
                 touch = world_cull(
                     k, wox, woy, woz, wix, wiy, wiz, limit
                 )
@@ -2687,11 +2274,7 @@ def _mesh_trace_kernel_factory(
                     node, occluded = walk
                     # Occluded lanes stop driving the walk: their packet
                     # limit is -INF so no node can pass their slab test.
-                    walk_blocked = (
-                        jnp.maximum(occluded, 1.0 - match_f)
-                        if match_f is not None else occluded
-                    )
-                    limit = jnp.where(walk_blocked > 0.0, -INF, INF)
+                    limit = jnp.where(occluded > 0.0, -INF, INF)
                     next_node, start, count, do_leaf = walk_step(
                         node, obase, ox, oy, oz, dx, dy, dz, invx, invy,
                         invz, limit,
@@ -2711,8 +2294,6 @@ def _mesh_trace_kernel_factory(
                         ),
                         lambda: jnp.zeros((1, block), jnp.float32),
                     )
-                    if match_f is not None:
-                        occ_add = occ_add * match_f
                     occluded = jnp.maximum(occluded, occ_add)
                     return next_node, occluded
 
@@ -2728,47 +2309,17 @@ def _mesh_trace_kernel_factory(
             if use_tlas:
                 # Same two-level shape as the nearest walk, with the
                 # any-hit limit convention: lanes whose result cannot
-                # matter (pre-occluded, other-frame in pool mode) carry
-                # a -INF limit and never drive a node's packet test.
-                def tlas_walk_occluded(node0, node_end, match_f, occ0):
-                    def limit_of(c):
-                        blocked = (
-                            jnp.maximum(c[0], 1.0 - match_f)
-                            if match_f is not None else c[0]
-                        )
-                        return jnp.where(blocked > 0.0, -INF, INF)
-
-                    return tlas_walk(
-                        node0, node_end, tlas_base(sunx, suny, sunz),
-                        wox, woy, woz, wix, wiy, wiz,
-                        limit_of,
-                        lambda k, c: (per_instance(k, c[0]),),
-                        (occ0,),
-                    )[0]
-
-                if pool_io:
-                    def per_frame(f, occluded):
-                        node0 = f * tlas_per_frame
-                        return tlas_walk_occluded(
-                            node0, node0 + tlas_per_frame,
-                            (fid_row == f.astype(jnp.float32)).astype(
-                                jnp.float32
-                            ),
-                            occluded,
-                        )
-
-                    return jax.lax.fori_loop(
-                        fid_lo, fid_hi + 1,
-                        per_frame, occluded0,
-                    )
-                return tlas_walk_occluded(
-                    jnp.int32(0), jnp.int32(tlas_nodes), None, occluded0
-                )
-            return jax.lax.fori_loop(
-                k_sweep_lo if pool_io else 0,
-                k_sweep_hi if pool_io else k_count,
-                per_instance, occluded0,
-            )
+                # matter (pre-occluded) carry a -INF limit and never
+                # drive a node's packet test.
+                return tlas_walk(
+                    jnp.int32(0), jnp.int32(tlas_nodes),
+                    tlas_base(sunx, suny, sunz),
+                    wox, woy, woz, wix, wiy, wiz,
+                    lambda c: jnp.where(c[0] > 0.0, -INF, INF),
+                    lambda k, c: (per_instance(k, c[0]),),
+                    (occluded0,),
+                )[0]
+            return jax.lax.fori_loop(0, k_count, per_instance, occluded0)
 
         throughput = jnp.ones((3, block), jnp.float32)
         radiance = jnp.zeros((3, block), jnp.float32)
@@ -2785,8 +2336,6 @@ def _mesh_trace_kernel_factory(
             oc_sq = o_sq - 2.0 * oc + csq
             disc = oc_dot_d * oc_dot_d - (oc_sq - r2)
             valid = (disc > 0.0) & (r2 > 0.0)
-            if fid_match is not None:
-                valid = valid & fid_match
             sqrt_disc = jnp.sqrt(jnp.maximum(disc, 0.0))
             t0 = oc_dot_d - sqrt_disc
             t1 = oc_dot_d + sqrt_disc
@@ -2884,8 +2433,6 @@ def _mesh_trace_kernel_factory(
             ocsq_s = osq_s - 2.0 * oc_s + csq
             disc_s = ocd_s * ocd_s - (ocsq_s - r2)
             valid_s = (disc_s > 0.0) & (r2 > 0.0)
-            if fid_match is not None:
-                valid_s = valid_s & fid_match
             t1_s = ocd_s + jnp.sqrt(jnp.maximum(disc_s, 0.0))
             shadowed = jnp.max(
                 jnp.where(valid_s & (t1_s > EPS), 1.0, 0.0),
@@ -2942,23 +2489,20 @@ def _mesh_trace_kernel_factory(
             d = jnp.where(live, new_d, d)
             return (o, d, throughput, radiance, alive, hit_slot)
 
-        if state_io or pool_io:
+        if state_io:
             # ONE bounce with streamed state: overwrite the in-kernel
             # initial state with the caller's, run bounce_step once at the
             # caller's bounce index, stream everything back out. Blocks
             # whose first lane is past the live count are all-dead (the
-            # Morton sort / compaction puts dead lanes at the tail) and
+            # Morton sort puts dead lanes at the tail) and
             # pass state through untouched — bit-identical to what the
             # masked bounce computes for dead lanes, without paying for
-            # the walks. Pool mode: the bounce index is a per-lane row
-            # (mixed depths), consumed only by the RNG counter.
+            # the walks.
             throughput = thr_ref[:, :]
             alive = alive_ref[:, :]
-            bounce_index = (
-                bounce_row_ref[:, :] if pool_io else bounce_ref[0, 0]
-            )
+            bounce_index = bounce_ref[0, 0]
             block_start = pl.program_id(0) * block
-            slot_sentinel = jnp.float32(k_per_frame if pool_io else k_count)
+            slot_sentinel = jnp.float32(k_count)
             o, d, throughput, radiance, alive, hit_slot = jax.lax.cond(
                 block_start < live_ref[0, 0],
                 lambda: bounce_step(
@@ -2992,45 +2536,38 @@ def _mesh_trace_kernel_factory(
                 eix, eiy, eiz = winv(edx), winv(edy), winv(edz)
                 live_lane = alive > 0.5
 
-                def entry_leaf(slot_offset):
-                    def leaf_step(k, carry):
-                        best_e, best_s = carry
-                        lox = (inst_ref[k, 13] - eox) * eix
-                        hix = (inst_ref[k, 16] - eox) * eix
-                        loy = (inst_ref[k, 14] - eoy) * eiy
-                        hiy = (inst_ref[k, 17] - eoy) * eiy
-                        loz = (inst_ref[k, 15] - eoz) * eiz
-                        hiz = (inst_ref[k, 18] - eoz) * eiz
-                        near = jnp.maximum(
-                            jnp.maximum(
-                                jnp.minimum(lox, hix), jnp.minimum(loy, hiy)
-                            ),
-                            jnp.minimum(loz, hiz),
-                        )
-                        far = jnp.minimum(
-                            jnp.minimum(
-                                jnp.maximum(lox, hix), jnp.maximum(loy, hiy)
-                            ),
-                            jnp.maximum(loz, hiz),
-                        )
-                        overlap = far >= jnp.maximum(near, 0.0)
-                        if pool_io:
-                            overlap = overlap & (fid_row == inst_ref[k, 22])
-                        entry = jnp.where(
-                            overlap, jnp.maximum(near, 0.0), INF
-                        )
-                        improved = entry < best_e
-                        best_e = jnp.where(improved, entry, best_e)
-                        best_s = jnp.where(
-                            improved,
-                            (k - slot_offset).astype(jnp.float32),
-                            best_s,
-                        )
-                        return best_e, best_s
+                def entry_leaf(k, carry):
+                    best_e, best_s = carry
+                    lox = (inst_ref[k, 13] - eox) * eix
+                    hix = (inst_ref[k, 16] - eox) * eix
+                    loy = (inst_ref[k, 14] - eoy) * eiy
+                    hiy = (inst_ref[k, 17] - eoy) * eiy
+                    loz = (inst_ref[k, 15] - eoz) * eiz
+                    hiz = (inst_ref[k, 18] - eoz) * eiz
+                    near = jnp.maximum(
+                        jnp.maximum(
+                            jnp.minimum(lox, hix), jnp.minimum(loy, hiy)
+                        ),
+                        jnp.minimum(loz, hiz),
+                    )
+                    far = jnp.minimum(
+                        jnp.minimum(
+                            jnp.maximum(lox, hix), jnp.maximum(loy, hiy)
+                        ),
+                        jnp.maximum(loz, hiz),
+                    )
+                    overlap = far >= jnp.maximum(near, 0.0)
+                    entry = jnp.where(
+                        overlap, jnp.maximum(near, 0.0), INF
+                    )
+                    improved = entry < best_e
+                    best_e = jnp.where(improved, entry, best_e)
+                    best_s = jnp.where(
+                        improved, k.astype(jnp.float32), best_s
+                    )
+                    return best_e, best_s
 
-                    return leaf_step
-
-                sentinel = jnp.float32(k_per_frame if pool_io else k_count)
+                sentinel = jnp.float32(k_count)
                 # Packed-key tier: mesh-hit lanes already carry their
                 # candidate (the nearest walk's winning slot), so they
                 # stop driving the entry walk's packet descents.
@@ -3039,51 +2576,26 @@ def _mesh_trace_kernel_factory(
                     else live_lane
                 )
 
-                def entry_walk(node0, node_end, slot_offset, match, carry):
-                    drive = (
-                        entry_lane if match is None else entry_lane & match
-                    )
-                    return tlas_walk(
-                        node0, node_end, tlas_base(edx, edy, edz),
-                        eox, eoy, eoz, eix, eiy, eiz,
-                        lambda c: jnp.where(drive, c[0], -INF),
-                        entry_leaf(slot_offset), carry,
-                    )
                 entry_init = (
                     jnp.full((1, block), INF, jnp.float32),
                     jnp.full((1, block), sentinel, jnp.float32),
                 )
 
                 def run_entry_walk():
-                    if pool_io:
-                        def per_frame_entry(f, carry):
-                            node0 = f * tlas_per_frame
-                            return entry_walk(
-                                node0, node0 + tlas_per_frame,
-                                f * k_per_frame,
-                                fid_row == f.astype(jnp.float32), carry,
-                            )
-
-                        return jax.lax.fori_loop(
-                            fid_lo, fid_hi + 1,
-                            per_frame_entry, entry_init,
-                        )
-                    return entry_walk(
-                        jnp.int32(0), jnp.int32(tlas_nodes), jnp.int32(0),
-                        None, entry_init,
+                    return tlas_walk(
+                        jnp.int32(0), jnp.int32(tlas_nodes),
+                        tlas_base(edx, edy, edz),
+                        eox, eoy, eoz, eix, eiy, eiz,
+                        lambda c: jnp.where(entry_lane, c[0], -INF),
+                        entry_leaf, entry_init,
                     )
 
-                # Final-bounce launches (state_io: the bounce index is a
-                # uniform scalar) never have their key consumed — the
-                # driver's loop ends — so skip the entry walk there and
-                # key with the sentinel candidate. Pool mode cannot gate:
-                # lanes sit at MIXED depths and the next pool iteration
-                # always sorts by this column.
-                want_candidates = block_start < live_ref[0, 0]
-                if not pool_io:
-                    want_candidates = want_candidates & (
-                        bounce_ref[0, 0] < max_bounces - 1
-                    )
+                # Final-bounce launches never have their key consumed —
+                # the integrator's loop ends — so skip the entry walk
+                # there and key with the sentinel candidate.
+                want_candidates = (block_start < live_ref[0, 0]) & (
+                    bounce_ref[0, 0] < max_bounces - 1
+                )
                 _, best_slot = jax.lax.cond(
                     want_candidates,
                     run_entry_walk,
@@ -3109,8 +2621,7 @@ def _mesh_trace_kernel_factory(
                     o[2:3, :] + d[2:3, :],
                     d[0:1, :], d[1:2, :], d[2:3, :],
                     alive <= 0.5,
-                    (fid_row.astype(jnp.int32) if pool_io
-                     else jnp.zeros((1, block), jnp.int32)),
+                    jnp.zeros((1, block), jnp.int32),
                     best_slot.astype(jnp.int32),
                     keysm_ref[0], keysm_ref[1], keysm_ref[2],
                     keysm_ref[3], keysm_ref[4], keysm_ref[5],
@@ -3164,7 +2675,7 @@ def _node_table_operands(lo, hi, skip, first, count, *, quant: int,
                          first_unit: int):
     """(operands, specs) for one node-table block in either format.
 
-    The ONE packing site all three mesh drivers share: fp32 mode ships
+    The ONE packing site both mesh kernels share: fp32 mode ships
     the five classic SMEM refs; quantized mode ships the packed
     bq/meta/grid triple from ``mesh.quantize_node_tables`` (static BLAS
     tables constant-fold under jit; traced TLAS bounds quantize as cheap
@@ -3549,7 +3060,7 @@ def _mesh_bounce_io(
 def mesh_bounce_pallas(
     scene, mesh, origins, directions, throughput, alive, seed, bounce,
     *, total_bounces: int, lane=None, live_count=None, use_tlas=None,
-    quant: int | None = None, tlas_block: int | None = None,
+    quant: int | None = None,
 ):
     """One fused path-trace bounce for deep-walk mesh scenes.
 
@@ -3558,7 +3069,7 @@ def mesh_bounce_pallas(
     bounces (packet coherence) without paying per-bounce XLA glue —
     separate sphere/shadow kernels, threefry RNG, and a dozen elementwise
     HBM round trips. ``lane`` carries each ray's ORIGINAL lane id — the
-    RNG counter, so a ray's stream survives the re-sort/compaction
+    RNG counter, so a ray's stream survives the re-sort
     permutations; ``live_count`` is the number of leading live lanes
     (dead lanes must be sorted to the tail), letting all-dead tail
     blocks skip the bounce. Defaults: positional lanes, nothing skipped.
@@ -3590,7 +3101,7 @@ def mesh_bounce_pallas(
         total_bounces=total_bounces, interpret=_interpret(),
         use_tlas=use_tlas_for(instances.translation.shape[0], use_tlas),
         tlas_leaf=tlas_leaf_size(),
-        tlas_block=tlas_block_r() if tlas_block is None else int(tlas_block),
+        tlas_block=tlas_block_r(),
         quant=bvh_quant_mode() if quant is None else int(quant),
     )
 
@@ -3680,408 +3191,3 @@ def occluded_instances_pallas(bvh, instances, origins, directions, already):
         bvh.skip, bvh.first, bvh.count,
         interpret=_interpret(),
     )
-
-
-# ---------------------------------------------------------------------------
-# Device-resident ray-pool (render/raypool.py) kernel plumbing.
-#
-# The pool driver runs the whole multi-frame batch inside ONE jitted
-# lax.while_loop, so these wrappers are NOT jitted themselves: operand prep
-# that is loop-invariant (the stacked multi-frame scene) is hoisted into
-# PoolSphereOperands / PoolMeshOperands built once before the loop, and the
-# per-iteration bounce call only transposes the pool state and launches the
-# pool_io kernel. Pool width must be a multiple of the kernel block — the
-# driver rounds up, so no per-call ray padding exists on this path.
-
-
-class PoolSphereOperands(NamedTuple):
-    """Loop-invariant kernel operands for a stacked multi-frame sphere
-    scene (frames on a per-sphere ``fid`` column; padded slots fid=-1)."""
-
-    c_t: jnp.ndarray  # [3, Np]
-    r2: jnp.ndarray  # [Np, 1]
-    csq: jnp.ndarray  # [Np, 1]
-    rad: jnp.ndarray  # [Np, 1]
-    albedo_t: jnp.ndarray  # [3, Np]
-    emission_t: jnp.ndarray  # [3, Np]
-    dc_sun: jnp.ndarray  # [Np, 1]
-    sfid: jnp.ndarray  # [Np, 1] float32 frame ids (-1 = padding)
-    params: jnp.ndarray  # [8, 3]
-
-
-def pool_sphere_operands(
-    centers, radii, albedo, emission, sphere_fid,
-    sun_direction, sun_color, sky_horizon, sky_zenith,
-    plane_albedo_a, plane_albedo_b,
-) -> PoolSphereOperands:
-    """Stack-prep for the pool sphere kernel. ``centers``/... are the
-    multi-frame concatenation [F*N, ...]; ``sphere_fid`` [F*N] int."""
-    n = centers.shape[0]
-    padded_n = -(-n // _SUBLANE) * _SUBLANE
-    pad = padded_n - n
-    c_t = jnp.pad(centers, ((0, pad), (0, 0))).T
-    radii_p = jnp.pad(radii, (0, pad))
-    albedo_t = jnp.pad(albedo, ((0, pad), (0, 0))).T
-    emission_t = jnp.pad(emission, ((0, pad), (0, 0))).T
-    sfid = jnp.pad(
-        sphere_fid.astype(jnp.float32), (0, pad), constant_values=-1.0
-    )[:, None]
-    params = jnp.zeros((8, 3), jnp.float32)
-    params = params.at[0].set(sun_direction)
-    params = params.at[1].set(sun_color)
-    params = params.at[2].set(sky_horizon)
-    params = params.at[3].set(sky_zenith)
-    params = params.at[4].set(plane_albedo_a)
-    params = params.at[5].set(plane_albedo_b)
-    return PoolSphereOperands(
-        c_t=c_t,
-        r2=(radii_p * radii_p)[:, None],
-        csq=jnp.sum(c_t * c_t, axis=0)[:, None],
-        rad=radii_p[:, None],
-        albedo_t=albedo_t,
-        emission_t=emission_t,
-        dc_sun=_center_dot_sun(c_t, sun_direction),
-        sfid=sfid,
-        params=params,
-    )
-
-
-class PoolMeshOperands(NamedTuple):
-    """PoolSphereOperands plus the shared BVH and the stacked (multi-
-    frame) instance transforms; ``ifid`` [F*K] marks each instance's
-    frame. ``sun_direction`` rides along for the kernel's SMEM scalars."""
-
-    spheres: PoolSphereOperands
-    sun_direction: jnp.ndarray  # [3]
-    # FID-MAJOR stacking contract: frame f's instances occupy rows
-    # [f*K, (f+1)*K) — the kernel's per-block frame-window sweep indexes
-    # the table by that arithmetic.
-    rotation: jnp.ndarray  # [F*K, 3, 3]
-    translation: jnp.ndarray  # [F*K, 3]
-    scale: jnp.ndarray  # [F*K]
-    inst_albedo: jnp.ndarray  # [F*K, 3]
-    ifid: jnp.ndarray  # [F*K] int32
-    k_per_frame: int  # K (static Python int; ops are closed over, not traced)
-    v0: jnp.ndarray
-    e1: jnp.ndarray
-    e2: jnp.ndarray
-    normal: jnp.ndarray
-    bounds_min: jnp.ndarray
-    bounds_max: jnp.ndarray
-    skip: jnp.ndarray
-    first: jnp.ndarray
-    count: jnp.ndarray
-    octant: object = None  # mesh.OctantTables | None (sah builds)
-
-
-def pool_instance_aabbs(ops: PoolMeshOperands):
-    """World AABBs (lo, hi) of the stacked instances — the broadphase
-    input for the pool's coherence-sort candidate key."""
-    table = _instance_table(
-        ops.rotation, ops.translation, ops.scale,
-        ops.bounds_min, ops.bounds_max,
-    )
-    return table[:, 13:16], table[:, 16:19]
-
-
-def pool_sphere_bounce(
-    ops: PoolSphereOperands, origins, directions, throughput, alive,
-    lane, fid, seed_row, bounce_row, live_count, *, total_bounces: int,
-):
-    """One pool bounce over a sphere-only stacked scene.
-
-    Pool width must be a multiple of SPHERE_BOUNCE_BLOCK_R. Returns
-    (contribution [P, 3], origins, directions, throughput, alive).
-    """
-    rays = origins.shape[0]
-    block = SPHERE_BOUNCE_BLOCK_R
-    if rays % block:
-        raise ValueError(f"pool width {rays} not a multiple of {block}")
-    padded_n = ops.c_t.shape[1]
-    o_t = origins.T
-    d_t = directions.T
-    thr_t = throughput.T
-    alive_t = alive.astype(jnp.float32)[None, :]
-    lane_t = lane.astype(jnp.int32)[None, :]
-    seed_t = seed_row.astype(jnp.int32)[None, :]
-    bounce_t = bounce_row.astype(jnp.int32)[None, :]
-    fid_t = fid.astype(jnp.float32)[None, :]
-    live_arr = jnp.asarray(live_count, jnp.int32).reshape(1, 1)
-
-    grid = (rays // block,)
-    whole = lambda i: (0, 0)  # noqa: E731
-    ray_block = pl.BlockSpec(
-        (3, block), lambda i: (0, i), memory_space=pltpu.VMEM
-    )
-    row_block = pl.BlockSpec(
-        (1, block), lambda i: (0, i), memory_space=pltpu.VMEM
-    )
-    contrib, o2, d2, thr2, alive2 = pl.pallas_call(
-        _trace_kernel_factory(total_bounces, padded_n, pool_io=True),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM),
-            ray_block,
-            ray_block,
-            ray_block,
-            row_block,
-            row_block,
-            row_block,
-            row_block,
-            row_block,
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((8, 3), whole, memory_space=pltpu.VMEM),
-        ],
-        out_specs=[ray_block, ray_block, ray_block, ray_block, row_block],
-        out_shape=[
-            jax.ShapeDtypeStruct((3, rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, rays), jnp.float32),
-            jax.ShapeDtypeStruct((1, rays), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(live_arr, o_t, d_t, thr_t, alive_t, lane_t, seed_t, bounce_t, fid_t,
-      ops.c_t, ops.r2, ops.csq, ops.rad, ops.albedo_t, ops.emission_t,
-      ops.dc_sun, ops.sfid, ops.params)
-    return contrib.T, o2.T, d2.T, thr2.T, alive2[0] > 0.5
-
-
-def pool_mesh_bounce(
-    ops: PoolMeshOperands, origins, directions, throughput, alive,
-    lane, fid, seed_row, bounce_row, live_count, *, total_bounces: int,
-    use_tlas: bool = False, tlas_leaf: int = 4,
-    tlas_block: int | None = None, quant: int = 0,
-):
-    """One pool bounce over a stacked multi-frame mesh scene.
-
-    Pool width must be a multiple of the active ray block (the TLAS
-    variant packets at the narrower tlas_block_r; every tlas_block_r
-    divides BVH_BLOCK_R, so a BVH_BLOCK_R-rounded pool satisfies both).
-    On the flat variant the front-to-back instance ordering is
-    recomputed per call (ray origins move every iteration); the TLAS
-    variant slot-orders each frame's segment by Morton code instead
-    (ray-independent) and walks one per-frame TLAS window per block.
-    Results are instance-order invariant either way, as in
-    _mesh_bounce_io. Returns (contribution, origins, directions,
-    throughput, alive, key-or-None).
-    """
-    from tpu_render_cluster.render.mesh import LEAF_SIZE
-
-    if tlas_block is None:
-        tlas_block = tlas_block_r()  # untraced callers only
-    block = tlas_block if use_tlas else BVH_BLOCK_R
-    rays = origins.shape[0]
-    if rays % block:
-        raise ValueError(
-            f"pool width {rays} not a multiple of {block}"
-        )
-    sp = ops.spheres
-    padded_n = sp.c_t.shape[1]
-    o_t = origins.T
-    d_t = directions.T
-    thr_t = throughput.T
-    alive_t = alive.astype(jnp.float32)[None, :]
-    lane_t = lane.astype(jnp.int32)[None, :]
-    seed_t = seed_row.astype(jnp.int32)[None, :]
-    bounce_t = bounce_row.astype(jnp.int32)[None, :]
-    fid_t = fid.astype(jnp.float32)[None, :]
-    live_arr = jnp.asarray(live_count, jnp.int32).reshape(1, 1)
-    # Per-block frame-id windows: the kernel sweeps only the table's
-    # contiguous [fid_lo*K, (fid_hi+1)*K) slice for each block
-    # (conservative: computed over every lane incl. the stale dead tail).
-    fid_blocks = fid.astype(jnp.int32).reshape(rays // block, block)
-    fid_lo = fid_blocks.min(axis=1)[None, :]  # [1, n_blocks]
-    fid_hi = fid_blocks.max(axis=1)[None, :]
-
-    k_per_frame = ops.k_per_frame
-    n_frames = ops.rotation.shape[0] // k_per_frame
-    if use_tlas:
-        # Morton slot order WITHIN each frame's segment (stacking stays
-        # fid-major — the kernel windows on frame f owning rows
-        # [f*K, (f+1)*K)), plus one per-frame TLAS node window stacked
-        # the same way: frame f's nodes are rows [f*M, (f+1)*M) with
-        # skip links and leaf starts offset into the global node/slot
-        # index spaces.
-        from tpu_render_cluster.render.mesh import (
-            cached_tlas_topology,
-            instance_morton_order,
-            tlas_node_bounds,
-        )
-
-        lo_w, hi_w = pool_instance_aabbs(ops)  # [F*K, 3]
-        lo_f = lo_w.reshape(n_frames, k_per_frame, 3)
-        hi_f = hi_w.reshape(n_frames, k_per_frame, 3)
-        within = jax.vmap(instance_morton_order)(lo_f, hi_f)  # [F, K]
-        near_first = (
-            within
-            + (jnp.arange(n_frames, dtype=within.dtype) * k_per_frame)[
-                :, None
-            ]
-        ).reshape(-1)
-        topology = cached_tlas_topology(k_per_frame, tlas_leaf)
-        m = int(topology.skip.shape[0])
-        slo = lo_w[near_first].reshape(n_frames, k_per_frame, 3)
-        shi = hi_w[near_first].reshape(n_frames, k_per_frame, 3)
-        node_lo, node_hi = jax.vmap(
-            lambda lo, hi: tlas_node_bounds(topology, lo, hi)
-        )(slo, shi)
-        node_offset = jnp.arange(n_frames, dtype=jnp.int32)[:, None] * m
-        slot_offset = (
-            jnp.arange(n_frames, dtype=jnp.int32)[:, None] * k_per_frame
-        )
-        key_lo, key_inv = mesh_key_bounds(lo_w, hi_w)
-        tlas_nodes = n_frames * m
-        tlas_per_frame = m
-        quant = resolve_bvh_quant(
-            quant,
-            (ops.skip.shape[0], ops.v0.shape[0] // LEAF_SIZE, LEAF_SIZE),
-            (tlas_nodes, ops.rotation.shape[0], tlas_leaf),
-        )
-        # The stacked per-frame node windows quantize against ONE grid
-        # (the union over every frame's instance AABBs): skip/leaf-start
-        # links carry their frame offsets INSIDE the packed meta words.
-        tlas_operands, tlas_specs = _node_table_operands(
-            node_lo.reshape(-1, 3),
-            node_hi.reshape(-1, 3),
-            (jnp.asarray(topology.skip)[None, :] + node_offset).reshape(-1),
-            (jnp.asarray(topology.first)[None, :] + slot_offset).reshape(
-                -1
-            ),
-            jnp.tile(jnp.asarray(topology.count), n_frames),
-            quant=quant, first_unit=1,
-        )
-        extra_operands = (
-            *tlas_operands, jnp.concatenate([key_lo, key_inv]),
-        )
-    else:
-        # Front-to-back instance order WITHIN each frame's segment, from
-        # the mean live origin (dead lanes parked far away must not drag
-        # the anchor): near instances seed tight best-t early within
-        # each frame. Results are instance-order invariant, as in
-        # _mesh_bounce_io.
-        valid = (jnp.abs(origins) < 1e6).all(axis=1) & alive
-        anchor = jnp.sum(
-            jnp.where(valid[:, None], origins, 0.0), axis=0
-        ) / jnp.maximum(jnp.sum(valid), 1)
-        dist2 = jnp.sum(
-            (ops.translation - anchor[None, :]) ** 2, axis=1
-        ).reshape(n_frames, k_per_frame)
-        within = jnp.argsort(dist2, axis=1)  # [F, K]
-        near_first = (
-            within
-            + (jnp.arange(n_frames, dtype=within.dtype) * k_per_frame)[
-                :, None
-            ]
-        ).reshape(-1)
-        quant = resolve_bvh_quant(
-            quant,
-            (ops.skip.shape[0], ops.v0.shape[0] // LEAF_SIZE, LEAF_SIZE),
-        )
-        tlas_specs = []
-        extra_operands = ()
-        tlas_nodes = 0
-        tlas_per_frame = 0
-    inst_table = _instance_table(
-        ops.rotation[near_first], ops.translation[near_first],
-        ops.scale[near_first],
-        ops.bounds_min, ops.bounds_max, ops.inst_albedo[near_first],
-    )
-    inst_table = jnp.concatenate(
-        [inst_table, ops.ifid[near_first].astype(jnp.float32)[:, None]],
-        axis=1,
-    )  # [F*K, 23]: column 22 is the instance's frame id
-    n_nodes = ops.skip.shape[0]
-    k_count = ops.rotation.shape[0]
-
-    grid = (rays // block,)
-    whole = lambda i: (0, 0)  # noqa: E731
-    flat = lambda i: (0,)  # noqa: E731
-    ray_block = pl.BlockSpec(
-        (3, block), lambda i: (0, i), memory_space=pltpu.VMEM
-    )
-    row_block = pl.BlockSpec(
-        (1, block), lambda i: (0, i), memory_space=pltpu.VMEM
-    )
-    blas_arrays = _blas_node_arrays(
-        ops.bounds_min, ops.bounds_max, ops.skip, ops.first, ops.count,
-        ops.octant,
-    )
-    ordered = blas_arrays[5]
-    blas_operands, blas_specs = _node_table_operands(
-        *blas_arrays[:5], quant=quant, first_unit=LEAF_SIZE,
-    )
-    extra_specs = (
-        tlas_specs + [pl.BlockSpec((6,), flat, memory_space=pltpu.SMEM)]
-        if use_tlas
-        else []
-    )
-    key_out_specs = [row_block] if use_tlas else []
-    key_out_shapes = (
-        [jax.ShapeDtypeStruct((1, rays), jnp.int32)] if use_tlas else []
-    )
-    results = pl.pallas_call(
-        _mesh_trace_kernel_factory(
-            total_bounces, padded_n, n_nodes, LEAF_SIZE, k_count,
-            pool_io=True, k_per_frame=k_per_frame,
-            use_tlas=use_tlas, tlas_nodes=tlas_nodes,
-            tlas_per_frame=tlas_per_frame, quant=quant, ordered=ordered,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM),
-            ray_block,
-            ray_block,
-            ray_block,
-            row_block,
-            row_block,
-            row_block,
-            row_block,
-            row_block,
-            # Whole [1, n_blocks] rows in SMEM, indexed by program_id in
-            # the kernel (a (1, 1) block over the row is not a legal TPU
-            # tiling; same form as _bvh_nearest_instanced's candidates).
-            pl.BlockSpec(fid_lo.shape, whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec(fid_hi.shape, whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((8, 3), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((3,), flat, memory_space=pltpu.SMEM),
-            pl.BlockSpec(inst_table.shape, whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec(ops.v0.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(ops.e1.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(ops.e2.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(ops.normal.shape, whole, memory_space=pltpu.VMEM),
-        ] + blas_specs + extra_specs,
-        out_specs=[ray_block, ray_block, ray_block, ray_block, row_block]
-        + key_out_specs,
-        out_shape=[
-            jax.ShapeDtypeStruct((3, rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, rays), jnp.float32),
-            jax.ShapeDtypeStruct((3, rays), jnp.float32),
-            jax.ShapeDtypeStruct((1, rays), jnp.float32),
-        ] + key_out_shapes,
-        interpret=_interpret(),
-    )(live_arr, o_t, d_t, thr_t, alive_t, lane_t, seed_t, bounce_t, fid_t,
-      fid_lo, fid_hi,
-      sp.c_t, sp.r2, sp.csq, sp.rad, sp.albedo_t, sp.emission_t,
-      sp.dc_sun, sp.sfid, sp.params, ops.sun_direction, inst_table,
-      ops.v0, ops.e1, ops.e2, ops.normal, *blas_operands,
-      *extra_operands)
-    contrib, o2, d2, thr2, alive2 = results[:5]
-    key2 = results[5][0] if use_tlas else None
-    return contrib.T, o2.T, d2.T, thr2.T, alive2[0] > 0.5, key2

@@ -30,7 +30,7 @@ def configure_compile_cache() -> str:
     directory is left alone; otherwise the cache lives in
     ``DEFAULT_COMPILE_CACHE_DIR``. Every program is cached, however small
     or quick to compile: a worker compiles dozens of sub-second helper
-    programs (compaction buckets, tonemap) besides the render kernels.
+    programs (scene build, tonemap) besides the render kernels.
     """
     import jax
 

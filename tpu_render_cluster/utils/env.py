@@ -65,14 +65,10 @@ declare("TRC_HEARTBEAT_PONG_RETRIES", "int", 1, "Extra pings after a missed pong
 declare("TRC_MAX_UNIT_ERRORS", "int", 8, "Deterministic render errors per unit before the job fails")
 # -- render tiers ------------------------------------------------------------
 declare("TRC_PALLAS", "flag", None, "Pallas kernel dispatch override (1/0; unset = TPU only)")
-declare("TRC_WAVEFRONT", "spec", "auto", "Wavefront tier: auto | force | off")
-declare("TRC_RAYPOOL", "spec", "auto", "Device-resident ray-pool tier: auto | force | off")
-declare("TRC_RAYPOOL_FRAMES", "int", 8, "Frames per compiled pool window")
-declare("TRC_RAYPOOL_WIDTH", "int", None, "Ray-pool width (default: one frame, block-rounded)")
 declare("TRC_TLAS", "flag", 1, "Two-level (TLAS) mesh traversal on/off")
 declare("TRC_TLAS_LEAF", "int", 4, "Instances per TLAS leaf (clamped 1..16)")
 declare("TRC_TLAS_BLOCK", "int", 256, "Ray-block width of the TLAS kernel variants")
-declare("TRC_BVH_QUANT", "int", 0, "Quantized BVH/TLAS node tier: 0 off, 1 16-bit, 2 8-bit slabs (+ packed carried ray state)")
+declare("TRC_BVH_QUANT", "int", 0, "Quantized BVH/TLAS node tier: 0 off, 1 16-bit, 2 8-bit slabs")
 declare("TRC_BVH_BUILDER", "spec", "sah", "BLAS build strategy: sah (binned) | median")
 declare("TRC_BVH_WIDE", "int", 4, "BLAS branching factor after wide collapse (1 = binary, clamped 1..8)")
 # -- jobs / tiles ------------------------------------------------------------

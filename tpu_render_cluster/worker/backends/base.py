@@ -23,17 +23,6 @@ class RenderBackend(abc.ABC):
     the frame. Backends that cannot render sub-frame regions (the
     Blender subprocess backend) must raise a clear error instead of
     silently rendering the whole frame under a tile's name.
-
-    Optional hint protocol: a backend may additionally define
-    ``note_upcoming_frames(job, units)``. Before each ``render_frame``
-    the worker queue calls it (when present) with the OTHER work units
-    (``jobs.tiles.WorkUnit``) of the same job still queued locally —
-    the honest work-ahead visible to this worker. Backends that batch
-    internally (the tpu-raytrace ray-pool mode renders several queued
-    frames in one device program and serves later requests from its
-    cache) key off this hint; the one-unit-per-request wire contract is
-    unchanged, so masters and peers cannot tell a batching worker from
-    a serial one.
     """
 
     @abc.abstractmethod

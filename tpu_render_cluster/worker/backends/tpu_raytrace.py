@@ -26,6 +26,12 @@ from tpu_render_cluster.utils.paths import parse_with_base_directory_prefix
 from tpu_render_cluster.worker.backends.base import RenderBackend
 
 
+# Linear bucket bounds for render_launch_occupancy: fractions live in
+# [0, 1], where the default log ladder (1e-4..1e3) has almost no
+# resolution.
+ALIVE_FRACTION_BUCKETS = tuple((i + 1) / 16 for i in range(16))
+
+
 class TpuRaytraceBackend(RenderBackend):
     def __init__(
         self,
@@ -37,8 +43,6 @@ class TpuRaytraceBackend(RenderBackend):
         max_bounces: int = 4,
         tile_size: int | None = None,
         sharding: str | None = None,
-        wavefront: str | None = None,
-        raypool: str | None = None,
     ) -> None:
         from tpu_render_cluster.utils.accelerator import require_tpu_device
 
@@ -52,79 +56,15 @@ class TpuRaytraceBackend(RenderBackend):
         self.samples = samples
         self.max_bounces = max_bounces
         self.tile_size = tile_size
-        # None = single device; "tile" / "spp" shard across the local mesh
-        # (tpu_render_cluster/parallel/sharded_render.py).
+        # None = single device; "tile" / "spp" shard a whole frame across
+        # the local mesh (tpu_render_cluster/parallel/sharded_render.py).
         self.sharding = sharding
-        # Wavefront (compact + bucketed relaunch) execution: None defers
-        # to the TRC_WAVEFRONT env tier; "off"/"auto"/"force" override it
-        # per backend (render/compaction.py). Only "force" turns it on;
-        # auto is the one-program tier for every scene. Only applies to
-        # the single-device path — tile/spp sharding gets the IN-JIT
-        # compaction (live-count tail skip) instead, which composes with
-        # shard_map.
-        self.wavefront = wavefront
-        # Device-resident ray pool (render/raypool.py): None defers to the
-        # TRC_RAYPOOL env tier; "off"/"auto"/"force" override per backend.
-        # Only "force" turns it on (frames queued ahead do not). The
-        # queue's note_upcoming_frames hint supplies the work-ahead, and
-        # the backend then renders several of ITS OWN queued frames in
-        # one pool batch, serving later requests from the cache below.
-        # Worker-internal only: one frame per request on the wire.
-        self.raypool = raypool
-        # Work units (jobs.tiles.WorkUnit) of each job still queued here.
-        self._upcoming: dict[str, tuple] = {}
-        # (job_name, frame_index, tile) -> linear image rendered ahead by
-        # a pool batch. Bounded BY BYTES: stale entries (stolen/removed
-        # units we rendered ahead of) are evicted oldest-first.
-        self._raypool_cache: dict[tuple[str, int, int | None], object] = {}
-        # The three whole-frame tiers are exposed from the start, at 0: a
-        # scrape that finds no series could not tell "no frame went that
-        # way" from "not counted".
+        # Every unit shape is exposed from the start, at 0: a scrape that
+        # finds no series could not tell "no frame went that way" from
+        # "not counted".
         self._tier_frames = self._tier_frames_counter()
-        for tier in ("masked", "wavefront", "raypool"):
+        for tier in ("masked", "region", "sharded"):
             self._tier_frames.inc(0.0, tier=tier)
-
-    # Staleness backstop, not a working-set budget: live entries drain
-    # within one pool window of requests, so anything pushing the cache
-    # past this is stolen/removed frames.
-    _RAYPOOL_CACHE_MAX_BYTES = 64 * 1024 * 1024
-
-    def note_upcoming_frames(self, job: BlenderJob, units: tuple) -> None:
-        """Queue hint (RenderBackend hint protocol): same-job work units
-        still queued on this worker, i.e. what a pool batch may render
-        ahead (same-tile units of other frames, for tiled jobs).
-
-        An empty hint drops the job's entry — the map tracks only jobs
-        with outstanding local work, so a long-lived worker's job history
-        doesn't accumulate here. Bare ints are accepted as whole-frame
-        units (the pre-tiling call shape).
-        """
-        if units:
-            from tpu_render_cluster.jobs.tiles import WorkUnit
-
-            self._upcoming[job.job_name] = tuple(
-                WorkUnit(u) if isinstance(u, int) else u for u in units
-            )
-        else:
-            self._upcoming.pop(job.job_name, None)
-
-    def _use_wavefront(self, scene_name: str) -> bool:
-        if self.sharding in ("tile", "spp"):
-            return False
-        from tpu_render_cluster.render.compaction import wavefront_active
-
-        return wavefront_active(scene_name, backend_flag=self.wavefront)
-
-    def _use_raypool(self, scene_name: str, frames_ahead: int) -> bool:
-        if self.sharding in ("tile", "spp"):
-            return False
-        from tpu_render_cluster.render.raypool import raypool_active
-
-        return raypool_active(
-            scene_name,
-            backend_flag=self.raypool,
-            frames_ahead=frames_ahead,
-        )
 
     def warm(self, scene_name: str) -> None:
         """Compile + execute the renderer once, outside any job window.
@@ -143,54 +83,17 @@ class TpuRaytraceBackend(RenderBackend):
         scene_name = scene_for_job_name(scene_name)
 
         if self.sharding in ("tile", "spp"):
-            from tpu_render_cluster.parallel.sharded_render import render_frame_sharded
+            from tpu_render_cluster.parallel.sharded_render import sharded_frame_renderer
 
             np.asarray(
-                render_frame_sharded(
+                sharded_frame_renderer(
                     scene_name,
-                    1,
-                    width=self.width,
-                    height=self.height,
-                    samples=self.samples,
-                    max_bounces=self.max_bounces,
-                    mode=self.sharding,
-                )
-            )
-            return
-        if self._use_raypool(scene_name, frames_ahead=1):
-            # The pool program is one compile per pool config, batch size
-            # independent — a single-frame batch warms it completely. The
-            # per-frame fallback below is ALSO warmed: the job's tail
-            # frame (nothing queued behind it) renders through it, and
-            # its compile must not land inside a frame trace either.
-            from tpu_render_cluster.render.raypool import render_batch_raypool
-
-            np.asarray(
-                render_batch_raypool(
-                    scene_name,
-                    [1],
-                    width=self.width,
-                    height=self.height,
-                    samples=self.samples,
-                    max_bounces=self.max_bounces,
-                )[0]
-            )
-        if self._use_wavefront(scene_name):
-            # One full wavefront frame: compiles the compaction +
-            # bounce programs for the buckets this workload actually
-            # visits (render_compiles_total then stays flat over the
-            # job's frames).
-            from tpu_render_cluster.render.compaction import render_frame_wavefront
-
-            np.asarray(
-                render_frame_wavefront(
-                    scene_name,
-                    1,
-                    width=self.width,
-                    height=self.height,
-                    samples=self.samples,
-                    max_bounces=self.max_bounces,
-                )
+                    self.width,
+                    self.height,
+                    self.samples,
+                    self.max_bounces,
+                    self.sharding,
+                )(1)
             )
         else:
             from tpu_render_cluster.render.integrator import fused_frame_renderer
@@ -211,85 +114,84 @@ class TpuRaytraceBackend(RenderBackend):
     ) -> FrameRenderTime:
         return await asyncio.to_thread(self._render_sync, job, frame_index, tile)
 
-    def _trim_raypool_cache(self) -> None:
-        """Evict oldest rendered-ahead frames past the byte cap (stale
-        entries accumulate when frames we batched ahead get stolen or
-        removed; at production resolution each image is megabytes, so the
-        bound must be bytes, not entries)."""
-        excess = (
-            sum(
-                getattr(image, "nbytes", 0)
-                for image in self._raypool_cache.values()
-            )
-            - self._RAYPOOL_CACHE_MAX_BYTES
-        )
-        while self._raypool_cache and excess > 0:
-            victim = self._raypool_cache.pop(next(iter(self._raypool_cache)))
-            excess -= getattr(victim, "nbytes", 0)
-
     @staticmethod
     def _tier_frames_counter():
         from tpu_render_cluster.obs import get_registry
 
         return get_registry().counter(
             "render_tier_frames_total",
-            "Frames rendered, by the execution tier that rendered them",
+            "Frames rendered, by the shape of the work unit: masked (a "
+            "whole frame on one device), region (a tile), sharded (a whole "
+            "frame across the local mesh)",
             labels=("tier",),
         )
 
+    # The three launch series keep the names the benchmark's per-layer
+    # metrics (launch occupancy, pool_live_lane_share) read them by:
+    # renaming a series is a change of yardstick.
     @staticmethod
-    def _observe_launches(launches) -> None:
-        """Launch occupancy of a one-program frame of a deep mesh scene:
-        every bounce is one kernel launch, ``launches[b]`` its (live
-        rays, width) — the width the program picked for that bounce from
-        its live count (integrator.launch_width_ladder), dead lanes
-        sorted to the tail and skipped by blocks. Fed into the series the
-        wavefront driver (per relaunch, live / bucket) and the raypool
-        (per iteration, live / launched lanes) feed for their launches,
-        so the tier that renders is the one the occupancy describes."""
-        from tpu_render_cluster.render.compaction import launch_occupancy_histogram
-        from tpu_render_cluster.render.raypool import (
-            pool_launched_lanes_counter,
-            pool_live_lanes_counter,
+    def _launch_occupancy_histogram():
+        from tpu_render_cluster.obs import get_registry
+
+        return get_registry().histogram(
+            "render_launch_occupancy",
+            "Per bounce launch of a deep mesh frame: live rays / the width "
+            "the program ran the launch at",
+            buckets=ALIVE_FRACTION_BUCKETS,
         )
 
-        occupancy = launch_occupancy_histogram()
+    @staticmethod
+    def _launched_lanes_counter():
+        from tpu_render_cluster.obs import get_registry
+
+        return get_registry().counter(
+            "render_pool_launched_lanes_total",
+            "Lanes launched: the width the program ran each bounce launch "
+            "of a deep mesh frame at, summed over launches",
+        )
+
+    @staticmethod
+    def _live_lanes_counter():
+        from tpu_render_cluster.obs import get_registry
+
+        return get_registry().counter(
+            "render_pool_live_lanes_total",
+            "Live rays at each bounce launch of a deep mesh frame, summed "
+            "over launches",
+        )
+
+    @classmethod
+    def _observe_launches(cls, launches) -> None:
+        """Launch occupancy of a whole frame of a deep mesh scene: every
+        bounce is one kernel launch, ``launches[b]`` its (live rays,
+        width) — the width the program picked for that bounce from its
+        live count (integrator.launch_width_ladder), dead lanes sorted to
+        the tail and skipped by blocks."""
+        occupancy = cls._launch_occupancy_histogram()
         for live, width in launches:
             occupancy.observe(int(live) / int(width))
-        pool_launched_lanes_counter().inc(float(launches[:, 1].sum()))
-        pool_live_lanes_counter().inc(float(launches[:, 0].sum()))
+        cls._launched_lanes_counter().inc(float(launches[:, 1].sum()))
+        cls._live_lanes_counter().inc(float(launches[:, 0].sum()))
 
     @staticmethod
     def _observe_render_obs(
-        *, execute_seconds: float, from_cache: bool = False,
-        kernel: str | None = None,
+        *, execute_seconds: float, kernel: str | None = None
     ) -> None:
         """Feed the process-global obs registry (one TPU per process).
 
         The frame's own times are the phase and step histograms the
         worker queue feeds; what is left here is what those cannot say:
-        cache hits, the frames/s gauge bench.py shares, and the roofline
-        pairing.
+        the frames/s gauge bench.py shares, and the roofline pairing.
         """
-        from tpu_render_cluster.obs import get_registry, render_fps_gauge
+        from tpu_render_cluster.obs import render_fps_gauge
 
-        registry = get_registry()
-        if from_cache:
-            # A ray-pool cache hit: this frame's device time was amortized
-            # into the batch that rendered it ahead — its ~tonemap-only
-            # execute time does not belong in the fps gauge (it would
-            # report fantasy per-frame device rates under batching).
-            registry.counter(
-                "render_raypool_cache_hits_total",
-                "Frames served from the ray-pool rendered-ahead cache",
-            ).inc()
+        if execute_seconds <= 0:
             return
-        if execute_seconds > 0:
-            render_fps_gauge(registry).set(1.0 / execute_seconds)
-        if kernel is not None and execute_seconds > 0:
-            # Roofline pairing: this tier's whole frame is one fenced
-            # program execution (render + readback), keyed identically to
-            # the cost capture inside the renderer factory.
+        render_fps_gauge().set(1.0 / execute_seconds)
+        if kernel is not None:
+            # Roofline pairing: the whole frame is one fenced program
+            # execution (render + readback), keyed identically to the
+            # cost capture inside the renderer factory.
             from tpu_render_cluster.obs.profiling import get_profiler
 
             get_profiler().record_execute(kernel, execute_seconds)
@@ -308,6 +210,7 @@ class TpuRaytraceBackend(RenderBackend):
         self, job: BlenderJob, frame_index: int, tile: int | None,
         steps: list[tuple[str, float, float]],
     ) -> FrameRenderTime:
+        import jax.numpy as jnp
         import numpy as np
 
         from tpu_render_cluster.obs import step
@@ -316,18 +219,26 @@ class TpuRaytraceBackend(RenderBackend):
             output_path_for_tile,
             write_image,
         )
-        from tpu_render_cluster.render.integrator import fused_frame_renderer, tonemap
+        from tpu_render_cluster.render.integrator import (
+            fused_frame_renderer,
+            fused_region_renderer,
+            tonemap,
+        )
         from tpu_render_cluster.render.scene import scene_for_job_name
 
         started_process_at = time.time()
 
+        # "Loading" = fetching (or first-building) the compiled renderer
+        # for this scene/config — the analog of Blender's .blend load
+        # phase. Scene construction itself is fused into the XLA program:
+        # one device dispatch per frame instead of dozens of eager array
+        # ops. There is one way to render; what the unit is decides the
+        # program's shape. ``render()`` returns the u8 pixels and, for a
+        # whole frame of a deep mesh scene, the per-bounce (live rays,
+        # launch width) — an output of the frame's own program.
         with step("resolve"):
             scene_name = scene_for_job_name(job.job_name)
-            # Tiled work unit: resolve the tile's pixel region once. All three
-            # execution tiers below serve it through their region paths, which
-            # trace the FULL frame's rays/RNG restricted to these pixels — a
-            # master-assembled grid of tiles is pixel-identical to the
-            # whole-frame render (render/integrator.region_rays_and_seed).
+            shape = (self.width, self.height, self.samples, self.max_bounces)
             region = None
             if tile is not None:
                 from tpu_render_cluster.jobs.tiles import tile_bounds
@@ -340,163 +251,51 @@ class TpuRaytraceBackend(RenderBackend):
                 region = tile_bounds(
                     tile, job.tile_grid, width=self.width, height=self.height
                 )
-            # "Loading" = fetching (or first-building) the compiled renderer for
-            # this scene/config — the analog of Blender's .blend load phase.
-            # Scene construction itself is fused into the XLA program: one
-            # device dispatch per frame instead of dozens of eager array ops.
-            # Wavefront mode has no single cached renderer (its per-bucket
-            # programs compile lazily inside the render — warm() pre-visits
-            # them), so its loading phase is just scene-name resolution; same
-            # for the ray-pool path (one pool program per config, warmed).
-            cache_key = (job.job_name, frame_index, tile)
-            cached_linear = self._raypool_cache.pop(cache_key, None)
-            # Work-ahead for a pool batch: same-job units still queued HERE
-            # with the SAME tile (a pool batch spans frames, not regions).
-            upcoming = [
-                u.frame_index
-                for u in self._upcoming.get(job.job_name, ())
-                if u.tile == tile
-                and u.frame_index != frame_index
-                and (job.job_name, u.frame_index, tile) not in self._raypool_cache
-            ]
-            use_raypool = cached_linear is None and self._use_raypool(
-                scene_name, frames_ahead=len(upcoming)
-            )
-            use_wavefront = (
-                cached_linear is None
-                and not use_raypool
-                and self._use_wavefront(scene_name)
-            )
-            use_sharded = self.sharding in ("tile", "spp") and region is None
-            # The tier that renders this frame (render_tier_frames_total's
-            # label; a frame served from the rendered-ahead cache was
-            # rendered by the pool).
-            if cached_linear is not None or use_raypool:
-                tier = "raypool"
-            elif use_sharded:
-                tier = "sharded"
-            elif use_wavefront:
-                tier = "wavefront"
-            elif region is not None:
+            if region is not None:
+                # A tile unit: the jitted region program (one compile per
+                # tile shape; y0/x0/frame are traced) traces the FULL
+                # frame's rays/RNG restricted to these pixels, so a
+                # master-assembled grid of tiles is pixel-identical to the
+                # whole frame (render/integrator.region_rays_and_seed).
+                # Local sharding is bypassed: the unit is already
+                # sub-frame work.
                 tier = "region"
+                y0, x0, tile_height, tile_width = region
+                region_renderer = fused_region_renderer(
+                    scene_name, self.width, self.height, tile_height,
+                    tile_width, self.samples, self.max_bounces,
+                )
+
+                def render():
+                    linear = region_renderer(
+                        jnp.asarray(frame_index, jnp.float32), y0, x0
+                    )
+                    return tonemap(linear), None
+            elif self.sharding in ("tile", "spp"):
+                from tpu_render_cluster.parallel.sharded_render import sharded_frame_renderer
+
+                tier = "sharded"
+                sharded_renderer = sharded_frame_renderer(
+                    scene_name, *shape, self.sharding
+                )
+
+                def render():
+                    return tonemap(sharded_renderer(frame_index)), None
             else:
                 tier = "masked"
-                renderer = fused_frame_renderer(
-                    scene_name,
-                    self.width,
-                    self.height,
-                    self.samples,
-                    self.max_bounces,
-                    with_live=True,
+                frame_renderer = fused_frame_renderer(
+                    scene_name, *shape, with_live=True
                 )
+
+                def render():
+                    return frame_renderer(frame_index)
         finished_loading_at = time.time()
 
         started_rendering_at = time.time()
-        # The one-program tier's per-bounce (live rays, launch width) (deep
-        # mesh scenes only), an output of the frame's own program.
-        launches = None
-        # Issuing the device's work; the wavefront and raypool drivers open
-        # their own device_wait / readback steps inside, which suspend it.
         with step("dispatch"):
-            if cached_linear is not None:
-                # Rendered ahead by an earlier pool batch of this job: only
-                # the tonemap + readback run now. The batch's device time was
-                # carried by the frame that triggered it — per-frame phase
-                # timings under batching reflect that amortization.
-                display = tonemap(cached_linear)
-            elif use_sharded:
-                from tpu_render_cluster.parallel.sharded_render import render_frame_sharded
-
-                linear = render_frame_sharded(
-                    scene_name,
-                    frame_index,
-                    width=self.width,
-                    height=self.height,
-                    samples=self.samples,
-                    max_bounces=self.max_bounces,
-                    mode=self.sharding,
-                )
-                display = tonemap(linear)
-            elif use_raypool:
-                from tpu_render_cluster.render.raypool import (
-                    raypool_frame_cap,
-                    render_batch_raypool,
-                )
-
-                # One pool window: this unit plus the next queued same-tile
-                # frames of the same job (the queue's hint — all assigned to
-                # THIS worker, so nothing is rendered speculatively). Units
-                # rendered ahead are served from the cache on their own
-                # requests.
-                batch = [frame_index] + upcoming[: raypool_frame_cap() - 1]
-                images = render_batch_raypool(
-                    scene_name,
-                    batch,
-                    width=self.width,
-                    height=self.height,
-                    samples=self.samples,
-                    max_bounces=self.max_bounces,
-                    region=region,
-                )
-                for ahead_frame, image in zip(batch[1:], images[1:]):
-                    self._raypool_cache[(job.job_name, ahead_frame, tile)] = image
-                self._trim_raypool_cache()
-                display = tonemap(images[0])
-            elif use_wavefront:
-                from tpu_render_cluster.render.compaction import (
-                    render_frame_wavefront,
-                    render_region_wavefront,
-                )
-
-                if region is None:
-                    linear = render_frame_wavefront(
-                        scene_name,
-                        frame_index,
-                        width=self.width,
-                        height=self.height,
-                        samples=self.samples,
-                        max_bounces=self.max_bounces,
-                    )
-                else:
-                    y0, x0, tile_height, tile_width = region
-                    linear = render_region_wavefront(
-                        scene_name,
-                        frame_index,
-                        y0=y0,
-                        x0=x0,
-                        tile_height=tile_height,
-                        tile_width=tile_width,
-                        width=self.width,
-                        height=self.height,
-                        samples=self.samples,
-                        max_bounces=self.max_bounces,
-                    )
-                display = tonemap(linear)
-            elif region is not None:
-                # Masked tier, one tile: the jitted region program (one
-                # compile per tile shape; y0/x0/frame are traced). Local
-                # tile/spp sharding is bypassed for cluster-tile units — the
-                # unit is already sub-frame work.
-                from tpu_render_cluster.render.integrator import render_frame_region
-
-                y0, x0, tile_height, tile_width = region
-                linear = render_frame_region(
-                    scene_name,
-                    frame_index,
-                    y0=y0,
-                    x0=x0,
-                    tile_height=tile_height,
-                    tile_width=tile_width,
-                    width=self.width,
-                    height=self.height,
-                    samples=self.samples,
-                    max_bounces=self.max_bounces,
-                )
-                display = tonemap(linear)
-            else:
-                display, launches = renderer(frame_index)
-                if launches is not None:
-                    launches.copy_to_host_async()
+            display, launches = render()
+            if launches is not None:
+                launches.copy_to_host_async()
             # Ask for the pixels now, behind the frame's work in the
             # device's queue, as np.asarray on an unfinished array does:
             # a copy first asked for after the wait below would cost the
@@ -544,10 +343,8 @@ class TpuRaytraceBackend(RenderBackend):
         file_saving_finished_at = time.time()
 
         # Which roofline kernel this frame's fenced execute time pairs
-        # with: only tiers whose frame is ONE program execution keyed by
-        # a factory-side cost capture (the wavefront/raypool drivers pair
-        # their own launches internally; cache hits executed nothing;
-        # sharded programs are per-device and not cost-captured).
+        # with: the programs keyed by a factory-side cost capture
+        # (sharded programs are per-device and not cost-captured).
         kernel = None
         if tier in ("region", "masked"):
             from tpu_render_cluster.obs.profiling import kernel_key
@@ -561,7 +358,6 @@ class TpuRaytraceBackend(RenderBackend):
             self._observe_launches(launches)
         self._observe_render_obs(
             execute_seconds=finished_rendering_at - started_rendering_at,
-            from_cache=cached_linear is not None,
             kernel=kernel,
         )
         return FrameRenderTime(

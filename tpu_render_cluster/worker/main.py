@@ -17,7 +17,6 @@ from pathlib import Path
 
 from tpu_render_cluster.obs import (
     export_chrome_trace,
-    get_tracer,
     write_metrics_snapshot,
 )
 from tpu_render_cluster.protocol import messages as pm
@@ -77,30 +76,6 @@ def build_parser() -> argparse.ArgumentParser:
         type=int,
         default=8,
         help="tpu-raytrace only: samples per pixel (default 8).",
-    )
-    parser.add_argument(
-        "--wavefront",
-        choices=["auto", "off", "force"],
-        default=None,
-        help="tpu-raytrace only: wavefront execution (per-bounce active-ray "
-        "compaction + bucketed relaunch; render/compaction.py). Default "
-        "defers to the TRC_WAVEFRONT env tier; only force turns it on: "
-        "auto renders every scene in the one-program tier (PERF.md §6, "
-        "PR 27; ledger PR 25, 03ph2mesh-1w-fine).",
-    )
-    parser.add_argument(
-        "--raypool",
-        choices=["auto", "off", "force"],
-        default=None,
-        help="tpu-raytrace only: device-resident ray-pool execution "
-        "(cross-frame wavefront batching with in-jit compaction; "
-        "render/raypool.py). Default defers to the TRC_RAYPOOL env tier; "
-        "only force turns it on (the worker then batches its queued "
-        "frames into one pool internally, wire format unchanged): under "
-        "auto frames queued ahead do not engage it (ledger PR 25: "
-        "03ph2mesh-1w-queued 0.7674 frames/s under the pool, "
-        "03ph2mesh-1w-fine 0.8415 without). Takes precedence over "
-        "--wavefront when both are forced.",
     )
     parser.add_argument(
         "--telemetryPort",
@@ -170,8 +145,6 @@ def make_backend(args: argparse.Namespace):
             height=height,
             samples=args.render_samples,
             sharding=None if args.sharding == "none" else args.sharding,
-            wavefront=args.wavefront,
-            raypool=args.raypool,
         )
     return create_backend("mock")
 
@@ -309,14 +282,10 @@ def main(argv: list[str] | None = None) -> int:
         obs_directory = Path(args.base_directory) / "obs"
         worker_name = f"worker-{pm.worker_id_to_string(worker.worker_id)}"
         try:
-            # The process-global tracer rides along: render-path spans (the
-            # wavefront driver's per-bounce track) belong in the same file
-            # as this worker's connection + frame-phase rows.
             export_chrome_trace(
                 obs_directory / f"{worker_name}_trace-events.json",
-                [worker.span_tracer, get_tracer()],
+                [worker.span_tracer],
             )
-            get_tracer().clear()
             # The roofline section (obs/profiling.py): per-kernel XLA
             # cost analysis paired with this worker's measured execute
             # times — the per-kernel achieved-vs-peak evidence the

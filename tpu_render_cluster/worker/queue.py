@@ -42,9 +42,9 @@ FRAME_PHASES = ("queue_wait", "read", "render", "write")
 #   no_work      no queued frame; waiting for the master (draining excluded)
 #   render_call  inside backend.render_frame, thread hop included — the
 #                frame's steps (obs.FRAME_STEPS) lie in it
-#   report       the rest of a frame's turn: the upcoming-frames hint, the
-#                rendering/finished events, trace bookkeeping, feeding the
-#                phase and step series
+#   report       the rest of a frame's turn: the rendering/finished
+#                events, trace bookkeeping, feeding the phase and step
+#                series
 LOOP_STATES = ("no_work", "render_call", "report")
 
 
@@ -306,21 +306,6 @@ class WorkerAutomaticQueue:
         self._enter_loop_state("report")
         frame.state = FrameState.RENDERING
         job_name = frame.job.job_name
-        # Backends that batch internally (ray-pool mode) get the same-job
-        # frames still queued HERE — real assigned work, so batching ahead
-        # never renders a frame this worker doesn't own (see
-        # RenderBackend's hint protocol).
-        note_upcoming = getattr(self._backend, "note_upcoming_frames", None)
-        if note_upcoming is not None:
-            note_upcoming(
-                frame.job,
-                tuple(
-                    f.unit
-                    for f in self._frames
-                    if f.state is FrameState.QUEUED
-                    and f.job.job_name == job_name
-                ),
-            )
         await self._sender.send_message(
             pm.WorkerFrameQueueItemRenderingEvent(
                 job_name, frame.frame_index, trace=frame.trace,
