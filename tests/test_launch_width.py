@@ -82,8 +82,8 @@ def test_the_rung_holds_the_live_rays_and_never_widens(n):
 
 # frame_program's jitted closures, by what shapes the trace: the frame is an
 # operand, so the cases that differ by the frame alone share a program. The
-# ladder function in force is part of the key: a patched ladder is another
-# program, traced when first asked for, under that patch.
+# ladder function and the unsort in force are part of the key: a patched one
+# is another program, traced when first asked for, under that patch.
 _FRAME_PROGRAMS: dict[tuple, object] = {}
 
 
@@ -108,7 +108,7 @@ def frame_program(scene_name, frame_index, *, size, samples, bounces):
             use_tlas=use_tlas, quant=quant, with_live=True,
         )
 
-    key = (scene_name, size, samples, bounces, integrator.launch_width_ladder)
+    key = (scene_name, size, samples, bounces, integrator.launch_width_ladder, integrator._unsort)
     program = _FRAME_PROGRAMS.setdefault(key, jax.jit(render))
     image, launches = program(jnp.asarray(frame_index, jnp.float32))
     return np.asarray(image), None if launches is None else np.asarray(launches)
@@ -223,3 +223,122 @@ def test_with_no_ray_left_the_narrowest_rung_returns_what_was_gathered(monkeypat
     assert np.asarray(full).tolist() == [[n, n], [0, n], [0, n]]
     assert_the_same_image(np.asarray(radiance), np.asarray(reference))
     assert float(jnp.min(radiance)) > 0.0  # the sky's, on every lane
+
+
+# -- the unsort ------------------------------------------------------------------
+
+
+def checked_against_the_scatter(unsort):
+    """``unsort``, with every row whose bits differ from the scatter
+    form's (``zeros.at[lane].set(radiance)``, the unsort until PR 31)
+    replaced by NaN: both forms read the same state, in one program."""
+    import jax
+    import jax.numpy as jnp
+
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    def checked(radiance, lane):
+        by_sort = unsort(radiance, lane)
+        by_scatter = jnp.zeros_like(radiance).at[lane].set(radiance)
+        return jnp.where(bits(by_sort) == bits(by_scatter), by_sort, jnp.nan)
+
+    return checked
+
+
+def one_block_program(scene_name, frame_index, *, size, samples, bounces):
+    """A whole frame of ``size`` x ``size`` at one sample: a ray set of
+    one kernel block or less, whose ladder has one rung and whose program
+    has no ``switch``."""
+    from tpu_render_cluster.render import integrator
+
+    assert integrator.launch_width_ladder(size * size) == (size * size,)
+    return frame_program(scene_name, frame_index, size=size, samples=1, bounces=bounces)
+
+
+UNSORT_CASES = {
+    # name: (program, frame, size, bounces, ladder or None for the real one, widths expected or None)
+    "whole_frame": (frame_program, 295, 32, BOUNCES, None, [2048, 2048, 1024, 1024]),
+    "region_with_rng_lanes": (region_program, 295, 64, BOUNCES, None, None),
+    "tile_sharded": (tile_sharded_program, 295, 64, 3, None, None),
+    "one_rung_ray_set": (one_block_program, 295, 32, BOUNCES, None, [1024] * BOUNCES),
+    "last_bounce_on_a_narrow_rung": (frame_program, 30, 64, BOUNCES, two_steps_down, [8192, 8192, 1024, 512]),
+    # the last bounce at full width, behind a switch all the same
+    "last_bounce_at_full_width": (frame_program, 295, 32, 2, None, [2048, 2048]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNSORT_CASES))
+def test_the_sort_puts_every_ray_where_the_scatter_put_it(case, monkeypatch, interpreted_kernels):
+    """Bit for bit: not one row of the deep program's radiance differs
+    from the same state unsorted by the scatter."""
+    from tpu_render_cluster.render import integrator
+
+    program, frame, size, bounces, ladder, expected = UNSORT_CASES[case]
+    if ladder is not None:
+        monkeypatch.setattr(integrator, "launch_width_ladder", ladder)
+    monkeypatch.setattr(integrator, "_unsort", checked_against_the_scatter(integrator._unsort))
+    image, launches = program(DEEP_SCENE, frame, size=size, samples=2, bounces=bounces)
+    assert np.isfinite(image).all(), f"{np.isnan(image).any(axis=-1).sum()} pixels hold a ray the two forms place differently"
+    assert image.max() > 0.1 and image.std() > 0.01  # a picture, not a constant
+    assert (launches is None) == (expected is None)
+    if launches is not None:
+        assert launches[:, 1].tolist() == expected
+
+
+def test_no_scatter_over_all_the_rays_is_left_in_the_deep_program(interpreted_kernels):
+    """The unsort was the program's one ``scatter`` (set) of n rows, the
+    dearest operation outside the kernels (PERF.md §6 PR 31). What may
+    remain: the narrow rungs' ``scatter-add`` and the updates of a prefix."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+    from tpu_render_cluster.render.mesh import scene_mesh_set
+    from tpu_render_cluster.render.scene import build_scene
+
+    n = 4096
+    _tlas, quant, builder, wide = integrator.resolve_bvh_config()
+    scene = build_scene(DEEP_SCENE, 295)
+    mesh = scene_mesh_set(DEEP_SCENE, 295, builder, wide)
+    assert len(integrator.launch_width_ladder(n)) > 1
+
+    def trace(origins, directions):
+        return integrator.trace_paths(
+            scene, origins, directions, jax.random.PRNGKey(3), max_bounces=BOUNCES,
+            mesh=mesh, quant=quant, rng_lanes=jnp.arange(n)[::-1],
+        )
+
+    def equations(jaxpr):
+        for equation in jaxpr.eqns:
+            yield equation
+            for inner in jax.core.jaxprs_in_params(equation.params):
+                yield from equations(inner)
+
+    rays = jax.ShapeDtypeStruct((n, 3), jnp.float32)
+    found = list(equations(jax.make_jaxpr(trace)(rays, rays).jaxpr))
+    rows = {}  # primitive name: the row counts of what it writes
+    for equation in found:
+        if equation.primitive.name.startswith("scatter"):
+            rows.setdefault(equation.primitive.name, set()).add(equation.invars[2].aval.shape[0])
+    assert n not in rows.get("scatter", ()), rows
+    assert rows.get("scatter-add") and max(rows["scatter-add"]) < n, rows  # the narrow rungs'
+    assert any(e.primitive.name == "sort" and len(e.invars) == 4 for e in found)  # the unsort itself
+
+
+def test_the_unsort_is_the_scatter_under_vmap_too():
+    """``render_frames_batched`` maps the deep program over a batch of
+    frames: each frame's rows go back by its own lanes."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+
+    frames, n = 3, 2048
+    keys = jax.random.split(jax.random.PRNGKey(31), frames)
+    lanes = jax.vmap(lambda key: jax.random.permutation(key, n))(keys).astype(jnp.int32)
+    radiance = jax.random.uniform(keys[0], (frames, n, 3), minval=-4.0, maxval=4.0)
+    by_sort = jax.jit(jax.vmap(integrator._unsort))(radiance, lanes)
+    by_scatter = jax.vmap(lambda r, l: jnp.zeros_like(r).at[l].set(r))(radiance, lanes)
+    assert by_sort.dtype == jnp.float32 and np.array_equal(np.asarray(by_sort), np.asarray(by_scatter))
+    assert not np.array_equal(np.asarray(by_sort), np.asarray(radiance))

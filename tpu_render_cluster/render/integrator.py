@@ -398,6 +398,25 @@ def launch_rung(live, widths):
     return jnp.sum(live <= jnp.asarray(widths[1:], jnp.int32), dtype=jnp.int32)
 
 
+def _unsort(radiance, lane):
+    """``radiance`` [n, 3] with row i moved to row ``lane[i]``; ``lane`` is
+    a permutation of 0..n-1. Done as a sort on the lane with the three
+    radiance columns riding it: that is the permutation's inverse exactly,
+    with no arithmetic on radiance, and a sort streams its operands where
+    the scatter it stands for (``zeros.at[lane].set(radiance)``) pays for
+    every row (on a v5e, 2,097,152 rows: 3.6 ms against 84, PERF.md §6
+    PR 31)."""
+    with jax.named_scope("unsort"):
+        # No two lanes are equal, so an unstable sort has the stable one's
+        # result without the tie-breaking operand XLA adds for it (1.2 ms,
+        # and 16 s of compiling on a cold start).
+        _, *columns = jax.lax.sort(
+            (lane, radiance[:, 0], radiance[:, 1], radiance[:, 2]),
+            num_keys=1, is_stable=False,
+        )
+        return jnp.stack(columns, axis=1)
+
+
 def _trace_paths_deep(
     scene, mesh, origins, directions, seed, *, max_bounces, rng_lanes,
     use_tlas, quant, live_counts,
@@ -430,6 +449,11 @@ def _trace_paths_deep(
     whose rays do not die takes the widest rung on every bounce. Per-ray
     arithmetic is the same at every width: same kernel, same RNG lane,
     the same four additions in the same order.
+
+    After the last bounce the image's order is restored once, after the
+    last ``switch``, by a sort on the carried lane (``_unsort``): a sort
+    streams its operands, so it does not care that a ``conditional``'s
+    results live in HBM, where a scatter pays for every row it places.
     """
     from tpu_render_cluster.render import pallas_kernels
 
@@ -495,10 +519,7 @@ def _trace_paths_deep(
                     contribution, unique_indices=True
                 )
         if bounce + 1 == max_bounces:
-            # The last bounce hands back the image's rays in place: the
-            # unsort, inside the branch that ran the bounce.
-            with jax.named_scope("unsort"):
-                return jnp.zeros_like(radiance).at[lane].set(radiance)
+            return radiance, lane
         new = dict(state, radiance=radiance, lane=lane)
         with jax.named_scope("resort"):
             # The next bounce's sort, over this bounce's width: its live
@@ -560,7 +581,7 @@ def _trace_paths_deep(
             )
         else:
             state = bounce_at(n, bounce, state)
-    return state  # the last bounce's: the radiance, unsorted
+    return _unsort(*state)  # the last bounce's radiance and lane
 
 
 def trace_paths(
