@@ -1,0 +1,140 @@
+#!/usr/bin/env python3
+"""On-chip microbenchmark of the streamed BLAS walk (03_physics-2-scan).
+
+    chiprun -- python scripts/bench-scan-walk.py [samples ...]
+    JAX_PLATFORMS=cpu TRC_PALLAS=1 python scripts/bench-scan-walk.py --rehearse 1
+
+For each ``samples`` (default 8 and 1): the frame program's compile and
+three frames' times with each bounce launch's (live, width) and (node
+visits, treelet fetches); then bounce 0 and bounce 1 alone at full width
+(one launch each, rays sorted as the frame program sorts them); then one
+round of the glue the other walk design would pay between launches (a sort
+of the ray keys, a packed gather of the ray state, a gather of node rows).
+One JSON line per stage on standard output; the same lines in
+chiprun_out/scan_walk.jsonl. A microbenchmark of kernels, not the served
+path: benchmark/run.py measures that. Off a TPU it exits 2 and times
+nothing; `--rehearse` walks through it at 32x32 on whatever device there is
+(every line then says `"rehearsal": true`: counts, never times to quote).
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from tpu_render_cluster.render import integrator, mesh as mesh_module, pallas_kernels  # noqa: E402
+from tpu_render_cluster.render.camera import scene_camera  # noqa: E402
+from tpu_render_cluster.render.scene import build_scene  # noqa: E402
+from tpu_render_cluster.utils.accelerator import configure_compile_cache  # noqa: E402
+
+SCENE, BOUNCES, FRAME = "03_physics-2-scan", 4, 295
+REHEARSE = "--rehearse" in sys.argv[1:]
+SIZE = 32 if REHEARSE else 512
+OUT = ROOT / "chiprun_out" / "scan_walk.jsonl"
+
+
+def say(stage: str, **fields) -> None:
+    line = json.dumps({"stage": stage, **({"rehearsal": True} if REHEARSE else {}), **fields})
+    print(line, flush=True)
+    OUT.parent.mkdir(parents=True, exist_ok=True)
+    with OUT.open("a") as handle:
+        handle.write(line + "\n")
+
+
+def timed(fn, *args, repeats: int = 3):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    times = []
+    for _ in range(repeats):
+        start = time.perf_counter()
+        out = fn(*args)
+        jax.block_until_ready(out)
+        times.append(time.perf_counter() - start)
+    return out, times
+
+
+def main(argv: list[str]) -> int:
+    configure_compile_cache()
+    device = jax.devices()[0]
+    say("device", platform=device.platform, kind=device.device_kind)
+    if device.platform != "tpu" and not REHEARSE:
+        print(f"bench-scan-walk: a {device.platform} is not a TPU: nothing timed (--rehearse walks through it)", file=sys.stderr)
+        return 2
+    start = time.perf_counter()
+    stream = mesh_module.scene_blas_stream(SCENE)
+    jax.block_until_ready(stream)
+    say("bvh_build", seconds=time.perf_counter() - start, bytes=mesh_module.geometry_bytes(mesh_module.cached_mesh_bvh("scan")),
+        memory=device.memory_stats() and device.memory_stats().get("bytes_in_use"))
+
+    for samples in [int(a) for a in argv if a != "--rehearse"] or [8, 1]:
+        start = time.perf_counter()
+        render = integrator.fused_frame_renderer(SCENE, SIZE, SIZE, samples, BOUNCES, with_live=True)
+        out = render(jnp.float32(FRAME))
+        jax.block_until_ready(out)
+        first = time.perf_counter() - start
+        times = []
+        for frame in (FRAME, FRAME + 1, FRAME + 100):
+            start = time.perf_counter()
+            image, live, walk = render(jnp.float32(frame))
+            jax.block_until_ready(image)
+            times.append(time.perf_counter() - start)
+        say("frame_program", samples=samples, first_call_s=first, frame_s=times,
+            live=np.asarray(live).tolist(), walk=np.asarray(walk).tolist(),
+            image_std=float(np.asarray(image).std()),
+            peak=device.memory_stats() and device.memory_stats().get("peak_bytes_in_use"))
+
+        # Bounces 0 and 1 alone at full width, sorted as the program sorts.
+        scene = build_scene(SCENE, FRAME)
+        mesh = mesh_module.scene_mesh_set(SCENE, FRAME, stream=stream)
+        camera = scene_camera(SCENE, FRAME)
+        base_key = integrator.tile_base_key(jnp.float32(FRAME), 0, 0)
+        origins, directions = integrator.flat_sample_rays(
+            camera, base_key, width=SIZE, height=SIZE, y0=0, x0=0,
+            tile_height=SIZE, tile_width=SIZE, samples=samples,
+        )
+        n = origins.shape[0]
+        alive = jnp.ones((n,), bool)
+        order = jnp.argsort(pallas_kernels.initial_mesh_sort_keys(mesh, origins, directions, alive))
+        lane = jnp.arange(n, dtype=jnp.int32)[order]
+        bounce = jax.jit(
+            pallas_kernels.mesh_bounce_pallas, static_argnames=("total_bounces", "use_tlas", "quant"),
+        )
+        state = (origins[order], directions[order], jnp.ones((n, 3), jnp.float32), alive)
+        for index in (0, 1):
+            out, times = timed(
+                lambda s, i=index: bounce(
+                    scene, mesh, *s, 7, jnp.int32(i), total_bounces=BOUNCES, lane=lane,
+                    live_count=jnp.sum(s[3], dtype=jnp.int32), use_tlas=True, quant=0,
+                ), state,
+            )
+            _, o2, d2, thr2, alive2, keys, walk = out
+            say("bounce_alone", samples=samples, bounce=index, rays=n, seconds=times,
+                live_in=int(jnp.sum(state[3])), walk=np.asarray(walk).tolist())
+            order = jnp.argsort(keys)
+            lane = lane[order]
+            state = (o2[order], d2[order], thr2[order], alive2[order])
+
+        # One round of design (b)'s glue at this width.
+        keys32 = keys
+        packed = jnp.concatenate([o2, d2, thr2, thr2], axis=1)
+        table = stream.top_bounds.reshape(-1, 6)
+        node = (keys32 % table.shape[0]).astype(jnp.int32)
+        _, sort_times = timed(jax.jit(jnp.argsort), keys32)
+        _, gather_times = timed(jax.jit(lambda p, o: p[o]), packed, order)
+        _, node_times = timed(jax.jit(lambda t, i: t[i]), table, node)
+        say("design_b_round_glue", samples=samples, rays=n, sort_s=sort_times,
+            packed_gather_s=gather_times, node_row_gather_s=node_times)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

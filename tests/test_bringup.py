@@ -315,3 +315,74 @@ def test_bounce_kernels_compile_with_mosaic(tmp_path):
         pytest.skip(result.stdout.strip())
     assert result.returncode == 0, result.stderr[-3000:]
     assert result.stdout.count("COMPILED") == 3
+
+
+_COMPILE_STREAMED_BOUNCE = """
+import sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+try:
+    topology = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+except Exception as error:  # no usable libtpu here: nothing to compile with
+    print("NO_TOPOLOGY", error)
+    sys.exit(0)
+from tpu_render_cluster.render import mesh as mesh_module, pallas_kernels as pk
+from tpu_render_cluster.render.integrator import launch_width_ladder
+from tpu_render_cluster.render.scene import build_mesh_instances, build_scene
+
+pk._interpret = lambda: False
+on_chip = SingleDeviceSharding(topology.devices[0])
+
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+def tree(value):
+    return jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), value)
+
+# The configuration's BLAS by its shapes alone (871,200 triangles: 1,024
+# treelets of 64 leaf slots under a top of 2,047 nodes); nothing is built.
+treelets, leaves, top = 1024, mesh_module.TREELET_LEAVES, 2047
+stream = mesh_module.BlasStream(
+    tri=spec((treelets, 2 * leaves, 128), jnp.float32),
+    nodes=spec((treelets * 1024,), jnp.float32),
+    top_bounds=spec((top * 6,), jnp.float32), top_meta=spec((top,), jnp.int32),
+    root=spec((2, 3), jnp.float32),
+)
+scene = tree(build_scene("03_physics-2-scan", 1))
+instances = tree(build_mesh_instances("03_physics-2-scan", 1))
+i32 = spec((), jnp.int32)
+
+def bounce(scene, stream, instances, origins, directions, throughput, alive, lane, live):
+    mesh = mesh_module.MeshSet(mesh_module.traced_stream_bvh(stream), instances)
+    return pk.mesh_bounce_pallas(
+        scene, mesh, origins, directions, throughput, alive, 7, jnp.int32(1),
+        total_bounces=4, lane=lane, live_count=live, use_tlas=True,
+    )
+
+widths = launch_width_ladder(512 * 512)
+for width in (widths[0], widths[-1]):
+    vec = spec((width, 3), jnp.float32)
+    compiled = jax.jit(bounce).trace(
+        scene, stream, instances, vec, vec, vec, spec((width,), jnp.bool_),
+        spec((width,), jnp.int32), i32,
+    ).lower(lowering_platforms=("tpu",)).compile()
+    assert "mesh_bounce_streamed" in compiled.as_text()
+    print("COMPILED streamed bounce", width)
+"""
+
+
+def test_the_streamed_bounce_kernel_compiles_with_mosaic(tmp_path):
+    """The bounce kernel over a BLAS in HBM (ISSUE 32: copies from a tiled
+    1-D HBM array into SMEM scratch, a roll by a traced amount, a float
+    read as an integer on the scalar side), at the configuration's table
+    shapes and the widest and narrowest rung of a 1 spp frame, through the
+    real compiler. A subprocess: it loads libtpu."""
+    result = _run(
+        _COMPILE_STREAMED_BOUNCE, TRC_PALLAS="1", TPU_LOG_DIR=str(tmp_path)
+    )
+    if "NO_TOPOLOGY" in result.stdout:
+        pytest.skip(result.stdout.strip())
+    assert result.returncode == 0, result.stderr[-3000:]
+    assert result.stdout.count("COMPILED") == 2

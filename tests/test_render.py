@@ -37,7 +37,10 @@ def test_camera_rays_unit_norm():
     np.testing.assert_allclose(norms, 1.0, atol=1e-5)
 
 
-@pytest.mark.parametrize("scene_name", SCENE_NAMES)
+# The scan family's BLAS is streamed from HBM by the Pallas bounce kernel
+# alone and its mesh takes seconds to build: tests/test_scan_stream.py renders
+# it, over a small mesh.
+@pytest.mark.parametrize("scene_name", [name for name in SCENE_NAMES if name != "03_physics-2-scan"])
 def test_render_all_scenes(scene_name):
     image = np.asarray(tonemap(render_frame(scene_name, 5, **SMALL)))
     assert image.shape == (64, 64, 3)

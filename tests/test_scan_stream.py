@@ -1,0 +1,522 @@
+"""A BLAS too large for VMEM is streamed from HBM by treelet: the scene
+family ``03_physics-2-scan`` (ISSUE 32).
+
+Small and seeded: a 2,048-triangle scan mesh (``make_scan_mesh(grid=32)``),
+streaming forced by a small treelet budget, the Pallas interpreter on the
+CPU. What is held:
+
+- the vectorised build keeps the invariants the recursive one's tests
+  assert, and its walk finds what brute force finds;
+- the treelets partition the tree;
+- the streamed walk gives the resident walk's bounce bit for bit over the
+  same tree (state out, radiance, sort keys), and both give brute force's
+  hit: the distance and the triangle through the new origin (hit point
+  pushed along the triangle's normal), the instance through the throughput
+  (its albedo), the any-hit through the sun term;
+- the family's frame agrees with the benchmark's independent reference
+  (``plain_tracer_accel``) by the check's own rule, and that reference agrees
+  with ``plain_tracer`` where both can trace;
+- resident or streamed follows from the tables' bytes, and the icosphere
+  scene's program holds the kernels it held.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+import pytest
+
+SCAN_SCENE = "03_physics-2-scan"
+SMALL_GRID = 32  # 2 * 32 * 32 = 2,048 triangles
+SMALL_TREELET = 8
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    monkeypatch.setenv("TRC_PALLAS", "1")
+
+
+@pytest.fixture
+def small_scan_family(monkeypatch, interpreted_kernels):
+    """The scan family over the 2,048-triangle mesh, streamed: the mesh's
+    size, the VMEM budget and the treelet size are the module's constants,
+    so a test changes them there and nowhere in the program."""
+    from tpu_render_cluster.render import integrator
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    monkeypatch.setattr(
+        mesh_module, "make_scan_mesh",
+        functools.partial(mesh_module.make_scan_mesh, grid=SMALL_GRID),
+    )
+    monkeypatch.setattr(mesh_module, "RESIDENT_VMEM_BUDGET", 0)
+    monkeypatch.setattr(mesh_module, "TREELET_LEAVES", SMALL_TREELET)
+    mesh_module.reset_geometry_cache()
+    integrator.fused_frame_renderer.cache_clear()
+    integrator.fused_region_renderer.cache_clear()
+    yield mesh_module
+    mesh_module.reset_geometry_cache()
+    integrator.fused_frame_renderer.cache_clear()
+    integrator.fused_region_renderer.cache_clear()
+
+
+def small_tree(treelet_leaves=None):
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    vertices, faces = mesh_module.make_scan_mesh(grid=SMALL_GRID)
+    return mesh_module.build_bvh(
+        vertices, faces, builder="morton", treelet_leaves=treelet_leaves
+    ), faces
+
+
+# -- the build -------------------------------------------------------------------
+
+
+def test_the_scan_mesh_is_closed_seeded_and_irregular():
+    from tpu_render_cluster.render.mesh import make_scan_mesh
+
+    vertices, faces = make_scan_mesh(grid=SMALL_GRID)
+    again, _ = make_scan_mesh(grid=SMALL_GRID)
+    assert faces.shape == (2 * SMALL_GRID * SMALL_GRID, 3)
+    np.testing.assert_array_equal(vertices, again)
+    edges = np.sort(np.concatenate([faces[:, [0, 1]], faces[:, [1, 2]], faces[:, [2, 0]]]), axis=1)
+    _, shared = np.unique(edges, axis=0, return_counts=True)
+    assert (shared == 2).all(), "every edge belongs to two triangles: a closed surface"
+    corners = vertices[faces]
+    areas = 0.5 * np.linalg.norm(np.cross(corners[:, 1] - corners[:, 0], corners[:, 2] - corners[:, 0]), axis=1)
+    assert areas.min() > 0 and areas.max() > 3 * np.median(areas)
+    assert np.abs(vertices).max() <= 0.5  # inside the unit box the other meshes fill
+
+
+@pytest.mark.parametrize("source", ["scan", "box", "icosphere"])
+def test_the_vectorised_build_keeps_the_recursive_builds_invariants(source):
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    if source == "scan":
+        vertices, faces = mesh_module.make_scan_mesh(grid=SMALL_GRID)
+    else:
+        vertices, faces = mesh_module.make_box() if source == "box" else mesh_module.make_icosphere(2)
+    bvh = mesh_module.build_bvh(vertices, faces, builder="morton")
+    n_nodes = bvh.skip.shape[0]
+    skip, count, first = (np.asarray(a) for a in (bvh.skip, bvh.count, bvh.first))
+    assert (skip > np.arange(n_nodes)).all() and (skip <= n_nodes).all() and skip[0] == n_nodes
+    leaves = count > 0
+    assert (first[leaves] % mesh_module.LEAF_SIZE == 0).all()
+    assert (count[leaves] <= mesh_module.LEAF_SIZE).all()
+    assert bvh.v0.shape[0] % mesh_module.LEAF_SIZE == 0
+    assert int(count.sum()) == len(faces)
+    assert sorted(first[leaves]) == list(range(0, bvh.v0.shape[0], mesh_module.LEAF_SIZE))
+    # a leaf's box holds its triangles, a node's box its subtree's boxes
+    low, high = np.asarray(bvh.bounds_min), np.asarray(bvh.bounds_max)
+    v0, e1, e2 = (np.asarray(a) for a in (bvh.v0, bvh.e1, bvh.e2))
+    for node in np.flatnonzero(leaves):
+        rows = slice(first[node], first[node] + count[node])
+        points = np.concatenate([v0[rows], v0[rows] + e1[rows], v0[rows] + e2[rows]])
+        assert (points >= low[node] - 1e-6).all() and (points <= high[node] + 1e-6).all()
+    for node in np.flatnonzero(~leaves):
+        inside = slice(node + 1, skip[node])
+        assert (low[inside] >= low[node]).all() and (high[inside] <= high[node]).all()
+    # the same triangles, in another order
+    ours = np.sort(np.round(v0[np.abs(e1).sum(1) > 0] * 1e5).astype(np.int64), axis=0)
+    theirs = np.sort(np.round(vertices[faces][:, 0] * 1e5).astype(np.int64), axis=0)
+    np.testing.assert_array_equal(ours, theirs)
+
+
+def test_the_walk_of_the_vectorised_tree_finds_what_brute_force_finds():
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render.mesh import intersect_bvh_packet, intersect_triangles_brute
+
+    bvh, _ = small_tree()
+    rng = np.random.default_rng(4)
+    origins = rng.normal(size=(256, 3)).astype(np.float32) * 0.1 + np.array([0, 0, -2], np.float32)
+    directions = np.array([0, 0, 1], np.float32) + rng.normal(size=(256, 3)).astype(np.float32) * 0.15
+    directions /= np.linalg.norm(directions, axis=1, keepdims=True)
+    origins, directions = jnp.asarray(origins), jnp.asarray(directions)
+    t_brute, index_brute = intersect_triangles_brute(bvh, origins, directions)
+    t_walk, index_walk = intersect_bvh_packet(bvh, origins, directions)
+    hit = np.asarray(t_brute) < 1e29
+    assert hit.sum() > 100
+    np.testing.assert_allclose(np.asarray(t_walk), np.asarray(t_brute), rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(index_walk)[hit], np.asarray(index_brute)[hit])
+
+
+@pytest.mark.parametrize("treelet_leaves", [8, 16, 64])
+def test_the_treelets_partition_the_tree(treelet_leaves):
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    bvh, _ = small_tree(treelet_leaves)
+    stream = bvh.stream
+    n_treelets = stream.tri.shape[0]
+    assert mesh_module.treelet_leaves(stream) == treelet_leaves
+    words = mesh_module.treelet_node_words(stream)
+    assert words % 1024 == 0  # a copy out of a 1-D HBM array starts on its tiling
+    nodes = np.asarray(stream.nodes).reshape(n_treelets, words // 8, 8)
+    meta = nodes[:, :, 6].astype(np.int64)
+    local_skip, local_leaf, count = meta & 0xFF, (meta >> 8) & 0xFF, meta >> 16
+    n_local = local_skip[:, 0]
+    rows = np.asarray(stream.tri).reshape(n_treelets, treelet_leaves // 8, 16, 8, 16)
+    seen = []
+    for t in range(n_treelets):
+        used = np.arange(words // 8) < n_local[t]
+        assert (local_skip[t][used] > np.arange(words // 8)[used]).all()
+        assert (local_skip[t][used] <= n_local[t]).all()
+        leaves = used & (count[t] > 0)
+        assert 1 <= leaves.sum() <= treelet_leaves
+        assert sorted(local_leaf[t][leaves]) == list(range(leaves.sum()))
+        for leaf in local_leaf[t][leaves]:
+            seen.append(rows[t, leaf // 8, :, leaf % 8, 0:3])
+    # every leaf of the tree in one treelet, its rows the tree's rows
+    assert len(seen) == int((np.asarray(bvh.count) > 0).sum())
+    np.testing.assert_array_equal(
+        np.sort(np.concatenate(seen).round(6), axis=0), np.sort(np.asarray(bvh.v0).round(6), axis=0)
+    )
+    top_meta = np.asarray(stream.top_meta).astype(np.int64)
+    top_skip, top_treelet = top_meta & 0xFFFF, (top_meta >> 16) - 1
+    assert (top_skip > np.arange(len(top_meta))).all() and top_skip[0] == len(top_meta)
+    assert sorted(top_treelet[top_treelet >= 0]) == list(range(n_treelets))
+    np.testing.assert_array_equal(np.asarray(stream.root), [np.asarray(bvh.bounds_min)[0], np.asarray(bvh.bounds_max)[0]])
+
+
+def test_resident_or_streamed_follows_the_tables_bytes():
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    for kind in ("box", "icosphere"):
+        bvh = mesh_module.cached_mesh_bvh(kind)
+        assert bvh.stream is None
+        assert mesh_module.resident_table_bytes(bvh.v0.shape[0], bvh.skip.shape[0]) < mesh_module.RESIDENT_VMEM_BUDGET
+    small, _ = small_tree()
+    assert small.stream is None  # 2,048 triangles fit: 4 MiB of padded rows
+    assert mesh_module.geometry_bytes(small)["hbm"] == 0 and mesh_module.geometry_bytes(small)["vmem"] > 0
+    forced, _ = small_tree(SMALL_TREELET)
+    where = mesh_module.geometry_bytes(forced)
+    assert where["hbm"] > 2048 * 64 and where["vmem"] == 0 and 0 < where["smem"] < 8192
+    # the configuration's mesh, from its shapes: 871,200 rows, 108,899 nodes
+    assert mesh_module.resident_table_bytes(871_200, 108_899) > 200 * mesh_module.RESIDENT_VMEM_BUDGET
+    assert 2 * mesh_module.SCAN_GRID ** 2 == 871_200
+
+
+# -- the walk --------------------------------------------------------------------
+
+
+def bounce_inputs(n=1024, k=6, seed=1):
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import mesh as mesh_module
+    from tpu_render_cluster.render.scene import build_scene
+
+    rng = np.random.default_rng(seed)
+    scene = build_scene("03_physics-2-mesh", 295.0)
+    scene = scene._replace(radii=jnp.zeros_like(scene.radii))  # no spheres: plane, sky and mesh
+    instances = mesh_module.MeshInstances(
+        rotation=mesh_module.rotation_y(jnp.asarray(rng.random(k) * 6.28, jnp.float32)),
+        translation=jnp.asarray(
+            np.stack([rng.random(k) * 6 - 3, 0.6 + rng.random(k), rng.random(k) * 6 - 3], axis=1), jnp.float32
+        ),
+        albedo=jnp.asarray(0.2 + 0.7 * rng.random((k, 3)), jnp.float32),
+        scale=jnp.asarray(0.8 + rng.random(k), jnp.float32),
+    )
+    origins = np.tile([[8.0, 5.0, 8.0]], (n, 1)) + rng.normal(size=(n, 3)) * 0.01
+    target = np.asarray(instances.translation)[rng.integers(0, k, n)] + rng.normal(size=(n, 3)) * 0.4
+    directions = target - origins
+    directions /= np.linalg.norm(directions, axis=1, keepdims=True)
+    return scene, instances, jnp.asarray(origins, jnp.float32), jnp.asarray(directions, jnp.float32)
+
+
+def brute_force_bounce(bvh, scene, instances, origins, directions):
+    """Nearest mesh hit and the shadow ray's fate, by testing every
+    triangle of every instance: (t, world normal, albedo, shadowed)."""
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    def nearest(origins, directions):
+        best = np.full(origins.shape[0], 1e30, np.float32)
+        normal = np.zeros((origins.shape[0], 3), np.float32)
+        albedo = np.zeros((origins.shape[0], 3), np.float32)
+        for k in range(instances.scale.shape[0]):
+            local_o, local_d = mesh_module._rays_to_object_space(instances, k, origins, directions)
+            t, index = mesh_module.intersect_triangles_brute(bvh, local_o, local_d)
+            t, index = np.asarray(t), np.asarray(index)
+            closer = t < best
+            world = np.asarray(mesh_module._normals_to_world(instances.rotation[k], bvh.normal[index]))
+            best = np.where(closer, t, best)
+            normal = np.where(closer[:, None], world, normal)
+            albedo = np.where(closer[:, None], np.asarray(instances.albedo[k])[None], albedo)
+        return best, normal, albedo
+
+    t, normal, albedo = nearest(origins, directions)
+    facing = (normal * np.asarray(directions)).sum(axis=1) < 0
+    normal = np.where(facing[:, None], normal, -normal)
+    start = np.asarray(origins) + np.asarray(directions) * t[:, None] + normal * 4e-3
+    sun = np.broadcast_to(np.asarray(scene.sun_direction), start.shape)
+    shadow_t, _, _ = nearest(jnp.asarray(start, jnp.float32), jnp.asarray(sun, jnp.float32))
+    return t, normal, albedo, shadow_t < 1e29
+
+
+@pytest.mark.parametrize("use_tlas", [False, True], ids=["flat", "tlas"])
+def test_the_streamed_walk_is_the_resident_walk_bit_for_bit_and_brute_forces_hit(use_tlas, interpreted_kernels):
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import mesh as mesh_module
+    from tpu_render_cluster.render import pallas_kernels
+
+    bvh, _ = small_tree(SMALL_TREELET)
+    scene, instances, origins, directions = bounce_inputs()
+    n = origins.shape[0]
+
+    def bounce(tree):
+        return pallas_kernels.mesh_bounce_pallas(
+            scene, mesh_module.MeshSet(tree, instances), origins, directions,
+            jnp.ones((n, 3), jnp.float32), jnp.ones((n,), bool), 7, 0,
+            total_bounces=4, use_tlas=use_tlas,
+        )
+
+    resident = bounce(bvh._replace(stream=None))
+    streamed = bounce(bvh)
+    assert len(resident) == 6 and len(streamed) == 7
+    for ours, theirs in zip(streamed[:5], resident[:5]):
+        np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
+    if use_tlas:
+        np.testing.assert_array_equal(np.asarray(streamed[5]), np.asarray(resident[5]))
+    visits, fetches = (int(x) for x in streamed[6])
+    assert visits > fetches > 0
+
+    # ... and both are what testing every triangle finds.
+    contribution, new_origins, _, throughput, alive = (np.asarray(x) for x in streamed[:5])
+    t, normal, albedo, shadowed = brute_force_bounce(bvh, scene, instances, origins, directions)
+    o, d = np.asarray(origins), np.asarray(directions)
+    t_plane = np.where(d[:, 1] < -1e-8, -o[:, 1] / np.minimum(d[:, 1], -1e-8), 1e30)
+    on_mesh = t < t_plane
+    assert on_mesh.sum() > 300 and (~on_mesh).sum() > 50
+    assert alive[on_mesh].all()
+    expected = o + d * t[:, None] + normal * 4e-3
+    np.testing.assert_allclose(new_origins[on_mesh], expected[on_mesh], rtol=0, atol=2e-5)  # t and the triangle
+    np.testing.assert_allclose(throughput[on_mesh], albedo[on_mesh], rtol=1e-6)  # the instance
+    lit = contribution.sum(axis=1) > 0  # no sphere emits and the sky is for misses: the sun term alone
+    facing_sun = (normal @ np.asarray(scene.sun_direction)) > 1e-3
+    ask = on_mesh & facing_sun
+    assert (lit[ask] == ~shadowed[ask]).mean() > 0.995  # the any-hit walk
+
+
+# -- the family, and the benchmark's reference ------------------------------------
+
+
+def scene_arrays(scene_name, frame):
+    """What ``benchmark/lib/region_child.py`` hands the reference."""
+    from tpu_render_cluster.render.camera import scene_camera
+    from tpu_render_cluster.render.mesh import scene_mesh_set
+    from tpu_render_cluster.render.scene import build_scene
+
+    scene = {key: np.asarray(value) for key, value in build_scene(scene_name, frame)._asdict().items()}
+    camera = {key: np.asarray(value) for key, value in scene_camera(scene_name, frame)._asdict().items()}
+    mesh_set = scene_mesh_set(scene_name, frame)
+    mesh = {key: np.asarray(getattr(mesh_set.bvh, key)) for key in ("v0", "e1", "e2")}
+    mesh.update({key: np.asarray(value) for key, value in mesh_set.instances._asdict().items()})
+    return scene, camera, mesh
+
+
+@pytest.mark.time_limit(420)
+def test_the_scan_familys_frame_agrees_with_the_independent_reference(small_scan_family):
+    from benchmark.lib import check
+    from benchmark.reference import plain_tracer_accel
+    from tpu_render_cluster.render import integrator
+
+    size, samples, frame = 32, 2, 300
+    assert small_scan_family.cached_mesh_bvh("scan").stream is not None
+    linear = integrator.render_frame_region(
+        SCAN_SCENE, frame, y0=0, x0=0, tile_height=size, tile_width=size,
+        width=size, height=size, samples=samples, max_bounces=4,
+    )
+    served = np.asarray(integrator.tonemap(linear))
+    assert served.std() > 5.0
+    scene, camera, mesh = scene_arrays(SCAN_SCENE, frame)
+    assert mesh["v0"].shape[0] == 2 * SMALL_GRID * SMALL_GRID
+    replicas = plain_tracer_accel.render_crop_replicas(
+        scene, camera, mesh, width=size, height=size, y0=0, x0=0, size=size, samples=samples,
+        max_bounces=4, replicas=8, seed=11, min_triangles=mesh["v0"].shape[0],
+    )
+    ok, excess = check.independent_agreement(served, replicas, block=16, sigmas=5.0, abs_levels=2.5)
+    assert ok, f"a block mean lies {excess:.2f} levels beyond the reference's own spread"
+    # ... of which 2.5 levels are JPEG's, which this frame never met
+    assert check.independent_agreement(served, replicas, block=16, sigmas=5.0, abs_levels=0.0)[0]
+
+
+def test_the_scan_familys_frame_program_returns_the_walks_counts(small_scan_family):
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+
+    image, live, walk = integrator.fused_frame_renderer(SCAN_SCENE, 32, 32, 2, 4, with_live=True)(jnp.float32(295))
+    assert image.shape == (32, 32, 3) and image.dtype == jnp.uint8
+    live, walk = np.asarray(live), np.asarray(walk)
+    assert live.shape == walk.shape == (4, 2)
+    assert (walk[:, 0] >= walk[:, 1]).all() and walk[0, 1] > 0
+    # without the counts asked for, the same picture
+    plain = integrator.fused_frame_renderer(SCAN_SCENE, 32, 32, 2, 4)(jnp.float32(295))
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(image))
+
+
+def test_off_the_kernels_a_streamed_scene_says_what_it_needs(small_scan_family, monkeypatch):
+    from tpu_render_cluster.render.integrator import render_frame
+
+    monkeypatch.setenv("TRC_PALLAS", "0")
+    with pytest.raises(NotImplementedError, match="TRC_PALLAS=1"):
+        render_frame(SCAN_SCENE, 5, width=16, height=16, samples=1, max_bounces=2)
+
+
+@pytest.mark.parametrize("crop", [(288, 224), (240, 304), (304, 64)])
+def test_the_accelerated_reference_is_the_plain_one_on_the_icosphere_scene(crop):
+    from benchmark.reference import plain_tracer, plain_tracer_accel
+
+    scene, camera, mesh = scene_arrays("03_physics-2-mesh", 304)
+    shape = dict(
+        width=512, height=512, y0=crop[0], x0=crop[1], size=24, samples=2, max_bounces=4, replicas=2, seed=5,
+    )
+    plain = plain_tracer.render_crop_replicas(scene, camera, mesh, **shape)
+    accelerated = plain_tracer_accel.render_crop_replicas(scene, camera, mesh, min_triangles=320, **shape)
+    assert plain.std() > 5.0
+    # Equal to rounding: display levels of 255. One path in thousands may
+    # land on the other side of an edge (another order of the same sums),
+    # which moves its pixel and nothing else: the random numbers drawn after
+    # it are the same ones.
+    apart = (np.abs(accelerated - plain) > 0.05).any(axis=-1)
+    assert apart.sum() <= 2, f"{apart.sum()} of {apart.size} pixels differ"
+    np.testing.assert_allclose(accelerated, plain, rtol=0, atol=2.0)
+
+
+@pytest.mark.parametrize("case", ["no mesh", "an icosphere", "a sphere family's arrays"])
+def test_the_accelerated_reference_refuses_what_is_not_the_configurations_scene(case):
+    from benchmark.reference import plain_tracer_accel
+
+    assert plain_tracer_accel.stated_bodies() == [("03ph2scan-480f-1w", 48, 871_200)]
+    scene, camera, mesh = scene_arrays("03_physics-2-mesh", 304)
+    handed = {"no mesh": None, "an icosphere": mesh, "a sphere family's arrays": {}}[case]
+    with pytest.raises(plain_tracer_accel.Refused, match="871200"):
+        plain_tracer_accel.render_crop_replicas(
+            scene, camera, handed, width=64, height=64, y0=0, x0=0, size=16, samples=1,
+            max_bounces=2, replicas=1, seed=1,
+        )
+
+
+@pytest.mark.parametrize("case", ["whole", "one dropped", "one held twice", "one moved", "padded"])
+def test_the_accelerated_reference_takes_only_a_closed_surface(case):
+    """The one check of the handed-over triangles that does not come from
+    the program: every edge is shared by exactly two triangles."""
+    from benchmark.reference import plain_tracer_accel
+
+    scene, camera, mesh = scene_arrays("03_physics-2-mesh", 304)
+    v0, e1, e2 = (np.array(mesh[key], np.float32) for key in ("v0", "e1", "e2"))
+    if case == "one dropped":
+        v0, e1, e2 = v0[1:], e1[1:], e2[1:]
+    elif case == "one held twice":
+        v0, e1, e2 = (np.concatenate([a, a[7:8]]) for a in (v0, e1, e2))
+    elif case == "one moved":
+        v0[100] += np.float32(1e-3)
+    elif case == "padded":  # rows of zeros are padding, not triangles
+        v0, e1, e2 = (np.concatenate([a, np.zeros((5, 3), np.float32)]) for a in (v0, e1, e2))
+    handed = {**mesh, "v0": v0, "e1": e1, "e2": e2}
+    render = lambda: plain_tracer_accel.render_crop_replicas(  # noqa: E731
+        scene, camera, handed, width=64, height=64, y0=24, x0=24, size=8, samples=1,
+        max_bounces=1, replicas=1, seed=1, min_triangles=300,
+    )
+    real = (np.abs(e1).sum(axis=1) > 0) & (np.abs(e2).sum(axis=1) > 0)
+    unshared = plain_tracer_accel.unshared_edges(v0[real], e1[real], e2[real])
+    if case in ("whole", "padded"):
+        assert unshared == 0 and real.sum() == 320
+        assert render().shape == (1, 8, 8, 3)
+    else:
+        assert unshared >= 2
+        with pytest.raises(plain_tracer_accel.Refused, match="not shared by exactly two"):
+            render()
+
+
+def test_the_scan_mesh_is_a_closed_surface_by_the_references_own_count():
+    from benchmark.reference import plain_tracer_accel
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    vertices, faces = mesh_module.make_scan_mesh()
+    corners = vertices[faces].astype(np.float32)
+    v0, e1, e2 = corners[:, 0], corners[:, 1] - corners[:, 0], corners[:, 2] - corners[:, 0]
+    assert len(v0) == 871_200 and plain_tracer_accel.unshared_edges(v0, e1, e2) == 0
+
+
+# -- the accepted scenes keep their programs --------------------------------------
+
+
+def pallas_calls(jaxpr):
+    import jax
+
+    for equation in jaxpr.eqns:
+        if equation.primitive.name == "pallas_call":
+            yield equation
+        for inner in jax.core.jaxprs_in_params(equation.params):
+            yield from pallas_calls(inner)
+
+
+@pytest.mark.time_limit(420)
+def test_the_icosphere_scenes_program_holds_the_kernels_it_held(interpreted_kernels):
+    """One launch at full width and one per rung for each later bounce, all
+    of the resident kernel: 13 at the worker's shape (PERF.md §5), none of
+    them the streamed one, and no new argument that carries anything."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+
+    render = integrator.fused_frame_renderer("03_physics-2-mesh", 512, 512, 8, 4, with_live=True)
+    jaxpr = jax.make_jaxpr(render.__wrapped__)(jnp.float32(295))
+    calls = list(pallas_calls(jaxpr.jaxpr))
+    rungs = len(integrator.launch_width_ladder(512 * 512 * 8))
+    assert len(calls) == 1 + 3 * rungs == 13
+    names = {str(call.params.get("name") or call.params.get("name_and_src_info", "")) for call in calls}
+    assert not any("streamed" in name for name in names), names
+    assert len(jaxpr.jaxpr.invars) == 1  # the frame, and nothing else
+
+
+def test_the_scan_scenes_program_streams_every_bounce(small_scan_family):
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+
+    render = integrator.fused_frame_renderer(SCAN_SCENE, 64, 64, 2, 4, with_live=True)
+    jaxpr = jax.make_jaxpr(render.__wrapped__)(jnp.float32(295))
+    calls = list(pallas_calls(jaxpr.jaxpr))
+    assert calls and all("mesh_bounce_streamed" in str(call.params) for call in calls)
+    # the BLAS is an argument of the jitted program: HBM tables, not constants
+    inner = [e for e in jaxpr.jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+    assert inner and len(inner[-1].invars) == 1 + 5  # the frame, and BlasStream's arrays
+
+
+# -- the backend's series ---------------------------------------------------------
+
+
+def test_the_backend_says_where_the_geometry_lives_and_counts_the_walk(small_scan_family, tmp_path):
+    from tpu_render_cluster.jobs.models import BlenderJob, DistributionStrategy
+    from tpu_render_cluster.obs import get_registry
+    from tpu_render_cluster.obs.prometheus import render_prometheus
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    def value(text, series):
+        lines = [line for line in text.splitlines() if line.startswith(series + " ") or line.startswith(series + "{")]
+        return sum(float(line.rsplit(" ", 1)[1]) for line in lines) if lines else None
+
+    before = render_prometheus(get_registry().snapshot())
+    backend = TpuRaytraceBackend(base_directory=tmp_path, width=32, height=32, samples=2)
+    backend.warm(f"{SCAN_SCENE}_measuring_480f-1w")
+    assert backend.bvh_build is not None and backend.bvh_build[1] > 0
+    job = BlenderJob(
+        job_name=f"{SCAN_SCENE}_test", job_description=None, project_file_path="%BASE%/p.blend",
+        render_script_path="%BASE%/s.py", frame_range_from=295, frame_range_to=296,
+        wait_for_number_of_workers=1, frame_distribution_strategy=DistributionStrategy.naive_fine(),
+        output_directory_path="%BASE%/frames", output_file_name_format="rendered-######",
+        output_file_format="JPEG",
+    )
+    backend._render_sync(job, 295)
+    after = render_prometheus(get_registry().snapshot())
+    assert value(after, 'render_geometry_bytes{space="hbm"}') > 2048 * 64
+    assert value(after, "render_bvh_build_seconds") > 0
+    for series in ("render_walk_node_visits_total", "render_treelet_fetches_total", "render_treelet_fetch_bytes_total"):
+        assert value(after, series) > (value(before, series) or 0.0), series
+    assert (tmp_path / "frames" / "rendered-000295.jpg").is_file()

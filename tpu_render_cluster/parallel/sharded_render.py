@@ -149,7 +149,7 @@ def sharded_frame_renderer(
     frame across the local mesh: one compiled program per config, fed
     the frame's scene, camera and mesh set."""
     from tpu_render_cluster.render.integrator import resolve_bvh_config
-    from tpu_render_cluster.render.mesh import scene_mesh_set
+    from tpu_render_cluster.render.mesh import scene_blas_stream, scene_mesh_set
 
     # BVH env tiers resolve HERE (untraced): the node format keys the
     # cached program, the build picks the tree it is handed — the
@@ -159,12 +159,14 @@ def sharded_frame_renderer(
         scene_name, width, height, samples, max_bounces, mode, n_devices,
         quant,
     )
+    # A BLAS streamed from HBM goes in as its tables, not its host arrays.
+    blas = scene_blas_stream(scene_name, builder, wide)
 
     def render(frame_index):
         return program(
             build_scene(scene_name, frame_index),
             scene_camera(scene_name, frame_index),
-            scene_mesh_set(scene_name, frame_index, builder, wide),
+            scene_mesh_set(scene_name, frame_index, builder, wide, stream=blas),
             jnp.asarray(frame_index, jnp.float32),
         )
 

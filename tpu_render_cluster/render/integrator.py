@@ -419,7 +419,7 @@ def _unsort(radiance, lane):
 
 def _trace_paths_deep(
     scene, mesh, origins, directions, seed, *, max_bounces, rng_lanes,
-    use_tlas, quant, live_counts,
+    use_tlas, quant, live_counts, walk_counts=None,
 ):
     """trace_paths for deep-walk mesh scenes: the megakernel's
     bounce_step as ONE fused launch per bounce (sphere/plane/mesh
@@ -505,7 +505,8 @@ def _trace_paths_deep(
             # permutation.
             alive = jnp.arange(width, dtype=jnp.int32) < state["live"]
         with jax.named_scope("bounce"):
-            contribution, origins, directions, throughput, alive, keys = launch(
+            # a streamed BLAS's launch also returns its walk's counts
+            contribution, origins, directions, throughput, alive, keys, *walk = launch(
                 scene, mesh, packed[:, 0:3], packed[:, 3:6], packed[:, 6:9],
                 alive, seed, jnp.int32(bounce), total_bounces=max_bounces,
                 lane=rng, live_count=state["live"], use_tlas=tlas,
@@ -518,9 +519,10 @@ def _trace_paths_deep(
                 radiance = state["radiance"].at[slot].add(
                     contribution, unique_indices=True
                 )
+        counted = {"walk": state["walk"].at[bounce].set(walk[0])} if walk else {}
         if bounce + 1 == max_bounces:
-            return radiance, lane
-        new = dict(state, radiance=radiance, lane=lane)
+            return (radiance, lane, *counted.values())
+        new = dict(state, radiance=radiance, lane=lane, **counted)
         with jax.named_scope("resort"):
             # The next bounce's sort, over this bounce's width: its live
             # rays are among these rows and nowhere else.
@@ -564,6 +566,9 @@ def _trace_paths_deep(
     )
     if rng_lanes is not None:
         state["rng"] = jnp.asarray(rng_lanes, jnp.int32)
+    if mesh.bvh.stream is not None:
+        # (node visits, treelet fetches) of each bounce's launch
+        state["walk"] = jnp.zeros((max_bounces, 2), jnp.int32)
     for bounce in range(max_bounces):
         # Every ray is live at the first bounce, and a one-rung ladder
         # leaves nothing to pick: no switch in the program.
@@ -581,12 +586,16 @@ def _trace_paths_deep(
             )
         else:
             state = bounce_at(n, bounce, state)
-    return _unsort(*state)  # the last bounce's radiance and lane
+    radiance, lane, *walk = state  # the last bounce's
+    if walk and walk_counts is not None:
+        walk_counts.extend(walk[0])
+    return _unsort(radiance, lane)
 
 
 def trace_paths(
     scene: Scene, origins, directions, key, *, max_bounces: int = 4, mesh=None,
     rng_lanes=None, use_tlas=None, quant=None, live_counts=None,
+    walk_counts=None,
 ) -> jnp.ndarray:
     """Trace one sample per ray; returns radiance [R, 3].
 
@@ -621,7 +630,9 @@ def trace_paths(
     of ``launch_width_ladder`` the program ran the bounce at — so the
     caller can return them from the same program: the frame's launch
     occupancy at no extra sync. Other paths launch no per-bounce
-    kernel and leave the list empty.
+    kernel and leave the list empty. ``walk_counts`` (optional list)
+    collects the same launches' (node visits, treelet fetches) where the
+    mesh's BLAS is streamed from HBM (``mesh.bvh.stream``).
     """
     from tpu_render_cluster.render import pallas_kernels
 
@@ -655,7 +666,12 @@ def trace_paths(
         return _trace_paths_deep(
             scene, mesh, origins, directions, seed, max_bounces=max_bounces,
             rng_lanes=rng_lanes, use_tlas=use_tlas, quant=quant,
-            live_counts=live_counts,
+            live_counts=live_counts, walk_counts=walk_counts,
+        )
+    if mesh is not None and mesh.bvh.stream is not None:
+        raise NotImplementedError(
+            "a BLAS streamed from HBM is walked by the Pallas bounce kernel "
+            "alone: set TRC_PALLAS=1 to render this scene off the chip"
         )
     # Non-Pallas reference path: the plain XLA bounce loop. Order-invariant
     # per lane, so no sort machinery.
@@ -680,7 +696,7 @@ def trace_paths(
     jax.jit,
     static_argnames=(
         "width", "height", "tile_height", "tile_width", "samples",
-        "max_bounces", "use_tlas", "quant", "with_live",
+        "max_bounces", "use_tlas", "quant", "with_live", "with_walk",
     ),
 )
 def render_tile(
@@ -700,6 +716,7 @@ def render_tile(
     use_tlas=None,
     quant=None,
     with_live: bool = False,
+    with_walk: bool = False,
 ) -> jnp.ndarray:
     """Render a tile; returns [tile_height, tile_width, 3] linear radiance.
 
@@ -712,11 +729,14 @@ def render_tile(
     ``with_live`` (static) returns ``(radiance, live)`` instead, ``live``
     the int32 [max_bounces, 2] (live rays, width) of each per-bounce
     launch (trace_paths' ``live_counts``), or None where the scene's
-    path launches no per-bounce kernel.
+    path launches no per-bounce kernel. ``with_walk`` (static, a scene
+    whose BLAS is streamed) appends ``walk``, the int32 [max_bounces, 2]
+    (node visits, treelet fetches) of the same launches.
     """
     n = tile_height * tile_width
     base_key = tile_base_key(frame, y0, x0)
     live_counts = [] if with_live else None
+    walk_counts = [] if with_walk else None
 
     from tpu_render_cluster.render import pallas_kernels
 
@@ -752,6 +772,7 @@ def render_tile(
             use_tlas=use_tlas,
             quant=quant,
             live_counts=live_counts,
+            walk_counts=walk_counts,
         )
         image = radiance.reshape(samples, n, 3).mean(axis=0)
     else:
@@ -780,8 +801,11 @@ def render_tile(
         )
         image = total / samples
     image = image.reshape(tile_height, tile_width, 3)
-    if with_live:
-        return image, (jnp.stack(live_counts) if live_counts else None)
+    if with_live or with_walk:
+        live = jnp.stack(live_counts) if live_counts else None
+        if with_walk:
+            return image, live, jnp.stack(walk_counts)
+        return image, live
     return image
 
 
@@ -796,13 +820,16 @@ def render_frame(
     tile_size: int | None = None,
 ) -> jnp.ndarray:
     """Render a full frame on the default device; returns [H, W, 3] linear."""
-    from tpu_render_cluster.render.mesh import scene_mesh_set
+    from tpu_render_cluster.render.mesh import scene_blas_stream, scene_mesh_set
 
     scene = build_scene(scene_name, frame_index)
     camera = scene_camera(scene_name, frame_index)
     # BVH env tiers resolve HERE, outside the jitted tile renders.
     _tlas, bvh_quant, bvh_builder, bvh_wide = resolve_bvh_config()
-    mesh = scene_mesh_set(scene_name, frame_index, bvh_builder, bvh_wide)
+    mesh = scene_mesh_set(
+        scene_name, frame_index, bvh_builder, bvh_wide,
+        stream=scene_blas_stream(scene_name, bvh_builder, bvh_wide),
+    )
     frame = jnp.asarray(frame_index, jnp.float32)
     if tile_size is None:
         return render_tile(
@@ -873,6 +900,25 @@ def resolve_bvh_config(use_tlas=None, quant=None, builder=None, wide=None):
     )
 
 
+class _WithBlasTables:
+    """A jitted program whose last argument is its scene's streamed BLAS
+    (``mesh.scene_blas_stream``: HBM tables, or None for every other
+    scene), with that argument bound: called, traced and lowered as the
+    program of the arguments that are left."""
+
+    def __init__(self, program, blas):
+        self.program, self.blas = program, blas
+
+    def __call__(self, *args):
+        return self.program(*args, self.blas)
+
+    def trace(self, *args):
+        return self.program.trace(*args, self.blas)
+
+    def lower(self, *args):
+        return self.program.lower(*args, self.blas)
+
+
 @functools.lru_cache(maxsize=32)
 def _fused_frame_renderer(
     scene_name: str,
@@ -892,14 +938,21 @@ def _fused_frame_renderer(
 
     # This body runs on the lru_cache's miss only: one program built.
     render_compile_counter().inc()
+    from tpu_render_cluster.render.mesh import scene_blas_stream
+
+    # A streamed BLAS is an argument of the program, built (once a
+    # process) here and not inside the trace; None for every other scene,
+    # whose program is the one it always was.
+    blas = scene_blas_stream(scene_name, builder, wide)
+    with_walk = with_live and blas is not None
 
     @jax.jit
-    def render(frame: jnp.ndarray) -> jnp.ndarray:
+    def program(frame: jnp.ndarray, blas) -> jnp.ndarray:
         from tpu_render_cluster.render.mesh import scene_mesh_set
 
         scene = build_scene(scene_name, frame)
         camera = scene_camera(scene_name, frame)
-        mesh = scene_mesh_set(scene_name, frame, builder, wide)
+        mesh = scene_mesh_set(scene_name, frame, builder, wide, stream=blas)
         rendered = render_tile(
             scene,
             camera,
@@ -916,11 +969,17 @@ def _fused_frame_renderer(
             use_tlas=use_tlas,
             quant=quant,
             with_live=with_live,
+            with_walk=with_walk,
         )
+        if with_walk:
+            linear, live, walk = rendered
+            return tonemap(linear), live, walk
         if with_live:
             linear, live = rendered
             return tonemap(linear), live
         return tonemap(rendered)
+
+    render = _WithBlasTables(program, blas)
 
     # Roofline profiling (obs/profiling.py): the first call captures the
     # program's XLA cost analysis (FLOPs/bytes) under the masked tier's
@@ -1006,14 +1065,17 @@ def _fused_region_renderer(
     from tpu_render_cluster.render.scene import build_scene
 
     render_compile_counter().inc()
+    from tpu_render_cluster.render.mesh import scene_blas_stream
+
+    blas = scene_blas_stream(scene_name, builder, wide)  # see the frame renderer
 
     @jax.jit
-    def render(frame: jnp.ndarray, y0, x0) -> jnp.ndarray:
+    def program(frame: jnp.ndarray, y0, x0, blas) -> jnp.ndarray:
         from tpu_render_cluster.render.mesh import scene_mesh_set
 
         scene = build_scene(scene_name, frame)
         camera = scene_camera(scene_name, frame)
-        mesh = scene_mesh_set(scene_name, frame, builder, wide)
+        mesh = scene_mesh_set(scene_name, frame, builder, wide, stream=blas)
         with jax.named_scope("raygen"):
             origins, directions, lanes, seed = region_rays_and_seed(
                 camera, jnp.asarray(frame, jnp.float32),
@@ -1042,6 +1104,8 @@ def _fused_region_renderer(
         return radiance.reshape(samples, n, 3).mean(axis=0).reshape(
             tile_height, tile_width, 3
         )
+
+    render = _WithBlasTables(program, blas)
 
     # Roofline profiling: one cost capture per tile SHAPE (matching the
     # one-compile-per-shape contract of this renderer).

@@ -96,6 +96,73 @@ class MeshBVH(NamedTuple):
     first: jnp.ndarray  # [N] int32 — leaf triangle start (0 for inner)
     count: jnp.ndarray  # [N] int32 — leaf triangle count (0 for inner)
     octant: "OctantTables | None" = None
+    # Set when the BLAS is too large to sit in VMEM/SMEM whole: the
+    # tables the bounce kernel streams from HBM by treelet. The fields
+    # above are then host (NumPy) arrays — the contract of the tree, for
+    # tests and the benchmark's reference — and no traced program reads
+    # them (``traced_stream_bvh``).
+    stream: "BlasStream | None" = None
+
+
+class BlasStream(NamedTuple):
+    """A BLAS cut into treelets, as the device arrays the bounce kernel
+    reads from HBM (``pallas_kernels``: ``memory_space=pl.ANY``, staged
+    to VMEM/SMEM scratch by DMA when a packet enters a treelet).
+
+    The tree's top — every node that holds more leaves than one treelet,
+    with the treelet roots as its leaves — stays resident in SMEM; below
+    it treelet ``t`` is a subtree of at most ``treelet_leaves(stream)``
+    leaves with its own threaded node table (LOCAL skip links, the local
+    node count its terminator) and its leaves' triangles. Every static
+    size is read from a shape, so the pytree holds arrays only.
+
+    ``tri`` packs a triangle as 12 floats (v0, e1, e2, unit normal) in 16
+    lanes, eight leaves side by side across the 128 lanes and a leaf's 16
+    triangles down 16 rows: leaf ``l`` of a treelet is rows
+    ``16 * (l // 8) .. + 16``, lanes ``16 * (l % 8) .. + 12``. That is
+    64 B a triangle in HBM where a ``[T, 3]`` table is 512 B a triangle
+    a table on the chip (lanes padded to 128).
+    """
+
+    tri: jnp.ndarray  # [NT, 16 * L/8, 128] f32
+    # [NT * W] f32, W = treelet_node_words: node i of a treelet at words
+    # 8i..8i+7 of its slot: lo xyz, hi xyz, then skip | leaf << 8 |
+    # count << 16 as a float (whole numbers under 2**24 are exact) and a
+    # spare. One table, one copy a fetch; a 1-D array in HBM is tiled by
+    # 1024 words and a copy must start on a tile, so W is a multiple.
+    nodes: jnp.ndarray
+    top_bounds: jnp.ndarray  # [NTOP * 6] f32
+    top_meta: jnp.ndarray  # [NTOP] int32: skip | (treelet + 1) << 16
+    root: jnp.ndarray  # [2, 3] f32: the whole tree's bounds
+
+
+def treelet_leaves(stream: BlasStream) -> int:
+    return stream.tri.shape[1] // 2
+
+
+def treelet_node_words(stream: BlasStream) -> int:
+    return stream.nodes.shape[0] // stream.tri.shape[0]
+
+
+def geometry_bytes(bvh: MeshBVH) -> dict[str, int]:
+    """Bytes of a BLAS's tables by the memory they live in while a bounce
+    kernel runs: streamed, the treelet tables in HBM and the tree's top in
+    SMEM; resident, the padded triangle tables in VMEM and the nodes in
+    SMEM (``resident_table_bytes``)."""
+    stream = bvh.stream
+    if stream is None:
+        nodes = bvh.skip.shape[0] * 9 * 4 * (1 if bvh.octant is None else 8)
+        return {"hbm": 0, "vmem": bvh.v0.shape[0] * 128 * 4 * 4, "smem": nodes}
+    return {
+        "hbm": sum(int(a.size) * 4 for a in (stream.tri, stream.nodes)),
+        "vmem": 0,
+        "smem": sum(int(a.size) * 4 for a in (stream.top_bounds, stream.top_meta)),
+    }
+
+
+def treelet_fetch_bytes(stream: BlasStream) -> int:
+    """Bytes one treelet fetch copies: its triangle rows and node table."""
+    return (stream.tri.shape[1] * 128 + treelet_node_words(stream)) * 4
 
 
 # ---------------------------------------------------------------------------
@@ -169,6 +236,63 @@ def make_icosphere(subdivisions: int = 2) -> tuple[np.ndarray, np.ndarray]:
         vertices = np.stack(vertex_list)
         faces = np.array(new_faces, np.int32)
     return (vertices * 0.5).astype(np.float32), faces
+
+
+# The scanned-model stand-in (scene family 03_physics-2-scan): 660 x 660
+# quads = 871,200 triangles, the count of the Stanford 3D Scanning
+# Repository's dragon_vrip.ply (871,414). The geometry seed is a constant
+# of the family, as for every procedural scene here.
+SCAN_GRID = 660
+SCAN_SEED = 871414
+SCAN_MAJOR_RADIUS = 0.26
+SCAN_TUBE_RADIUS = 0.16
+SCAN_NOISE_AMPLITUDE = 0.075  # 15% of the bounding radius 0.5
+SCAN_OCTAVES = 5
+
+
+def make_scan_mesh(
+    grid: int = SCAN_GRID, seed: int = SCAN_SEED
+) -> tuple[np.ndarray, np.ndarray]:
+    """A closed, non-convex surface of ``2 * grid * grid`` triangles that
+    stands for a scanned model: a doubly periodic ``grid x grid`` net of
+    quads wrapped on a ring (axis z, so it stands on the floor like a
+    wheel inside the unit box the other meshes fill), each quad split
+    along a seeded diagonal, the surface pushed along the tube's normal
+    by ``SCAN_OCTAVES`` octaves of seeded periodic noise (amplitudes
+    halving, summing to ``SCAN_NOISE_AMPLITUDE``) and every vertex moved
+    inside its cell, so triangle areas are irregular."""
+    rng = np.random.default_rng(seed)
+    iu, iv = np.meshgrid(np.arange(grid), np.arange(grid), indexing="ij")
+    two_pi = 2.0 * np.pi
+    u = (iu + (rng.random((grid, grid)) - 0.5) * 0.7) * (two_pi / grid)
+    v = (iv + (rng.random((grid, grid)) - 0.5) * 0.7) * (two_pi / grid)
+    weights = 0.5 ** np.arange(SCAN_OCTAVES)
+    weights *= SCAN_NOISE_AMPLITUDE / weights.sum()
+    waves = 3
+    displacement = np.zeros((grid, grid))
+    for octave, weight in enumerate(weights):
+        for _ in range(waves):
+            # whole-number frequencies keep the noise periodic in u and v
+            fu, fv = rng.integers(-3, 4, size=2) * (1 << octave)
+            if fu == 0 and fv == 0:
+                fu = 1 << octave
+            displacement += (weight / waves) * np.cos(
+                fu * u + fv * v + rng.random() * two_pi
+            )
+    tube = SCAN_TUBE_RADIUS + displacement
+    ring = SCAN_MAJOR_RADIUS + tube * np.cos(v)
+    vertices = np.stack(
+        [ring * np.cos(u), ring * np.sin(u), tube * np.sin(v)], axis=-1
+    ).reshape(-1, 3).astype(np.float32)
+    a = (iu * grid + iv).reshape(-1)
+    b = (((iu + 1) % grid) * grid + iv).reshape(-1)
+    c = (((iu + 1) % grid) * grid + (iv + 1) % grid).reshape(-1)
+    d = (iu * grid + (iv + 1) % grid).reshape(-1)
+    flip = rng.random(a.shape[0]) < 0.5
+    first = np.stack([a, b, np.where(flip, d, c)], axis=1)
+    second = np.stack([np.where(flip, b, a), c, d], axis=1)
+    faces = np.stack([first, second], axis=1).reshape(-1, 3).astype(np.int32)
+    return vertices, faces
 
 
 # ---------------------------------------------------------------------------
@@ -254,8 +378,14 @@ def build_bvh(
     faces: np.ndarray,
     builder: str = "median",
     wide: int = 1,
+    treelet_leaves: int | None = None,
 ) -> MeshBVH:
     """Host-side BLAS build, threaded for stackless traversal.
+
+    ``builder="morton"`` is the vectorised build for meshes the recursive
+    ones below would take minutes over (``morton_bvh``: binary, ``wide``
+    does not apply); ``treelet_leaves`` is its treelet size where the
+    caller wants the streamed tables whatever the mesh's size.
 
     ``builder`` selects the split strategy — ``median`` (the original
     spatial-median over centroids) or ``sah`` (binned surface-area
@@ -270,6 +400,8 @@ def build_bvh(
     """
     leaf_size = LEAF_SIZE
     wide = max(1, min(int(wide), 8))
+    if builder == "morton":
+        return morton_bvh(vertices, faces, treelet_leaves=treelet_leaves)
     if builder not in ("median", "sah"):
         raise ValueError(f"Unknown BVH builder: {builder!r}")
     tri = vertices[faces]  # [T, 3, 3]
@@ -468,6 +600,225 @@ def build_bvh(
         )
 
 
+# ---------------------------------------------------------------------------
+# Vectorised build (Morton order, balanced tree) and the treelet partition
+
+
+def _morton_codes(centroids: np.ndarray) -> np.ndarray:
+    """63-bit Morton codes of ``centroids`` (21 bits an axis)."""
+    lo = centroids.min(axis=0)
+    span = np.maximum(centroids.max(axis=0) - lo, 1e-12)
+    cells = np.minimum(
+        ((centroids - lo) / span * (1 << 21)).astype(np.uint64), (1 << 21) - 1
+    )
+
+    def dilate(x):
+        x = (x | (x << np.uint64(32))) & np.uint64(0x1F00000000FFFF)
+        x = (x | (x << np.uint64(16))) & np.uint64(0x1F0000FF0000FF)
+        x = (x | (x << np.uint64(8))) & np.uint64(0x100F00F00F00F00F)
+        x = (x | (x << np.uint64(4))) & np.uint64(0x10C30C30C30C30C3)
+        x = (x | (x << np.uint64(2))) & np.uint64(0x1249249249249249)
+        return x
+
+    return (
+        dilate(cells[:, 0])
+        | (dilate(cells[:, 1]) << np.uint64(1))
+        | (dilate(cells[:, 2]) << np.uint64(2))
+    )
+
+
+def _balanced_tree(n_leaves: int):
+    """The median-split binary tree over ``n_leaves`` ordered leaves, in
+    DFS preorder, level by level (no Python per node): a node over ``m``
+    leaves holds ``2m - 1`` nodes, its left child follows it and its right
+    child follows the left subtree. Returns (leaf range lo, hi per node,
+    [(parents, lefts, rights)] per level from the root down)."""
+    n_nodes = 2 * n_leaves - 1
+    node_lo = np.zeros(n_nodes, np.int64)
+    node_hi = np.zeros(n_nodes, np.int64)
+    index = np.zeros(1, np.int64)
+    lo = np.zeros(1, np.int64)
+    hi = np.full(1, n_leaves, np.int64)
+    levels = []
+    while index.size:
+        node_lo[index], node_hi[index] = lo, hi
+        inner = hi - lo > 1
+        index, lo, hi = index[inner], lo[inner], hi[inner]
+        mid = (lo + hi) // 2
+        left, right = index + 1, index + 2 * (mid - lo)
+        levels.append((index, left, right))
+        index = np.concatenate([left, right])
+        lo, hi = np.concatenate([lo, mid]), np.concatenate([mid, hi])
+    return node_lo, node_hi, levels
+
+
+def _build_bvh_morton(vertices: np.ndarray, faces: np.ndarray) -> dict:
+    """The vectorised BLAS build behind ``build_bvh(builder="morton")``:
+    triangles sorted along a Morton curve through their centroids, every
+    ``LEAF_SIZE`` consecutive ones a leaf, a balanced median-split tree
+    over the leaves, bounds reduced bottom-up a level at a time. Host
+    arrays under ``MeshBVH``'s field names (threaded DFS preorder,
+    leaf-contiguous triangles), plus the leaf range of every node."""
+    tri = np.asarray(vertices, np.float32)[np.asarray(faces)]  # [T, 3, 3]
+    tri = tri[np.argsort(_morton_codes(tri.mean(axis=1, dtype=np.float64)), kind="stable")]
+    n_tri = tri.shape[0]
+    n_leaves = -(-n_tri // LEAF_SIZE)
+    padded = np.zeros((n_leaves * LEAF_SIZE, 3, 3), np.float32)
+    padded[:n_tri] = tri
+    leaf_count = np.full(n_leaves, LEAF_SIZE, np.int32)
+    leaf_count[-1] = n_tri - (n_leaves - 1) * LEAF_SIZE
+    # Bounds of a leaf over its real triangles: the padding repeats the
+    # leaf's first vertex for the reduction only.
+    points = padded.copy()
+    points[n_tri:] = padded[(n_leaves - 1) * LEAF_SIZE, 0]
+    points = points.reshape(n_leaves, LEAF_SIZE * 3, 3)
+    node_lo, node_hi, levels = _balanced_tree(n_leaves)
+    n_nodes = node_lo.shape[0]
+    is_leaf = node_hi - node_lo == 1
+    bounds_min = np.zeros((n_nodes, 3), np.float32)
+    bounds_max = np.zeros((n_nodes, 3), np.float32)
+    bounds_min[is_leaf] = points.min(axis=1)[node_lo[is_leaf]]
+    bounds_max[is_leaf] = points.max(axis=1)[node_lo[is_leaf]]
+    for parents, lefts, rights in reversed(levels):
+        bounds_min[parents] = np.minimum(bounds_min[lefts], bounds_min[rights])
+        bounds_max[parents] = np.maximum(bounds_max[lefts], bounds_max[rights])
+    e1 = padded[:, 1] - padded[:, 0]
+    e2 = padded[:, 2] - padded[:, 0]
+    normal = np.cross(e1, e2)
+    length = np.linalg.norm(normal, axis=1, keepdims=True)
+    normal = np.where(
+        length > 1e-12, normal / np.maximum(length, 1e-12),
+        np.array([[0.0, 1.0, 0.0]], np.float32),
+    ).astype(np.float32)
+    return dict(
+        v0=np.ascontiguousarray(padded[:, 0]), e1=e1, e2=e2, normal=normal,
+        bounds_min=bounds_min, bounds_max=bounds_max,
+        skip=(np.arange(n_nodes) + 2 * (node_hi - node_lo) - 1).astype(np.int32),
+        first=np.where(is_leaf, node_lo * LEAF_SIZE, 0).astype(np.int32),
+        count=np.where(is_leaf, leaf_count[np.minimum(node_lo, n_leaves - 1)], 0).astype(np.int32),
+        leaf_lo=node_lo, leaf_hi=node_hi,
+    )
+
+
+# What one treelet may hold, in leaves: 64 leaves are 1,024 triangles,
+# 64 KiB of triangle rows and 3.5 KiB of node table a fetch.
+TREELET_LEAVES = 64
+# A BLAS stays resident (today's kernels: triangle tables whole in VMEM,
+# node tables in SMEM) while its tables fit this share of the 16 MiB of
+# VMEM a kernel may use by default; a larger one is streamed.
+RESIDENT_VMEM_BUDGET = 8 << 20
+
+
+def resident_table_bytes(n_triangle_rows: int, n_nodes: int) -> int:
+    """VMEM + SMEM bytes of a BLAS as the resident kernels hold it: four
+    ``[T, 3]`` f32 tables whose rows pad to 128 lanes, and 9 words a
+    node."""
+    return n_triangle_rows * 128 * 4 * 4 + n_nodes * 9 * 4
+
+
+def partition_treelets(tree: dict, max_leaves: int = TREELET_LEAVES) -> dict:
+    """Cut a ``_build_bvh_morton`` tree into treelets of at most
+    ``max_leaves`` leaves (a multiple of 8, at most 128: a byte budget of
+    ``max_leaves`` KiB of triangle rows): host arrays under
+    ``BlasStream``'s field names. A treelet root is a node that fits the
+    budget whose parent does not; what lies above is the resident top."""
+    if max_leaves % 8 or not 8 <= max_leaves <= 128:
+        raise ValueError(f"treelet size {max_leaves}: want a multiple of 8 in 8..128")
+    lo, hi = tree["leaf_lo"], tree["leaf_hi"]
+    n_nodes = lo.shape[0]
+    fits = hi - lo <= max_leaves
+    # In preorder a node's parent is the nearest earlier node whose range
+    # holds it; a node is a treelet root iff it fits and is the first
+    # fitting node on its path — iff no fitting ancestor, iff it is not
+    # inside the preorder span of an earlier fitting node.
+    span_end = np.where(fits, np.arange(n_nodes) + 2 * (hi - lo) - 1, 0)
+    covered_until = np.maximum.accumulate(np.concatenate([[0], span_end[:-1]]))
+    is_root = fits & (np.arange(n_nodes) >= covered_until)
+    in_top = ~fits | is_root
+    n_treelets = int(is_root.sum())
+    if n_treelets + 1 >= 1 << 15 or int(in_top.sum()) >= 1 << 16:
+        raise ValueError("the resident top outgrows its 16-bit links")
+    treelet = np.cumsum(is_root) - 1  # of a root, and of every node under it
+    tops = np.flatnonzero(in_top)
+    # skip of a top node, in top numbering: the top nodes before its target
+    top_skip = np.cumsum(in_top)[tree["skip"][tops] - 1]
+    top_meta = (
+        top_skip | np.where(is_root[tops], treelet[tops] + 1, 0) << 16
+    ).astype(np.int32)
+    top_bounds = np.concatenate(
+        [tree["bounds_min"][tops], tree["bounds_max"][tops]], axis=1
+    ).reshape(-1).astype(np.float32)
+
+    words = -(-(2 * max_leaves * 8) // 1024) * 1024  # 2L - 1 nodes of 8
+    roots = np.flatnonzero(is_root)
+    inside = fits  # a node that fits is a root or under one
+    t_in = treelet[inside]
+    l_in = np.flatnonzero(inside) - roots[t_in]
+    nodes = np.zeros((n_treelets, words // 8, 8), np.float32)
+    nodes[t_in, l_in, 0:3] = tree["bounds_min"][inside]
+    nodes[t_in, l_in, 3:6] = tree["bounds_max"][inside]
+    local_skip = tree["skip"][inside] - roots[t_in]
+    local_leaf = np.where(tree["count"][inside] > 0, lo[inside] - lo[roots[t_in]], 0)
+    nodes[t_in, l_in, 6] = (
+        local_skip | local_leaf << 8 | tree["count"][inside].astype(np.int64) << 16
+    )
+    # Triangle rows: [treelet, leaf, triangle, 16 lanes] -> leaves eight
+    # abreast across the 128 lanes.
+    n_leaves = int(hi[0])
+    record = np.zeros((n_leaves * LEAF_SIZE, 16), np.float32)
+    for column, name in enumerate(("v0", "e1", "e2", "normal")):
+        record[:, 3 * column:3 * column + 3] = tree[name]
+    record = record.reshape(n_leaves, LEAF_SIZE, 16)
+    leaf_nodes = np.flatnonzero(tree["count"] > 0)
+    slots = np.zeros((n_treelets, max_leaves, LEAF_SIZE, 16), np.float32)
+    slots[treelet[leaf_nodes], lo[leaf_nodes] - lo[roots[treelet[leaf_nodes]]]] = record[lo[leaf_nodes]]
+    tri = slots.reshape(n_treelets, max_leaves // 8, 8, LEAF_SIZE, 16).transpose(
+        0, 1, 3, 2, 4
+    ).reshape(n_treelets, max_leaves * 2, 128)
+    return dict(
+        tri=tri, nodes=nodes.reshape(-1), top_bounds=top_bounds, top_meta=top_meta,
+        root=np.stack([tree["bounds_min"][0], tree["bounds_max"][0]]),
+    )
+
+
+def morton_bvh(
+    vertices: np.ndarray, faces: np.ndarray, *,
+    treelet_leaves: int | None = None,
+) -> MeshBVH:
+    """``_build_bvh_morton``'s tree as a ``MeshBVH``. Resident or streamed
+    follows from the tables' bytes against ``RESIDENT_VMEM_BUDGET``:
+    a tree that fits comes back as device arrays like every other build;
+    one that does not keeps its arrays on the host and carries the
+    treelet tables (``BlasStream``) on the device. ``treelet_leaves``
+    asks for the streamed tables beside device arrays whatever the size
+    (tests walk one tree both ways)."""
+    tree = _build_bvh_morton(vertices, faces)
+    fields = {name: tree[name] for name in MeshBVH._fields[:9]}
+    streamed = resident_table_bytes(
+        tree["v0"].shape[0], tree["skip"].shape[0]
+    ) > RESIDENT_VMEM_BUDGET
+    with jax.ensure_compile_time_eval():  # see build_bvh
+        stream = None
+        if streamed or treelet_leaves is not None:
+            tables = partition_treelets(tree, treelet_leaves or TREELET_LEAVES)
+            stream = BlasStream(**{k: jnp.asarray(v) for k, v in tables.items()})
+        if not streamed:
+            fields = {name: jnp.asarray(value) for name, value in fields.items()}
+        return MeshBVH(**fields, stream=stream)
+
+
+def traced_stream_bvh(stream: BlasStream) -> MeshBVH:
+    """The ``MeshBVH`` a traced program holds of a streamed BLAS: the
+    treelet tables (its arguments) and the root's bounds, which is all the
+    instance table takes of the tree; the host arrays stay out of the
+    program."""
+    return MeshBVH(
+        v0=None, e1=None, e2=None, normal=None,
+        bounds_min=stream.root[0:1], bounds_max=stream.root[1:2],
+        skip=None, first=None, count=None, stream=stream,
+    )
+
+
 # Process-wide geometry-build memo: host-side BVH/TLAS builds keyed by
 # every parameter that shapes the result — (kind, leaf_size) for BLAS
 # builds, (k_count, tlas_leaf_size) for TLAS topologies — so the test
@@ -517,6 +868,8 @@ def cached_mesh_bvh(
     ``TRC_BVH_BUILDER``/``TRC_BVH_WIDE`` mid-process can never serve a
     tree built under the old knobs. ``None`` resolves the env tiers
     (callers inside traced code must pass explicit values)."""
+    if kind == "scan":
+        builder, wide = "morton", 1  # its one build: the env tiers do not apply
     builder = bvh_builder() if builder is None else builder
     wide = bvh_wide() if wide is None else max(1, min(int(wide), 8))
     key = ("bvh", kind, LEAF_SIZE, builder, wide)
@@ -526,6 +879,8 @@ def cached_mesh_bvh(
             bvh = build_bvh(*make_box(), builder=builder, wide=wide)
         elif kind == "icosphere":
             bvh = build_bvh(*make_icosphere(2), builder=builder, wide=wide)
+        elif kind == "scan":
+            bvh = morton_bvh(*make_scan_mesh())
         else:
             raise ValueError(f"Unknown mesh kind: {kind!r}")
         _geometry_cache[key] = bvh
@@ -1230,7 +1585,7 @@ class MeshSet(NamedTuple):
 
 def scene_mesh_set(
     scene_name: str, frame, builder: str | None = None,
-    wide: int | None = None,
+    wide: int | None = None, stream: "BlasStream | None" = None,
 ) -> "MeshSet | None":
     """The MeshSet for a scene (None for sphere-only scenes).
 
@@ -1239,7 +1594,9 @@ def scene_mesh_set(
     ``builder``/``wide`` select the BLAS build (None = env tiers); the
     jitted renderer factories resolve them OUTSIDE the trace and pass
     explicit values, so the compiled program's tree matches its cache
-    key.
+    key. ``stream`` is a streamed BLAS's tables where the caller is a
+    traced program that takes them as arguments (``scene_blas_stream``):
+    the set then holds those and none of the tree's host arrays.
     """
     from tpu_render_cluster.render.scene import (
         build_mesh_instances,
@@ -1250,9 +1607,25 @@ def scene_mesh_set(
     if kind is None:
         return None
     return MeshSet(
-        bvh=cached_mesh_bvh(kind, builder, wide),
+        bvh=(
+            cached_mesh_bvh(kind, builder, wide) if stream is None
+            else traced_stream_bvh(stream)
+        ),
         instances=build_mesh_instances(scene_name, frame),
     )
+
+
+def scene_blas_stream(
+    scene_name: str, builder: str | None = None, wide: int | None = None
+) -> "BlasStream | None":
+    """The HBM tables of a scene whose BLAS is streamed, None for every
+    other scene. The renderer factories call this outside their traces
+    and hand the tables to the frame's program as arguments: 70 MB
+    closed over would be 70 MB of constants in the compiled program."""
+    from tpu_render_cluster.render.scene import mesh_kind_for_scene
+
+    kind = mesh_kind_for_scene(scene_name)
+    return None if kind is None else cached_mesh_bvh(kind, builder, wide).stream
 
 
 # NOTE: an instance-flattened variant (one K*R-ray traversal call instead

@@ -861,6 +861,8 @@ def mesh_megakernel_eligible(mesh) -> bool:
     flatten samples for a scene that then takes the per-bounce walk,
     hitting the packet-coherence cliff flattening is gated against.
     """
+    if mesh.bvh.stream is not None:
+        return False  # the megakernel holds its BLAS whole
     return (
         mesh.bvh.skip.shape[0] * mesh.instances.translation.shape[0]
         <= MESH_MEGAKERNEL_MAX_WALK
@@ -1705,7 +1707,7 @@ def _mesh_trace_kernel_factory(
     max_bounces: int, n_padded: int, n_nodes: int, leaf_size: int,
     k_count: int, state_io: bool = False, use_tlas: bool = False,
     tlas_nodes: int = 0, quant: int = 0, ordered: bool = False,
-    tlas_ordered: bool = False,
+    tlas_ordered: bool = False, stream: tuple[int, int] | None = None,
 ):
     """Mesh path-trace kernel. Two shapes share one bounce_step:
 
@@ -1718,8 +1720,20 @@ def _mesh_trace_kernel_factory(
       any-hits, shading, in-kernel PCG resample) stays fused — deep-walk
       scenes. ``max_bounces`` still names the TOTAL bounce count so the
       per-(ray, bounce) RNG counters match the megakernel's stream layout.
+
+    ``stream`` = (nodes of the tree's top, words of a treelet's node
+    table) makes the BLAS operands
+    HBM tables (``mesh.BlasStream``) in place of the resident triangle and
+    node blocks: the walk runs over the resident top of the tree and,
+    where a packet enters a treelet, copies that treelet's triangle rows
+    and node table into scratch and walks inside it (``stream_walk``).
+    The node sequence is the resident walk's over the same tree, and the
+    arithmetic per node and per leaf is the same code. One more output
+    row carries each block's node visits and treelet fetches.
     """
     contract_first = (((0,), (0,)), ((), ()))
+    if stream is not None and not state_io:
+        raise ValueError("a streamed BLAS runs one bounce a launch")
 
     def kernel(*refs):
         # Fixed-prefix unpacking, then the BLAS node block (fp32: 5 SMEM
@@ -1738,16 +1752,24 @@ def _mesh_trace_kernel_factory(
             (seed_ref, bounce_ref, live_ref, o_ref, d_ref, thr_ref,
              alive_ref, lane_ref,
              c_ref, r2_ref, csq_ref, rad_ref, albedo_ref, emission_ref,
-             dcsun_ref, params_ref, sunsm_ref, inst_ref, v0_ref, e1_ref,
-             e2_ref, nrm_ref) = take(22)
+             dcsun_ref, params_ref, sunsm_ref, inst_ref) = take(18)
         else:
             (seed_ref, o_ref, d_ref, c_ref, r2_ref, csq_ref, rad_ref,
              albedo_ref, emission_ref, dcsun_ref, params_ref, sunsm_ref,
-             inst_ref, v0_ref, e1_ref, e2_ref, nrm_ref) = take(17)
-        if quant:
-            (bq_ref, bmeta_ref, bgrid_ref) = take(3)
+             inst_ref) = take(13)
+        if stream is not None:
+            # The BLAS in HBM and its resident top; scratch comes last:
+            # the staged treelet and which one it is.
+            (tri_hbm, nodes_hbm, topb_ref, topm_ref) = take(4)
+            (tri_buf, nodes_buf, staged_ref, dma_sem) = refs[-4:]
+            del refs[-4:]
         else:
-            (bmin_ref, bmax_ref, skip_ref, first_ref, count_ref) = take(5)
+            (v0_ref, e1_ref, e2_ref, nrm_ref) = take(4)
+            if quant:
+                (bq_ref, bmeta_ref, bgrid_ref) = take(3)
+            else:
+                (bmin_ref, bmax_ref, skip_ref, first_ref,
+                 count_ref) = take(5)
         if use_tlas:
             if quant:
                 (tbq_ref, tmeta_ref, tgrid_ref) = take(3)
@@ -1756,6 +1778,9 @@ def _mesh_trace_kernel_factory(
                  tcount_ref) = take(5)
         if state_io and use_tlas:
             (keysm_ref,) = take(1)
+        if stream is not None:
+            stats_ref = refs.pop()
+        if state_io and use_tlas:
             (out_ref, o_out_ref, d_out_ref, thr_out_ref, alive_out_ref,
              key_out_ref) = refs
         elif state_io:
@@ -1829,6 +1854,29 @@ def _mesh_trace_kernel_factory(
                      tbmax_ref[node, 2]),
                     tskip_ref[node], tfirst_ref[node], tcount_ref[node],
                 )
+        def slab_any(bounds, ox, oy, oz, invx, invy, invz, limit):
+            """THE packet test of a node's box, shared by every walk
+            below (TLAS, resident BLAS, streamed BLAS): whether any lane's
+            ray meets the six slab scalars ``bounds`` ahead of it and
+            nearer than its ``limit``."""
+            nlx, nly, nlz, nhx, nhy, nhz = bounds
+            lox = (nlx - ox) * invx
+            hix = (nhx - ox) * invx
+            loy = (nly - oy) * invy
+            hiy = (nhy - oy) * invy
+            loz = (nlz - oz) * invz
+            hiz = (nhz - oz) * invz
+            tnear = jnp.maximum(
+                jnp.maximum(jnp.minimum(lox, hix), jnp.minimum(loy, hiy)),
+                jnp.minimum(loz, hiz),
+            )
+            tfar = jnp.minimum(
+                jnp.minimum(jnp.maximum(lox, hix), jnp.maximum(loy, hiy)),
+                jnp.maximum(loz, hiz),
+            )
+            packet_hit = (tfar >= jnp.maximum(tnear, 0.0)) & (tnear < limit)
+            return jnp.any(packet_hit)
+
         if use_tlas:
             # THE threaded skip-link walk over TLAS node slabs, shared
             # by the nearest, any-hit, and key-epilogue entry walks
@@ -1848,31 +1896,8 @@ def _mesh_trace_kernel_factory(
                     node = walk[0]
                     carry = tuple(walk[1:])
                     limit = limit_of(carry)
-                    (nlx, nly, nlz, nhx, nhy, nhz), nskip, start, cnt = (
-                        tlas_node(tbase + node)
-                    )
-                    lox = (nlx - ox) * ix
-                    hix = (nhx - ox) * ix
-                    loy = (nly - oy) * iy
-                    hiy = (nhy - oy) * iy
-                    loz = (nlz - oz) * iz
-                    hiz = (nhz - oz) * iz
-                    tnear = jnp.maximum(
-                        jnp.maximum(
-                            jnp.minimum(lox, hix), jnp.minimum(loy, hiy)
-                        ),
-                        jnp.minimum(loz, hiz),
-                    )
-                    tfar = jnp.minimum(
-                        jnp.minimum(
-                            jnp.maximum(lox, hix), jnp.maximum(loy, hiy)
-                        ),
-                        jnp.maximum(loz, hiz),
-                    )
-                    packet_hit = (
-                        tfar >= jnp.maximum(tnear, 0.0)
-                    ) & (tnear < limit)
-                    hit_any = jnp.any(packet_hit)
+                    bounds, nskip, start, cnt = tlas_node(tbase + node)
+                    hit_any = slab_any(bounds, ox, oy, oz, ix, iy, iz, limit)
                     is_leaf = cnt > 0
                     next_node = jnp.where(
                         hit_any,
@@ -1983,25 +2008,8 @@ def _mesh_trace_kernel_factory(
             so only the reads offset. Returns (next_node, leaf start,
             leaf count, do_leaf).
             """
-            (nlx, nly, nlz, nhx, nhy, nhz), nskip, start, count = (
-                blas_node(obase + node)
-            )
-            lox = (nlx - ox) * invx
-            hix = (nhx - ox) * invx
-            loy = (nly - oy) * invy
-            hiy = (nhy - oy) * invy
-            loz = (nlz - oz) * invz
-            hiz = (nhz - oz) * invz
-            tnear = jnp.maximum(
-                jnp.maximum(jnp.minimum(lox, hix), jnp.minimum(loy, hiy)),
-                jnp.minimum(loz, hiz),
-            )
-            tfar = jnp.minimum(
-                jnp.minimum(jnp.maximum(lox, hix), jnp.maximum(loy, hiy)),
-                jnp.maximum(loz, hiz),
-            )
-            packet_hit = (tfar >= jnp.maximum(tnear, 0.0)) & (tnear < limit)
-            hit_any = jnp.any(packet_hit)
+            bounds, nskip, start, count = blas_node(obase + node)
+            hit_any = slab_any(bounds, ox, oy, oz, invx, invy, invz, limit)
             is_leaf = count > 0
             next_node = jnp.where(
                 hit_any,
@@ -2020,6 +2028,13 @@ def _mesh_trace_kernel_factory(
             v0b = v0_ref[pl.dslice(start, leaf_size), :]
             e1b = e1_ref[pl.dslice(start, leaf_size), :]
             e2b = e2_ref[pl.dslice(start, leaf_size), :]
+            return triangle_tcand(
+                v0b, e1b, e2b, count, ox, oy, oz, dx, dy, dz
+            )
+
+        def triangle_tcand(v0b, e1b, e2b, count, ox, oy, oz, dx, dy, dz):
+            """``leaf_tcand``'s test on a leaf's rows, wherever they were
+            read from (the resident tables or a staged treelet)."""
             v0x, v0y, v0z = v0b[:, 0:1], v0b[:, 1:2], v0b[:, 2:3]
             e1x, e1y, e1z = e1b[:, 0:1], e1b[:, 1:2], e1b[:, 2:3]
             e2x, e2y, e2z = e2b[:, 0:1], e2b[:, 1:2], e2b[:, 2:3]
@@ -2047,6 +2062,135 @@ def _mesh_trace_kernel_factory(
             )
             t_cand = jnp.where(tri_hit, tt, INF)
             return tri_hit, t_cand
+
+        if stream is not None:
+            top_nodes, node_words = stream
+
+            def six(ref, node, stride):
+                base = node * stride
+                return tuple(ref[base + i] for i in range(6))
+
+            def staged_meta(node):
+                return nodes_buf[node * 8 + 6].astype(jnp.int32)
+
+            def stage_treelet(treelet):
+                """Bring ``treelet`` into scratch unless it is the one
+                staged already; returns 1 where it was fetched."""
+                fetch = staged_ref[0] != treelet
+
+                @pl.when(fetch)
+                def _():
+                    copies = (
+                        pltpu.make_async_copy(
+                            tri_hbm.at[treelet], tri_buf, dma_sem.at[0]
+                        ),
+                        pltpu.make_async_copy(
+                            nodes_hbm.at[
+                                pl.ds(
+                                    pl.multiple_of(
+                                        treelet * node_words, node_words
+                                    ),
+                                    node_words,
+                                )
+                            ],
+                            nodes_buf, dma_sem.at[1],
+                        ),
+                    )
+                    for copy in copies:
+                        copy.start()
+                    for copy in copies:
+                        copy.wait()
+                    staged_ref[0] = treelet
+
+                return fetch.astype(jnp.int32)
+
+            def staged_leaf(leaf):
+                """The 16 rows of local leaf ``leaf`` with its 12 columns
+                brought to lanes 0..11: v0, e1, e2, normal."""
+                rows = tri_buf[
+                    pl.ds(pl.multiple_of((leaf >> 3) * leaf_size, leaf_size),
+                          leaf_size), :
+                ]
+                return pltpu.roll(rows, (128 - (leaf & 7) * 16) & 127, 1)
+
+            def stream_walk(
+                touch, ox, oy, oz, invx, invy, invz, limit_of, on_leaf,
+                carry, stats,
+            ):
+                """The threaded walk of one instance's BLAS over HBM
+                tables: the resident top, and under a top leaf the
+                treelet it names, staged and walked in place. ``carry`` is
+                the walk's tuple of [1, BR] rows, ``limit_of(carry)`` the
+                per-lane cull distance, ``on_leaf(carry, rows, count)``
+                the leaf's update; ``stats`` = (node visits, treelet
+                fetches) so far. Returns (carry, stats)."""
+                width = len(carry)
+
+                def inside(treelet, carry, stats):
+                    fetched = stage_treelet(treelet)
+                    root = staged_meta(0)
+                    local_nodes = root & 0xFF
+                    # The root's box is the top leaf's, tested already;
+                    # a one-leaf treelet's root is the leaf itself.
+                    node0 = jnp.where((root >> 16) > 0, 0, 1)
+
+                    def body(walk):
+                        node, visits = walk[0], walk[-1]
+                        carry = tuple(walk[1:-1])
+                        meta = staged_meta(node)
+                        count = meta >> 16
+                        hit_any = slab_any(
+                            six(nodes_buf, node, 8), ox, oy, oz, invx, invy,
+                            invz, limit_of(carry),
+                        )
+                        is_leaf = count > 0
+                        carry = jax.lax.cond(
+                            is_leaf & hit_any,
+                            lambda: on_leaf(
+                                carry, staged_leaf((meta >> 8) & 0xFF), count
+                            ),
+                            lambda: carry,
+                        )
+                        next_node = jnp.where(
+                            hit_any & jnp.logical_not(is_leaf),
+                            node + 1, meta & 0xFF,
+                        )
+                        return (next_node, *carry, visits + 1)
+
+                    walk = jax.lax.while_loop(
+                        lambda walk: walk[0] < local_nodes, body,
+                        (node0, *carry, stats[0]),
+                    )
+                    return tuple(walk[1:-1]), (walk[-1], stats[1] + fetched)
+
+                def top_body(walk):
+                    node = walk[0]
+                    carry = tuple(walk[1:1 + width])
+                    stats = tuple(walk[1 + width:])
+                    meta = topm_ref[node]
+                    treelet = (meta >> 16) - 1
+                    hit_any = slab_any(
+                        six(topb_ref, node, 6), ox, oy, oz, invx, invy, invz,
+                        limit_of(carry),
+                    )
+                    is_leaf = treelet >= 0
+                    carry, stats = jax.lax.cond(
+                        is_leaf & hit_any,
+                        lambda: inside(treelet, carry, stats),
+                        lambda: (carry, stats),
+                    )
+                    next_node = jnp.where(
+                        hit_any & jnp.logical_not(is_leaf),
+                        node + 1, meta & 0xFFFF,
+                    )
+                    return (next_node, *carry, stats[0] + 1, stats[1])
+
+                node0 = jnp.where(touch, jnp.int32(0), jnp.int32(top_nodes))
+                walk = jax.lax.while_loop(
+                    lambda walk: walk[0] < top_nodes, top_body,
+                    (node0, *carry, *stats),
+                )
+                return tuple(walk[1:1 + width]), tuple(walk[1 + width:])
 
         def world_cull(k, wox, woy, woz, wix, wiy, wiz, limit_t):
             """Block-wide test of the untransformed rays against instance
@@ -2084,7 +2228,7 @@ def _mesh_trace_kernel_factory(
             wix, wiy, wiz = winv(wdx), winv(wdy), winv(wdz)
 
             def per_instance(k, carry):
-                best_t, bnx, bny, bnz, bar, bag, bab, bslot = carry
+                best_t, bnx, bny, bnz, bar, bag, bab, bslot = carry[:8]
                 # The winning instance's SLOT label: the quant tiers'
                 # packed-key candidate — a lane that hit instance X
                 # bounces off X's surface, so X IS the next ray's
@@ -2108,6 +2252,49 @@ def _mesh_trace_kernel_factory(
                 dy = (wdx * r01 + wdy * r11 + wdz * r21) * inv_s
                 dz = (wdx * r02 + wdy * r12 + wdz * r22) * inv_s
                 invx, invy, invz = winv(dx), winv(dy), winv(dz)
+                if stream is not None:
+                    def on_leaf(carry, rows, count):
+                        (best_t, bnx, bny, bnz, bar_, bag_, bab_,
+                         bslot_) = carry
+                        _tri_hit, t_cand = triangle_tcand(
+                            rows[:, 0:3], rows[:, 3:6], rows[:, 6:9], count,
+                            ox, oy, oz, dx, dy, dz,
+                        )
+                        t_leaf = jnp.min(t_cand, axis=0, keepdims=True)
+                        local = jnp.min(
+                            jnp.where(t_cand == t_leaf, lanes, leaf_size),
+                            axis=0, keepdims=True,
+                        )
+                        winner = (lanes == local).astype(jnp.float32)
+                        nox = jnp.sum(
+                            winner * rows[:, 9:10], axis=0, keepdims=True
+                        )
+                        noy = jnp.sum(
+                            winner * rows[:, 10:11], axis=0, keepdims=True
+                        )
+                        noz = jnp.sum(
+                            winner * rows[:, 11:12], axis=0, keepdims=True
+                        )
+                        closer = t_leaf < best_t
+                        wnx = r00 * nox + r01 * noy + r02 * noz
+                        wny = r10 * nox + r11 * noy + r12 * noz
+                        wnz = r20 * nox + r21 * noy + r22 * noz
+                        return (
+                            jnp.where(closer, t_leaf, best_t),
+                            jnp.where(closer, wnx, bnx),
+                            jnp.where(closer, wny, bny),
+                            jnp.where(closer, wnz, bnz),
+                            jnp.where(closer, ar, bar_),
+                            jnp.where(closer, ag, bag_),
+                            jnp.where(closer, ab, bab_),
+                            jnp.where(closer, slot_of_k, bslot_),
+                        )
+
+                    walked, stats = stream_walk(
+                        touch, ox, oy, oz, invx, invy, invz,
+                        lambda c: c[0], on_leaf, carry[:8], carry[8:],
+                    )
+                    return (*walked, *stats)
                 obase = blas_base(dx, dy, dz)
 
                 def cond(walk):
@@ -2198,6 +2385,8 @@ def _mesh_trace_kernel_factory(
                 jnp.zeros((1, block), jnp.float32),
                 jnp.full((1, block), slot_sentinel, jnp.float32),
             )
+            if stream is not None:
+                init = (*init, jnp.int32(0), jnp.int32(0))
             if use_tlas:
                 # Two-level walk: threaded skip-link TLAS over instance
                 # groups; a leaf hit runs the EXISTING per-instance BLAS
@@ -2206,17 +2395,15 @@ def _mesh_trace_kernel_factory(
                 # beats its entry) jumps the whole subtree — the flat
                 # K-cull sweep this replaces paid every instance every
                 # block.
-                best_t, bnx, bny, bnz, bar, bag, bab, bslot = tlas_walk(
+                walked = tlas_walk(
                     jnp.int32(0), jnp.int32(tlas_nodes),
                     tlas_base(wdx, wdy, wdz),
                     wox, woy, woz, wix, wiy, wiz,
                     lambda c: c[0], per_instance, init,
                 )
             else:
-                (best_t, bnx, bny, bnz, bar, bag, bab,
-                 bslot) = jax.lax.fori_loop(
-                    0, k_count, per_instance, init,
-                )
+                walked = jax.lax.fori_loop(0, k_count, per_instance, init)
+            best_t, bnx, bny, bnz, bar, bag, bab, bslot = walked[:8]
             # Flip toward the incoming ray (matches mesh.intersect_instances).
             facing = (
                 bnx * d[0:1, :] + bny * d[1:2, :] + bnz * d[2:3, :]
@@ -2224,11 +2411,13 @@ def _mesh_trace_kernel_factory(
             sign = jnp.where(facing, 1.0, -1.0)
             return (
                 best_t, (bnx * sign, bny * sign, bnz * sign),
-                (bar, bag, bab), bslot,
+                (bar, bag, bab), bslot, *walked[8:],
             )
 
-        def mesh_occluded(o, occluded0):
+        def mesh_occluded(o, occluded0, stats=()):
             """Any-hit toward the (uniform) sun for shadow origins ``o``.
+            ``stats`` rides the walks of a streamed BLAS (``stream_walk``)
+            and comes back after the result.
 
             ``occluded0`` [1, BR] pre-marks lanes whose result cannot
             matter (sphere-shadowed, dead, backfacing): they stop driving
@@ -2245,7 +2434,7 @@ def _mesh_trace_kernel_factory(
             sunz = sunsm_ref[2]
             wix, wiy, wiz = winv(sunx), winv(suny), winv(sunz)
 
-            def per_instance(k, occluded):
+            def per_instance(k, occluded, stats=()):
                 r00, r01, r02 = inst_ref[k, 0], inst_ref[k, 1], inst_ref[k, 2]
                 r10, r11, r12 = inst_ref[k, 3], inst_ref[k, 4], inst_ref[k, 5]
                 r20, r21, r22 = inst_ref[k, 6], inst_ref[k, 7], inst_ref[k, 8]
@@ -2265,6 +2454,23 @@ def _mesh_trace_kernel_factory(
                 dy = (sunx * r01 + suny * r11 + sunz * r21) * inv_s
                 dz = (sunx * r02 + suny * r12 + sunz * r22) * inv_s
                 invx, invy, invz = winv(dx), winv(dy), winv(dz)
+                if stream is not None:
+                    def on_leaf(carry, rows, count):
+                        tri_hit, _ = triangle_tcand(
+                            rows[:, 0:3], rows[:, 3:6], rows[:, 6:9], count,
+                            ox, oy, oz, dx, dy, dz,
+                        )
+                        return (jnp.maximum(carry[0], jnp.max(
+                            jnp.where(tri_hit, 1.0, 0.0), axis=0,
+                            keepdims=True,
+                        )),)
+
+                    (walked_occluded,), stats = stream_walk(
+                        touch, ox, oy, oz, invx, invy, invz,
+                        lambda c: jnp.where(c[0] > 0.0, -INF, INF),
+                        on_leaf, (occluded,), stats,
+                    )
+                    return (walked_occluded, *stats)
                 obase = blas_base(dx, dy, dz)
 
                 def cond(walk):
@@ -2311,6 +2517,15 @@ def _mesh_trace_kernel_factory(
                 # any-hit limit convention: lanes whose result cannot
                 # matter (pre-occluded) carry a -INF limit and never
                 # drive a node's packet test.
+                if stream is not None:
+                    return tlas_walk(
+                        jnp.int32(0), jnp.int32(tlas_nodes),
+                        tlas_base(sunx, suny, sunz),
+                        wox, woy, woz, wix, wiy, wiz,
+                        lambda c: jnp.where(c[0] > 0.0, -INF, INF),
+                        lambda k, c: per_instance(k, c[0], c[1:]),
+                        (occluded0, *stats),
+                    )
                 return tlas_walk(
                     jnp.int32(0), jnp.int32(tlas_nodes),
                     tlas_base(sunx, suny, sunz),
@@ -2319,6 +2534,12 @@ def _mesh_trace_kernel_factory(
                     lambda k, c: (per_instance(k, c[0]),),
                     (occluded0,),
                 )[0]
+            if stream is not None:
+                return jax.lax.fori_loop(
+                    0, k_count,
+                    lambda k, c: per_instance(k, c[0], c[1:]),
+                    (occluded0, *stats),
+                )
             return jax.lax.fori_loop(0, k_count, per_instance, occluded0)
 
         throughput = jnp.ones((3, block), jnp.float32)
@@ -2365,7 +2586,7 @@ def _mesh_trace_kernel_factory(
             # lanes stays finite and alive-masked).
             t_sp = jnp.minimum(t_sphere, t_plane)
             seed_t = jnp.where(alive > 0.5, t_sp, -INF)
-            t_mesh, (mnx, mny, mnz), (mar, mag, mab), hit_slot = (
+            t_mesh, (mnx, mny, mnz), (mar, mag, mab), hit_slot, *stats = (
                 mesh_nearest(o, d, seed_t)
             )
 
@@ -2451,7 +2672,10 @@ def _mesh_trace_kernel_factory(
                     1.0 - alive, (cos_sun <= 0.0).astype(jnp.float32)
                 ),
             )
-            shadowed = mesh_occluded(shadow_o, occluded0)
+            if stream is not None:
+                shadowed, *stats = mesh_occluded(shadow_o, occluded0, stats)
+            else:
+                shadowed = mesh_occluded(shadow_o, occluded0)
             direct = (
                 albedo * sun_color * (cos_sun * (1.0 - shadowed) * alive)
                 / jnp.float32(jnp.pi)
@@ -2487,7 +2711,7 @@ def _mesh_trace_kernel_factory(
             live = alive > 0.5
             o = jnp.where(live, new_o, o)
             d = jnp.where(live, new_d, d)
-            return (o, d, throughput, radiance, alive, hit_slot)
+            return (o, d, throughput, radiance, alive, hit_slot, *stats)
 
         if state_io:
             # ONE bounce with streamed state: overwrite the in-kernel
@@ -2503,7 +2727,16 @@ def _mesh_trace_kernel_factory(
             bounce_index = bounce_ref[0, 0]
             block_start = pl.program_id(0) * block
             slot_sentinel = jnp.float32(k_count)
-            o, d, throughput, radiance, alive, hit_slot = jax.lax.cond(
+            if stream is not None:
+                # Nothing is staged when the launch begins; scratch
+                # outlives a grid step, so later blocks find what the
+                # last one left.
+                @pl.when(pl.program_id(0) == 0)
+                def _():
+                    staged_ref[0] = jnp.int32(-1)
+
+            no_stats = () if stream is None else (jnp.int32(0), jnp.int32(0))
+            o, d, throughput, radiance, alive, hit_slot, *stats = jax.lax.cond(
                 block_start < live_ref[0, 0],
                 lambda: bounce_step(
                     bounce_index, (o, d, throughput, radiance, alive)
@@ -2511,8 +2744,16 @@ def _mesh_trace_kernel_factory(
                 lambda: (
                     o, d, throughput, radiance, alive,
                     jnp.full((1, block), slot_sentinel, jnp.float32),
+                    *no_stats,
                 ),
             )
+            if stream is not None:
+                # lane 0: node visits, lane 1: treelet fetches
+                lane_id = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+                stats_ref[:, :] = jnp.where(
+                    lane_id == 0, stats[0],
+                    jnp.where(lane_id == 1, stats[1], 0),
+                )
             out_ref[:, :] = radiance
             o_out_ref[:, :] = o
             d_out_ref[:, :] = d
@@ -2863,12 +3104,16 @@ def _mesh_bounce_io(
     plane_albedo_a, plane_albedo_b,
     rotation, translation, scale, inst_albedo,
     v0, e1, e2, normal, bounds_min, bounds_max, skip, first, count,
-    octant=None,
+    octant=None, stream=None,
     *, total_bounces: int, interpret: bool, use_tlas: bool = False,
     tlas_leaf: int = 4, tlas_block: int = 256, quant: int = 0,
 ):
     from tpu_render_cluster.render.mesh import LEAF_SIZE
 
+    if stream is not None:
+        # A streamed BLAS has no resident tables to pack: fp32 node words
+        # in HBM, one canonical order.
+        quant = 0
     # The TLAS variant blocks rays at its own narrower packet width —
     # threaded in by the caller (env tiers resolve outside traces).
     block = tlas_block if use_tlas else BVH_BLOCK_R
@@ -2905,7 +3150,8 @@ def _mesh_bounce_io(
     bounce_arr = jnp.asarray(bounce, jnp.int32).reshape(1, 1)
     live_arr = jnp.asarray(live_count, jnp.int32).reshape(1, 1)
 
-    n_nodes = skip.shape[0]
+    n_nodes = 0 if stream is not None else skip.shape[0]
+    tri_rows = 0 if stream is not None else v0.shape[0]
     k_count = rotation.shape[0]
     if use_tlas:
         # TLAS slot order: Morton over instance world-AABB centers —
@@ -2937,7 +3183,7 @@ def _mesh_bounce_io(
         tlas_nodes = int(topology.skip.shape[0])
         quant = resolve_bvh_quant(
             quant,
-            (n_nodes, v0.shape[0] // LEAF_SIZE, LEAF_SIZE),
+            (n_nodes, tri_rows // LEAF_SIZE, LEAF_SIZE),
             (tlas_nodes, k_count, tlas_leaf),
         )
         tlas_operands, tlas_specs = _node_table_operands(
@@ -2966,19 +3212,11 @@ def _mesh_bounce_io(
             bounds_min, bounds_max, inst_albedo[near_first],
         )
         quant = resolve_bvh_quant(
-            quant, (n_nodes, v0.shape[0] // LEAF_SIZE, LEAF_SIZE)
+            quant, (n_nodes, tri_rows // LEAF_SIZE, LEAF_SIZE)
         )
         tlas_specs = []
         extra_operands = ()
         tlas_nodes = 0
-    blas_arrays = _blas_node_arrays(
-        bounds_min, bounds_max, skip, first, count, octant
-    )
-    ordered = blas_arrays[5]
-    blas_operands, blas_specs = _node_table_operands(
-        *blas_arrays[:5], quant=quant, first_unit=LEAF_SIZE,
-    )
-
     grid = (padded_rays // block,)
     whole = lambda i: (0, 0)  # noqa: E731
     flat = lambda i: (0,)  # noqa: E731
@@ -2988,6 +3226,52 @@ def _mesh_bounce_io(
     row_block = pl.BlockSpec(
         (1, block), lambda i: (0, i), memory_space=pltpu.VMEM
     )
+    if stream is not None:
+        from tpu_render_cluster.render.mesh import treelet_node_words
+
+        # The BLAS stays in HBM: the kernel copies a treelet's rows and
+        # node table into this scratch when a packet enters it. Only the
+        # tree's top sits in SMEM for the whole launch.
+        node_words = treelet_node_words(stream)
+        ordered = False
+        geometry_operands = (
+            stream.tri, stream.nodes, stream.top_bounds, stream.top_meta,
+        )
+        geometry_specs = [
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(stream.top_bounds.shape, flat, memory_space=pltpu.SMEM),
+            pl.BlockSpec(stream.top_meta.shape, flat, memory_space=pltpu.SMEM),
+        ]
+        scratch_shapes = [
+            pltpu.VMEM(stream.tri.shape[1:], jnp.float32),
+            pltpu.SMEM((node_words,), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+        stream_shape = (int(stream.top_meta.shape[0]), node_words)
+        stats_specs = [row_block]
+        stats_shapes = [jax.ShapeDtypeStruct((1, padded_rays), jnp.int32)]
+        kernel_name = "mesh_bounce_streamed"
+    else:
+        blas_arrays = _blas_node_arrays(
+            bounds_min, bounds_max, skip, first, count, octant
+        )
+        ordered = blas_arrays[5]
+        blas_operands, blas_specs = _node_table_operands(
+            *blas_arrays[:5], quant=quant, first_unit=LEAF_SIZE,
+        )
+        geometry_operands = (v0, e1, e2, normal, *blas_operands)
+        geometry_specs = [
+            pl.BlockSpec(v0.shape, whole, memory_space=pltpu.VMEM),
+            pl.BlockSpec(e1.shape, whole, memory_space=pltpu.VMEM),
+            pl.BlockSpec(e2.shape, whole, memory_space=pltpu.VMEM),
+            pl.BlockSpec(normal.shape, whole, memory_space=pltpu.VMEM),
+        ] + blas_specs
+        scratch_shapes = []
+        stream_shape = None
+        stats_specs, stats_shapes = [], []
+        kernel_name = None
     extra_specs = (
         tlas_specs + [pl.BlockSpec((6,), flat, memory_space=pltpu.SMEM)]
         if use_tlas
@@ -3003,7 +3287,7 @@ def _mesh_bounce_io(
             total_bounces, padded_n, n_nodes, LEAF_SIZE, k_count,
             state_io=True, use_tlas=use_tlas, tlas_nodes=tlas_nodes,
             quant=quant, ordered=ordered,
-            tlas_ordered=use_tlas and ordered,
+            tlas_ordered=use_tlas and ordered, stream=stream_shape,
         ),
         grid=grid,
         in_specs=[
@@ -3025,29 +3309,27 @@ def _mesh_bounce_io(
             pl.BlockSpec((8, 3), whole, memory_space=pltpu.VMEM),
             pl.BlockSpec((3,), flat, memory_space=pltpu.SMEM),
             pl.BlockSpec(inst_table.shape, whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec(v0.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(e1.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(e2.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(normal.shape, whole, memory_space=pltpu.VMEM),
-        ] + blas_specs + extra_specs,
+        ] + geometry_specs + extra_specs,
         out_specs=[ray_block, ray_block, ray_block, ray_block, row_block]
-        + key_out_specs,
+        + key_out_specs + stats_specs,
         out_shape=[
             jax.ShapeDtypeStruct((3, padded_rays), jnp.float32),
             jax.ShapeDtypeStruct((3, padded_rays), jnp.float32),
             jax.ShapeDtypeStruct((3, padded_rays), jnp.float32),
             jax.ShapeDtypeStruct((3, padded_rays), jnp.float32),
             jax.ShapeDtypeStruct((1, padded_rays), jnp.float32),
-        ] + key_out_shapes,
+        ] + key_out_shapes + stats_shapes,
+        scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name=kernel_name,
     )(seed_arr, bounce_arr, live_arr, o_t, d_t, thr_t, alive_t, lane_t,
       c_t, r2, csq, rad,
       albedo_t, emission_t, dc_sun, params, sun_direction, inst_table,
-      v0, e1, e2, normal, *blas_operands,
+      *geometry_operands,
       *extra_operands)
     contrib, o2, d2, thr2, alive2 = results[:5]
     key2 = results[5][0, :rays] if use_tlas else None
-    return (
+    state = (
         contrib.T[:rays],
         o2.T[:rays],
         d2.T[:rays],
@@ -3055,6 +3337,11 @@ def _mesh_bounce_io(
         alive2[0, :rays] > 0.5,
         key2,
     )
+    if stream is None:
+        return state
+    # every block's (node visits, treelet fetches), summed over the launch
+    walk = results[-1].reshape(-1, block)[:, :2].sum(axis=0)
+    return (*state, walk)
 
 
 def mesh_bounce_pallas(
@@ -3078,7 +3365,9 @@ def mesh_bounce_pallas(
     two-level TLAS kernel variant, which also emits the fused coherence
     sort key of the POST-bounce state. Returns (radiance contribution
     [R, 3], new origins, new directions, new throughput, new alive,
-    key [R] int32 — None on the flat variant).
+    key [R] int32 — None on the flat variant). A mesh whose BLAS is
+    streamed (``mesh.bvh.stream``) also returns the launch's walk counts,
+    int32 [2]: node visits and treelet fetches.
     """
     n = origins.shape[0]
     if lane is None:
@@ -3087,6 +3376,11 @@ def mesh_bounce_pallas(
         live_count = jnp.int32(n)
     bvh = mesh.bvh
     instances = mesh.instances
+    if bvh.stream is not None:
+        # No traced program holds the host arrays of a streamed tree.
+        from tpu_render_cluster.render.mesh import traced_stream_bvh
+
+        bvh = traced_stream_bvh(bvh.stream)
     return _mesh_bounce_io(
         origins, directions, throughput, alive, lane, live_count, seed,
         bounce,
@@ -3097,7 +3391,7 @@ def mesh_bounce_pallas(
         instances.albedo,
         bvh.v0, bvh.e1, bvh.e2, bvh.normal,
         bvh.bounds_min, bvh.bounds_max, bvh.skip, bvh.first, bvh.count,
-        bvh.octant,
+        bvh.octant, bvh.stream,
         total_bounces=total_bounces, interpret=_interpret(),
         use_tlas=use_tlas_for(instances.translation.shape[0], use_tlas),
         tlas_leaf=tlas_leaf_size(),

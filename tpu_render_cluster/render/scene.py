@@ -41,9 +41,13 @@ SCENE_NAMES = (
     "01_simple-animation",
     "02_physics-mesh",
     "02_physics",
+    "03_physics-2-scan",
     "03_physics-2-mesh",
     "03_physics-2",
 )
+# The rigid-body families whose 48 bodies share one set of transforms:
+# icospheres, or instances of the 871,200-triangle scan stand-in.
+_PHYSICS_2_BODIES = ("03_physics-2-mesh", "03_physics-2-scan")
 
 _FPS = 24.0
 _GRAVITY = 9.81
@@ -182,7 +186,7 @@ def build_mesh_instances(name: str, frame):
     shared box BVH); only the rigid transforms depend on the frame, so the
     whole thing jits and vmaps over frames.
     """
-    if name not in ("02_physics-mesh", "03_physics-2-mesh"):
+    if name != "02_physics-mesh" and name not in _PHYSICS_2_BODIES:
         return None
     from tpu_render_cluster.render.mesh import MeshInstances, rotation_y
 
@@ -190,12 +194,12 @@ def build_mesh_instances(name: str, frame):
     t = frame / _FPS
     # 03's variant: more, smaller icosphere instances (chaotic spread) —
     # the deeper 127-node BVH makes traversal depth matter.
-    k = 48 if name == "03_physics-2-mesh" else 24
+    k = 48 if name in _PHYSICS_2_BODIES else 24
     index = jnp.arange(k, dtype=jnp.float32)
     u1 = jnp.mod(index * 0.7548776662, 1.0)
     u2 = jnp.mod(index * 0.5698402909, 1.0)
     u3 = jnp.mod(index * 0.3819660113, 1.0)
-    if name == "03_physics-2-mesh":
+    if name in _PHYSICS_2_BODIES:
         size = 0.45 + 0.35 * u3
         x = (u1 - 0.5) * 9.0 + 0.5 * jnp.sin(12.0 * u2)
         z = (u2 - 0.5) * 9.0 + 0.5 * jnp.cos(12.0 * u1)
@@ -236,6 +240,8 @@ def mesh_kind_for_scene(name: str) -> str | None:
         return "box"
     if name == "03_physics-2-mesh":
         return "icosphere"
+    if name == "03_physics-2-scan":
+        return "scan"
     return None
 
 
@@ -255,7 +261,7 @@ def build_scene(name: str, frame) -> Scene:
         spheres = _physics(frame, 12, 16, chaos=0.0)
     elif name == "03_physics-2":
         spheres = _physics(frame, 96, 128, chaos=1.0)
-    elif name == "03_physics-2-mesh":
+    elif name in _PHYSICS_2_BODIES:
         spheres = _physics(frame, 16, 16, chaos=1.0)
     else:
         raise ValueError(f"Unknown scene: {name!r} (have {SCENE_NAMES})")
@@ -278,7 +284,7 @@ def scene_for_job_name(job_name: str) -> str:
             return name
     # Two-digit project prefixes map to the classic (non-mesh) families.
     for name in SCENE_NAMES:
-        if name.endswith("-mesh"):
+        if name.endswith(("-mesh", "-scan")):
             continue
         if job_name.startswith(name.split("_", 1)[0]):
             return name

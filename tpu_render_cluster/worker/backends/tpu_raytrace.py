@@ -65,6 +65,21 @@ class TpuRaytraceBackend(RenderBackend):
         self._tier_frames = self._tier_frames_counter()
         for tier in ("masked", "region", "sharded"):
             self._tier_frames.inc(0.0, tier=tier)
+        from tpu_render_cluster.obs import get_registry
+
+        get_registry().gauge(
+            "render_device_units",
+            "Devices this backend renders on, labelled with JAX's platform "
+            "and device_kind: what a reader needs to pick the chip's "
+            "published peaks",
+            labels=("platform", "kind"),
+        ).set(
+            float(self.device["count"]), platform=self.device["platform"],
+            kind=self.device["device_kind"],
+        )
+        # (start, seconds) of warm()'s BLAS build, for the worker's
+        # timeline: its span tracer does not exist yet when warm() runs.
+        self.bvh_build: tuple[float, float] | None = None
 
     def warm(self, scene_name: str) -> None:
         """Compile + execute the renderer once, outside any job window.
@@ -81,6 +96,7 @@ class TpuRaytraceBackend(RenderBackend):
         # the render path does — otherwise the warmed program can differ
         # from the one the job compiles.
         scene_name = scene_for_job_name(scene_name)
+        self._build_geometry(scene_name)
 
         if self.sharding in ("tile", "spp"):
             from tpu_render_cluster.parallel.sharded_render import sharded_frame_renderer
@@ -99,7 +115,7 @@ class TpuRaytraceBackend(RenderBackend):
             from tpu_render_cluster.render.integrator import fused_frame_renderer
 
             # The program _render_timed runs: with the live counts.
-            display, _ = fused_frame_renderer(
+            display, *_ = fused_frame_renderer(
                 scene_name,
                 self.width,
                 self.height,
@@ -108,6 +124,40 @@ class TpuRaytraceBackend(RenderBackend):
                 with_live=True,
             )(1)
             np.asarray(display)
+
+    def _build_geometry(self, scene_name: str) -> None:
+        """Build the scene's BLAS (once a process: the renderer factories
+        find it cached) and say how long it took and where it lives."""
+        import jax
+
+        from tpu_render_cluster.obs import get_registry
+        from tpu_render_cluster.render.integrator import resolve_bvh_config
+        from tpu_render_cluster.render.mesh import cached_mesh_bvh, geometry_bytes
+        from tpu_render_cluster.render.scene import mesh_kind_for_scene
+
+        kind = mesh_kind_for_scene(scene_name)
+        if kind is None:
+            return
+        started_at, started = time.time(), time.perf_counter()
+        bvh = cached_mesh_bvh(kind, *resolve_bvh_config()[2:])
+        jax.block_until_ready(bvh)  # the tables are on the device, not on their way
+        seconds = time.perf_counter() - started
+        self.bvh_build = (started_at, seconds)
+        registry = get_registry()
+        registry.gauge(
+            "render_bvh_build_seconds",
+            "Seconds warm() spent building the scene's BLAS and putting "
+            "its tables on the device",
+        ).set(seconds)
+        where = registry.gauge(
+            "render_geometry_bytes",
+            "Bytes of the scene's BLAS tables by the memory they live in "
+            "while a bounce kernel runs: hbm (streamed by treelet), vmem "
+            "and smem (resident)",
+            labels=("space",),
+        )
+        for space, count in geometry_bytes(bvh).items():
+            where.set(float(count), space=space)
 
     async def render_frame(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
@@ -172,6 +222,36 @@ class TpuRaytraceBackend(RenderBackend):
             occupancy.observe(int(live) / int(width))
         cls._launched_lanes_counter().inc(float(launches[:, 1].sum()))
         cls._live_lanes_counter().inc(float(launches[:, 0].sum()))
+
+    @staticmethod
+    def _observe_walk(walk, scene_name: str) -> None:
+        """The walk of a frame whose BLAS is streamed from HBM:
+        ``walk[b]`` = bounce b's launch's (node visits, treelet fetches),
+        counted by the kernel and returned by the frame's program."""
+        from tpu_render_cluster.obs import get_registry
+        from tpu_render_cluster.render.integrator import resolve_bvh_config
+        from tpu_render_cluster.render.mesh import scene_blas_stream, treelet_fetch_bytes
+
+        # the tables the frame's program was handed (cached: a lookup)
+        fetch_bytes = treelet_fetch_bytes(
+            scene_blas_stream(scene_name, *resolve_bvh_config()[2:])
+        )
+        registry = get_registry()
+        fetches = float(walk[:, 1].sum())
+        registry.counter(
+            "render_walk_node_visits_total",
+            "BLAS nodes visited by the bounce launches' packets (a visit "
+            "serves a whole block of rays)",
+        ).inc(float(walk[:, 0].sum()))
+        registry.counter(
+            "render_treelet_fetches_total",
+            "Treelets copied from HBM into a bounce kernel's scratch",
+        ).inc(fetches)
+        registry.counter(
+            "render_treelet_fetch_bytes_total",
+            "Bytes of treelet rows and node tables copied from HBM into a "
+            "bounce kernel's scratch",
+        ).inc(fetches * fetch_bytes)
 
     @staticmethod
     def _observe_render_obs(
@@ -293,9 +373,11 @@ class TpuRaytraceBackend(RenderBackend):
 
         started_rendering_at = time.time()
         with step("dispatch"):
-            display, launches = render()
-            if launches is not None:
-                launches.copy_to_host_async()
+            # a frame whose BLAS is streamed also returns its walk's counts
+            display, launches, *walk = render()
+            for counts in (launches, *walk):
+                if counts is not None:
+                    counts.copy_to_host_async()
             # Ask for the pixels now, behind the frame's work in the
             # device's queue, as np.asarray on an unfinished array does:
             # a copy first asked for after the wait below would cost the
@@ -310,6 +392,7 @@ class TpuRaytraceBackend(RenderBackend):
             pixels = np.asarray(display)
             if launches is not None:
                 launches = np.asarray(launches)
+            walk = [np.asarray(counts) for counts in walk]
         finished_rendering_at = time.time()
 
         file_saving_started_at = time.time()
@@ -356,6 +439,8 @@ class TpuRaytraceBackend(RenderBackend):
         self._tier_frames.inc(tier=tier)
         if launches is not None:
             self._observe_launches(launches)
+        for counts in walk:
+            self._observe_walk(counts, scene_name)
         self._observe_render_obs(
             execute_seconds=finished_rendering_at - started_rendering_at,
             kernel=kernel,

@@ -255,6 +255,12 @@ def main(argv: list[str] | None = None) -> int:
     if args.warm_scene and args.backend == "tpu-raytrace":
         backend.warm(args.warm_scene)
     worker = Worker(args.master_host, args.master_port, backend)
+    bvh_build = getattr(backend, "bvh_build", None)
+    if bvh_build:
+        worker.span_tracer.complete(
+            "bvh_build", cat="render", track="setup",
+            start_wall=bvh_build[0], duration=bvh_build[1],
+        )
     # Which timeline is which chip's: the device stamp's index rides the
     # exported timeline's process metadata as well as the snapshot.
     device = getattr(backend, "device", None)
