@@ -5,11 +5,15 @@
     JAX_PLATFORMS=cpu TRC_PALLAS=1 python scripts/bench-scan-walk.py --rehearse 1
 
 For each ``samples`` (default 8 and 1): the frame program's compile and
-three frames' times with each bounce launch's (live, width) and (node
-visits, treelet fetches); then bounce 0 and bounce 1 alone at full width
-(one launch each, rays sorted as the frame program sorts them); then one
-round of the glue the other walk design would pay between launches (a sort
-of the ray keys, a packed gather of the ray state, a gather of node rows).
+three frames' times and picture hashes with each bounce launch's (live,
+width) and walk counts (``pallas_kernels.WALK_COUNTS``: steps, fetches,
+leaf tests, treelet entries, group tests); then bounce 0 and bounce 1 alone
+at full width (one launch each, rays sorted as the frame program sorts
+them), each line with the steps split into box steps (top, wide) and leaf
+tests, the mean children hit per wide test and the microseconds a step;
+then one round of the glue the other walk design would pay between launches
+(a sort of the ray keys, a packed gather of the ray state, a gather of node
+rows).
 One JSON line per stage on standard output; the same lines in
 chiprun_out/scan_walk.jsonl. A microbenchmark of kernels, not the served
 path: benchmark/run.py measures that. Off a TPU it exits 2 and times
@@ -19,6 +23,7 @@ nothing; `--rehearse` walks through it at 32x32 on whatever device there is
 
 from __future__ import annotations
 
+import hashlib
 import json
 import sys
 import time
@@ -37,6 +42,9 @@ from tpu_render_cluster.render.scene import build_scene  # noqa: E402
 from tpu_render_cluster.utils.accelerator import configure_compile_cache  # noqa: E402
 
 SCENE, BOUNCES, FRAME = "03_physics-2-scan", 4, 295
+FRAMES = (304, 320, FRAME)  # two the benchmark's check may draw, and the bounces' frame last
+# the counts of a tree from before ISSUE 33, so one script reads both sides
+WALK_COUNTS = getattr(pallas_kernels, "WALK_COUNTS", ("node_visits", "treelet_fetches"))
 REHEARSE = "--rehearse" in sys.argv[1:]
 SIZE = 32 if REHEARSE else 512
 OUT = ROOT / "chiprun_out" / "scan_walk.jsonl"
@@ -62,6 +70,27 @@ def timed(fn, *args, repeats: int = 3):
     return out, times
 
 
+def walk_shape(walk, seconds: float) -> dict:
+    """A launch's counts, split: a step is a box test (a node of the top, or
+    a wide node's eight children at once) or a leaf's triangles; a wide
+    test's children hit are the groups and leaves whose turn it caused."""
+    counts = dict(zip(WALK_COUNTS, (int(x) for x in walk)))
+    steps = counts["node_visits"]
+    if "leaf_tests" not in counts:
+        return dict(counts, us_per_step=seconds * 1e6 / max(steps, 1))
+    leaf_tests = counts["leaf_tests"]
+    wide_tests = counts["treelet_entries"] + counts["group_tests"]
+    return dict(
+        counts,
+        box_steps=steps - leaf_tests,
+        top_steps=steps - leaf_tests - wide_tests,
+        wide_tests=wide_tests,
+        children_hit_per_wide_test=(counts["group_tests"] + leaf_tests) / max(wide_tests, 1),
+        leaf_test_share=leaf_tests / max(steps, 1),
+        us_per_step=seconds * 1e6 / max(steps, 1),
+    )
+
+
 def main(argv: list[str]) -> int:
     configure_compile_cache()
     device = jax.devices()[0]
@@ -81,15 +110,16 @@ def main(argv: list[str]) -> int:
         out = render(jnp.float32(FRAME))
         jax.block_until_ready(out)
         first = time.perf_counter() - start
-        times = []
-        for frame in (FRAME, FRAME + 1, FRAME + 100):
+        times, pictures = [], {}
+        for frame in FRAMES:
             start = time.perf_counter()
             image, live, walk = render(jnp.float32(frame))
             jax.block_until_ready(image)
             times.append(time.perf_counter() - start)
-        say("frame_program", samples=samples, first_call_s=first, frame_s=times,
+            pictures[frame] = hashlib.sha256(np.asarray(image).tobytes()).hexdigest()[:16]
+        say("frame_program", samples=samples, first_call_s=first, frames=FRAMES, frame_s=times,
             live=np.asarray(live).tolist(), walk=np.asarray(walk).tolist(),
-            image_std=float(np.asarray(image).std()),
+            image_std=float(np.asarray(image).std()), image_sha256=pictures,
             peak=device.memory_stats() and device.memory_stats().get("peak_bytes_in_use"))
 
         # Bounces 0 and 1 alone at full width, sorted as the program sorts.
@@ -118,7 +148,7 @@ def main(argv: list[str]) -> int:
             )
             _, o2, d2, thr2, alive2, keys, walk = out
             say("bounce_alone", samples=samples, bounce=index, rays=n, seconds=times,
-                live_in=int(jnp.sum(state[3])), walk=np.asarray(walk).tolist())
+                live_in=int(jnp.sum(state[3])), **walk_shape(np.asarray(walk), min(times)))
             order = jnp.argsort(keys)
             lane = lane[order]
             state = (o2[order], d2[order], thr2[order], alive2[order])

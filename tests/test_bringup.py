@@ -345,8 +345,7 @@ def tree(value):
 # treelets of 64 leaf slots under a top of 2,047 nodes); nothing is built.
 treelets, leaves, top = 1024, mesh_module.TREELET_LEAVES, 2047
 stream = mesh_module.BlasStream(
-    tri=spec((treelets, 2 * leaves, 128), jnp.float32),
-    nodes=spec((treelets * 1024,), jnp.float32),
+    tri=spec((treelets, 2 * leaves + mesh_module.WIDE, 128), jnp.float32),
     top_bounds=spec((top * 6,), jnp.float32), top_meta=spec((top,), jnp.int32),
     root=spec((2, 3), jnp.float32),
 )
@@ -374,9 +373,10 @@ for width in (widths[0], widths[-1]):
 
 
 def test_the_streamed_bounce_kernel_compiles_with_mosaic(tmp_path):
-    """The bounce kernel over a BLAS in HBM (ISSUE 32: copies from a tiled
-    1-D HBM array into SMEM scratch, a roll by a traced amount, a float
-    read as an integer on the scalar side), at the configuration's table
+    """The bounce kernel over a BLAS in HBM (ISSUE 32: a copy from HBM into
+    VMEM scratch, a roll by a traced amount; ISSUE 33: eight boxes down
+    the sublanes against a row of rays, their hits reduced to one scalar
+    mask), at the configuration's table
     shapes and the widest and narrowest rung of a 1 spp frame, through the
     real compiler. A subprocess: it loads libtpu."""
     result = _run(

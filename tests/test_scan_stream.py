@@ -141,6 +141,18 @@ def test_the_walk_of_the_vectorised_tree_finds_what_brute_force_finds():
     np.testing.assert_array_equal(np.asarray(index_walk)[hit], np.asarray(index_brute)[hit])
 
 
+def wide_tables(stream):
+    """The staged slab's wide nodes as ``[treelet, wide node, child, 8]``
+    and its triangle rows as ``[treelet, leaf slot, 16 triangles, 16]``."""
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    slab = np.asarray(stream.tri)
+    n_treelets, leaves = slab.shape[0], mesh_module.treelet_leaves(stream)
+    nodes = slab[:, 2 * leaves:].reshape(n_treelets, 8, 16, 8).transpose(0, 2, 1, 3)
+    rows = slab[:, :2 * leaves].reshape(n_treelets, leaves // 8, 16, 8, 16).transpose(0, 1, 3, 2, 4)
+    return nodes, rows.reshape(n_treelets, leaves, 16, 16)
+
+
 @pytest.mark.parametrize("treelet_leaves", [8, 16, 64])
 def test_the_treelets_partition_the_tree(treelet_leaves):
     from tpu_render_cluster.render import mesh as mesh_module
@@ -149,33 +161,111 @@ def test_the_treelets_partition_the_tree(treelet_leaves):
     stream = bvh.stream
     n_treelets = stream.tri.shape[0]
     assert mesh_module.treelet_leaves(stream) == treelet_leaves
-    words = mesh_module.treelet_node_words(stream)
-    assert words % 1024 == 0  # a copy out of a 1-D HBM array starts on its tiling
-    nodes = np.asarray(stream.nodes).reshape(n_treelets, words // 8, 8)
-    meta = nodes[:, :, 6].astype(np.int64)
-    local_skip, local_leaf, count = meta & 0xFF, (meta >> 8) & 0xFF, meta >> 16
-    n_local = local_skip[:, 0]
-    rows = np.asarray(stream.tri).reshape(n_treelets, treelet_leaves // 8, 16, 8, 16)
-    seen = []
+    # one slab a fetch: the triangle rows and one register of wide nodes,
+    # which fits the 1,024-word tile the node table had
+    assert stream.tri.shape[1:] == (2 * treelet_leaves + 8, 128)
+    assert mesh_module.treelet_fetch_bytes(stream) == (treelet_leaves * 16 * 16 + 1024) * 4
+    nodes, slots = wide_tables(stream)
+    boxes, bits = nodes[..., 0:6], nodes[..., 6]
+    child_bit = np.broadcast_to(2.0 ** np.arange(8), bits.shape)
+    assert set(np.unique(bits / child_bit)) <= {0.0, 1.0}  # child c's bit is 1 << c, or 0
+    present = bits > 0
+    # empty slots hold inverted boxes, wherever they are
+    assert (boxes[~present][:, 0:3] == 1e30).all() and (boxes[~present][:, 3:6] == -1e30).all()
+    assert (boxes[present][:, 0:3] <= boxes[present][:, 3:6]).all()
+    # a root and at most max_leaves / 8 groups, children packed from slot 0
+    groups = present[:, 0].sum(axis=1)
+    assert (groups >= 1).all() and groups.max() <= treelet_leaves // 8
+    assert not present[:, 1 + treelet_leaves // 8:].any()
+    for held in (present[:, 0], *np.moveaxis(present[:, 1:], 1, 0)):
+        assert (held == (np.arange(8) < held.sum(axis=1, keepdims=True))).all()
+
+    # Against the binary tree: its leaves in preorder are the wide nodes'
+    # leaves read treelet by treelet, group by group, child by child.
+    low, high = np.asarray(bvh.bounds_min), np.asarray(bvh.bounds_max)
+    first, count = np.asarray(bvh.first), np.asarray(bvh.count)
+    v0 = np.asarray(bvh.v0)
+    leaves = iter(np.flatnonzero(count > 0))
+    seen = 0
     for t in range(n_treelets):
-        used = np.arange(words // 8) < n_local[t]
-        assert (local_skip[t][used] > np.arange(words // 8)[used]).all()
-        assert (local_skip[t][used] <= n_local[t]).all()
-        leaves = used & (count[t] > 0)
-        assert 1 <= leaves.sum() <= treelet_leaves
-        assert sorted(local_leaf[t][leaves]) == list(range(leaves.sum()))
-        for leaf in local_leaf[t][leaves]:
-            seen.append(rows[t, leaf // 8, :, leaf % 8, 0:3])
-    # every leaf of the tree in one treelet, its rows the tree's rows
-    assert len(seen) == int((np.asarray(bvh.count) > 0).sum())
-    np.testing.assert_array_equal(
-        np.sort(np.concatenate(seen).round(6), axis=0), np.sort(np.asarray(bvh.v0).round(6), axis=0)
-    )
+        assert (present[t, 1:1 + groups[t]].sum(axis=1) >= 1).all()
+        assert not present[t, 1 + groups[t]:].any()
+        for group in range(groups[t]):
+            held = int(present[t, 1 + group].sum())
+            members = [next(leaves) for _ in range(held)]
+            # a child's box is its leaf's box, a group's the union of its leaves', to the bit
+            np.testing.assert_array_equal(boxes[t, 1 + group, :held, 0:3], low[members])
+            np.testing.assert_array_equal(boxes[t, 1 + group, :held, 3:6], high[members])
+            np.testing.assert_array_equal(boxes[t, 0, group, 0:3], low[members].min(axis=0))
+            np.testing.assert_array_equal(boxes[t, 0, group, 3:6], high[members].max(axis=0))
+            for child, leaf in enumerate(members):
+                # the child's leaf slot is its place: 8 * group + child
+                rows = slots[t, 8 * group + child]
+                np.testing.assert_array_equal(rows[:count[leaf], 0:3], v0[first[leaf]:first[leaf] + count[leaf]])
+                assert not rows[count[leaf]:].any()  # padding rows meet no ray
+            assert not slots[t, 8 * group + held:8 * group + 8].any()
+            seen += held
+    # every leaf of the tree the child of exactly one wide node
+    assert next(leaves, None) is None and seen == int((count > 0).sum()) == int(present[:, 1:].sum())
     top_meta = np.asarray(stream.top_meta).astype(np.int64)
     top_skip, top_treelet = top_meta & 0xFFFF, (top_meta >> 16) - 1
     assert (top_skip > np.arange(len(top_meta))).all() and top_skip[0] == len(top_meta)
     assert sorted(top_treelet[top_treelet >= 0]) == list(range(n_treelets))
     np.testing.assert_array_equal(np.asarray(stream.root), [np.asarray(bvh.bounds_min)[0], np.asarray(bvh.bounds_max)[0]])
+
+
+def one_box_at_a_time(boxes, origins, directions, limit):
+    """Which boxes some ray meets, by the kernels' packet test
+    (``slab_any``) on one box at a time, in float32."""
+    inverse = (1.0 / directions).astype(np.float32)
+    met = []
+    for box in boxes:
+        lo = (box[0:3] - origins) * inverse
+        hi = (box[3:6] - origins) * inverse
+        tnear = np.minimum(lo, hi).max(axis=1)
+        tfar = np.maximum(lo, hi).min(axis=1)
+        met.append(bool(((tfar >= np.maximum(tnear, 0.0)) & (tnear < limit)).any()))
+    return met
+
+
+@pytest.mark.parametrize("case", ["all eight", "none", "some", "an empty slot"])
+def test_a_wide_test_is_eight_box_tests_and_one_mask(case):
+    """A treelet's wide root out of the real tables against a packet of 256
+    rays: every child met, none met (through the root's own box), and a
+    packet whose limit culls some."""
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render.pallas_kernels import slab_mask
+
+    bvh, _ = small_tree(64)  # 128 leaves: two treelets of eight groups of eight
+    nodes, _ = wide_tables(bvh.stream)
+    node = nodes[0, 0].copy()
+    assert (node[:, 6] == 2.0 ** np.arange(8)).all()
+    rng = np.random.default_rng(33)
+    low, high = node[:, 0:3].min(axis=0), node[:, 3:6].max(axis=0)
+    origins = (rng.uniform(low, high, size=(256, 3)) + [0.0, 0.0, -3.0]).astype(np.float32)
+    directions = np.tile(np.float32([1e-3, 2e-3, 1.0]), (256, 1))
+    limit = np.full(256, 1e30, np.float32)
+    if case == "none":
+        # inside the root's box (the top walk would enter), outside every
+        # child's: start beyond the far side of each and look away
+        origins = origins + np.float32([0.0, 0.0, 3.0 + high[2] - low[2]])
+    elif case == "some":
+        limit = rng.uniform(2.4, 3.2, 256).astype(np.float32)
+        origins, directions = origins[:3].repeat(86, 0)[:256], directions
+    elif case == "an empty slot":
+        node[5, 0:3], node[5, 3:6], node[5, 6] = 1e30, -1e30, 0.0
+    rows = [jnp.asarray(a.reshape(1, 256)) for a in (*origins.T, *(1.0 / directions).astype(np.float32).T)]
+    mask = int(slab_mask(jnp.asarray(node), *rows, jnp.asarray(limit.reshape(1, 256))))
+    met = one_box_at_a_time(node[:, 0:6], origins, directions, limit)
+    if case == "an empty slot":
+        assert met[5]  # the test alone takes an inverted box for a hit: its bit is what keeps it out
+        met[5] = False
+    assert mask == sum(1 << child for child in range(8) if met[child])
+    if case in ("all eight", "none"):
+        assert mask == {"all eight": 0xFF, "none": 0}[case]
+    else:
+        assert 0 < mask < 0xFF
 
 
 def test_resident_or_streamed_follows_the_tables_bytes():
@@ -279,8 +369,8 @@ def test_the_streamed_walk_is_the_resident_walk_bit_for_bit_and_brute_forces_hit
         np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
     if use_tlas:
         np.testing.assert_array_equal(np.asarray(streamed[5]), np.asarray(resident[5]))
-    visits, fetches = (int(x) for x in streamed[6])
-    assert visits > fetches > 0
+    visits, fetches, leaf_tests = (int(x) for x in streamed[6][:3])
+    assert visits > leaf_tests > fetches > 0
 
     # ... and both are what testing every triangle finds.
     contribution, new_origins, _, throughput, alive = (np.asarray(x) for x in streamed[:5])
@@ -346,12 +436,29 @@ def test_the_scan_familys_frame_program_returns_the_walks_counts(small_scan_fami
     import jax.numpy as jnp
 
     from tpu_render_cluster.render import integrator
+    from tpu_render_cluster.render.pallas_kernels import WALK_COUNTS
 
     image, live, walk = integrator.fused_frame_renderer(SCAN_SCENE, 32, 32, 2, 4, with_live=True)(jnp.float32(295))
     assert image.shape == (32, 32, 3) and image.dtype == jnp.uint8
     live, walk = np.asarray(live), np.asarray(walk)
-    assert live.shape == walk.shape == (4, 2)
-    assert (walk[:, 0] >= walk[:, 1]).all() and walk[0, 1] > 0
+    assert live.shape == (4, 2) and walk.shape == (4, len(WALK_COUNTS)) == (4, 5)
+    steps, fetches, leaf_tests, entries, group_tests = walk.T
+    assert (steps >= leaf_tests + entries + group_tests).all()  # the rest are the top's
+    assert (entries >= fetches).all() and fetches[0] > 0
+    assert (group_tests > 0).all() and (leaf_tests > 0).all()
+    # The binary treelet walk (the parent commit 270e495 on this frame of this
+    # family, treelets of 8 leaves; its leaf tests read once with a counter
+    # added to a scratch copy) paid these steps for the same picture. A
+    # leaf's box is now tested in its group's step, so the steps are fewer;
+    # a wide test culls with the best-t it had before its children ran, so
+    # the leaves are no fewer; the top walk, which decides the fetches, is
+    # the parent's.
+    parent_steps, parent_leaf_tests, parent_fetches = (
+        np.array(counts) for counts in ([8240, 5557, 2926, 1382], [1693, 1094, 463, 204], [692, 473, 243, 115])
+    )
+    assert (steps < 0.75 * parent_steps).all(), steps
+    assert (leaf_tests >= parent_leaf_tests).all() and (leaf_tests < 1.1 * parent_leaf_tests).all(), leaf_tests
+    assert (np.abs(fetches - parent_fetches) <= 0.02 * parent_fetches).all(), fetches
     # without the counts asked for, the same picture
     plain = integrator.fused_frame_renderer(SCAN_SCENE, 32, 32, 2, 4)(jnp.float32(295))
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(image))
@@ -486,7 +593,7 @@ def test_the_scan_scenes_program_streams_every_bounce(small_scan_family):
     assert calls and all("mesh_bounce_streamed" in str(call.params) for call in calls)
     # the BLAS is an argument of the jitted program: HBM tables, not constants
     inner = [e for e in jaxpr.jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
-    assert inner and len(inner[-1].invars) == 1 + 5  # the frame, and BlasStream's arrays
+    assert inner and len(inner[-1].invars) == 1 + 4  # the frame, and BlasStream's arrays
 
 
 # -- the backend's series ---------------------------------------------------------
@@ -517,6 +624,14 @@ def test_the_backend_says_where_the_geometry_lives_and_counts_the_walk(small_sca
     after = render_prometheus(get_registry().snapshot())
     assert value(after, 'render_geometry_bytes{space="hbm"}') > 2048 * 64
     assert value(after, "render_bvh_build_seconds") > 0
-    for series in ("render_walk_node_visits_total", "render_treelet_fetches_total", "render_treelet_fetch_bytes_total"):
-        assert value(after, series) > (value(before, series) or 0.0), series
+    grown = {}
+    for series in (
+        "render_walk_node_visits_total", "render_walk_leaf_tests_total", "render_treelet_fetches_total",
+        "render_treelet_fetch_bytes_total",
+    ):
+        grown[series] = value(after, series) - (value(before, series) or 0.0)
+        assert grown[series] > 0, series
+    # leaf tests are some of the steps, and a fetch is a whole slab
+    assert grown["render_walk_node_visits_total"] > grown["render_walk_leaf_tests_total"] > grown["render_treelet_fetches_total"]
+    assert grown["render_treelet_fetch_bytes_total"] == grown["render_treelet_fetches_total"] * (8 * 16 * 16 + 1024) * 4
     assert (tmp_path / "frames" / "rendered-000295.jpg").is_file()

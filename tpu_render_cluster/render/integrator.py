@@ -567,8 +567,10 @@ def _trace_paths_deep(
     if rng_lanes is not None:
         state["rng"] = jnp.asarray(rng_lanes, jnp.int32)
     if mesh.bvh.stream is not None:
-        # (node visits, treelet fetches) of each bounce's launch
-        state["walk"] = jnp.zeros((max_bounces, 2), jnp.int32)
+        # each bounce launch's counts
+        state["walk"] = jnp.zeros(
+            (max_bounces, len(pallas_kernels.WALK_COUNTS)), jnp.int32
+        )
     for bounce in range(max_bounces):
         # Every ray is live at the first bounce, and a one-rung ladder
         # leaves nothing to pick: no switch in the program.
@@ -631,8 +633,9 @@ def trace_paths(
     caller can return them from the same program: the frame's launch
     occupancy at no extra sync. Other paths launch no per-bounce
     kernel and leave the list empty. ``walk_counts`` (optional list)
-    collects the same launches' (node visits, treelet fetches) where the
-    mesh's BLAS is streamed from HBM (``mesh.bvh.stream``).
+    collects the same launches' walk counts
+    (``pallas_kernels.WALK_COUNTS``) where the mesh's BLAS is streamed
+    from HBM (``mesh.bvh.stream``).
     """
     from tpu_render_cluster.render import pallas_kernels
 
@@ -730,8 +733,9 @@ def render_tile(
     the int32 [max_bounces, 2] (live rays, width) of each per-bounce
     launch (trace_paths' ``live_counts``), or None where the scene's
     path launches no per-bounce kernel. ``with_walk`` (static, a scene
-    whose BLAS is streamed) appends ``walk``, the int32 [max_bounces, 2]
-    (node visits, treelet fetches) of the same launches.
+    whose BLAS is streamed) appends ``walk``, the int32
+    [max_bounces, len(pallas_kernels.WALK_COUNTS)] counts of the same
+    launches' walks.
     """
     n = tile_height * tile_width
     base_key = tile_base_key(frame, y0, x0)

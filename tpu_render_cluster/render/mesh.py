@@ -107,41 +107,46 @@ class MeshBVH(NamedTuple):
 class BlasStream(NamedTuple):
     """A BLAS cut into treelets, as the device arrays the bounce kernel
     reads from HBM (``pallas_kernels``: ``memory_space=pl.ANY``, staged
-    to VMEM/SMEM scratch by DMA when a packet enters a treelet).
+    to VMEM scratch by one DMA when a packet enters a treelet).
 
     The tree's top — every node that holds more leaves than one treelet,
     with the treelet roots as its leaves — stays resident in SMEM; below
     it treelet ``t`` is a subtree of at most ``treelet_leaves(stream)``
-    leaves with its own threaded node table (LOCAL skip links, the local
-    node count its terminator) and its leaves' triangles. Every static
-    size is read from a shape, so the pytree holds arrays only.
+    leaves, held as at most nine WIDE nodes of eight children in two
+    levels: a root whose children are the subtree's groups (its first
+    nodes of at most ``WIDE`` leaves, in the binary tree's preorder), and
+    a node a group whose children are the group's leaves. A wide node's
+    eight child boxes are tested in one ``[8, block]`` slab test, so a
+    leaf's box is tested in its group's step. Every static size is read
+    from a shape, so the pytree holds arrays only.
 
-    ``tri`` packs a triangle as 12 floats (v0, e1, e2, unit normal) in 16
-    lanes, eight leaves side by side across the 128 lanes and a leaf's 16
-    triangles down 16 rows: leaf ``l`` of a treelet is rows
-    ``16 * (l // 8) .. + 16``, lanes ``16 * (l % 8) .. + 12``. That is
-    64 B a triangle in HBM where a ``[T, 3]`` table is 512 B a triangle
-    a table on the chip (lanes padded to 128).
+    ``tri`` is one slab a treelet, what a fetch copies. Its first
+    ``16 * L / 8`` rows pack a triangle as 12 floats (v0, e1, e2, unit
+    normal) in 16 lanes, eight leaves side by side across the 128 lanes
+    and a leaf's 16 triangles down 16 rows: child ``c`` of group ``g`` is
+    leaf slot ``8g + c``, rows ``16g .. 16g + 16``, lanes
+    ``16c .. 16c + 12`` (a group is a row block, so the slot needs no
+    table; padding rows are zero and meet no ray). That is 64 B a
+    triangle in HBM where a ``[T, 3]`` table is 512 B a triangle a table
+    on the chip (lanes padded to 128). Its last ``WIDE`` rows are the
+    wide nodes' boxes: child ``c`` down the sublanes, and along the
+    lanes wide node ``w`` (0 the root, ``1 + g`` group ``g``) at lanes
+    ``8w .. 8w + 7``: lo xyz, hi xyz, then the child's bit ``1 << c`` as
+    a float (0 for an empty slot, whose box is inverted) and a spare.
     """
 
-    tri: jnp.ndarray  # [NT, 16 * L/8, 128] f32
-    # [NT * W] f32, W = treelet_node_words: node i of a treelet at words
-    # 8i..8i+7 of its slot: lo xyz, hi xyz, then skip | leaf << 8 |
-    # count << 16 as a float (whole numbers under 2**24 are exact) and a
-    # spare. One table, one copy a fetch; a 1-D array in HBM is tiled by
-    # 1024 words and a copy must start on a tile, so W is a multiple.
-    nodes: jnp.ndarray
+    tri: jnp.ndarray  # [NT, 16 * L/8 + WIDE, 128] f32
     top_bounds: jnp.ndarray  # [NTOP * 6] f32
     top_meta: jnp.ndarray  # [NTOP] int32: skip | (treelet + 1) << 16
     root: jnp.ndarray  # [2, 3] f32: the whole tree's bounds
 
 
+# Children of a wide node: the sublanes of one f32 vector register.
+WIDE = 8
+
+
 def treelet_leaves(stream: BlasStream) -> int:
-    return stream.tri.shape[1] // 2
-
-
-def treelet_node_words(stream: BlasStream) -> int:
-    return stream.nodes.shape[0] // stream.tri.shape[0]
+    return (stream.tri.shape[1] - WIDE) // 2
 
 
 def geometry_bytes(bvh: MeshBVH) -> dict[str, int]:
@@ -154,15 +159,15 @@ def geometry_bytes(bvh: MeshBVH) -> dict[str, int]:
         nodes = bvh.skip.shape[0] * 9 * 4 * (1 if bvh.octant is None else 8)
         return {"hbm": 0, "vmem": bvh.v0.shape[0] * 128 * 4 * 4, "smem": nodes}
     return {
-        "hbm": sum(int(a.size) * 4 for a in (stream.tri, stream.nodes)),
+        "hbm": int(stream.tri.size) * 4,
         "vmem": 0,
         "smem": sum(int(a.size) * 4 for a in (stream.top_bounds, stream.top_meta)),
     }
 
 
 def treelet_fetch_bytes(stream: BlasStream) -> int:
-    """Bytes one treelet fetch copies: its triangle rows and node table."""
-    return (stream.tri.shape[1] * 128 + treelet_node_words(stream)) * 4
+    """Bytes one treelet fetch copies: its triangle rows and wide nodes."""
+    return stream.tri.shape[1] * 128 * 4
 
 
 # ---------------------------------------------------------------------------
@@ -701,7 +706,7 @@ def _build_bvh_morton(vertices: np.ndarray, faces: np.ndarray) -> dict:
 
 
 # What one treelet may hold, in leaves: 64 leaves are 1,024 triangles,
-# 64 KiB of triangle rows and 3.5 KiB of node table a fetch.
+# 64 KiB of triangle rows and 4 KiB of wide nodes a fetch.
 TREELET_LEAVES = 64
 # A BLAS stays resident (today's kernels: triangle tables whole in VMEM,
 # node tables in SMEM) while its tables fit this share of the 16 MiB of
@@ -716,25 +721,35 @@ def resident_table_bytes(n_triangle_rows: int, n_nodes: int) -> int:
     return n_triangle_rows * 128 * 4 * 4 + n_nodes * 9 * 4
 
 
+def _first_fitting(lo: np.ndarray, hi: np.ndarray, most: int) -> np.ndarray:
+    """The nodes of a preorder tree that hold at most ``most`` leaves and
+    whose parent holds more. In preorder a node's parent is the nearest
+    earlier node whose range holds it, so a fitting node is the first on
+    its path iff it is not inside the preorder span of an earlier fitting
+    node."""
+    index = np.arange(lo.shape[0])
+    fits = hi - lo <= most
+    span_end = np.where(fits, index + 2 * (hi - lo) - 1, 0)
+    covered_until = np.maximum.accumulate(np.concatenate([[0], span_end[:-1]]))
+    return fits & (index >= covered_until)
+
+
 def partition_treelets(tree: dict, max_leaves: int = TREELET_LEAVES) -> dict:
     """Cut a ``_build_bvh_morton`` tree into treelets of at most
-    ``max_leaves`` leaves (a multiple of 8, at most 128: a byte budget of
+    ``max_leaves`` leaves (8, 16, 32 or 64: a byte budget of
     ``max_leaves`` KiB of triangle rows): host arrays under
     ``BlasStream``'s field names. A treelet root is a node that fits the
-    budget whose parent does not; what lies above is the resident top."""
-    if max_leaves % 8 or not 8 <= max_leaves <= 128:
-        raise ValueError(f"treelet size {max_leaves}: want a multiple of 8 in 8..128")
+    budget whose parent does not; what lies above is the resident top.
+    Inside a treelet the same rule at ``WIDE`` leaves finds its groups:
+    the balanced tree has at most ``max_leaves / WIDE`` of them, which
+    become the children of the treelet's wide root, and a group's leaves
+    the children of its wide node."""
+    if max_leaves not in (8, 16, 32, 64):
+        raise ValueError(f"treelet size {max_leaves}: want 8, 16, 32 or 64")
     lo, hi = tree["leaf_lo"], tree["leaf_hi"]
-    n_nodes = lo.shape[0]
-    fits = hi - lo <= max_leaves
-    # In preorder a node's parent is the nearest earlier node whose range
-    # holds it; a node is a treelet root iff it fits and is the first
-    # fitting node on its path — iff no fitting ancestor, iff it is not
-    # inside the preorder span of an earlier fitting node.
-    span_end = np.where(fits, np.arange(n_nodes) + 2 * (hi - lo) - 1, 0)
-    covered_until = np.maximum.accumulate(np.concatenate([[0], span_end[:-1]]))
-    is_root = fits & (np.arange(n_nodes) >= covered_until)
-    in_top = ~fits | is_root
+    is_root = _first_fitting(lo, hi, max_leaves)
+    is_group = _first_fitting(lo, hi, WIDE)
+    in_top = (hi - lo > max_leaves) | is_root
     n_treelets = int(is_root.sum())
     if n_treelets + 1 >= 1 << 15 or int(in_top.sum()) >= 1 << 16:
         raise ValueError("the resident top outgrows its 16-bit links")
@@ -749,34 +764,42 @@ def partition_treelets(tree: dict, max_leaves: int = TREELET_LEAVES) -> dict:
         [tree["bounds_min"][tops], tree["bounds_max"][tops]], axis=1
     ).reshape(-1).astype(np.float32)
 
-    words = -(-(2 * max_leaves * 8) // 1024) * 1024  # 2L - 1 nodes of 8
-    roots = np.flatnonzero(is_root)
-    inside = fits  # a node that fits is a root or under one
-    t_in = treelet[inside]
-    l_in = np.flatnonzero(inside) - roots[t_in]
-    nodes = np.zeros((n_treelets, words // 8, 8), np.float32)
-    nodes[t_in, l_in, 0:3] = tree["bounds_min"][inside]
-    nodes[t_in, l_in, 3:6] = tree["bounds_max"][inside]
-    local_skip = tree["skip"][inside] - roots[t_in]
-    local_leaf = np.where(tree["count"][inside] > 0, lo[inside] - lo[roots[t_in]], 0)
-    nodes[t_in, l_in, 6] = (
-        local_skip | local_leaf << 8 | tree["count"][inside].astype(np.int64) << 16
-    )
-    # Triangle rows: [treelet, leaf, triangle, 16 lanes] -> leaves eight
-    # abreast across the 128 lanes.
+    # Wide nodes. Groups and leaves come in preorder, which is left to
+    # right: a group's place under its root and a leaf's under its group
+    # are the binary walk's order of meeting them.
+    groups = np.flatnonzero(is_group)
+    group_treelet = treelet[groups]
+    group_child = np.arange(groups.shape[0]) - np.searchsorted(group_treelet, group_treelet)
+    leaves = np.flatnonzero(tree["count"] > 0)
+    leaf_group = (np.cumsum(is_group) - 1)[leaves]
+    leaf_child = lo[leaves] - lo[groups[leaf_group]]
+    assert group_child.max() < max_leaves // WIDE and leaf_child.max() < WIDE
+    # [treelet, child, wide node, 8 words]: an empty slot's box is
+    # inverted and its bit 0, so no mask holds it.
+    wide = np.zeros((n_treelets, WIDE, 128 // 8, 8), np.float32)
+    wide[..., 0:3], wide[..., 3:6] = INF, -INF
+    for treelets, child, node, members in (
+        (group_treelet, group_child, 0, groups),
+        (treelet[leaves], leaf_child, 1 + group_child[leaf_group], leaves),
+    ):
+        wide[treelets, child, node, 0:3] = tree["bounds_min"][members]
+        wide[treelets, child, node, 3:6] = tree["bounds_max"][members]
+        wide[treelets, child, node, 6] = 1 << child
+    # Triangle rows: [treelet, leaf slot, triangle, 16 lanes] -> leaves
+    # eight abreast across the 128 lanes; a group is a block of 16 rows.
     n_leaves = int(hi[0])
     record = np.zeros((n_leaves * LEAF_SIZE, 16), np.float32)
     for column, name in enumerate(("v0", "e1", "e2", "normal")):
         record[:, 3 * column:3 * column + 3] = tree[name]
     record = record.reshape(n_leaves, LEAF_SIZE, 16)
-    leaf_nodes = np.flatnonzero(tree["count"] > 0)
     slots = np.zeros((n_treelets, max_leaves, LEAF_SIZE, 16), np.float32)
-    slots[treelet[leaf_nodes], lo[leaf_nodes] - lo[roots[treelet[leaf_nodes]]]] = record[lo[leaf_nodes]]
+    slots[treelet[leaves], WIDE * group_child[leaf_group] + leaf_child] = record[lo[leaves]]
     tri = slots.reshape(n_treelets, max_leaves // 8, 8, LEAF_SIZE, 16).transpose(
         0, 1, 3, 2, 4
     ).reshape(n_treelets, max_leaves * 2, 128)
     return dict(
-        tri=tri, nodes=nodes.reshape(-1), top_bounds=top_bounds, top_meta=top_meta,
+        tri=np.concatenate([tri, wide.reshape(n_treelets, WIDE, 128)], axis=1),
+        top_bounds=top_bounds, top_meta=top_meta,
         root=np.stack([tree["bounds_min"][0], tree["bounds_max"][0]]),
     )
 

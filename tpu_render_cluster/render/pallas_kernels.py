@@ -846,6 +846,14 @@ def trace_paths_fused(
 # construction).
 
 BVH_DONE_EPS = 1e-12
+# What a launch over a streamed BLAS counts, in the order it returns them.
+# A node visit is a step a packet paid: a box test (one node of the
+# resident top, or a wide node's eight children at once) or a leaf's
+# triangles; the last two split the box tests inside treelets.
+WALK_COUNTS = (
+    "node_visits", "treelet_fetches", "leaf_tests", "treelet_entries",
+    "group_tests",
+)
 # Mesh-megakernel dispatch bound: use the fused whole-bounce-loop kernel
 # when bvh_nodes x instances is at most this; deeper walks pay more for
 # the in-kernel normal tracking than the fusion saves (see
@@ -1703,6 +1711,39 @@ def _bvh_anyhit_instanced(
 # diverging.
 
 
+def slab_mask(node, ox, oy, oz, invx, invy, invz, limit):
+    """The mesh kernels' packet test of a box (``slab_any``) on a wide
+    node's eight children at once. ``node`` is the node's ``[8, >= 7]``
+    block of a staged treelet (``mesh.BlasStream``): a child a sublane;
+    lo xyz, hi xyz and the child's bit ``1 << c`` along the lanes. The
+    rays' components are ``[1, block]`` rows (or scalars). The same
+    arithmetic on the same float32 boxes as eight ``slab_any``s, in one
+    ``[8, block]`` tile; the children some lane's ray meets ahead of it
+    and nearer than its ``limit`` come back as ONE scalar mask, so the
+    walk waits for the scalar side once a node, not once a box. The test
+    is symmetric in lo and hi, so an empty slot's inverted box would pass
+    it: its bit is 0."""
+    lox = (node[:, 0:1] - ox) * invx
+    hix = (node[:, 3:4] - ox) * invx
+    loy = (node[:, 1:2] - oy) * invy
+    hiy = (node[:, 4:5] - oy) * invy
+    loz = (node[:, 2:3] - oz) * invz
+    hiz = (node[:, 5:6] - oz) * invz
+    tnear = jnp.maximum(
+        jnp.maximum(jnp.minimum(lox, hix), jnp.minimum(loy, hiy)),
+        jnp.minimum(loz, hiz),
+    )
+    tfar = jnp.minimum(
+        jnp.minimum(jnp.maximum(lox, hix), jnp.maximum(loy, hiy)),
+        jnp.maximum(loz, hiz),
+    )
+    packet_hit = (tfar >= jnp.maximum(tnear, 0.0)) & (tnear < limit)
+    child_hit = jnp.max(
+        jnp.where(packet_hit, 1.0, 0.0), axis=1, keepdims=True
+    )
+    return jnp.sum(child_hit * node[:, 6:7]).astype(jnp.int32)
+
+
 def _mesh_trace_kernel_factory(
     max_bounces: int, n_padded: int, n_nodes: int, leaf_size: int,
     k_count: int, state_io: bool = False, use_tlas: bool = False,
@@ -1721,15 +1762,19 @@ def _mesh_trace_kernel_factory(
       scenes. ``max_bounces`` still names the TOTAL bounce count so the
       per-(ray, bounce) RNG counters match the megakernel's stream layout.
 
-    ``stream`` = (nodes of the tree's top, words of a treelet's node
-    table) makes the BLAS operands
-    HBM tables (``mesh.BlasStream``) in place of the resident triangle and
-    node blocks: the walk runs over the resident top of the tree and,
-    where a packet enters a treelet, copies that treelet's triangle rows
-    and node table into scratch and walks inside it (``stream_walk``).
-    The node sequence is the resident walk's over the same tree, and the
-    arithmetic per node and per leaf is the same code. One more output
-    row carries each block's node visits and treelet fetches.
+    ``stream`` = (nodes of the tree's top, leaf slots of a treelet) makes
+    the BLAS operands HBM tables (``mesh.BlasStream``) in place of the
+    resident triangle and node blocks: the walk runs over the resident top
+    of the tree and, where a packet enters a treelet, copies that
+    treelet's slab (triangle rows and wide nodes) into scratch and walks
+    inside it (``stream_walk``): a wide node's eight child boxes in one
+    ``[8, block]`` test (``slab_mask``), a leaf's box in its group's. The
+    leaves come in the resident walk's order over the same tree, the
+    boxes, the triangles and the arithmetic on them are the same, and a
+    wide test culls with the best-t it had before its children ran: it
+    meets the resident walk's leaves and some it would have culled, which
+    change nothing. One more output row carries each block's counts
+    (``WALK_COUNTS``).
     """
     contract_first = (((0,), (0,)), ((), ()))
     if stream is not None and not state_io:
@@ -1760,9 +1805,9 @@ def _mesh_trace_kernel_factory(
         if stream is not None:
             # The BLAS in HBM and its resident top; scratch comes last:
             # the staged treelet and which one it is.
-            (tri_hbm, nodes_hbm, topb_ref, topm_ref) = take(4)
-            (tri_buf, nodes_buf, staged_ref, dma_sem) = refs[-4:]
-            del refs[-4:]
+            (tri_hbm, topb_ref, topm_ref) = take(3)
+            (tri_buf, staged_ref, dma_sem) = refs[-3:]
+            del refs[-3:]
         else:
             (v0_ref, e1_ref, e2_ref, nrm_ref) = take(4)
             if quant:
@@ -2064,14 +2109,12 @@ def _mesh_trace_kernel_factory(
             return tri_hit, t_cand
 
         if stream is not None:
-            top_nodes, node_words = stream
+            top_nodes, leaf_slots = stream
+            no_counts = (jnp.int32(0),) * len(WALK_COUNTS)
 
             def six(ref, node, stride):
                 base = node * stride
                 return tuple(ref[base + i] for i in range(6))
-
-            def staged_meta(node):
-                return nodes_buf[node * 8 + 6].astype(jnp.int32)
 
             def stage_treelet(treelet):
                 """Bring ``treelet`` into scratch unless it is the one
@@ -2080,26 +2123,11 @@ def _mesh_trace_kernel_factory(
 
                 @pl.when(fetch)
                 def _():
-                    copies = (
-                        pltpu.make_async_copy(
-                            tri_hbm.at[treelet], tri_buf, dma_sem.at[0]
-                        ),
-                        pltpu.make_async_copy(
-                            nodes_hbm.at[
-                                pl.ds(
-                                    pl.multiple_of(
-                                        treelet * node_words, node_words
-                                    ),
-                                    node_words,
-                                )
-                            ],
-                            nodes_buf, dma_sem.at[1],
-                        ),
+                    copy = pltpu.make_async_copy(
+                        tri_hbm.at[treelet], tri_buf, dma_sem.at[0]
                     )
-                    for copy in copies:
-                        copy.start()
-                    for copy in copies:
-                        copy.wait()
+                    copy.start()
+                    copy.wait()
                     staged_ref[0] = treelet
 
                 return fetch.astype(jnp.int32)
@@ -2121,47 +2149,73 @@ def _mesh_trace_kernel_factory(
                 tables: the resident top, and under a top leaf the
                 treelet it names, staged and walked in place. ``carry`` is
                 the walk's tuple of [1, BR] rows, ``limit_of(carry)`` the
-                per-lane cull distance, ``on_leaf(carry, rows, count)``
-                the leaf's update; ``stats`` = (node visits, treelet
-                fetches) so far. Returns (carry, stats)."""
+                per-lane cull distance, ``on_leaf(carry, rows)`` the update
+                by a leaf's 16 staged rows; ``stats`` = the walk's counts so far
+                (``WALK_COUNTS``). Returns (carry, stats)."""
                 width = len(carry)
 
+                def children(mask, step, state):
+                    """``step(child, state)`` for each set bit of ``mask``,
+                    lowest first: a turn a child met, none for the others,
+                    and scalar arithmetic alone between turns (the bit's
+                    index by halving: no vector is waited for)."""
+                    def turn(walk):
+                        mask = walk[0]
+                        low = mask & -mask
+                        child = (
+                            jnp.where((low & 0xAA) != 0, 1, 0)
+                            + jnp.where((low & 0xCC) != 0, 2, 0)
+                            + jnp.where((low & 0xF0) != 0, 4, 0)
+                        )
+                        return (mask ^ low, *step(child, tuple(walk[1:])))
+
+                    return jax.lax.while_loop(
+                        lambda walk: walk[0] != 0, turn, (mask, *state)
+                    )[1:]
+
                 def inside(treelet, carry, stats):
-                    fetched = stage_treelet(treelet)
-                    root = staged_meta(0)
-                    local_nodes = root & 0xFF
-                    # The root's box is the top leaf's, tested already;
-                    # a one-leaf treelet's root is the leaf itself.
-                    node0 = jnp.where((root >> 16) > 0, 0, 1)
+                    """The treelet's two levels of wide nodes: the root's
+                    children are its groups, a group's its leaves, both
+                    in the binary tree's order. The root's own box is
+                    the top leaf's, tested already. A leaf's rows are
+                    tested whole: its padding rows are zero and meet no
+                    ray."""
+                    visits, fetches, leaf_tests, entries, group_tests = stats
+                    fetches = fetches + stage_treelet(treelet)
+                    nodes = tri_buf[2 * leaf_slots:2 * leaf_slots + 8, :]
 
-                    def body(walk):
-                        node, visits = walk[0], walk[-1]
-                        carry = tuple(walk[1:-1])
-                        meta = staged_meta(node)
-                        count = meta >> 16
-                        hit_any = slab_any(
-                            six(nodes_buf, node, 8), ox, oy, oz, invx, invy,
-                            invz, limit_of(carry),
+                    def met(node, carry):
+                        return slab_mask(
+                            node, ox, oy, oz, invx, invy, invz,
+                            limit_of(carry),
                         )
-                        is_leaf = count > 0
-                        carry = jax.lax.cond(
-                            is_leaf & hit_any,
-                            lambda: on_leaf(
-                                carry, staged_leaf((meta >> 8) & 0xFF), count
+
+                    # state: the carry, then leaves and groups tested
+                    def in_leaf(group, child, state):
+                        carry = on_leaf(
+                            state[:width], staged_leaf(group * 8 + child)
+                        )
+                        return (*carry, state[-2] + 1, state[-1])
+
+                    def in_group(group, state):
+                        return children(
+                            met(
+                                pltpu.roll(nodes, 120 - group * 8, 1),
+                                state[:width],
                             ),
-                            lambda: carry,
+                            functools.partial(in_leaf, group),
+                            (*state[:-1], state[-1] + 1),
                         )
-                        next_node = jnp.where(
-                            hit_any & jnp.logical_not(is_leaf),
-                            node + 1, meta & 0xFF,
-                        )
-                        return (next_node, *carry, visits + 1)
 
-                    walk = jax.lax.while_loop(
-                        lambda walk: walk[0] < local_nodes, body,
-                        (node0, *carry, stats[0]),
+                    *carry, leaves, groups = children(
+                        met(nodes, carry), in_group,
+                        (*carry, jnp.int32(0), jnp.int32(0)),
                     )
-                    return tuple(walk[1:-1]), (walk[-1], stats[1] + fetched)
+                    return tuple(carry), (
+                        visits + 1 + groups + leaves, fetches,
+                        leaf_tests + leaves, entries + 1,
+                        group_tests + groups,
+                    )
 
                 def top_body(walk):
                     node = walk[0]
@@ -2183,7 +2237,7 @@ def _mesh_trace_kernel_factory(
                         hit_any & jnp.logical_not(is_leaf),
                         node + 1, meta & 0xFFFF,
                     )
-                    return (next_node, *carry, stats[0] + 1, stats[1])
+                    return (next_node, *carry, stats[0] + 1, *stats[1:])
 
                 node0 = jnp.where(touch, jnp.int32(0), jnp.int32(top_nodes))
                 walk = jax.lax.while_loop(
@@ -2253,12 +2307,12 @@ def _mesh_trace_kernel_factory(
                 dz = (wdx * r02 + wdy * r12 + wdz * r22) * inv_s
                 invx, invy, invz = winv(dx), winv(dy), winv(dz)
                 if stream is not None:
-                    def on_leaf(carry, rows, count):
+                    def on_leaf(carry, rows):
                         (best_t, bnx, bny, bnz, bar_, bag_, bab_,
                          bslot_) = carry
                         _tri_hit, t_cand = triangle_tcand(
-                            rows[:, 0:3], rows[:, 3:6], rows[:, 6:9], count,
-                            ox, oy, oz, dx, dy, dz,
+                            rows[:, 0:3], rows[:, 3:6], rows[:, 6:9],
+                            leaf_size, ox, oy, oz, dx, dy, dz,
                         )
                         t_leaf = jnp.min(t_cand, axis=0, keepdims=True)
                         local = jnp.min(
@@ -2386,7 +2440,7 @@ def _mesh_trace_kernel_factory(
                 jnp.full((1, block), slot_sentinel, jnp.float32),
             )
             if stream is not None:
-                init = (*init, jnp.int32(0), jnp.int32(0))
+                init = (*init, *no_counts)
             if use_tlas:
                 # Two-level walk: threaded skip-link TLAS over instance
                 # groups; a leaf hit runs the EXISTING per-instance BLAS
@@ -2455,10 +2509,10 @@ def _mesh_trace_kernel_factory(
                 dz = (sunx * r02 + suny * r12 + sunz * r22) * inv_s
                 invx, invy, invz = winv(dx), winv(dy), winv(dz)
                 if stream is not None:
-                    def on_leaf(carry, rows, count):
+                    def on_leaf(carry, rows):
                         tri_hit, _ = triangle_tcand(
-                            rows[:, 0:3], rows[:, 3:6], rows[:, 6:9], count,
-                            ox, oy, oz, dx, dy, dz,
+                            rows[:, 0:3], rows[:, 3:6], rows[:, 6:9],
+                            leaf_size, ox, oy, oz, dx, dy, dz,
                         )
                         return (jnp.maximum(carry[0], jnp.max(
                             jnp.where(tri_hit, 1.0, 0.0), axis=0,
@@ -2735,7 +2789,7 @@ def _mesh_trace_kernel_factory(
                 def _():
                     staged_ref[0] = jnp.int32(-1)
 
-            no_stats = () if stream is None else (jnp.int32(0), jnp.int32(0))
+            no_stats = () if stream is None else no_counts
             o, d, throughput, radiance, alive, hit_slot, *stats = jax.lax.cond(
                 block_start < live_ref[0, 0],
                 lambda: bounce_step(
@@ -2748,12 +2802,12 @@ def _mesh_trace_kernel_factory(
                 ),
             )
             if stream is not None:
-                # lane 0: node visits, lane 1: treelet fetches
+                # count i of WALK_COUNTS in lane i
                 lane_id = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-                stats_ref[:, :] = jnp.where(
-                    lane_id == 0, stats[0],
-                    jnp.where(lane_id == 1, stats[1], 0),
-                )
+                row = jnp.zeros((1, block), jnp.int32)
+                for index, count in enumerate(stats):
+                    row = jnp.where(lane_id == index, count, row)
+                stats_ref[:, :] = row
             out_ref[:, :] = radiance
             o_out_ref[:, :] = o
             d_out_ref[:, :] = d
@@ -3227,29 +3281,24 @@ def _mesh_bounce_io(
         (1, block), lambda i: (0, i), memory_space=pltpu.VMEM
     )
     if stream is not None:
-        from tpu_render_cluster.render.mesh import treelet_node_words
+        from tpu_render_cluster.render.mesh import treelet_leaves
 
-        # The BLAS stays in HBM: the kernel copies a treelet's rows and
-        # node table into this scratch when a packet enters it. Only the
-        # tree's top sits in SMEM for the whole launch.
-        node_words = treelet_node_words(stream)
+        # The BLAS stays in HBM: the kernel copies a treelet's slab
+        # (triangle rows, wide nodes) into this scratch when a packet
+        # enters it. Only the tree's top sits in SMEM for the whole launch.
         ordered = False
-        geometry_operands = (
-            stream.tri, stream.nodes, stream.top_bounds, stream.top_meta,
-        )
+        geometry_operands = (stream.tri, stream.top_bounds, stream.top_meta)
         geometry_specs = [
-            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(stream.top_bounds.shape, flat, memory_space=pltpu.SMEM),
             pl.BlockSpec(stream.top_meta.shape, flat, memory_space=pltpu.SMEM),
         ]
         scratch_shapes = [
             pltpu.VMEM(stream.tri.shape[1:], jnp.float32),
-            pltpu.SMEM((node_words,), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
         ]
-        stream_shape = (int(stream.top_meta.shape[0]), node_words)
+        stream_shape = (int(stream.top_meta.shape[0]), treelet_leaves(stream))
         stats_specs = [row_block]
         stats_shapes = [jax.ShapeDtypeStruct((1, padded_rays), jnp.int32)]
         kernel_name = "mesh_bounce_streamed"
@@ -3339,8 +3388,8 @@ def _mesh_bounce_io(
     )
     if stream is None:
         return state
-    # every block's (node visits, treelet fetches), summed over the launch
-    walk = results[-1].reshape(-1, block)[:, :2].sum(axis=0)
+    # every block's counts, summed over the launch
+    walk = results[-1].reshape(-1, block)[:, :len(WALK_COUNTS)].sum(axis=0)
     return (*state, walk)
 
 
@@ -3367,7 +3416,7 @@ def mesh_bounce_pallas(
     [R, 3], new origins, new directions, new throughput, new alive,
     key [R] int32 — None on the flat variant). A mesh whose BLAS is
     streamed (``mesh.bvh.stream``) also returns the launch's walk counts,
-    int32 [2]: node visits and treelet fetches.
+    int32 ``[len(WALK_COUNTS)]``.
     """
     n = origins.shape[0]
     if lane is None:

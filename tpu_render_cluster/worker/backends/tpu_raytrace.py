@@ -226,8 +226,10 @@ class TpuRaytraceBackend(RenderBackend):
     @staticmethod
     def _observe_walk(walk, scene_name: str) -> None:
         """The walk of a frame whose BLAS is streamed from HBM:
-        ``walk[b]`` = bounce b's launch's (node visits, treelet fetches),
-        counted by the kernel and returned by the frame's program."""
+        ``walk[b]`` = bounce b's launch's counts in the order of
+        ``pallas_kernels.WALK_COUNTS`` (node visits, treelet fetches, leaf
+        tests first), counted by the kernel and returned by the frame's
+        program."""
         from tpu_render_cluster.obs import get_registry
         from tpu_render_cluster.render.integrator import resolve_bvh_config
         from tpu_render_cluster.render.mesh import scene_blas_stream, treelet_fetch_bytes
@@ -240,16 +242,21 @@ class TpuRaytraceBackend(RenderBackend):
         fetches = float(walk[:, 1].sum())
         registry.counter(
             "render_walk_node_visits_total",
-            "BLAS nodes visited by the bounce launches' packets (a visit "
-            "serves a whole block of rays)",
+            "Steps the bounce launches' packets paid in the BLAS: box tests "
+            "(a node of the top, or a wide node's eight children at once) "
+            "and leaves' triangle tests (a step serves a whole block of rays)",
         ).inc(float(walk[:, 0].sum()))
+        registry.counter(
+            "render_walk_leaf_tests_total",
+            "Of those steps, the leaves whose triangles were tested",
+        ).inc(float(walk[:, 2].sum()))
         registry.counter(
             "render_treelet_fetches_total",
             "Treelets copied from HBM into a bounce kernel's scratch",
         ).inc(fetches)
         registry.counter(
             "render_treelet_fetch_bytes_total",
-            "Bytes of treelet rows and node tables copied from HBM into a "
+            "Bytes of treelet rows and wide nodes copied from HBM into a "
             "bounce kernel's scratch",
         ).inc(fetches * fetch_bytes)
 
