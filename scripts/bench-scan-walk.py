@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
-"""On-chip microbenchmark of the streamed BLAS walk (03_physics-2-scan).
+"""On-chip microbenchmark of the streamed BLAS walk: a scene family whose
+BLAS is streamed, 03_physics-2-scan unless the family is named
+(03_physics-2-assets: three BLASes in one table).
 
-    chiprun -- python scripts/bench-scan-walk.py [samples ...]
+    chiprun -- python scripts/bench-scan-walk.py [family] [samples ...]
     JAX_PLATFORMS=cpu TRC_PALLAS=1 python scripts/bench-scan-walk.py --rehearse 1
 
 For each ``samples`` (default 8 and 1): the frame program's compile and
@@ -38,10 +40,11 @@ import numpy as np  # noqa: E402
 
 from tpu_render_cluster.render import integrator, mesh as mesh_module, pallas_kernels  # noqa: E402
 from tpu_render_cluster.render.camera import scene_camera  # noqa: E402
-from tpu_render_cluster.render.scene import build_scene  # noqa: E402
+from tpu_render_cluster.render.scene import SCENE_NAMES, build_scene, mesh_kind_for_scene  # noqa: E402
 from tpu_render_cluster.utils.accelerator import configure_compile_cache  # noqa: E402
 
-SCENE, BOUNCES, FRAME = "03_physics-2-scan", 4, 295
+SCENE = next((a for a in sys.argv[1:] if a in SCENE_NAMES), "03_physics-2-scan")
+BOUNCES, FRAME = 4, 295
 FRAMES = (304, 320, FRAME)  # two the benchmark's check may draw, and the bounces' frame last
 # the counts of a tree from before ISSUE 33, so one script reads both sides
 WALK_COUNTS = getattr(pallas_kernels, "WALK_COUNTS", ("node_visits", "treelet_fetches"))
@@ -98,13 +101,17 @@ def main(argv: list[str]) -> int:
     if device.platform != "tpu" and not REHEARSE:
         print(f"bench-scan-walk: a {device.platform} is not a TPU: nothing timed (--rehearse walks through it)", file=sys.stderr)
         return 2
-    start = time.perf_counter()
+    start, models = time.perf_counter(), []
+    bvh = mesh_module.cached_mesh_bvh(
+        mesh_kind_for_scene(SCENE), built=lambda model, triangles, _began, seconds: models.append((model, triangles, seconds))
+    )
     stream = mesh_module.scene_blas_stream(SCENE)
     jax.block_until_ready(stream)
-    say("bvh_build", seconds=time.perf_counter() - start, bytes=mesh_module.geometry_bytes(mesh_module.cached_mesh_bvh("scan")),
+    say("bvh_build", scene=SCENE, seconds=time.perf_counter() - start,
+        bytes=mesh_module.geometry_bytes(bvh), models=models,
         memory=device.memory_stats() and device.memory_stats().get("bytes_in_use"))
 
-    for samples in [int(a) for a in argv if a != "--rehearse"] or [8, 1]:
+    for samples in [int(a) for a in argv if a.isdigit()] or [8, 1]:
         start = time.perf_counter()
         render = integrator.fused_frame_renderer(SCENE, SIZE, SIZE, samples, BOUNCES, with_live=True)
         out = render(jnp.float32(FRAME))

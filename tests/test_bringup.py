@@ -37,7 +37,7 @@ WIDTH = HEIGHT = 512
 SAMPLES, BOUNCES = 8, 4
 
 
-def _run(code: str, **env_overrides) -> subprocess.CompletedProcess:
+def _run(code: str, *arguments: str, **env_overrides) -> subprocess.CompletedProcess:
     env = {**os.environ, "PYTHONPATH": str(REPO_ROOT)}
     for key, value in env_overrides.items():
         if value is None:
@@ -45,7 +45,7 @@ def _run(code: str, **env_overrides) -> subprocess.CompletedProcess:
         else:
             env[key] = value
     return subprocess.run(
-        [sys.executable, "-c", code], env=env, capture_output=True,
+        [sys.executable, "-c", code, *arguments], env=env, capture_output=True,
         text=True, timeout=120, cwd=REPO_ROOT,
     )
 
@@ -341,16 +341,18 @@ def spec(shape, dtype):
 def tree(value):
     return jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), value)
 
-# The configuration's BLAS by its shapes alone (871,200 triangles: 1,024
-# treelets of 64 leaf slots under a top of 2,047 nodes); nothing is built.
-treelets, leaves, top = 1024, mesh_module.TREELET_LEAVES, 2047
+# The configuration's BLASes by their shapes alone; nothing is built:
+# models, treelets of 64 leaf slots, nodes of resident top.
+family = sys.argv[1]
+models, treelets, top = (int(word) for word in sys.argv[2:5])
+leaves = mesh_module.TREELET_LEAVES
 stream = mesh_module.BlasStream(
     tri=spec((treelets, 2 * leaves + mesh_module.WIDE, 128), jnp.float32),
     top_bounds=spec((top * 6,), jnp.float32), top_meta=spec((top,), jnp.int32),
-    root=spec((2, 3), jnp.float32),
+    root=spec((models, 2, 3), jnp.float32), top_first=spec((models + 1,), jnp.int32),
 )
-scene = tree(build_scene("03_physics-2-scan", 1))
-instances = tree(build_mesh_instances("03_physics-2-scan", 1))
+scene = tree(build_scene(family, 1))
+instances = tree(build_mesh_instances(family, 1))
 i32 = spec((), jnp.int32)
 
 def bounce(scene, stream, instances, origins, directions, throughput, alive, lane, live):
@@ -372,15 +374,28 @@ for width in (widths[0], widths[-1]):
 """
 
 
-def test_the_streamed_bounce_kernel_compiles_with_mosaic(tmp_path):
+@pytest.mark.parametrize("family, models, treelets, top", [
+    # 871,200 triangles: 1,024 treelets under a top of 2,047 nodes
+    pytest.param("03_physics-2-scan", 1, 1024, 2047, id="03_physics-2-scan"),
+    # three models, 1,286,504 triangles: 1,664 treelets, 3,325 nodes (93 KB of SMEM)
+    pytest.param("03_physics-2-assets", 3, 1664, 3325, id="03_physics-2-assets"),
+    # the program is general in the number of models: a table of five
+    # (with the happy buddha and the Asian dragon, 9,592,842 triangles;
+    # rendered on the chip in PR 34 and cut for the benchmark run's time
+    # limit): 11,904 treelets, 23,803 nodes, two thirds of SMEM
+    pytest.param("03_physics-2-assets", 5, 11904, 23803, id="03_physics-2-assets-five-models"),
+])
+def test_the_streamed_bounce_kernel_compiles_with_mosaic(tmp_path, family, models, treelets, top):
     """The bounce kernel over a BLAS in HBM (ISSUE 32: a copy from HBM into
     VMEM scratch, a roll by a traced amount; ISSUE 33: eight boxes down
     the sublanes against a row of rays, their hits reduced to one scalar
-    mask), at the configuration's table
-    shapes and the widest and narrowest rung of a 1 spp frame, through the
-    real compiler. A subprocess: it loads libtpu."""
+    mask; ISSUE 34: a walk that begins and ends at nodes read from the
+    instance table, over the resident tops of several models), at the
+    configurations' table shapes and the widest and narrowest rung of a
+    1 spp frame, through the real compiler. A subprocess: it loads libtpu."""
     result = _run(
-        _COMPILE_STREAMED_BOUNCE, TRC_PALLAS="1", TPU_LOG_DIR=str(tmp_path)
+        _COMPILE_STREAMED_BOUNCE, family, str(models), str(treelets), str(top),
+        TRC_PALLAS="1", TPU_LOG_DIR=str(tmp_path),
     )
     if "NO_TOPOLOGY" in result.stdout:
         pytest.skip(result.stdout.strip())

@@ -37,10 +37,14 @@ def test_camera_rays_unit_norm():
     np.testing.assert_allclose(norms, 1.0, atol=1e-5)
 
 
-# The scan family's BLAS is streamed from HBM by the Pallas bounce kernel
-# alone and its mesh takes seconds to build: tests/test_scan_stream.py renders
-# it, over a small mesh.
-@pytest.mark.parametrize("scene_name", [name for name in SCENE_NAMES if name != "03_physics-2-scan"])
+# The scan and assets families' BLASes are streamed from HBM by the Pallas
+# bounce kernel alone and their meshes take seconds to build:
+# tests/test_scan_stream.py and tests/test_asset_set.py render them, over
+# small meshes.
+STREAMED = ("03_physics-2-scan", "03_physics-2-assets")
+
+
+@pytest.mark.parametrize("scene_name", [name for name in SCENE_NAMES if name not in STREAMED])
 def test_render_all_scenes(scene_name):
     image = np.asarray(tonemap(render_frame(scene_name, 5, **SMALL)))
     assert image.shape == (64, 64, 3)

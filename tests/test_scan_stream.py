@@ -211,7 +211,8 @@ def test_the_treelets_partition_the_tree(treelet_leaves):
     top_skip, top_treelet = top_meta & 0xFFFF, (top_meta >> 16) - 1
     assert (top_skip > np.arange(len(top_meta))).all() and top_skip[0] == len(top_meta)
     assert sorted(top_treelet[top_treelet >= 0]) == list(range(n_treelets))
-    np.testing.assert_array_equal(np.asarray(stream.root), [np.asarray(bvh.bounds_min)[0], np.asarray(bvh.bounds_max)[0]])
+    np.testing.assert_array_equal(np.asarray(stream.root)[0], [np.asarray(bvh.bounds_min)[0], np.asarray(bvh.bounds_max)[0]])
+    np.testing.assert_array_equal(np.asarray(stream.top_first), [0, len(top_meta)])  # one model: its whole top
 
 
 def one_box_at_a_time(boxes, origins, directions, limit):
@@ -593,7 +594,8 @@ def test_the_scan_scenes_program_streams_every_bounce(small_scan_family):
     assert calls and all("mesh_bounce_streamed" in str(call.params) for call in calls)
     # the BLAS is an argument of the jitted program: HBM tables, not constants
     inner = [e for e in jaxpr.jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
-    assert inner and len(inner[-1].invars) == 1 + 4  # the frame, and BlasStream's arrays
+    # the frame, and BlasStream's arrays
+    assert inner and len(inner[-1].invars) == 1 + len(small_scan_family.BlasStream._fields) == 6
 
 
 # -- the backend's series ---------------------------------------------------------
@@ -612,7 +614,8 @@ def test_the_backend_says_where_the_geometry_lives_and_counts_the_walk(small_sca
     before = render_prometheus(get_registry().snapshot())
     backend = TpuRaytraceBackend(base_directory=tmp_path, width=32, height=32, samples=2)
     backend.warm(f"{SCAN_SCENE}_measuring_480f-1w")
-    assert backend.bvh_build is not None and backend.bvh_build[1] > 0
+    ((model, triangles, _, seconds),) = backend.bvh_builds  # one BLAS: one build
+    assert (model, triangles) == ("scan", 2 * SMALL_GRID * SMALL_GRID) and seconds > 0
     job = BlenderJob(
         job_name=f"{SCAN_SCENE}_test", job_description=None, project_file_path="%BASE%/p.blend",
         render_script_path="%BASE%/s.py", frame_range_from=295, frame_range_to=296,
@@ -623,7 +626,7 @@ def test_the_backend_says_where_the_geometry_lives_and_counts_the_walk(small_sca
     backend._render_sync(job, 295)
     after = render_prometheus(get_registry().snapshot())
     assert value(after, 'render_geometry_bytes{space="hbm"}') > 2048 * 64
-    assert value(after, "render_bvh_build_seconds") > 0
+    assert value(after, "render_bvh_build_seconds") > 0 and value(after, "render_geometry_blas_units") == 1
     grown = {}
     for series in (
         "render_walk_node_visits_total", "render_walk_leaf_tests_total", "render_treelet_fetches_total",

@@ -173,13 +173,7 @@ def _ray_sort_order(origins, directions, alive, mesh=None):
         # ray counts): the ray's nearest-entry overlapped instance AABB,
         # K (=instances) for rays overlapping nothing — the same helper
         # the nearest wrapper derives its per-block candidates from.
-        table = pk._instance_table(
-            mesh.instances.rotation,
-            mesh.instances.translation,
-            mesh.instances.scale,
-            mesh.bvh.bounds_min,
-            mesh.bvh.bounds_max,
-        )
+        table = pk.mesh_instance_table(mesh)
         candidate = pk.instance_entry_candidates(
             origins, directions, table[:, 13:16], table[:, 16:19]
         ).astype(jnp.uint32)

@@ -31,6 +31,8 @@ triangles) is the correctness reference the BVH paths are tested against.
 
 from __future__ import annotations
 
+import functools
+import time
 from typing import NamedTuple
 
 import jax
@@ -102,12 +104,16 @@ class MeshBVH(NamedTuple):
     # tests and the benchmark's reference — and no traced program reads
     # them (``traced_stream_bvh``).
     stream: "BlasStream | None" = None
+    # A set of several BLASes (``morton_bvh_set``): model ``m``'s triangles
+    # are rows ``tri_first[m] .. tri_first[m + 1]`` of ``v0`` / ``e1`` /
+    # ``e2`` / ``normal`` (host ints). None: one BLAS, every row.
+    tri_first: "np.ndarray | None" = None
 
 
 class BlasStream(NamedTuple):
-    """A BLAS cut into treelets, as the device arrays the bounce kernel
-    reads from HBM (``pallas_kernels``: ``memory_space=pl.ANY``, staged
-    to VMEM scratch by one DMA when a packet enters a treelet).
+    """One BLAS or several, cut into treelets, as the device arrays the
+    bounce kernel reads from HBM (``pallas_kernels``: ``memory_space=pl.ANY``,
+    staged to VMEM scratch by one DMA when a packet enters a treelet).
 
     The tree's top — every node that holds more leaves than one treelet,
     with the treelet roots as its leaves — stays resident in SMEM; below
@@ -133,12 +139,20 @@ class BlasStream(NamedTuple):
     lanes wide node ``w`` (0 the root, ``1 + g`` group ``g``) at lanes
     ``8w .. 8w + 7``: lo xyz, hi xyz, then the child's bit ``1 << c`` as
     a float (0 for an empty slot, whose box is inverted) and a spare.
+
+    Several BLASes lie end to end in the same tables: model ``m``'s slabs
+    follow model ``m - 1``'s in ``tri``, and its top is nodes
+    ``top_first[m] .. top_first[m + 1]`` of the one top, whose skip links
+    and treelet numbers count from the tables' start. A walk of model
+    ``m`` begins at ``top_first[m]`` and is done at ``top_first[m + 1]``,
+    where its last skip link points.
     """
 
     tri: jnp.ndarray  # [NT, 16 * L/8 + WIDE, 128] f32
     top_bounds: jnp.ndarray  # [NTOP * 6] f32
     top_meta: jnp.ndarray  # [NTOP] int32: skip | (treelet + 1) << 16
-    root: jnp.ndarray  # [2, 3] f32: the whole tree's bounds
+    root: jnp.ndarray  # [M, 2, 3] f32: each model's whole tree's bounds
+    top_first: jnp.ndarray  # [M + 1] int32: where each model's top begins
 
 
 # Children of a wide node: the sublanes of one f32 vector register.
@@ -150,10 +164,10 @@ def treelet_leaves(stream: BlasStream) -> int:
 
 
 def geometry_bytes(bvh: MeshBVH) -> dict[str, int]:
-    """Bytes of a BLAS's tables by the memory they live in while a bounce
-    kernel runs: streamed, the treelet tables in HBM and the tree's top in
-    SMEM; resident, the padded triangle tables in VMEM and the nodes in
-    SMEM (``resident_table_bytes``)."""
+    """Bytes of a scene's BLAS tables (one BLAS or a set) by the memory
+    they live in while a bounce kernel runs: streamed, the treelet tables
+    in HBM and the trees' tops in SMEM; resident, the padded triangle
+    tables in VMEM and the nodes in SMEM (``resident_table_bytes``)."""
     stream = bvh.stream
     if stream is None:
         nodes = bvh.skip.shape[0] * 9 * 4 * (1 if bvh.octant is None else 8)
@@ -163,6 +177,11 @@ def geometry_bytes(bvh: MeshBVH) -> dict[str, int]:
         "vmem": 0,
         "smem": sum(int(a.size) * 4 for a in (stream.top_bounds, stream.top_meta)),
     }
+
+
+def blas_count(bvh: MeshBVH) -> int:
+    """How many BLASes the scene's geometry holds."""
+    return 1 if bvh.stream is None else bvh.stream.root.shape[0]
 
 
 def treelet_fetch_bytes(stream: BlasStream) -> int:
@@ -255,8 +274,38 @@ SCAN_NOISE_AMPLITUDE = 0.075  # 15% of the bounding radius 0.5
 SCAN_OCTAVES = 5
 
 
+class ScanModel(NamedTuple):
+    """One generated stand-in for a scanned model: ``make_scan_mesh``'s
+    arguments, and what it stands for."""
+
+    stands_for: str
+    published_triangles: int
+    grid: int
+    seed: int
+    tube_over_major: float | None
+
+
+# The bodies of scene family 03_physics-2-assets: three different meshes
+# at the triangle counts of the Stanford 3D Scanning Repository's models
+# (no network here, so each is generated; the seed is the published count,
+# the proportions differ so that the models differ in shape). Body ``i`` is
+# an instance of model ``i mod 3``; "dragon" is the scan family's mesh to
+# the byte. The program is general in the number of models; the list stops
+# at the dragon because a benchmark run builds the set three times and
+# checks it against a plain reference inside a time limit (PERF.md §7: with
+# the happy buddha's 1,087,716 as a fourth model a run did not fit it), and
+# Thai statue (10,000,000) and Lucy (28,055,742) would besides put the
+# set's resident top past SMEM (``TOP_SMEM_BUDGET``).
+ASSET_MODELS: dict[str, ScanModel] = {
+    "bunny": ScanModel("bun_zipper.ply", 69_451, 186, 69_451, 0.9),
+    "armadillo": ScanModel("Armadillo.ply", 345_944, 416, 345_944, 0.45),
+    "dragon": ScanModel("dragon_vrip.ply", 871_414, SCAN_GRID, SCAN_SEED, None),
+}
+
+
 def make_scan_mesh(
-    grid: int = SCAN_GRID, seed: int = SCAN_SEED
+    grid: int = SCAN_GRID, seed: int = SCAN_SEED,
+    tube_over_major: float | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """A closed, non-convex surface of ``2 * grid * grid`` triangles that
     stands for a scanned model: a doubly periodic ``grid x grid`` net of
@@ -265,7 +314,13 @@ def make_scan_mesh(
     along a seeded diagonal, the surface pushed along the tube's normal
     by ``SCAN_OCTAVES`` octaves of seeded periodic noise (amplitudes
     halving, summing to ``SCAN_NOISE_AMPLITUDE``) and every vertex moved
-    inside its cell, so triangle areas are irregular."""
+    inside its cell, so triangle areas are irregular. ``tube_over_major``
+    is the ring's proportions, the tube's radius over the ring's, at the
+    same reach (their sum); None is the scan family's 0.16 over 0.26."""
+    major, tube_radius = SCAN_MAJOR_RADIUS, SCAN_TUBE_RADIUS
+    if tube_over_major is not None:
+        major = (SCAN_MAJOR_RADIUS + SCAN_TUBE_RADIUS) / (1.0 + tube_over_major)
+        tube_radius = major * tube_over_major
     rng = np.random.default_rng(seed)
     iu, iv = np.meshgrid(np.arange(grid), np.arange(grid), indexing="ij")
     two_pi = 2.0 * np.pi
@@ -284,8 +339,8 @@ def make_scan_mesh(
             displacement += (weight / waves) * np.cos(
                 fu * u + fv * v + rng.random() * two_pi
             )
-    tube = SCAN_TUBE_RADIUS + displacement
-    ring = SCAN_MAJOR_RADIUS + tube * np.cos(v)
+    tube = tube_radius + displacement
+    ring = major + tube * np.cos(v)
     vertices = np.stack(
         [ring * np.cos(u), ring * np.sin(u), tube * np.sin(v)], axis=-1
     ).reshape(-1, 3).astype(np.float32)
@@ -800,7 +855,51 @@ def partition_treelets(tree: dict, max_leaves: int = TREELET_LEAVES) -> dict:
     return dict(
         tri=np.concatenate([tri, wide.reshape(n_treelets, WIDE, 128)], axis=1),
         top_bounds=top_bounds, top_meta=top_meta,
-        root=np.stack([tree["bounds_min"][0], tree["bounds_max"][0]]),
+        root=np.stack([tree["bounds_min"][0], tree["bounds_max"][0]])[None],
+        top_first=np.array([0, top_meta.shape[0]], np.int32),
+    )
+
+
+# What the resident tops of a scene's BLASes may take of a core's 1 MiB
+# of SMEM, at six bounds and one meta word a node: the bounce kernel's
+# other SMEM operands and scratch are a few kilobytes, and the compiler
+# refuses the launch at 1.00 MiB (1.13 MiB asked for at 40,000 nodes).
+TOP_SMEM_BUDGET = 896 << 10
+TOP_NODE_BYTES = 7 * 4
+
+
+def join_treelet_tables(models: list[dict]) -> dict:
+    """Several ``partition_treelets`` results as one set of tables: the
+    slabs and the tops end to end, a later model's skip links and treelet
+    numbers moved past the earlier models' nodes and slabs. The tables of
+    one model come back as they are."""
+    nodes = np.cumsum([0] + [m["top_meta"].shape[0] for m in models])
+    slabs = np.cumsum([0] + [m["tri"].shape[0] for m in models])
+    if slabs[-1] + 1 >= 1 << 15 or nodes[-1] >= 1 << 16:
+        raise ValueError(
+            f"{nodes[-1]} top nodes over {slabs[-1]} treelets: the resident "
+            "top outgrows its 16-bit links"
+        )
+    if int(nodes[-1]) * TOP_NODE_BYTES > TOP_SMEM_BUDGET:
+        raise ValueError(
+            f"a resident top of {nodes[-1]} nodes "
+            f"({int(nodes[-1]) * TOP_NODE_BYTES} B) does not fit SMEM "
+            f"({TOP_SMEM_BUDGET} B)"
+        )
+    if len({m["tri"].shape[1] for m in models}) != 1:
+        raise ValueError("the BLASes of one set share one treelet size")
+    top_meta = np.concatenate([
+        m["top_meta"] + np.where(
+            m["top_meta"] >> 16 > 0, int(slab) << 16, 0
+        ).astype(np.int32) + np.int32(node)
+        for m, node, slab in zip(models, nodes, slabs)
+    ])
+    return dict(
+        tri=np.concatenate([m["tri"] for m in models]),
+        top_bounds=np.concatenate([m["top_bounds"] for m in models]),
+        top_meta=top_meta,
+        root=np.concatenate([m["root"] for m in models]),
+        top_first=nodes.astype(np.int32),
     )
 
 
@@ -817,9 +916,7 @@ def morton_bvh(
     (tests walk one tree both ways)."""
     tree = _build_bvh_morton(vertices, faces)
     fields = {name: tree[name] for name in MeshBVH._fields[:9]}
-    streamed = resident_table_bytes(
-        tree["v0"].shape[0], tree["skip"].shape[0]
-    ) > RESIDENT_VMEM_BUDGET
+    streamed = _is_streamed(tree)
     with jax.ensure_compile_time_eval():  # see build_bvh
         stream = None
         if streamed or treelet_leaves is not None:
@@ -830,14 +927,71 @@ def morton_bvh(
         return MeshBVH(**fields, stream=stream)
 
 
+def _is_streamed(tree: dict) -> bool:
+    return resident_table_bytes(
+        tree["v0"].shape[0], tree["skip"].shape[0]
+    ) > RESIDENT_VMEM_BUDGET
+
+
+def morton_bvh_set(
+    meshes: dict, *, treelet_leaves: int | None = None, built=None,
+) -> MeshBVH:
+    """Several meshes' BLASes as ONE set the bounce kernel streams: each
+    mesh of ``meshes`` (by name, a callable that makes its vertices and
+    faces, so only one mesh's working arrays live at a time) is built by
+    ``_build_bvh_morton`` and cut by ``partition_treelets`` on its own,
+    the tables are joined on the host (``join_treelet_tables``) and put on
+    the device once. The triangle arrays are the models' end to end
+    (host), ``tri_first`` says where each begins, ``bounds_min`` /
+    ``bounds_max`` hold each model's root box, and the set has no node
+    arrays of its own. A set is streamed whole: a mesh small enough to be
+    resident is refused (one resident BLAS runs the resident kernels, and
+    there is no third path for a mix). ``built(name, triangles, began,
+    seconds)`` hears of each mesh as its tables are done, and of the
+    join and the copy to the device as ``"upload"``."""
+    trees, tables = [], []
+    for name, make in meshes.items():
+        began, started = time.time(), time.perf_counter()
+        vertices, faces = make()
+        tree = _build_bvh_morton(vertices, faces)
+        if not _is_streamed(tree) and treelet_leaves is None:
+            raise ValueError(
+                f"mesh {name!r} of the set ({faces.shape[0]} triangles) fits "
+                "the resident budget: a scene's BLASes are one resident "
+                "BLAS or all streamed, not a mix"
+            )
+        tables.append(partition_treelets(tree, treelet_leaves or TREELET_LEAVES))
+        trees.append({f: tree[f] for f in ("v0", "e1", "e2", "normal")})
+        if built is not None:
+            built(name, faces.shape[0], began, time.perf_counter() - started)
+    began, started = time.time(), time.perf_counter()
+    joined = join_treelet_tables(tables)
+    del tables
+    triangles = {f: np.concatenate([t[f] for t in trees]) for f in trees[0]}
+    with jax.ensure_compile_time_eval():  # see build_bvh
+        bvh = MeshBVH(
+            **triangles,
+            bounds_min=joined["root"][:, 0], bounds_max=joined["root"][:, 1],
+            skip=None, first=None, count=None,
+            stream=BlasStream(**{k: jnp.asarray(v) for k, v in joined.items()}),
+            tri_first=np.cumsum(
+                [0] + [t["v0"].shape[0] for t in trees]
+            ).astype(np.int32),
+        )
+        jax.block_until_ready(bvh.stream)
+    if built is not None:
+        built("upload", 0, began, time.perf_counter() - started)
+    return bvh
+
+
 def traced_stream_bvh(stream: BlasStream) -> MeshBVH:
-    """The ``MeshBVH`` a traced program holds of a streamed BLAS: the
-    treelet tables (its arguments) and the root's bounds, which is all the
-    instance table takes of the tree; the host arrays stay out of the
-    program."""
+    """The ``MeshBVH`` a traced program holds of its streamed BLASes: the
+    treelet tables (its arguments) and each model's root box (row ``m`` of
+    ``bounds_min`` / ``bounds_max``), which is all the instance table
+    takes of a tree; the host arrays stay out of the program."""
     return MeshBVH(
         v0=None, e1=None, e2=None, normal=None,
-        bounds_min=stream.root[0:1], bounds_max=stream.root[1:2],
+        bounds_min=stream.root[:, 0], bounds_max=stream.root[:, 1],
         skip=None, first=None, count=None, stream=stream,
     )
 
@@ -884,28 +1038,49 @@ def bvh_wide() -> int:
 
 
 def cached_mesh_bvh(
-    kind: str, builder: str | None = None, wide: int | None = None
+    kind: str, builder: str | None = None, wide: int | None = None, *,
+    built=None,
 ) -> MeshBVH:
     """Memoized BLAS build. The key carries EVERY build parameter —
     (kind, leaf size, builder, wide arity) — so flipping
     ``TRC_BVH_BUILDER``/``TRC_BVH_WIDE`` mid-process can never serve a
     tree built under the old knobs. ``None`` resolves the env tiers
-    (callers inside traced code must pass explicit values)."""
-    if kind == "scan":
-        builder, wide = "morton", 1  # its one build: the env tiers do not apply
+    (callers inside traced code must pass explicit values).
+    ``built(model, triangles, began, seconds)`` hears of each BLAS this
+    call builds (``morton_bvh_set``'s for a set; none where the build is
+    found cached)."""
+    if kind in ("scan", "assets"):
+        builder, wide = "morton", 1  # their one build: the env tiers do not apply
     builder = bvh_builder() if builder is None else builder
     wide = bvh_wide() if wide is None else max(1, min(int(wide), 8))
     key = ("bvh", kind, LEAF_SIZE, builder, wide)
     bvh = _geometry_cache.get(key)
     if bvh is None:
-        if kind == "box":
-            bvh = build_bvh(*make_box(), builder=builder, wide=wide)
-        elif kind == "icosphere":
-            bvh = build_bvh(*make_icosphere(2), builder=builder, wide=wide)
-        elif kind == "scan":
-            bvh = morton_bvh(*make_scan_mesh())
+        began, started = time.time(), time.perf_counter()
+        if kind == "assets":
+            bvh = morton_bvh_set(
+                {
+                    name: functools.partial(
+                        make_scan_mesh, model.grid, model.seed, model.tube_over_major
+                    )
+                    for name, model in ASSET_MODELS.items()
+                },
+                built=built,
+            )
         else:
-            raise ValueError(f"Unknown mesh kind: {kind!r}")
+            if kind == "box":
+                vertices, faces = make_box()
+            elif kind == "icosphere":
+                vertices, faces = make_icosphere(2)
+            elif kind == "scan":
+                vertices, faces = make_scan_mesh()
+            else:
+                raise ValueError(f"Unknown mesh kind: {kind!r}")
+            bvh = build_bvh(vertices, faces, builder=builder, wide=wide)
+            # the tables are on the device, not on their way
+            jax.block_until_ready(bvh)
+            if built is not None:
+                built(kind, faces.shape[0], began, time.perf_counter() - started)
         _geometry_cache[key] = bvh
     return bvh
 
@@ -1105,19 +1280,28 @@ def occluded_mesh(bvh: MeshBVH, origins, directions, already) -> jnp.ndarray:
 
 
 class MeshInstances(NamedTuple):
-    """K similarity-transformed instances of one object-space mesh.
+    """K similarity-transformed instances of object-space meshes.
 
     ``x_world = scale * rotation @ x_obj + translation``. Rays are pulled
     back with the inverse; dividing BOTH the local origin and direction by
     ``scale`` preserves the ray parameter t, so per-instance hits compare
     directly in world units and one static BVH serves every animated
-    instance.
+    instance of a model.
+
+    ``model`` says which BLAS of the scene's set an instance is (None:
+    the one there is); ``tri_first`` / ``tri_count`` are that model's rows
+    of the set's triangle arrays, filled in by ``scene_mesh_set`` for
+    whoever takes the geometry as plain arrays (the benchmark's
+    references) and read by no traced program.
     """
 
     rotation: jnp.ndarray  # [K, 3, 3] pure rotations
     translation: jnp.ndarray  # [K, 3]
     albedo: jnp.ndarray  # [K, 3]
     scale: jnp.ndarray  # [K] uniform per-instance scale
+    model: "jnp.ndarray | None" = None  # [K] int32
+    tri_first: "np.ndarray | None" = None  # [K] int32
+    tri_count: "np.ndarray | None" = None  # [K] int32
 
 
 def _rays_to_object_space(instances: MeshInstances, k, origins, directions):
@@ -1600,7 +1784,9 @@ def instance_morton_order(lo_w, hi_w):
 
 
 class MeshSet(NamedTuple):
-    """A mesh-backed scene's geometry: one shared BVH + its instances."""
+    """A mesh-backed scene's geometry: its BLAS, or its set of BLASes in
+    one ``MeshBVH`` (``morton_bvh_set``), + the instances, each of which
+    names its model."""
 
     bvh: MeshBVH
     instances: MeshInstances
@@ -1629,13 +1815,18 @@ def scene_mesh_set(
     kind = mesh_kind_for_scene(scene_name)
     if kind is None:
         return None
-    return MeshSet(
-        bvh=(
-            cached_mesh_bvh(kind, builder, wide) if stream is None
-            else traced_stream_bvh(stream)
-        ),
-        instances=build_mesh_instances(scene_name, frame),
+    instances = build_mesh_instances(scene_name, frame)
+    if stream is not None:
+        return MeshSet(traced_stream_bvh(stream), instances)
+    bvh = cached_mesh_bvh(kind, builder, wide)
+    first = (
+        np.array([0, bvh.v0.shape[0]], np.int32) if bvh.tri_first is None
+        else bvh.tri_first
     )
+    model = np.asarray(instances.model)  # the family's rule: never traced
+    return MeshSet(bvh, instances._replace(
+        tri_first=first[model], tri_count=first[model + 1] - first[model],
+    ))
 
 
 def scene_blas_stream(

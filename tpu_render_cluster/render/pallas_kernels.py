@@ -317,10 +317,7 @@ def initial_mesh_sort_keys(mesh, origins, directions, alive):
     pinned by tests/test_tlas.py)."""
     from tpu_render_cluster.render.mesh import instance_morton_order
 
-    table = _instance_table(
-        mesh.instances.rotation, mesh.instances.translation,
-        mesh.instances.scale, mesh.bvh.bounds_min, mesh.bvh.bounds_max,
-    )
+    table = mesh_instance_table(mesh)
     lo_w, hi_w = table[:, 13:16], table[:, 16:19]
     # Candidates are SLOT labels (the Morton-sorted order the kernels'
     # instance table uses), not original-index labels — the epilogue's
@@ -1495,7 +1492,7 @@ def _bvh_instanced_kernel_factory(
 
 
 def _instance_table(rotation, translation, scale, bounds_min, bounds_max,
-                    albedo=None):
+                    albedo=None, *, model=None, top_first=None):
     """[K, 22] SMEM table: rotation row-major (0..8), translation (9..11),
     1/scale (12), the instance's WORLD-space AABB (13..18) — the top-level
     cull the kernel applies before paying for the object-space walk — and
@@ -1503,19 +1500,42 @@ def _instance_table(rotation, translation, scale, bounds_min, bounds_max,
 
     World AABB of a transformed box: center_w = s R c_o + t,
     half_w = s |R| h_o (elementwise absolute rotation).
+
+    Over streamed BLASes (``top_first``: ``mesh.BlasStream.top_first``)
+    row ``m`` of ``bounds_min`` / ``bounds_max`` is model ``m``'s root
+    box, instance ``k`` is model ``model[k]`` (None: model 0), and the
+    table is [K, 24]: columns 22 and 23 are the node of the resident top
+    where the instance's walk begins and the node at which it is done
+    (whole numbers under 65,536, exact in f32).
     """
     k = rotation.shape[0]
-    center_obj = 0.5 * (bounds_min[0] + bounds_max[0])  # root node
-    half_obj = 0.5 * (bounds_max[0] - bounds_min[0])
-    center_w = (
-        scale[:, None] * jnp.einsum(
-            "kij,j->ki", rotation, center_obj, precision="highest"
+
+    def world_box(root):
+        center_obj = 0.5 * (bounds_min[root] + bounds_max[root])
+        half_obj = 0.5 * (bounds_max[root] - bounds_min[root])
+        center_w = (
+            scale[:, None] * jnp.einsum(
+                "kij,j->ki", rotation, center_obj, precision="highest"
+            )
+            + translation
         )
-        + translation
-    )
-    half_w = scale[:, None] * jnp.einsum(
-        "kij,j->ki", jnp.abs(rotation), half_obj, precision="highest"
-    )
+        half_w = scale[:, None] * jnp.einsum(
+            "kij,j->ki", jnp.abs(rotation), half_obj, precision="highest"
+        )
+        return jnp.concatenate([center_w - half_w, center_w + half_w], axis=1)
+
+    box = world_box(0)  # the root node
+    top_range = []
+    if top_first is not None:
+        if model is None:
+            model = jnp.zeros((k,), jnp.int32)
+        # one box per model by the one expression, each instance its own's
+        for m in range(1, top_first.shape[0] - 1):
+            box = jnp.where((model == m)[:, None], world_box(m), box)
+        top_range = [
+            top_first[model].astype(jnp.float32)[:, None],
+            top_first[model + 1].astype(jnp.float32)[:, None],
+        ]
     if albedo is None:
         albedo = jnp.zeros((k, 3), jnp.float32)
     return jnp.concatenate(
@@ -1523,11 +1543,24 @@ def _instance_table(rotation, translation, scale, bounds_min, bounds_max,
             rotation.reshape(k, 9),
             translation,
             (1.0 / scale)[:, None],
-            center_w - half_w,
-            center_w + half_w,
+            box,
             albedo,
+            *top_range,
         ],
         axis=1,
+    )
+
+
+def mesh_instance_table(mesh):
+    """``_instance_table`` of a MeshSet without albedo: each instance's
+    world box from its own model's root box."""
+    bvh, instances = mesh
+    blas_of = {} if bvh.stream is None else dict(
+        model=instances.model, top_first=bvh.stream.top_first
+    )
+    return _instance_table(
+        instances.rotation, instances.translation, instances.scale,
+        bvh.bounds_min, bvh.bounds_max, **blas_of,
     )
 
 
@@ -1748,7 +1781,7 @@ def _mesh_trace_kernel_factory(
     max_bounces: int, n_padded: int, n_nodes: int, leaf_size: int,
     k_count: int, state_io: bool = False, use_tlas: bool = False,
     tlas_nodes: int = 0, quant: int = 0, ordered: bool = False,
-    tlas_ordered: bool = False, stream: tuple[int, int] | None = None,
+    tlas_ordered: bool = False, stream: int | None = None,
 ):
     """Mesh path-trace kernel. Two shapes share one bounce_step:
 
@@ -1762,10 +1795,11 @@ def _mesh_trace_kernel_factory(
       scenes. ``max_bounces`` still names the TOTAL bounce count so the
       per-(ray, bounce) RNG counters match the megakernel's stream layout.
 
-    ``stream`` = (nodes of the tree's top, leaf slots of a treelet) makes
-    the BLAS operands HBM tables (``mesh.BlasStream``) in place of the
+    ``stream`` = the leaf slots of a treelet makes the BLAS operands HBM
+    tables (``mesh.BlasStream``: one BLAS or a set of them) in place of the
     resident triangle and node blocks: the walk runs over the resident top
-    of the tree and, where a packet enters a treelet, copies that
+    of the instance's tree, whose node range the instance table carries,
+    and, where a packet enters a treelet, copies that
     treelet's slab (triangle rows and wide nodes) into scratch and walks
     inside it (``stream_walk``): a wide node's eight child boxes in one
     ``[8, block]`` test (``slab_mask``), a leaf's box in its group's. The
@@ -2109,7 +2143,7 @@ def _mesh_trace_kernel_factory(
             return tri_hit, t_cand
 
         if stream is not None:
-            top_nodes, leaf_slots = stream
+            leaf_slots = stream
             no_counts = (jnp.int32(0),) * len(WALK_COUNTS)
 
             def six(ref, node, stride):
@@ -2142,11 +2176,13 @@ def _mesh_trace_kernel_factory(
                 return pltpu.roll(rows, (128 - (leaf & 7) * 16) & 127, 1)
 
             def stream_walk(
-                touch, ox, oy, oz, invx, invy, invz, limit_of, on_leaf,
+                k, touch, ox, oy, oz, invx, invy, invz, limit_of, on_leaf,
                 carry, stats,
             ):
-                """The threaded walk of one instance's BLAS over HBM
-                tables: the resident top, and under a top leaf the
+                """The threaded walk of instance ``k``'s BLAS over HBM
+                tables: its nodes of the resident top (from the instance
+                table's column 22 to its column 23, where the tree's last
+                skip link points), and under a top leaf the
                 treelet it names, staged and walked in place. ``carry`` is
                 the walk's tuple of [1, BR] rows, ``limit_of(carry)`` the
                 per-lane cull distance, ``on_leaf(carry, rows)`` the update
@@ -2239,9 +2275,12 @@ def _mesh_trace_kernel_factory(
                     )
                     return (next_node, *carry, stats[0] + 1, *stats[1:])
 
-                node0 = jnp.where(touch, jnp.int32(0), jnp.int32(top_nodes))
+                done = inst_ref[k, 23].astype(jnp.int32)
+                node0 = jnp.where(
+                    touch, inst_ref[k, 22].astype(jnp.int32), done
+                )
                 walk = jax.lax.while_loop(
-                    lambda walk: walk[0] < top_nodes, top_body,
+                    lambda walk: walk[0] < done, top_body,
                     (node0, *carry, *stats),
                 )
                 return tuple(walk[1:1 + width]), tuple(walk[1 + width:])
@@ -2345,7 +2384,7 @@ def _mesh_trace_kernel_factory(
                         )
 
                     walked, stats = stream_walk(
-                        touch, ox, oy, oz, invx, invy, invz,
+                        k, touch, ox, oy, oz, invx, invy, invz,
                         lambda c: c[0], on_leaf, carry[:8], carry[8:],
                     )
                     return (*walked, *stats)
@@ -2520,7 +2559,7 @@ def _mesh_trace_kernel_factory(
                         )),)
 
                     (walked_occluded,), stats = stream_walk(
-                        touch, ox, oy, oz, invx, invy, invz,
+                        k, touch, ox, oy, oz, invx, invy, invz,
                         lambda c: jnp.where(c[0] > 0.0, -INF, INF),
                         on_leaf, (occluded,), stats,
                     )
@@ -3158,16 +3197,19 @@ def _mesh_bounce_io(
     plane_albedo_a, plane_albedo_b,
     rotation, translation, scale, inst_albedo,
     v0, e1, e2, normal, bounds_min, bounds_max, skip, first, count,
-    octant=None, stream=None,
+    octant=None, stream=None, model=None,
     *, total_bounces: int, interpret: bool, use_tlas: bool = False,
     tlas_leaf: int = 4, tlas_block: int = 256, quant: int = 0,
 ):
     from tpu_render_cluster.render.mesh import LEAF_SIZE
 
+    blas_of = {}
     if stream is not None:
         # A streamed BLAS has no resident tables to pack: fp32 node words
-        # in HBM, one canonical order.
+        # in HBM, one canonical order. Which of the set's BLASes an
+        # instance is rides the instance table, as its top's node range.
         quant = 0
+        blas_of = dict(model=model, top_first=stream.top_first)
     # The TLAS variant blocks rays at its own narrower packet width —
     # threaded in by the caller (env tiers resolve outside traces).
     block = tlas_block if use_tlas else BVH_BLOCK_R
@@ -3224,7 +3266,7 @@ def _mesh_bounce_io(
         # IS rebuilding on gathered inputs — exactly, same f32 ops).
         table = _instance_table(
             rotation, translation, scale, bounds_min, bounds_max,
-            inst_albedo,
+            inst_albedo, **blas_of,
         )
         lo_w, hi_w = table[:, 13:16], table[:, 16:19]
         order = instance_morton_order(lo_w, hi_w)
@@ -3260,10 +3302,12 @@ def _mesh_bounce_io(
         near_first = jnp.argsort(
             jnp.sum((translation - anchor_point[None, :]) ** 2, axis=1)
         )
+        if blas_of.get("model") is not None:
+            blas_of["model"] = jnp.asarray(model)[near_first]
         inst_table = _instance_table(
             rotation[near_first], translation[near_first],
             scale[near_first],
-            bounds_min, bounds_max, inst_albedo[near_first],
+            bounds_min, bounds_max, inst_albedo[near_first], **blas_of,
         )
         quant = resolve_bvh_quant(
             quant, (n_nodes, tri_rows // LEAF_SIZE, LEAF_SIZE)
@@ -3283,9 +3327,10 @@ def _mesh_bounce_io(
     if stream is not None:
         from tpu_render_cluster.render.mesh import treelet_leaves
 
-        # The BLAS stays in HBM: the kernel copies a treelet's slab
+        # The BLASes stay in HBM: the kernel copies a treelet's slab
         # (triangle rows, wide nodes) into this scratch when a packet
-        # enters it. Only the tree's top sits in SMEM for the whole launch.
+        # enters it. Only the trees' tops sit in SMEM for the whole launch
+        # (mesh.TOP_SMEM_BUDGET).
         ordered = False
         geometry_operands = (stream.tri, stream.top_bounds, stream.top_meta)
         geometry_specs = [
@@ -3298,7 +3343,7 @@ def _mesh_bounce_io(
             pltpu.SMEM((1,), jnp.int32),
             pltpu.SemaphoreType.DMA((1,)),
         ]
-        stream_shape = (int(stream.top_meta.shape[0]), treelet_leaves(stream))
+        stream_shape = treelet_leaves(stream)
         stats_specs = [row_block]
         stats_shapes = [jax.ShapeDtypeStruct((1, padded_rays), jnp.int32)]
         kernel_name = "mesh_bounce_streamed"
@@ -3440,7 +3485,7 @@ def mesh_bounce_pallas(
         instances.albedo,
         bvh.v0, bvh.e1, bvh.e2, bvh.normal,
         bvh.bounds_min, bvh.bounds_max, bvh.skip, bvh.first, bvh.count,
-        bvh.octant, bvh.stream,
+        bvh.octant, bvh.stream, instances.model,
         total_bounces=total_bounces, interpret=_interpret(),
         use_tlas=use_tlas_for(instances.translation.shape[0], use_tlas),
         tlas_leaf=tlas_leaf_size(),

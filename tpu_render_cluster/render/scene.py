@@ -41,13 +41,17 @@ SCENE_NAMES = (
     "01_simple-animation",
     "02_physics-mesh",
     "02_physics",
+    "03_physics-2-assets",
     "03_physics-2-scan",
     "03_physics-2-mesh",
     "03_physics-2",
 )
 # The rigid-body families whose 48 bodies share one set of transforms:
-# icospheres, or instances of the 871,200-triangle scan stand-in.
-_PHYSICS_2_BODIES = ("03_physics-2-mesh", "03_physics-2-scan")
+# icospheres, instances of the 871,200-triangle scan stand-in, or
+# instances of three different scan stand-ins (body i is model i mod 3).
+_PHYSICS_2_BODIES = (
+    "03_physics-2-mesh", "03_physics-2-scan", "03_physics-2-assets",
+)
 
 _FPS = 24.0
 _GRAVITY = 9.81
@@ -188,6 +192,7 @@ def build_mesh_instances(name: str, frame):
     """
     if name != "02_physics-mesh" and name not in _PHYSICS_2_BODIES:
         return None
+    from tpu_render_cluster.render import mesh
     from tpu_render_cluster.render.mesh import MeshInstances, rotation_y
 
     frame = jnp.asarray(frame, jnp.float32)
@@ -215,8 +220,12 @@ def build_mesh_instances(name: str, frame):
     rotation = rotation_y(tau * (0.6 + 2.0 * u2) + u1 * 6.28)
     translation = jnp.stack([x, y, z], axis=-1)
     albedo = _grid_colors(k)
+    # Which BLAS of the scene's set a body is: a rule of the family, never
+    # of the frame (NumPy, so it is a constant of any trace it meets).
+    models = len(mesh.ASSET_MODELS) if name == "03_physics-2-assets" else 1
     return MeshInstances(
-        rotation=rotation, translation=translation, albedo=albedo, scale=size
+        rotation=rotation, translation=translation, albedo=albedo, scale=size,
+        model=(np.arange(k) % models).astype(np.int32),
     )
 
 
@@ -242,6 +251,8 @@ def mesh_kind_for_scene(name: str) -> str | None:
         return "icosphere"
     if name == "03_physics-2-scan":
         return "scan"
+    if name == "03_physics-2-assets":
+        return "assets"  # a set: mesh.ASSET_MODELS' three BLASes
     return None
 
 
@@ -284,7 +295,7 @@ def scene_for_job_name(job_name: str) -> str:
             return name
     # Two-digit project prefixes map to the classic (non-mesh) families.
     for name in SCENE_NAMES:
-        if name.endswith(("-mesh", "-scan")):
+        if name.endswith(("-mesh", "-scan", "-assets")):
             continue
         if job_name.startswith(name.split("_", 1)[0]):
             return name

@@ -77,9 +77,10 @@ class TpuRaytraceBackend(RenderBackend):
             float(self.device["count"]), platform=self.device["platform"],
             kind=self.device["device_kind"],
         )
-        # (start, seconds) of warm()'s BLAS build, for the worker's
-        # timeline: its span tracer does not exist yet when warm() runs.
-        self.bvh_build: tuple[float, float] | None = None
+        # warm()'s BLAS builds, one (model, triangles, start, seconds) a
+        # model, for the worker's timeline: its span tracer does not exist
+        # yet when warm() runs.
+        self.bvh_builds: list[tuple[str, int, float, float]] = []
 
     def warm(self, scene_name: str) -> None:
         """Compile + execute the renderer once, outside any job window.
@@ -126,37 +127,44 @@ class TpuRaytraceBackend(RenderBackend):
             np.asarray(display)
 
     def _build_geometry(self, scene_name: str) -> None:
-        """Build the scene's BLAS (once a process: the renderer factories
-        find it cached) and say how long it took and where it lives."""
-        import jax
-
+        """Build the scene's BLAS or its set of BLASes (once a process:
+        the renderer factories find them cached) and say how long each
+        model took, how many there are and where they live."""
         from tpu_render_cluster.obs import get_registry
+        from tpu_render_cluster.render import mesh
         from tpu_render_cluster.render.integrator import resolve_bvh_config
-        from tpu_render_cluster.render.mesh import cached_mesh_bvh, geometry_bytes
         from tpu_render_cluster.render.scene import mesh_kind_for_scene
 
         kind = mesh_kind_for_scene(scene_name)
         if kind is None:
             return
-        started_at, started = time.time(), time.perf_counter()
-        bvh = cached_mesh_bvh(kind, *resolve_bvh_config()[2:])
-        jax.block_until_ready(bvh)  # the tables are on the device, not on their way
-        seconds = time.perf_counter() - started
-        self.bvh_build = (started_at, seconds)
+        self.bvh_builds = []
+        bvh = mesh.cached_mesh_bvh(
+            kind, *resolve_bvh_config()[2:],
+            built=lambda *build: self.bvh_builds.append(build),
+        )
         registry = get_registry()
-        registry.gauge(
+        seconds = registry.gauge(
             "render_bvh_build_seconds",
-            "Seconds warm() spent building the scene's BLAS and putting "
-            "its tables on the device",
-        ).set(seconds)
+            "Seconds warm() spent building a model's BLAS (model "
+            "\"upload\": joining a set's tables and putting them on the "
+            "device; one BLAS alone: its build and its copy together)",
+            labels=("model",),
+        )
+        for model, _triangles, _began, took in self.bvh_builds:
+            seconds.set(took, model=model)
+        registry.gauge(
+            "render_geometry_blas_units",
+            "BLASes the scene's geometry holds: 1, or the models of a set",
+        ).set(float(mesh.blas_count(bvh)))
         where = registry.gauge(
             "render_geometry_bytes",
-            "Bytes of the scene's BLAS tables by the memory they live in "
-            "while a bounce kernel runs: hbm (streamed by treelet), vmem "
-            "and smem (resident)",
+            "Bytes of the scene's BLAS tables (a set's together) by the "
+            "memory they live in while a bounce kernel runs: hbm (streamed "
+            "by treelet), vmem and smem (resident)",
             labels=("space",),
         )
-        for space, count in geometry_bytes(bvh).items():
+        for space, count in mesh.geometry_bytes(bvh).items():
             where.set(float(count), space=space)
 
     async def render_frame(
@@ -227,9 +235,9 @@ class TpuRaytraceBackend(RenderBackend):
     def _observe_walk(walk, scene_name: str) -> None:
         """The walk of a frame whose BLAS is streamed from HBM:
         ``walk[b]`` = bounce b's launch's counts in the order of
-        ``pallas_kernels.WALK_COUNTS`` (node visits, treelet fetches, leaf
-        tests first), counted by the kernel and returned by the frame's
-        program."""
+        ``pallas_kernels.WALK_COUNTS``, counted by the kernel and returned
+        by the frame's program. Steps that are none of leaf tests, treelet
+        entries and group tests are the resident top's."""
         from tpu_render_cluster.obs import get_registry
         from tpu_render_cluster.render.integrator import resolve_bvh_config
         from tpu_render_cluster.render.mesh import scene_blas_stream, treelet_fetch_bytes
@@ -250,6 +258,15 @@ class TpuRaytraceBackend(RenderBackend):
             "render_walk_leaf_tests_total",
             "Of those steps, the leaves whose triangles were tested",
         ).inc(float(walk[:, 2].sum()))
+        registry.counter(
+            "render_walk_treelet_entries_total",
+            "Of those steps, the wide tests of a treelet's root: a packet "
+            "entered the treelet",
+        ).inc(float(walk[:, 3].sum()))
+        registry.counter(
+            "render_walk_group_tests_total",
+            "Of those steps, the wide tests of a group's eight leaves",
+        ).inc(float(walk[:, 4].sum()))
         registry.counter(
             "render_treelet_fetches_total",
             "Treelets copied from HBM into a bounce kernel's scratch",

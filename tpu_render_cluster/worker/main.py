@@ -255,11 +255,11 @@ def main(argv: list[str] | None = None) -> int:
     if args.warm_scene and args.backend == "tpu-raytrace":
         backend.warm(args.warm_scene)
     worker = Worker(args.master_host, args.master_port, backend)
-    bvh_build = getattr(backend, "bvh_build", None)
-    if bvh_build:
+    for model, triangles, began, seconds in getattr(backend, "bvh_builds", ()):
         worker.span_tracer.complete(
             "bvh_build", cat="render", track="setup",
-            start_wall=bvh_build[0], duration=bvh_build[1],
+            start_wall=began, duration=seconds,
+            args={"model": model, "triangles": triangles},
         )
     # Which timeline is which chip's: the device stamp's index rides the
     # exported timeline's process metadata as well as the snapshot.
