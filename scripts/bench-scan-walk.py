@@ -9,10 +9,11 @@ BLAS is streamed, 03_physics-2-scan unless the family is named
 For each ``samples`` (default 8 and 1): the frame program's compile and
 three frames' times and picture hashes with each bounce launch's (live,
 width) and walk counts (``pallas_kernels.WALK_COUNTS``: steps, fetches,
-leaf tests, treelet entries, group tests); then bounce 0 and bounce 1 alone
+leaf tests, treelet entries, group tests, prefetches); then bounce 0 and bounce 1 alone
 at full width (one launch each, rays sorted as the frame program sorts
 them), each line with the steps split into box steps (top, wide) and leaf
-tests, the mean children hit per wide test and the microseconds a step;
+tests, the mean children hit per wide test, the share of fetches started
+one treelet ahead and the microseconds a step;
 then one round of the glue the other walk design would pay between launches
 (a sort of the ray keys, a packed gather of the ray state, a gather of node
 rows).
@@ -83,8 +84,13 @@ def walk_shape(walk, seconds: float) -> dict:
         return dict(counts, us_per_step=seconds * 1e6 / max(steps, 1))
     leaf_tests = counts["leaf_tests"]
     wide_tests = counts["treelet_entries"] + counts["group_tests"]
+    prefetched = (
+        {"prefetch_share": counts["treelet_prefetches"] / max(counts["treelet_fetches"], 1)}
+        if "treelet_prefetches" in counts else {}  # a tree from before ISSUE 36 has no such count
+    )
     return dict(
         counts,
+        **prefetched,
         box_steps=steps - leaf_tests,
         top_steps=steps - leaf_tests - wide_tests,
         wide_tests=wide_tests,

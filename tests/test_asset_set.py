@@ -154,7 +154,7 @@ def test_a_set_of_one_model_is_todays_single_blas_bit_for_bit(use_tlas, interpre
 @pytest.mark.parametrize("m, name", list(enumerate(SMALL_MODELS)))
 def test_a_walk_never_leaves_its_instances_nodes_of_the_top(m, name, interpreted_kernels):  # noqa: F811
     """Every instance model ``m`` of the set: the walk begins at that
-    model's first top node and is done at its last skip link, so its five
+    model's first top node and is done at its last skip link, so its six
     counts and every hit are those of the model's tree alone. A walk that
     ran on into the next model's nodes would count more steps."""
     _, instances, _, _ = scan_tests.bounce_inputs()
@@ -191,6 +191,24 @@ def test_three_equal_models_render_the_one_model_scenes_bytes(small_assets_famil
     assert image.std() > 5.0 and walk[:, 0].sum() > 0
     np.testing.assert_array_equal(image, alone_image)
     np.testing.assert_array_equal(walk[:, [0, 2, 3, 4]], alone_walk[:, [0, 2, 3, 4]])  # fetches differ: three copies of a slab
+
+
+def test_the_assets_familys_frame_is_the_parents_and_no_leaf_is_tested_that_was_not(small_assets_family):
+    """Frame 295 of the family over the three small models against what the
+    walk that fetched a treelet where it entered it gave (commit 81f8c34,
+    before ISSUE 36; `test_scan_stream` says the same of one BLAS): the
+    look-ahead of every model's walk ends where its own top ends, so the
+    picture, the leaves and the groups tested are that walk's, and only
+    treelets found too far ahead are entered besides."""
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator
+
+    image, _live, walk = integrator.fused_frame_renderer(ASSETS_SCENE, 32, 32, 2, 4, with_live=True)(jnp.float32(295))
+    scan_tests.assert_the_parents_walk(
+        walk, [[3780, 527, 1300, 527, 527], [2645, 356, 878, 356, 356], [1404, 180, 386, 182, 182], [893, 110, 245, 110, 110]]
+    )
+    scan_tests.assert_the_parents_picture(image, "assets")
 
 
 def brute_force_bounce(singles, scene, instances, origins, directions):
@@ -233,8 +251,8 @@ def test_the_sets_walk_finds_what_brute_force_over_each_instances_own_model_find
     scene, instances, origins, directions = scan_tests.bounce_inputs(k=9)
     instances = instances._replace(model=(np.arange(9) % 3).astype(np.int32))
     streamed = whole_bounce(bvh, instances, use_tlas, k=9)
-    visits, fetches, leaf_tests, entries, group_tests = (int(x) for x in streamed[6])
-    assert visits > leaf_tests + entries + group_tests and entries >= fetches > 0
+    visits, fetches, leaf_tests, entries, group_tests, prefetches = (int(x) for x in streamed[6])
+    assert visits > leaf_tests + entries + group_tests and entries >= fetches >= prefetches > 0
 
     contribution, new_origins, _, throughput, alive = (np.asarray(x) for x in streamed[:5])
     t, normal, albedo, shadowed = brute_force_bounce(singles, scene, instances, origins, directions)

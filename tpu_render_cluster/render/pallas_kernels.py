@@ -846,10 +846,12 @@ BVH_DONE_EPS = 1e-12
 # What a launch over a streamed BLAS counts, in the order it returns them.
 # A node visit is a step a packet paid: a box test (one node of the
 # resident top, or a wide node's eight children at once) or a leaf's
-# triangles; the last two split the box tests inside treelets.
+# triangles; entries and group tests split the box tests inside treelets;
+# a prefetch is a fetch started while another treelet of the same walk was
+# still to be walked (the rest are a walk's first).
 WALK_COUNTS = (
     "node_visits", "treelet_fetches", "leaf_tests", "treelet_entries",
-    "group_tests",
+    "group_tests", "treelet_prefetches",
 )
 # Mesh-megakernel dispatch bound: use the fused whole-bounce-loop kernel
 # when bvh_nodes x instances is at most this; deeper walks pay more for
@@ -1799,9 +1801,9 @@ def _mesh_trace_kernel_factory(
     tables (``mesh.BlasStream``: one BLAS or a set of them) in place of the
     resident triangle and node blocks: the walk runs over the resident top
     of the instance's tree, whose node range the instance table carries,
-    and, where a packet enters a treelet, copies that
-    treelet's slab (triangle rows and wide nodes) into scratch and walks
-    inside it (``stream_walk``): a wide node's eight child boxes in one
+    and copies a treelet's slab (triangle rows and wide nodes) into one
+    of two scratch slots, one treelet ahead of the one the packet walks
+    inside (``stream_walk``): a wide node's eight child boxes in one
     ``[8, block]`` test (``slab_mask``), a leaf's box in its group's. The
     leaves come in the resident walk's order over the same tree, the
     boxes, the triangles and the arithmetic on them are the same, and a
@@ -1838,7 +1840,8 @@ def _mesh_trace_kernel_factory(
              inst_ref) = take(13)
         if stream is not None:
             # The BLAS in HBM and its resident top; scratch comes last:
-            # the staged treelet and which one it is.
+            # two slots for staged treelets, which treelet each holds,
+            # and a semaphore a slot.
             (tri_hbm, topb_ref, topm_ref) = take(3)
             (tri_buf, staged_ref, dma_sem) = refs[-3:]
             del refs[-3:]
@@ -2150,26 +2153,39 @@ def _mesh_trace_kernel_factory(
                 base = node * stride
                 return tuple(ref[base + i] for i in range(6))
 
-            def stage_treelet(treelet):
-                """Bring ``treelet`` into scratch unless it is the one
-                staged already; returns 1 where it was fetched."""
-                fetch = staged_ref[0] != treelet
+            def slab_copy(treelet, slot):
+                return pltpu.make_async_copy(
+                    tri_hbm.at[treelet], tri_buf.at[slot], dma_sem.at[slot]
+                )
+
+            def start_treelet(treelet, busy):
+                """Start the copy of ``treelet`` (-1: none) unless a slot
+                holds it already; a new one goes to the slot that is not
+                ``busy``, the one whose treelet is still to be walked.
+                Returns (its slot, 1 where a copy was started: whoever
+                enters the treelet waits for that copy first)."""
+                in_first = staged_ref[0] == treelet
+                in_second = staged_ref[1] == treelet
+                slot = jnp.where(
+                    in_first, 0, jnp.where(in_second, 1, 1 - busy)
+                )
+                fetch = (treelet >= 0) & jnp.logical_not(
+                    in_first | in_second
+                )
 
                 @pl.when(fetch)
                 def _():
-                    copy = pltpu.make_async_copy(
-                        tri_hbm.at[treelet], tri_buf, dma_sem.at[0]
-                    )
-                    copy.start()
-                    copy.wait()
-                    staged_ref[0] = treelet
+                    staged_ref[slot] = treelet
+                    slab_copy(treelet, slot).start()
 
-                return fetch.astype(jnp.int32)
+                return slot, fetch.astype(jnp.int32)
 
-            def staged_leaf(leaf):
-                """The 16 rows of local leaf ``leaf`` with its 12 columns
-                brought to lanes 0..11: v0, e1, e2, normal."""
+            def staged_leaf(slot, leaf):
+                """The 16 rows of local leaf ``leaf`` of the treelet in
+                ``slot`` with its 12 columns brought to lanes 0..11: v0,
+                e1, e2, normal."""
                 rows = tri_buf[
+                    slot,
                     pl.ds(pl.multiple_of((leaf >> 3) * leaf_size, leaf_size),
                           leaf_size), :
                 ]
@@ -2182,13 +2198,19 @@ def _mesh_trace_kernel_factory(
                 """The threaded walk of instance ``k``'s BLAS over HBM
                 tables: its nodes of the resident top (from the instance
                 table's column 22 to its column 23, where the tree's last
-                skip link points), and under a top leaf the
-                treelet it names, staged and walked in place. ``carry`` is
-                the walk's tuple of [1, BR] rows, ``limit_of(carry)`` the
-                per-lane cull distance, ``on_leaf(carry, rows)`` the update
-                by a leaf's 16 staged rows; ``stats`` = the walk's counts so far
+                skip link points), and under a top leaf the treelet it
+                names, staged and walked in place. The top is walked one
+                hit leaf ahead: the copy of the next treelet the packet
+                will enter is started before the current one is walked,
+                and waited for where that one is entered, so every copy
+                started is waited for once and none outlives the walk.
+                ``carry`` is the walk's tuple of [1, BR] rows,
+                ``limit_of(carry)`` the per-lane cull distance,
+                ``on_leaf(carry, rows)`` the update by a leaf's 16 staged
+                rows; ``stats`` = the walk's counts so far
                 (``WALK_COUNTS``). Returns (carry, stats)."""
                 width = len(carry)
+                done = inst_ref[k, 23].astype(jnp.int32)
 
                 def children(mask, step, state):
                     """``step(child, state)`` for each set bit of ``mask``,
@@ -2209,16 +2231,18 @@ def _mesh_trace_kernel_factory(
                         lambda walk: walk[0] != 0, turn, (mask, *state)
                     )[1:]
 
-                def inside(treelet, carry, stats):
-                    """The treelet's two levels of wide nodes: the root's
-                    children are its groups, a group's its leaves, both
-                    in the binary tree's order. The root's own box is
-                    the top leaf's, tested already. A leaf's rows are
-                    tested whole: its padding rows are zero and meet no
-                    ray."""
-                    visits, fetches, leaf_tests, entries, group_tests = stats
-                    fetches = fetches + stage_treelet(treelet)
-                    nodes = tri_buf[2 * leaf_slots:2 * leaf_slots + 8, :]
+                def inside(slot, carry):
+                    """The two levels of wide nodes of the treelet in
+                    ``slot``: the root's children are its groups, a
+                    group's its leaves, both in the binary tree's order.
+                    The root's own box is the top leaf's, tested already,
+                    by a limit no sharper than the one the root's test
+                    reads: a treelet the look-ahead found and the hits
+                    since have culled meets no group here. A leaf's rows
+                    are tested whole: its padding rows are zero and meet
+                    no ray. Returns (carry, leaves tested, groups
+                    tested)."""
+                    nodes = tri_buf[slot, pl.ds(2 * leaf_slots, 8), :]
 
                     def met(node, carry):
                         return slab_mask(
@@ -2229,7 +2253,8 @@ def _mesh_trace_kernel_factory(
                     # state: the carry, then leaves and groups tested
                     def in_leaf(group, child, state):
                         carry = on_leaf(
-                            state[:width], staged_leaf(group * 8 + child)
+                            state[:width],
+                            staged_leaf(slot, group * 8 + child),
                         )
                         return (*carry, state[-2] + 1, state[-1])
 
@@ -2247,43 +2272,73 @@ def _mesh_trace_kernel_factory(
                         met(nodes, carry), in_group,
                         (*carry, jnp.int32(0), jnp.int32(0)),
                     )
-                    return tuple(carry), (
-                        visits + 1 + groups + leaves, fetches,
+                    return tuple(carry), leaves, groups
+
+                def find_next(node, limit, visits):
+                    """The scalar walk of the resident top from ``node`` to
+                    the next top leaf whose box the packet hits nearer
+                    than ``limit``. Returns (the node to go on from, that
+                    leaf's treelet or -1 where the walk is done, the
+                    steps counted)."""
+                    def top_step(find):
+                        node, _, visits = find
+                        meta = topm_ref[node]
+                        treelet = (meta >> 16) - 1
+                        hit_any = slab_any(
+                            six(topb_ref, node, 6), ox, oy, oz, invx, invy,
+                            invz, limit,
+                        )
+                        is_leaf = treelet >= 0
+                        next_node = jnp.where(
+                            hit_any & jnp.logical_not(is_leaf),
+                            node + 1, meta & 0xFFFF,
+                        )
+                        return (
+                            next_node,
+                            jnp.where(is_leaf & hit_any, treelet, -1),
+                            visits + 1,
+                        )
+
+                    return jax.lax.while_loop(
+                        lambda find: (find[0] < done) & (find[1] < 0),
+                        top_step, (node, jnp.int32(-1), visits),
+                    )
+
+                def per_treelet(walk):
+                    node, treelet, slot, waits = walk[:4]
+                    carry = tuple(walk[4:4 + width])
+                    (visits, fetches, leaf_tests, entries, group_tests,
+                     prefetches) = walk[4 + width:]
+                    # The look-ahead culls by the limit as it stands
+                    # before this treelet is walked: never too sharp.
+                    node, ahead, visits = find_next(
+                        node, limit_of(carry), visits
+                    )
+                    ahead_slot, ahead_waits = start_treelet(ahead, slot)
+
+                    @pl.when(waits > 0)
+                    def _():
+                        slab_copy(treelet, slot).wait()
+
+                    carry, leaves, groups = inside(slot, carry)
+                    return (
+                        node, ahead, ahead_slot, ahead_waits, *carry,
+                        visits + 1 + groups + leaves, fetches + ahead_waits,
                         leaf_tests + leaves, entries + 1,
-                        group_tests + groups,
+                        group_tests + groups, prefetches + ahead_waits,
                     )
 
-                def top_body(walk):
-                    node = walk[0]
-                    carry = tuple(walk[1:1 + width])
-                    stats = tuple(walk[1 + width:])
-                    meta = topm_ref[node]
-                    treelet = (meta >> 16) - 1
-                    hit_any = slab_any(
-                        six(topb_ref, node, 6), ox, oy, oz, invx, invy, invz,
-                        limit_of(carry),
-                    )
-                    is_leaf = treelet >= 0
-                    carry, stats = jax.lax.cond(
-                        is_leaf & hit_any,
-                        lambda: inside(treelet, carry, stats),
-                        lambda: (carry, stats),
-                    )
-                    next_node = jnp.where(
-                        hit_any & jnp.logical_not(is_leaf),
-                        node + 1, meta & 0xFFFF,
-                    )
-                    return (next_node, *carry, stats[0] + 1, *stats[1:])
-
-                done = inst_ref[k, 23].astype(jnp.int32)
-                node0 = jnp.where(
-                    touch, inst_ref[k, 22].astype(jnp.int32), done
+                node, treelet, visits = find_next(
+                    jnp.where(touch, inst_ref[k, 22].astype(jnp.int32), done),
+                    limit_of(carry), stats[0],
                 )
+                slot, waits = start_treelet(treelet, jnp.int32(1))
                 walk = jax.lax.while_loop(
-                    lambda walk: walk[0] < done, top_body,
-                    (node0, *carry, *stats),
+                    lambda walk: walk[1] >= 0, per_treelet,
+                    (node, treelet, slot, waits, *carry, visits,
+                     stats[1] + waits, *stats[2:]),
                 )
-                return tuple(walk[1:1 + width]), tuple(walk[1 + width:])
+                return tuple(walk[4:4 + width]), tuple(walk[4 + width:])
 
         def world_cull(k, wox, woy, woz, wix, wiy, wiz, limit_t):
             """Block-wide test of the untransformed rays against instance
@@ -2827,6 +2882,7 @@ def _mesh_trace_kernel_factory(
                 @pl.when(pl.program_id(0) == 0)
                 def _():
                     staged_ref[0] = jnp.int32(-1)
+                    staged_ref[1] = jnp.int32(-1)
 
             no_stats = () if stream is None else no_counts
             o, d, throughput, radiance, alive, hit_slot, *stats = jax.lax.cond(
@@ -3328,8 +3384,9 @@ def _mesh_bounce_io(
         from tpu_render_cluster.render.mesh import treelet_leaves
 
         # The BLASes stay in HBM: the kernel copies a treelet's slab
-        # (triangle rows, wide nodes) into this scratch when a packet
-        # enters it. Only the trees' tops sit in SMEM for the whole launch
+        # (triangle rows, wide nodes) into one of this scratch's two slots
+        # before a packet enters it, while the packet walks the treelet in
+        # the other. Only the trees' tops sit in SMEM for the whole launch
         # (mesh.TOP_SMEM_BUDGET).
         ordered = False
         geometry_operands = (stream.tri, stream.top_bounds, stream.top_meta)
@@ -3339,9 +3396,9 @@ def _mesh_bounce_io(
             pl.BlockSpec(stream.top_meta.shape, flat, memory_space=pltpu.SMEM),
         ]
         scratch_shapes = [
-            pltpu.VMEM(stream.tri.shape[1:], jnp.float32),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.VMEM((2, *stream.tri.shape[1:]), jnp.float32),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
         ]
         stream_shape = treelet_leaves(stream)
         stats_specs = [row_block]

@@ -272,6 +272,12 @@ class TpuRaytraceBackend(RenderBackend):
             "Treelets copied from HBM into a bounce kernel's scratch",
         ).inc(fetches)
         registry.counter(
+            "render_treelet_prefetches_total",
+            "Of those copies, the ones started while the packet still had "
+            "another treelet of the same walk to walk (the rest are a walk's "
+            "first)",
+        ).inc(float(walk[:, 5].sum()))
+        registry.counter(
             "render_treelet_fetch_bytes_total",
             "Bytes of treelet rows and wide nodes copied from HBM into a "
             "bounce kernel's scratch",
