@@ -106,4 +106,21 @@ def _isolated_process_global_stores():
     mesh = sys.modules.get("tpu_render_cluster.render.mesh")
     if mesh is not None:
         mesh.reset_geometry_cache()
+    # The start-up recorder (obs/startup.py): process-scoped, and its marks
+    # are set once, so every test's workers begin a start-up of their own.
+    startup = sys.modules.get("tpu_render_cluster.obs.startup")
+    if startup is not None:
+        startup.reset_startup()
     yield
+
+
+@pytest.fixture
+def startup_timeline():
+    """A tracer the process's start-up recorder writes through to, as a
+    worker's would be: the stages, the ``bvh_build`` spans and JAX's
+    ``render.compile`` spans of this test land in its events."""
+    from tpu_render_cluster.obs import MetricsRegistry, Tracer, get_startup
+
+    tracer = Tracer("startup-under-test")
+    get_startup().attach(tracer, MetricsRegistry())
+    return tracer

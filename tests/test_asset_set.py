@@ -473,7 +473,7 @@ def test_the_job_name_finds_the_family_and_older_names_find_theirs():
 # -- the backend's series ------------------------------------------------------------------
 
 
-def test_the_backend_counts_the_blases_times_each_build_and_splits_the_steps(small_assets_family, tmp_path):
+def test_the_backend_counts_the_blases_times_each_build_and_splits_the_steps(small_assets_family, tmp_path, startup_timeline):
     from tpu_render_cluster.jobs.models import BlenderJob, DistributionStrategy
     from tpu_render_cluster.obs import get_registry
     from tpu_render_cluster.obs.prometheus import render_prometheus
@@ -486,8 +486,10 @@ def test_the_backend_counts_the_blases_times_each_build_and_splits_the_steps(sma
     before = render_prometheus(get_registry().snapshot())
     backend = TpuRaytraceBackend(base_directory=tmp_path, width=32, height=32, samples=2)
     backend.warm(f"{ASSETS_SCENE}_measuring_480f-1w")
-    assert [(model, triangles) for model, triangles, _, _ in backend.bvh_builds] == [
-        ("fat", 512), ("ring", 1152), ("thin", 2048), ("upload", 0),
+    # one span a model on the worker's timeline, through the start-up recorder
+    builds = [e for e in startup_timeline.events() if e["name"] == "bvh_build"]
+    assert [(e["cat"], e["args"]["model"], e["args"]["triangles"]) for e in builds] == [
+        ("render", "fat", 512), ("render", "ring", 1152), ("render", "thin", 2048), ("render", "upload", 0),
     ]
     job = BlenderJob(
         job_name=f"{ASSETS_SCENE}_test", job_description=None, project_file_path="%BASE%/p.blend",
@@ -502,8 +504,9 @@ def test_the_backend_counts_the_blases_times_each_build_and_splits_the_steps(sma
     held = small_assets_family.geometry_bytes(small_assets_family.cached_mesh_bvh("assets"))
     assert value(after, 'render_geometry_bytes{space="hbm"}') == held["hbm"] > sum(SMALL_TRIANGLES) * 64
     assert value(after, 'render_geometry_bytes{space="smem"}') == held["smem"]
-    for model, _, _, seconds in backend.bvh_builds:  # a gauge a model: what the benchmark's bvh_build_s sums
-        assert seconds > 0 and value(after, f'render_bvh_build_seconds{{model="{model}"}}') == pytest.approx(seconds)
+    for build in builds:  # a gauge a model: what the benchmark's bvh_build_s sums
+        model, seconds = build["args"]["model"], build["dur"] / 1e6
+        assert seconds > 0 and value(after, f'render_bvh_build_seconds{{model="{model}"}}') == pytest.approx(seconds, abs=1e-6)
     grown = {}
     for series in (
         "render_walk_node_visits_total", "render_walk_leaf_tests_total",

@@ -732,7 +732,7 @@ def test_the_scan_scenes_program_streams_every_bounce(small_scan_family):
 # -- the backend's series ---------------------------------------------------------
 
 
-def test_the_backend_says_where_the_geometry_lives_and_counts_the_walk(small_scan_family, tmp_path):
+def test_the_backend_says_where_the_geometry_lives_and_counts_the_walk(small_scan_family, tmp_path, startup_timeline):
     from tpu_render_cluster.jobs.models import BlenderJob, DistributionStrategy
     from tpu_render_cluster.obs import get_registry
     from tpu_render_cluster.obs.prometheus import render_prometheus
@@ -745,8 +745,8 @@ def test_the_backend_says_where_the_geometry_lives_and_counts_the_walk(small_sca
     before = render_prometheus(get_registry().snapshot())
     backend = TpuRaytraceBackend(base_directory=tmp_path, width=32, height=32, samples=2)
     backend.warm(f"{SCAN_SCENE}_measuring_480f-1w")
-    ((model, triangles, _, seconds),) = backend.bvh_builds  # one BLAS: one build
-    assert (model, triangles) == ("scan", 2 * SMALL_GRID * SMALL_GRID) and seconds > 0
+    (build,) = [e for e in startup_timeline.events() if e["name"] == "bvh_build"]  # one BLAS: one build
+    assert build["args"] == {"model": "scan", "triangles": 2 * SMALL_GRID * SMALL_GRID} and build["dur"] > 0
     job = BlenderJob(
         job_name=f"{SCAN_SCENE}_test", job_description=None, project_file_path="%BASE%/p.blend",
         render_script_path="%BASE%/s.py", frame_range_from=295, frame_range_to=296,

@@ -4,6 +4,9 @@
   with labels; compact wire form for the heartbeat metrics payload.
 - ``tracer`` — spans (wall-clock anchor + monotonic duration) exported as
   Chrome trace-event JSON, loadable in Perfetto / chrome://tracing.
+- ``startup`` — a worker's start-up as eight exclusive stages, with JAX's
+  own trace / lower / compile / cache-load events as spans and counters
+  beneath them; buffers until the worker's tracer exists.
 - ``snapshot`` — periodic atomic JSON snapshots for live inspection.
 - ``clocksync`` — NTP-style per-worker clock-offset estimation from the
   heartbeat's four timestamps (median-of-window + drift tracking).
@@ -37,6 +40,7 @@ from tpu_render_cluster.obs.registry import (
     merge_wire,
 )
 from tpu_render_cluster.obs.snapshot import SnapshotWriter, write_metrics_snapshot
+from tpu_render_cluster.obs.startup import STARTUP_STAGES, get_startup
 from tpu_render_cluster.obs.timeline import (
     TimelineProcess,
     export_cluster_trace,
@@ -67,6 +71,7 @@ __all__ = [
     "HistoryStore",
     "LoopLagMonitor",
     "MetricsRegistry",
+    "STARTUP_STAGES",
     "SnapshotWriter",
     "TimelineProcess",
     "Tracer",
@@ -74,6 +79,7 @@ __all__ = [
     "export_cluster_trace",
     "frame_steps",
     "get_registry",
+    "get_startup",
     "log_buckets",
     "merge_timeline",
     "merge_wire",

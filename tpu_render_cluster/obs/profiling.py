@@ -220,10 +220,15 @@ class KernelProfiler:
         """
         if not profiling_enabled() or self.captured(kernel):
             return False
+        from tpu_render_cluster.obs.startup import get_startup
+
         started = time.perf_counter()
         try:
-            lowered = jitted.lower(*args, **kwargs)
-            cost = lowered.cost_analysis()
+            # A span of the worker's timeline: what the capture costs the
+            # program's build (the trace and lower spans lie inside it).
+            with get_startup().child("profiler_capture", kernel=kernel):
+                lowered = jitted.lower(*args, **kwargs)
+                cost = lowered.cost_analysis()
             if isinstance(cost, (list, tuple)):  # per-device list on some paths
                 cost = cost[0] if cost else {}
             flops = float(cost.get("flops", 0.0) or 0.0)

@@ -29,6 +29,13 @@ strings (empty = valid):
     instant (``i``) events — a ``B``/``E`` or flow event landing there
     means a merge folded another track onto an attribution row.
 
+7.  A process's start-up is stitched right: its ``worker.startup`` spans
+    (obs/startup.py) name stages of ``STARTUP_STAGES``, each at most once
+    and in that order, do not overlap, and leave no gap over 1 ms — the
+    stages are exclusive and contiguous by construction, so anything else
+    means a mark was set twice or a merge mixed two processes' start-ups.
+    A worker that died before its first frame exports a prefix of them.
+
 ``scripts/validate_trace.py`` is the CLI wrapper; tests call these
 functions directly on every artifact they export.
 """
@@ -40,6 +47,8 @@ import json
 import math
 from pathlib import Path
 from typing import Any, Iterable
+
+from tpu_render_cluster.obs.startup import STARTUP_STAGES
 
 __all__ = [
     "validate_trace_events",
@@ -55,6 +64,11 @@ __all__ = [
 # between the clocks; 5 ms is far above that and far below any real
 # ordering violation a merge or rebase bug would introduce.
 END_ORDER_TOLERANCE_US = 5000.0
+
+# Start-up stages share their edges: a gap is a stitching fault, an overlap
+# beyond the timestamps' rounding too.
+STARTUP_GAP_TOLERANCE_US = 1000.0
+STARTUP_OVERLAP_TOLERANCE_US = 1.0
 
 
 def _finite_nonneg(value: Any) -> bool:
@@ -74,6 +88,7 @@ def validate_trace_events(events: Iterable[Any]) -> list[str]:
     thread_names: dict[tuple[Any, Any], str] = {}
     flow_events: list[dict[str, Any]] = []
     phases_by_track: dict[tuple[Any, Any], set[str]] = {}
+    startup_by_pid: dict[Any, list[dict[str, Any]]] = {}
 
     for i, event in enumerate(events):
         if not isinstance(event, dict) or "ph" not in event:
@@ -114,6 +129,8 @@ def validate_trace_events(events: Iterable[Any]) -> list[str]:
                 )
                 continue
             spans_by_track.setdefault(track, []).append(event)
+            if event.get("cat") == "worker.startup":
+                startup_by_pid.setdefault(event.get("pid"), []).append(event)
         elif ph == "B":
             open_stacks.setdefault(track, []).append(str(event.get("name")))
         elif ph == "E":
@@ -157,6 +174,33 @@ def validate_trace_events(events: Iterable[Any]) -> list[str]:
                     f"earlier-appended span's end (non-monotonic track)"
                 )
             high_water = max(high_water, end)
+
+    # Invariant 7: each process's start-up stages, in order and edge to edge.
+    for pid, stages in startup_by_pid.items():
+        previous = None
+        for span in stages:
+            name = span.get("name")
+            if name not in STARTUP_STAGES:
+                problems.append(f"pid {pid}: unknown start-up stage {name!r}")
+                continue
+            if previous is not None:
+                if STARTUP_STAGES.index(name) <= STARTUP_STAGES.index(previous["name"]):
+                    problems.append(
+                        f"pid {pid}: start-up stage {name!r} after "
+                        f"{previous['name']!r} (out of STARTUP_STAGES order)"
+                    )
+                gap = float(span["ts"]) - (float(previous["ts"]) + float(previous["dur"]))
+                if gap > STARTUP_GAP_TOLERANCE_US:
+                    problems.append(
+                        f"pid {pid}: {gap:.1f}us between start-up stages "
+                        f"{previous['name']!r} and {name!r} (they share an edge)"
+                    )
+                elif gap < -STARTUP_OVERLAP_TOLERANCE_US:
+                    problems.append(
+                        f"pid {pid}: start-up stages {previous['name']!r} and "
+                        f"{name!r} overlap by {-gap:.1f}us"
+                    )
+            previous = span
 
     # Flow resolution: start + terminal per id, every event bound to a span.
     # Binding is a point-stabbing query per flow event; a linear scan over

@@ -44,12 +44,17 @@ class TpuRaytraceBackend(RenderBackend):
         tile_size: int | None = None,
         sharding: str | None = None,
     ) -> None:
+        from tpu_render_cluster.obs.startup import get_startup, watch_jax_compiles
         from tpu_render_cluster.utils.accelerator import require_tpu_device
 
+        # From here on every program JAX builds is counted by phase and,
+        # from 10 ms, a span of the worker's timeline.
+        watch_jax_compiles()
         # Refuses to build on a non-TPU backend unless JAX_PLATFORMS asks
         # for the CPU; the stamp rides the worker's exported metrics
         # snapshot.
-        self.device = require_tpu_device()
+        with get_startup().child("open_device"):
+            self.device = require_tpu_device()
         self.base_directory = Path(base_directory) if base_directory else None
         self.width = width
         self.height = height
@@ -77,41 +82,42 @@ class TpuRaytraceBackend(RenderBackend):
             float(self.device["count"]), platform=self.device["platform"],
             kind=self.device["device_kind"],
         )
-        # warm()'s BLAS builds, one (model, triangles, start, seconds) a
-        # model, for the worker's timeline: its span tracer does not exist
-        # yet when warm() runs.
-        self.bvh_builds: list[tuple[str, int, float, float]] = []
 
     def warm(self, scene_name: str) -> None:
         """Compile + execute the renderer once, outside any job window.
 
         The process-level analog of pre-pulling the Blender container
         (reference: pull-blender-image.sh): the first XLA compile costs
-        20-40 s and must not land inside a rendered frame's trace.
+        20-40 s and must not land inside a rendered frame's trace. Fills
+        three of start-up's stages (obs/startup.py): ``geometry``,
+        ``program_build`` (until the program's first call has returned:
+        the executable exists, the work is queued) and ``first_execute``.
         """
         import numpy as np
 
+        from tpu_render_cluster.obs.startup import get_startup
         from tpu_render_cluster.render.scene import scene_for_job_name
 
+        startup = get_startup()
+        startup.enter("geometry")
         # Accept job names as well as scene names, resolving exactly like
         # the render path does — otherwise the warmed program can differ
         # from the one the job compiles.
         scene_name = scene_for_job_name(scene_name)
         self._build_geometry(scene_name)
 
+        startup.enter("program_build")
         if self.sharding in ("tile", "spp"):
             from tpu_render_cluster.parallel.sharded_render import sharded_frame_renderer
 
-            np.asarray(
-                sharded_frame_renderer(
-                    scene_name,
-                    self.width,
-                    self.height,
-                    self.samples,
-                    self.max_bounces,
-                    self.sharding,
-                )(1)
-            )
+            display = sharded_frame_renderer(
+                scene_name,
+                self.width,
+                self.height,
+                self.samples,
+                self.max_bounces,
+                self.sharding,
+            )(1)
         else:
             from tpu_render_cluster.render.integrator import fused_frame_renderer
 
@@ -124,13 +130,15 @@ class TpuRaytraceBackend(RenderBackend):
                 self.max_bounces,
                 with_live=True,
             )(1)
-            np.asarray(display)
+        startup.enter("first_execute")
+        np.asarray(display)
 
     def _build_geometry(self, scene_name: str) -> None:
         """Build the scene's BLAS or its set of BLASes (once a process:
         the renderer factories find them cached) and say how long each
         model took, how many there are and where they live."""
         from tpu_render_cluster.obs import get_registry
+        from tpu_render_cluster.obs.startup import get_startup
         from tpu_render_cluster.render import mesh
         from tpu_render_cluster.render.integrator import resolve_bvh_config
         from tpu_render_cluster.render.scene import mesh_kind_for_scene
@@ -138,11 +146,6 @@ class TpuRaytraceBackend(RenderBackend):
         kind = mesh_kind_for_scene(scene_name)
         if kind is None:
             return
-        self.bvh_builds = []
-        bvh = mesh.cached_mesh_bvh(
-            kind, *resolve_bvh_config()[2:],
-            built=lambda *build: self.bvh_builds.append(build),
-        )
         registry = get_registry()
         seconds = registry.gauge(
             "render_bvh_build_seconds",
@@ -151,8 +154,16 @@ class TpuRaytraceBackend(RenderBackend):
             "device; one BLAS alone: its build and its copy together)",
             labels=("model",),
         )
-        for model, _triangles, _began, took in self.bvh_builds:
+
+        def built(model: str, triangles: int, began: float, took: float) -> None:
+            # one gauge and one span of the worker's timeline a model
             seconds.set(took, model=model)
+            get_startup().span(
+                "bvh_build", cat="render", start_wall=began, duration=took,
+                args={"model": model, "triangles": triangles},
+            )
+
+        bvh = mesh.cached_mesh_bvh(kind, *resolve_bvh_config()[2:], built=built)
         registry.gauge(
             "render_geometry_blas_units",
             "BLASes the scene's geometry holds: 1, or the models of a set",

@@ -19,6 +19,7 @@ from tpu_render_cluster.obs import (
     export_chrome_trace,
     write_metrics_snapshot,
 )
+from tpu_render_cluster.obs.startup import get_startup
 from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.utils.logging import initialize_console_and_file_logging
 from tpu_render_cluster.worker.backends import create_backend
@@ -110,7 +111,10 @@ def build_parser() -> argparse.ArgumentParser:
         default=None,
         help="tpu-raytrace only: compile the renderer for this scene BEFORE "
         "connecting to the master, so the job window never contains XLA "
-        "compilation (the analog of pre-pulling the Blender image).",
+        "compilation (the analog of pre-pulling the Blender image). Fills "
+        "the start-up stages geometry, program_build and first_execute "
+        "(worker_startup_stage_seconds); without it they read 0 and their "
+        "cost lies in first_frame.",
     )
     return parser
 
@@ -125,6 +129,9 @@ def make_backend(args: argparse.Namespace):
             append_arguments=args.append_arguments,
         )
     if args.backend == "tpu-raytrace":
+        with get_startup().child("import_jax"):
+            import jax  # noqa: F401 - timed here, used by what follows
+
         from tpu_render_cluster.parallel.mesh import initialize_multihost
         from tpu_render_cluster.utils.accelerator import configure_compile_cache
 
@@ -249,18 +256,17 @@ async def _run_worker(
 
 
 def main(argv: list[str] | None = None) -> int:
+    startup = get_startup()
+    startup.enter("backend_init")
     args = build_parser().parse_args(argv)
     initialize_console_and_file_logging(args.log_file_path)
     backend = make_backend(args)
     if args.warm_scene and args.backend == "tpu-raytrace":
         backend.warm(args.warm_scene)
+    startup.enter("connect")
+    # The worker's tracer begins here: what start-up has recorded so far
+    # is handed over to it (Worker.__init__), the rest is written through.
     worker = Worker(args.master_host, args.master_port, backend)
-    for model, triangles, began, seconds in getattr(backend, "bvh_builds", ()):
-        worker.span_tracer.complete(
-            "bvh_build", cat="render", track="setup",
-            start_wall=began, duration=seconds,
-            args={"model": model, "triangles": triangles},
-        )
     # Which timeline is which chip's: the device stamp's index rides the
     # exported timeline's process metadata as well as the snapshot.
     device = getattr(backend, "device", None)

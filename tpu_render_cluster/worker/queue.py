@@ -23,6 +23,7 @@ from dataclasses import dataclass, field
 from tpu_render_cluster.jobs.models import BlenderJob
 from tpu_render_cluster.jobs.tiles import WorkUnit
 from tpu_render_cluster.obs import MetricsRegistry, Tracer
+from tpu_render_cluster.obs.startup import get_startup
 from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.transport.actors import SenderHandle
 from tpu_render_cluster.traces.worker_trace import WorkerTraceBuilder
@@ -133,6 +134,7 @@ class WorkerAutomaticQueue:
         )
         self._loop_state: str | None = None
         self._loop_state_since = time.perf_counter()
+        self._startup = get_startup()
         self._frames: list[QueuedFrame] = []
         self._finished_indices: set[tuple[str, int, int | None]] = set()
         # Bumped by reset_session(): a frame queued under a previous
@@ -164,6 +166,8 @@ class WorkerAutomaticQueue:
             # the master returns the frame to the pending pool — a frame
             # accepted here after drain() collected the queue would be lost.
             raise RuntimeError("Worker is draining; not accepting new frames.")
+        if not self._startup.finished:
+            self._startup.enter("first_frame")
         self._frames.append(
             QueuedFrame(
                 job, frame_index, trace=trace, job_id=job_id, tile=tile,
@@ -336,6 +340,8 @@ class WorkerAutomaticQueue:
             )
             return
         self._enter_loop_state("report")
+        if not self._startup.finished:
+            self._startup.finish()  # the first frame's file is in place
         self._tracer.trace_new_rendered_frame(frame.frame_index, timing)
         self._observe_frame_phases(frame, timing)
         self._remove(frame)

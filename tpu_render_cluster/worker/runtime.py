@@ -23,6 +23,7 @@ from tpu_render_cluster.obs import (
     Tracer,
     get_registry,
 )
+from tpu_render_cluster.obs.startup import get_startup
 from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.traces.worker_trace import WorkerTrace, WorkerTraceBuilder
 from tpu_render_cluster.transport.actors import MessageRouter, SenderHandle
@@ -133,6 +134,9 @@ class Worker:
         self.span_tracer = span_tracer or Tracer(
             f"worker-{pm.worker_id_to_string(self.worker_id)}"
         )
+        # Start-up's stages and the spans beneath them were buffered until
+        # a tracer existed: this is it (the first worker of a process).
+        get_startup().attach(self.span_tracer, self.metrics)
         # Worker-end wire accounting + event-loop lag probe: the same
         # transport_*/obs_loop_* families the master exports, so both
         # ends of every exchange (and both loops) are priced.
@@ -293,6 +297,7 @@ class Worker:
             return ws
 
         first = await fresh_connection(False)
+        get_startup().enter("await_job")
         client = ReconnectingClient(
             first,
             lambda: fresh_connection(True),
