@@ -13,8 +13,11 @@ import numpy as np
 import pytest
 
 import tpu_render_cluster.render.geometry as geometry
+from tpu_render_cluster.render import pallas_kernels
 from tpu_render_cluster.render.camera import camera_rays, scene_camera
-from tpu_render_cluster.render.pallas_kernels import intersect_spheres_pallas
+from tpu_render_cluster.render.pallas_kernels import (
+    EPS, INF, intersect_spheres_pallas, trace_paths_fused,
+)
 from tpu_render_cluster.render.scene import SCENE_NAMES, build_scene
 
 
@@ -119,7 +122,6 @@ def test_sun_disc_escape_matches_reference_path(monkeypatch):
     in the fused path, with the RNG never consulted.
     """
     from tpu_render_cluster.render.integrator import trace_paths
-    from tpu_render_cluster.render.pallas_kernels import trace_paths_fused
 
     # Pin the reference to the XLA path: trace_paths dispatches to the
     # fused kernel when pallas is enabled (e.g. on a real TPU backend).
@@ -167,3 +169,297 @@ def test_stochastic_render_agrees_statistically(monkeypatch):
     assert np.abs(out - ref).max() < 0.2, (
         f"max per-pixel diff {np.abs(out - ref).max():.3f}"
     )
+
+
+# ---------------------------------------------------------------------------
+# The sphere megakernel's contractions (PR 38): one bf16 matmul for the
+# hit's rows, c . o carried from the shadow origin.
+
+_GATHER_SPHERES = 13  # padded to 16: three padded spheres
+
+
+def _gather_tables():
+    """Four per-sphere tables whose values use every bit of a float32
+    significand, plus a zero, a 1e-3 and a 1e5 in each."""
+    rng = np.random.default_rng(38)
+    n = _GATHER_SPHERES
+
+    def full_mantissa(shape, scale):
+        # an odd 24-bit integer times a power of two: no trailing zero bit
+        odd = rng.integers(1 << 23, 1 << 24, size=shape, dtype=np.int64) | 1
+        sign = rng.choice([-1.0, 1.0], size=shape)
+        return (sign * odd * scale).astype(np.float32)
+
+    tables = {
+        "centre": full_mantissa((3, n), 2.0 ** -20),
+        "albedo": full_mantissa((3, n), 2.0 ** -24),
+        "emission": full_mantissa((3, n), 2.0 ** -21),
+        "radius": np.abs(full_mantissa((1, n), 2.0 ** -23)),
+    }
+    for table in tables.values():
+        table[0, 1], table[0, 2], table[0, 3] = 0.0, 1e-3, 1e5
+    return tables
+
+
+@pytest.mark.parametrize("which", ["centre", "albedo", "emission", "radius"])
+def test_gather_hit_returns_table_columns_bit_for_bit(which):
+    """One bf16 matmul against a one-hot returns the float32 tables'
+    columns unchanged, for every index and for the padded spheres."""
+    tables = _gather_tables()
+    n = _GATHER_SPHERES
+    padded_n = -(-n // 8) * 8
+    padded = {
+        name: np.pad(table, ((0, 0), (0, padded_n - n)))
+        for name, table in tables.items()
+    }
+    table = pallas_kernels._gather_table(
+        jnp.asarray(tables["centre"].T), jnp.asarray(tables["albedo"].T),
+        jnp.asarray(tables["emission"].T), jnp.asarray(tables["radius"][0]),
+        padded_n,
+    )
+    assert table.dtype == jnp.bfloat16
+    assert table.shape == (3 * pallas_kernels._GATHER_ROWS, padded_n)
+    lanes = 128
+    idx = jnp.arange(lanes, dtype=jnp.int32)[None, :] % padded_n  # every index
+    sphere_iota = jax.lax.broadcasted_iota(jnp.int32, (padded_n, lanes), 0)
+    gathered = dict(zip(
+        ("centre", "albedo", "emission", "radius"),
+        pallas_kernels._gather_hit(table, sphere_iota, idx),
+    ))
+    got = np.asarray(gathered[which])
+    want = padded[which][:, np.asarray(idx[0])]
+    assert got.dtype == np.float32 and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    assert not want[:, n:padded_n].any()  # a padded sphere reads zeros
+
+
+def _parent_formulation(scene, origins, directions, seed, *, max_bounces):
+    """The megakernel's bounce loop as PR 37 had it, in plain jnp over the
+    whole ray set: six ``_dot_f32`` contractions a bounce (c . d, c . o,
+    c . shadow_o and three one-hot gathers), the radius gathered on the
+    VPU, c . o made anew from the origin every bounce."""
+    dot = pallas_kernels._dot_f32
+    contract_first = (((0,), (0,)), ((), ()))
+    gather = (((1,), (0,)), ((), ()))
+    n = scene.centers.shape[0]
+    n_padded = -(-n // 8) * 8
+    pad = n_padded - n
+    c = jnp.pad(scene.centers, ((0, pad), (0, 0))).T
+    radius = jnp.pad(scene.radii, (0, pad))[:, None]
+    r2 = radius * radius
+    csq = jnp.sum(c * c, axis=0)[:, None]
+    albedo_t = jnp.pad(scene.albedo, ((0, pad), (0, 0))).T
+    emission_t = jnp.pad(scene.emission, ((0, pad), (0, 0))).T
+    dc_sun = pallas_kernels._center_dot_sun(c, scene.sun_direction)
+    sun = scene.sun_direction[:, None]
+    sun_color = scene.sun_color[:, None]
+    sky_horizon = scene.sky_horizon[:, None]
+    sky_zenith = scene.sky_zenith[:, None]
+    plane_a = scene.plane_albedo_a[:, None]
+    plane_b = scene.plane_albedo_b[:, None]
+
+    o, d = origins.T, directions.T
+    rays = o.shape[1]
+    seed = jnp.asarray(seed, jnp.int32).astype(jnp.uint32)
+    ray_index = jnp.arange(rays, dtype=jnp.uint32)[None, :]
+    sphere_iota = jax.lax.broadcasted_iota(jnp.int32, (n_padded, rays), 0)
+    throughput = jnp.ones((3, rays), jnp.float32)
+    radiance = jnp.zeros((3, rays), jnp.float32)
+    alive = jnp.ones((1, rays), jnp.float32)
+    for bounce in range(max_bounces):
+        dc = dot(c, d, contract_first)
+        oc = dot(c, o, contract_first)
+        od = jnp.sum(o * d, axis=0, keepdims=True)
+        o_sq = jnp.sum(o * o, axis=0, keepdims=True)
+        oc_dot_d = dc - od
+        oc_sq = o_sq - 2.0 * oc + csq
+        disc = oc_dot_d * oc_dot_d - (oc_sq - r2)
+        valid = (disc > 0.0) & (r2 > 0.0)
+        sqrt_disc = jnp.sqrt(jnp.maximum(disc, 0.0))
+        t0 = oc_dot_d - sqrt_disc
+        t1 = oc_dot_d + sqrt_disc
+        t_all = jnp.where(t0 > EPS, t0, jnp.where(t1 > EPS, t1, INF))
+        t_all = jnp.where(valid, t_all, INF)
+        t_sphere = jnp.min(t_all, axis=0, keepdims=True)
+        idx = jnp.min(
+            jnp.where(t_all == t_sphere, sphere_iota, n_padded),
+            axis=0, keepdims=True,
+        )
+        idx = jnp.minimum(idx, n_padded - 1)
+        d_y, o_y = d[1:2, :], o[1:2, :]
+        denom = jnp.where(jnp.abs(d_y) < 1e-8, 1e-8, d_y)
+        t_plane = -o_y / denom
+        t_plane = jnp.where(
+            (t_plane > EPS) & (jnp.abs(d_y) >= 1e-8), t_plane, INF
+        )
+        is_plane = (t_plane < t_sphere).astype(jnp.float32)
+        t = jnp.minimum(t_sphere, t_plane)
+        hit = (t < INF).astype(jnp.float32)
+        blend = jnp.clip(d[1:2, :], 0.0, 1.0)
+        sun_cos_dir = jnp.sum(d * sun, axis=0, keepdims=True)
+        sun_disc = jnp.where(sun_cos_dir > 0.9995, 8.0, 0.0)
+        sky = (1.0 - blend) * sky_horizon + blend * sky_zenith
+        sky = sky + sun_disc * sun_color
+        radiance = radiance + throughput * sky * (alive * (1.0 - hit))
+        alive = alive * hit
+        p = o + d * t
+        one_hot = (sphere_iota == idx).astype(jnp.float32)
+        c_hit = dot(c, one_hot, gather)
+        r_hit = jnp.sum(radius * one_hot, axis=0, keepdims=True)
+        albedo_hit = dot(albedo_t, one_hot, gather)
+        emission_hit = dot(emission_t, one_hot, gather)
+        sphere_normal = (p - c_hit) / jnp.maximum(r_hit, 1e-6)
+        plane_normal = jnp.concatenate(
+            [jnp.zeros((1, rays)), jnp.ones((1, rays)), jnp.zeros((1, rays))]
+        ).astype(jnp.float32)
+        normal = is_plane * plane_normal + (1.0 - is_plane) * sphere_normal
+        checker = (
+            jnp.floor(p[0:1, :]).astype(jnp.int32)
+            + jnp.floor(p[2:3, :]).astype(jnp.int32)
+        ) % 2
+        checker_rgb = jnp.where(checker == 0, plane_a, plane_b)
+        albedo = is_plane * checker_rgb + (1.0 - is_plane) * albedo_hit
+        emission = (1.0 - is_plane) * emission_hit
+        radiance = radiance + throughput * emission * alive
+        shadow_o = p + normal * (EPS * 4.0)
+        oc_s = dot(c, shadow_o, contract_first)
+        od_s = jnp.sum(shadow_o * sun, axis=0, keepdims=True)
+        osq_s = jnp.sum(shadow_o * shadow_o, axis=0, keepdims=True)
+        ocd_s = dc_sun - od_s
+        ocsq_s = osq_s - 2.0 * oc_s + csq
+        disc_s = ocd_s * ocd_s - (ocsq_s - r2)
+        valid_s = (disc_s > 0.0) & (r2 > 0.0)
+        t1_s = ocd_s + jnp.sqrt(jnp.maximum(disc_s, 0.0))
+        shadowed = jnp.max(
+            jnp.where(valid_s & (t1_s > EPS), 1.0, 0.0), axis=0, keepdims=True
+        )
+        cos_sun = jnp.maximum(jnp.sum(normal * sun, axis=0, keepdims=True), 0.0)
+        direct = (
+            albedo * sun_color * (cos_sun * (1.0 - shadowed) * alive)
+            / jnp.float32(jnp.pi)
+        )
+        radiance = radiance + throughput * direct
+        throughput = throughput * (alive * albedo + (1.0 - alive))
+        counter = (
+            ray_index * jnp.uint32(2 * max_bounces + 2)
+            + jnp.uint32(2 * bounce)
+        )
+        u1 = pallas_kernels._uniform_from_hash(
+            pallas_kernels._pcg_hash(counter ^ seed)
+        )
+        u2 = pallas_kernels._uniform_from_hash(
+            pallas_kernels._pcg_hash((counter + jnp.uint32(1)) ^ seed)
+        )
+        r = jnp.sqrt(u1)
+        phi = jnp.float32(2.0 * jnp.pi) * u2
+        x, y = r * jnp.cos(phi), r * jnp.sin(phi)
+        z = jnp.sqrt(jnp.maximum(0.0, 1.0 - u1))
+        helper_x = jnp.where(jnp.abs(normal[0:1, :]) > 0.9, 0.0, 1.0)
+        helper_y = 1.0 - helper_x
+        tangent = jnp.concatenate([
+            helper_y * normal[2:3, :],
+            -helper_x * normal[2:3, :],
+            helper_x * normal[1:2, :] - helper_y * normal[0:1, :],
+        ])
+        tangent = tangent / jnp.maximum(
+            jnp.sqrt(jnp.sum(tangent * tangent, axis=0, keepdims=True)), 1e-8
+        )
+        bitangent = jnp.concatenate([
+            normal[1:2, :] * tangent[2:3, :] - normal[2:3, :] * tangent[1:2, :],
+            normal[2:3, :] * tangent[0:1, :] - normal[0:1, :] * tangent[2:3, :],
+            normal[0:1, :] * tangent[1:2, :] - normal[1:2, :] * tangent[0:1, :],
+        ])
+        new_d = x * tangent + y * bitangent + z * normal
+        new_o = p + normal * (EPS * 4.0)
+        live = alive > 0.5
+        o = jnp.where(live, new_o, o)
+        d = jnp.where(live, new_d, d)
+    return radiance.T
+
+
+def _frame_rays(size=64, samples=2, frame=1):
+    from tpu_render_cluster.render.integrator import (
+        flat_sample_rays, tile_base_key, tile_trace_key, trace_seed,
+    )
+
+    base_key = tile_base_key(jnp.int32(frame), 0, 0)
+    origins, directions = flat_sample_rays(
+        scene_camera("04_very-simple", frame), base_key, width=size,
+        height=size, y0=0, x0=0, tile_height=size, tile_width=size,
+        samples=samples,
+    )
+    return origins, directions, trace_seed(tile_trace_key(base_key))
+
+
+def test_megakernel_matches_parent_formulation():
+    """4 bounces of 64x64x2spp of 04_very-simple: the one-matmul gather
+    and the carried c . o against six `_dot_f32` contractions a bounce.
+    Neither changes a value, so every path takes the same turns and what
+    is left is the compiler's rounding (2.4e-7 at most)."""
+    scene = build_scene("04_very-simple", 1)
+    origins, directions, seed = _frame_rays()
+    want = np.asarray(
+        jax.jit(_parent_formulation, static_argnames="max_bounces")(
+            scene, origins, directions, seed, max_bounces=4
+        )
+    )
+    got = np.asarray(
+        trace_paths_fused(scene, origins, directions, seed, max_bounces=4)
+    )
+    assert got.shape == want.shape == (64 * 64 * 2, 3)
+    assert want.max() > 0.1  # a picture, not a black frame
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_megakernel_lane_rows_match_whole_frame_bit_for_bit():
+    """The `lane_io` form on a cropped region, fed the full frame's lane
+    ids, computes the whole-frame form's values on those lanes."""
+    scene = build_scene("04_very-simple", 1)
+    origins, directions, seed = _frame_rays()
+    whole = np.asarray(
+        trace_paths_fused(scene, origins, directions, seed, max_bounces=4)
+    )
+    size, samples = 64, 2
+    ys, xs = np.meshgrid(np.arange(17, 49), np.arange(9, 41), indexing="ij")
+    pixels = (ys * size + xs).reshape(-1)
+    lanes = (np.arange(samples)[:, None] * size * size + pixels[None, :]).reshape(-1)
+    region = np.asarray(
+        trace_paths_fused(
+            scene, origins[lanes], directions[lanes], seed, max_bounces=4,
+            lane=jnp.asarray(lanes, jnp.int32),
+        )
+    )
+    np.testing.assert_array_equal(
+        region.view(np.uint32), whole[lanes].view(np.uint32)
+    )
+
+
+@pytest.mark.parametrize("fate", ["all_miss", "die_at_bounce_1"])
+def test_megakernel_dead_lanes_stay_finite(fate):
+    """The carried c . o of a lane that has left the scene stays finite:
+    a NaN there reaches `cos_sun` through `p`, and NaN * 0 is NaN."""
+    scene = build_scene("04_very-simple", 1)
+    n = 256
+    if fate == "all_miss":
+        origin, direction = [0.0, 50.0, 0.0], [0.0, 1.0, 0.0]
+    else:
+        # Straight down onto the plane a kilometre from the spheres: the
+        # resampled direction points up and nothing is above.
+        origin, direction = [1000.0, 50.0, 1000.0], [0.0, -1.0, 0.0]
+    origins = jnp.tile(jnp.asarray([origin], jnp.float32), (n, 1))
+    origins = origins + jnp.arange(n, dtype=jnp.float32)[:, None] * jnp.asarray(
+        [[0.25, 0.0, 0.125]], jnp.float32
+    )
+    directions = jnp.tile(jnp.asarray([direction], jnp.float32), (n, 1))
+    for lane in (None, jnp.arange(n, dtype=jnp.int32) * 7):
+        out = np.asarray(
+            trace_paths_fused(scene, origins, directions, 11, max_bounces=4, lane=lane)
+        )
+        assert np.isfinite(out).all()
+        assert (out > 0.0).all()  # sky, or sunlit ground then sky
+    if fate == "die_at_bounce_1":
+        # one bounce sees the ground alone; four add the sky above it
+        first = np.asarray(
+            trace_paths_fused(scene, origins, directions, 11, max_bounces=1)
+        )
+        assert (out.sum(axis=1) > first.sum(axis=1)).all()

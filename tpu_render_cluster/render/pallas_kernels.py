@@ -15,8 +15,18 @@ Layout choices (see /opt/skills/guides/pallas_guide.md):
   reduction producing [1, BLOCK_R];
 - sphere data ([3, N] centers, [N, 1] radius^2 / |c|^2) is small enough to
   sit whole in VMEM for every grid step;
-- the two contractions (d.c and o.c) are K=3 dot_generals on the MXU at
-  full f32 precision (``_dot_f32``).
+- every in-kernel contraction is exact in float32. The K=3 ones (d.c,
+  o.c) are dot_generals at full f32 precision (``_dot_f32``: six bf16
+  MXU passes, both operands split on the VPU), and so are the one-hot
+  gathers of the per-bounce and mesh kernels. The sphere megakernel
+  (``_trace_kernel_factory``) pays only the passes its operands need: a
+  hit's centre, radius, albedo and emission come from ONE single-pass
+  bf16 matmul of a one-hot, which is 0.0 / 1.0 and so exact in bf16,
+  against the tables' three exact bf16 parts (``_gather_hit``), and o.c
+  is carried from the shadow origin to the next bounce instead of being
+  made twice. Its two K=3 contractions a bounce stay six-pass: as three
+  broadcast multiply-adds on the VPU both together read slower on the
+  chip (21.6 against 16.9 ms a 512x512x8 frame; PERF.md, PR 38).
 
 On non-TPU backends the kernel runs in interpret mode, so the same code
 path is exercised by CPU tests.
@@ -94,6 +104,14 @@ def _dot_f32(a, b, dimension_numbers):
     and on the centers the one-hot gathers read back, and chip frames
     disagreed with interpret frames on half their pixels. HIGHEST is
     Mosaic's fp32 contract precision; elsewhere it changes nothing.
+
+    It is six bf16 passes with BOTH operands split into three bf16
+    arrays on the VPU, which is what two arbitrary float32 operands
+    need. An operand that is exact in bfloat16 needs none of it: the
+    sphere megakernel's one-hot gathers are one default-precision pass
+    against tables split once outside the loop (``_gather_hit``). Its
+    K=3 contractions, the per-bounce sphere kernels and the mesh kernels
+    (their sphere pass and one-hot gathers included) use this function.
     """
     return jax.lax.dot_general(
         a, b, dimension_numbers, precision=jax.lax.Precision.HIGHEST,
@@ -527,6 +545,70 @@ def _uniform_from_hash(h):
     return word.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
 
 
+# Rows of one part of the megakernel's gather table (one bf16 sublane
+# tile): centre 0:3, albedo 3:6, emission 6:9, radius 9, zeros above.
+_GATHER_ROWS = 16
+
+
+def _bf16_parts(x):
+    """Three bfloat16 arrays whose float32 sum ``(hi + mid) + lo`` is ``x``
+    bit for bit, for normal float32 ``x``: the top 8, next 8 and last 8
+    bits of the significand. Cut with an integer mask, not by rounding
+    conversions, so nothing XLA may do to a float32 -> bfloat16 ->
+    float32 round trip can empty ``mid`` and ``lo``; each part is already
+    a bfloat16 value when it is converted."""
+
+    def top8(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32
+        )
+
+    hi = top8(x)
+    rest = x - hi  # exact: at most 16 significant bits are left
+    mid = top8(rest)
+    lo = rest - mid  # exact, at most 8 bits
+    return tuple(part.astype(jnp.bfloat16) for part in (hi, mid, lo))
+
+
+def _gather_table(centers, albedo, emission, radii, padded_n):
+    """[3 * _GATHER_ROWS, padded_n] bfloat16: the ``hi``, ``mid`` and
+    ``lo`` parts (``_bf16_parts``) of the per-sphere values a hit reads —
+    centre, albedo, emission ([N, 3] each) and radius ([N]) — as rows, one
+    sublane tile a part, so ``_gather_hit`` fetches all ten with one
+    matmul. Spheres N..padded_n are zeros."""
+    rows = jnp.concatenate(
+        [centers, albedo, emission, radii[:, None]], axis=1
+    ).T  # [10, N]
+    rows = jnp.pad(
+        rows,
+        ((0, _GATHER_ROWS - rows.shape[0]), (0, padded_n - rows.shape[1])),
+    )
+    return jnp.concatenate(_bf16_parts(rows), axis=0)
+
+
+def _gather_hit(table, sphere_iota, idx):
+    """Column ``idx`` of the float32 rows behind ``table``
+    (``_gather_table``), bit for bit, for every lane: ``(c_hit [3, BR],
+    albedo_hit [3, BR], emission_hit [3, BR], r_hit [1, BR])``.
+
+    A one-hot is 0.0 / 1.0, exact in bfloat16, so ONE bf16 MXU pass with
+    float32 accumulation returns each part of each row unchanged (a
+    product by 1.0 and sums with zeros), and ``(hi + mid) + lo`` is the
+    float32 value: no float32 array is rounded. At ``Precision.HIGHEST``
+    the same gather was six passes a table with the ``[N, BR]`` one-hot
+    split into three bf16 arrays on the VPU each time, four of them by
+    zeros."""
+    one_hot = jnp.where(sphere_iota == idx, 1.0, 0.0).astype(jnp.bfloat16)
+    parts = jax.lax.dot_general(
+        table, one_hot, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [3 * _GATHER_ROWS, BR]
+    g = _GATHER_ROWS
+    rows = (parts[0:g] + parts[g:2 * g]) + parts[2 * g:3 * g]
+    return rows[0:3], rows[3:6], rows[6:9], rows[9:10]
+
+
 def _trace_kernel_factory(
     max_bounces: int, n_padded: int, lane_io: bool = False,
 ):
@@ -543,20 +625,16 @@ def _trace_kernel_factory(
             # whole-frame megakernel (same kernel, same loop — only the
             # RNG counter's source differs).
             (seed_ref, o_ref, d_ref, lane_ref, c_ref, r2_ref, csq_ref,
-             rad_ref, albedo_ref, emission_ref, dcsun_ref, params_ref,
-             out_ref) = refs
+             table_ref, dcsun_ref, params_ref, out_ref) = refs
         else:
-            (seed_ref, o_ref, d_ref, c_ref, r2_ref, csq_ref, rad_ref,
-             albedo_ref, emission_ref, dcsun_ref, params_ref,
-             out_ref) = refs
+            (seed_ref, o_ref, d_ref, c_ref, r2_ref, csq_ref, table_ref,
+             dcsun_ref, params_ref, out_ref) = refs
         o = o_ref[:, :]  # [3, BR] ray origins
         d = d_ref[:, :]  # [3, BR] ray directions
         c = c_ref[:, :]  # [3, N] sphere centers
         r2 = r2_ref[:, :]  # [N, 1] radius^2 (0 for padding -> never hits)
         csq = csq_ref[:, :]  # [N, 1] |c|^2
-        radius = rad_ref[:, :]  # [N, 1]
-        albedo_t = albedo_ref[:, :]  # [3, N]
-        emission_t = emission_ref[:, :]  # [3, N]
+        table = table_ref[:, :]  # [48, N] bf16, _gather_table
         dc_sun = dcsun_ref[:, :]  # [N, 1] c . sun
         # params rows: 0 sun_dir, 1 sun_color, 2 sky_horizon, 3 sky_zenith,
         # 4 plane_albedo_a, 5 plane_albedo_b   (each [1, 3] -> column vecs)
@@ -588,12 +666,13 @@ def _trace_kernel_factory(
         alive = jnp.ones((1, block), jnp.float32)
 
         def bounce_step(bounce, carry):
-            o, d, throughput, radiance, alive = carry
+            # oc = c . o and o_sq = |o|^2 ride the carry: this bounce's
+            # origin is the last bounce's shadow origin, whose products
+            # the sun test already made.
+            o, d, throughput, radiance, alive, oc, o_sq = carry
             # -- nearest sphere hit (same math as _nearest_hit_kernel) ----
             dc = _dot_f32(c, d, contract_first)
-            oc = _dot_f32(c, o, contract_first)
             od = jnp.sum(o * d, axis=0, keepdims=True)
-            o_sq = jnp.sum(o * o, axis=0, keepdims=True)
             oc_dot_d = dc - od
             oc_sq = o_sq - 2.0 * oc + csq
             disc = oc_dot_d * oc_dot_d - (oc_sq - r2)
@@ -632,15 +711,13 @@ def _trace_kernel_factory(
             radiance = radiance + throughput * sky * (alive * (1.0 - hit))
 
             alive = alive * hit
+            live = alive > 0.5
             p = o + d * t  # [3, BR]
 
-            # -- gathers as one-hot matmuls (N is small, MXU-friendly) ----
-            one_hot = (sphere_iota == idx).astype(jnp.float32)  # [N, BR]
-            gather = (((1,), (0,)), ((), ()))
-            c_hit = _dot_f32(c, one_hot, gather)  # [3, BR]
-            r_hit = jnp.sum(radius * one_hot, axis=0, keepdims=True)  # [1, BR]
-            albedo_hit = _dot_f32(albedo_t, one_hot, gather)
-            emission_hit = _dot_f32(emission_t, one_hot, gather)
+            # -- the hit sphere's rows: one one-hot matmul, exact ---------
+            c_hit, albedo_hit, emission_hit, r_hit = _gather_hit(
+                table, sphere_iota, idx
+            )
 
             sphere_normal = (p - c_hit) / jnp.maximum(r_hit, 1e-6)
             plane_normal = jnp.concatenate(
@@ -663,7 +740,11 @@ def _trace_kernel_factory(
             radiance = radiance + throughput * emission * alive
 
             # -- sun NEE: one any-hit shadow dot (sun dir is uniform) -----
-            shadow_o = p + normal * (EPS * 4.0)
+            # The shadow origin is the next bounce's origin. where-select
+            # (not multiply-mask): a dead lane keeps its old finite one,
+            # and with it finite oc_s / osq_s in the carry, so no inf*0
+            # can poison later bounces (its shadow test is masked below).
+            shadow_o = jnp.where(live, p + normal * (EPS * 4.0), o)
             oc_s = _dot_f32(c, shadow_o, contract_first)
             od_s = jnp.sum(shadow_o * sun, axis=0, keepdims=True)
             osq_s = jnp.sum(shadow_o * shadow_o, axis=0, keepdims=True)
@@ -710,18 +791,17 @@ def _trace_kernel_factory(
             bz = normal[0:1, :] * tangent[1:2, :] - normal[1:2, :] * tangent[0:1, :]
             bitangent = jnp.concatenate([bx, by, bz], axis=0)
             new_d = x * tangent + y * bitangent + z * normal
-            new_o = p + normal * (EPS * 4.0)
-            # where-select (not multiply-mask): dead lanes keep their old
-            # finite state, so no inf*0 can poison later bounces.
-            live = alive > 0.5
-            o = jnp.where(live, new_o, o)
-            d = jnp.where(live, new_d, d)
-            return (o, d, throughput, radiance, alive)
+            d = jnp.where(live, new_d, d)  # dead lanes stay finite, as o
+            return (shadow_o, d, throughput, radiance, alive, oc_s, osq_s)
 
-        _, _, _, radiance, _ = jax.lax.fori_loop(
+        radiance = jax.lax.fori_loop(
             0, max_bounces, bounce_step,
-            (o, d, throughput, radiance, alive),
-        )
+            (
+                o, d, throughput, radiance, alive,
+                _dot_f32(c, o, contract_first),
+                jnp.sum(o * o, axis=0, keepdims=True),
+            ),
+        )[3]
         out_ref[:, :] = radiance
 
     return kernel
@@ -752,9 +832,7 @@ def _trace_fused(
     radii_p = jnp.pad(radii, (0, sphere_pad))
     r2 = (radii_p * radii_p)[:, None]
     csq = jnp.sum(c_t * c_t, axis=0)[:, None]
-    rad = radii_p[:, None]
-    albedo_t = jnp.pad(albedo, ((0, sphere_pad), (0, 0))).T
-    emission_t = jnp.pad(emission, ((0, sphere_pad), (0, 0))).T
+    table = _gather_table(centers, albedo, emission, radii, padded_n)
     dc_sun = _center_dot_sun(c_t, sun_direction)  # [Np, 1]
 
     params = jnp.zeros((8, 3), jnp.float32)
@@ -778,16 +856,14 @@ def _trace_fused(
         pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
         pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
         pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-        pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
-        pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
-        pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
+        pl.BlockSpec(table.shape, whole, memory_space=pltpu.VMEM),
         pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
         pl.BlockSpec((8, 3), whole, memory_space=pltpu.VMEM),
     ]
     operands = [seed_arr, o_t, d_t]
     if lane_t is not None:
         operands.append(lane_t)
-    operands += [c_t, r2, csq, rad, albedo_t, emission_t, dc_sun, params]
+    operands += [c_t, r2, csq, table, dc_sun, params]
     out = pl.pallas_call(
         _trace_kernel_factory(max_bounces, padded_n, lane_io=lane_t is not None),
         grid=grid,
