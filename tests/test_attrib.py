@@ -415,7 +415,13 @@ def test_midjob_scrapes_and_attribution_acceptance(monkeypatch):
                     _fetch, manager.telemetry.port, "/metrics"
                 )
             )
-            if all(name in parsed for name in wanted):
+            # a job's first dispatch waits for the workers' ready events (a
+            # tick after its admission): scrape once one has gone out
+            dispatched = any(
+                labels.get("tag") == "request_frame-queue_add"
+                for labels, _value in parsed.get("transport_message_bytes_total", ())
+            )
+            if dispatched and all(name in parsed for name in wanted):
                 scraped["master"] = parsed
                 break
             assert time.monotonic() < deadline, (

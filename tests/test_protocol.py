@@ -81,13 +81,14 @@ EXPECTED_WIRE_TAGS = {
     pm.ReplicationRecordEvent: "event_replication-record",
     pm.ReplicationAckEvent: "event_replication-ack",
     pm.MasterWorkerMigrateEvent: "event_worker-migrate",
+    pm.WorkerJobReadyEvent: "event_job-ready",
 }
 
 
 def test_all_wire_tags_exact():
     # The reference's 14 messages plus the goodbye drain extension, the
-    # four replication messages, and the migrate event.
-    assert len(pm.ALL_MESSAGE_TYPES) == 20
+    # four replication messages, the migrate event and the job-ready event.
+    assert len(pm.ALL_MESSAGE_TYPES) == 21
     for cls, tag in EXPECTED_WIRE_TAGS.items():
         assert cls.type_name == tag
 
@@ -119,6 +120,10 @@ def all_example_messages() -> list[pm.Message]:
             reason="drain", job_name=job.job_name, returned_frames=(3, 4, 9)
         ),
         pm.MasterJobStartedEvent(),
+        pm.MasterJobStartedEvent(trace_id=3, job_id="job-0001", job=job),
+        pm.WorkerJobReadyEvent(job.job_name),
+        pm.WorkerJobReadyEvent(job.job_name, job_id="job-0001"),
+        pm.WorkerHandshakeResponse("first-connection", "1.0.0", 9, prepares_jobs=True),
         pm.MasterJobFinishedRequest(99),
         pm.WorkerJobFinishedResponse(99, make_trace()),
         pm.ReplicationAttachRequest(7, last_seq=0),

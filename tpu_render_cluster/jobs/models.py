@@ -126,6 +126,70 @@ class JobSlo:
         )
 
 
+RENDER_SHAPE_KEYS = ("width", "height", "samples", "max_bounces")
+
+
+@dataclass(frozen=True)
+class JobRender:
+    """A job's render shape (new; absent from reference TOMLs, which keep
+    theirs inside the ``.blend``).
+
+    Declared in the job TOML as a ``[render]`` table of ``width``,
+    ``height``, ``samples`` and ``max_bounces``; a key left out, like the
+    whole table, leaves that size to the worker's own flags. Honoured by
+    the ``tpu-raytrace`` backend, frame by frame: two jobs of different
+    shapes share one worker.
+    """
+
+    width: int | None = None
+    height: int | None = None
+    samples: int | None = None
+    max_bounces: int | None = None
+
+    def __post_init__(self) -> None:
+        problems = []
+        for name in RENDER_SHAPE_KEYS:
+            value = getattr(self, name)
+            # bool is an int subclass: `samples = true` must be an error.
+            if value is not None and (
+                isinstance(value, bool) or not isinstance(value, int) or value < 1
+            ):
+                problems.append(
+                    f"render.{name} must be a positive integer, got {value!r}"
+                )
+        if all(getattr(self, name) is None for name in RENDER_SHAPE_KEYS):
+            problems.append(
+                "[render] table states no size (set any of "
+                + ", ".join(RENDER_SHAPE_KEYS) + ")"
+            )
+        if problems:
+            raise ValueError("; ".join(problems))
+
+    def shape(self, defaults: tuple[int, int, int, int]) -> tuple[int, int, int, int]:
+        """(width, height, samples, max_bounces), ``defaults`` where this
+        table is silent."""
+        return tuple(
+            default if getattr(self, name) is None else getattr(self, name)
+            for name, default in zip(RENDER_SHAPE_KEYS, defaults)
+        )
+
+    def to_dict(self) -> dict[str, Any]:
+        return {
+            name: getattr(self, name)
+            for name in RENDER_SHAPE_KEYS
+            if getattr(self, name) is not None
+        }
+
+    @classmethod
+    def from_dict(cls, data: dict[str, Any]) -> "JobRender":
+        if not isinstance(data, dict):
+            raise ValueError(f"render must be a table, got {data!r}")
+        unknown = set(data) - set(RENDER_SHAPE_KEYS)
+        if unknown:
+            raise ValueError(f"unknown render key(s): {sorted(unknown)}")
+        return cls(**{name: data.get(name) for name in RENDER_SHAPE_KEYS})
+
+
 STRATEGY_NAIVE_FINE = "naive-fine"
 STRATEGY_EAGER_NAIVE_COARSE = "eager-naive-coarse"
 STRATEGY_DYNAMIC = "dynamic"
@@ -262,6 +326,10 @@ class BlenderJob:
     # Master-side only — workers ignore it; absent = no SLO tracking and
     # reference-identical serialization.
     slo: JobSlo | None = None
+    # New (optional): the job's render shape ([render] TOML table). None,
+    # or a key the table leaves out, leaves the size to the worker's own
+    # flags; absent = reference-identical serialization.
+    render: JobRender | None = None
 
     def __post_init__(self) -> None:
         """Reject structurally-broken jobs at load time, not mid-dispatch.
@@ -324,6 +392,12 @@ class BlenderJob:
             except ValueError as e:
                 problems.append(str(e))
                 object.__setattr__(self, "slo", None)
+        if self.render is not None and not isinstance(self.render, JobRender):
+            try:
+                object.__setattr__(self, "render", JobRender.from_dict(self.render))
+            except ValueError as e:
+                problems.append(str(e))
+                object.__setattr__(self, "render", None)
         if problems:
             raise ValueError(
                 f"Invalid job {self.job_name!r}: " + "; ".join(problems)
@@ -379,6 +453,8 @@ class BlenderJob:
             out["tiles"] = list(self.tile_grid)
         if self.slo is not None:
             out["slo"] = self.slo.to_dict()
+        if self.render is not None:
+            out["render"] = self.render.to_dict()
         return out
 
     @classmethod
@@ -403,6 +479,7 @@ class BlenderJob:
             # instead of a bare int() traceback here.
             tile_grid=data.get("tiles"),
             slo=data.get("slo"),
+            render=data.get("render"),
         )
 
     @classmethod

@@ -309,8 +309,12 @@ class ClusterManager:
         """
         return self.state
 
-    def _active_job_announcements(self) -> list[tuple[int | None, str | None]]:
-        """(trace_id, job_id) per job a late-joining worker must learn of.
+    def _active_job_announcements(
+        self,
+    ) -> list[tuple[int | None, str | None, BlenderJob | None]]:
+        """(trace_id, job_id, job) per job a late-joining worker must learn
+        of; the job itself only from the scheduler service, whose workers
+        prepare a job when it is announced.
 
         Resolves the inherited reference FIXME (master/src/cluster/mod.rs:
         616-617): a worker whose handshake completes after job start still
@@ -318,7 +322,7 @@ class ClusterManager:
         job so it holds with several jobs running concurrently.
         """
         if self._job_started and self.state is not None:
-            return [(self.state.trace_id, None)]
+            return [(self.state.trace_id, None, None)]
         return []
 
     # -- public ------------------------------------------------------------
@@ -587,7 +591,9 @@ class ClusterManager:
             await ws.send_text(
                 self._wire.encode(pm.MasterHandshakeAcknowledgement(True))
             )
-            await self._register_new_worker(response.worker_id, ws)
+            await self._register_new_worker(
+                response.worker_id, ws, prepares_jobs=response.prepares_jobs
+            )
         elif response.handshake_type == pm.HANDSHAKE_TYPE_RECONNECTING:
             known = response.worker_id in self.workers
             await ws.send_text(
@@ -620,7 +626,9 @@ class ClusterManager:
                 f"Unknown handshake type: {response.handshake_type!r}"
             )
 
-    async def _register_new_worker(self, worker_id: int, ws: WebSocketConnection) -> None:
+    async def _register_new_worker(
+        self, worker_id: int, ws: WebSocketConnection, *, prepares_jobs: bool = False
+    ) -> None:
         if worker_id in self.workers:
             logger.warning(
                 "Worker id collision (%08x); refusing duplicate.", worker_id
@@ -649,6 +657,7 @@ class ClusterManager:
             on_unit_latency=self.slo.observe_unit_latency,
             on_protocol_event=self._on_worker_protocol_event,
             epoch=self.epoch,
+            prepares_jobs=prepares_jobs,
         )
         self.workers[worker_id] = worker
         worker.start()
@@ -662,8 +671,8 @@ class ClusterManager:
         # Late joiners still learn which jobs have started (reference FIXME
         # at master/src/cluster/mod.rs:616-617) — replayed for EVERY active
         # job, which becomes load-bearing once several run concurrently.
-        for trace_id, job_id in self._active_job_announcements():
-            await worker.send_job_started(trace_id=trace_id, job_id=job_id)
+        for trace_id, job_id, job in self._active_job_announcements():
+            await worker.send_job_started(trace_id=trace_id, job_id=job_id, job=job)
 
     async def _evict_worker(self, worker: WorkerHandle, reason: str) -> None:
         """Return a dead worker's units to the pool so its jobs can finish."""

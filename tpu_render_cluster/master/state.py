@@ -20,6 +20,7 @@ event) fires exactly once — when the last tile lands. Whole-frame jobs
 from __future__ import annotations
 
 import enum
+import time
 from collections import deque
 from dataclasses import dataclass
 
@@ -121,6 +122,10 @@ class ClusterManagerState:
         # and ledger replay all funnel through these transitions, so no
         # event source needs separate instrumentation.
         self.version: int = 0
+        # When the first unit was handed to a worker, and when the newest
+        # result was taken (the scheduler's per-job phases).
+        self.first_queued_at: float | None = None
+        self.last_finished_at: float | None = None
         # O(1) mirrors of the status population. ``_pending_live`` counts
         # frames whose STATUS is PENDING (the deque may briefly hold
         # stale or duplicate entries; status is the truth);
@@ -291,6 +296,8 @@ class ClusterManagerState:
         record.status = FrameStatus.QUEUED_ON_WORKER
         record.worker_id = worker_id
         record.queued_at = queued_at
+        if self.first_queued_at is None:
+            self.first_queued_at = queued_at
         if stolen_from is not None:
             record.stolen_from = stolen_from
             record.stolen_at = stolen_at
@@ -323,6 +330,7 @@ class ClusterManagerState:
         record.status = FrameStatus.FINISHED
         self._retrack(record, old)
         self._finished_count += 1
+        self.last_finished_at = time.time()
         if self.on_unit_finished is not None:
             self.on_unit_finished(unit)
         if self._tiles_per_frame == 1:

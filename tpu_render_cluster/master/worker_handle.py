@@ -98,6 +98,11 @@ class WorkerHandle:
     epoch: int | None = None
     _shutdown_started = False
     _on_protocol_event = None
+    # The worker's handshake said it answers announced jobs with
+    # event_job-ready; ``ready_jobs`` holds the (job_name, job_id) pairs it
+    # has reported. A worker that did not say so is ready for every job.
+    prepares_jobs = False
+    ready_jobs: frozenset | set = frozenset()
 
     def __init__(
         self,
@@ -117,8 +122,11 @@ class WorkerHandle:
         | None = None,
         on_protocol_event: Callable[[str, dict], None] | None = None,
         epoch: int | None = None,
+        prepares_jobs: bool = False,
     ) -> None:
         self.worker_id = worker_id
+        self.prepares_jobs = prepares_jobs
+        self.ready_jobs = set()
         self.connection = connection
         # Master incarnation epoch (ha/ledger.py; None without a ledger):
         # stamped on every queue-add and checked against the epoch echoed
@@ -638,17 +646,27 @@ class WorkerHandle:
     # -- job lifecycle RPCs --------------------------------------------------
 
     async def send_job_started(
-        self, *, trace_id: int | None = None, job_id: str | None = None
+        self,
+        *,
+        trace_id: int | None = None,
+        job_id: str | None = None,
+        job: BlenderJob | None = None,
     ) -> None:
         """Announce a job start. Single-job callers pass nothing (the one
         state's trace id is used); the multi-job scheduler passes each
-        admitted job's (trace_id, job_id) — including replays to late
-        joiners, one event per active job."""
+        admitted job's (trace_id, job_id) and the job itself — including
+        replays to late joiners, one event per active job — so that a
+        worker can prepare the job and report it ready."""
         if trace_id is None and self.state is not None:
             trace_id = self.state.trace_id
         await self.sender.send_message(
-            pm.MasterJobStartedEvent(trace_id=trace_id, job_id=job_id)
+            pm.MasterJobStartedEvent(trace_id=trace_id, job_id=job_id, job=job)
         )
+
+    def is_ready_for(self, job_name: str, job_id: str | None) -> bool:
+        """Whether frames of the job may be handed to this worker: it has
+        reported the job ready, or never said it reports."""
+        return not self.prepares_jobs or (job_name, job_id) in self.ready_jobs
 
     async def send_migrate(
         self, host: str, port: int, *, reason: str | None = None
@@ -1172,6 +1190,17 @@ class WorkerHandle:
         rendering_queue = self.router.subscribe(pm.WorkerFrameQueueItemRenderingEvent)
         finished_queue = self.router.subscribe(pm.WorkerFrameQueueItemFinishedEvent)
         goodbye_queue = self.router.subscribe(pm.WorkerGoodbyeEvent)
+        ready_queue = self.router.subscribe(pm.WorkerJobReadyEvent)
+
+        async def handle_ready() -> None:
+            while True:
+                event = await ready_queue.get()
+                self.ready_jobs.add((event.job_name, event.job_id))
+                state = self._state_for(event.job_name)
+                if state is not None:
+                    # the job's dispatchable demand changed: the scheduler
+                    # resyncs it on its next tick
+                    state.version += 1
 
         async def handle_rendering() -> None:
             while True:
@@ -1191,6 +1220,7 @@ class WorkerHandle:
             asyncio.ensure_future(handle_rendering()),
             asyncio.ensure_future(handle_finished()),
             asyncio.ensure_future(handle_goodbye()),
+            asyncio.ensure_future(handle_ready()),
         ]
         try:
             await asyncio.gather(*tasks)

@@ -25,8 +25,15 @@ last; a stage never entered reads 0.
                    waits for every worker, starts the job, assigns
     first_frame    -> the first frame's file is renamed into place
 
-Without ``--warmScene`` the three middle stages stay empty and their cost
-lies in ``first_frame``, where such a worker pays it.
+Without ``--warmScene`` the three middle stages stay empty, unless a job
+is prepared when it is announced (a scheduler service sends the job with
+``event_job-started``): what the preparations that ended before the first
+frame was queued spent on geometry, the program and its first execute is
+CREDITED to the three (``credit``) and taken out of ``await_job``, inside
+which it was spent, so the eight still add up; on the timeline
+``await_job`` stays one whole span with the ``job_prepare`` spans inside
+it. A worker whose master announces no job pays all of it in
+``first_frame``.
 
 The recorder is process-scoped like ``get_registry()`` (where several
 workers share a process, as in the in-process harness, the first to reach
@@ -119,6 +126,8 @@ class StartupRecorder:
         self._since = process_start
         self._cpu_since = 0.0
         self._seconds: dict[str, float] = {}
+        # seconds of the open stage that credit() gave to earlier ones
+        self._credited = 0.0
         self._buffer: list[dict[str, Any]] = []
         self._tracer = None
         self._gauge = None
@@ -155,13 +164,34 @@ class StartupRecorder:
             for skipped in STARTUP_STAGES[self._open + 1 : index]:
                 self._close(skipped, now, now, 0.0)
             self._open, self._since, self._cpu_since = index, now, cpu
+            self._credited = 0.0
+            return True
+
+    def credit(self, stage: str, seconds: float) -> bool:
+        """Give ``seconds`` of the open stage to ``stage``, a closed one
+        before it: work of that stage's kind done late (a job prepared
+        while its first frame is awaited). The closed stage's gauge rises
+        and the open stage will read that much less when it closes, so the
+        stages stay exclusive and add up; the spans are not moved. Refused
+        (False) once the first frame is queued: from then on a preparation
+        is another job's, beside frames that are landing."""
+        index = STARTUP_STAGES.index(stage)
+        with self._lock:
+            if index >= self._open or self._open >= STARTUP_STAGES.index("first_frame"):
+                return False
+            seconds = max(0.0, seconds)
+            self._credited += seconds
+            self._seconds[stage] = self._seconds.get(stage, 0.0) + seconds
+            if self._gauge is not None:
+                self._gauge.set(self._seconds[stage], stage=stage)
             return True
 
     def _close(self, stage: str, start: float, end: float, cpu_s: float) -> None:
         seconds = max(0.0, end - start)
-        self._seconds[stage] = seconds
+        # the gauge is exclusive: less what was credited to earlier stages
+        self._seconds[stage] = max(0.0, seconds - self._credited)
         if self._gauge is not None:
-            self._gauge.set(seconds, stage=stage)
+            self._gauge.set(self._seconds[stage], stage=stage)
         self.span(
             stage, cat="worker.startup", start_wall=start, duration=seconds,
             args={"cpu_s": round(cpu_s, 6)},

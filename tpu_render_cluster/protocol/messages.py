@@ -234,13 +234,22 @@ class WorkerHandshakeResponse(Message):
     handshake_type: str  # "first-connection" | "reconnecting"
     worker_version: str
     worker_id: int
+    # Optional: this worker answers every ``event_job-started`` that
+    # carries a ``job`` with an ``event_job-ready`` once what the job needs
+    # is resident, so a scheduler may hold the job's frames back until
+    # then. Absent (the reference's worker, the C++ daemon): the worker is
+    # taken as ready for every job it is told of.
+    prepares_jobs: bool = False
 
     def to_payload(self) -> dict[str, Any]:
-        return {
+        out: dict[str, Any] = {
             "handshake_type": self.handshake_type,
             "worker_version": self.worker_version,
             "worker_id": self.worker_id,
         }
+        if self.prepares_jobs:
+            out["prepares_jobs"] = True
+        return out
 
     @classmethod
     def from_payload(cls, payload: dict[str, Any]) -> "WorkerHandshakeResponse":
@@ -248,6 +257,7 @@ class WorkerHandshakeResponse(Message):
             handshake_type=str(payload["handshake_type"]),
             worker_version=str(payload["worker_version"]),
             worker_id=int(payload["worker_id"]),
+            prepares_jobs=bool(payload.get("prepares_jobs", False)),
         )
 
 
@@ -712,6 +722,12 @@ class MasterJobStartedEvent(Message):
     # Optional scheduler job id (multi-job masters announce one event per
     # ACTIVE job — late joiners get them all replayed at handshake time).
     job_id: str | None = None
+    # Optional: the job itself (its ``[render]`` table included), so that
+    # a worker can make what the job needs resident before the first
+    # frame arrives. The scheduler service sends it; a worker that said
+    # ``prepares_jobs`` answers it with ``event_job-ready``. Single-job
+    # masters never set it: their traffic stays byte-identical.
+    job: BlenderJob | None = None
 
     def to_payload(self) -> dict[str, Any]:
         out: dict[str, Any] = {}
@@ -719,13 +735,50 @@ class MasterJobStartedEvent(Message):
             out["trace_id"] = self.trace_id
         if self.job_id is not None:
             out["job_id"] = self.job_id
+        if self.job is not None:
+            out["job"] = self.job.to_dict()
         return out
 
     @classmethod
     def from_payload(cls, payload: dict[str, Any]) -> "MasterJobStartedEvent":
         trace_id = payload.get("trace_id")
+        job = payload.get("job")
         return cls(
             trace_id=None if trace_id is None else int(trace_id),
+            job_id=_job_id_from_payload(payload),
+            job=None if job is None else BlenderJob.from_dict(job),
+        )
+
+
+@dataclass(frozen=True)
+class WorkerJobReadyEvent(Message):
+    """W→M: what an announced job needs is resident on this worker
+    (beyond-reference; the answer to an ``event_job-started`` that
+    carried a ``job``).
+
+    Sent once per announcement, when the backend's preparation of the job
+    (geometry, program, first execute for ``tpu-raytrace``; nothing for a
+    backend that prepares nothing) has ended — also when it failed: the
+    job's frames then fail one by one through the errored-result path,
+    which is where a master accounts for a job a worker cannot render.
+    The scheduler service hands a ``prepares_jobs`` worker no frame of a
+    job before this event.
+    """
+
+    type_name: ClassVar[str] = "event_job-ready"
+    job_name: str
+    job_id: str | None = None
+
+    def to_payload(self) -> dict[str, Any]:
+        out: dict[str, Any] = {"job_name": self.job_name}
+        if self.job_id is not None:
+            out["job_id"] = self.job_id
+        return out
+
+    @classmethod
+    def from_payload(cls, payload: dict[str, Any]) -> "WorkerJobReadyEvent":
+        return cls(
+            job_name=str(payload["job_name"]),
             job_id=_job_id_from_payload(payload),
         )
 
@@ -998,6 +1051,7 @@ ALL_MESSAGE_TYPES: tuple[type[Message], ...] = (
     MasterHeartbeatRequest,
     WorkerHeartbeatResponse,
     MasterJobStartedEvent,
+    WorkerJobReadyEvent,
     MasterJobFinishedRequest,
     WorkerJobFinishedResponse,
     ReplicationAttachRequest,

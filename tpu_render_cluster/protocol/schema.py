@@ -93,6 +93,7 @@ WIRE_SCHEMAS: dict[str, WireSchema] = {
             "handshake_response",
             "W->M",
             required=("handshake_type", "worker_version", "worker_id"),
+            optional=("prepares_jobs",),
         ),
         WireSchema(
             "handshake_acknowledgement",
@@ -154,7 +155,13 @@ WIRE_SCHEMAS: dict[str, WireSchema] = {
             "event_job-started",
             "M->W",
             required=(),
-            optional=("trace_id", "job_id"),
+            optional=("trace_id", "job_id", "job"),
+        ),
+        WireSchema(
+            "event_job-ready",
+            "W->M",
+            required=("job_name",),
+            optional=("job_id",),
         ),
         WireSchema(
             "request_job-finished",

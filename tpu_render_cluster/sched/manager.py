@@ -186,13 +186,12 @@ class JobManager(ClusterManager):
         run = self._active_by_name.get(job_name)
         return run.spec.job if run is not None else None
 
-    def _active_job_announcements(self) -> list[tuple[int | None, str | None]]:
-        out: list[tuple[int | None, str | None]] = []
-        for job_id in self._running:
-            run = self._runs[job_id]
-            if run.state is not None:
-                out.append((run.state.trace_id, run.job_id))
-        return out
+    def _active_job_announcements(self):
+        return [
+            (run.state.trace_id, run.job_id, run.spec.job)
+            for run in (self._runs[job_id] for job_id in self._running)
+            if run.state is not None
+        ]
 
     def _jobs_view(self) -> dict:
         return {job_id: run.view() for job_id, run in self._runs.items()}
@@ -582,6 +581,7 @@ class JobManager(ClusterManager):
             "sched_admission_wait_seconds",
             "Submit-to-admission wait per job",
         ).observe(max(0.0, now - run.submitted_at))
+        self._observe_job_phase("queued", now - run.submitted_at)
         self.span_tracer.instant(
             "job admitted",
             cat="sched",
@@ -593,7 +593,8 @@ class JobManager(ClusterManager):
         for worker in self.live_workers():
             try:
                 await worker.send_job_started(
-                    trace_id=run.state.trace_id, job_id=run.job_id
+                    trace_id=run.state.trace_id, job_id=run.job_id,
+                    job=run.spec.job,
                 )
             except Exception as e:  # noqa: BLE001 - heartbeat will evict it
                 logger.warning(
@@ -602,9 +603,32 @@ class JobManager(ClusterManager):
 
     # -- completion / cancellation -------------------------------------------
 
+    def _observe_job_phase(self, phase: str, seconds: float) -> None:
+        self.metrics.histogram(
+            "sched_job_phase_seconds",
+            "A job's time in the scheduler's own hands, by phase: queued "
+            "(submit to admission), admit_to_first_dispatch (admission to "
+            "its first unit handed to a worker: the announcement, the "
+            "workers' preparation, a tick), last_result_to_finished (its "
+            "last result taken to the job reported finished)",
+            labels=("phase",),
+        ).observe(max(0.0, seconds), phase=phase)
+
     def _finish_run(self, run: JobRun, status: str, now: float) -> None:
         run.status = status
         run.finished_at = now
+        for worker in self.workers.values():
+            worker.ready_jobs.discard((run.job_name, run.job_id))
+        state = run.state
+        if status == JOB_FINISHED and state is not None and run.admitted_at is not None:
+            if state.first_queued_at is not None:
+                self._observe_job_phase(
+                    "admit_to_first_dispatch", state.first_queued_at - run.admitted_at
+                )
+            if state.last_finished_at is not None:
+                self._observe_job_phase(
+                    "last_result_to_finished", now - state.last_finished_at
+                )
         if self.ledger_appender is not None and run.state is not None:
             # Close the job's ledger lifecycle so a restarted service does
             # not re-admit it (and a later same-name submission starts a
@@ -745,13 +769,39 @@ class JobManager(ClusterManager):
                     weight=run.spec.weight,
                     priority=run.spec.priority,
                     in_flight=run.state.in_flight_count(),
-                    pending=run.state.pending_count(),
+                    pending=self._dispatchable_pending(run),
                     in_flight_cost=(
                         self._in_flight_cost(run) if include_cost else None
                     ),
                 )
             )
         return out
+
+    def _dispatchable_pending(self, run: JobRun) -> int:
+        """The job's pending units, or 0 while no live worker could take
+        one: every worker that prepares announced jobs is still preparing
+        this one. Such a job asks for no slot, so nothing is preempted for
+        it; a worker's ready event bumps the state's version and the next
+        tick resyncs."""
+        assert run.state is not None
+        if any(
+            worker.is_ready_for(run.job_name, run.job_id)
+            for worker in self.live_workers()
+        ):
+            return run.state.pending_count()
+        return 0
+
+    def _pick_for_worker(self, worker: WorkerHandle, job_id: str, inputs_fn) -> str | None:
+        """``job_id`` (the fair pick) if ``worker`` has reported it ready,
+        else the fair pick among the jobs it has (``inputs_fn()``: this
+        moment's share inputs): a worker still preparing one job goes on
+        with the others'."""
+        if worker.is_ready_for(self._runs[job_id].job_name, job_id):
+            return job_id
+        return fair_share.pick_job_to_dispatch([
+            job for job in inputs_fn()
+            if worker.is_ready_for(self._runs[job.job_id].job_name, job.job_id)
+        ])
 
     # -- incremental WFQ (heap/verify tick modes) -----------------------------
 
@@ -791,7 +841,7 @@ class JobManager(ClusterManager):
                 weight=run.spec.weight,
                 priority=run.spec.priority,
                 in_flight=state.in_flight_count(),
-                pending=state.pending_count(),
+                pending=self._dispatchable_pending(run),
                 cost=cost,
                 state_version=state.version,
             )
@@ -959,13 +1009,17 @@ class JobManager(ClusterManager):
                 and len(worker.queue) < self.config.target_queue_size
             ):
                 if mode == "heap":
-                    job_id = self._wfq.pick_dispatch()
+                    job_id, inputs_fn = self._wfq.pick_dispatch(), self._wfq.inputs
                 else:
                     if mode == "verify":
                         self._verify_pick(self._wfq.pick_dispatch(), inputs_now())
                     job_id = fair_share.pick_job_to_dispatch(inputs_now())
+                    inputs_fn = inputs_now
                 if job_id is None:
                     return  # nothing pending anywhere
+                job_id = self._pick_for_worker(worker, job_id, inputs_fn)
+                if job_id is None:
+                    break  # nothing pending that this worker has reported ready
                 run = self._runs[job_id]
                 assert run.state is not None
                 # Price the unit dispatch_one_pending is about to claim
@@ -1019,7 +1073,7 @@ class JobManager(ClusterManager):
             over_id, starved_id = decision
             run = self._runs[over_id]
             assert run.state is not None
-            found = self._find_preemptible_frame(run.job_name)
+            found = self._find_preemptible_frame(run.job_name, self._runs[starved_id])
             if found is None:
                 return  # everything the job holds is already rendering
             victim, frame = found
@@ -1046,13 +1100,16 @@ class JobManager(ClusterManager):
             )
 
     def _find_preemptible_frame(
-        self, job_name: str
+        self, job_name: str, starved: JobRun
     ) -> tuple[WorkerHandle, Any] | None:
         """The job's NEWEST not-yet-rendering mirrored frame (preempting
         the most recently queued wastes the least accumulated wait and is
-        the frame least likely to be picked up mid-RPC)."""
+        the frame least likely to be picked up mid-RPC), on a worker that
+        could take a frame of the ``starved`` job in its place."""
         best: tuple[WorkerHandle, Any] | None = None
         for worker in self.live_workers():
+            if not worker.is_ready_for(starved.job_name, starved.job_id):
+                continue
             for frame in worker.queue.frames_for_job(job_name):
                 if frame.is_rendering:
                     continue

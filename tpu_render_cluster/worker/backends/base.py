@@ -25,6 +25,12 @@ class RenderBackend(abc.ABC):
     silently rendering the whole frame under a tile's name.
     """
 
+    async def prepare_job(self, job: BlenderJob) -> None:
+        """Make resident what the job's frames need, off the render path
+        (called when a scheduler service announces the job; the worker
+        reports the job ready when this returns). Nothing, for a backend
+        whose first frame costs what every frame costs."""
+
     @abc.abstractmethod
     async def render_frame(
         self, job: BlenderJob, frame_index: int, tile: int | None = None

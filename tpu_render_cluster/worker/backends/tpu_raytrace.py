@@ -12,11 +12,22 @@ backends apart (BASELINE.md north star). Phase mapping:
 
 The heavy work runs in a thread (`asyncio.to_thread`) so heartbeats and
 queue RPCs stay responsive while a frame renders.
+
+A frame's program is picked per JOB: ``(scene family, width, height,
+samples, max_bounces)``, the shape from the job's ``[render]`` table over
+the worker's flags (``program_key``). What a key needs (geometry, program,
+first execute) is made resident once a process by ``prepare``: before
+connecting (``--warmScene``), when a job is announced (``prepare_job``, on
+a thread of its own while the render thread goes on with other jobs'
+frames), or, where nobody announced the job, by its first frame as it
+always was. A key is claimed under a lock and built by exactly one
+thread; a frame that meets a preparation in hand waits for it.
 """
 
 from __future__ import annotations
 
 import asyncio
+import threading
 import time
 from pathlib import Path
 
@@ -56,10 +67,9 @@ class TpuRaytraceBackend(RenderBackend):
         with get_startup().child("open_device"):
             self.device = require_tpu_device()
         self.base_directory = Path(base_directory) if base_directory else None
-        self.width = width
-        self.height = height
-        self.samples = samples
-        self.max_bounces = max_bounces
+        # The worker's own shape: what a job whose [render] table is
+        # silent (or absent) renders at.
+        self.default_shape = (width, height, samples, max_bounces)
         self.tile_size = tile_size
         # None = single device; "tile" / "spp" shard a whole frame across
         # the local mesh (tpu_render_cluster/parallel/sharded_render.py).
@@ -72,7 +82,48 @@ class TpuRaytraceBackend(RenderBackend):
             self._tier_frames.inc(0.0, tier=tier)
         from tpu_render_cluster.obs import get_registry
 
-        get_registry().gauge(
+        # program key -> set once what the key needs is resident. A key
+        # is claimed by whoever meets it first (an announcement's thread,
+        # --warmScene, or a frame) and built by that thread alone.
+        self._resident: dict[tuple, threading.Event] = {}
+        self._resident_lock = threading.Lock()
+        # family -> bytes of its BLAS tables by memory space
+        self._geometry: dict[str, dict[str, int]] = {}
+        self._blas_units: dict[str, int] = {}
+        self._last_key: tuple | None = None  # the render thread's own
+        registry = get_registry()
+        self._before_ready = registry.counter(
+            "worker_frames_before_ready_total",
+            "Frames that reached the render thread before what their job "
+            "needs was resident, and waited for the preparation in hand (or "
+            "built it themselves, where nobody had announced the job)",
+        )
+        self._switches = registry.counter(
+            "worker_program_switches_total",
+            "Frames whose program (scene family and shape) differs from "
+            "the frame before on the render thread",
+        )
+        self._family_frames = registry.counter(
+            "worker_frames_rendered_by_family_total",
+            "Frames rendered and written, by scene family",
+            labels=("family",),
+        )
+        for counter in (self._before_ready, self._switches):
+            counter.inc(0.0)
+        self._prepare_seconds = registry.histogram(
+            "worker_job_prepare_seconds",
+            "Seconds from a job's announcement (or --warmScene) to what "
+            "its frames need being resident: geometry, program, first "
+            "execute; near 0 where it already was",
+            labels=("family",),
+        )
+        self._resident_programs = registry.gauge(
+            "render_resident_program_units",
+            "Frame programs resident in this process, one a (scene family, "
+            "shape) that was prepared or rendered",
+        )
+        self._resident_programs.set(0.0)
+        registry.gauge(
             "render_device_units",
             "Devices this backend renders on, labelled with JAX's platform "
             "and device_kind: what a reader needs to pick the chip's "
@@ -84,59 +135,133 @@ class TpuRaytraceBackend(RenderBackend):
         )
 
     def warm(self, scene_name: str) -> None:
-        """Compile + execute the renderer once, outside any job window.
+        """``prepare`` at the worker's own shape, before connecting: the
+        process-level analog of pre-pulling the Blender container
+        (reference: pull-blender-image.sh). The first XLA compile costs
+        20-40 s and must not land inside a rendered frame's trace."""
+        self.prepare(scene_name)
 
-        The process-level analog of pre-pulling the Blender container
-        (reference: pull-blender-image.sh): the first XLA compile costs
-        20-40 s and must not land inside a rendered frame's trace. Fills
-        three of start-up's stages (obs/startup.py): ``geometry``,
-        ``program_build`` (until the program's first call has returned:
-        the executable exists, the work is queued) and ``first_execute``.
+    def program_key(self, job: BlenderJob) -> tuple:
+        """(scene family, width, height, samples, max_bounces) of the
+        job's frames: its [render] table over this worker's flags."""
+        from tpu_render_cluster.render.scene import scene_for_job_name
+
+        shape = self.default_shape
+        if job.render is not None:
+            shape = job.render.shape(shape)
+        return (scene_for_job_name(job.job_name), *shape)
+
+    async def prepare_job(self, job: BlenderJob) -> None:
+        await asyncio.to_thread(self.prepare, job.job_name, self.program_key(job))
+
+    def _claim(self, key: tuple) -> tuple[threading.Event, bool]:
+        """The key's residency event, and whether the caller is the one
+        to build what it needs."""
+        with self._resident_lock:
+            done = self._resident.get(key)
+            if done is not None:
+                return done, False
+            done = self._resident[key] = threading.Event()
+            return done, True
+
+    def _settle(self, key: tuple, done: threading.Event, built: bool) -> None:
+        """End a claim: resident (the gauges say so), or given up, so
+        that the next to meet the key builds it."""
+        with self._resident_lock:
+            if built:
+                self._note_geometry(key[0])
+            else:
+                self._resident.pop(key, None)
+            done.set()
+            self._resident_programs.set(
+                float(sum(event.is_set() for event in self._resident.values()))
+            )
+
+    def prepare(self, scene_name: str, key: tuple | None = None) -> None:
+        """Make resident what frames of ``key`` need: geometry, the
+        frame's program up to its first call's return (the executable
+        exists, the work is queued), and one execute. Accepts job names as
+        well as scene names, resolving exactly like the render path does —
+        otherwise the prepared program can differ from the one the job
+        compiles. Once a key: a second call, or one that meets a
+        preparation in hand, waits for it and builds nothing.
+
+        Before connecting it fills three of start-up's stages
+        (obs/startup.py): ``geometry``, ``program_build``,
+        ``first_execute``; after, what it spent is credited to them while
+        the first frame is still awaited. Either way one ``job_prepare``
+        span with the three as children, and one observation of
+        ``worker_job_prepare_seconds{family}``.
         """
         import numpy as np
 
         from tpu_render_cluster.obs.startup import get_startup
         from tpu_render_cluster.render.scene import scene_for_job_name
 
-        startup = get_startup()
-        startup.enter("geometry")
-        # Accept job names as well as scene names, resolving exactly like
-        # the render path does — otherwise the warmed program can differ
-        # from the one the job compiles.
         scene_name = scene_for_job_name(scene_name)
-        self._build_geometry(scene_name)
+        if key is None:
+            key = (scene_name, *self.default_shape)
+        startup = get_startup()
+        began = time.time()
+        done, mine = self._claim(key)
+        edges, staged = [began], False
+        if not mine:
+            done.wait()
+        else:
+            try:
+                # a stage is entered before connecting only; afterwards
+                # the mark is refused and the seconds are credited below
+                staged = startup.enter("geometry")
+                self._build_geometry(scene_name)
+                edges.append(time.time())
+                startup.enter("program_build")
+                display = self._first_call(key)
+                edges.append(time.time())
+                startup.enter("first_execute")
+                np.asarray(display)
+                edges.append(time.time())
+            except BaseException:
+                self._settle(key, done, built=False)
+                raise
+            self._settle(key, done, built=True)
+        seconds = time.time() - began
+        track = "prepare {}@{}x{}x{}x{}".format(*key)
+        for name, start, end in zip(
+            ("geometry", "program_build", "first_execute"), edges, edges[1:]
+        ):
+            if not staged:
+                startup.credit(name, end - start)
+            startup.span(
+                name, cat="worker.prepare", start_wall=start,
+                duration=end - start, track=track,
+            )
+        startup.span(
+            "job_prepare", cat="worker.prepare", start_wall=began,
+            duration=seconds, track=track,
+            args={
+                "family": scene_name, "resident": not mine,
+                "shape": "{}x{}x{}x{}".format(*key[1:]),
+            },
+        )
+        self._prepare_seconds.observe(seconds, family=scene_name)
 
-        startup.enter("program_build")
+    def _first_call(self, key: tuple):
+        """The first call of the key's whole-frame program: builds it."""
+        scene_name, *shape = key
         if self.sharding in ("tile", "spp"):
             from tpu_render_cluster.parallel.sharded_render import sharded_frame_renderer
 
-            display = sharded_frame_renderer(
-                scene_name,
-                self.width,
-                self.height,
-                self.samples,
-                self.max_bounces,
-                self.sharding,
-            )(1)
-        else:
-            from tpu_render_cluster.render.integrator import fused_frame_renderer
+            return sharded_frame_renderer(scene_name, *shape, self.sharding)(1)
+        from tpu_render_cluster.render.integrator import fused_frame_renderer
 
-            # The program _render_timed runs: with the live counts.
-            display, *_ = fused_frame_renderer(
-                scene_name,
-                self.width,
-                self.height,
-                self.samples,
-                self.max_bounces,
-                with_live=True,
-            )(1)
-        startup.enter("first_execute")
-        np.asarray(display)
+        # The program _render_timed runs: with the live counts.
+        display, *_ = fused_frame_renderer(scene_name, *shape, with_live=True)(1)
+        return display
 
     def _build_geometry(self, scene_name: str) -> None:
         """Build the scene's BLAS or its set of BLASes (once a process:
         the renderer factories find them cached) and say how long each
-        model took, how many there are and where they live."""
+        model took."""
         from tpu_render_cluster.obs import get_registry
         from tpu_render_cluster.obs.startup import get_startup
         from tpu_render_cluster.render import mesh
@@ -146,12 +271,11 @@ class TpuRaytraceBackend(RenderBackend):
         kind = mesh_kind_for_scene(scene_name)
         if kind is None:
             return
-        registry = get_registry()
-        seconds = registry.gauge(
+        seconds = get_registry().gauge(
             "render_bvh_build_seconds",
-            "Seconds warm() spent building a model's BLAS (model "
-            "\"upload\": joining a set's tables and putting them on the "
-            "device; one BLAS alone: its build and its copy together)",
+            "Seconds spent building a model's BLAS (model \"upload\": "
+            "joining a set's tables and putting them on the device; one "
+            "BLAS alone: its build and its copy together)",
             labels=("model",),
         )
 
@@ -163,20 +287,57 @@ class TpuRaytraceBackend(RenderBackend):
                 args={"model": model, "triangles": triangles},
             )
 
-        bvh = mesh.cached_mesh_bvh(kind, *resolve_bvh_config()[2:], built=built)
+        mesh.cached_mesh_bvh(kind, *resolve_bvh_config()[2:], built=built)
+
+    def _note_geometry(self, scene_name: str) -> None:
+        """Say what this process holds now that ``scene_name`` is
+        resident: its BLAS tables by family and memory space, and over
+        every family the process has met (a worker that serves two holds
+        both). A lookup: the build is cached."""
+        from tpu_render_cluster.obs import get_registry
+        from tpu_render_cluster.render import mesh
+        from tpu_render_cluster.render.integrator import resolve_bvh_config
+        from tpu_render_cluster.render.scene import mesh_kind_for_scene
+
+        kind = mesh_kind_for_scene(scene_name)
+        if scene_name in self._geometry:
+            return
+        if kind is None:
+            self._geometry[scene_name] = {"hbm": 0, "vmem": 0, "smem": 0}
+            self._blas_units[scene_name] = 0
+        else:
+            bvh = mesh.cached_mesh_bvh(kind, *resolve_bvh_config()[2:])
+            self._geometry[scene_name] = mesh.geometry_bytes(bvh)
+            self._blas_units[scene_name] = mesh.blas_count(bvh)
+        registry = get_registry()
+        by_family = registry.gauge(
+            "render_resident_geometry_bytes",
+            "Bytes of BLAS tables resident in this process, by scene family "
+            "and by the memory they live in while a bounce kernel runs",
+            labels=("family", "space"),
+        )
+        for space, count in self._geometry[scene_name].items():
+            by_family.set(float(count), family=scene_name, space=space)
+        if not any(self._blas_units.values()):
+            return  # sphere families only: the mesh gauges stay unexposed
         registry.gauge(
             "render_geometry_blas_units",
-            "BLASes the scene's geometry holds: 1, or the models of a set",
-        ).set(float(mesh.blas_count(bvh)))
+            "BLASes the resident geometry holds: 1 a mesh family, or the "
+            "models of a set; summed over the families this process holds",
+        ).set(float(sum(self._blas_units.values())))
         where = registry.gauge(
             "render_geometry_bytes",
-            "Bytes of the scene's BLAS tables (a set's together) by the "
-            "memory they live in while a bounce kernel runs: hbm (streamed "
-            "by treelet), vmem and smem (resident)",
+            "Bytes of the resident BLAS tables (a set's together, every "
+            "family this process holds) by the memory they live in while a "
+            "bounce kernel runs: hbm (streamed by treelet), vmem and smem "
+            "(resident)",
             labels=("space",),
         )
-        for space, count in mesh.geometry_bytes(bvh).items():
-            where.set(float(count), space=space)
+        for space in ("hbm", "vmem", "smem"):
+            where.set(
+                float(sum(sizes[space] for sizes in self._geometry.values())),
+                space=space,
+            )
 
     async def render_frame(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
@@ -322,14 +483,36 @@ class TpuRaytraceBackend(RenderBackend):
     ) -> FrameRenderTime:
         """One frame on the render thread; its exclusive steps (obs.step)
         ride the timing beside the seven points."""
-        from tpu_render_cluster.obs import frame_steps
+        from tpu_render_cluster.obs import frame_steps, step
 
+        key = self.program_key(job)
+        if self._last_key is not None and key != self._last_key:
+            self._switches.inc()
+        self._last_key = key
+        done, mine = self._claim(key)
         with frame_steps() as steps:
-            return self._render_timed(job, frame_index, tile, steps)
+            if not done.is_set():
+                # Nobody announced the job (this frame builds what it
+                # needs, as a first frame always did), or its preparation
+                # is still in hand: wait for that one, never build twice.
+                self._before_ready.inc()
+                if not mine:
+                    with step("resolve"):
+                        done.wait()
+            try:
+                timing = self._render_timed(job, frame_index, tile, steps, key)
+            except BaseException:
+                if mine:
+                    self._settle(key, done, built=False)
+                raise
+        if mine:
+            self._settle(key, done, built=True)
+        self._family_frames.inc(family=key[0])
+        return timing
 
     def _render_timed(
         self, job: BlenderJob, frame_index: int, tile: int | None,
-        steps: list[tuple[str, float, float]],
+        steps: list[tuple[str, float, float]], key: tuple,
     ) -> FrameRenderTime:
         import jax.numpy as jnp
         import numpy as np
@@ -345,8 +528,6 @@ class TpuRaytraceBackend(RenderBackend):
             fused_region_renderer,
             tonemap,
         )
-        from tpu_render_cluster.render.scene import scene_for_job_name
-
         started_process_at = time.time()
 
         # "Loading" = fetching (or first-building) the compiled renderer
@@ -358,8 +539,8 @@ class TpuRaytraceBackend(RenderBackend):
         # whole frame of a deep mesh scene, the per-bounce (live rays,
         # launch width) — an output of the frame's own program.
         with step("resolve"):
-            scene_name = scene_for_job_name(job.job_name)
-            shape = (self.width, self.height, self.samples, self.max_bounces)
+            scene_name, *shape = key
+            width, height, samples, max_bounces = shape
             region = None
             if tile is not None:
                 from tpu_render_cluster.jobs.tiles import tile_bounds
@@ -369,9 +550,7 @@ class TpuRaytraceBackend(RenderBackend):
                         f"Tile {tile} requested but job {job.job_name!r} "
                         "carries no tile grid."
                     )
-                region = tile_bounds(
-                    tile, job.tile_grid, width=self.width, height=self.height
-                )
+                region = tile_bounds(tile, job.tile_grid, width=width, height=height)
             if region is not None:
                 # A tile unit: the jitted region program (one compile per
                 # tile shape; y0/x0/frame are traced) traces the FULL
@@ -383,8 +562,8 @@ class TpuRaytraceBackend(RenderBackend):
                 tier = "region"
                 y0, x0, tile_height, tile_width = region
                 region_renderer = fused_region_renderer(
-                    scene_name, self.width, self.height, tile_height,
-                    tile_width, self.samples, self.max_bounces,
+                    scene_name, width, height, tile_height, tile_width,
+                    samples, max_bounces,
                 )
 
                 def render():
@@ -473,7 +652,7 @@ class TpuRaytraceBackend(RenderBackend):
         if tier in ("region", "masked"):
             from tpu_render_cluster.obs.profiling import kernel_key
 
-            dims = dict(w=self.width, h=self.height, s=self.samples, b=self.max_bounces)
+            dims = dict(w=width, h=height, s=samples, b=max_bounces)
             if region is not None:
                 dims.update(th=region[2], tw=region[3])
             kernel = kernel_key(tier, scene_name, **dims)

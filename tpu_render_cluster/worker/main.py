@@ -109,12 +109,15 @@ def build_parser() -> argparse.ArgumentParser:
         "--warmScene",
         dest="warm_scene",
         default=None,
-        help="tpu-raytrace only: compile the renderer for this scene BEFORE "
-        "connecting to the master, so the job window never contains XLA "
-        "compilation (the analog of pre-pulling the Blender image). Fills "
-        "the start-up stages geometry, program_build and first_execute "
-        "(worker_startup_stage_seconds); without it they read 0 and their "
-        "cost lies in first_frame.",
+        help="tpu-raytrace only: prepare this scene at the worker's own "
+        "shape BEFORE connecting to the master, so the job window never "
+        "contains XLA compilation (the analog of pre-pulling the Blender "
+        "image). Fills the start-up stages geometry, program_build and "
+        "first_execute (worker_startup_stage_seconds). Without it a worker "
+        "prepares each job when a scheduler service announces it (the same "
+        "call, credited to the same stages while the first frame is "
+        "awaited); under a master that announces no job the cost lies in "
+        "first_frame.",
     )
     return parser
 

@@ -89,7 +89,9 @@ async def _perform_handshake(
     )
     await ws.send_text(
         wire.encode(
-            pm.WorkerHandshakeResponse(handshake_type, PROTOCOL_VERSION, worker_id)
+            pm.WorkerHandshakeResponse(
+                handshake_type, PROTOCOL_VERSION, worker_id, prepares_jobs=True
+            )
         )
     )
     ack = wire.decode(await ws.receive_text())
@@ -489,9 +491,32 @@ class Worker:
                     )
                 )
 
+        preparations: set[asyncio.Task] = set()
+
+        async def prepare_and_report(event: pm.MasterJobStartedEvent) -> None:
+            """The job came with its announcement: have the backend make
+            what it needs resident (on a thread of the backend's own; the
+            render loop goes on), then tell the master, which holds the
+            job's frames back until it hears. A preparation that fails is
+            reported ready all the same: the job's frames then fail one by
+            one through the errored-result path."""
+            try:
+                await self.backend.prepare_job(event.job)
+            except Exception:  # noqa: BLE001 - the frames will say it again
+                logger.exception(
+                    "Preparing job %r failed.", event.job.job_name
+                )
+            await sender.send_message(
+                pm.WorkerJobReadyEvent(event.job.job_name, job_id=event.job_id)
+            )
+
         async def handle_job_started() -> None:
             while True:
                 event = await started_queue.get()
+                if event.job is not None:
+                    task = asyncio.create_task(prepare_and_report(event))
+                    preparations.add(task)
+                    task.add_done_callback(preparations.discard)
                 logger.info(
                     "Job started%s.",
                     f" ({event.job_id})" if event.job_id is not None else "",
@@ -593,8 +618,8 @@ class Worker:
                 )
         finally:
             job_done_task.cancel()
-            for task in tasks:
+            for task in (*tasks, *preparations):
                 task.cancel()
             await asyncio.gather(
-                job_done_task, *tasks, return_exceptions=True
+                job_done_task, *tasks, *preparations, return_exceptions=True
             )
