@@ -475,7 +475,10 @@ def test_midjob_scrapes_and_attribution_acceptance(monkeypatch):
         for labels, value in master["sched_tick_seconds_count"]
         if value > 0
     }
-    assert "total" in phases_seen and "dispatch" in phases_seen
+    # a pass is the timer's (``total``) or one a worker's event started
+    # early (``event_total``, master/wakeup.py): with a queue of 2 over
+    # mock frames nearly every pass is the second kind
+    assert {"total", "event_total"} & phases_seen and "dispatch" in phases_seen
     assert {"fair_share", "share_scan"} <= phases_seen
     assert any(
         value > 0 for _labels, value in master["obs_loop_lag_seconds_count"]

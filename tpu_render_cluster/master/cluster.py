@@ -33,6 +33,7 @@ from tpu_render_cluster.master.speculate import (
 )
 from tpu_render_cluster.master.state import ClusterManagerState, FrameStatus
 from tpu_render_cluster.master.strategies import run_strategy
+from tpu_render_cluster.master.wakeup import DispatchWakeup
 from tpu_render_cluster.master.worker_handle import WorkerHandle
 from tpu_render_cluster.obs import (
     FlightRecorder,
@@ -147,6 +148,12 @@ class ClusterManager:
         self.metrics = metrics if metrics is not None else get_registry()
         self.span_tracer = span_tracer or Tracer("master")
         self._transport_metrics = TransportMetrics(self.metrics)
+        # What the shallow-queue dispatch loops (naive-fine, the service
+        # loop) wait on between passes in place of a whole tick
+        # (master/wakeup.py): set by a worker's handle when a result leaves
+        # it nothing queued behind the frame in hand, or it reports a job
+        # ready, and here when a worker connects.
+        self.dispatch_wakeup = DispatchWakeup(self.metrics)
         # Tiled frames: when the last tile of a frame lands, the assembly
         # service stitches the tile files into the frame's final image
         # (master/assembly.py). ``output_base_directory`` resolves a job's
@@ -658,9 +665,11 @@ class ClusterManager:
             on_protocol_event=self._on_worker_protocol_event,
             epoch=self.epoch,
             prepares_jobs=prepares_jobs,
+            wakeup=self.dispatch_wakeup,
         )
         self.workers[worker_id] = worker
         worker.start()
+        self.dispatch_wakeup.set()
         logger.info(
             "Worker %08x connected from %s (%d/%d).",
             worker_id,
@@ -807,6 +816,7 @@ class ClusterManager:
                     self.live_workers,
                     self.cancellation,
                     cost_service=self.cost_service,
+                    wakeup=self.dispatch_wakeup,
                 )
                 # Let the sidecar settle open races (outcomes accounted,
                 # losers unqueued) before the finalization sweep audits

@@ -3,7 +3,9 @@
 Behavioral contract from the reference (master/src/cluster/strategies.rs):
 
 - **naive-fine** (strategies.rs:16-68): 50 ms tick; any worker with an empty
-  queue receives exactly one pending frame.
+  queue receives exactly one pending frame. A pass also starts as soon as a
+  result leaves a worker's queue empty (master/wakeup.py), so the tick is
+  only what a lost signal costs.
 - **eager-naive-coarse** (strategies.rs:70-150): 100 ms tick; every worker's
   queue is topped up to ``target_queue_size``.
 - **dynamic** (strategies.rs:155-405): 50 ms tick; workers sorted by queue
@@ -33,6 +35,7 @@ from tpu_render_cluster.jobs.models import (
 from tpu_render_cluster.jobs.tiles import WorkUnit
 from tpu_render_cluster.master.queue_mirror import FrameOnWorker
 from tpu_render_cluster.master.state import ClusterManagerState, FrameStatus
+from tpu_render_cluster.master.wakeup import DispatchWakeup
 from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.utils.cancellation import CancellationToken
 
@@ -167,8 +170,14 @@ async def naive_fine_strategy(
     state: ClusterManagerState,
     workers_fn,
     cancellation: CancellationToken,
+    wakeup: DispatchWakeup,
 ) -> None:
-    """One frame at a time per idle worker (reference: strategies.rs:16-68)."""
+    """One frame at a time per idle worker (reference: strategies.rs:16-68).
+
+    Between passes the loop waits for ``wakeup`` (a finished event that
+    left a worker's mirror empty) or for the tick, whichever comes first;
+    ``has_empty_queue()`` gates every dispatch as before, so a worker that
+    holds a frame never gets a second one."""
     while not cancellation.is_cancelled():
         if state.all_frames_finished():
             return
@@ -177,7 +186,7 @@ async def naive_fine_strategy(
             if worker.is_dead or not worker.has_empty_queue():
                 continue
             await _queue_one_pending(worker, job, state)
-        await asyncio.sleep(NAIVE_FINE_TICK)
+        await wakeup.wait(NAIVE_FINE_TICK)
 
 
 async def eager_naive_coarse_strategy(
@@ -351,6 +360,7 @@ async def run_strategy(
     cancellation: CancellationToken,
     *,
     cost_service=None,
+    wakeup: DispatchWakeup,
 ) -> None:
     """Dispatch on the job's strategy (reference: master/src/cluster/mod.rs:622-654).
 
@@ -358,11 +368,14 @@ async def run_strategy(
     (sched/cost_model.CostModelService); the tpu-batch strategy prices
     its auction off it (warm-started from ``TRC_COST_MODEL`` snapshots
     and shared with the speculation loop). The reference strategies
-    ignore it — their dispatch order is fixed by contract.
+    ignore it — their dispatch order is fixed by contract. ``wakeup`` is
+    the manager's dispatch wake-up; naive-fine alone waits on it (the
+    other loops keep queues no finished event can run shallow, and their
+    ticks).
     """
     strategy = job.frame_distribution_strategy
     if strategy.strategy_type == "naive-fine":
-        await naive_fine_strategy(job, state, workers_fn, cancellation)
+        await naive_fine_strategy(job, state, workers_fn, cancellation, wakeup)
     elif strategy.strategy_type == "eager-naive-coarse":
         await eager_naive_coarse_strategy(
             job, state, workers_fn, cancellation, strategy.eager.target_queue_size

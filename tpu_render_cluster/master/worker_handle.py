@@ -38,6 +38,7 @@ from tpu_render_cluster.jobs.models import BlenderJob
 from tpu_render_cluster.jobs.tiles import WorkUnit
 from tpu_render_cluster.master.queue_mirror import FrameOnWorker, WorkerQueueMirror
 from tpu_render_cluster.master.state import ClusterManagerState, FrameStatus
+from tpu_render_cluster.master.wakeup import SHALLOW_QUEUE, DispatchWakeup
 from tpu_render_cluster.obs import ClockOffsetEstimator, MetricsRegistry, Tracer
 from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.protocol.frames import DispatchFrameCache, frames_cached
@@ -103,6 +104,8 @@ class WorkerHandle:
     # has reported. A worker that did not say so is ready for every job.
     prepares_jobs = False
     ready_jobs: frozenset | set = frozenset()
+    # The manager's dispatch wake-up (master/wakeup.py); None on a bare handle.
+    _wakeup: DispatchWakeup | None = None
 
     def __init__(
         self,
@@ -123,9 +126,11 @@ class WorkerHandle:
         on_protocol_event: Callable[[str, dict], None] | None = None,
         epoch: int | None = None,
         prepares_jobs: bool = False,
+        wakeup: DispatchWakeup | None = None,
     ) -> None:
         self.worker_id = worker_id
         self.prepares_jobs = prepares_jobs
+        self._wakeup = wakeup
         self.ready_jobs = set()
         self.connection = connection
         # Master incarnation epoch (ha/ledger.py; None without a ledger):
@@ -496,6 +501,8 @@ class WorkerHandle:
             from tpu_render_cluster.sched.tickprof import observe_dispatch_phase
 
             observe_dispatch_phase(self.metrics, "dispatch_rpc_await", rpc_seconds)
+        if self._wakeup is not None:
+            self._wakeup.count_dispatched_frame()
         if self.span_tracer is not None:
             # Constant span name (frame index in args) so viewers and the
             # analysis roll-up aggregate all assignments into one stat.
@@ -884,6 +891,12 @@ class WorkerHandle:
             )
         started = self._rendering_started_at.pop((event.job_name, unit), None)
         self._update_queue_depth_gauge()
+        if self._wakeup is not None and len(self.queue) <= SHALLOW_QUEUE:
+            # Nothing is queued behind the frame this worker renders (or
+            # takes next): the dispatch loop's next pass starts now, not
+            # at its tick. It runs after this handler has returned, so it
+            # sees the frame table as this event leaves it.
+            self._wakeup.set()
         if self.metrics is not None:
             self.metrics.counter(
                 "master_frame_results_total",
@@ -1199,8 +1212,10 @@ class WorkerHandle:
                 state = self._state_for(event.job_name)
                 if state is not None:
                     # the job's dispatchable demand changed: the scheduler
-                    # resyncs it on its next tick
+                    # resyncs it on its next pass, which starts now
                     state.version += 1
+                    if self._wakeup is not None:
+                        self._wakeup.set()
 
         async def handle_rendering() -> None:
             while True:

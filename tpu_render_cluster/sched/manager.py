@@ -67,7 +67,9 @@ class SchedulerConfig:
     """Tuning knobs, each with a ``TRC_SCHED_*`` environment override."""
 
     # Dispatch/admission tick. The single-job strategies tick at 50 ms
-    # (reference: strategies.rs); the service loop matches.
+    # (reference: strategies.rs); the service loop matches. It is the
+    # longest the loop waits between passes: a worker event that leaves a
+    # queue shallow starts the next pass at once (master/wakeup.py).
     tick_seconds: float = 0.05
     # In-flight frame slots per live worker (the eager-naive-coarse
     # "target queue size" generalized to the whole service).
@@ -401,6 +403,15 @@ class JobManager(ClusterManager):
             await self._shutdown_server()
 
     async def _scheduler_loop(self) -> None:
+        """Admission, finalization and one dispatch pass, over and over.
+
+        Between passes the loop waits for the manager's dispatch wake-up
+        (master/wakeup.py: a result left a worker nothing queued behind
+        the frame it has in hand, a worker reported a job ready, a worker
+        connected) or for the tick, whichever comes first. An event-woken
+        pass is the ordinary pass: the same picks, the same claim before
+        the RPC, and ``dt`` taken from the clock, so share accounting does
+        not care how long the wait was."""
         last = time.time()
         while not self.cancellation.is_cancelled():
             now = time.time()
@@ -458,7 +469,9 @@ class JobManager(ClusterManager):
             if self._draining and not self._admission and not self._running:
                 return
             if self._running:
-                self.tickprof.begin_tick()
+                self.tickprof.begin_tick(
+                    event_woken=self.dispatch_wakeup.trigger == "event"
+                )
                 # Fold fresh completion observations into the shared cost
                 # model first: this tick's WFQ pick and speculation
                 # decisions price off the newest evidence.
@@ -495,7 +508,7 @@ class JobManager(ClusterManager):
                                 )
                 self._finalize_finished_jobs(time.time())
                 self.tickprof.end_tick()
-            await asyncio.sleep(self.config.tick_seconds)
+            await self.dispatch_wakeup.wait(self.config.tick_seconds)
 
     def _cancel_unadmittable_queued_jobs(self, now: float) -> None:
         live = len(self.live_workers())

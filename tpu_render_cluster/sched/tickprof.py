@@ -11,7 +11,11 @@ the whole tick feed the ``sched_tick_seconds{phase}`` histogram
 (``phase="total"`` for the tick) and draw spans on a dedicated "sched"
 Perfetto track; ``sched_tick_budget_ratio`` is a rolling gauge of mean
 tick time over the configured tick budget (``> 1`` means the loop can
-no longer hold its cadence).
+no longer hold its cadence). A pass that a worker event started before
+the tick was due (master/wakeup.py) records its phases like any other but
+its whole under ``phase="event_total"``, draws an ``sched event pass``
+span, and stays out of ``phase="total"``, the tick count and the budget
+gauge: those keep meaning the timer's ticks against the timer's budget.
 
 The dispatch RPC round-trip and the queue-add JSON serialize happen off
 the tick's critical section (inside ``WorkerHandle``), so those sites
@@ -83,6 +87,7 @@ class TickProfiler:
         self._budget = metrics.gauge(BUDGET_METRIC, _BUDGET_HELP)
         self._totals: deque[float] = deque(maxlen=BUDGET_WINDOW)
         self._tick_active = False
+        self._event_woken = False
         self._tick_start_wall = 0.0
         self._tick_start = 0.0
         # Flight-recorder seam (obs/flightrec.py): a rolling budget ratio
@@ -93,8 +98,9 @@ class TickProfiler:
         self.flightrec = flightrec
         self._over_budget = False
 
-    def begin_tick(self) -> None:
+    def begin_tick(self, *, event_woken: bool = False) -> None:
         self._tick_active = profiling_enabled()
+        self._event_woken = event_woken
         if not self._tick_active:
             return
         self._tick_start_wall = time.time()
@@ -126,38 +132,45 @@ class TickProfiler:
             return
         self._tick_active = False
         total = time.perf_counter() - self._tick_start
-        self.ticks += 1
-        self._hist.observe(total, phase="total")
-        self._totals.append(total)
-        ratio = sum(self._totals) / len(self._totals) / self.tick_budget_seconds
-        self._budget.set(ratio)
-        if self.flightrec is not None:
-            if ratio > 1.0:
-                if not self._over_budget:
-                    self._over_budget = True
-                    from tpu_render_cluster.obs.flightrec import (
-                        TRIGGER_TICK_BUDGET,
-                    )
-
-                    self.flightrec.trigger(
-                        TRIGGER_TICK_BUDGET,
-                        {
-                            "budget_ratio": round(ratio, 4),
-                            "tick_budget_seconds": self.tick_budget_seconds,
-                            "last_tick_seconds": round(total, 6),
-                            "ticks": self.ticks,
-                        },
-                    )
-            else:
-                self._over_budget = False
+        if self._event_woken:
+            # A pass a worker's event started early: timed apart, outside
+            # the tick count and the budget (the timer's, both of them).
+            phase, span = "event_total", "sched event pass"
+        else:
+            phase, span = "total", "sched tick"
+            self.ticks += 1
+            self._observe_budget(total)
+        self._hist.observe(total, phase=phase)
         if self.span_tracer is not None:
             self.span_tracer.complete(
-                "sched tick",
+                span,
                 cat="sched",
                 start_wall=self._tick_start_wall,
                 duration=total,
                 track="sched",
                 args={"tick": self.ticks},
+            )
+
+    def _observe_budget(self, total: float) -> None:
+        self._totals.append(total)
+        ratio = sum(self._totals) / len(self._totals) / self.tick_budget_seconds
+        self._budget.set(ratio)
+        if self.flightrec is None:
+            return
+        if ratio <= 1.0:
+            self._over_budget = False
+        elif not self._over_budget:
+            self._over_budget = True
+            from tpu_render_cluster.obs.flightrec import TRIGGER_TICK_BUDGET
+
+            self.flightrec.trigger(
+                TRIGGER_TICK_BUDGET,
+                {
+                    "budget_ratio": round(ratio, 4),
+                    "tick_budget_seconds": self.tick_budget_seconds,
+                    "last_tick_seconds": round(total, 6),
+                    "ticks": self.ticks,
+                },
             )
 
 
