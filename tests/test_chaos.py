@@ -324,7 +324,7 @@ def test_duplicate_and_late_results_keep_ledger_exact():
     state.mark_frame_as_queued(2, a.worker_id, now)
     a.queue.add(FrameOnWorker(2, queued_at=now))
     a.is_dead = True
-    state.return_frame_to_pending(2)
+    state.return_frame_to_pending(2, "eviction")
     a.queue.clear()
     state.mark_frame_as_queued(2, b.worker_id, now)
     b.queue.add(FrameOnWorker(2, queued_at=now))
@@ -407,7 +407,7 @@ def test_steal_aborts_when_eviction_already_requeued():
     async def scenario():
         async def evict_during_rpc(victim, frame_index):
             victim.is_dead = True
-            victim.state.return_frame_to_pending(frame_index)
+            victim.state.return_frame_to_pending(frame_index, "eviction")
             victim.queue.clear()
 
         job, state, thief, victim = _steal_setup()

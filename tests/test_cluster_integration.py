@@ -481,10 +481,19 @@ def test_dead_worker_is_evicted_and_frames_requeue(monkeypatch):
             asyncio.create_task(w.connect_and_run_to_job_completion())
             for w in workers
         ]
-        # Let the job start (the worker barrier polls at 1 s) and queues
-        # fill, then kill worker 2 outright: cancel its tasks and sever
-        # its socket (no clean goodbye).
-        await asyncio.sleep(1.6)
+        # Wait for the event, not for a time: the job has started (the
+        # worker barrier polls at 1 s, later on a busy host), worker 2 has
+        # rendered a frame and the master's mirror of its queue holds
+        # frames to give back. Then kill it outright: cancel its tasks and
+        # sever its socket (no clean goodbye). A fixed sleep of 1.6 s fell
+        # before the job's start, or after its end, when the host was busy.
+        def casualty_holds_frames() -> bool:
+            handle = manager.workers.get(workers[1].worker_id)
+            return bool(casualty.rendered_frames) and handle is not None and len(handle.queue) >= 2
+
+        while not casualty_holds_frames():
+            assert not server_task.done(), "the job ended before worker 2 held frames"
+            await asyncio.sleep(0.005)
         tasks[1].cancel()
         client = workers[1]._client
         if client is not None:

@@ -329,7 +329,7 @@ class _FakeWorker:
         self.queue.remove(unit.frame_index, job_name, unit.tile)
         return pm.FRAME_QUEUE_REMOVE_RESULT_REMOVED
 
-    async def queue_frame(self, job, unit, *, stolen_from=None, job_id=None):
+    async def queue_frame(self, job, unit, *, stolen_from=None, job_id=None, trigger=None):
         self.queued_units.append(unit)
         now = time.time()
         self.queue.add(

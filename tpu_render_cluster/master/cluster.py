@@ -666,6 +666,7 @@ class ClusterManager:
             epoch=self.epoch,
             prepares_jobs=prepares_jobs,
             wakeup=self.dispatch_wakeup,
+            on_job_ready=self._on_worker_job_ready,
         )
         self.workers[worker_id] = worker
         worker.start()
@@ -680,8 +681,16 @@ class ClusterManager:
         # Late joiners still learn which jobs have started (reference FIXME
         # at master/src/cluster/mod.rs:616-617) — replayed for EVERY active
         # job, which becomes load-bearing once several run concurrently.
+        await self._announce_active_jobs(worker)
+
+    async def _announce_active_jobs(self, worker: WorkerHandle) -> None:
         for trace_id, job_id, job in self._active_job_announcements():
             await worker.send_job_started(trace_id=trace_id, job_id=job_id, job=job)
+
+    def _on_worker_job_ready(
+        self, worker: WorkerHandle, job_name: str, job_id: str | None
+    ) -> None:
+        """A worker reported a job ready (the scheduler service's hook)."""
 
     async def _evict_worker(self, worker: WorkerHandle, reason: str) -> None:
         """Return a dead worker's units to the pool so its jobs can finish."""
@@ -709,7 +718,7 @@ class ClusterManager:
                 # a ghost copy from a superseded dispatch) — requeueing
                 # those would put a unit in play twice while its primary
                 # still renders it.
-                state.return_frame_to_pending(frame.unit)
+                state.return_frame_to_pending(frame.unit, "eviction")
         # No ghost assignments: a dead worker's mirror must not keep
         # offering steal candidates (or claim queue depth) for frames that
         # just went back to the pool.

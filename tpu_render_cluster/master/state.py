@@ -28,6 +28,16 @@ from tpu_render_cluster.jobs.models import BlenderJob
 from tpu_render_cluster.jobs.tiles import WorkUnit
 from tpu_render_cluster.protocol.messages import generate_trace_id
 
+# Why a unit left the worker that held it without a result (``handbacks``).
+HANDBACK_CAUSES = (
+    "preemption",  # fair share unqueued it for a starved job
+    "steal",  # another worker took it over
+    "eviction",  # its worker died
+    "drain",  # its worker said goodbye and returned it
+    "error",  # it errored on the worker and is rescheduled
+    "dispatch_failed",  # its queue-add was not acknowledged, or was superseded
+)
+
 
 class FrameStatus(enum.Enum):
     PENDING = "pending"
@@ -170,6 +180,15 @@ class ClusterManagerState:
         # (exact, one float per unit): the p99 the predictive scheduler is
         # judged on (bench.py --speculation, chaos report stats).
         self.unit_seconds: list[float] = []
+        # Every time a unit left the worker that held it without a result:
+        # (unit, worker it left, cause, wall time), ``cause`` one of
+        # ``HANDBACK_CAUSES``. What the master reports of a unit that two
+        # workers may have rendered (``JobManager.handbacks_view``): a
+        # worker that had already taken the unit in hand renders it all
+        # the same, and its copy is the duplicate the dedup seam absorbs.
+        # It lives as long as the job is listed: the scheduler service
+        # empties it when the job leaves its list of ended jobs.
+        self.handbacks: list[tuple[WorkUnit, int | None, str, float]] = []
 
     # -- queries -----------------------------------------------------------
 
@@ -301,6 +320,7 @@ class ClusterManagerState:
         if stolen_from is not None:
             record.stolen_from = stolen_from
             record.stolen_at = stolen_at
+            self.handbacks.append((unit, stolen_from, "steal", queued_at))
         if self._pending and self._pending[0] == unit:
             self._pending.popleft()
         self._retrack(record, old)
@@ -347,17 +367,21 @@ class ClusterManagerState:
         if self.on_frame_assembled is not None:
             self.on_frame_assembled(frame_index)
 
-    def return_frame_to_pending(self, unit: "WorkUnit | int") -> None:
+    def return_frame_to_pending(self, unit: "WorkUnit | int", cause: str) -> None:
         """Unit comes back to the pool (steal succeeded, render errored,
         or its worker died). Unlike the reference — where a dead worker's
         frames stay QueuedOnWorker forever (SURVEY.md §5.3) — this makes
         eviction recoverable. Idempotent: under fault races (an eviction
         and a failed dispatch both returning the same unit) the second
-        call must not add a second pending entry."""
+        call must not add a second pending entry. ``cause`` (one of
+        ``HANDBACK_CAUSES``) is kept in ``handbacks``."""
+        if cause not in HANDBACK_CAUSES:
+            raise ValueError(f"unknown handback cause: {cause!r}")
         unit = self._as_unit(unit)
         record = self.frames[unit]
         if record.status in (FrameStatus.FINISHED, FrameStatus.PENDING):
             return
+        self.handbacks.append((unit, record.worker_id, cause, time.time()))
         old = record.status
         record.status = FrameStatus.PENDING
         record.worker_id = None

@@ -127,6 +127,41 @@ def check_job_failed(state: ClusterManagerState) -> None:
         raise RuntimeError(f"Job failed: {state.failed_reason}")
 
 
+def claim_pending_unit(
+    worker: "WorkerHandle", state: ClusterManagerState
+) -> WorkUnit | None:
+    """Claim the next pending unit of ``state`` for ``worker``, before any
+    RPC: concurrent assignment in the same pass cannot double-queue it.
+    ``send_claimed_unit`` confirms the claim or gives the unit back."""
+    unit = state.next_pending_unit()
+    if unit is not None:
+        state.mark_frame_as_queued(unit, worker.worker_id, time.time())
+    return unit
+
+
+async def send_claimed_unit(
+    worker: "WorkerHandle",
+    job: BlenderJob,
+    state: ClusterManagerState,
+    unit: WorkUnit,
+    *,
+    job_id: str | None = None,
+    trigger: str | None = None,
+) -> bool:
+    """RPC a claimed unit onto ``worker``; a unit whose queue-add fails
+    goes back to the pending pool. ``trigger``: the kind of the pass that
+    claimed it, where that pass does not wait here (master/wakeup.py)."""
+    try:
+        await worker.queue_frame(job, unit, job_id=job_id, trigger=trigger)
+    except Exception as e:  # noqa: BLE001 - worker failure mid-RPC
+        logger.warning(
+            "Failed to queue unit %s on %08x: %s", unit.label, worker.worker_id, e
+        )
+        state.return_frame_to_pending(unit, "dispatch_failed")
+        return False
+    return True
+
+
 async def dispatch_one_pending(
     worker: "WorkerHandle",
     job: BlenderJob,
@@ -136,27 +171,18 @@ async def dispatch_one_pending(
 ) -> bool:
     """Claim + RPC-dispatch one pending frame of ``state`` onto ``worker``.
 
-    The shared dispatch primitive: every single-job strategy and the
-    multi-job fair-share loop (sched/manager.py) go through here, so the
-    claim-before-RPC double-queue guard and the failure-requeue path have
-    exactly one definition. ``job_id`` is the scheduler's submission id,
-    piggybacked on the wire (None on the single-job path).
+    The shared dispatch primitive: every single-job strategy goes through
+    here, and the multi-job fair-share loop (sched/manager.py) through its
+    two halves (it claims for every worker first and sends to all of them
+    at once), so the claim-before-RPC double-queue guard and the
+    failure-requeue path have exactly one definition. ``job_id`` is the
+    scheduler's submission id, piggybacked on the wire (None on the
+    single-job path).
     """
-    unit = state.next_pending_unit()
+    unit = claim_pending_unit(worker, state)
     if unit is None:
         return False
-    # Claim immediately so concurrent assignment in the same tick can't
-    # double-queue the unit, then confirm via RPC.
-    state.mark_frame_as_queued(unit, worker.worker_id, time.time())
-    try:
-        await worker.queue_frame(job, unit, job_id=job_id)
-    except Exception as e:  # noqa: BLE001 - worker failure mid-RPC
-        logger.warning(
-            "Failed to queue unit %s on %08x: %s", unit.label, worker.worker_id, e
-        )
-        state.return_frame_to_pending(unit)
-        return False
-    return True
+    return await send_claimed_unit(worker, job, state, unit, job_id=job_id)
 
 
 async def _queue_one_pending(
@@ -285,7 +311,7 @@ async def steal_frame(
     )
     if victim.is_dead or not owned_by_victim:
         if owned_by_victim:
-            state.return_frame_to_pending(unit)
+            state.return_frame_to_pending(unit, "eviction")
         logger.warning(
             "Steal of unit %s aborted: victim %08x %s mid-steal.",
             unit.label,
@@ -298,7 +324,7 @@ async def steal_frame(
         await thief.queue_frame(job, unit, stolen_from=victim.worker_id)
     except Exception as e:  # noqa: BLE001
         logger.warning("Steal requeue failed on %08x: %s", thief.worker_id, e)
-        state.return_frame_to_pending(unit)
+        state.return_frame_to_pending(unit, "steal")
         return False
     logger.debug(
         "Stole unit %s: %08x -> %08x", unit.label, victim.worker_id, thief.worker_id
@@ -349,7 +375,7 @@ async def preempt_frame(
             victim.worker_id,
         )
         return False
-    state.return_frame_to_pending(unit)
+    state.return_frame_to_pending(unit, "preemption")
     return True
 
 

@@ -349,7 +349,7 @@ async def tpu_batch_strategy(
                             worker.worker_id,
                             e,
                         )
-                        state.return_frame_to_pending(unit)
+                        state.return_frame_to_pending(unit, "dispatch_failed")
 
                 tasks = []
                 for i, unit in enumerate(units):

@@ -19,12 +19,18 @@ wait on it and keep their ticks.
 
 **Coalescing**: it is one ``asyncio.Event``. A burst of results while the
 loop waits is one early pass; a result that lands while a pass is under
-way (the pass awaits its queue-add RPCs) starts one more pass after it.
+way starts one more pass after it. (``naive_fine_strategy``'s pass awaits
+its queue-add RPCs; the service's pass only picks and claims, and its
+queue-adds go out on a task a worker beside it, ``JobManager._send_claims``.)
 
 ``trigger`` is the kind of the pass under way, ``"event"`` or ``"tick"``
 (every pass of a loop that never waits here is a tick).
 ``master_dispatch_frames_total{trigger}`` counts the frames handed to
-workers by it; both label values exist at zero from the start.
+workers by the kind of the pass that CLAIMED them, once their queue-add is
+acknowledged: a pass whose queue-adds outlive it (the service's) hands its
+kind along with the claim, since ``wait()`` may have started the next pass
+by the time the acknowledgement lands; both label values exist at zero
+from the start.
 """
 
 from __future__ import annotations
@@ -70,6 +76,8 @@ class DispatchWakeup:
         self._event.clear()
         return self.trigger
 
-    def count_dispatched_frame(self) -> None:
+    def count_dispatched_frame(self, trigger: str | None = None) -> None:
+        """``trigger``: the kind of the pass that claimed the frame, where
+        that pass may be over by now; else the pass under way."""
         if self._dispatched is not None:
-            self._dispatched.inc(trigger=self.trigger)
+            self._dispatched.inc(trigger=trigger or self.trigger)

@@ -91,6 +91,19 @@ def heartbeat_pong_retries() -> int:
     return env_int("TRC_HEARTBEAT_PONG_RETRIES", 1)
 
 
+def rendered_twice_counter(metrics: MetricsRegistry):
+    """``sched_units_rendered_twice_total{cause}``: fed where a duplicate
+    ok result is taken; the scheduler service exposes it at 0 from its
+    start, so a scrape tells "none yet" from "no such counter"."""
+    return metrics.counter(
+        "sched_units_rendered_twice_total",
+        "Ok results for units that already had one, by the cause the master "
+        "recorded when the unit left a worker without a result (none: it "
+        "recorded nothing)",
+        labels=("cause",),
+    )
+
+
 class WorkerHandle:
     """One connected worker, as seen by the master."""
 
@@ -106,6 +119,7 @@ class WorkerHandle:
     ready_jobs: frozenset | set = frozenset()
     # The manager's dispatch wake-up (master/wakeup.py); None on a bare handle.
     _wakeup: DispatchWakeup | None = None
+    _on_job_ready = None
 
     def __init__(
         self,
@@ -127,10 +141,15 @@ class WorkerHandle:
         epoch: int | None = None,
         prepares_jobs: bool = False,
         wakeup: DispatchWakeup | None = None,
+        on_job_ready: Callable[["WorkerHandle", str, str | None], None]
+        | None = None,
     ) -> None:
         self.worker_id = worker_id
         self.prepares_jobs = prepares_jobs
         self._wakeup = wakeup
+        # Fires with each job this worker reports ready (the scheduler
+        # service closes the job's announcement span on it).
+        self._on_job_ready = on_job_ready
         self.ready_jobs = set()
         self.connection = connection
         # Master incarnation epoch (ha/ledger.py; None without a ledger):
@@ -411,6 +430,7 @@ class WorkerHandle:
         stolen_from: int | None = None,
         job_id: str | None = None,
         speculative: bool = False,
+        trigger: str | None = None,
     ) -> None:
         """RPC a work unit onto this worker's queue; sync mirror + state.
 
@@ -428,6 +448,10 @@ class WorkerHandle:
         re-pointed — the primary still owns it, so the first accepted ok
         result wins through the existing dedup seam exactly as a
         late-result race would (master/speculate.py resolves the loser).
+
+        ``trigger``: the kind of the dispatch pass that claimed the unit
+        (master/wakeup.py), from a caller whose pass does not wait for
+        this RPC; None counts the frame to the pass under way.
         """
         if isinstance(unit, int):
             unit = WorkUnit(unit)
@@ -502,7 +526,7 @@ class WorkerHandle:
 
             observe_dispatch_phase(self.metrics, "dispatch_rpc_await", rpc_seconds)
         if self._wakeup is not None:
-            self._wakeup.count_dispatched_frame()
+            self._wakeup.count_dispatched_frame(trigger)
         if self.span_tracer is not None:
             # Constant span name (frame index in args) so viewers and the
             # analysis roll-up aggregate all assignments into one stat.
@@ -968,6 +992,15 @@ class WorkerHandle:
                     state=state,
                     ledger_key="duplicate_results",
                 )
+                if self.metrics is not None:
+                    # Two renders of one unit, by what the master knows of
+                    # why the unit left a worker that held it (the newest
+                    # of ``state.handbacks``; "none": it knows of nothing).
+                    newest = next(
+                        (c for u, _w, c, _t in reversed(state.handbacks) if u == unit),
+                        "none",
+                    )
+                    rendered_twice_counter(self.metrics).inc(cause=newest)
                 self.logger.warning(
                     "Duplicate result for unit %s ignored.", unit.label
                 )
@@ -1043,7 +1076,7 @@ class WorkerHandle:
                 record.errored_count,
                 unit_error_limit(),
             )
-            state.return_frame_to_pending(unit)
+            state.return_frame_to_pending(unit, "error")
 
     def _record_winning_result(
         self,
@@ -1171,7 +1204,7 @@ class WorkerHandle:
                 and record.status is not FrameStatus.FINISHED
                 and record.worker_id == self.worker_id
             ):
-                state.return_frame_to_pending(unit)
+                state.return_frame_to_pending(unit, "drain")
                 requeued += 1
         self._update_queue_depth_gauge()
         if self.metrics is not None:
@@ -1209,6 +1242,8 @@ class WorkerHandle:
             while True:
                 event = await ready_queue.get()
                 self.ready_jobs.add((event.job_name, event.job_id))
+                if self._on_job_ready is not None:
+                    self._on_job_ready(self, event.job_name, event.job_id)
                 state = self._state_for(event.job_name)
                 if state is not None:
                     # the job's dispatchable demand changed: the scheduler

@@ -8,7 +8,17 @@ scheduler with ``nc``. Operations:
 - ``{"op": "submit", "spec": {"job": {...BlenderJob...}, "weight": 3, "priority": 0}}``
   -> ``{"ok": true, "job_id": "job-0001"}``
 - ``{"op": "status"}`` -> ``{"ok": true, "sched": {...scheduler_view...}}``
+  — its ``jobs`` lists every queued and running job and the newest 256
+  ended ones (``sched/manager.py::ENDED_JOBS_LISTED``), not every job the
+  service ever had
 - ``{"op": "status", "job_id": "job-0001"}`` -> ``{"ok": true, "job": {...}}``
+  — any job the service has had, ended ones from the view frozen as they ended
+- ``{"op": "handbacks"}`` / ``{"op": "handbacks", "since": 1759250000.5}`` ->
+  ``{"ok": true, "handbacks": [...]}`` — every unit of a listed job that left
+  a worker without a result (preemption, steal, eviction, drain, error,
+  failed dispatch), oldest first, with ``since`` those after that wall time:
+  what the master can say of a (job, frame) that two workers rendered. A
+  job's are dropped when it leaves the list of ended jobs
 - ``{"op": "cancel", "job_id": "job-0001"}`` -> ``{"ok": true, "cancelled": bool}``
 - ``{"op": "drain"}`` -> stop admitting; the service exits when idle
 - ``{"op": "migrate_workers", "count": 2, "host": "...", "port": N}``
@@ -60,6 +70,8 @@ async def handle_request(manager: "JobManager", request: dict[str, Any]) -> dict
             if view is None:
                 return {"ok": False, "error": f"unknown job_id: {job_id!r}"}
             return {"ok": True, "job": view}
+        if op == "handbacks":
+            return {"ok": True, "handbacks": manager.handbacks_view(request.get("since"))}
         if op == "cancel":
             job_id = request.get("job_id")
             if job_id is None:

@@ -167,3 +167,4 @@ def pick_preemption(
     if starved is None or over is None or starved.job_id == over.job_id:
         return None
     return over.job_id, starved.job_id
+

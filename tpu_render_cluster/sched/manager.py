@@ -9,8 +9,8 @@ and overriding the two multi-job hooks:
 - ``_state_for_job``: worker events route to the owning job's frame table
   by the reference ``job_name`` field every event already carries (so C++
   workers that echo no ``job_id`` piggyback still route correctly);
-- ``_active_job_announcements``: late-joining workers get one
-  ``event_job-started`` replay per ACTIVE job.
+- ``_active_job_announcements`` / ``_announce_active_jobs``: late-joining
+  workers get one ``event_job-started`` replay per ACTIVE job.
 
 Scheduling model (sched/fair_share.py): jobs are admitted from a queue
 (priority order, capped by ``TRC_SCHED_MAX_ACTIVE_JOBS`` and each job's
@@ -40,10 +40,14 @@ from typing import Any
 from tpu_render_cluster.master.cluster import ClusterManager
 from tpu_render_cluster.master.state import ClusterManagerState, FrameStatus
 from tpu_render_cluster.master.strategies import (
-    dispatch_one_pending,
+    claim_pending_unit,
     preempt_frame,
+    send_claimed_unit,
 )
-from tpu_render_cluster.master.worker_handle import WorkerHandle
+from tpu_render_cluster.master.worker_handle import (
+    WorkerHandle,
+    rendered_twice_counter,
+)
 from tpu_render_cluster.obs import MetricsRegistry, Tracer
 from tpu_render_cluster.sched import fair_share
 from tpu_render_cluster.sched.tickprof import TickProfiler
@@ -57,9 +61,17 @@ from tpu_render_cluster.sched.models import (
 )
 from tpu_render_cluster.sched.wfq import IncrementalWFQ
 from tpu_render_cluster.traces.worker_trace import WorkerTrace
+from tpu_render_cluster.utils.background import BackgroundTasks
 from tpu_render_cluster.utils.env import env_float, env_int, env_str
 
 logger = logging.getLogger(__name__)
+
+# ``status`` without a ``job_id`` lists every queued and running job and the
+# newest this many ended ones: a service that has run for a day has ended
+# tens of thousands, and a client that polls asks ten times a second.
+ENDED_JOBS_LISTED = 256
+# Buckets of ``sched_job_worker_units``: a count of workers, not of seconds.
+JOB_WORKERS_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0, 24.0, 32.0, 48.0, 64.0, 96.0)
 
 
 @dataclass(frozen=True)
@@ -170,6 +182,22 @@ class JobManager(ClusterManager):
         self._drain_stuck_since: float | None = None
         self._job_seq = 0
         self._started_serving = time.time()
+        # the newest ENDED_JOBS_LISTED ended job_ids, oldest first
+        self._ended: list[str] = []
+        # Queue-adds under way (``_send_claims``): the tasks, and per
+        # worker the claimed units its mirror does not hold yet.
+        self._sends = BackgroundTasks()
+        self._unacked: dict[int, int] = {}
+        # /metrics carries no process CPU otherwise: one Python loop admits,
+        # dispatches, takes every result and answers every ``status``.
+        self._process_cpu = self.metrics.counter(
+            "master_process_cpu_seconds_total",
+            "CPU seconds of the master's process (time.process_time()), "
+            "brought up to date by every pass of the scheduler loop",
+        )
+        self._process_cpu_seen = 0.0
+        self._note_process_cpu()
+        rendered_twice_counter(self.metrics).inc(0.0, cause="none")
 
     # -- ClusterManager hooks -------------------------------------------------
 
@@ -196,7 +224,145 @@ class JobManager(ClusterManager):
         ]
 
     def _jobs_view(self) -> dict:
-        return {job_id: run.view() for job_id, run in self._runs.items()}
+        """Every queued and running job and the newest ``ENDED_JOBS_LISTED``
+        ended ones, in submit order. An ended job's view is the one frozen as
+        it ended, so the list costs its live jobs' views and one dict
+        lookup an ended job; ``job_status(job_id)`` still answers for every
+        job the service has had."""
+        listed = self._ended + self._running + self._admission
+        return {job_id: self._runs[job_id].view() for job_id in sorted(listed, key=self._submit_order)}
+
+    @staticmethod
+    def _submit_order(job_id: str) -> int:
+        return int(job_id.rpartition("-")[2])
+
+    def _note_process_cpu(self) -> None:
+        used = time.process_time()
+        self._process_cpu.inc(max(0.0, used - self._process_cpu_seen))
+        self._process_cpu_seen = used
+
+    async def _announce_active_jobs(self, worker: WorkerHandle) -> None:
+        """A late joiner is replayed one announcement per RUNNING job, and
+        joins each such job's ``job_announce`` span if that is still open."""
+        for _trace_id, job_id, _job in self._active_job_announcements():
+            await self._announce(self._runs[job_id], worker)
+
+    async def _announce(self, run: JobRun, worker: WorkerHandle) -> None:
+        assert run.state is not None
+        if worker.prepares_jobs and not run.announce_closed:
+            run.announced_at[worker.worker_id] = time.time()
+        try:
+            await worker.send_job_started(
+                trace_id=run.state.trace_id, job_id=run.job_id, job=run.spec.job
+            )
+        except Exception as e:  # noqa: BLE001 - heartbeat will evict it
+            run.announced_at.pop(worker.worker_id, None)
+            logger.warning(
+                "job-started announce to %08x failed: %s", worker.worker_id, e
+            )
+
+    def _announce_histogram(self):
+        return self.metrics.histogram(
+            "sched_job_announce_seconds",
+            "From a job's admission to the first (edge=first_ready) and to "
+            "the last (edge=all_ready) of the workers it was announced to "
+            "reporting it ready",
+            labels=("edge",),
+        )
+
+    def _on_worker_job_ready(
+        self, worker: WorkerHandle, job_name: str, job_id: str | None
+    ) -> None:
+        run = self._active_by_name.get(job_name)
+        if (
+            run is None
+            or run.job_id != job_id
+            or run.admitted_at is None
+            or worker.worker_id not in run.announced_at
+            or worker.worker_id in run.ready_at
+        ):
+            return
+        now = time.time()
+        sent = run.announced_at[worker.worker_id]
+        run.ready_at[worker.worker_id] = now
+        self.span_tracer.complete(
+            "announce worker",
+            cat="sched",
+            start_wall=sent,
+            duration=max(0.0, now - sent),
+            track=f"job {run.job_id}",
+            args={"job_id": run.job_id, "worker": f"{worker.worker_id:08x}"},
+        )
+        if len(run.ready_at) == 1:
+            self._announce_histogram().observe(
+                max(0.0, now - run.admitted_at), edge="first_ready"
+            )
+        self._close_announce_if_all_ready(run, now)
+
+    def _close_announce_if_all_ready(self, run: JobRun, now: float) -> None:
+        """Write the job's ``job_announce`` span once every live worker it
+        was announced to has reported it ready (a worker that died in
+        between is no longer waited for)."""
+        if run.announce_closed or not run.ready_at:
+            return
+        live = {w.worker_id for w in self.live_workers()}
+        if any(
+            worker_id in live and worker_id not in run.ready_at
+            for worker_id in run.announced_at
+        ):
+            return
+        self._announce_histogram().observe(
+            max(0.0, now - run.admitted_at), edge="all_ready"
+        )
+        self._write_announce_span(run, now, all_ready=True)
+
+    def _write_announce_span(self, run: JobRun, now: float, *, all_ready: bool) -> None:
+        run.announce_closed = True
+        self.span_tracer.complete(
+            "job_announce",
+            cat="sched",
+            start_wall=run.admitted_at,
+            duration=max(0.0, now - run.admitted_at),
+            track=f"job {run.job_id}",
+            args={
+                "job_id": run.job_id,
+                "job_name": run.job_name,
+                "announced": len(run.announced_at),
+                "ready": len(run.ready_at),
+                "all_ready": all_ready,
+            },
+        )
+
+    def handbacks_view(self, since: float | None = None) -> list[dict[str, Any]]:
+        """Every unit that left a worker without a result, oldest first:
+        job, frame (and tile), the worker it left, the cause
+        (``master/state.py::HANDBACK_CAUSES``) and when; with ``since``
+        only those after that time. Over the jobs ``status`` lists: a job's
+        are dropped when it leaves the list of ended jobs, so a client that
+        wants them all asks as it goes, with the newest ``at`` it has. A
+        unit two workers rendered has an entry here, or the master handed
+        it out twice without knowing why."""
+        out = []
+        for job_id in [*self._ended, *self._running]:
+            run = self._runs[job_id]
+            if run.state is None:
+                continue
+            for unit, worker_id, cause, at in run.state.handbacks:
+                if since is not None and at <= since:
+                    continue
+                entry = {
+                    "job_id": run.job_id,
+                    "job_name": run.job_name,
+                    "frame": unit.frame_index,
+                    "worker": None if worker_id is None else f"{worker_id:08x}",
+                    "cause": cause,
+                    "at": at,
+                }
+                if unit.tile is not None:
+                    entry["tile"] = unit.tile
+                out.append(entry)
+        out.sort(key=lambda entry: entry["at"])
+        return out
 
     # -- lifecycle API --------------------------------------------------------
 
@@ -247,7 +413,7 @@ class JobManager(ClusterManager):
             "running": list(self._running),
             "total_slots": self._total_slots(),
             "rebalance": self.rebalance_view(),
-            "jobs": {job_id: run.view() for job_id, run in self._runs.items()},
+            "jobs": self._jobs_view(),
         }
 
     def rebalance_view(self) -> dict[str, Any]:
@@ -389,6 +555,11 @@ class JobManager(ClusterManager):
             try:
                 await self._scheduler_loop()
             finally:
+                # Queue-adds still under way belong to jobs that are gone
+                # (a drained loop has none running): nothing waits for them.
+                for task in self._sends.pending():
+                    task.cancel()
+                await self._sends.drain()
                 # Tiled jobs: stitches scheduled by the last finished
                 # events may still be in flight when the loop drains —
                 # or when it RAISES; either way they must land, not be
@@ -416,6 +587,7 @@ class JobManager(ClusterManager):
         while not self.cancellation.is_cancelled():
             now = time.time()
             dt, last = now - last, now
+            self._note_process_cpu()
             await self._admit_ready_jobs(now)
             self._finalize_finished_jobs(now)
             # SLO tick inline (the single-job master runs a sidecar task
@@ -603,16 +775,12 @@ class JobManager(ClusterManager):
                   "wait_s": round(now - run.submitted_at, 6)},
         )
         logger.info("Job %s admitted (%r).", run.job_id, run.job_name)
-        for worker in self.live_workers():
-            try:
-                await worker.send_job_started(
-                    trace_id=run.state.trace_id, job_id=run.job_id,
-                    job=run.spec.job,
-                )
-            except Exception as e:  # noqa: BLE001 - heartbeat will evict it
-                logger.warning(
-                    "job-started announce to %08x failed: %s", worker.worker_id, e
-                )
+        # To every live worker, all sends in flight together: a worker whose
+        # socket is slow to take the announcement does not hold back the
+        # others' (each prepares the job and reports it ready on its own).
+        await asyncio.gather(
+            *(self._announce(run, worker) for worker in self.live_workers())
+        )
 
     # -- completion / cancellation -------------------------------------------
 
@@ -633,6 +801,18 @@ class JobManager(ClusterManager):
         for worker in self.workers.values():
             worker.ready_jobs.discard((run.job_name, run.job_id))
         state = run.state
+        if run.announced_at and not run.announce_closed:
+            # ended before every worker had reported it ready
+            self._write_announce_span(run, now, all_ready=False)
+        if status == JOB_FINISHED and state is not None:
+            self.metrics.histogram(
+                "sched_job_worker_units",
+                "Distinct workers that rendered a finished job's frames",
+                buckets=JOB_WORKERS_BUCKETS,
+            ).observe(len({
+                record.worker_id for record in state.frames.values()
+                if record.worker_id is not None
+            }))
         if status == JOB_FINISHED and state is not None and run.admitted_at is not None:
             if state.first_queued_at is not None:
                 self._observe_job_phase(
@@ -700,6 +880,13 @@ class JobManager(ClusterManager):
                 args={"job_id": run.job_id, "job_name": run.job_name},
             )
         logger.info("Job %s %s (%r).", run.job_id, status, run.job_name)
+        run.final_view = run.view()
+        self._ended.append(run.job_id)
+        while len(self._ended) > ENDED_JOBS_LISTED:
+            # no longer listed: what the service reported of its units goes
+            unlisted = self._runs[self._ended.pop(0)].state
+            if unlisted is not None:
+                unlisted.handbacks.clear()
 
     def _finalize_finished_jobs(self, now: float) -> None:
         for job_id in list(self._running):
@@ -971,7 +1158,12 @@ class JobManager(ClusterManager):
     async def _dispatch_tick(
         self, inputs: list[fair_share.JobShareInput] | None = None
     ) -> None:
-        """Fill every under-target worker with the fairest job's frames.
+        """Fill every under-target worker with the fairest job's frames:
+        the picks one after another here, the queue-add RPCs beside the
+        pass, a task a worker (``_send_claims``). The tick profiler's
+        ``dispatch`` phase is therefore the picking and claiming alone;
+        what a queue-add's round trip costs stays in
+        ``master_assignment_latency_seconds`` and ``dispatch_rpc_await``.
 
         ``heap`` mode picks each slot's job with an O(log n) heap peek
         and folds the dispatch into the entry; ``scan`` keeps the legacy
@@ -1015,11 +1207,28 @@ class JobManager(ClusterManager):
                 )
             return out
 
-        workers = sorted(self.live_workers(), key=lambda w: len(w.queue))
+        # Plan here, send beside. Every pick and every claim of a unit is
+        # made in this pass without an await, in the order they were always
+        # made (the emptiest worker first, each filled to the target); each
+        # worker's queue-adds then go out on a task of their own, in order,
+        # and the pass does not wait for them. A worker slow to acknowledge
+        # holds back its own frames and nobody else's: its unacknowledged
+        # claims count against its target, so the next pass (a result
+        # elsewhere starts one at once) fills the others and leaves it be.
+        def depth(worker: WorkerHandle) -> int:
+            return len(worker.queue) + self._unacked.get(worker.worker_id, 0)
+
+        workers = sorted(self.live_workers(), key=depth)
+        # The kind of this pass goes with its claims: by the time a
+        # queue-add is acknowledged the loop may be in its next pass.
+        trigger = self.dispatch_wakeup.trigger
+        anything_pending = True
         for worker in workers:
+            claims: list[tuple[JobRun, Any]] = []
             while (
-                not worker.is_dead
-                and len(worker.queue) < self.config.target_queue_size
+                anything_pending
+                and not worker.is_dead
+                and depth(worker) + len(claims) < self.config.target_queue_size
             ):
                 if mode == "heap":
                     job_id, inputs_fn = self._wfq.pick_dispatch(), self._wfq.inputs
@@ -1029,42 +1238,72 @@ class JobManager(ClusterManager):
                     job_id = fair_share.pick_job_to_dispatch(inputs_now())
                     inputs_fn = inputs_now
                 if job_id is None:
-                    return  # nothing pending anywhere
+                    anything_pending = False  # nothing pending anywhere
+                    break
                 job_id = self._pick_for_worker(worker, job_id, inputs_fn)
                 if job_id is None:
                     break  # nothing pending that this worker has reported ready
                 run = self._runs[job_id]
                 assert run.state is not None
-                # Price the unit dispatch_one_pending is about to claim
-                # (the pool head) BEFORE the await so the local cost
-                # ledger can fold it in when the RPC lands.
-                next_unit = run.state.next_pending_unit()
-                predicted = (
-                    self.cost_service.predict_unit_seconds(
-                        worker.worker_id, next_unit, run.spec.job
-                    )
-                    if next_unit is not None
-                    else 0.0
-                )
-                if await dispatch_one_pending(
-                    worker, run.spec.job, run.state, job_id=job_id
-                ):
-                    if track_counts:
-                        counts[job_id][0] += 1
-                        counts[job_id][1] -= 1
-                        if counts[job_id][2] is not None:
-                            counts[job_id][2] += predicted
-                    if use_heap:
-                        self._wfq.on_dispatched(job_id, predicted)
-                else:
-                    # Dispatch failed (worker died mid-RPC, cancel raced,
-                    # or the pending pool emptied under us): stop filling
-                    # this worker; the pending count is refreshed next tick.
+                unit = claim_pending_unit(worker, run.state)
+                if unit is None:
+                    # The pending pool emptied under the entry: stop
+                    # offering it this pass; the next sync restores it.
                     if track_counts:
                         counts[job_id][1] = max(0, counts[job_id][1] - 1)
                     if use_heap:
                         self._wfq.on_dispatch_failed(job_id)
                     break
+                # The claimed unit's predicted seconds go into the local
+                # cost ledger with the claim, so one pass's fills stay
+                # cost-fair too.
+                predicted = self.cost_service.predict_unit_seconds(
+                    worker.worker_id, unit, run.spec.job
+                )
+                if track_counts:
+                    counts[job_id][0] += 1
+                    counts[job_id][1] -= 1
+                    if counts[job_id][2] is not None:
+                        counts[job_id][2] += predicted
+                if use_heap:
+                    self._wfq.on_dispatched(job_id, predicted)
+                claims.append((run, unit))
+            if claims:
+                self._unacked[worker.worker_id] = (
+                    self._unacked.get(worker.worker_id, 0) + len(claims)
+                )
+                self._sends.spawn(
+                    self._send_claims(worker, claims, trigger),
+                    name=f"queue-adds-{worker.worker_id:08x}",
+                )
+
+    async def _send_claims(
+        self, worker: WorkerHandle, claims: list[tuple[JobRun, Any]], trigger: str
+    ) -> None:
+        """One worker's share of a dispatch pass, in order, on a task of
+        its own. After a queue-add that fails (the worker died mid-RPC, a
+        cancel raced) the rest of its claims go back to their pools
+        unsent; every such return bumps the job's state version, so the
+        next pass (the tick's: a worker that refuses, as a draining one
+        does, must not be asked again at once) resyncs its entry from the
+        truth."""
+        failed = False
+        for run, unit in claims:
+            assert run.state is not None
+            try:
+                if failed:
+                    run.state.return_frame_to_pending(unit, "dispatch_failed")
+                    continue
+                failed = not await send_claimed_unit(
+                    worker, run.spec.job, run.state, unit, job_id=run.job_id,
+                    trigger=trigger,
+                )
+            finally:
+                left = self._unacked.get(worker.worker_id, 0) - 1
+                if left > 0:
+                    self._unacked[worker.worker_id] = left
+                else:
+                    self._unacked.pop(worker.worker_id, None)
 
     async def _preempt_tick(self) -> None:
         # 0 legitimately disables per-tick preemption without touching

@@ -89,6 +89,16 @@ class JobRun:
     overlap_target_integral: float = 0.0
     overlap_seconds: float = 0.0
     last_target_share: float = 0.0
+    # The job's announcement to the pool: when it was sent to each worker
+    # that prepares announced jobs (worker_id -> wall time), when each of
+    # them reported the job ready, and whether its ``job_announce`` span
+    # has been written (every announced worker ready, or the job ended).
+    announced_at: dict[int, float] = field(default_factory=dict)
+    ready_at: dict[int, float] = field(default_factory=dict)
+    announce_closed: bool = False
+    # An ended job's view as it ended: it never changes again, and a
+    # long-lived service answers ``status`` from it.
+    final_view: dict[str, Any] | None = None
     extras: dict[str, Any] = field(default_factory=dict)
 
     @property
@@ -127,6 +137,8 @@ class JobRun:
         """Live JSON view (cluster_view 'jobs' section / control 'status')."""
         from tpu_render_cluster.master.cluster import job_state_view
 
+        if self.final_view is not None:
+            return self.final_view
         out: dict[str, Any] = {
             "job_id": self.job_id,
             "job_name": self.job_name,

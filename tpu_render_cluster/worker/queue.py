@@ -381,7 +381,9 @@ class WorkerAutomaticQueue:
             if self._phase_histogram is not None:
                 self._phase_histogram.observe(duration, phase=phase)
             if self._span_tracer is not None:
-                args = {"frame": frame.frame_index}
+                # the job by name: a frame number alone does not say whose
+                # frame this worker rendered once several jobs share it
+                args = {"frame": frame.frame_index, "job": frame.job.job_name}
                 if frame.tile is not None:
                     args["tile"] = frame.tile
                 if frame.trace is not None:
