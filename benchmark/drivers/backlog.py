@@ -7,6 +7,12 @@ processes. This process never imports JAX. It sees the program from
 outside: the frame files, the `/metrics` endpoints of master and workers,
 and the files the workers export when they drain.
 
+What a run needs of its job: alive from the first frame to the window's
+end, and no longer (`child_exit`). The window's end is where the
+measurement is made; in a traced run's tail, while the workers write their
+profiles, a job that is done may end. Each configuration states the rate
+its smallest backlog holds under that rule (`held_rate`).
+
 What `run` hands to the per-layer readers (`lib/readers.py`), as `run`:
 
     window_s, workers, frames_per_s, render   the window, the cluster, the shape
@@ -20,6 +26,7 @@ What `run` hands to the per-layer readers (`lib/readers.py`), as `run`:
 from __future__ import annotations
 
 import dataclasses
+import http.client
 import json
 import os
 import shutil
@@ -32,13 +39,14 @@ from pathlib import Path
 
 from benchmark.lib import check, estimator, launch, readers, scrape, trace_reduce
 from benchmark.lib.launch import BenchFailure
-from benchmark.lib.manifest import BENCH_DIR, ROOT, Cell
+from benchmark.lib.manifest import BENCH_DIR, ROOT, Cell, load_benchmark
 from benchmark.lib.peaks import chip_peaks
 
 SETUP_SECONDS = 1000  # a first run compiles; the contract allows it 1200 s in all
 WARMUP_SECONDS = 240
 TRACE_WRITE_SECONDS = 90
 DRAIN_SECONDS = 90
+JOB_END_SECONDS = 15  # from the master's exit at its job's end to its workers' own
 # The profiler names a device operation by its HLO text; a Pallas kernel is
 # a custom call whose target is the TPU's kernel entry.
 KERNEL_PATTERN = r"tpu_custom_call"
@@ -71,6 +79,65 @@ def render_job_file(cell: Cell, seed: int, out: Path) -> tuple[str, int, int]:
         text = text.replace(token, value)
     out.write_text(text)
     return tomllib.loads(text)["job_name"], first, last
+
+
+def smallest_backlog(config: dict) -> int:
+    """Frames of the job at the highest first frame a seed can draw."""
+    start = config["frame_range_from"]
+    return config["frames"] - (start["first"] + start["span"] - 1) + 1
+
+
+def held_rate(config: dict, run_seconds: float) -> float | None:
+    """The rate, in frames a second over the whole cluster, up to which the
+    configuration's smallest backlog outlasts a run: what is left of it
+    after the warm-up, over the lull, the window and a margin for the
+    scrapes at the window's end. None where the configuration states no
+    `holds_frames_per_s`."""
+    stated = config.get("holds_frames_per_s")
+    if stated is None:
+        return None
+    seconds = stated["lull_s"] + run_seconds + stated["margin_s"]
+    return (smallest_backlog(config) - stated["warmup_frames"]) / seconds
+
+
+def job_ended_message(
+    cell: Cell, backlog: int, seen: dict[str, tuple[float, int]], window_start: float | None, seconds: float,
+) -> str:
+    """The one line a ledger's reader needs when a job has run out: how
+    long the backlog was, when it ended and at what rate, and the rate the
+    configuration says it holds."""
+    if window_start is None:
+        when = "before the window began"
+    else:
+        landed = [mtime for mtime, _ in seen.values() if mtime > window_start]
+        into = max(landed, default=window_start) - window_start
+        rate = f" at {len(landed) / into:.4g} frames/s" if into > 0 else ""
+        when = f"{into:.1f} s into the window of {seconds:g} s{rate}"
+    held = held_rate(cell.config, load_benchmark()["run_seconds"])
+    holds = "states no holds_frames_per_s" if held is None else f"holds to {held:.4g} frames/s"
+    return (
+        f"the job's backlog of {backlog} frames ended {when}; "
+        f"frame_range_from of {cell.config_name} {holds}"
+    )
+
+
+TAIL = "trace"  # the phase after the window's end, while the workers write their profiles
+
+
+def child_exit(phase: str, code: int, job_done: bool) -> str | None:
+    """What the exit of a child (the master or a worker) means for the run.
+    None: no fault. "job_ended": the job's backlog ran out before the
+    measurement was made. "fault": anything else.
+
+    A `run-job` master exits 0 when its job is done, and its workers exit 0
+    once they have answered its job-finished request (which may be before
+    the master itself has written its results and left): so an exit code
+    of 0 with every frame of the job on disk is the job's end. After the
+    window's end the run needs the job no longer; before it, the run
+    cannot stand for a measurement."""
+    if code != 0 or not job_done:
+        return "fault"
+    return None if phase == TAIL else "job_ended"
 
 
 def scan_frames(directory: Path, extension: str, seen: dict[str, tuple[float, int]]) -> None:
@@ -219,7 +286,7 @@ def _run_in(
     master_port, master_telemetry = launch.free_port(), launch.free_port()
     worker_telemetry = [launch.free_port() for _ in range(workers)]
 
-    processes.spawn(
+    master_process = processes.spawn(
         [sys.executable, "-m", "tpu_render_cluster.master.main",
          "--host", "127.0.0.1", "--port", str(master_port),
          "--telemetryPort", str(master_telemetry),
@@ -244,13 +311,31 @@ def _run_in(
             run_dir / f"worker-{index}.log", worker_env, ROOT,
         ))
 
+    children = [("the master", master_process)]
+    children += [(f"worker {index}", process) for index, process in enumerate(worker_processes)]
+    backlog = last_frame - first_frame + 1
+    seen: dict[str, tuple[float, int]] = {}
+    window_start = None
+
+    def check_children(phase: str) -> None:
+        """Raise unless every child is alive or has left as `child_exit` allows."""
+        for name, process in children:
+            code = process.poll()
+            if code is None:
+                continue
+            scan_frames(frames_dir, extension, seen)  # the verdict needs the files as they are now
+            verdict = child_exit(phase, code, len(seen) >= backlog)
+            if verdict == "job_ended":
+                raise BenchFailure(job_ended_message(cell, backlog, seen, window_start, seconds))
+            if verdict == "fault":
+                raise BenchFailure(f"{phase}: {name} ({' '.join(process.args[1:4])}) exited {code}")
+
     # Set-up ends when the first frame file is whole on disk: the master
     # starts the job only once every worker has connected, and a worker
     # connects only after it has warmed the cell's scene and shape.
-    seen: dict[str, tuple[float, int]] = {}
     deadline = time.monotonic() + SETUP_SECONDS
     while not seen:
-        processes.check_alive("set-up")
+        check_children("set-up")
         if time.monotonic() > deadline:
             raise BenchFailure("set-up: no frame within the deadline")
         time.sleep(0.02)
@@ -263,7 +348,7 @@ def _run_in(
     warmup_frames = cell.traffic["warmup_frames_per_worker"]
     deadline = time.monotonic() + WARMUP_SECONDS
     while True:
-        processes.check_alive("warm-up")
+        check_children("warm-up")
         done = [scrape.total(s, "worker_frames_rendered_total") or 0 for s in scrape_all(worker_telemetry)]
         if min(done) >= warmup_frames:
             break
@@ -288,23 +373,40 @@ def _run_in(
     slice_s = min(float(config["trace_slice_s"]), seconds / 2.0)
     trace_at = window_start + (seconds - slice_s) / 2.0 if trace else None
     while time.time() < window_end:
-        processes.check_alive("window")
+        check_children("window")
         if trace_at is not None and time.time() >= trace_at:
             for index in range(workers):
                 (run_dir / f"trace-{index}.go").write_text(str(slice_s))
             trace_at = None
         scan_frames(frames_dir, extension, seen)
         time.sleep(min(0.1, max(0.0, window_end - time.time())))
-    after = {"master": scrape_all([master_telemetry]), "workers": scrape_all(worker_telemetry)}
+    try:
+        after = {"master": scrape_all([master_telemetry]), "workers": scrape_all(worker_telemetry)}
+    except (OSError, http.client.HTTPException) as error:
+        check_children("window")  # a child that left as the window ended says why
+        raise BenchFailure(f"window: no scrape at the window's end: {error}") from None
     scraped_at = time.time()
     entries_after = cache_entries()
     time.sleep(0.05)  # a file renamed at the edge shows in the next scan
     scan_frames(frames_dir, extension, seen)
 
+    # The measurement is made. From here to the stop a job that is done may
+    # end: the master and then its workers exit 0, and a worker that leaves
+    # with its job still writes its profile first (`worker_entry.py`).
+    master_left_at = None
     if trace:
         deadline = time.monotonic() + TRACE_WRITE_SECONDS
-        while not all((run_dir / f"trace-{i}.done").exists() for i in range(workers)):
-            processes.check_alive("trace")
+        waiting = list(range(workers))
+        while waiting:
+            check_children(TAIL)
+            if master_left_at is None and master_process.poll() is not None:
+                master_left_at = time.time()
+            # Asked before the file is looked for: a worker writes its `.done`, then leaves.
+            left = {index for index in waiting if worker_processes[index].poll() is not None}
+            waiting = [index for index in waiting if not (run_dir / f"trace-{index}.done").exists()]
+            gone = sorted(left.intersection(waiting))
+            if gone:
+                raise BenchFailure(f"trace: workers {gone} left without writing their profile")
             if time.monotonic() > deadline:
                 raise BenchFailure("trace: a worker did not finish writing its trace")
             time.sleep(0.1)
@@ -312,9 +414,21 @@ def _run_in(
     # Stop: the workers drain (finish the frame in hand, export their spans
     # and snapshot, exit); a run-job master whose workers left mid-job would
     # wait for ever, so it is ended next.
+    if master_process.poll() == 0:  # the job is done: its workers are leaving by themselves
+        deadline = time.monotonic() + JOB_END_SECONDS
+        while time.monotonic() < deadline and any(p.poll() is None for p in worker_processes):
+            time.sleep(0.1)
     codes = processes.terminate(worker_processes, DRAIN_SECONDS)
+    master_code = master_process.poll()
     processes.kill_all()
-    say("stopped", worker_exit_codes=codes)
+    written = [
+        json.loads(path.read_text()).get("written_wall_s") for path in sorted(run_dir.glob("trace-*.done"))
+    ]
+    say(
+        "stopped", worker_exit_codes=codes, master_exit_code=master_code,
+        master_left_s_after_window=None if master_left_at is None else master_left_at - window_end,
+        profiles_written_s_after_window=[None if at is None else at - window_end for at in written],
+    )
     scan_frames(frames_dir, extension, seen)  # frames finished in the drain are on disk too
 
     files = sorted(
@@ -325,6 +439,7 @@ def _run_in(
     frames_per_s = estimator.slope_rate(times)
     say(
         "window", seconds=seconds, files=len(files), frames_per_s=frames_per_s, traced=trace,
+        backlog=backlog, backlog_left=backlog - sum(1 for mtime, _ in seen.values() if mtime <= window_end),
         per_second=estimator.per_second(times, window_start, seconds),
     )
     if frames_per_s is None:

@@ -410,8 +410,9 @@ def _run_in(
     )
     if frames_per_s is None:
         raise BenchFailure(f"only {len(in_window)} frames completed inside the window")
-    # Where a frame's time went, per frame of the window: detail beside the
-    # cell's per-layer metrics (the accepted step metrics list their cells).
+    # Where a frame's time went, per frame of the window: detail that an
+    # untraced run has too (a traced line carries the step metrics, which
+    # list this cell since PR 44).
     frames = scrape.delta(
         before["workers"], after["workers"], "worker_frame_phase_seconds_count", {"phase": "render"}
     )

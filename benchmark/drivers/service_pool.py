@@ -457,8 +457,8 @@ def _run_in(
         return scrape.delta([before["workers"][one]], [after["workers"][one]], series, labels) or 0.0
 
     # Where a frame's time went, per frame of the window, over the pool, and
-    # what each worker did: detail beside the cell's per-layer metrics (the
-    # accepted step metrics list their cells).
+    # what each worker did: detail that an untraced run has too (a traced
+    # line carries the step metrics, which list this cell since PR 44).
     frames = scrape.delta(
         before["workers"], after["workers"], "worker_frame_phase_seconds_count", {"phase": "render"}
     )
@@ -629,8 +629,9 @@ def _run_in(
             if value is not None:
                 result["metrics"][metric["name"]] = {"value": value, "unit": metric["unit"]}
         compiles = result["metrics"].get("compiles_in_window", {}).get("value", 0)
-        # The service's own metrics of the one-worker cell, whose lists
-        # name that cell alone: detail beside this cell's line.
+        # The service's own metrics as the one-worker cell names them, in
+        # one place (all but `jobs_per_min` list this cell too since PR 44,
+        # and are on the line).
         say("service_metrics", **{
             name: readers.read_metric(name, observed) for name in (
                 "job_admit_ms_mean", "job_finish_ms_mean", "jobs_per_min", "job_prepare_s_mean",

@@ -9,8 +9,10 @@ With `BENCH_TRACE=1` a watcher thread waits for `D/trace-<I>.go` (its text
 is the slice's length in seconds), traces that long into `D/trace-<I>/`,
 and writes `D/trace-<I>.done` with the slice's wall-clock edges. JAX is
 imported inside that thread only, after the worker has opened its device.
-When the worker's `main` returns (graceful drain on SIGTERM), the device's
-memory statistics go to `D/device-<I>.json`.
+When the worker's `main` returns (graceful drain on SIGTERM, or the job's
+end), the device's memory statistics go to `D/device-<I>.json`, and then
+the watcher is waited for: a worker that leaves while its profile is being
+written writes it out first.
 """
 
 from __future__ import annotations
@@ -86,9 +88,12 @@ def main(argv: list[str]) -> int:
         return worker_main(worker_argv)
     finally:
         stop.set()
+        # The device's numbers first: the harness takes `trace-<I>.done` as
+        # this worker's last word, and a worker that leaves with its job
+        # (no SIGTERM) is still writing its profile here.
+        _write_device_stats(directory, index)
         if watcher is not None:
             watcher.join(timeout=60)
-        _write_device_stats(directory, index)
 
 
 if __name__ == "__main__":

@@ -5,6 +5,7 @@ reducer, exactly as it read before."""
 
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -29,9 +30,9 @@ def test_the_new_metrics_are_data_files_and_nothing_else():
     assert manifest_lib.validate(ROOT) == []
     listed = {m["name"]: m for m in manifest_lib.load_benchmark(ROOT)["per_layer"]}
     every_cell = {w["name"] for w in manifest_lib.load_benchmark(ROOT)["workloads"]}
-    for name in STEP_METRICS:
+    for name in STEP_METRICS:  # every cell runs the worker's frame loop (the service cells since PR 44)
         assert set(listed[name]["workloads"]) == every_cell
-    assert listed["pool_live_lane_share"]["workloads"] == ["03ph2mesh-1w-queued"]
+    assert set(listed["pool_live_lane_share"]["workloads"]) == {w for w in every_cell if w.endswith("-1w-queued")}
     assert listed["wavefront_launch_occupancy"]["workloads"] == ["03ph2mesh-1w-fine"]
 
 
@@ -48,10 +49,9 @@ def test_every_new_metric_of_a_cell_is_a_number_on_a_rehearsals_line(cell, secon
         assert isinstance(result["metrics"][name]["value"], float), name
     metrics = {name: result["metrics"][name]["value"] for name in wanted}
     assert metrics["device_wait_ms_per_frame"] > 0 and metrics["encode_ms_per_frame"] > 0
-    if cell == "04vs-1w-coarse":
-        assert metrics["host_syncs_per_frame"] == 1.0
+    assert metrics["host_syncs_per_frame"] == 1.0  # a frame is one program in every cell since PR 30
     if cell == "03ph2mesh-1w-fine":
-        assert metrics["host_syncs_per_frame"] > 2 and 0 < metrics["wavefront_launch_occupancy"] <= 100
+        assert 0 < metrics["wavefront_launch_occupancy"] <= 100
     if cell == "03ph2mesh-1w-queued":
         assert 0 < metrics["pool_live_lane_share"] <= 100
     # the steps add up to the phases they lie in
@@ -80,7 +80,8 @@ def test_a_timeline_with_steps_reads_as_it_read_without_them(tmp_path):
             )
             timing = FrameRenderTime(at, at + 0.01, at + 0.01, at + 0.6, at + 0.6, at + 0.8, at + 0.81,
                                      steps=steps if with_steps else ())
-            queue._observe_frame_phases(QueuedFrame(None, frame, queued_at=at - 0.5), timing)
+            job = SimpleNamespace(job_name="a-job")  # a frame's spans carry their job's name since PR 43
+            queue._observe_frame_phases(QueuedFrame(job, frame, queued_at=at - 0.5), timing)
         return tracer.export(tmp_path / f"worker-{with_steps}_trace-events.json")
 
     with_steps, without = timeline(True), timeline(False)

@@ -37,8 +37,15 @@ def test_units_are_short_and_have_no_space(unit, ok):
 @pytest.mark.parametrize("cell_name", [w["name"] for w in BENCHMARK["workloads"]])
 def test_every_cell_finds_its_files_by_name(cell_name):
     cell = manifest.load_cell(cell_name)
-    assert (cell.config_dir / cell.config["job_template"]).is_file()
-    assert cell.traffic["driver"] == "backlog"
+    # the driver is the one the traffic file names, and the job templates are where it looks for them
+    assert (manifest.BENCH_DIR / "drivers" / f"{cell.traffic['driver']}.py").is_file()
+    if cell.traffic["driver"] == "backlog":
+        assert (cell.config_dir / cell.config["job_template"]).is_file()
+    else:  # a service configuration names accepted configurations and reads their templates
+        listed = {c["name"]: c for c in BENCHMARK["configs"]}
+        for family in cell.config["families"]:
+            directory = (manifest.ROOT / listed[family["config"]]["file"]).parent
+            assert (directory / "job.toml.template").is_file()
     assert cell.config["workers"] == cell.chips
     assert {m["name"] for m in cell.end_to_end} == {"frames_per_s", "setup_s"}
     for metric in cell.per_layer:

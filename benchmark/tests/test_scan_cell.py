@@ -31,9 +31,11 @@ def test_the_cell_and_its_metrics_find_their_files():
     assert cell.chips == 1 and cell.config["deployment"]["triangles_per_body"] == 871_200
     assert cell.traffic["strategy"]["strategy_type"] == "tpu-batch"
     names = {metric["name"] for metric in cell.per_layer}
-    # every accepted metric whose layer the cell runs reads here under its accepted name: the ten
-    # without a list, the twelve whose lists took the cell, and the four this configuration brought
-    assert NEW_METRICS < names and len(names) == 26
+    # every accepted metric whose layer the cell runs reads here under its accepted name; the count
+    # is the manifest's own: every metric without a list, and those that list the cell
+    benchmark = manifest.load_benchmark(ROOT)
+    wanted = {m["name"] for m in benchmark["per_layer"] if "workloads" not in m or CELL in m["workloads"]}
+    assert NEW_METRICS < names and names == wanted
     assert {"kernel_ms_per_frame", "device_wait_ms_per_frame", "host_syncs_per_frame",
             "masked_tier_frame_share", "pool_live_lane_share", "compiles_in_window"} < names
     assert "wavefront_launch_occupancy" not in names  # the naive-fine cell's name for the same counts

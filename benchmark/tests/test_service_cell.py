@@ -40,7 +40,10 @@ def test_the_cell_and_its_metrics_find_their_files():
     wanted = {m["name"] for m in benchmark["per_layer"] if "workloads" not in m or CELL in m["workloads"]}
     names = {metric["name"] for metric in cell.per_layer}
     assert names == wanted and NEW_METRICS < names
-    assert all(m["workloads"] == [CELL] for m in benchmark["per_layer"] if m["name"] in NEW_METRICS)
+    # the cell's own; the pool cell runs the same layers and took all but `jobs_per_min` in PR 44
+    assert all(
+        set(m["workloads"]) - {"svc2fam-4w-closed12"} == {CELL} for m in benchmark["per_layer"] if m["name"] in NEW_METRICS
+    )
     assert {metric["name"] for metric in cell.end_to_end} == {"frames_per_s", "setup_s"}
     # the families are accepted configurations, read and not copied
     listed = {c["name"] for c in benchmark["configs"]}
