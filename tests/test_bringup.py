@@ -153,6 +153,12 @@ def lower_for_tpu(monkeypatch):
 
     monkeypatch.setenv("TRC_PALLAS", "1")
     monkeypatch.setattr(pk, "_interpret", lambda: False)
+    # TRC_PALLAS is read at trace time and JAX keeps the traces of inner
+    # jitted functions: one that an earlier test of this process traced
+    # for the XLA twin would be found again here (which test files share a
+    # process is xdist's to decide), and one traced here must not be found
+    # by a later test.
+    jax.clear_caches()
 
     def lower(jitted, *args, **kwargs):
         text = jitted.trace(*args, **kwargs).lower(
@@ -161,7 +167,8 @@ def lower_for_tpu(monkeypatch):
         assert "tpu_custom_call" in text  # a Mosaic kernel, not interpret
         return text
 
-    return lower
+    yield lower
+    jax.clear_caches()
 
 
 def _f32():

@@ -1,0 +1,728 @@
+"""The two-stage frame: a frame's save runs beside the next frame's render.
+
+A fake two-stage backend (sleeps on threads, a record of what happened
+when) drives `WorkerAutomaticQueue` through the cases where timing
+matters; the tpu-raytrace backend on small CPU frames shows that the
+files, the steps and the series are what a serial frame's were; the
+reducers (`WorkerPerformance.from_worker_trace`, the analysis suite's
+utilization) are held to "idle is the time no frame covers" on an
+overlapped trace and to their old numbers on a serial one; and one job
+goes through `master run-job` and a `tpu-raytrace` worker process to its
+processed results.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import dataclasses
+import functools
+import json
+import os
+import socket
+import subprocess
+import sys
+import threading
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from tpu_render_cluster.jobs.models import BlenderJob, DistributionStrategy
+from tpu_render_cluster.obs import FRAME_STEPS, MetricsRegistry, Tracer, validate_trace_file
+from tpu_render_cluster.obs.prometheus import render_prometheus
+from tpu_render_cluster.protocol import messages as pm
+from tpu_render_cluster.traces.performance import WorkerPerformance
+from tpu_render_cluster.traces.worker_trace import (
+    FrameRenderTime,
+    WorkerFrameTrace,
+    WorkerTrace,
+    WorkerTraceBuilder,
+)
+from tpu_render_cluster.utils.cancellation import CancellationToken
+from tpu_render_cluster.worker.backends.base import RenderBackend, RenderedFrame
+from tpu_render_cluster.worker.backends.mock import MockBackend
+from tpu_render_cluster.worker.queue import LOOP_STATES, WorkerAutomaticQueue
+
+
+def make_job(name: str, frames: int, output: str = "%BASE%/out", file_format: str = "JPEG") -> BlenderJob:
+    return BlenderJob(
+        job_name=name,
+        job_description=None,
+        project_file_path="%BASE%/p.blend",
+        render_script_path="%BASE%/s.py",
+        frame_range_from=1,
+        frame_range_to=frames,
+        wait_for_number_of_workers=1,
+        frame_distribution_strategy=DistributionStrategy.naive_fine(),
+        output_directory_path=output,
+        output_file_name_format="rendered-#####",
+        output_file_format=file_format,
+    )
+
+
+class RecordingSender:
+    """Keeps what was sent, with the time and whether the frame's file was
+    there at that moment."""
+
+    def __init__(self, directory: Path | None = None) -> None:
+        self.sent: list[tuple[float, object, bool]] = []
+        self.directory = directory
+
+    async def send_message(self, message) -> None:
+        there = (
+            self.directory is not None
+            and isinstance(message, pm.WorkerFrameQueueItemFinishedEvent)
+            and (self.directory / f"{message.frame_index}.bin").exists()
+        )
+        self.sent.append((time.time(), message, there))
+
+    def finished(self) -> list[pm.WorkerFrameQueueItemFinishedEvent]:
+        return [m for _, m, _ in self.sent if isinstance(m, pm.WorkerFrameQueueItemFinishedEvent)]
+
+
+class TwoStageBackend(RenderBackend):
+    """A device stage and a save stage that sleep, each on the thread it is
+    given, and say when they began and ended: `log` holds
+    `(what, frame, wall time)` with `what` one of device_start, dispatched,
+    device_end, save_start, save_end."""
+
+    def __init__(
+        self, directory: Path, *, dispatch_seconds: float = 0.005, device_seconds: float = 0.04,
+        save_seconds: float = 0.02, fail_saves: frozenset[int] = frozenset(),
+        fail_devices: frozenset[int] = frozenset(),
+    ) -> None:
+        self.directory = directory
+        self.dispatch_seconds = dispatch_seconds
+        self.device_seconds = device_seconds
+        self.save_seconds = save_seconds
+        self.fail_saves = fail_saves
+        self.fail_devices = fail_devices
+        self.log: list[tuple[str, int, float]] = []
+        self._lock = threading.Lock()
+        self.save_threads: set[str] = set()
+
+    def note(self, what: str, frame: int) -> float:
+        now = time.time()
+        with self._lock:
+            self.log.append((what, frame, now))
+        return now
+
+    def times(self, what: str) -> dict[int, float]:
+        return {frame: at for name, frame, at in self.log if name == what}
+
+    async def render_frame(self, job, frame_index, tile=None):
+        rendered = await self.render_device_stage(job, frame_index, tile, dispatched=lambda: None)
+        return await asyncio.to_thread(rendered.save)
+
+    async def render_device_stage(self, job, frame_index, tile=None, *, dispatched):
+        return await asyncio.to_thread(self._device, frame_index, dispatched)
+
+    def _device(self, frame: int, dispatched) -> RenderedFrame:
+        started = self.note("device_start", frame)
+        if frame in self.fail_devices:
+            raise RuntimeError(f"device stage of frame {frame} failed")
+        time.sleep(self.dispatch_seconds)
+        self.note("dispatched", frame)
+        dispatched()
+        time.sleep(self.device_seconds)
+        ended = self.note("device_end", frame)
+        return RenderedFrame(save=functools.partial(self._save, frame, started, ended))
+
+    def _save(self, frame: int, started: float, rendered: float) -> FrameRenderTime:
+        self.save_threads.add(threading.current_thread().name)
+        save_started = self.note("save_start", frame)
+        time.sleep(self.save_seconds)
+        if frame in self.fail_saves:
+            self.note("save_end", frame)
+            raise OSError(f"disk full under frame {frame}")
+        self.directory.mkdir(parents=True, exist_ok=True)
+        (self.directory / f"{frame}.bin").write_bytes(b"pixels")
+        save_ended = self.note("save_end", frame)
+        return FrameRenderTime(
+            started_process_at=started,
+            finished_loading_at=started,
+            started_rendering_at=started,
+            finished_rendering_at=rendered,
+            file_saving_started_at=save_started,
+            file_saving_finished_at=save_ended,
+            exited_process_at=time.time(),
+        )
+
+
+@dataclasses.dataclass
+class Driven:
+    queue: WorkerAutomaticQueue
+    sender: RecordingSender
+    traces: WorkerTraceBuilder
+    metrics: MetricsRegistry
+    tracer: Tracer
+    wall: float = 0.0
+
+    def counter(self, name: str, **labels) -> float:
+        return self.metrics.counter(name, labels=tuple(labels)).value(**labels)
+
+
+def drive(backend, body, *, directory: Path | None = None) -> Driven:
+    """Run `body(driven)` (a coroutine function) against a started queue."""
+    driven = Driven(
+        queue=None, sender=RecordingSender(directory), traces=WorkerTraceBuilder(),
+        metrics=MetricsRegistry(), tracer=Tracer("worker-pipeline-test"),
+    )
+
+    async def run() -> None:
+        driven.queue = WorkerAutomaticQueue(
+            backend, driven.sender, driven.traces, CancellationToken(),
+            metrics=driven.metrics, span_tracer=driven.tracer,
+        )
+        started = time.perf_counter()
+        driven.queue.start()
+        try:
+            await asyncio.wait_for(body(driven), 150.0)
+        finally:
+            await driven.queue.join()
+        driven.wall = time.perf_counter() - started
+
+    asyncio.run(run())
+    return driven
+
+
+async def until(condition, seconds: float = 10.0) -> None:
+    deadline = time.perf_counter() + seconds
+    while not condition():
+        assert time.perf_counter() < deadline, "timed out"
+        await asyncio.sleep(0.002)
+
+
+def render_all(backend, frames: int, *, directory: Path | None = None, job=None) -> Driven:
+    job = job or make_job("two-stage", frames)
+
+    async def body(driven: Driven) -> None:
+        for frame in range(1, frames + 1):
+            driven.queue.queue_frame(job, frame)
+        await until(lambda: len(driven.sender.finished()) == frames)
+
+    return drive(backend, body, directory=directory)
+
+
+# -- the pipeline, on a fake backend ------------------------------------------------
+
+
+def test_the_next_device_stage_starts_before_the_save_ends_and_behind_its_dispatch(tmp_path):
+    backend = TwoStageBackend(tmp_path)
+    driven = render_all(backend, 5)
+    device_start, dispatched = backend.times("device_start"), backend.times("dispatched")
+    save_start, save_end = backend.times("save_start"), backend.times("save_end")
+    for frame in range(1, 5):
+        # beside: the next frame's device stage is open before this save ends
+        assert device_start[frame + 1] < save_end[frame]
+        # behind: and its device work was issued before this save began
+        assert dispatched[frame + 1] <= save_start[frame]
+    # the last frame has nothing to wait behind
+    assert save_start[5] - backend.times("device_end")[5] < 0.25
+    assert driven.counter("worker_frames_saved_beside_render_total") == 4
+    assert backend.save_threads == {"frame-save_0"}  # one thread, not the default executor's many
+
+
+def test_finished_events_leave_in_frame_order_and_each_after_its_file(tmp_path):
+    backend = TwoStageBackend(tmp_path, device_seconds=0.02, save_seconds=0.03)
+    driven = render_all(backend, 6, directory=tmp_path)
+    finished = driven.sender.finished()
+    assert [event.frame_index for event in finished] == [1, 2, 3, 4, 5, 6]
+    assert all(event.result == pm.FRAME_QUEUE_ITEM_FINISHED_OK for event in finished)
+    sent_at = {
+        message.frame_index: (at, there) for at, message, there in driven.sender.sent
+        if isinstance(message, pm.WorkerFrameQueueItemFinishedEvent)
+    }
+    save_end = backend.times("save_end")
+    for frame, (at, there) in sent_at.items():
+        assert there, f"finished event of frame {frame} left before its file was in place"
+        assert at >= save_end[frame]
+    # the rendering event of i+1 may precede the finished event of i, and here it does
+    order = [
+        (type(message).__name__, message.frame_index) for _, message, _ in driven.sender.sent
+    ]
+    assert order.index(("WorkerFrameQueueItemRenderingEvent", 2)) < order.index(
+        ("WorkerFrameQueueItemFinishedEvent", 1)
+    )
+    assert [frame.frame_index for frame in driven.traces._frame_render_traces] == [1, 2, 3, 4, 5, 6]
+
+
+@pytest.mark.parametrize("device_seconds,save_seconds", [(0.04, 0.01), (0.01, 0.04)])
+def test_never_more_than_one_frame_saving_and_one_in_its_device_stage(
+    tmp_path, device_seconds, save_seconds
+):
+    backend = TwoStageBackend(tmp_path, device_seconds=device_seconds, save_seconds=save_seconds)
+    driven = render_all(backend, 7)
+    open_stages = {"device": 0, "save": 0}
+    for what, _frame, _at in sorted(backend.log, key=lambda entry: entry[2]):
+        stage, edge = what.split("_") if "_" in what else (what, None)
+        if edge == "start":
+            open_stages[stage] += 1
+            assert open_stages[stage] <= 1, f"two frames in their {stage} stage"
+        elif edge == "end":
+            open_stages[stage] -= 1
+    waited = driven.counter("worker_loop_seconds_total", state="save_wait")
+    if save_seconds > device_seconds:
+        # save slower than render: the pipeline is full for the difference, a frame
+        assert waited > 4 * (save_seconds - device_seconds - 0.01)
+    else:
+        assert waited < 0.05
+
+
+def test_with_nothing_queued_the_save_starts_at_once_and_nothing_is_counted(tmp_path):
+    backend = TwoStageBackend(tmp_path)
+    job = make_job("one-at-a-time", 4)
+
+    async def body(driven: Driven) -> None:
+        for frame in range(1, 5):  # the next frame arrives on the finished event, as naive-fine sends it
+            driven.queue.queue_frame(job, frame)
+            await until(lambda: len(driven.sender.finished()) == frame)
+
+    driven = drive(backend, body)
+    device_end, save_start = backend.times("device_end"), backend.times("save_start")
+    assert all(save_start[frame] - device_end[frame] < 0.25 for frame in range(1, 5))
+    assert driven.counter("worker_frames_saved_beside_render_total") == 0
+    assert driven.counter("worker_loop_seconds_total", state="save_wait") == 0
+
+
+def test_a_frame_that_arrives_during_a_save_renders_beside_it(tmp_path):
+    backend = TwoStageBackend(tmp_path, device_seconds=0.01, save_seconds=0.25)
+    job = make_job("late-arrival", 2)
+
+    async def body(driven: Driven) -> None:
+        driven.queue.queue_frame(job, 1)
+        await until(lambda: 1 in backend.times("save_start"))
+        driven.queue.queue_frame(job, 2)
+        await until(lambda: len(driven.sender.finished()) == 2)
+
+    driven = drive(backend, body)
+    assert backend.times("device_start")[2] < backend.times("save_end")[1]
+    assert driven.counter("worker_frames_saved_beside_render_total") == 1
+    assert [event.frame_index for event in driven.sender.finished()] == [1, 2]
+
+
+def test_a_failing_save_errors_that_frame_alone(tmp_path):
+    backend = TwoStageBackend(tmp_path, fail_saves=frozenset({2}))
+    driven = render_all(backend, 4, directory=tmp_path)
+    finished = driven.sender.finished()
+    assert [event.frame_index for event in finished] == [1, 2, 3, 4]
+    assert [event.result for event in finished] == ["ok", "errored", "ok", "ok"]
+    assert "disk full" in finished[1].error_reason
+    assert sorted(path.name for path in tmp_path.iterdir()) == ["1.bin", "3.bin", "4.bin"]
+    assert driven.counter("worker_frames_errored_total") == 1
+    assert driven.counter("worker_frames_rendered_total") == 3
+    job_name = "two-stage"
+    assert driven.queue.unqueue_frame(job_name, 2) == pm.FRAME_QUEUE_REMOVE_RESULT_ERRORED  # not in the finished index
+    assert driven.queue.unqueue_frame(job_name, 3) == pm.FRAME_QUEUE_REMOVE_RESULT_ALREADY_FINISHED
+    # the frame in its device stage while frame 2's save failed went on undisturbed
+    assert backend.times("device_start")[3] < backend.times("save_end")[2] < backend.times("device_end")[3]
+
+
+def test_a_failing_device_stage_errors_that_frame_alone_and_in_order(tmp_path):
+    backend = TwoStageBackend(tmp_path, fail_devices=frozenset({2}))
+    driven = render_all(backend, 3, directory=tmp_path)
+    finished = driven.sender.finished()
+    assert [event.frame_index for event in finished] == [1, 2, 3]
+    assert [event.result for event in finished] == ["ok", "errored", "ok"]
+    assert sorted(path.name for path in tmp_path.iterdir()) == ["1.bin", "3.bin"]
+
+
+def in_hand(tmp_path: Path, then) -> tuple[TwoStageBackend, Driven]:
+    """Queue four frames and call `then(driven, backend, job)` at a moment
+    when frame 1 is saving and frame 2 is in its device stage."""
+    backend = TwoStageBackend(tmp_path, device_seconds=0.3, save_seconds=0.25)
+    job = make_job("in-hand", 4)
+
+    async def body(driven: Driven) -> None:
+        for frame in range(1, 5):
+            driven.queue.queue_frame(job, frame)
+        await until(lambda: 1 in backend.times("save_start") and 2 in backend.times("dispatched"))
+        assert 1 not in backend.times("save_end") and 2 not in backend.times("device_end")
+        await then(driven, backend, job)
+
+    return backend, drive(backend, body, directory=tmp_path)
+
+
+def test_unqueue_answers_already_rendering_in_either_stage(tmp_path):
+    answers = {}
+
+    async def then(driven, backend, job):
+        for frame in (1, 2, 3):
+            answers[frame] = driven.queue.unqueue_frame(job.job_name, frame)
+        await until(lambda: len(driven.sender.finished()) == 3)
+
+    _backend, driven = in_hand(tmp_path, then)
+    assert answers[1] == pm.FRAME_QUEUE_REMOVE_RESULT_ALREADY_RENDERING  # saving
+    assert answers[2] == pm.FRAME_QUEUE_REMOVE_RESULT_ALREADY_RENDERING  # in its device stage
+    assert answers[3] == pm.FRAME_QUEUE_REMOVE_RESULT_REMOVED
+    assert [event.frame_index for event in driven.sender.finished()] == [1, 2, 4]
+
+
+def test_drain_waits_for_both_frames_and_hands_back_the_rest(tmp_path):
+    returned = []
+
+    async def then(driven, backend, job):
+        returned.extend(await driven.queue.drain())
+        # both frames in hand are finished, files in place, events sent, when drain returns
+        assert set(backend.times("save_end")) == {1, 2}
+        assert [event.frame_index for event in driven.sender.finished()] == [1, 2]
+        with pytest.raises(RuntimeError):
+            driven.queue.queue_frame(job, 9)
+
+    backend, driven = in_hand(tmp_path, then)
+    assert [(name, unit.frame_index) for name, unit in returned] == [("in-hand", 3), ("in-hand", 4)]
+    assert set(backend.times("device_start")) == {1, 2}  # nothing started after the drain began
+    assert all(there for _, message, there in driven.sender.sent
+               if isinstance(message, pm.WorkerFrameQueueItemFinishedEvent))
+
+
+def test_reset_session_fences_a_saving_frame_as_it_fences_a_rendering_one(tmp_path):
+    async def then(driven, backend, job):
+        assert driven.queue.reset_session() == 2  # frames 3 and 4 were queued, not started
+        await until(lambda: len(driven.sender.finished()) == 2)
+        # both finished events went out (the new master refuses them by their epoch) ...
+        assert [event.frame_index for event in driven.sender.finished()] == [1, 2]
+        # ... and neither frame entered the new session's finished index
+        for frame in (1, 2):
+            assert driven.queue.unqueue_frame(job.job_name, frame) == pm.FRAME_QUEUE_REMOVE_RESULT_ERRORED
+        # a frame of the new session is indexed as ever
+        driven.queue.queue_frame(job, 3)
+        await until(lambda: len(driven.sender.finished()) == 3)
+        assert driven.queue.unqueue_frame(job.job_name, 3) == pm.FRAME_QUEUE_REMOVE_RESULT_ALREADY_FINISHED
+
+    in_hand(tmp_path, then)
+
+
+def test_joining_mid_pipeline_leaves_no_thread_blocked(tmp_path):
+    backend = TwoStageBackend(tmp_path, device_seconds=0.05, save_seconds=0.05)
+    job = make_job("cut-short", 6)
+
+    async def body(driven: Driven) -> None:
+        for frame in range(1, 7):
+            driven.queue.queue_frame(job, frame)
+        await until(lambda: 2 in backend.times("device_start"))
+
+    before = {thread for thread in threading.enumerate()}
+    drive(backend, body)
+    deadline = time.perf_counter() + 5.0
+    while time.perf_counter() < deadline:
+        left = [t for t in threading.enumerate() if t not in before and t.name.startswith("frame-save")]
+        if not left:
+            break
+        time.sleep(0.01)
+    assert not left
+
+
+def test_a_backend_with_no_save_stage_goes_through_the_same_loop_one_frame_at_a_time():
+    backend = MockBackend(load_seconds=0.001, render_seconds=0.01, save_seconds=0.004)
+    driven = render_all(backend, 4)
+    assert backend.rendered_frames == [1, 2, 3, 4]
+    frames = [trace.details for trace in driven.traces._frame_render_traces]
+    assert all(later.started_process_at >= earlier.exited_process_at
+               for earlier, later in zip(frames, frames[1:]))
+    assert driven.counter("worker_frames_saved_beside_render_total") == 0
+    assert driven.counter("worker_loop_seconds_total", state="save_wait") == 0
+    # every event of frame i before any event of frame i+1, as it always was
+    order = [message.frame_index for _, message, _ in driven.sender.sent]
+    assert order == [1, 1, 2, 2, 3, 3, 4, 4]
+
+
+def test_both_series_are_exposed_at_zero_from_the_workers_start():
+    async def body(driven: Driven) -> None:
+        await asyncio.sleep(0)
+
+    driven = drive(MockBackend(), body)
+    snapshot = driven.metrics.snapshot()
+    assert snapshot["worker_frames_saved_beside_render_total"]["series"]
+    states = {key.removeprefix("state=") for key in snapshot["worker_loop_seconds_total"]["series"]}
+    assert states == set(LOOP_STATES) == {"no_work", "render_call", "report", "save_wait"}
+    text = render_prometheus(snapshot)
+    assert "worker_frames_saved_beside_render_total 0" in text
+    assert 'worker_loop_seconds_total{state="save_wait"} 0' in text
+
+
+def test_the_four_loop_states_add_up_to_the_loops_wall_time_under_overlap(tmp_path):
+    backend = TwoStageBackend(tmp_path, device_seconds=0.02, save_seconds=0.03)
+    job = make_job("four-states", 6)
+
+    async def body(driven: Driven) -> None:
+        await asyncio.sleep(0.15)  # nothing queued yet: the loop starves
+        for frame in range(1, 7):
+            driven.queue.queue_frame(job, frame)
+        await until(lambda: len(driven.sender.finished()) == 6)
+
+    driven = drive(backend, body)
+    by_state = {state: driven.counter("worker_loop_seconds_total", state=state) for state in LOOP_STATES}
+    assert sum(by_state.values()) == pytest.approx(driven.wall, abs=0.05)
+    assert by_state["no_work"] == pytest.approx(0.15, abs=0.08)
+    assert by_state["save_wait"] > 0.02 and by_state["render_call"] > 0.1 and by_state["report"] > 0
+
+
+def test_the_overlapped_timeline_passes_the_trace_validator(tmp_path):
+    backend = TwoStageBackend(tmp_path / "frames", device_seconds=0.02, save_seconds=0.015)
+    driven = render_all(backend, 6)
+    path = driven.tracer.export(tmp_path / "worker-test_trace-events.json")
+    assert validate_trace_file(path) == []
+    events = [e for e in driven.tracer.events() if e.get("cat") == "worker"]
+    tracks = {
+        m["args"]["name"]: m["tid"] for m in driven.tracer.metadata_events() if m["name"] == "thread_name"
+    }
+    assert {e["tid"] for e in events if e["name"] == "write"} == {tracks["saves"]}
+    assert {e["tid"] for e in events if e["name"] in ("read", "render")} == {tracks["frames"]}
+    # a frame's write lies under the next frame's render: that is why it has a track of its own
+    writes = {e["args"]["frame"]: e for e in events if e["name"] == "write"}
+    renders = {e["args"]["frame"]: e for e in events if e["name"] == "render"}
+    assert any(
+        renders[frame + 1]["ts"] < writes[frame]["ts"] + writes[frame]["dur"] for frame in range(1, 6)
+    )
+
+
+# -- the tpu-raytrace backend through the pipeline ---------------------------------------
+
+
+@pytest.fixture(scope="module")
+def raytraced(tmp_path_factory):
+    """Three whole frames (JPEG) and the four tiles of a fourth (PNG) of the
+    sphere scene at 32x32, through the queue; the pixels each save stage was
+    handed are kept beside."""
+    from tpu_render_cluster.worker.backends.tpu_raytrace import TpuRaytraceBackend
+
+    base = tmp_path_factory.mktemp("pipeline")
+
+    class KeepsPixels(TpuRaytraceBackend):
+        def __init__(self, **kwargs) -> None:
+            super().__init__(**kwargs)
+            self.pixels: dict[tuple[int, int | None], np.ndarray] = {}
+
+        def _save_stage(self, job, frame_index, tile, pixels, **rendered):
+            self.pixels[(frame_index, tile)] = np.array(pixels)
+            return super()._save_stage(job, frame_index, tile, pixels, **rendered)
+
+    backend = KeepsPixels(base_directory=base, width=32, height=32, samples=1, max_bounces=2)
+    whole = make_job("04_very-simple_pipeline", 4)
+    tiled = dataclasses.replace(whole, tile_grid=(2, 2))
+    backend._render_sync(whole, 1)  # both programs built before the timed frames
+    backend._render_sync(tiled, 4, 0)
+    backend.pixels.clear()
+
+    async def body(driven: Driven) -> None:
+        for frame in (1, 2, 3):
+            driven.queue.queue_frame(whole, frame)
+        for tile in range(4):
+            driven.queue.queue_frame(tiled, 4, tile=tile)
+        await until(lambda: len(driven.sender.finished()) == 7, 120.0)
+
+    driven = drive(backend, body)
+    return base, backend, driven
+
+
+def test_a_frame_and_a_tile_through_the_pipeline_are_write_images_bytes(raytraced, tmp_path):
+    from tpu_render_cluster.render.image_io import write_image
+
+    base, backend, driven = raytraced
+    assert all(event.result == "ok" for event in driven.sender.finished())
+    written = sorted((base / "out").iterdir())
+    assert [path.name for path in written] == [
+        "rendered-00001.jpg", "rendered-00002.jpg", "rendered-00003.jpg",
+        "rendered-00004.tile_r0c0.png", "rendered-00004.tile_r0c1.png",
+        "rendered-00004.tile_r1c0.png", "rendered-00004.tile_r1c1.png",
+    ]
+    units = [(1, None), (2, None), (3, None), (4, 0), (4, 1), (4, 2), (4, 3)]
+    for path, unit in zip(written, units):
+        again = tmp_path / path.name
+        write_image(again, backend.pixels[unit], "PNG" if unit[1] is not None else "JPEG")
+        assert path.read_bytes() == again.read_bytes()
+    assert backend.pixels[(1, None)].shape == (32, 32, 3) and backend.pixels[(4, 0)].shape == (16, 16, 3)
+    assert not [path for path in (base / "out").iterdir() if path.name.endswith(".tmp")]
+
+
+def test_a_pipelined_frames_timing_holds_all_six_steps_and_feeds_the_series(raytraced):
+    _base, _backend, driven = raytraced
+    traces = driven.traces._frame_render_traces
+    assert len(traces) == 7
+    for trace in traces:
+        timing = trace.details
+        names = [name for name, _, _ in timing.steps]
+        assert names == list(FRAME_STEPS[:4]) + ["file_write", "encode", "file_write"]
+        points = [
+            timing.started_process_at, timing.finished_loading_at, timing.started_rendering_at,
+            timing.finished_rendering_at, timing.file_saving_started_at,
+            timing.file_saving_finished_at, timing.exited_process_at,
+        ]
+        assert points == sorted(points)
+        # the save stage's steps lie inside the write phase, the others before it
+        for name, start, seconds in timing.steps:
+            if name in ("encode", "file_write"):
+                assert timing.file_saving_started_at <= start
+                assert start + seconds <= timing.file_saving_finished_at + 1e-3
+            else:
+                assert start + seconds <= timing.finished_rendering_at + 1e-3
+    snapshot = driven.metrics.snapshot()
+    by_step = {
+        key.removeprefix("step="): entry
+        for key, entry in snapshot["worker_frame_step_seconds"]["series"].items()
+    }
+    assert set(by_step) == set(FRAME_STEPS)
+    assert by_step["device_wait"]["count"] == 7 and by_step["encode"]["count"] == 7
+    assert by_step["file_write"]["count"] == 14
+    phases = snapshot["worker_frame_phase_seconds"]["series"]
+    assert phases["phase=render"]["count"] == 7 and phases["phase=write"]["count"] == 7
+    # the save steps lie inside the write phase (what a loaded machine puts between two
+    # steps of a 32x32 frame, a thread waiting for the GIL, is in the phase and in no step)
+    write = sum(t.details.file_saving_finished_at - t.details.file_saving_started_at for t in traces)
+    assert 0 < by_step["encode"]["sum"] + by_step["file_write"]["sum"] <= write
+    assert phases["phase=write"]["sum"] == pytest.approx(write)
+    by_state = {state: driven.counter("worker_loop_seconds_total", state=state) for state in LOOP_STATES}
+    assert sum(by_state.values()) == pytest.approx(driven.wall, abs=0.1)
+    assert driven.counter("worker_frames_saved_beside_render_total") == 6  # all but the last
+    assert driven.counter("worker_frames_rendered_total") == 7
+
+
+# -- the trace of record under overlap ---------------------------------------------------
+
+
+def frame_at(start: float, *, read=1.0, render=2.0, gap=0.0, save=0.5, after=0.5) -> FrameRenderTime:
+    rendered = start + read + render
+    return FrameRenderTime(
+        started_process_at=start,
+        finished_loading_at=start + read,
+        started_rendering_at=start + read,
+        finished_rendering_at=rendered,
+        file_saving_started_at=rendered + gap,
+        file_saving_finished_at=rendered + gap + save,
+        exited_process_at=rendered + gap + save + after,
+    )
+
+
+def trace_of(frames: list[FrameRenderTime], start: float, finish: float) -> WorkerTrace:
+    return WorkerTrace(
+        total_queued_frames=len(frames),
+        total_queued_frames_removed_from_queue=0,
+        job_start_time=start,
+        job_finish_time=finish,
+        frame_render_traces=[WorkerFrameTrace(index + 1, frame) for index, frame in enumerate(frames)],
+        ping_traces=[],
+        reconnection_traces=[],
+    )
+
+
+def test_the_reducer_counts_idle_as_the_time_no_frame_covers():
+    # Four frames of 4 s each (3 s to the pixels, 1 s of saving), each begun
+    # when the one before has its pixels: starts 105, 108, 111; then a gap
+    # of 2 s that no frame covers, a frame at 117, and a tail.
+    frames = [frame_at(105.0), frame_at(108.0), frame_at(111.0), frame_at(117.0)]
+    trace = trace_of(frames, 100.0, 124.0)
+    performance = WorkerPerformance.from_worker_trace(trace)  # raised "Idle time between frames is negative"
+    assert performance.total_frames_rendered == 4
+    assert performance.total_blend_file_reading_time == pytest.approx(4.0)
+    assert performance.total_rendering_time == pytest.approx(8.0)
+    assert performance.total_image_saving_time == pytest.approx(2.0)
+    # lead-in 5, nothing between the overlapped three, tail 124 - 121 = 3; the
+    # last frame's gap to its predecessor is not counted, as in the reference
+    assert performance.total_idle_time == pytest.approx(8.0)
+    # with one more frame behind, that gap of 2 s (115 -> 117) is a middle one and counts
+    longer = trace_of(frames + [frame_at(121.0)], 100.0, 128.0)
+    assert WorkerPerformance.from_worker_trace(longer).total_idle_time == pytest.approx(5.0 + 2.0 + 3.0)
+
+
+def test_the_reducer_reads_a_serial_trace_as_it_always_did():
+    frames = [frame_at(105.0), frame_at(111.0), frame_at(118.0)]
+    performance = WorkerPerformance.from_worker_trace(trace_of(frames, 100.0, 126.0))
+    # lead-in 5 + gap (111 - 109) 2 + tail (126 - 122) 4; tests/test_traces.py has the same numbers
+    assert performance.total_idle_time == pytest.approx(11.0)
+    assert performance.total_time == 26.0
+    assert performance.total_rendering_time == pytest.approx(6.0)
+
+
+def job_trace_of(worker: WorkerTrace):
+    from tpu_render_cluster.analysis.models import JobTrace
+
+    return JobTrace(
+        job=make_job("utilization", 4), job_started_at=worker.job_start_time,
+        job_finished_at=worker.job_finish_time, worker_traces={"worker-a": worker},
+    )
+
+
+def test_utilization_is_the_union_of_the_frames_and_at_most_one():
+    from tpu_render_cluster.analysis.metrics import worker_utilizations
+    from tpu_render_cluster.analysis.models import mean_frame_time, worker_active_time
+
+    # ten frames of 4 s, a new one every 3 s: the sum of their durations is 40 s of a 31 s window
+    frames = [frame_at(100.0 + 3.0 * index) for index in range(10)]
+    overlapped = trace_of(frames, 100.0, 131.0)
+    assert sum(frame.total_execution_time() for frame in frames) == pytest.approx(40.0)
+    assert worker_active_time(overlapped) == pytest.approx(31.0)
+    assert mean_frame_time(overlapped) == pytest.approx(4.0)
+    serial = trace_of([frame_at(105.0), frame_at(111.0), frame_at(118.0)], 100.0, 126.0)
+    assert worker_active_time(serial) == pytest.approx(12.0)  # the plain sum, as before
+    assert mean_frame_time(serial) == pytest.approx(4.0)
+    (utilization,) = worker_utilizations(job_trace_of(overlapped))
+    assert utilization.utilization == pytest.approx(1.0) and utilization.utilization <= 1.0
+    assert utilization.utilization_without_tail <= 1.0
+    (serial_utilization,) = worker_utilizations(job_trace_of(serial))
+    assert serial_utilization.utilization == pytest.approx(12.0 / 26.0)
+
+
+# -- a whole job through `master run-job` ------------------------------------------------
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def test_a_job_run_to_its_end_writes_its_processed_results_from_an_overlapped_trace(tmp_path):
+    frames_dir = tmp_path / "frames"
+    job_path = tmp_path / "job.toml"
+    job_path.write_text(f'''
+job_name = "04_very-simple_overlap"
+job_description = "save beside render, through run-job"
+project_file_path = "%BASE%/p.blend"
+render_script_path = "%BASE%/s.py"
+frame_range_from = 1
+frame_range_to = 8
+wait_for_number_of_workers = 1
+output_directory_path = "{frames_dir}"
+output_file_name_format = "rendered-####"
+output_file_format = "JPEG"
+
+[frame_distribution_strategy]
+strategy_type = "eager-naive-coarse"
+target_queue_size = 8
+''')
+    port = free_port()
+    results = tmp_path / "results"
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    master = subprocess.Popen(
+        [sys.executable, "-m", "tpu_render_cluster.master.main", "--host", "127.0.0.1",
+         "--port", str(port), "run-job", str(job_path), "--resultsDirectory", str(results)],
+        env=env,
+    )
+    worker = subprocess.Popen(
+        [sys.executable, "-m", "tpu_render_cluster.worker.main", "--masterServerHost", "127.0.0.1",
+         "--masterServerPort", str(port), "--baseDirectory", str(tmp_path), "--backend", "tpu-raytrace",
+         "--renderSize", "32x32", "--renderSamples", "2", "--warmScene", "04_very-simple"],
+        env=env,
+    )
+    try:
+        assert master.wait(timeout=300) == 0
+        worker.wait(timeout=60)
+    finally:
+        for process in (worker, master):
+            if process.poll() is None:
+                process.kill()
+    assert len(list(frames_dir.glob("rendered-*.jpg"))) == 8
+    raw = json.loads(next(results.glob("*_raw-trace.json")).read_text())
+    (worker_trace,) = raw["worker_traces"].values()
+    frames = [entry["details"] for entry in worker_trace["frame_render_traces"]]
+    assert [entry["frame_index"] for entry in worker_trace["frame_render_traces"]] == list(range(1, 9))
+    # the trace of record IS overlapped: a frame began before the one before it had left
+    assert any(later["started_process_at"] < earlier["exited_process_at"]
+               for earlier, later in zip(frames, frames[1:]))
+    processed = json.loads(next(results.glob("*_processed-results.json")).read_text())
+    (performance,) = processed["worker_performance"].values()
+    assert performance["total_frames_rendered"] == 8
+    assert 0.0 <= performance["total_idle_time"] <= performance["total_time"]
+    assert validate_trace_file(next(results.glob("*_cluster_trace-events.json"))) == []

@@ -5,8 +5,9 @@ there was none, a `trc:<step>` annotation in a profile), the three unit
 shapes of the tpu-raytrace backend on a 64x64 CPU frame (all six steps,
 adding up to the phases), `write_image`'s split (the file on disk is
 the parent's, byte for byte), and the worker queue (steps enter the
-registry and the timeline with the phases; the loop counter's three states
-add up to the loop's wall time).
+registry and the timeline with the phases; the loop counter's four states
+add up to the loop's wall time; tests/test_frame_pipeline.py holds the same
+sum under overlap).
 """
 
 from __future__ import annotations
@@ -558,7 +559,7 @@ def run_queue(backend, frames: int, *, idle_seconds: float = 0.0, sender_seconds
     return asyncio.run(drive()), metrics, span_tracer
 
 
-def test_the_loops_three_states_add_up_to_its_wall_time():
+def test_the_loops_four_states_add_up_to_its_wall_time():
     backend = MockBackend(load_seconds=0.002, render_seconds=0.02, save_seconds=0.002)
     wall, metrics, _ = run_queue(backend, 6, idle_seconds=0.25, sender_seconds=0.003)
     counter = metrics.counter("worker_loop_seconds_total", labels=("state",))
@@ -567,6 +568,7 @@ def test_the_loops_three_states_add_up_to_its_wall_time():
     assert by_state["render_call"] == pytest.approx(6 * 0.024, abs=0.03)
     assert by_state["no_work"] == pytest.approx(0.5, abs=0.06)
     assert by_state["report"] >= 6 * 2 * 0.003  # two events a frame through the sender
+    assert by_state["save_wait"] == 0.0  # a backend with no save stage never fills the pipeline
 
 
 def test_a_failed_frame_is_charged_to_the_render_call_and_the_loop_goes_on():

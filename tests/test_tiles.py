@@ -41,6 +41,7 @@ from tpu_render_cluster.master.queue_mirror import FrameOnWorker, WorkerQueueMir
 from tpu_render_cluster.master.state import ClusterManagerState, FrameStatus
 from tpu_render_cluster.master.strategies import preempt_frame, steal_frame
 from tpu_render_cluster.protocol import messages as pm
+from tpu_render_cluster.worker.backends.base import RenderBackend
 
 pytestmark = pytest.mark.tiles
 
@@ -561,7 +562,7 @@ class TestTiledClusterE2E:
         assert not list((tmp_path / "tiled").glob("*.tile_*"))
 
 
-class _AlwaysFailBackend:
+class _AlwaysFailBackend(RenderBackend):
     """A backend that deterministically cannot render (the Blender-backend
     tiled-unit shape)."""
 

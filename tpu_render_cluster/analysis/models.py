@@ -77,11 +77,26 @@ def worker_tail_delay(trace: WorkerTrace, global_last_finish: float) -> float:
 
 
 def worker_active_time(trace: WorkerTrace) -> float:
-    """Total wall time spent inside frame renders."""
-    return sum(t.details.total_execution_time() for t in trace.frame_render_traces)
+    """Total wall time spent inside frame renders: the union of the
+    frames' intervals. A worker saves frame i while it renders frame i+1
+    (worker/queue.py), so consecutive frames overlap and their plain sum
+    would count that time twice (a utilization over 1); on a serial trace
+    the union IS the sum."""
+    active, covered_until = 0.0, float("-inf")
+    for start, end in sorted(
+        (t.details.started_process_at, t.details.exited_process_at)
+        for t in trace.frame_render_traces
+    ):
+        if end < start:
+            raise ValueError("Total execution time is negative?!")
+        active += max(0.0, end - max(start, covered_until))
+        covered_until = max(covered_until, end)
+    return active
 
 
 def mean_frame_time(trace: WorkerTrace) -> float:
-    if not trace.frame_render_traces:
+    """Mean duration of a frame, first point to last (overlap and all)."""
+    frames = trace.frame_render_traces
+    if not frames:
         return 0.0
-    return worker_active_time(trace) / len(trace.frame_render_traces)
+    return sum(t.details.total_execution_time() for t in frames) / len(frames)

@@ -40,8 +40,15 @@ _pid_counter = itertools.count(1)
 # is 201,600 events.
 MAX_EVENTS = 300_000
 
-# The steps of one frame on the worker's render thread, the same for every
-# unit shape. At every instant of a frame exactly one is open:
+# The steps of one frame, the same for every unit shape. Exclusive PER
+# THREAD (the sink below is thread-local): the first four are the frame's
+# device stage on the render thread, the last two its save stage on the
+# save thread, and on either thread at most one step is open at an
+# instant. Across threads they overlap: the worker's queue saves frame i
+# while it renders frame i+1 (worker/queue.py), so frame i's ``encode`` and
+# ``file_write`` lie under frame i+1's ``device_wait``. A frame's own
+# steps never overlap each other and are handed over together, in the
+# order they ended:
 #   resolve      scene name, tile region, unit shape, compiled-renderer fetch
 #   dispatch     host time issuing device work that does not block (asking
 #                for the copy back included)
@@ -87,10 +94,12 @@ class _Segment:
 
 @contextmanager
 def step(name: str) -> Iterator[None]:
-    """One of FRAME_STEPS, timed where it happens, EXCLUSIVELY.
+    """One of FRAME_STEPS, timed where it happens, EXCLUSIVELY on its
+    thread.
 
     A step opened inside another suspends the outer one until it ends, so
-    a frame's steps never overlap and add up to the frame. Each
+    the steps one thread takes for a frame never overlap and add up to
+    that thread's stage of the frame. Each
     uninterrupted stretch (a suspended step resumes as a new one) is
     remembered as ``(name, start_wall, seconds)`` for the frame in hand
     (``frame_steps``; nothing is kept outside one), and lies inside a

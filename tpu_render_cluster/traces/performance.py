@@ -8,6 +8,13 @@ branch ordering means the last frame's gap to its predecessor is *not*
 counted — we replicate that deliberately since processed-results numbers are
 part of the metric contract. Durations serialise as fractional seconds
 (``DurationSecondsWithFrac<f64>`` equivalence).
+
+One extension: a worker whose queue saves frame *i* while it renders frame
+*i+1* (worker/queue.py) hands in frames whose intervals overlap. Idle is
+the time NO frame covers: a gap counts from the latest exit of any earlier
+frame, and a frame that starts under an earlier one adds none. On a serial
+trace, where each frame starts after the one before has exited, every
+number is the reference's.
 """
 
 from __future__ import annotations
@@ -48,6 +55,7 @@ class WorkerPerformance:
         idle = 0.0
 
         frames = trace.frame_render_traces
+        covered_until = trace.job_start_time  # latest exit of the frames so far
         for i, frame in enumerate(frames):
             d = frame.details
             reading += _nonnegative(
@@ -66,14 +74,12 @@ class WorkerPerformance:
                 )
             elif i == len(frames) - 1:
                 idle += _nonnegative(
-                    trace.job_finish_time - d.exited_process_at,
+                    trace.job_finish_time - max(covered_until, d.exited_process_at),
                     "Idle time after last frame",
                 )
             else:
-                idle += _nonnegative(
-                    d.started_process_at - frames[i - 1].details.exited_process_at,
-                    "Idle time between frames",
-                )
+                idle += max(0.0, d.started_process_at - covered_until)
+            covered_until = max(covered_until, d.exited_process_at)
 
         return cls(
             total_frames_rendered=len(frames),

@@ -3,13 +3,37 @@
 from __future__ import annotations
 
 import abc
+from dataclasses import dataclass
+from typing import Callable
 
 from tpu_render_cluster.jobs.models import BlenderJob
 from tpu_render_cluster.traces.worker_trace import FrameRenderTime
 
 
+@dataclass(frozen=True)
+class RenderedFrame:
+    """A frame whose device stage is done: its pixels are on the host.
+
+    ``save`` is the frame's save stage (encode, temporary file, write,
+    close, rename): a plain blocking call for the thread the worker's
+    queue gives it, which returns the frame's seven points once the file
+    is in place. The queue runs it beside the NEXT frame's device stage.
+    """
+
+    save: Callable[[], FrameRenderTime]
+
+
 class RenderBackend(abc.ABC):
-    """Renders one frame of a job and reports 7-phase timing.
+    """Renders the frames of a job and reports 7-phase timing.
+
+    A frame has two stages. The **device stage** ends with the pixels on
+    the host; the **save stage** ends with the file renamed into place.
+    The worker's queue asks for the device stage (``render_device_stage``)
+    and, where the backend hands back a ``RenderedFrame``, runs that
+    frame's save stage while the next frame is in its device stage: up to
+    two frames of one backend are in hand at a time, never two in the
+    same stage. A backend with no separable save stage implements
+    ``render_frame`` alone and is asked for one whole frame at a time.
 
     Implementations must write the output file to the job's resolved output
     directory and return a ``FrameRenderTime`` whose phases satisfy the
@@ -35,4 +59,23 @@ class RenderBackend(abc.ABC):
     async def render_frame(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
     ) -> FrameRenderTime:
-        ...
+        """One whole frame: returns when its file is in place."""
+
+    async def render_device_stage(
+        self,
+        job: BlenderJob,
+        frame_index: int,
+        tile: int | None = None,
+        *,
+        dispatched: Callable[[], None],
+    ) -> RenderedFrame | FrameRenderTime:
+        """The frame's device stage, or, from a backend that cannot part
+        the two, the whole frame (a ``FrameRenderTime``: nothing is left
+        to save). ``dispatched`` is called, from any thread, once the
+        frame's device work has been issued: the frame before begins its
+        save stage behind that call, so that encoding does not contend
+        with the Python that feeds the device. The caller lets go itself
+        when the stage returns or raises, so a stage that fails early
+        need not call it."""
+        dispatched()
+        return await self.render_frame(job, frame_index, tile=tile)

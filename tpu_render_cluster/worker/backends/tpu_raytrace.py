@@ -7,11 +7,17 @@ backends apart (BASELINE.md north star). Phase mapping:
 
 - started_process/finished_loading: scene + camera build (host->device);
 - started/finished_rendering: device compute (block_until_ready fenced);
-- file_saving: tonemap + PNG/JPEG encode + write;
+- file_saving: PNG/JPEG encode + write;
 - exited_process: after the output file hits disk.
 
-The heavy work runs in a thread (`asyncio.to_thread`) so heartbeats and
-queue RPCs stay responsive while a frame renders.
+A frame is two stages (``RenderBackend``): the **device stage**
+(``resolve``, ``dispatch``, ``device_wait``, ``readback``: the u8 pixels
+are on the host) and the **save stage** (``encode``, ``file_write``). Each
+is a blocking call on a thread the event loop does not run on, so
+heartbeats and queue RPCs stay responsive; the worker's queue runs the
+save stage of frame *i* beside the device stage of frame *i+1*, so the
+seven points of two consecutive frames overlap: frame *i*'s saving lies
+inside frame *i+1*'s rendering.
 
 A frame's program is picked per JOB: ``(scene family, width, height,
 samples, max_bounces)``, the shape from the job's ``[render]`` table over
@@ -27,14 +33,16 @@ thread; a frame that meets a preparation in hand waits for it.
 from __future__ import annotations
 
 import asyncio
+import functools
 import threading
 import time
 from pathlib import Path
+from typing import Callable
 
 from tpu_render_cluster.jobs.models import BlenderJob
 from tpu_render_cluster.traces.worker_trace import FrameRenderTime
 from tpu_render_cluster.utils.paths import parse_with_base_directory_prefix
-from tpu_render_cluster.worker.backends.base import RenderBackend
+from tpu_render_cluster.worker.backends.base import RenderBackend, RenderedFrame
 
 
 # Linear bucket bounds for render_launch_occupancy: fractions live in
@@ -254,7 +262,7 @@ class TpuRaytraceBackend(RenderBackend):
             return sharded_frame_renderer(scene_name, *shape, self.sharding)(1)
         from tpu_render_cluster.render.integrator import fused_frame_renderer
 
-        # The program _render_timed runs: with the live counts.
+        # The program _render_pixels runs: with the live counts.
         display, *_ = fused_frame_renderer(scene_name, *shape, with_live=True)(1)
         return display
 
@@ -338,6 +346,18 @@ class TpuRaytraceBackend(RenderBackend):
                 float(sum(sizes[space] for sizes in self._geometry.values())),
                 space=space,
             )
+
+    async def render_device_stage(
+        self,
+        job: BlenderJob,
+        frame_index: int,
+        tile: int | None = None,
+        *,
+        dispatched: Callable[[], None],
+    ) -> RenderedFrame:
+        return await asyncio.to_thread(
+            self._device_stage, job, frame_index, tile, dispatched
+        )
 
     async def render_frame(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
@@ -481,8 +501,17 @@ class TpuRaytraceBackend(RenderBackend):
     def _render_sync(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
     ) -> FrameRenderTime:
-        """One frame on the render thread; its exclusive steps (obs.step)
-        ride the timing beside the seven points."""
+        """Both stages back to back on the calling thread: a whole frame,
+        for a caller with no next frame to render beside the save."""
+        return self._device_stage(job, frame_index, tile, lambda: None).save()
+
+    def _device_stage(
+        self, job: BlenderJob, frame_index: int, tile: int | None,
+        dispatched: Callable[[], None],
+    ) -> RenderedFrame:
+        """A frame up to its pixels on the host, on the render thread; its
+        exclusive steps (obs.step) ride on to the save stage, which adds
+        its own and hands all of them over beside the seven points."""
         from tpu_render_cluster.obs import frame_steps, step
 
         key = self.program_key(job)
@@ -500,29 +529,26 @@ class TpuRaytraceBackend(RenderBackend):
                     with step("resolve"):
                         done.wait()
             try:
-                timing = self._render_timed(job, frame_index, tile, steps, key)
+                rendered = self._render_pixels(
+                    job, frame_index, tile, steps, key, dispatched
+                )
             except BaseException:
                 if mine:
                     self._settle(key, done, built=False)
                 raise
         if mine:
             self._settle(key, done, built=True)
-        self._family_frames.inc(family=key[0])
-        return timing
+        return rendered
 
-    def _render_timed(
+    def _render_pixels(
         self, job: BlenderJob, frame_index: int, tile: int | None,
         steps: list[tuple[str, float, float]], key: tuple,
-    ) -> FrameRenderTime:
+        dispatched: Callable[[], None],
+    ) -> RenderedFrame:
         import jax.numpy as jnp
         import numpy as np
 
         from tpu_render_cluster.obs import step
-        from tpu_render_cluster.render.image_io import (
-            output_path_for_frame,
-            output_path_for_tile,
-            write_image,
-        )
         from tpu_render_cluster.render.integrator import (
             fused_frame_renderer,
             fused_region_renderer,
@@ -603,9 +629,12 @@ class TpuRaytraceBackend(RenderBackend):
             # a copy first asked for after the wait below would cost the
             # frame a second host round trip.
             display.copy_to_host_async()
+        # The device has this frame's work: the frame before may encode
+        # and write while this thread is blocked below, the GIL released.
+        dispatched()
         # One device sync per frame, then (what is left of) the copy.
         # Readback counts as rendering, like Blender's in-process
-        # compositing; "saving" below is encode + disk only.
+        # compositing; "saving" is encode + disk only.
         with step("device_wait"):
             display.block_until_ready()
         with step("readback"):
@@ -614,36 +643,6 @@ class TpuRaytraceBackend(RenderBackend):
                 launches = np.asarray(launches)
             walk = [np.asarray(counts) for counts in walk]
         finished_rendering_at = time.time()
-
-        file_saving_started_at = time.time()
-        with step("file_write"):
-            output_directory = parse_with_base_directory_prefix(
-                job.output_directory_path, self.base_directory
-            )
-            if tile is None:
-                path = output_path_for_frame(
-                    output_directory,
-                    job.output_file_name_format,
-                    job.output_file_format,
-                    frame_index,
-                )
-            else:
-                # One tile file per unit; the master's assembly service
-                # stitches the grid into the frame file and removes these.
-                # Always PNG (lossless — see image_io.output_path_for_tile);
-                # the assembler encodes the final frame in the job's format.
-                path = output_path_for_tile(
-                    output_directory,
-                    job.output_file_name_format,
-                    job.output_file_format,
-                    frame_index,
-                    tile,
-                    job.tile_grid,
-                )
-        write_image(
-            path, pixels, "PNG" if tile is not None else job.output_file_format
-        )
-        file_saving_finished_at = time.time()
 
         # Which roofline kernel this frame's fenced execute time pairs
         # with: the programs keyed by a factory-side cost capture
@@ -656,22 +655,77 @@ class TpuRaytraceBackend(RenderBackend):
             if region is not None:
                 dims.update(th=region[2], tw=region[3])
             kernel = kernel_key(tier, scene_name, **dims)
+        return RenderedFrame(
+            save=functools.partial(
+                self._save_stage, job, frame_index, tile, pixels,
+                points=(
+                    started_process_at, finished_loading_at,
+                    started_rendering_at, finished_rendering_at,
+                ),
+                device_steps=steps, tier=tier, scene_name=scene_name,
+                kernel=kernel, launches=launches, walk=walk,
+            )
+        )
+
+    def _save_stage(
+        self, job: BlenderJob, frame_index: int, tile: int | None, pixels, *,
+        points: tuple[float, float, float, float],
+        device_steps: list[tuple[str, float, float]],
+        tier: str, scene_name: str, kernel: str | None, launches, walk: list,
+    ) -> FrameRenderTime:
+        """The frame's file, from its pixels: on whichever thread the
+        caller runs it, with that thread's own steps. What the frame
+        counts (tier, launches, walk, family) is counted once its file is
+        in place, as it always was."""
+        from tpu_render_cluster.obs import frame_steps, step
+        from tpu_render_cluster.render.image_io import (
+            output_path_for_frame,
+            output_path_for_tile,
+            write_image,
+        )
+
+        file_saving_started_at = time.time()
+        with frame_steps() as save_steps:
+            with step("file_write"):
+                output_directory = parse_with_base_directory_prefix(
+                    job.output_directory_path, self.base_directory
+                )
+                if tile is None:
+                    path = output_path_for_frame(
+                        output_directory,
+                        job.output_file_name_format,
+                        job.output_file_format,
+                        frame_index,
+                    )
+                else:
+                    # One tile file per unit; the master's assembly service
+                    # stitches the grid into the frame file and removes these.
+                    # Always PNG (lossless — see image_io.output_path_for_tile);
+                    # the assembler encodes the final frame in the job's format.
+                    path = output_path_for_tile(
+                        output_directory,
+                        job.output_file_name_format,
+                        job.output_file_format,
+                        frame_index,
+                        tile,
+                        job.tile_grid,
+                    )
+            write_image(
+                path, pixels, "PNG" if tile is not None else job.output_file_format
+            )
+        file_saving_finished_at = time.time()
+
         self._tier_frames.inc(tier=tier)
         if launches is not None:
             self._observe_launches(launches)
         for counts in walk:
             self._observe_walk(counts, scene_name)
-        self._observe_render_obs(
-            execute_seconds=finished_rendering_at - started_rendering_at,
-            kernel=kernel,
-        )
+        self._observe_render_obs(execute_seconds=points[3] - points[2], kernel=kernel)
+        self._family_frames.inc(family=scene_name)
         return FrameRenderTime(
-            started_process_at=started_process_at,
-            finished_loading_at=finished_loading_at,
-            started_rendering_at=started_rendering_at,
-            finished_rendering_at=finished_rendering_at,
+            *points,
             file_saving_started_at=file_saving_started_at,
             file_saving_finished_at=file_saving_finished_at,
             exited_process_at=time.time(),
-            steps=tuple(steps),
+            steps=(*device_steps, *save_steps),
         )

@@ -2,14 +2,25 @@
 
 Reference: ``WorkerAutomaticQueue`` (worker/src/rendering/queue.rs:16-230) —
 a 100 ms poll loop takes the first Queued frame, marks it Rendering, renders
-one frame at a time, then emits the finished event and pops it.
+it, then emits the finished event and pops it.
 
-Two deliberate deviations (reference bugs fixed — SURVEY.md §7):
+Three deliberate deviations:
 - the ``event_frame-queue_item-started-rendering`` event IS emitted (the
-  reference defines and handles it but never sends it, §3.3);
+  reference defines and handles it but never sends it, SURVEY.md §3.3);
 - a render failure emits ``event_frame-queue_item-finished`` with
   ``errored`` instead of silently dropping the frame (which would hang the
-  reference master forever — worker/src/rendering/queue.rs:169-174).
+  reference master forever — worker/src/rendering/queue.rs:169-174);
+- **a frame is two stages, and two frames are in hand at a time**: the
+  device stage (to the pixels on the host) and the save stage (encode,
+  write, rename; ``worker/backends/base.py``). The loop starts the next
+  queued frame's device stage as soon as the one in hand has returned, and
+  that frame's save stage runs beside it on a thread of its own: at most
+  one frame in each stage, so up to two units are ``RENDERING``. A
+  finished event still leaves only after its frame's file has been
+  renamed into place, and finished events leave in the order the frames
+  were rendered; the ``rendering`` event of frame *i+1* may precede the
+  finished event of frame *i*. A backend with no separable save stage
+  goes through the same loop one whole frame at a time.
 """
 
 from __future__ import annotations
@@ -17,7 +28,9 @@ from __future__ import annotations
 import asyncio
 import enum
 import logging
+import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 from tpu_render_cluster.jobs.models import BlenderJob
@@ -28,7 +41,8 @@ from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.transport.actors import SenderHandle
 from tpu_render_cluster.traces.worker_trace import WorkerTraceBuilder
 from tpu_render_cluster.utils.cancellation import CancellationToken
-from tpu_render_cluster.worker.backends.base import RenderBackend
+from tpu_render_cluster.traces.worker_trace import FrameRenderTime
+from tpu_render_cluster.worker.backends.base import RenderBackend, RenderedFrame
 
 logger = logging.getLogger(__name__)
 
@@ -39,14 +53,28 @@ QUEUE_POLL_SECONDS = 0.1  # reference: worker/src/rendering/queue.rs:74-96
 # post-hoc from trace gaps — here measured directly.
 FRAME_PHASES = ("queue_wait", "read", "render", "write")
 
-# The render loop's wall time, partitioned (worker_loop_seconds_total):
-#   no_work      no queued frame; waiting for the master (draining excluded)
-#   render_call  inside backend.render_frame, thread hop included — the
-#                frame's steps (obs.FRAME_STEPS) lie in it
-#   report       the rest of a frame's turn: the rendering/finished
-#                events, trace bookkeeping, feeding the phase and step
-#                series
-LOOP_STATES = ("no_work", "render_call", "report")
+# The render loop's wall time, partitioned (worker_loop_seconds_total). The
+# loop is one coroutine with up to two frames in hand, and what it is
+# charged is what IT waits for or does, not what a frame costs:
+#   no_work      nothing queued and no stage in hand; waiting for the
+#                master (draining excluded)
+#   render_call  waiting for a backend stage with nothing else to start:
+#                a frame's device stage (thread hop included), or, with
+#                nothing queued, the save stage of the last frame. The
+#                frames' steps (obs.FRAME_STEPS) lie in it; the save of
+#                frame i mostly under the device stage of frame i+1
+#   report       the loop's own work: the rendering/finished events, trace
+#                bookkeeping, feeding the phase and step series
+#   save_wait    the pipeline is full: a frame's device stage has returned
+#                and the frame before it is still in its save stage, so
+#                neither that frame's save nor the next queued frame's
+#                device stage can start (save slower than render)
+LOOP_STATES = ("no_work", "render_call", "report", "save_wait")
+
+# The steps of the save stage (of obs.FRAME_STEPS): they and the ``write``
+# phase go on timeline tracks of their own, because frame i's lie under
+# frame i+1's device steps and a track's spans must not overlap.
+SAVE_STEPS = ("encode", "file_write")
 
 
 class FrameState(enum.Enum):
@@ -83,8 +111,29 @@ class QueuedFrame:
         return WorkUnit(self.frame_index, self.tile)
 
 
+@dataclass
+class _SavingFrame:
+    """A frame in its save stage."""
+
+    frame: QueuedFrame
+    future: asyncio.Future  # the frame's FrameRenderTime, or what the save raised
+    # what the save waits behind: the next frame's dispatch, or nothing
+    gate: threading.Event
+    # a later frame's device stage was open while this save ran
+    beside_render: bool = False
+
+
+def _outcome(future: asyncio.Future) -> object:
+    """What a stage came to: its result, or the exception it raised."""
+    try:
+        return future.result()
+    except Exception as e:  # noqa: BLE001 - reported as the frame's error
+        return e
+
+
 class WorkerAutomaticQueue:
-    """Serial render queue polled every 100 ms."""
+    """Two-stage render queue: one frame in its device stage, the frame
+    before it in its save stage; woken by events, polled every 100 ms."""
 
     def __init__(
         self,
@@ -126,12 +175,33 @@ class WorkerAutomaticQueue:
             metrics.counter(
                 "worker_loop_seconds_total",
                 "Wall time of the render loop by state "
-                "(no_work/render_call/report)",
+                "(no_work/render_call/report/save_wait)",
                 labels=("state",),
             )
             if metrics is not None
             else None
         )
+        self._saved_beside_render = (
+            metrics.counter(
+                "worker_frames_saved_beside_render_total",
+                "Frames whose save stage (encode, write, rename) ran while "
+                "a later frame's device stage was open",
+            )
+            if metrics is not None
+            else None
+        )
+        if metrics is not None:
+            # Exposed at 0 from the start: a scrape that finds no series
+            # could not tell "never happened" from "not counted".
+            self._saved_beside_render.inc(0.0)
+            for state in LOOP_STATES:
+                self._loop_seconds.inc(0.0, state=state)
+        # The save stage's thread: one, so that at most one frame is
+        # saving; idle until the backend hands back a RenderedFrame.
+        self._saver = ThreadPoolExecutor(max_workers=1, thread_name_prefix="frame-save")
+        # The frame in its device stage and the frame in its save stage.
+        self._device_stage: tuple[QueuedFrame, asyncio.Task] | None = None
+        self._saving: _SavingFrame | None = None
         self._loop_state: str | None = None
         self._loop_state_since = time.perf_counter()
         self._startup = get_startup()
@@ -206,11 +276,11 @@ class WorkerAutomaticQueue:
         return len(self._frames)
 
     async def drain(self) -> list[tuple[str, int]]:
-        """Graceful drain: finish the in-flight frame, hand back the rest.
+        """Graceful drain: finish the frames in hand, hand back the rest.
 
-        Stops the loop from starting new frames, waits for the one
-        currently rendering to complete (its finished event goes out
-        normally), and returns the ``(job_name, frame_index)`` pairs that
+        Stops the loop from starting new frames, waits for the one in its
+        device stage and the one in its save stage to complete (their
+        finished events go out normally), and returns the ``(job_name, frame_index)`` pairs that
         never started — the payload of the goodbye message the runtime
         sends so the master can requeue them without waiting for a
         heartbeat-timeout eviction.
@@ -234,7 +304,8 @@ class WorkerAutomaticQueue:
         incarnation (epoch change / refused reconnect): the queued-but-
         not-started frames belong to assignments the new master does not
         know about, so replaying them would render work nobody tracks.
-        The frame currently RENDERING is left to finish — its finished
+        A frame currently RENDERING (in its device stage or in its save
+        stage: there may be one of each) is left to finish — its finished
         event carries the OLD epoch and the new master refuses it as
         stale, which is the fence working as designed. The already-
         finished index is cleared too: the new master may legitimately
@@ -247,8 +318,8 @@ class WorkerAutomaticQueue:
             f for f in self._frames if f.state is not FrameState.QUEUED
         ]
         self._finished_indices.clear()
-        # The frame left mid-RENDER belongs to the OLD session: when it
-        # finishes, it must not re-enter the just-cleared finished index
+        # A frame left mid-RENDER or mid-save belongs to the OLD session:
+        # when it finishes, it must not re-enter the just-cleared finished index
         # (the generation check at insert time fences it out).
         self._session_generation += 1
         return len(dropped)
@@ -265,6 +336,8 @@ class WorkerAutomaticQueue:
                 await self._task
             except asyncio.CancelledError:
                 pass
+        # a save under way ends on its own, as a render thread's always did
+        self._saver.shutdown(wait=False)
 
     def _next_queued(self) -> QueuedFrame | None:
         for frame in self._frames:
@@ -288,42 +361,112 @@ class WorkerAutomaticQueue:
             await self._run_loop()
         finally:
             self._enter_loop_state(None)
+            if self._device_stage is not None:
+                self._device_stage[1].cancel()
+            if self._saving is not None:
+                self._saving.gate.set()  # no thread is left blocked behind it
 
     async def _run_loop(self) -> None:
+        # device stage done and save not begun: (frame, RenderedFrame), or
+        # (frame, FrameRenderTime | Exception) with nothing left to save
+        rendered: tuple[QueuedFrame, object] | None = None
         while not self._cancellation.is_cancelled():
-            frame = None if self._draining else self._next_queued()
-            if frame is None:
+            # Cleared before anything is looked at: whatever ends or
+            # arrives from here on wakes the wait at the bottom.
+            self._work_available.clear()
+            # What has ended is taken in first, the save before the device
+            # stage: finished events leave in the order of the frames.
+            if self._saving is not None and self._saving.future.done():
+                saved, self._saving = self._saving, None
+                await self._report(
+                    saved.frame, _outcome(saved.future), saved.beside_render
+                )
+                continue
+            if self._device_stage is not None and self._device_stage[1].done():
+                (frame, task), self._device_stage = self._device_stage, None
+                rendered = (frame, _outcome(task))
+                continue
+            next_frame = (
+                None if self._draining or self._device_stage is not None
+                else self._next_queued()
+            )
+            if rendered is not None and self._saving is None:
+                (frame, outcome), rendered = rendered, None
+                if not isinstance(outcome, RenderedFrame):
+                    await self._report(frame, outcome)
+                    continue
+                # The hand-over, in this order: the next frame's device
+                # work is issued FIRST and this frame's save begins behind
+                # it (encoding holds the GIL the dispatch needs). With
+                # nothing queued there is nothing to wait behind.
+                self._saving = self._begin_save(frame, outcome)
+                if next_frame is None:
+                    self._saving.gate.set()
+                else:
+                    await self._begin_device_stage(next_frame, self._saving.gate.set)
+                continue
+            if next_frame is not None and rendered is None:
+                await self._begin_device_stage(next_frame, lambda: None)
+                continue
+            if rendered is not None:
+                self._enter_loop_state("save_wait")
+            elif self._device_stage is not None or self._saving is not None:
+                self._enter_loop_state("render_call")
+            else:
                 # Fed at every poll, so a scrape is never more than one
                 # poll interval behind on a starved worker.
                 self._enter_loop_state(None if self._draining else "no_work")
-                self._work_available.clear()
-                try:
-                    await asyncio.wait_for(
-                        self._work_available.wait(), QUEUE_POLL_SECONDS
-                    )
-                except asyncio.TimeoutError:
-                    pass
-                continue
-            await self._render_frame_and_report(frame)
+            try:
+                await asyncio.wait_for(
+                    self._work_available.wait(), QUEUE_POLL_SECONDS
+                )
+            except asyncio.TimeoutError:
+                pass
 
-    async def _render_frame_and_report(self, frame: QueuedFrame) -> None:
+    def _wake(self, _ended: asyncio.Future) -> None:
+        self._work_available.set()
+
+    async def _begin_device_stage(self, frame: QueuedFrame, dispatched) -> None:
         self._enter_loop_state("report")
         frame.state = FrameState.RENDERING
-        job_name = frame.job.job_name
+        if self._saving is not None:
+            self._saving.beside_render = True
         await self._sender.send_message(
             pm.WorkerFrameQueueItemRenderingEvent(
-                job_name, frame.frame_index, trace=frame.trace,
+                frame.job.job_name, frame.frame_index, trace=frame.trace,
                 job_id=frame.job_id, tile=frame.tile, epoch=frame.epoch,
             )
         )
-        self._enter_loop_state("render_call")
-        try:
-            timing = await self._backend.render_frame(
-                frame.job, frame.frame_index, tile=frame.tile
+        task = asyncio.ensure_future(
+            self._backend.render_device_stage(
+                frame.job, frame.frame_index, tile=frame.tile, dispatched=dispatched
             )
-        except Exception as e:  # noqa: BLE001 - report, don't hang the master
-            self._enter_loop_state("report")
-            logger.error("Unit %s render failed: %s", frame.unit.label, e)
+        )
+        # a stage that ends, however it ends, lets the save behind it go
+        task.add_done_callback(lambda _ended: dispatched())
+        task.add_done_callback(self._wake)
+        self._device_stage = (frame, task)
+
+    def _begin_save(self, frame: QueuedFrame, rendered: RenderedFrame) -> _SavingFrame:
+        gate = threading.Event()
+
+        def save() -> FrameRenderTime:
+            gate.wait()
+            return rendered.save()
+
+        future = asyncio.get_running_loop().run_in_executor(self._saver, save)
+        future.add_done_callback(self._wake)
+        return _SavingFrame(frame, future, gate)
+
+    async def _report(
+        self, frame: QueuedFrame, outcome: object, beside_render: bool = False
+    ) -> None:
+        """A frame's end: its file is in place (``outcome`` is its seven
+        points) or one of its stages raised (``outcome`` is the error)."""
+        self._enter_loop_state("report")
+        job_name = frame.job.job_name
+        if not isinstance(outcome, FrameRenderTime):
+            logger.error("Unit %s render failed: %s", frame.unit.label, outcome)
             if self._metrics is not None:
                 self._metrics.counter(
                     "worker_frames_errored_total", "Frames that failed to render"
@@ -334,16 +477,18 @@ class WorkerAutomaticQueue:
             self._remove(frame)
             await self._sender.send_message(
                 pm.WorkerFrameQueueItemFinishedEvent.new_errored(
-                    job_name, frame.frame_index, str(e), trace=frame.trace,
+                    job_name, frame.frame_index, str(outcome), trace=frame.trace,
                     job_id=frame.job_id, tile=frame.tile, epoch=frame.epoch,
                 )
             )
             return
-        self._enter_loop_state("report")
+        timing = outcome
         if not self._startup.finished:
             self._startup.finish()  # the first frame's file is in place
         self._tracer.trace_new_rendered_frame(frame.frame_index, timing)
         self._observe_frame_phases(frame, timing)
+        if beside_render and self._saved_beside_render is not None:
+            self._saved_beside_render.inc()
         self._remove(frame)
         if frame.session == self._session_generation:
             # A frame queued under a PREVIOUS master session (failover hit
@@ -365,7 +510,10 @@ class WorkerAutomaticQueue:
 
         The spans reuse the 7-point wall-clock timestamps the backend
         already measured (the trace of record), so the Perfetto view and
-        the legacy ``FrameRenderTime`` analysis agree exactly.
+        the legacy ``FrameRenderTime`` analysis agree exactly. A frame's
+        ``write`` lies under the next frame's ``read`` and ``render``, so
+        it has a track of its own (``saves``), and so have the save
+        stage's steps (``save steps``).
         """
         if self._metrics is None and self._span_tracer is None:
             return
@@ -388,12 +536,13 @@ class WorkerAutomaticQueue:
                     args["tile"] = frame.tile
                 if frame.trace is not None:
                     args["flow"] = frame.trace.flow_id
+                track = "saves" if phase == "write" else "frames"
                 self._span_tracer.complete(
                     phase,
                     cat="worker",
                     start_wall=start,
                     duration=duration,
-                    track="frames",
+                    track=track,
                     args=args,
                 )
                 if frame.trace is not None:
@@ -409,13 +558,13 @@ class WorkerAutomaticQueue:
                         id=frame.trace.flow_id,
                         ts=start + duration / 2.0,
                         cat="frame",
-                        track="frames",
+                        track=track,
                         args=flow_args,
                     )
         # The frame's steps enter the registry and the timeline together
         # with its phases, so a scrape never sees half a frame. A category
-        # and a track of their own: readers of the phase spans
-        # (cat "worker", one at a time) see the timeline they always saw.
+        # and tracks of their own: readers of the phase spans (cat
+        # "worker") see the spans they always saw.
         for name, start_wall, seconds in timing.steps:
             if self._step_histogram is not None:
                 self._step_histogram.observe(seconds, step=name)
@@ -425,7 +574,7 @@ class WorkerAutomaticQueue:
                     cat="worker.step",
                     start_wall=start_wall,
                     duration=seconds,
-                    track="steps",
+                    track="save steps" if name in SAVE_STEPS else "steps",
                     args={"frame": frame.frame_index},
                 )
         if self._metrics is not None:
