@@ -172,10 +172,18 @@ def test_stochastic_render_agrees_statistically(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# The sphere megakernel's contractions (PR 38): one bf16 matmul for the
-# hit's rows, c . o carried from the shadow origin.
+# The sphere megakernel's contractions: one bf16 matmul for the hit's rows
+# (PR 38), one bf16 pass for each K=3 contraction (PR 46).
 
 _GATHER_SPHERES = 13  # padded to 16: three padded spheres
+
+
+def _full_mantissa(rng, shape, scale):
+    """float32 values that use every bit of the significand: an odd 24-bit
+    integer times a power of two has no trailing zero bit."""
+    odd = rng.integers(1 << 23, 1 << 24, size=shape, dtype=np.int64) | 1
+    sign = rng.choice([-1.0, 1.0], size=shape)
+    return (sign * odd * scale).astype(np.float32)
 
 
 def _gather_tables():
@@ -183,18 +191,11 @@ def _gather_tables():
     significand, plus a zero, a 1e-3 and a 1e5 in each."""
     rng = np.random.default_rng(38)
     n = _GATHER_SPHERES
-
-    def full_mantissa(shape, scale):
-        # an odd 24-bit integer times a power of two: no trailing zero bit
-        odd = rng.integers(1 << 23, 1 << 24, size=shape, dtype=np.int64) | 1
-        sign = rng.choice([-1.0, 1.0], size=shape)
-        return (sign * odd * scale).astype(np.float32)
-
     tables = {
-        "centre": full_mantissa((3, n), 2.0 ** -20),
-        "albedo": full_mantissa((3, n), 2.0 ** -24),
-        "emission": full_mantissa((3, n), 2.0 ** -21),
-        "radius": np.abs(full_mantissa((1, n), 2.0 ** -23)),
+        "centre": _full_mantissa(rng, (3, n), 2.0 ** -20),
+        "albedo": _full_mantissa(rng, (3, n), 2.0 ** -24),
+        "emission": _full_mantissa(rng, (3, n), 2.0 ** -21),
+        "radius": np.abs(_full_mantissa(rng, (1, n), 2.0 ** -23)),
     }
     for table in tables.values():
         table[0, 1], table[0, 2], table[0, 3] = 0.0, 1e-3, 1e5
@@ -231,6 +232,77 @@ def test_gather_hit_returns_table_columns_bit_for_bit(which):
     assert got.dtype == np.float32 and got.shape == want.shape
     np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
     assert not want[:, n:padded_n].any()  # a padded sphere reads zeros
+
+
+def _k3_operands(ray_scale):
+    """The `_gather_tables` centres (a 0.0, a 1e-3 and a 1e5 among them)
+    padded to 16 spheres, and 128 ray-side vectors whose components use
+    every bit of a float32 significand, with a zero component, a
+    negative one and a zero vector among them."""
+    centres = _gather_tables()["centre"]  # [3, 13]
+    padded_n = -(-_GATHER_SPHERES // 8) * 8
+    vectors = _full_mantissa(np.random.default_rng(46), (3, 128), ray_scale)
+    vectors[1, 0] = 0.0
+    vectors[2, 1] = -abs(vectors[2, 1])
+    vectors[:, 2] = 0.0
+    return centres, padded_n, vectors
+
+
+@pytest.mark.parametrize(
+    "ray_scale", [2.0 ** -24, 2.0 ** -14, 2.0 ** -44],
+    ids=["directions", "origins_1e3", "tiny"],
+)
+def test_dot_k3_exact_is_as_near_the_float64_product_as_dot_f32(ray_scale):
+    """The one-pass K=3 contraction lies within two float32 roundings of
+    the magnitude sum of the float64 product, and no further from it than
+    `_dot_f32` does on the same operands; padded spheres read zero."""
+    centres, padded_n, vectors = _k3_operands(ray_scale)
+    padded = np.pad(centres, ((0, 0), (0, padded_n - centres.shape[1])))
+    stack = pallas_kernels._center_stack(jnp.asarray(centres.T), padded_n)
+    got = np.asarray(pallas_kernels._dot_k3_exact(stack, jnp.asarray(vectors)))
+    six_pass = np.asarray(pallas_kernels._dot_f32(
+        jnp.asarray(padded), jnp.asarray(vectors), (((0,), (0,)), ((), ()))
+    ))
+    assert got.dtype == np.float32 and got.shape == (padded_n, 128)
+    exact = padded.astype(np.float64).T @ vectors.astype(np.float64)
+    # One rounding of a sum this large: half an ulp of sum |c_k v_k|.
+    rounding = np.abs(padded.astype(np.float64)).T @ np.abs(
+        vectors.astype(np.float64)
+    ) * 2.0 ** -24
+    error = np.abs(got - exact)
+    assert (error <= 2.0 * rounding).all()
+    assert not got[centres.shape[1]:].any()  # a padded sphere reads zero
+    assert not got[:, 2].any()  # and so does a zero vector
+    scale = np.where(rounding > 0.0, rounding, 1.0)
+    assert (error / scale).max() <= (np.abs(six_pass - exact) / scale).max()
+    assert (error / scale).mean() <= (np.abs(six_pass - exact) / scale).mean()
+
+
+def test_center_stack_is_the_centres_bf16_parts_bit_for_bit():
+    """`_center_stack`: bfloat16, [N_padded, K], three equal blocks of
+    lo / mid / hi / zeros whose parts sum back to the float32 centres."""
+    centres = _gather_tables()["centre"]
+    n = centres.shape[1]
+    padded_n = -(-n // 8) * 8
+    stack = pallas_kernels._center_stack(jnp.asarray(centres.T), padded_n)
+    assert stack.dtype == jnp.bfloat16
+    assert stack.shape == (padded_n, pallas_kernels._K3_DEPTH)
+    assert pallas_kernels._K3_DEPTH <= 128  # one pass of the MXU
+    values = np.asarray(stack.astype(jnp.float32))
+    block = pallas_kernels._K3_BLOCK
+    for at in range(0, pallas_kernels._K3_DEPTH, block):
+        np.testing.assert_array_equal(values[:, at:at + block], values[:, :block])
+    lo, mid, hi = values[:, 0:3], values[:, 8:11], values[:, 16:19]
+    want = np.pad(centres.T, ((0, padded_n - n), (0, 0)))
+    np.testing.assert_array_equal(
+        ((hi + mid) + lo).view(np.uint32), want.view(np.uint32)
+    )
+    used = np.zeros(block, bool)
+    used[[0, 1, 2, 8, 9, 10, 16, 17, 18]] = True
+    assert not values[:, :block][:, ~used].any()
+    assert not values[n:].any()  # padded spheres
+    # smallest parts first: the accumulator adds along K
+    assert (np.abs(lo) <= np.abs(mid)).all() and (np.abs(mid) <= np.abs(hi)).all()
 
 
 def _parent_formulation(scene, origins, directions, seed, *, max_bounces):
@@ -393,9 +465,14 @@ def _frame_rays(size=64, samples=2, frame=1):
 
 def test_megakernel_matches_parent_formulation():
     """4 bounces of 64x64x2spp of 04_very-simple: the one-matmul gather
-    and the carried c . o against six `_dot_f32` contractions a bounce.
-    Neither changes a value, so every path takes the same turns and what
-    is left is the compiler's rounding (2.4e-7 at most)."""
+    and the one-pass K=3 contractions against six `_dot_f32` contractions
+    a bounce. The gather changes no value; the one-pass contraction
+    rounds its sums in another order than `_dot_f32`, so a ray in 25
+    takes another turn somewhere along its path (a hit an ulp before or
+    behind a silhouette or the shadow's edge) and the rest agree to the
+    compiler's rounding. Read on the CPU interpreter: 96.0% of rays
+    within `rtol=1e-5` (with the large parts first along K: 92.7%), the
+    image means equal to 8e-6."""
     scene = build_scene("04_very-simple", 1)
     origins, directions, seed = _frame_rays()
     want = np.asarray(
@@ -408,7 +485,11 @@ def test_megakernel_matches_parent_formulation():
     )
     assert got.shape == want.shape == (64 * 64 * 2, 3)
     assert want.max() > 0.1  # a picture, not a black frame
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert np.isfinite(got).all()
+    same_turns = np.isclose(got, want, rtol=1e-5, atol=1e-6).all(axis=1)
+    assert same_turns.mean() >= 0.94
+    means = got.mean(dtype=np.float64), want.mean(dtype=np.float64)
+    assert abs(means[0] - means[1]) <= 1e-4 * means[1]
 
 
 def test_megakernel_lane_rows_match_whole_frame_bit_for_bit():
@@ -436,8 +517,9 @@ def test_megakernel_lane_rows_match_whole_frame_bit_for_bit():
 
 @pytest.mark.parametrize("fate", ["all_miss", "die_at_bounce_1"])
 def test_megakernel_dead_lanes_stay_finite(fate):
-    """The carried c . o of a lane that has left the scene stays finite:
-    a NaN there reaches `cos_sun` through `p`, and NaN * 0 is NaN."""
+    """The carried origin and direction of a lane that has left the
+    scene stay finite: a NaN there reaches `cos_sun` through `p`, and
+    NaN * 0 is NaN."""
     scene = build_scene("04_very-simple", 1)
     n = 256
     if fate == "all_miss":

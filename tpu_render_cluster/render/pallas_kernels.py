@@ -16,17 +16,20 @@ Layout choices (see /opt/skills/guides/pallas_guide.md):
 - sphere data ([3, N] centers, [N, 1] radius^2 / |c|^2) is small enough to
   sit whole in VMEM for every grid step;
 - every in-kernel contraction is exact in float32. The K=3 ones (d.c,
-  o.c) are dot_generals at full f32 precision (``_dot_f32``: six bf16
-  MXU passes, both operands split on the VPU), and so are the one-hot
-  gathers of the per-bounce and mesh kernels. The sphere megakernel
+  o.c) of the per-bounce and mesh kernels are dot_generals at full f32
+  precision (``_dot_f32``: six bf16 MXU passes, both operands split on
+  the VPU), and so are their one-hot gathers. The sphere megakernel
   (``_trace_kernel_factory``) pays only the passes its operands need: a
   hit's centre, radius, albedo and emission come from ONE single-pass
   bf16 matmul of a one-hot, which is 0.0 / 1.0 and so exact in bf16,
-  against the tables' three exact bf16 parts (``_gather_hit``), and o.c
-  is carried from the shadow origin to the next bounce instead of being
-  made twice. Its two K=3 contractions a bounce stay six-pass: as three
-  broadcast multiply-adds on the VPU both together read slower on the
-  chip (21.6 against 16.9 ms a 512x512x8 frame; PERF.md, PR 38).
+  against the tables' three exact bf16 parts (``_gather_hit``), and each
+  of its three K=3 contractions a bounce is ONE bf16 pass too
+  (``_dot_k3_exact``): the ray vector's three bf16 parts against the
+  centres' three along K = 96, all nine cross terms summed in the MXU's
+  float32 accumulator, the smallest first. On the chip a 512x512x8
+  frame reads 16.92 ms with them six-pass and c . o carried between
+  bounces, 21.6 as broadcast multiply-adds on the VPU (PR 38) and 13.25
+  one-pass with nothing carried (PERF.md, PR 46).
 
 On non-TPU backends the kernel runs in interpret mode, so the same code
 path is exercised by CPU tests.
@@ -47,11 +50,13 @@ from jax.experimental.pallas import tpu as pltpu
 INF = 1e30
 EPS = 1e-3
 
-# Rays per grid step. Swept on the real chip (bench.py, 256x256 4spp):
-# 512 -> 432 f/s, 1024 -> 509, 2048 -> 538, 4096 -> 548, 8192 -> 545.
-# Bigger blocks amortize per-step scheduling and keep the VPU busier;
-# VMEM stays comfortable (the largest intermediate is [N_spheres, BLOCK_R]
-# ~ 1 MB at 64 spheres).
+# Rays per grid step, of the three sphere wrappers alike. Swept on the
+# chip on the one-pass megakernel (`_trace_fused` alone, 512x512x8, PR 46):
+# 2048 -> 13.26 ms a frame, 4096 -> 13.25, 8192 -> 13.08. An
+# [N_spheres, BLOCK_R] intermediate is 256 vregs at 64 spheres and 4096
+# rays, so the body streams through VMEM at every one of them and the
+# block hardly matters; 8192's 1.3% costs twice the Mosaic compile (4.8 s
+# against 2.4) and twice the padding of a cropped launch: 4096 stays.
 BLOCK_R = 4096
 # The BVH kernels use their own ray-block size: packet culling (the
 # block-wide any() on AABB tests and the instance-level world-AABB skip)
@@ -107,10 +112,12 @@ def _dot_f32(a, b, dimension_numbers):
 
     It is six bf16 passes with BOTH operands split into three bf16
     arrays on the VPU, which is what two arbitrary float32 operands
-    need. An operand that is exact in bfloat16 needs none of it: the
-    sphere megakernel's one-hot gathers are one default-precision pass
-    against tables split once outside the loop (``_gather_hit``). Its
-    K=3 contractions, the per-bounce sphere kernels and the mesh kernels
+    need. The sphere megakernel needs none of it: its one-hot gathers
+    are one default-precision pass against tables split once outside the
+    loop (``_gather_hit``), and its K=3 contractions one pass against a
+    stack of the centres' parts (``_dot_k3_exact``: 13.25 ms a
+    512x512x8 frame where this function read 16.92 and the VPU 21.6;
+    PERF.md, PR 46). The per-bounce sphere kernels and the mesh kernels
     (their sphere pass and one-hot gathers included) use this function.
     """
     return jax.lax.dot_general(
@@ -609,14 +616,67 @@ def _gather_hit(table, sphere_iota, idx):
     return rows[0:3], rows[3:6], rows[6:9], rows[9:10]
 
 
+# The megakernel's K=3 contractions (c . d, c . o, c . shadow_o) along a
+# K of three blocks, one a bf16 part of the ray-side vector: the part
+# three times over, an f32 sublane tile each (x, y, z, five zeros), against
+# the centres' three parts, then a tile of zeros that fills the block's
+# second bf16 tile. 96 of the MXU's 128 rows: one pass. SMALLEST PARTS
+# FIRST on both sides (lo, mid, hi): the accumulator adds along K in
+# float32, so the three hi x hi products have to come last. With them
+# first the pass read further from the float64 product on the chip than
+# ``_dot_f32`` (52% of results correctly rounded against 72%); in this
+# order 98.6% are (PERF.md, PR 46).
+_K3_BLOCK = 4 * _SUBLANE
+_K3_DEPTH = 3 * _K3_BLOCK
+
+
+def _center_stack(centers, padded_n):
+    """[padded_n, _K3_DEPTH] bfloat16, the left operand of
+    ``_dot_k3_exact``: in each of the three equal blocks of ``_K3_BLOCK``
+    columns, columns 0:3, 8:11 and 16:19 are the ``lo``, ``mid`` and
+    ``hi`` parts (``_bf16_parts``) of the [N, 3] centres; every other
+    column, and spheres N..padded_n, are zeros."""
+    tile = jnp.pad(
+        centers, ((0, padded_n - centers.shape[0]), (0, _SUBLANE - 3))
+    )
+    hi, mid, lo = _bf16_parts(tile)
+    block = jnp.concatenate([lo, mid, hi, jnp.zeros_like(hi)], axis=1)
+    return jnp.tile(block, (1, 3))
+
+
+def _dot_k3_exact(c_stack, v):
+    """[N, BR] float32 ``c . v`` of the centres behind ``c_stack``
+    (``_center_stack``) and a [3, BR] float32 ``v``, in ONE bf16 MXU pass.
+
+    ``v`` is cut into its three bf16 parts on the VPU (3 rows, with the
+    mask of ``_bf16_parts``) and each part, ``lo`` first, laid against
+    all three parts of the centres along K, so the pass sums all nine
+    ``part_i(c) * part_j(v)`` terms of every axis in the MXU's float32
+    accumulator. A product of two bfloat16 values is exact in float32
+    (8 + 8 significand bits), so only the accumulator's sums round, the
+    large terms' last: nearer the exact product than ``_dot_f32``, whose
+    six passes keep six of the nine terms, split BOTH operands on the VPU
+    each time and are summed there. Not the single ROUNDED bf16 pass,
+    which is wrong on half the pixels."""
+    tile = jnp.concatenate(
+        [v, jnp.zeros((_SUBLANE - 3, v.shape[1]), jnp.float32)], axis=0
+    )
+    zeros = jnp.zeros(tile.shape, jnp.bfloat16)
+    rows = []
+    for part in reversed(_bf16_parts(tile)):
+        rows += [part, part, part, zeros]
+    return jax.lax.dot_general(
+        c_stack, jnp.concatenate(rows, axis=0), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _trace_kernel_factory(
     max_bounces: int, n_padded: int, lane_io: bool = False,
 ):
     """Sphere path-trace MEGAKERNEL: the whole bounce loop in one launch,
     state VMEM-resident across all bounces, radiance out. ``lane_io``
     adds a per-lane ORIGINAL lane id row as the RNG counter's source."""
-    contract_first = (((0,), (0,)), ((), ()))
-
     def kernel(*refs):
         if lane_io:
             # The megakernel with an EXPLICIT lane row: the cluster-tile
@@ -624,14 +684,14 @@ def _trace_kernel_factory(
             # cropped launch runs bitwise-identical per-lane math to the
             # whole-frame megakernel (same kernel, same loop — only the
             # RNG counter's source differs).
-            (seed_ref, o_ref, d_ref, lane_ref, c_ref, r2_ref, csq_ref,
+            (seed_ref, o_ref, d_ref, lane_ref, cstack_ref, r2_ref, csq_ref,
              table_ref, dcsun_ref, params_ref, out_ref) = refs
         else:
-            (seed_ref, o_ref, d_ref, c_ref, r2_ref, csq_ref, table_ref,
+            (seed_ref, o_ref, d_ref, cstack_ref, r2_ref, csq_ref, table_ref,
              dcsun_ref, params_ref, out_ref) = refs
         o = o_ref[:, :]  # [3, BR] ray origins
         d = d_ref[:, :]  # [3, BR] ray directions
-        c = c_ref[:, :]  # [3, N] sphere centers
+        c_stack = cstack_ref[:, :]  # [N, 96] bf16, _center_stack
         r2 = r2_ref[:, :]  # [N, 1] radius^2 (0 for padding -> never hits)
         csq = csq_ref[:, :]  # [N, 1] |c|^2
         table = table_ref[:, :]  # [48, N] bf16, _gather_table
@@ -666,12 +726,15 @@ def _trace_kernel_factory(
         alive = jnp.ones((1, block), jnp.float32)
 
         def bounce_step(bounce, carry):
-            # oc = c . o and o_sq = |o|^2 ride the carry: this bounce's
-            # origin is the last bounce's shadow origin, whose products
-            # the sun test already made.
-            o, d, throughput, radiance, alive, oc, o_sq = carry
+            o, d, throughput, radiance, alive = carry
             # -- nearest sphere hit (same math as _nearest_hit_kernel) ----
-            dc = _dot_f32(c, d, contract_first)
+            # c . o is made anew although this origin is the last
+            # bounce's shadow origin, whose product the sun test made: a
+            # one-pass contraction costs less than carrying an [N, BR]
+            # value through VMEM (0.67 ms a frame on the chip).
+            dc = _dot_k3_exact(c_stack, d)
+            oc = _dot_k3_exact(c_stack, o)
+            o_sq = jnp.sum(o * o, axis=0, keepdims=True)
             od = jnp.sum(o * d, axis=0, keepdims=True)
             oc_dot_d = dc - od
             oc_sq = o_sq - 2.0 * oc + csq
@@ -742,10 +805,10 @@ def _trace_kernel_factory(
             # -- sun NEE: one any-hit shadow dot (sun dir is uniform) -----
             # The shadow origin is the next bounce's origin. where-select
             # (not multiply-mask): a dead lane keeps its old finite one,
-            # and with it finite oc_s / osq_s in the carry, so no inf*0
-            # can poison later bounces (its shadow test is masked below).
+            # so no inf*0 can poison later bounces (its shadow test is
+            # masked below).
             shadow_o = jnp.where(live, p + normal * (EPS * 4.0), o)
-            oc_s = _dot_f32(c, shadow_o, contract_first)
+            oc_s = _dot_k3_exact(c_stack, shadow_o)
             od_s = jnp.sum(shadow_o * sun, axis=0, keepdims=True)
             osq_s = jnp.sum(shadow_o * shadow_o, axis=0, keepdims=True)
             ocd_s = dc_sun - od_s
@@ -792,15 +855,11 @@ def _trace_kernel_factory(
             bitangent = jnp.concatenate([bx, by, bz], axis=0)
             new_d = x * tangent + y * bitangent + z * normal
             d = jnp.where(live, new_d, d)  # dead lanes stay finite, as o
-            return (shadow_o, d, throughput, radiance, alive, oc_s, osq_s)
+            return (shadow_o, d, throughput, radiance, alive)
 
         radiance = jax.lax.fori_loop(
             0, max_bounces, bounce_step,
-            (
-                o, d, throughput, radiance, alive,
-                _dot_f32(c, o, contract_first),
-                jnp.sum(o * o, axis=0, keepdims=True),
-            ),
+            (o, d, throughput, radiance, alive),
         )[3]
         out_ref[:, :] = radiance
 
@@ -832,6 +891,7 @@ def _trace_fused(
     radii_p = jnp.pad(radii, (0, sphere_pad))
     r2 = (radii_p * radii_p)[:, None]
     csq = jnp.sum(c_t * c_t, axis=0)[:, None]
+    c_stack = _center_stack(centers, padded_n)
     table = _gather_table(centers, albedo, emission, radii, padded_n)
     dc_sun = _center_dot_sun(c_t, sun_direction)  # [Np, 1]
 
@@ -853,7 +913,7 @@ def _trace_fused(
         ray_block,
         ray_block,
         *([lane_block] if lane_t is not None else []),
-        pl.BlockSpec((3, padded_n), whole, memory_space=pltpu.VMEM),
+        pl.BlockSpec(c_stack.shape, whole, memory_space=pltpu.VMEM),
         pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
         pl.BlockSpec((padded_n, 1), whole, memory_space=pltpu.VMEM),
         pl.BlockSpec(table.shape, whole, memory_space=pltpu.VMEM),
@@ -863,7 +923,7 @@ def _trace_fused(
     operands = [seed_arr, o_t, d_t]
     if lane_t is not None:
         operands.append(lane_t)
-    operands += [c_t, r2, csq, table, dc_sun, params]
+    operands += [c_stack, r2, csq, table, dc_sun, params]
     out = pl.pallas_call(
         _trace_kernel_factory(max_bounces, padded_n, lane_io=lane_t is not None),
         grid=grid,
