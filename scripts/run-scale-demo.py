@@ -13,7 +13,8 @@ the reference analysis loader and records a compact summary.
 The mock render time (default 25 ms) stands in for Blender so the run
 stresses what this demo is about: master control-plane throughput at
 reference scale (~1600 frame-RPCs/s cluster-wide), O(frames) state
-handling, and tail behavior — not raytracing speed (bench.py covers that).
+handling, and tail behavior — not raytracing speed (the benchmark,
+benchmark/run.py, covers that).
 
 The 14400-frame raw trace (~10 MB JSON) is deliberately written to a
 scratch directory and NOT committed; what lands in results/ is

@@ -2,7 +2,7 @@
 
 Forces JAX onto a virtual 8-device CPU mesh so multi-chip sharding paths are
 exercised without TPU hardware (the driver separately dry-runs the multichip
-path; bench.py runs on the real chip).
+path; the benchmark, ``benchmark/run.py``, runs on the real chip).
 
 Must run before the first ``import jax`` anywhere in the test session.
 """
@@ -93,12 +93,6 @@ def kill_leftover_children(monkeypatch):
 def _isolated_process_global_stores():
     """Reset process-global stores whose contents would otherwise depend
     on which tests ran earlier in this worker."""
-    # The kernel roofline profiler (obs/profiling.py): its capture/
-    # execution store is process-global and cumulative, so per-kernel
-    # assertions must start from a clean slate each test.
-    profiling = sys.modules.get("tpu_render_cluster.obs.profiling")
-    if profiling is not None:
-        profiling.get_profiler().reset()
     # The host-side geometry-build memo (render/mesh.py): BVH/
     # TLAS builds are pure, but per-test build-count assertions (e.g.
     # render_tlas_builds_total deltas) must not depend on which

@@ -449,12 +449,12 @@ def test_the_assets_scenes_program_streams_every_bounce_from_one_table(small_ass
     from tpu_render_cluster.render import integrator
 
     render = integrator.fused_frame_renderer(ASSETS_SCENE, 64, 64, 2, 4, with_live=True)
-    jaxpr = jax.make_jaxpr(render.__wrapped__)(jnp.float32(295))
+    jaxpr = jax.make_jaxpr(render)(jnp.float32(295))
     calls = list(pallas_calls(jaxpr.jaxpr))
     assert calls and all("mesh_bounce_streamed" in str(call.params) for call in calls)
     # as many launches as the scan family's program of this shape: one walk, not one a model
     scan = integrator.fused_frame_renderer("03_physics-2-scan", 64, 64, 2, 4, with_live=True)
-    assert len(calls) == len(list(pallas_calls(jax.make_jaxpr(scan.__wrapped__)(jnp.float32(295)).jaxpr)))
+    assert len(calls) == len(list(pallas_calls(jax.make_jaxpr(scan)(jnp.float32(295)).jaxpr)))
     inner = [e for e in jaxpr.jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
     assert inner and len(inner[-1].invars) == 1 + len(small_assets_family.BlasStream._fields)
 

@@ -150,6 +150,50 @@ def test_attribution_report_worker_seconds_fallback_and_empty():
     assert attribution_report(worker_seconds=0.0) is None
 
 
+def test_summarize_attribution_takes_device_wait_as_its_device_seconds():
+    """The device's share is the measured step: the sum of
+    ``worker_frame_step_seconds{step="device_wait"}`` over the snapshots'
+    registries (and the heartbeat wire form of a file that has none), no
+    other step and no other series."""
+    from tpu_render_cluster.analysis.obs_events import summarize_attribution
+
+    def steps(device_wait, readback):
+        return {
+            "step=device_wait": {"count": 4, "sum": device_wait},
+            "step=readback": {"count": 4, "sum": readback},
+        }
+
+    tick = {"series": {"phase=total": {"count": 2, "sum": 0.5}}}
+    snapshots = [
+        {
+            "written_at": 1.0,
+            "metrics": {"sched_tick_seconds": tick},
+            "workers": {
+                "w0": {"worker_frame_step_seconds": {"series": steps(3.0, 7.0)}},
+                "w1": {"worker_frame_step_seconds": {"series": steps(1.5, 9.0)}},
+            },
+        },
+        {  # a master's file: only the merged heartbeat wire form
+            "written_at": 2.0,
+            "cluster_metrics": {
+                "h": {
+                    "worker_frame_step_seconds|step=device_wait": {"n": 2, "s": 0.25},
+                    "worker_frame_step_seconds|step=encode": {"n": 2, "s": 5.0},
+                }
+            },
+        },
+    ]
+    report = summarize_attribution(snapshots, worker_seconds=100.0)
+    assert report["seconds"]["device_compute"] == pytest.approx(3.0 + 1.5 + 0.25)
+    assert report["seconds"]["control_plane"] == pytest.approx(0.5)
+    # a run that rendered on no device carves nothing out for it
+    no_device = summarize_attribution(
+        [{"written_at": 1.0, "metrics": {"sched_tick_seconds": tick}}],
+        worker_seconds=100.0,
+    )
+    assert no_device["seconds"]["device_compute"] == 0.0
+
+
 # ---------------------------------------------------------------------------
 # Wire-cost accounting
 

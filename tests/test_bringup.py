@@ -7,12 +7,11 @@ What stands between this repo and a chip (ISSUE 22), checked on the CPU:
    ``tpu-raytrace`` worker refuses a non-TPU backend unless
    ``JAX_PLATFORMS`` asks for the CPU, and stamps its device; the master CLI
    leaves JAX on ``cpu``; chips 0-3 get disjoint child environments.
-2. ``obs/profiling.py``: one peak table, an unknown ``device_kind`` raises.
-3. Every default render program LOWERS for TPU from here
+2. Every default render program LOWERS for TPU from here
    (``.trace().lower(lowering_platforms=("tpu",))`` with interpret off):
    the Python-side Pallas -> Mosaic lowering, which is where illegal block
    specs are refused.
-4. The per-bounce kernels also COMPILE, through libtpu's compile-only
+3. The per-bounce kernels also COMPILE, through libtpu's compile-only
    client for a v5e (the real XLA:TPU + Mosaic compilers, no chip needed):
    where operations Mosaic cannot legalize are refused. Whether a kernel
    computes the right thing only a chip can say — ``chip_smoke.py``.
@@ -134,14 +133,6 @@ def test_chip_environments_are_disjoint():
         chip_environment(-1)
 
 
-def test_peak_table_raises_on_an_unknown_device_kind():
-    from tpu_render_cluster.obs.profiling import chip_peaks
-
-    assert chip_peaks("TPU v5 lite") == (197e12, 819e9)
-    with pytest.raises(ValueError, match="TPU v9"):
-        chip_peaks("TPU v9")
-
-
 # -- cross-lowering for TPU --------------------------------------------------
 
 
@@ -190,11 +181,11 @@ def test_masked_frame_and_region_lower_for_tpu(lower_for_tpu, scene_name):
     )
 
     frame = fused_frame_renderer(scene_name, WIDTH, HEIGHT, SAMPLES, BOUNCES)
-    lower_for_tpu(frame.__wrapped__, _f32())
+    lower_for_tpu(frame, _f32())
     region = fused_region_renderer(
         scene_name, WIDTH, HEIGHT, HEIGHT // 2, WIDTH // 2, SAMPLES, BOUNCES
     )
-    lower_for_tpu(region.__wrapped__, _f32(), _i32(), _i32())
+    lower_for_tpu(region, _f32(), _i32(), _i32())
     fused_frame_renderer.cache_clear()
     fused_region_renderer.cache_clear()
 
@@ -207,7 +198,7 @@ def test_the_backends_deep_program_lowers_for_tpu(lower_for_tpu):
     frame = fused_frame_renderer(
         "03_physics-2-mesh", WIDTH, HEIGHT, SAMPLES, BOUNCES, with_live=True
     )
-    text = lower_for_tpu(frame.__wrapped__, _f32())
+    text = lower_for_tpu(frame, _f32())
     fused_frame_renderer.cache_clear()
     assert text.count("tpu_custom_call") >= BOUNCES
 

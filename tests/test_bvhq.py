@@ -259,8 +259,7 @@ def test_geometry_cache_keyed_on_build_params():
 
 def test_renderer_cache_keys_on_env_tiers(monkeypatch):
     """Toggling TRC_BVH_BUILDER / TRC_BVH_QUANT mid-process resolves to a
-    DIFFERENT cached renderer (fresh tree + kernel), never a stale hit —
-    the roofline keys differ too, so rows cannot be misattributed."""
+    DIFFERENT cached renderer (fresh tree + kernel), never a stale hit."""
     from tpu_render_cluster.render.integrator import fused_frame_renderer
 
     monkeypatch.setenv("TRC_BVH_BUILDER", "median")
@@ -273,10 +272,6 @@ def test_renderer_cache_keys_on_env_tiers(monkeypatch):
     monkeypatch.setenv("TRC_BVH_QUANT", "1")
     c = fused_frame_renderer(DEEP_SCENE, 8, 8, 1, 2)
     assert a is not b and b is not c
-    keys = {r.kernel_key for r in (a, b, c)}
-    assert len(keys) == 3
-    assert any("bvh=median1" in k for k in keys)
-    assert any("quant=1" in k for k in keys)
     # Same env resolves to the same cached renderer.
     assert fused_frame_renderer(DEEP_SCENE, 8, 8, 1, 2) is c
 

@@ -526,9 +526,9 @@ def test_env_registry_declares_every_helper_default():
 
 
 def test_no_option_selects_an_execution_tier():
-    """72 declared options, none of them a way to choose how a frame is
+    """69 declared options, none of them a way to choose how a frame is
     rendered: that is decided by the work unit and the worker's sharding."""
-    assert len(ENV_VARS) == 72
+    assert len(ENV_VARS) == 69
     assert not [name for name in ENV_VARS if "WAVEFRONT" in name or "RAYPOOL" in name]
 
 

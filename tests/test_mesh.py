@@ -1,9 +1,9 @@
 """Triangle-mesh + BVH tests (SURVEY.md §7 hard part #4).
 
-The acceptance pattern mirrors tests/test_pallas_kernels.py for spheres:
-every accelerated path (XLA threaded-BVH packet walk, Pallas stackless
-traversal kernel) is verified against the brute-force Möller–Trumbore
-reference on the same inputs.
+The XLA threaded-BVH packet walk is verified against the brute-force
+Möller–Trumbore reference on the same inputs, and the Pallas bounce kernel
+(``mesh_bounce_pallas``, through ``_trace_paths_deep``) against the XLA
+bounce loop at one bounce, where the radiance is RNG-free.
 """
 
 from __future__ import annotations
@@ -54,24 +54,6 @@ def test_bvh_packet_matches_brute_force(kind):
     hit = np.asarray(t_brute) < 1e29
     assert hit.sum() > 20, "test rays must actually hit the mesh"
     assert (np.asarray(idx_packet)[hit] == np.asarray(idx_brute)[hit]).all()
-
-
-@pytest.mark.parametrize("kind", ["box", "icosphere"])
-def test_bvh_pallas_matches_brute_force(kind):
-    # Interpret mode on CPU; the identical kernel runs compiled on TPU.
-    from tpu_render_cluster.render import pallas_kernels
-
-    bvh = cached_mesh_bvh(kind)
-    origins, directions = _rays(300, seed=2)
-    t_brute, idx_brute = intersect_triangles_brute(bvh, origins, directions)
-    t_pallas, idx_pallas = pallas_kernels.intersect_bvh_pallas(
-        bvh, origins, directions
-    )
-    np.testing.assert_allclose(
-        np.asarray(t_pallas), np.asarray(t_brute), rtol=1e-4, atol=1e-4
-    )
-    hit = np.asarray(t_brute) < 1e29
-    assert (np.asarray(idx_pallas)[hit] == np.asarray(idx_brute)[hit]).all()
 
 
 def test_bvh_structure_invariants():
@@ -147,67 +129,12 @@ def test_mesh_scene_job_name_mapping():
     assert scene_for_job_name("04_very-simple_10f") == "04_very-simple"
 
 
-def test_instanced_pallas_matches_scan_path():
-    # The single-launch instanced kernel (+ post-kernel normal/albedo
-    # gathers) must agree with the per-instance lax.scan walk on a
-    # multi-instance setup with distinct rotations, scales, and albedos.
-    import jax.numpy as jnp
-
-    from tpu_render_cluster.render import pallas_kernels
-
-    bvh = cached_mesh_bvh("box")
-    rng = np.random.default_rng(11)
-    k = 5
-    angles = jnp.asarray(rng.uniform(0, 2 * np.pi, size=k).astype(np.float32))
-    instances = MeshInstances(
-        rotation=rotation_y(angles).astype(jnp.float32),
-        translation=jnp.asarray(
-            rng.uniform(-2, 2, size=(k, 3)).astype(np.float32)
-        ),
-        albedo=jnp.asarray(rng.uniform(0.2, 1.0, size=(k, 3)).astype(np.float32)),
-        scale=jnp.asarray(rng.uniform(0.5, 1.5, size=k).astype(np.float32)),
-    )
-    origins, directions = _rays(400, seed=7, spread=0.8)
-
-    t_scan, n_scan, a_scan = intersect_instances(
-        bvh, instances, origins, directions
-    )
-
-    t_k, tri_k, inst_k = pallas_kernels.intersect_instances_pallas(
-        bvh, instances, origins, directions
-    )
-    prior = os.environ.get("TRC_PALLAS")
-    os.environ["TRC_PALLAS"] = "1"
-    try:
-        t_pl, n_pl, a_pl = intersect_instances(bvh, instances, origins, directions)
-    finally:
-        if prior is None:
-            del os.environ["TRC_PALLAS"]
-        else:
-            os.environ["TRC_PALLAS"] = prior
-
-    np.testing.assert_allclose(np.asarray(t_k), np.asarray(t_scan), rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(t_pl), np.asarray(t_scan), rtol=1e-4, atol=1e-4)
-    hit = np.asarray(t_scan) < 1e29
-    assert hit.sum() > 50, "test rays must actually hit instances"
-    np.testing.assert_allclose(
-        np.asarray(n_pl)[hit], np.asarray(n_scan)[hit], rtol=1e-4, atol=1e-4
-    )
-    np.testing.assert_allclose(
-        np.asarray(a_pl)[hit], np.asarray(a_scan)[hit], rtol=1e-5, atol=1e-5
-    )
-    # Misses keep the zero normal/albedo contract.
-    assert (np.asarray(n_pl)[~hit] == 0).all()
-    assert (np.asarray(a_pl)[~hit] == 0).all()
-
-
 def test_occlusion_anyhit_matches_nearest_hit():
-    # The dedicated any-hit walks (XLA + Pallas) must agree with "nearest
-    # hit exists" from the brute-force reference, and respect the
-    # `already` mask.
+    # The dedicated any-hit walk must agree with "nearest hit exists" from
+    # the brute-force reference, and respect the `already` mask (the
+    # bounce kernel's half: test_bounce_kernel_shadow_walk_keeps_already).
     import jax.numpy as jnp
 
-    from tpu_render_cluster.render import pallas_kernels
     from tpu_render_cluster.render.mesh import occluded_bvh_packet
 
     bvh = cached_mesh_bvh("icosphere")
@@ -216,16 +143,150 @@ def test_occlusion_anyhit_matches_nearest_hit():
     expected = np.asarray(t_brute) < 1e29
     none = jnp.zeros((300,), bool)
     occ_xla = np.asarray(occluded_bvh_packet(bvh, origins, directions, none))
-    occ_pl = np.asarray(
-        pallas_kernels.occluded_bvh_pallas(bvh, origins, directions, none)
-    )
     assert (occ_xla == expected).all()
-    assert (occ_pl == expected).all()
     # already-occluded rays stay occluded.
     all_occ = jnp.ones((300,), bool)
     assert np.asarray(
         occluded_bvh_packet(bvh, origins, directions, all_occ)
     ).all()
-    assert np.asarray(
-        pallas_kernels.occluded_bvh_pallas(bvh, origins, directions, all_occ)
-    ).all()
+
+
+# ---------------------------------------------------------------------------
+# The Pallas bounce kernel against the XLA bounce loop, one bounce
+
+
+def _one_instance(kind):
+    """The mesh alone at the origin, unrotated and unscaled."""
+    return mesh_mod.MeshSet(
+        cached_mesh_bvh(kind),
+        MeshInstances(
+            rotation=jnp.eye(3, dtype=jnp.float32)[None],
+            translation=jnp.zeros((1, 3), jnp.float32),
+            albedo=jnp.asarray([[0.8, 0.5, 0.3]], jnp.float32),
+            scale=jnp.ones((1,), jnp.float32),
+        ),
+    )
+
+
+def _five_boxes():
+    """Five boxes of distinct rotations, scales and albedos: more than one
+    TLAS leaf of 4, so the two-level variant engages."""
+    rng = np.random.default_rng(11)
+    k = 5
+    angles = jnp.asarray(rng.uniform(0, 2 * np.pi, size=k).astype(np.float32))
+    return mesh_mod.MeshSet(
+        cached_mesh_bvh("box"),
+        MeshInstances(
+            rotation=rotation_y(angles).astype(jnp.float32),
+            translation=jnp.asarray(
+                rng.uniform(-2, 2, size=(k, 3)).astype(np.float32)
+            ),
+            albedo=jnp.asarray(
+                rng.uniform(0.2, 1.0, size=(k, 3)).astype(np.float32)
+            ),
+            scale=jnp.asarray(rng.uniform(0.5, 1.5, size=k).astype(np.float32)),
+        ),
+    )
+
+
+@pytest.mark.parametrize(
+    "mesh_set, rays, use_tlas",
+    [
+        (lambda: _one_instance("box"), dict(n=300, seed=2), None),
+        (lambda: _one_instance("icosphere"), dict(n=300, seed=2), None),
+        (_five_boxes, dict(n=400, seed=7, spread=0.8), False),
+        (_five_boxes, dict(n=400, seed=7, spread=0.8), True),
+    ],
+    ids=["box", "icosphere", "five_boxes_flat", "five_boxes_tlas"],
+)
+def test_bounce_kernel_matches_xla_loop(monkeypatch, mesh_set, rays, use_tlas):
+    """``mesh_bounce_pallas`` through ``_trace_paths_deep`` (the re-sort,
+    the launch and the unsort) against one bounce of the XLA loop: sky,
+    the mesh's, spheres' and plane's nearest hit, the instance's albedo
+    and the triangle's normal through the sun term, both shadow walks. The
+    resampled direction is never traced, so no lane depends on an RNG."""
+    import jax
+
+    from tpu_render_cluster.render import integrator, pallas_kernels
+    from tpu_render_cluster.render.scene import build_scene
+
+    scene = build_scene("02_physics-mesh", 1)
+    mesh = mesh_set()
+    origins, directions = _rays(**rays)
+    assert not pallas_kernels.pallas_enabled()  # the XLA loop below
+    want = np.asarray(
+        integrator.trace_paths(
+            scene, origins, directions, jax.random.PRNGKey(3),
+            max_bounces=1, mesh=mesh,
+        )
+    )
+    t_mesh, _, _ = intersect_instances(
+        mesh.bvh, mesh.instances, origins, directions
+    )
+    assert (np.asarray(t_mesh) < 1e29).sum() > 20, "rays must hit the mesh"
+    if use_tlas is not None:
+        k = mesh.instances.translation.shape[0]
+        assert pallas_kernels.use_tlas_for(k, use_tlas) == use_tlas
+    got = np.asarray(
+        integrator._trace_paths_deep(
+            scene, mesh, origins, directions, jnp.int32(3), max_bounces=1,
+            rng_lanes=None, use_tlas=use_tlas, quant=0, live_counts=None,
+        )
+    )
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+
+def test_bounce_kernel_shadow_walk_keeps_already():
+    """The bounce kernel's shadow walk: a lane a sphere already shadows
+    comes back shadowed though its shadow ray meets no triangle, a lane
+    whose shadow ray meets the mesh gets no sun, a lane neither shadows
+    gets it. Each lane lands on the plane, whose only light at one bounce
+    is the sun's."""
+    import jax
+
+    from tpu_render_cluster.render import integrator, pallas_kernels
+    from tpu_render_cluster.render.scene import build_scene
+
+    scene = build_scene("04_very-simple", 1)
+    sun = np.asarray(scene.sun_direction, np.float64)
+    assert sun[1] > 0.1
+    sphere = np.array([-3.0, 3.0, 0.5])
+    box = np.array([4.0, 3.0, 0.5])
+    scene = scene._replace(
+        centers=jnp.asarray([sphere], jnp.float32),
+        radii=jnp.asarray([0.5], jnp.float32),
+        albedo=jnp.full((1, 3), 0.5, jnp.float32),
+        emission=jnp.zeros((1, 3), jnp.float32),
+    )
+    mesh = _one_instance("box")
+    mesh = mesh._replace(
+        instances=mesh.instances._replace(
+            translation=jnp.asarray([box], jnp.float32)
+        )
+    )
+
+    def shadow_of(centre):  # where the plane lies in its shadow
+        return centre - sun * (centre[1] / sun[1])
+
+    n = 128  # lanes 0: the sphere's shadow, 1: the box's, 2: open ground
+    landing = np.tile(np.array([[0.3, 0.0, -7.3]]), (n, 1))
+    landing[0], landing[1] = shadow_of(sphere), shadow_of(box)
+    origins = jnp.asarray(landing + [0.0, 0.5, 0.0], jnp.float32)
+    directions = jnp.tile(jnp.asarray([[0.0, -1.0, 0.0]], jnp.float32), (n, 1))
+    contribution = np.asarray(
+        pallas_kernels.mesh_bounce_pallas(
+            scene, mesh, origins, directions, jnp.ones((n, 3), jnp.float32),
+            jnp.ones((n,), bool), jnp.int32(3), jnp.int32(0), total_bounces=1,
+        )[0]
+    )
+    assert not contribution[0].any()  # shadowed by the sphere: stays so
+    assert not contribution[1].any()  # its shadow ray meets the box
+    assert (contribution[2:] > 0.05).all()  # sunlit ground
+    assert not pallas_kernels.pallas_enabled()
+    want = np.asarray(
+        integrator.trace_paths(
+            scene, origins, directions, jax.random.PRNGKey(3),
+            max_bounces=1, mesh=mesh,
+        )
+    )
+    np.testing.assert_allclose(contribution, want, rtol=2e-3, atol=2e-3)

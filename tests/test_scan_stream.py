@@ -704,7 +704,7 @@ def test_the_icosphere_scenes_program_holds_the_kernels_it_held(interpreted_kern
     from tpu_render_cluster.render import integrator
 
     render = integrator.fused_frame_renderer("03_physics-2-mesh", 512, 512, 8, 4, with_live=True)
-    jaxpr = jax.make_jaxpr(render.__wrapped__)(jnp.float32(295))
+    jaxpr = jax.make_jaxpr(render)(jnp.float32(295))
     calls = list(pallas_calls(jaxpr.jaxpr))
     rungs = len(integrator.launch_width_ladder(512 * 512 * 8))
     assert len(calls) == 1 + 3 * rungs == 13
@@ -720,7 +720,7 @@ def test_the_scan_scenes_program_streams_every_bounce(small_scan_family):
     from tpu_render_cluster.render import integrator
 
     render = integrator.fused_frame_renderer(SCAN_SCENE, 64, 64, 2, 4, with_live=True)
-    jaxpr = jax.make_jaxpr(render.__wrapped__)(jnp.float32(295))
+    jaxpr = jax.make_jaxpr(render)(jnp.float32(295))
     calls = list(pallas_calls(jaxpr.jaxpr))
     assert calls and all("mesh_bounce_streamed" in str(call.params) for call in calls)
     # the BLAS is an argument of the jitted program: HBM tables, not constants

@@ -507,7 +507,7 @@ def test_load_sched_bench_handles_missing_and_committed(tmp_path):
     bad = tmp_path / "bad.json"
     bad.write_text("not json", encoding="utf-8")
     assert load_sched_bench(str(bad)) is None
-    # The committed artifact (bench.py --sched) loads through the default
+    # The committed artifact loads through the default
     # path and carries both modes.
     record = load_sched_bench()
     assert record is not None

@@ -357,12 +357,12 @@ def test_warm_fills_its_three_stages_in_order_with_the_bvh_builds_inside_geometr
     assert edges(by_name["geometry"])[0] <= edges(build)[0] and edges(build)[1] <= edges(by_name["geometry"])[1]
     opened = by_name["open_device"]
     assert edges(by_name["backend_init"])[0] <= edges(opened)[0] and edges(opened)[1] <= edges(by_name["backend_init"])[1]
-    # the program's build holds JAX's phases, named, and the profiler's capture
+    # the program's build holds JAX's phases, named
     inside = edges(by_name["program_build"])
     compiles = [e for e in startup_timeline.events() if e["cat"] == "render.compile"]
     assert {e["name"] for e in compiles} == {"trace", "lower", "backend_compile"}
     assert any(e["args"]["fun_name"] == "jit(program)" for e in compiles)
-    for event in compiles + [by_name["profiler_capture"]]:
+    for event in compiles:
         assert inside[0] - 1e-3 <= edges(event)[0] and edges(event)[1] <= inside[1] + 1e-3, event
     gauge = get_registry().gauge("render_bvh_build_seconds", "", labels=("model",))
     assert gauge.value(model="box") == pytest.approx(build["dur"] / 1e6, abs=1e-6)

@@ -130,7 +130,7 @@ def test_segments_do_not_overlap_and_are_in_the_order_they_ended():
 
 
 def test_nothing_is_kept_outside_a_frame():
-    with step("dispatch"):  # render.cli, warm(), bench.py: no frame in hand
+    with step("dispatch"):  # render.cli, warm(): no frame in hand
         pass
     with frame_steps() as steps:
         pass

@@ -1,5 +1,5 @@
 """Live telemetry plane suite (obs/prometheus, obs/http, obs/dashboard,
-obs/slo, obs/profiling).
+obs/slo).
 
 Fast deterministic tier-1 subset (marked ``telemetry``):
 
@@ -13,9 +13,8 @@ Fast deterministic tier-1 subset (marked ``telemetry``):
   one-shot, TOML declaration, a deterministic breach-and-recovery e2e,
   and a seeded straggler chaos run driving a declared objective into
   burn with the full invariant audit still green;
-- roofline: capture-once instrumentation, placement math, the
-  statistics.json ``slo``/``roofline`` folds, and the run-job CLI's
-  crash-path artifact export.
+- the statistics.json ``slo`` fold, and the run-job CLI's crash-path
+  artifact export.
 """
 
 from __future__ import annotations
@@ -96,7 +95,6 @@ def test_every_registered_metric_name_is_lint_clean():
     registry."""
     registered: dict[tuple[str, str], str] = {}
     sources = list((REPO_ROOT / "tpu_render_cluster").rglob("*.py"))
-    sources.append(REPO_ROOT / "bench.py")
     for path in sources:
         text = path.read_text(encoding="utf-8")
         for match in _METRIC_CALL_RE.finditer(text):
@@ -773,101 +771,6 @@ def test_seeded_chaos_slo_breach(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Roofline profiling
-
-
-def test_roofline_placement_math():
-    from tpu_render_cluster.obs.profiling import roofline_placement
-
-    peaks = {"peak_flops": 100.0, "peak_bytes_per_second": 10.0}
-    # Intensity 20 flops/byte: compute-bound (20 * 10 >= 100).
-    placement = roofline_placement(100.0, 5.0, 2.0, peaks)
-    assert placement["bound"] == "compute"
-    assert placement["attainable_flops_per_second"] == 100.0
-    assert placement["achieved_flops_per_second"] == pytest.approx(50.0)
-    assert placement["achieved_fraction_of_peak"] == pytest.approx(0.5)
-    # Intensity 2: memory-bound, attainable capped by bandwidth.
-    placement = roofline_placement(100.0, 50.0, 1.0, peaks)
-    assert placement["bound"] == "memory"
-    assert placement["attainable_flops_per_second"] == pytest.approx(20.0)
-    assert placement["achieved_fraction_of_attainable"] == pytest.approx(5.0)
-
-
-def test_kernel_profiler_captures_once_and_exports(monkeypatch):
-    import jax
-    import jax.numpy as jnp
-
-    from tpu_render_cluster.obs import get_registry
-    from tpu_render_cluster.obs.profiling import get_profiler, kernel_key
-
-    monkeypatch.delenv("TRC_OBS_PROFILING", raising=False)
-    profiler = get_profiler()
-    key = kernel_key("unit", "scene", w=8)
-    assert key == "unit/scene@w=8"
-    jitted = jax.jit(lambda x: jnp.sin(x) @ x)
-    wrapped = profiler.instrument(key, jitted)
-    x = jnp.ones((8, 8), jnp.float32)
-    assert not profiler.captured(key)
-    wrapped(x)
-    assert profiler.captured(key)
-    flops_first = profiler.view()["kernels"][key]["flops"]
-    assert flops_first > 0
-    wrapped(x)  # second call must not re-capture
-    profiler.record_execute(key, 0.002)
-    profiler.record_execute(key, 0.004)
-    view = profiler.view()
-    entry = view["kernels"][key]
-    assert entry["flops"] == flops_first
-    assert entry["executions"] == 2
-    assert entry["execute_seconds_total"] == pytest.approx(0.006)
-    assert entry["achieved_flops_per_second"] == pytest.approx(
-        flops_first * 2 / 0.006
-    )
-    assert entry["bound"] in ("compute", "memory")
-    assert view["peaks"]["backend"] == jax.default_backend()
-    # The registry gauges mirror the capture + pairing (scrapeable).
-    registry = get_registry()
-    assert registry.gauge(
-        "render_kernel_flops", labels=("kernel",)
-    ).value(kernel=key) == pytest.approx(flops_first)
-    assert registry.gauge(
-        "render_kernel_achieved_flops_per_second", labels=("kernel",)
-    ).value(kernel=key) > 0
-    render_prometheus(registry.snapshot())  # lint-clean with kernel series
-
-
-def test_profiling_disabled_is_pass_through(monkeypatch):
-    import jax
-    import jax.numpy as jnp
-
-    from tpu_render_cluster.obs.profiling import get_profiler, kernel_key
-
-    monkeypatch.setenv("TRC_OBS_PROFILING", "0")
-    profiler = get_profiler()
-    key = kernel_key("unit-off", "scene")
-    wrapped = profiler.instrument(key, jax.jit(lambda x: x + 1))
-    assert float(wrapped(jnp.float32(1.0))) == 2.0
-    assert not profiler.captured(key)
-    assert profiler.view() == {}
-
-
-def test_render_tier_capture_masked():
-    """The masked-tier renderer factory is instrumented: one real tiny
-    render captures XLA cost analysis for the fused program under the
-    canonical kernel key."""
-    from tpu_render_cluster.obs.profiling import get_profiler
-    from tpu_render_cluster.render.integrator import fused_frame_renderer
-
-    render = fused_frame_renderer("04_very-simple", 16, 16, 1, 2)
-    render(1.0)
-    kernels = get_profiler().view().get("kernels", {})
-    masked = [k for k in kernels if k.startswith("masked/04_very-simple@")]
-    assert masked, sorted(kernels)
-    entry = kernels[masked[0]]
-    assert entry["captured"] is True
-
-
-# ---------------------------------------------------------------------------
 # statistics.json folds
 
 
@@ -904,47 +807,7 @@ def test_summarize_slo_section():
     }
 
 
-def test_summarize_roofline_section():
-    from tpu_render_cluster.analysis.obs_events import summarize_roofline
-
-    assert summarize_roofline([{}]) is None
-    snapshots = [
-        {
-            "written_at": 5.0,
-            "metrics": {
-                "render_kernel_flops": {
-                    "series": {"kernel=wire-only@x=1": 64.0}
-                },
-                "render_kernel_bytes": {
-                    "series": {"kernel=wire-only@x=1": 8.0}
-                },
-            },
-            "roofline": {
-                "peaks": {"peak_flops": 100.0, "peak_bytes_per_second": 10.0},
-                "kernels": {
-                    "masked/s@w=8": {
-                        "flops": 100.0,
-                        "bytes_accessed": 10.0,
-                        "captured": True,
-                        "executions": 4,
-                        "execute_seconds_total": 0.01,
-                        "achieved_flops_per_second": 40000.0,
-                    }
-                },
-            },
-        }
-    ]
-    section = summarize_roofline(snapshots)
-    assert section["peaks"]["peak_flops"] == 100.0
-    # The stamped section wins for its kernels; gauge-only kernels ride.
-    assert section["kernels"]["masked/s@w=8"]["executions"] == 4
-    assert section["kernels"]["wire-only@x=1"] == {
-        "flops": 64.0,
-        "bytes_accessed": 8.0,
-    }
-
-
-def test_summarize_obs_includes_slo_and_roofline():
+def test_summarize_obs_includes_slo():
     from tpu_render_cluster.analysis.obs_events import summarize_obs
 
     out = summarize_obs(
@@ -954,14 +817,10 @@ def test_summarize_obs_includes_slo_and_roofline():
                 "written_at": 2.0,
                 "metrics": {},
                 "slo": {"jobs": {"a": {"attainment": 1.0}}},
-                "roofline": {
-                    "kernels": {"masked/s@w=8": {"flops": 1.0, "captured": True}}
-                },
             }
         ],
     )
     assert out["slo"]["jobs"]["a"]["attainment"] == 1.0
-    assert "masked/s@w=8" in out["roofline"]["kernels"]
 
 
 # ---------------------------------------------------------------------------

@@ -142,3 +142,76 @@ def test_a_sharding_worker_shards_whole_frames_and_only_those(scene, sharding, r
         "masked": 0, "region": 1, "sharded": 2,
     }
     assert routed["mesh_sets"] == 0
+
+
+# -- which kernel a frame's program reaches ----------------------------------------------
+
+KERNEL_OF = {
+    "sphere": "_trace_fused",
+    "shallow": "_trace_fused_mesh",
+    "deep": "_mesh_bounce_io",
+}
+
+
+def test_three_pallas_call_sites_and_a_frame_program_reaches_exactly_one(monkeypatch):
+    """``pallas_kernels.py`` holds three ``pl.pallas_call(`` and the traced
+    frame program of each scene class launches from one of them only: no
+    kernel but the three that are served, and no scene served by two."""
+    import inspect
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_render_cluster.render import integrator, pallas_kernels
+
+    source = inspect.getsource(pallas_kernels)
+    assert source.count("pl.pallas_call(") == 3
+
+    sites = []
+    launch = pallas_kernels.pl.pallas_call
+
+    def counted(*args, **kwargs):
+        sites.append(sys._getframe(1).f_code.co_name)
+        return launch(*args, **kwargs)
+
+    monkeypatch.setenv("TRC_PALLAS", "1")
+    monkeypatch.setattr(pallas_kernels.pl, "pallas_call", counted)
+    for scene in sorted(SCENES):
+        jax.clear_caches()  # the wrappers are jitted: trace them anew
+        integrator.fused_frame_renderer.cache_clear()
+        sites.clear()
+        render = integrator.fused_frame_renderer(SCENES[scene], 16, 16, 1, 2)
+        jax.make_jaxpr(render)(jnp.float32(1))
+        assert sites and set(sites) == {KERNEL_OF[scene]}, (scene, sites)
+    integrator.fused_frame_renderer.cache_clear()
+    jax.clear_caches()
+
+
+def test_the_reference_walks_hold_no_kernel_whatever_the_environment_says(monkeypatch):
+    """With ``TRC_PALLAS=1`` the XLA walks still trace to XLA alone: what
+    the kernels are compared with cannot turn into a kernel behind a
+    test's back."""
+    import jax
+    import jax.numpy as jnp
+
+    from tests.test_scan_stream import pallas_calls
+    from tpu_render_cluster.render import geometry, mesh, pallas_kernels
+    from tpu_render_cluster.render.scene import build_scene
+
+    monkeypatch.setenv("TRC_PALLAS", "1")
+    jax.clear_caches()
+    assert pallas_kernels.pallas_enabled()
+    rays = jnp.ones((64, 3), jnp.float32)
+    scene = build_scene(SCENES["deep"], 1)
+    mesh_set = mesh.scene_mesh_set(SCENES["deep"], 1)
+    already = jnp.zeros((64,), bool)
+    for walk, args in (
+        (geometry.intersect_spheres, (scene, rays, rays)),
+        (geometry.occluded_sun, (scene, rays, rays)),
+        (mesh.intersect_instances, (*mesh_set, rays, rays)),
+        (mesh.occluded_instances, (*mesh_set, rays, rays, already)),
+    ):
+        jaxpr = jax.make_jaxpr(walk)(*args)
+        assert not list(pallas_calls(jaxpr.jaxpr)), walk.__name__
+    jax.clear_caches()

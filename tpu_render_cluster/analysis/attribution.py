@@ -1,14 +1,14 @@
 """Whole-stack time attribution: where did the wall time go?
 
-Folds the independently-collected timing evidence — per-kernel roofline
-execute seconds (obs/profiling.py), per-worker busy/idle windows from the
-merged cluster timeline (analysis/critical_path.py), scheduler tick
+Folds the independently-collected timing evidence — the frames'
+``device_wait`` step seconds (worker/queue.py), per-worker busy/idle
+windows from the merged cluster timeline (analysis/critical_path.py), scheduler tick
 phases (sched/tickprof.py), event-loop lag (obs/loopmon.py), and wire
 serialize costs (transport/wirecost.py) — into ONE partition of the
 run's worker-seconds:
 
-- ``device_compute`` — seconds the accelerator was actually executing
-  kernels (roofline measured-execute totals, capped by worker busy time);
+- ``device_compute`` — seconds a frame's host thread was blocked on the
+  device (the measured ``device_wait`` step, capped by worker busy time);
 - ``host_glue`` — worker busy time that was NOT device execute: Python
   driving, image encode, file IO, backend overhead;
 - ``transport`` — control-plane JSON serialize/parse seconds on both
@@ -25,8 +25,7 @@ clamped so overlapping instrumentation (a tick that runs while a worker
 renders) can never push the total past the denominator.
 
 ``summarize_attribution`` (analysis/obs_events.py) extracts the inputs
-from exported artifacts and calls :func:`attribution_report`; bench.py
-calls it directly with an explicit worker-seconds window.
+from exported artifacts and calls :func:`attribution_report`.
 """
 
 from __future__ import annotations

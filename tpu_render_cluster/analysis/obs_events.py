@@ -653,87 +653,6 @@ def summarize_history(
     return out or None
 
 
-def summarize_roofline(metrics: list[dict[str, Any]]) -> dict[str, Any] | None:
-    """Roll the kernel roofline evidence up (obs/profiling.py).
-
-    The full per-kernel view (FLOPs, bytes, executions, measured seconds,
-    achieved-vs-peak placement) rides in the ``roofline`` section workers
-    / the harness / bench stamp into their metrics snapshots; kernels are
-    merged across snapshots with newest-wins per kernel key (each
-    process's profiler is cumulative). The registry-only gauge family
-    (``render_kernel_*``) additionally contributes FLOPs/bytes/achieved
-    rows for kernels whose process exported metrics but no stamped
-    section (e.g. a heartbeat-wire-only worker). None when nothing was
-    profiled.
-    """
-    kernels: dict[str, dict[str, Any]] = {}
-    kernel_at: dict[str, float] = {}
-    peaks: dict[str, Any] | None = None
-    peaks_at = -1.0
-    gauge_rows: dict[str, dict[str, float]] = {}
-
-    def _fold_gauge(names: dict[str, Any], metric: str, field: str) -> bool:
-        entry = names.get(metric)
-        if not entry:
-            return False
-        for label, value in entry.get("series", {}).items():
-            kernel = label.partition("=")[2] or label
-            gauge_rows.setdefault(kernel, {})[field] = float(value)
-        return True
-
-    def take_registry(names: dict[str, Any]) -> bool:
-        took = False
-        for metric, field in (
-            ("render_kernel_flops", "flops"),
-            ("render_kernel_bytes", "bytes_accessed"),
-            (
-                "render_kernel_achieved_flops_per_second",
-                "achieved_flops_per_second",
-            ),
-        ):
-            took = _fold_gauge(names, metric, field) or took
-        return took
-
-    def take_wire(wire: dict[str, Any]) -> None:
-        for key, value in (wire.get("g") or {}).items():
-            name, _, label = key.partition("|")
-            kernel = label.partition("=")[2] or label
-            if name == "render_kernel_flops":
-                gauge_rows.setdefault(kernel, {})["flops"] = float(value)
-            elif name == "render_kernel_bytes":
-                gauge_rows.setdefault(kernel, {})["bytes_accessed"] = float(value)
-            elif name == "render_kernel_achieved_flops_per_second":
-                gauge_rows.setdefault(kernel, {})[
-                    "achieved_flops_per_second"
-                ] = float(value)
-
-    _consume_metric_snapshots(metrics, take_registry, take_wire)
-    for snapshot in metrics:
-        written_at = float(snapshot.get("written_at", 0.0))
-        section = snapshot.get("roofline")
-        if not isinstance(section, dict):
-            continue
-        if isinstance(section.get("peaks"), dict) and written_at >= peaks_at:
-            peaks = section["peaks"]
-            peaks_at = written_at
-        for kernel, entry in (section.get("kernels") or {}).items():
-            if isinstance(entry, dict) and written_at >= kernel_at.get(
-                kernel, -1.0
-            ):
-                kernels[kernel] = entry
-                kernel_at[kernel] = written_at
-    # Gauge-only kernels (no stamped section covered them) still get a row.
-    for kernel, fields in gauge_rows.items():
-        if kernel not in kernels:
-            kernels[kernel] = dict(fields)
-    if not kernels:
-        return None
-    out: dict[str, Any] = {"kernels": kernels}
-    if peaks is not None:
-        out["peaks"] = peaks
-    return out
-
-
 def _parse_series_labels(label: str) -> dict[str, str]:
     """Registry series key (``"tag=ping,direction=send"``) -> labels."""
     labels: dict[str, str] = {}
@@ -757,7 +676,9 @@ def summarize_attribution(
     + ``obs_loop_blocked_episodes_total``, obs/loopmon.py), the wire
     accounting families (``transport_serialize_seconds`` +
     ``transport_message_bytes_total``, transport/wirecost.py), and the
-    roofline execute totals, then hands them to
+    device seconds (the sum of ``worker_frame_step_seconds{step=
+    "device_wait"}``: the time a frame's host thread was blocked on the
+    device, worker/queue.py), then hands them to
     ``analysis/attribution.attribution_report`` against the per-worker
     busy/idle windows in ``critical_sections`` (or the explicit
     ``worker_seconds`` denominator). Same snapshot-family handling as
@@ -771,6 +692,7 @@ def summarize_attribution(
     episode_roles: dict[str, float] = {}
     talker_rows: dict[str, dict[str, float]] = {}
     transport_s = 0.0
+    device_s = 0.0
 
     def take_tick(phase: str, count: float, total: float) -> None:
         nonlocal found
@@ -816,7 +738,7 @@ def summarize_attribution(
         row["serialize_s"] += total
 
     def take_registry(names: dict[str, Any]) -> bool:
-        nonlocal found
+        nonlocal found, device_s
         took = False
         histogram = names.get("sched_tick_seconds")
         if histogram:
@@ -855,10 +777,15 @@ def summarize_attribution(
                 take_serialize(
                     _parse_series_labels(label), float(series.get("sum", 0.0))
                 )
+        histogram = names.get("worker_frame_step_seconds")
+        if histogram:
+            took = True
+            series = histogram.get("series", {}).get("step=device_wait", {})
+            device_s += float(series.get("sum", 0.0))
         return took
 
     def take_wire(wire: dict[str, Any]) -> None:
-        nonlocal found
+        nonlocal found, device_s
         for key, entry in (wire.get("h") or {}).items():
             name, _, label = key.partition("|")
             if name == "sched_tick_seconds":
@@ -878,6 +805,8 @@ def summarize_attribution(
                 take_serialize(
                     _parse_series_labels(label), float(entry.get("s", 0.0))
                 )
+            elif key == "worker_frame_step_seconds|step=device_wait":
+                device_s += float(entry.get("s", 0.0))
         for key, value in (wire.get("c") or {}).items():
             name, _, label = key.partition("|")
             if name == "obs_loop_blocked_episodes_total":
@@ -890,12 +819,6 @@ def summarize_attribution(
     _consume_metric_snapshots(metrics, take_registry, take_wire)
     if not found:
         return None
-
-    device_s = 0.0
-    roofline = summarize_roofline(metrics)
-    if roofline:
-        for entry in roofline.get("kernels", {}).values():
-            device_s += float(entry.get("execute_seconds_total", 0.0) or 0.0)
 
     # The tick's dispatch phase already spans its in-tick RPC awaits; the
     # off-tick dispatch_rpc_await/dispatch_serialize observations only
@@ -1073,9 +996,6 @@ def summarize_obs(
     history = summarize_history(metrics, flight_bundles)
     if history is not None:
         out["history"] = history
-    roofline = summarize_roofline(metrics)
-    if roofline is not None:
-        out["roofline"] = roofline
     if cluster_traces:
         from tpu_render_cluster.analysis.critical_path import (
             summarize_critical_path,

@@ -65,7 +65,7 @@ DEFAULT_RENDER_SECONDS = 0.12
 def unit_latency_stats(unit_seconds: list[float]) -> dict[str, float]:
     """Exact percentiles over the master's per-unit winning-result
     latencies (state.unit_seconds) — the tail the predictive scheduler
-    is judged on (bench.py --speculation)."""
+    is judged on."""
     if not unit_seconds:
         return {"count": 0}
     ordered = sorted(unit_seconds)

@@ -225,15 +225,6 @@ def run_local_job(
     return master_trace, worker_traces
 
 
-def _process_roofline() -> dict:
-    """The process-global kernel profiler's view (empty dict when nothing
-    was profiled — the snapshot key is always present so consumers can
-    distinguish 'no profiling' from 'old artifact')."""
-    from tpu_render_cluster.obs.profiling import get_profiler
-
-    return get_profiler().view()
-
-
 def save_obs_artifacts(
     prefix_path: Path, manager: ClusterManager, workers: list[Worker]
 ) -> tuple[Path, Path, Path]:
@@ -292,11 +283,6 @@ def save_obs_artifacts(
                 "pid": os.getpid(),
                 "metrics": get_registry().snapshot(),
             },
-            # Per-kernel roofline evidence (obs/profiling.py): like the
-            # process registry, the profiler is process-global and
-            # cumulative — summarize_roofline keeps newest-wins per
-            # kernel key.
-            "roofline": _process_roofline(),
             # Continuous-observability roll-up (obs/history.py): per-
             # counter increase/rate/trend and per-gauge envelopes over the
             # run's sampled window — the statistics.json `history` fold.

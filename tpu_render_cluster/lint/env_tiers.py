@@ -9,9 +9,7 @@ cache keys, and geometry-build memo keys. Reading one of their tier
 helpers from inside a traced function would bake the first trace's
 environment into the executable — the toggle-mid-process staleness bug
 the resolved-outside contract (integrator.resolve_bvh_config) exists
-to prevent, and exactly what lets the
-interleaved ``bench.py --bvh-compare`` hold every variant in one
-process.
+to prevent, and exactly what lets one process hold every variant.
 
 This pass finds the traced functions with the same static analysis as
 ``jit-purity`` (decorated defs, defs passed to ``jit``/``pallas_call``/

@@ -178,7 +178,7 @@ class ClusterManagerState:
         self.speculations: dict[WorkUnit, SpeculationRecord] = {}
         # Per-unit queue-to-result latency of each unit's WINNING result
         # (exact, one float per unit): the p99 the predictive scheduler is
-        # judged on (bench.py --speculation, chaos report stats).
+        # judged on (the chaos report's stats).
         self.unit_seconds: list[float] = []
         # Every time a unit left the worker that held it without a result:
         # (unit, worker it left, cause, wall time), ``cause`` one of

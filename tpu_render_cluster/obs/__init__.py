@@ -15,8 +15,8 @@
 - ``validate`` — trace-invariant checker backing scripts/validate_trace.py.
 
 ``get_registry()`` returns the process-global registry used by
-process-scoped subsystems (the render path, ``ops/assignment``,
-bench.py). Cluster components that can be colocated in one process (the
+process-scoped subsystems (the render path, ``ops/assignment``).
+Cluster components that can be colocated in one process (the
 harness runs a master and N workers on one loop) create their OWN
 instances so per-component views stay separable.
 """
@@ -84,7 +84,6 @@ __all__ = [
     "merge_timeline",
     "merge_wire",
     "render_compile_counter",
-    "render_fps_gauge",
     "resolve_flight_directory",
     "step",
     "tracer_process",
@@ -97,21 +96,8 @@ _global_registry = MetricsRegistry()
 
 
 def get_registry() -> MetricsRegistry:
-    """The process-global registry (render path, ops, bench)."""
+    """The process-global registry (render path, ops)."""
     return _global_registry
-
-
-def render_fps_gauge(registry: MetricsRegistry | None = None) -> Gauge:
-    """The frames/s gauge both bench.py and the TPU backend feed.
-
-    One definition site so the two writers can't drift apart in name,
-    help, or label shape (get-or-create raises on mismatch at runtime).
-    """
-    registry = registry if registry is not None else get_registry()
-    return registry.gauge(
-        "render_frames_per_second",
-        "Instantaneous device throughput (1 / execute_seconds)",
-    )
 
 
 def render_compile_counter(registry: MetricsRegistry | None = None) -> Counter:

@@ -249,9 +249,10 @@ def _fmt_bytes(value: float) -> str:
 
 
 def load_sched_bench(path: str | None = None) -> dict[str, Any] | None:
-    """The committed control-plane A/B record (``bench.py --sched`` →
-    ``results/SCHED_BENCH.json``), or None when absent/unreadable — the
-    dashboard must render fine on a checkout that never ran the bench."""
+    """The committed control-plane A/B record
+    (``results/SCHED_BENCH.json``, a CPU record of heap against scan tick
+    mode; nothing in the repo writes it any more, ROADMAP D5), or None
+    when absent/unreadable — the dashboard must render fine without it."""
     if path is None:
         path = os.path.join(
             os.path.dirname(os.path.dirname(os.path.dirname(

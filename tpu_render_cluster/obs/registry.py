@@ -9,8 +9,8 @@ module-global counters that used to be sprinkled through the scheduler.
 
 Design constraints:
 
-- zero dependencies (stdlib only) so the worker daemon, the render CLI,
-  and bench.py can all share it;
+- zero dependencies (stdlib only) so the worker daemon and the render
+  CLI can share it;
 - one lock per registry (metric mutation is a dict update + float add —
   far below contention at cluster event rates, and a single lock keeps
   ``snapshot()`` consistent);

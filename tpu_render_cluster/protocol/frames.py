@@ -22,7 +22,7 @@ boundary declared in :mod:`tpu_render_cluster.protocol.schema`
   (PROTOCOL.md: the split adds zero bytes on the wire).
 
 ``TRC_DISPATCH_FRAMES=encode`` restores the per-send ``encode_message``
-path (the A/B baseline for ``bench.py --sched``); the default
+path (the baseline the codec is compared with); the default
 ``cached`` uses this codec. Splices are pure string joins of int
 renderings (``str(int)`` is exactly ``json.dumps(int)``) plus one
 ``json.dumps`` for the ``job_id`` string (escaping).

@@ -2,8 +2,10 @@
 
 The hot op is a [rays x spheres] batch intersection whose inner products are
 matmul-shaped (``o @ centers^T``, ``d @ centers^T``) so XLA tiles them onto
-the MXU. Padded sphere slots carry radius 0 and never produce hits. A Pallas
-variant of the same kernel lives in pallas_kernels.py.
+the MXU. Padded sphere slots carry radius 0 and never produce hits. This is
+the reference path: what ``integrator.trace_paths`` runs where the Pallas
+kernels are off, and what every kernel in pallas_kernels.py is tested
+against.
 """
 
 from __future__ import annotations
@@ -35,17 +37,15 @@ def _ray_barrier(origins, directions):
 
 
 def intersect_spheres(scene: Scene, origins, directions):
-    """Nearest sphere hit per ray.
+    """Nearest sphere hit per ray, as an XLA pass (the reference path:
+    what ``trace_paths`` runs where the Pallas kernels are off, and what
+    every kernel is tested against).
 
     Args:
       origins, directions: [R, 3] float32 (directions unit).
     Returns:
       (t [R], index [R] int32) — t = INF when no hit.
     """
-    from tpu_render_cluster.render import pallas_kernels
-
-    if pallas_kernels.pallas_enabled():
-        return pallas_kernels.intersect_spheres_pallas(scene, origins, directions)
     origins, directions = _ray_barrier(origins, directions)
     oc_dot_d = directions @ scene.centers.T - jnp.sum(
         directions * origins, axis=-1, keepdims=True
@@ -96,13 +96,9 @@ def occluded_sun(scene: Scene, origins, directions) -> jnp.ndarray:
     """Unbounded any-hit shadow query (the sun is a delta light at infinity).
 
     Cheaper than ``occluded``: no nearest-hit ordering or argmin is needed,
-    just "does any sphere lie in front" — on TPU this runs a dedicated
-    Pallas any-hit kernel with a single OR-reduction over spheres.
+    just "does any sphere lie in front". An XLA pass like
+    ``intersect_spheres``: the reference path.
     """
-    from tpu_render_cluster.render import pallas_kernels
-
-    if pallas_kernels.pallas_enabled():
-        return pallas_kernels.occluded_pallas(scene, origins, directions)
     origins, directions = _ray_barrier(origins, directions)
     oc_dot_d = directions @ scene.centers.T - jnp.sum(
         directions * origins, axis=-1, keepdims=True

@@ -171,8 +171,7 @@ def _ray_sort_order(origins, directions, alive, mesh=None):
 
         # Shared broadphase (one fused [R, K] slab pass, ~1 ms at render
         # ray counts): the ray's nearest-entry overlapped instance AABB,
-        # K (=instances) for rays overlapping nothing — the same helper
-        # the nearest wrapper derives its per-block candidates from.
+        # K (=instances) for rays overlapping nothing.
         table = pk.mesh_instance_table(mesh)
         candidate = pk.instance_entry_candidates(
             origins, directions, table[:, 13:16], table[:, 16:19]
@@ -595,11 +594,17 @@ def trace_paths(
 ) -> jnp.ndarray:
     """Trace one sample per ray; returns radiance [R, 3].
 
-    On TPU this dispatches to the fused Pallas megakernel (the whole bounce
-    loop in one kernel, path state VMEM-resident, counter-based in-kernel
-    RNG — pallas_kernels.trace_paths_fused); elsewhere it runs the XLA
-    bounce scan below. The two paths use different RNG streams but identical
-    physics, so images agree statistically, not bit-for-bit.
+    Where ``pallas_enabled()`` (on the chip, or ``TRC_PALLAS=1``) one of
+    three Pallas kernels traces the rays, chosen by scene class: the
+    sphere megakernel (``trace_paths_fused``: the whole bounce loop in one
+    kernel, path state VMEM-resident, counter-based in-kernel RNG), the
+    mesh megakernel for a shallow resident mesh
+    (``trace_paths_fused_mesh``, ``mesh_megakernel_eligible``), or one
+    ``mesh_bounce_pallas`` launch a bounce with the rays re-sorted between
+    (``_trace_paths_deep``). Elsewhere the XLA bounce loop below runs: the
+    reference every kernel is tested against. The two sides use different
+    RNG streams but identical physics, so images agree statistically, not
+    bit-for-bit.
 
     ``rng_lanes`` (optional [R] int32) overrides the RNG counter per ray:
     the region render path (cluster tiling) passes each ray's FULL-frame
@@ -649,12 +654,13 @@ def trace_paths(
                 lane=jnp.asarray(rng_lanes, jnp.int32),
             )
         # Mesh scenes: the megakernel (whole bounce loop incl. the
-        # instanced BVH walk in one kernel) wins when the per-bounce walk
-        # is shallow — its in-walk normal/albedo tracking adds work to
-        # EVERY leaf visit, so deep-tree x many-instance scenes come out
-        # behind the per-bounce instanced kernels (measured on-chip,
-        # 256x256 4spp: 02_physics-mesh [3 nodes x 24 inst] 16.9 -> 38.9
-        # f/s; 03_physics-2-mesh [127 nodes x 48 inst] 1.89 -> 1.52).
+        # instanced BVH walk in one kernel) takes a shallow resident mesh
+        # (nodes x instances <= MESH_MEGAKERNEL_MAX_WALK, the kernel's
+        # precondition); everything deeper, streamed or with explicit
+        # lanes takes one bounce kernel a bounce. Its in-walk normal /
+        # albedo tracking adds work to EVERY leaf visit; where the gate
+        # should lie against today's bounce kernel is not measured
+        # (ROADMAP D19).
         if rng_lanes is None and pallas_kernels.mesh_megakernel_eligible(mesh):
             return pallas_kernels.trace_paths_fused_mesh(
                 scene, mesh, origins, directions, seed,
@@ -977,29 +983,7 @@ def _fused_frame_renderer(
             return tonemap(linear), live
         return tonemap(rendered)
 
-    render = _WithBlasTables(program, blas)
-
-    # Roofline profiling (obs/profiling.py): the first call captures the
-    # program's XLA cost analysis (FLOPs/bytes) under the masked tier's
-    # kernel key; the lru_cache above caches the instrumented wrapper, so
-    # later frames pay one flag check. The tlas/quant/bvh dims key every
-    # node-format variant to its own roofline row — the per-kernel
-    # placement deltas bench.py --bvh-compare records.
-    from tpu_render_cluster.obs.profiling import (
-        bvh_dims,
-        get_profiler,
-        kernel_key,
-    )
-
-    return get_profiler().instrument(
-        kernel_key(
-            "masked", scene_name,
-            w=width, h=height, s=samples, b=max_bounces,
-            **bvh_dims(tlas=use_tlas, quant=quant, builder=builder,
-                       wide=wide),
-        ),
-        render,
-    )
+    return _WithBlasTables(program, blas)
 
 
 def fused_frame_renderer(
@@ -1024,10 +1008,9 @@ def fused_frame_renderer(
 
     ``use_tlas``/``quant``/``builder``/``wide`` (None = env tiers,
     resolved HERE — outside the trace) are part of the cache key AND the
-    compiled program's identity: the interleaved ``bench.py
-    --bvh-compare`` holds one renderer per node-format variant in the
-    same process, and an env toggle between calls gets a fresh renderer
-    with a matching tree instead of a stale cache hit.
+    compiled program's identity: one process can hold one renderer per
+    node-format variant, and an env toggle between calls gets a fresh
+    renderer with a matching tree instead of a stale cache hit.
 
     ``with_live`` makes the closure return ``(image, live)`` from the
     same program: ``live`` is render_tile's per-bounce (live rays,
@@ -1103,26 +1086,7 @@ def _fused_region_renderer(
             tile_height, tile_width, 3
         )
 
-    render = _WithBlasTables(program, blas)
-
-    # Roofline profiling: one cost capture per tile SHAPE (matching the
-    # one-compile-per-shape contract of this renderer).
-    from tpu_render_cluster.obs.profiling import (
-        bvh_dims,
-        get_profiler,
-        kernel_key,
-    )
-
-    return get_profiler().instrument(
-        kernel_key(
-            "region", scene_name,
-            w=width, h=height, th=tile_height, tw=tile_width,
-            s=samples, b=max_bounces,
-            **bvh_dims(tlas=use_tlas, quant=quant, builder=builder,
-                       wide=wide),
-        ),
-        render,
-    )
+    return _WithBlasTables(program, blas)
 
 
 def fused_region_renderer(

@@ -1198,17 +1198,6 @@ def intersect_bvh_packet(bvh: MeshBVH, origins, directions, init_t=None):
     return best_t, best_index
 
 
-def intersect_mesh(bvh: MeshBVH, origins, directions, init_t=None):
-    """Nearest mesh hit: Pallas packet kernel on TPU, XLA walk elsewhere."""
-    from tpu_render_cluster.render import pallas_kernels
-
-    if pallas_kernels.pallas_enabled():
-        return pallas_kernels.intersect_bvh_pallas(
-            bvh, origins, directions, init_t
-        )
-    return intersect_bvh_packet(bvh, origins, directions, init_t)
-
-
 def occluded_bvh_packet(bvh: MeshBVH, origins, directions, already) -> jnp.ndarray:
     """Any-hit packet walk: True per ray once ANY triangle is hit.
 
@@ -1262,17 +1251,6 @@ def occluded_bvh_packet(bvh: MeshBVH, origins, directions, already) -> jnp.ndarr
         cond, body, (jnp.int32(0), already)
     )
     return occluded
-
-
-def occluded_mesh(bvh: MeshBVH, origins, directions, already) -> jnp.ndarray:
-    """Any-hit dispatch: Pallas kernel on TPU, XLA walk elsewhere."""
-    from tpu_render_cluster.render import pallas_kernels
-
-    if pallas_kernels.pallas_enabled():
-        return pallas_kernels.occluded_bvh_pallas(
-            bvh, origins, directions, already
-        )
-    return occluded_bvh_packet(bvh, origins, directions, already)
 
 
 # ---------------------------------------------------------------------------
@@ -1357,32 +1335,10 @@ def intersect_instances(
     and a mesh miss returns t == init_t (never closer, so callers using a
     strict ``<`` comparison see it as a miss).
 
-    On TPU this is ONE instanced-kernel launch (grid = ray blocks x
-    instances, world-AABB top-level cull per block) followed by XLA
-    gathers for the winning triangle's normal and instance's
-    rotation/albedo; elsewhere it is a lax.scan of per-instance walks.
+    A lax.scan of per-instance XLA walks (``intersect_bvh_packet``): the
+    reference path, what ``trace_paths`` runs where the Pallas kernels
+    are off, and what every kernel is tested against.
     """
-    from tpu_render_cluster.render import pallas_kernels
-
-    if pallas_kernels.pallas_enabled():
-        t, tri, inst = pallas_kernels.intersect_instances_pallas(
-            bvh, instances, origins, directions, init_t
-        )
-        # A seeded miss comes back with t == init_t (< INF), so the hit
-        # test must compare against the seed, not INF — otherwise the
-        # tri=0/inst=0 gathers below leak garbage normals/albedo where the
-        # scan branch returns zeros.
-        seed = INF if init_t is None else init_t
-        hit = (t < seed)[:, None]
-        normal_obj = bvh.normal[tri]
-        rot = instances.rotation[inst]  # [R, 3, 3]
-        normal_world = _normals_to_world(rot, normal_obj)
-        facing = jnp.sum(normal_world * directions, axis=-1) < 0.0
-        normal_world = jnp.where(facing[:, None], normal_world, -normal_world)
-        # Misses keep the scan path's zero normal/albedo contract.
-        best_normal = jnp.where(hit, normal_world, 0.0)
-        best_albedo = jnp.where(hit, instances.albedo[inst], 0.0)
-        return t, best_normal, best_albedo
 
     def per_instance(carry, k):
         best_t, best_normal, best_albedo = carry
@@ -1392,7 +1348,9 @@ def intersect_instances(
         )
         # Seed the walk with the best hit so far: t is in world units for
         # every instance, so earlier instances' hits prune this walk.
-        t, tri = intersect_mesh(bvh, local_origins, local_directions, best_t)
+        t, tri = intersect_bvh_packet(
+            bvh, local_origins, local_directions, best_t
+        )
         normal_obj = bvh.normal[tri]
         normal_world = _normals_to_world(rot, normal_obj)
         closer = t < best_t
@@ -1428,23 +1386,19 @@ def occluded_instances(
     so the per-instance scan skips the normal/albedo gathers and transform.
     ``already`` (optional, [R] bool) marks lanes the caller already knows
     are occluded (e.g. by the sphere any-hit): they stop driving the walks
-    and come back True.
+    and come back True. A lax.scan of XLA walks (``occluded_bvh_packet``):
+    the reference path, like ``intersect_instances``.
     """
-
-    from tpu_render_cluster.render import pallas_kernels
-
     if already is None:
         already = jnp.zeros((origins.shape[0],), bool)
-    if pallas_kernels.pallas_enabled():
-        return pallas_kernels.occluded_instances_pallas(
-            bvh, instances, origins, directions, already
-        )
 
     def per_instance(occluded, k):
         local_origins, local_directions = _rays_to_object_space(
             instances, k, origins, directions
         )
-        occluded = occluded_mesh(bvh, local_origins, local_directions, occluded)
+        occluded = occluded_bvh_packet(
+            bvh, local_origins, local_directions, occluded
+        )
         return occluded, None
 
     k_count = instances.translation.shape[0]
@@ -1636,8 +1590,8 @@ def tlas_node_bounds(topology: TlasTopology, lo_sorted, hi_sorted):
 # ---------------------------------------------------------------------------
 # Quantized node tables (ISSUE 15): fixed-point AABB slabs + packed meta
 #
-# The traversal kernels are memory-bound on node bytes (BVH_BENCH roofline);
-# this compresses a node table from 36 B/node (6 f32 slabs + 3 int32 links)
+# A walk reads its node table once a visit, so node bytes are what it
+# moves; this compresses a node table from 36 B/node (6 f32 slabs + 3 int32 links)
 # to 16 B (quant tier 1: 16-bit slabs packed two-per-int32 word) or 12 B
 # (tier 2: 8-bit slabs packed six-per-two-words), with skip/first/count
 # folded into ONE int32 meta word. Quantization is against the table's own

@@ -1,13 +1,24 @@
-"""Pallas TPU kernel for the render engine's hot op: nearest-hit intersection.
+"""The render engine's three Pallas TPU kernels, one per scene class.
 
-The path tracer spends its time in the rays x spheres intersection
+The path tracer spends its time intersecting rays with the scene
 (reference analog: the per-frame render loop inside Blender that
 worker/src/rendering/runner/mod.rs shells out to; here the render engine is
-TPU-native so the hot loop is ours to own). The XLA version in
-``geometry.intersect_spheres`` materializes several [R, N] intermediates
-between HBM-level fusions; this kernel fuses quadratic solve, validity
-masking, and the min/argmin reduction into one VMEM-resident pass per ray
-block.
+TPU-native so the hot loop is ours to own). ``integrator.trace_paths``
+picks one kernel by what the scene holds, and each is one ``pallas_call``:
+
+- ``trace_paths_fused`` (``_trace_fused``): sphere scenes, the whole bounce
+  loop in one launch with the path state resident in VMEM;
+- ``trace_paths_fused_mesh`` (``_trace_fused_mesh``): the same for a
+  shallow resident mesh (``mesh_megakernel_eligible``);
+- ``mesh_bounce_pallas`` (``_mesh_bounce_io``): one bounce a launch, path
+  state in and out, for every deeper or streamed mesh, so that the
+  integrator can re-sort the rays between bounces.
+
+The XLA walks of ``geometry`` and ``mesh`` are the reference they are
+tested against and what runs where ``pallas_enabled()`` is false; they
+materialize several [R, N] intermediates between HBM-level fusions, where
+a kernel fuses the quadratic solve, validity masking and the min/argmin
+reduction into one VMEM-resident pass per ray block.
 
 Layout choices (see /opt/skills/guides/pallas_guide.md):
 - rays ride the *lane* axis (128-wide) as [3, BLOCK_R] blocks; the sphere
@@ -16,7 +27,7 @@ Layout choices (see /opt/skills/guides/pallas_guide.md):
 - sphere data ([3, N] centers, [N, 1] radius^2 / |c|^2) is small enough to
   sit whole in VMEM for every grid step;
 - every in-kernel contraction is exact in float32. The K=3 ones (d.c,
-  o.c) of the per-bounce and mesh kernels are dot_generals at full f32
+  o.c) of the two mesh kernels are dot_generals at full f32
   precision (``_dot_f32``: six bf16 MXU passes, both operands split on
   the VPU), and so are their one-hot gathers. The sphere megakernel
   (``_trace_kernel_factory``) pays only the passes its operands need: a
@@ -31,7 +42,7 @@ Layout choices (see /opt/skills/guides/pallas_guide.md):
   bounces, 21.6 as broadcast multiply-adds on the VPU (PR 38) and 13.25
   one-pass with nothing carried (PERF.md, PR 46).
 
-On non-TPU backends the kernel runs in interpret mode, so the same code
+On non-TPU backends the kernels run in interpret mode, so the same code
 path is exercised by CPU tests.
 """
 
@@ -50,8 +61,8 @@ from jax.experimental.pallas import tpu as pltpu
 INF = 1e30
 EPS = 1e-3
 
-# Rays per grid step, of the three sphere wrappers alike. Swept on the
-# chip on the one-pass megakernel (`_trace_fused` alone, 512x512x8, PR 46):
+# Rays per grid step of the sphere megakernel. Swept on the chip
+# on the one-pass kernel (`_trace_fused` alone, 512x512x8, PR 46):
 # 2048 -> 13.26 ms a frame, 4096 -> 13.25, 8192 -> 13.08. An
 # [N_spheres, BLOCK_R] intermediate is 256 vregs at 64 spheres and 4096
 # rays, so the body streams through VMEM at every one of them and the
@@ -65,16 +76,17 @@ BLOCK_R = 4096
 # candidate-first instance sweep runs inside the kernel) the on-chip sweep
 # favors 1024: smaller blocks are spatially tighter, so the seeded best-t
 # and the top-level AABB skip cull more of the per-block instance sweep,
-# and the walk's live-lane mask drains sooner. (The older two-axis
-# rays x instances grid amortized per-step overhead differently and
-# peaked at 2048 — that sweep read 1024 -> 16.1 f/s, 2048 -> 16.9,
-# 4096 -> 16.7, 8192 -> 15.0; it no longer applies.)
+# and the walk's live-lane mask drains sooner.
 BVH_BLOCK_R = 1024
 _SUBLANE = 8  # f32 sublane tile; sphere count is padded to a multiple
 
 
 def pallas_enabled() -> bool:
-    """Whether intersect dispatches to the Pallas kernel.
+    """Whether ``integrator.trace_paths`` traces through a Pallas kernel
+    (one of three, by scene class) or through the XLA bounce loop, the
+    reference. Asked there, and in the two places that shape its input
+    (``render_tile``, the region renderer); the XLA walks of ``geometry``
+    and ``mesh`` never ask.
 
     Default: only on a real TPU backend (interpret mode is a debugging
     path, much slower than XLA on CPU). ``TRC_PALLAS=1`` forces it on
@@ -117,8 +129,8 @@ def _dot_f32(a, b, dimension_numbers):
     loop (``_gather_hit``), and its K=3 contractions one pass against a
     stack of the centres' parts (``_dot_k3_exact``: 13.25 ms a
     512x512x8 frame where this function read 16.92 and the VPU 21.6;
-    PERF.md, PR 46). The per-bounce sphere kernels and the mesh kernels
-    (their sphere pass and one-hot gathers included) use this function.
+    PERF.md, PR 46). The two mesh kernels (their sphere pass and
+    one-hot gathers included) use this function.
     """
     return jax.lax.dot_general(
         a, b, dimension_numbers, precision=jax.lax.Precision.HIGHEST,
@@ -128,14 +140,14 @@ def _dot_f32(a, b, dimension_numbers):
 
 def tlas_enabled() -> bool:
     """Whether the mesh kernels traverse a two-level TLAS/BLAS hierarchy
-    (default) or the flat per-instance sweep (``TRC_TLAS=0`` — the A/B
-    baseline ``bench.py --bvh-compare`` measures against, and the on-chip
+    (default) or the flat per-instance sweep (``TRC_TLAS=0`` — the
+    baseline the two-level walk is compared with, and the on-chip
     triage kill switch).
 
     Read at *trace* time like ``TRC_PALLAS``: jitted renderers bake the
     decision, and the renderer factories additionally thread it as a
     static jit argument so both kernel variants can coexist in one
-    process (the interleaved A/B bench relies on that).
+    process.
     """
     value = env_str("TRC_TLAS")
     if value is None:
@@ -200,7 +212,7 @@ def bvh_quant_mode() -> int:
     ``TRC_TLAS``: read by untraced renderer factories only (the
     ``env-tiers`` lint pass pins this) and threaded into every kernel
     identity, so distinct tiers coexist as distinct compiled programs in
-    one process (the interleaved A/B bench).
+    one process.
     """
     return max(0, min(env_int("TRC_BVH_QUANT", 0), 2))
 
@@ -354,168 +366,6 @@ def initial_mesh_sort_keys(mesh, origins, directions, alive):
     return mesh_sort_keys(
         origins, directions, alive, key_lo, key_inv,
         candidate=instance_entry_candidates(origins, directions, lo_s, hi_s),
-    )
-
-
-def _nearest_hit_kernel(o_ref, d_ref, c_ref, r2_ref, csq_ref, t_ref, idx_ref):
-    """One ray block vs all spheres; writes min-t and argmin index."""
-    o = o_ref[:, :]  # [3, BR]
-    d = d_ref[:, :]  # [3, BR]
-    c = c_ref[:, :]  # [3, N]
-    contract_first = (((0,), (0,)), ((), ()))
-    # [N, BR] contractions on the MXU.
-    dc = _dot_f32(c, d, contract_first)
-    oc = _dot_f32(c, o, contract_first)
-    od = jnp.sum(o * d, axis=0, keepdims=True)  # [1, BR]
-    o_sq = jnp.sum(o * o, axis=0, keepdims=True)  # [1, BR]
-
-    r2 = r2_ref[:, :]  # [N, 1]
-    oc_dot_d = dc - od  # d . (c - o)
-    oc_sq = o_sq - 2.0 * oc + csq_ref[:, :]  # |o - c|^2
-    disc = oc_dot_d * oc_dot_d - (oc_sq - r2)
-    valid = (disc > 0.0) & (r2 > 0.0)
-    sqrt_disc = jnp.sqrt(jnp.maximum(disc, 0.0))
-    t0 = oc_dot_d - sqrt_disc
-    t1 = oc_dot_d + sqrt_disc
-    t = jnp.where(t0 > EPS, t0, jnp.where(t1 > EPS, t1, INF))
-    t = jnp.where(valid, t, INF)  # [N, BR]
-
-    n = t.shape[0]
-    t_min = jnp.min(t, axis=0, keepdims=True)  # [1, BR]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, t.shape, 0)
-    # First index attaining the min (matches jnp.argmin tie-breaking).
-    idx = jnp.min(jnp.where(t == t_min, lanes, n), axis=0, keepdims=True)
-    t_ref[:, :] = t_min
-    idx_ref[:, :] = jnp.minimum(idx, n - 1)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _nearest_hit(origins, directions, centers, radii, *, interpret: bool):
-    rays = origins.shape[0]
-    padded_rays = -(-rays // BLOCK_R) * BLOCK_R
-    ray_pad = padded_rays - rays
-    o_t = jnp.pad(origins, ((0, ray_pad), (0, 0))).T  # [3, Rp]
-    d_t = jnp.pad(directions, ((0, ray_pad), (0, 0))).T  # [3, Rp]
-
-    n = centers.shape[0]
-    padded_n = -(-n // _SUBLANE) * _SUBLANE
-    sphere_pad = padded_n - n
-    c_t = jnp.pad(centers, ((0, sphere_pad), (0, 0))).T  # [3, Np]
-    radii = jnp.pad(radii, (0, sphere_pad))
-    r2 = (radii * radii)[:, None]  # [Np, 1]
-    csq = jnp.sum(c_t * c_t, axis=0)[:, None]  # [Np, 1]
-
-    grid = (padded_rays // BLOCK_R,)
-    t, idx = pl.pallas_call(
-        _nearest_hit_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((3, BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, padded_n), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, padded_rays), jnp.float32),
-            jax.ShapeDtypeStruct((1, padded_rays), jnp.int32),
-        ],
-        interpret=interpret,
-    )(o_t, d_t, c_t, r2, csq)
-    return t[0, :rays], idx[0, :rays]
-
-
-def intersect_spheres_pallas(scene, origins, directions):
-    """Drop-in Pallas replacement for ``geometry.intersect_spheres``.
-
-    Returns (t [R] float32 with INF misses, index [R] int32).
-    """
-    # Padded ray slots (zero origin/direction) produce harmless garbage that
-    # the wrapper slices off; padded sphere slots have r2 == 0 -> never hit.
-    t, idx = _nearest_hit(
-        origins, directions, scene.centers, scene.radii, interpret=_interpret()
-    )
-    # Padded sphere indices can only appear for all-miss rays (t == INF);
-    # clamp into range like the jnp argmin would.
-    return t, jnp.minimum(idx, scene.centers.shape[0] - 1)
-
-
-def _any_hit_kernel(o_ref, d_ref, c_ref, r2_ref, csq_ref, hit_ref):
-    """Shadow query: does ANY sphere intersect the ray (t > EPS)?
-
-    Same quadratic solve as _nearest_hit_kernel but no argmin and no min-t:
-    the reduction is a single boolean OR over the sublane (sphere) axis —
-    about a third less VMEM traffic per block than the nearest-hit pass.
-    """
-    o = o_ref[:, :]  # [3, BR]
-    d = d_ref[:, :]  # [3, BR]
-    c = c_ref[:, :]  # [3, N]
-    contract_first = (((0,), (0,)), ((), ()))
-    dc = _dot_f32(c, d, contract_first)
-    oc = _dot_f32(c, o, contract_first)
-    od = jnp.sum(o * d, axis=0, keepdims=True)
-    o_sq = jnp.sum(o * o, axis=0, keepdims=True)
-
-    r2 = r2_ref[:, :]
-    oc_dot_d = dc - od
-    oc_sq = o_sq - 2.0 * oc + csq_ref[:, :]
-    disc = oc_dot_d * oc_dot_d - (oc_sq - r2)
-    valid = (disc > 0.0) & (r2 > 0.0)
-    sqrt_disc = jnp.sqrt(jnp.maximum(disc, 0.0))
-    # Hit iff the far root is in front and the near root isn't past EPS
-    # behind us: equivalent to (t0 > EPS) | (t1 > EPS) with t = min valid.
-    t1 = oc_dot_d + sqrt_disc
-    hit = valid & (t1 > EPS)
-    hit_ref[:, :] = jnp.max(
-        jnp.where(hit, 1.0, 0.0), axis=0, keepdims=True
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _any_hit(origins, directions, centers, radii, *, interpret: bool):
-    rays = origins.shape[0]
-    padded_rays = -(-rays // BLOCK_R) * BLOCK_R
-    ray_pad = padded_rays - rays
-    o_t = jnp.pad(origins, ((0, ray_pad), (0, 0))).T
-    d_t = jnp.pad(directions, ((0, ray_pad), (0, 0))).T
-
-    n = centers.shape[0]
-    padded_n = -(-n // _SUBLANE) * _SUBLANE
-    sphere_pad = padded_n - n
-    c_t = jnp.pad(centers, ((0, sphere_pad), (0, 0))).T
-    radii = jnp.pad(radii, (0, sphere_pad))
-    r2 = (radii * radii)[:, None]
-    csq = jnp.sum(c_t * c_t, axis=0)[:, None]
-
-    grid = (padded_rays // BLOCK_R,)
-    hit = pl.pallas_call(
-        _any_hit_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((3, BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, padded_n), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((padded_n, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((1, padded_rays), jnp.float32)],
-        interpret=interpret,
-    )(o_t, d_t, c_t, r2, csq)[0]
-    return hit[0, :rays] > 0.5
-
-
-def occluded_pallas(scene, origins, directions):
-    """Any-hit shadow query (Pallas). Matches ``geometry.occluded`` for the
-    sun case: unbounded max_t, plane excluded."""
-    return _any_hit(
-        origins, directions, scene.centers, scene.radii, interpret=_interpret()
     )
 
 
@@ -727,7 +577,7 @@ def _trace_kernel_factory(
 
         def bounce_step(bounce, carry):
             o, d, throughput, radiance, alive = carry
-            # -- nearest sphere hit (same math as _nearest_hit_kernel) ----
+            # -- nearest sphere hit (geometry.intersect_spheres' math) ----
             # c . o is made anew although this origin is the last
             # bounce's shadow origin, whose product the sun test made: a
             # one-pass contraction costs less than carrying an [N, BR]
@@ -989,10 +839,11 @@ WALK_COUNTS = (
     "node_visits", "treelet_fetches", "leaf_tests", "treelet_entries",
     "group_tests", "treelet_prefetches",
 )
-# Mesh-megakernel dispatch bound: use the fused whole-bounce-loop kernel
-# when bvh_nodes x instances is at most this; deeper walks pay more for
-# the in-kernel normal tracking than the fusion saves (see
-# integrator.trace_paths for the on-chip measurements).
+# Mesh-megakernel bound: the fused whole-bounce-loop kernel takes a mesh
+# whose bvh_nodes x instances is at most this, and refuses a deeper one
+# (``trace_paths_fused_mesh`` raises): deeper walks pay more for the
+# in-kernel normal tracking than the fusion saves, and no test holds the
+# kernel to the XLA loop past it (ROADMAP D19).
 MESH_MEGAKERNEL_MAX_WALK = 1024
 
 
@@ -1012,122 +863,7 @@ def mesh_megakernel_eligible(mesh) -> bool:
     )
 
 
-def _bvh_kernel_factory(n_nodes: int, leaf_size: int):
-    def kernel(
-        o_ref, d_ref, tinit_ref, v0_ref, e1_ref, e2_ref,
-        bmin_ref, bmax_ref, skip_ref, first_ref, count_ref,
-        t_ref, idx_ref,
-    ):
-        o = o_ref[:, :]  # [3, BR]
-        d = d_ref[:, :]
-        ox, oy, oz = o[0:1, :], o[1:2, :], o[2:3, :]
-        dx, dy, dz = d[0:1, :], d[1:2, :], d[2:3, :]
-        small = jnp.abs(d) < 1e-12
-        inv = 1.0 / jnp.where(small, jnp.where(d < 0, -1e-12, 1e-12), d)
-        invx, invy, invz = inv[0:1, :], inv[1:2, :], inv[2:3, :]
-        block = o.shape[1]
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (leaf_size, block), 0)
-
-        def cond(carry):
-            node, _, _ = carry
-            return node < n_nodes
-
-        def body(carry):
-            node, best_t, best_idx = carry
-            # Packet AABB slab test against this node ([1, BR] per axis).
-            lox = (bmin_ref[node, 0] - ox) * invx
-            hix = (bmax_ref[node, 0] - ox) * invx
-            loy = (bmin_ref[node, 1] - oy) * invy
-            hiy = (bmax_ref[node, 1] - oy) * invy
-            loz = (bmin_ref[node, 2] - oz) * invz
-            hiz = (bmax_ref[node, 2] - oz) * invz
-            tnear = jnp.maximum(
-                jnp.maximum(jnp.minimum(lox, hix), jnp.minimum(loy, hiy)),
-                jnp.minimum(loz, hiz),
-            )
-            tfar = jnp.minimum(
-                jnp.minimum(jnp.maximum(lox, hix), jnp.maximum(loy, hiy)),
-                jnp.maximum(loz, hiz),
-            )
-            packet_hit = (tfar >= jnp.maximum(tnear, 0.0)) & (tnear < best_t)
-            hit_any = jnp.any(packet_hit)
-
-            count = count_ref[node]
-            is_leaf = count > 0
-            start = first_ref[node]
-
-            # Branchless leaf pass: Moeller-Trumbore for the whole aligned
-            # slot, vectorized [leaf_size, BR]; masked to nothing on inner
-            # nodes / packet misses.
-            v0b = v0_ref[pl.dslice(start, leaf_size), :]
-            e1b = e1_ref[pl.dslice(start, leaf_size), :]
-            e2b = e2_ref[pl.dslice(start, leaf_size), :]
-            v0x, v0y, v0z = v0b[:, 0:1], v0b[:, 1:2], v0b[:, 2:3]  # [L, 1]
-            e1x, e1y, e1z = e1b[:, 0:1], e1b[:, 1:2], e1b[:, 2:3]
-            e2x, e2y, e2z = e2b[:, 0:1], e2b[:, 1:2], e2b[:, 2:3]
-            # pvec = d x e2 -> [L, BR]
-            pvx = dy * e2z - dz * e2y
-            pvy = dz * e2x - dx * e2z
-            pvz = dx * e2y - dy * e2x
-            det = e1x * pvx + e1y * pvy + e1z * pvz
-            inv_det = 1.0 / jnp.where(jnp.abs(det) < BVH_DONE_EPS,
-                                      BVH_DONE_EPS, det)
-            tvx = ox - v0x
-            tvy = oy - v0y
-            tvz = oz - v0z
-            u = (tvx * pvx + tvy * pvy + tvz * pvz) * inv_det
-            # qvec = tvec x e1 -> [L, BR]
-            qvx = tvy * e1z - tvz * e1y
-            qvy = tvz * e1x - tvx * e1z
-            qvz = tvx * e1y - tvy * e1x
-            v = (dx * qvx + dy * qvy + dz * qvz) * inv_det
-            tt = (e2x * qvx + e2y * qvy + e2z * qvz) * inv_det
-            tri_hit = (
-                (jnp.abs(det) > BVH_DONE_EPS)
-                & (u >= 0.0)
-                & (v >= 0.0)
-                & (u + v <= 1.0)
-                & (tt > EPS)
-                & (lanes < count)
-                & is_leaf
-                & hit_any
-            )
-            t_cand = jnp.where(tri_hit, tt, INF)  # [L, BR]
-            t_leaf = jnp.min(t_cand, axis=0, keepdims=True)  # [1, BR]
-            local = jnp.min(
-                jnp.where(t_cand == t_leaf, lanes, leaf_size),
-                axis=0,
-                keepdims=True,
-            )
-            closer = t_leaf < best_t
-            best_t = jnp.where(closer, t_leaf, best_t)
-            best_idx = jnp.where(
-                closer, start + jnp.minimum(local, leaf_size - 1), best_idx
-            )
-
-            next_node = jnp.where(
-                hit_any,
-                jnp.where(is_leaf, skip_ref[node], node + 1),
-                skip_ref[node],
-            )
-            return next_node, best_t, best_idx
-
-        _, best_t, best_idx = jax.lax.while_loop(
-            cond,
-            body,
-            (
-                jnp.int32(0),
-                tinit_ref[:, :],  # cull seed from earlier instances
-                jnp.zeros((1, block), jnp.int32),
-            ),
-        )
-        t_ref[:, :] = best_t
-        idx_ref[:, :] = best_idx
-
-    return kernel
-
-
-def _pad_rays_to_miss(origins, directions, block: int = BVH_BLOCK_R):
+def _pad_rays_to_miss(origins, directions, block: int):
     """Block-pad rays so pad lanes provably MISS the tree.
 
     A zero pad direction would turn the slab test degenerate (inv ~ 1e12
@@ -1143,490 +879,6 @@ def _pad_rays_to_miss(origins, directions, block: int = BVH_BLOCK_R):
     if ray_pad:
         d_t = d_t.at[1, rays:].set(1.0)
     return o_t, d_t, rays, padded_rays
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _bvh_nearest(
-    origins, directions, init_t, v0, e1, e2, bounds_min, bounds_max, skip,
-    first, count, *, interpret: bool,
-):
-    from tpu_render_cluster.render.mesh import LEAF_SIZE
-
-    o_t, d_t, rays, padded_rays = _pad_rays_to_miss(origins, directions)
-    t_init = jnp.pad(
-        init_t[None, :], ((0, 0), (0, padded_rays - rays)),
-        constant_values=INF,
-    )
-
-    n_nodes = skip.shape[0]
-    grid = (padded_rays // BVH_BLOCK_R,)
-    whole = lambda i: (0, 0)  # noqa: E731
-    flat = lambda i: (0,)  # noqa: E731
-    t, idx = pl.pallas_call(
-        _bvh_kernel_factory(n_nodes, LEAF_SIZE),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((3, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec(v0.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(e1.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(e2.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(bounds_min.shape, whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec(bounds_max.shape, whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-            pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-            pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, padded_rays), jnp.float32),
-            jax.ShapeDtypeStruct((1, padded_rays), jnp.int32),
-        ],
-        interpret=interpret,
-    )(o_t, d_t, t_init, v0, e1, e2, bounds_min, bounds_max, skip, first, count)
-    return t[0, :rays], idx[0, :rays]
-
-
-def intersect_bvh_pallas(bvh, origins, directions, init_t=None):
-    """Pallas drop-in for ``mesh.intersect_bvh_packet`` (same results)."""
-    if init_t is None:
-        init_t = jnp.full((origins.shape[0],), INF, jnp.float32)
-    return _bvh_nearest(
-        origins, directions, init_t, bvh.v0, bvh.e1, bvh.e2,
-        bvh.bounds_min, bvh.bounds_max, bvh.skip, bvh.first, bvh.count,
-        interpret=_interpret(),
-    )
-
-
-def _bvh_anyhit_kernel_factory(n_nodes: int, leaf_size: int):
-    def kernel(
-        o_ref, d_ref, already_ref, v0_ref, e1_ref, e2_ref,
-        bmin_ref, bmax_ref, skip_ref, first_ref, count_ref,
-        occ_ref,
-    ):
-        o = o_ref[:, :]
-        d = d_ref[:, :]
-        ox, oy, oz = o[0:1, :], o[1:2, :], o[2:3, :]
-        dx, dy, dz = d[0:1, :], d[1:2, :], d[2:3, :]
-        small = jnp.abs(d) < 1e-12
-        inv = 1.0 / jnp.where(small, jnp.where(d < 0, -1e-12, 1e-12), d)
-        invx, invy, invz = inv[0:1, :], inv[1:2, :], inv[2:3, :]
-        block = o.shape[1]
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (leaf_size, block), 0)
-
-        def cond(carry):
-            node, _ = carry
-            return node < n_nodes
-
-        def body(carry):
-            node, occluded = carry
-            lox = (bmin_ref[node, 0] - ox) * invx
-            hix = (bmax_ref[node, 0] - ox) * invx
-            loy = (bmin_ref[node, 1] - oy) * invy
-            hiy = (bmax_ref[node, 1] - oy) * invy
-            loz = (bmin_ref[node, 2] - oz) * invz
-            hiz = (bmax_ref[node, 2] - oz) * invz
-            tnear = jnp.maximum(
-                jnp.maximum(jnp.minimum(lox, hix), jnp.minimum(loy, hiy)),
-                jnp.minimum(loz, hiz),
-            )
-            tfar = jnp.minimum(
-                jnp.minimum(jnp.maximum(lox, hix), jnp.maximum(loy, hiy)),
-                jnp.maximum(loz, hiz),
-            )
-            packet_hit = (
-                (tfar >= jnp.maximum(tnear, 0.0)) & (occluded <= 0.0)
-            )
-            hit_any = jnp.any(packet_hit)
-
-            count = count_ref[node]
-            is_leaf = count > 0
-            start = first_ref[node]
-
-            v0b = v0_ref[pl.dslice(start, leaf_size), :]
-            e1b = e1_ref[pl.dslice(start, leaf_size), :]
-            e2b = e2_ref[pl.dslice(start, leaf_size), :]
-            v0x, v0y, v0z = v0b[:, 0:1], v0b[:, 1:2], v0b[:, 2:3]
-            e1x, e1y, e1z = e1b[:, 0:1], e1b[:, 1:2], e1b[:, 2:3]
-            e2x, e2y, e2z = e2b[:, 0:1], e2b[:, 1:2], e2b[:, 2:3]
-            pvx = dy * e2z - dz * e2y
-            pvy = dz * e2x - dx * e2z
-            pvz = dx * e2y - dy * e2x
-            det = e1x * pvx + e1y * pvy + e1z * pvz
-            inv_det = 1.0 / jnp.where(
-                jnp.abs(det) < BVH_DONE_EPS, BVH_DONE_EPS, det
-            )
-            tvx = ox - v0x
-            tvy = oy - v0y
-            tvz = oz - v0z
-            u = (tvx * pvx + tvy * pvy + tvz * pvz) * inv_det
-            qvx = tvy * e1z - tvz * e1y
-            qvy = tvz * e1x - tvx * e1z
-            qvz = tvx * e1y - tvy * e1x
-            v = (dx * qvx + dy * qvy + dz * qvz) * inv_det
-            tt = (e2x * qvx + e2y * qvy + e2z * qvz) * inv_det
-            tri_hit = (
-                (jnp.abs(det) > BVH_DONE_EPS)
-                & (u >= 0.0)
-                & (v >= 0.0)
-                & (u + v <= 1.0)
-                & (tt > EPS)
-                & (lanes < count)
-                & is_leaf
-                & hit_any
-            )
-            occluded = jnp.maximum(
-                occluded,
-                jnp.max(jnp.where(tri_hit, 1.0, 0.0), axis=0, keepdims=True),
-            )
-            next_node = jnp.where(
-                hit_any,
-                jnp.where(is_leaf, skip_ref[node], node + 1),
-                skip_ref[node],
-            )
-            return next_node, occluded
-
-        _, occluded = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), already_ref[:, :])
-        )
-        occ_ref[:, :] = occluded
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _bvh_anyhit(
-    origins, directions, already, v0, e1, e2, bounds_min, bounds_max, skip,
-    first, count, *, interpret: bool,
-):
-    from tpu_render_cluster.render.mesh import LEAF_SIZE
-
-    o_t, d_t, rays, padded_rays = _pad_rays_to_miss(origins, directions)
-    # Pad lanes start "occluded" so they never extend the walk.
-    already_f = jnp.pad(
-        already.astype(jnp.float32)[None, :],
-        ((0, 0), (0, padded_rays - rays)),
-        constant_values=1.0,
-    )
-
-    n_nodes = skip.shape[0]
-    grid = (padded_rays // BVH_BLOCK_R,)
-    whole = lambda i: (0, 0)  # noqa: E731
-    flat = lambda i: (0,)  # noqa: E731
-    occ = pl.pallas_call(
-        _bvh_anyhit_kernel_factory(n_nodes, LEAF_SIZE),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((3, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec(v0.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(e1.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(e2.shape, whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec(bounds_min.shape, whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec(bounds_max.shape, whole, memory_space=pltpu.SMEM),
-            pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-            pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-            pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((1, padded_rays), jnp.float32),
-        interpret=interpret,
-    )(o_t, d_t, already_f, v0, e1, e2, bounds_min, bounds_max, skip, first, count)
-    return occ[0, :rays] > 0.0
-
-
-def occluded_bvh_pallas(bvh, origins, directions, already):
-    """Pallas drop-in for ``mesh.occluded_bvh_packet`` (same results)."""
-    return _bvh_anyhit(
-        origins, directions, already, bvh.v0, bvh.e1, bvh.e2,
-        bvh.bounds_min, bvh.bounds_max, bvh.skip, bvh.first, bvh.count,
-        interpret=_interpret(),
-    )
-
-
-# ---------------------------------------------------------------------------
-# Instanced BVH traversal: ALL instances in one kernel launch.
-#
-# The scan-over-instances alternative executes the single-instance kernel K
-# times per pass; here the grid is (ray_blocks, K) with k minormost, so the
-# output block for a ray block stays VMEM-resident while every instance
-# walks it (initialize at k == 0, min-accumulate after). Instance
-# transforms (9 rotation + 3 translation + 1 inv-scale scalars) live in
-# SMEM and are applied to the ray block in-kernel — no [K*R] ray
-# materialization in HBM, one launch per pass instead of K.
-
-
-def _bvh_instanced_kernel_factory(
-    n_nodes: int, leaf_size: int, k_count: int, anyhit: bool
-):
-    def kernel(o_ref, d_ref, *rest):
-        if anyhit:
-            (inst_ref, v0_ref, e1_ref, e2_ref, bmin_ref, bmax_ref,
-             skip_ref, first_ref, count_ref, *out_refs) = rest
-        else:
-            # Nearest variant carries a seed-t input (the caller's already
-            # known closest hit — sphere/plane t from the same bounce, so
-            # walks that cannot beat it are culled before they start) and a
-            # per-block CANDIDATE instance (the broadphase's nearest-entry
-            # AABB for the block's first lane; the integrator sorts rays by
-            # candidate, so one id represents the block).
-            (tinit_ref, cand_ref, inst_ref, v0_ref, e1_ref, e2_ref,
-             bmin_ref, bmax_ref, skip_ref, first_ref, count_ref,
-             *out_refs) = rest
-
-        # One grid step per RAY BLOCK; instances run in an in-kernel fori
-        # loop. (An earlier revision put instances on a second grid axis —
-        # 48x more grid steps, each paying block-copy + bookkeeping
-        # overhead and round-tripping best-t through the output refs.)
-        wo = o_ref[:, :]
-        wd = d_ref[:, :]
-        block = wo.shape[1]
-
-        def winv(v):
-            small = jnp.abs(v) < 1e-12
-            return 1.0 / jnp.where(small, jnp.where(v < 0, -1e-12, 1e-12), v)
-
-        wox, woy, woz = wo[0:1, :], wo[1:2, :], wo[2:3, :]
-        wdx, wdy, wdz = wd[0:1, :], wd[1:2, :], wd[2:3, :]
-        wix, wiy, wiz = winv(wdx), winv(wdy), winv(wdz)
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (leaf_size, block), 0)
-
-        def per_instance(k, carry):
-            # World -> object from SMEM scalars (x' = R^T (x - t) / s; the
-            # direction scales by 1/s too so t stays in world units).
-            r00, r01, r02 = inst_ref[k, 0], inst_ref[k, 1], inst_ref[k, 2]
-            r10, r11, r12 = inst_ref[k, 3], inst_ref[k, 4], inst_ref[k, 5]
-            r20, r21, r22 = inst_ref[k, 6], inst_ref[k, 7], inst_ref[k, 8]
-            tx, ty, tz = inst_ref[k, 9], inst_ref[k, 10], inst_ref[k, 11]
-            inv_s = inst_ref[k, 12]
-
-            if anyhit:
-                # Lanes occluded by earlier instances stop driving the cull.
-                cull_limit = jnp.where(carry > 0.0, -INF, INF)
-            else:
-                # Per-lane best-so-far (seeded with the caller's
-                # sphere/plane t): an instance whose AABB entry lies beyond
-                # every lane's current best cannot improve anything.
-                cull_limit = carry[0]
-
-            # Top-level cull: slab-test the ray block against this
-            # instance's WORLD AABB with the untransformed rays; skip the
-            # whole walk when nothing in the block can touch the instance.
-            wlox = (inst_ref[k, 13] - wox) * wix
-            whix = (inst_ref[k, 16] - wox) * wix
-            wloy = (inst_ref[k, 14] - woy) * wiy
-            whiy = (inst_ref[k, 17] - woy) * wiy
-            wloz = (inst_ref[k, 15] - woz) * wiz
-            whiz = (inst_ref[k, 18] - woz) * wiz
-            wnear = jnp.maximum(
-                jnp.maximum(jnp.minimum(wlox, whix), jnp.minimum(wloy, whiy)),
-                jnp.minimum(wloz, whiz),
-            )
-            wfar = jnp.minimum(
-                jnp.minimum(jnp.maximum(wlox, whix), jnp.maximum(wloy, whiy)),
-                jnp.maximum(wloz, whiz),
-            )
-            touch = jnp.any(
-                (wfar >= jnp.maximum(wnear, 0.0)) & (wnear < cull_limit)
-            )
-
-            def run_walk():
-                sx, sy, sz = wox - tx, woy - ty, woz - tz
-                # Column j of R^T is row j of R: o'_i = sum_j s_j * R[j][i].
-                ox = (sx * r00 + sy * r10 + sz * r20) * inv_s
-                oy = (sx * r01 + sy * r11 + sz * r21) * inv_s
-                oz = (sx * r02 + sy * r12 + sz * r22) * inv_s
-                dx = (wdx * r00 + wdy * r10 + wdz * r20) * inv_s
-                dy = (wdx * r01 + wdy * r11 + wdz * r21) * inv_s
-                dz = (wdx * r02 + wdy * r12 + wdz * r22) * inv_s
-                invx, invy, invz = winv(dx), winv(dy), winv(dz)
-
-                def cond(walk):
-                    # (An all-lanes-occluded early exit for the anyhit walk
-                    # was measured slower: the per-iteration cross-lane
-                    # reduction costs more than the iterations it saves.)
-                    return walk[0] < n_nodes
-
-                def body(walk):
-                    if anyhit:
-                        node, occluded = walk
-                        best_t = jnp.where(occluded > 0.0, -INF, INF)
-                    else:
-                        node, best_t, best_tri, best_inst = walk
-                    lox = (bmin_ref[node, 0] - ox) * invx
-                    hix = (bmax_ref[node, 0] - ox) * invx
-                    loy = (bmin_ref[node, 1] - oy) * invy
-                    hiy = (bmax_ref[node, 1] - oy) * invy
-                    loz = (bmin_ref[node, 2] - oz) * invz
-                    hiz = (bmax_ref[node, 2] - oz) * invz
-                    tnear = jnp.maximum(
-                        jnp.maximum(
-                            jnp.minimum(lox, hix), jnp.minimum(loy, hiy)
-                        ),
-                        jnp.minimum(loz, hiz),
-                    )
-                    tfar = jnp.minimum(
-                        jnp.minimum(
-                            jnp.maximum(lox, hix), jnp.maximum(loy, hiy)
-                        ),
-                        jnp.maximum(loz, hiz),
-                    )
-                    packet_hit = (
-                        tfar >= jnp.maximum(tnear, 0.0)
-                    ) & (tnear < best_t)
-                    hit_any = jnp.any(packet_hit)
-
-                    count = count_ref[node]
-                    is_leaf = count > 0
-                    start = first_ref[node]
-
-                    def leaf_test():
-                        # The [leaf_size, block] Möller-Trumbore test — the
-                        # walk's dominant vector work. ``is_leaf & hit_any``
-                        # is a SCALAR (the whole block walks the same node),
-                        # so this runs under a real scalar-unit branch:
-                        # internal nodes and culled subtrees skip it
-                        # entirely instead of computing-and-masking (~2x on
-                        # deep walks, where half the visited nodes are
-                        # internal).
-                        v0b = v0_ref[pl.dslice(start, leaf_size), :]
-                        e1b = e1_ref[pl.dslice(start, leaf_size), :]
-                        e2b = e2_ref[pl.dslice(start, leaf_size), :]
-                        v0x, v0y, v0z = v0b[:, 0:1], v0b[:, 1:2], v0b[:, 2:3]
-                        e1x, e1y, e1z = e1b[:, 0:1], e1b[:, 1:2], e1b[:, 2:3]
-                        e2x, e2y, e2z = e2b[:, 0:1], e2b[:, 1:2], e2b[:, 2:3]
-                        pvx = dy * e2z - dz * e2y
-                        pvy = dz * e2x - dx * e2z
-                        pvz = dx * e2y - dy * e2x
-                        det = e1x * pvx + e1y * pvy + e1z * pvz
-                        inv_det = 1.0 / jnp.where(
-                            jnp.abs(det) < BVH_DONE_EPS, BVH_DONE_EPS, det
-                        )
-                        tvx = ox - v0x
-                        tvy = oy - v0y
-                        tvz = oz - v0z
-                        u = (tvx * pvx + tvy * pvy + tvz * pvz) * inv_det
-                        qvx = tvy * e1z - tvz * e1y
-                        qvy = tvz * e1x - tvx * e1z
-                        qvz = tvx * e1y - tvy * e1x
-                        v = (dx * qvx + dy * qvy + dz * qvz) * inv_det
-                        tt = (e2x * qvx + e2y * qvy + e2z * qvz) * inv_det
-                        tri_hit = (
-                            (jnp.abs(det) > BVH_DONE_EPS)
-                            & (u >= 0.0)
-                            & (v >= 0.0)
-                            & (u + v <= 1.0)
-                            & (tt > EPS)
-                            & (lanes < count)
-                        )
-                        if anyhit:
-                            return (
-                                jnp.max(
-                                    jnp.where(tri_hit, 1.0, 0.0),
-                                    axis=0,
-                                    keepdims=True,
-                                ),
-                                jnp.zeros((1, block), jnp.int32),
-                            )
-                        t_cand = jnp.where(tri_hit, tt, INF)
-                        t_leaf = jnp.min(t_cand, axis=0, keepdims=True)
-                        local = jnp.min(
-                            jnp.where(t_cand == t_leaf, lanes, leaf_size),
-                            axis=0,
-                            keepdims=True,
-                        )
-                        return t_leaf, local
-
-                    def leaf_skip():
-                        if anyhit:
-                            return (
-                                jnp.zeros((1, block), jnp.float32),
-                                jnp.zeros((1, block), jnp.int32),
-                            )
-                        return (
-                            jnp.full((1, block), INF, jnp.float32),
-                            jnp.zeros((1, block), jnp.int32),
-                        )
-
-                    leaf_a, leaf_b = jax.lax.cond(
-                        is_leaf & hit_any, leaf_test, leaf_skip
-                    )
-                    next_node = jnp.where(
-                        hit_any,
-                        jnp.where(is_leaf, skip_ref[node], node + 1),
-                        skip_ref[node],
-                    )
-                    if anyhit:
-                        occluded = jnp.maximum(occluded, leaf_a)
-                        return next_node, occluded
-                    t_leaf, local = leaf_a, leaf_b
-                    closer = t_leaf < best_t
-                    best_t = jnp.where(closer, t_leaf, best_t)
-                    best_tri = jnp.where(
-                        closer,
-                        start + jnp.minimum(local, leaf_size - 1),
-                        best_tri,
-                    )
-                    best_inst = jnp.where(closer, k, best_inst)
-                    return next_node, best_t, best_tri, best_inst
-
-                if anyhit:
-                    _, occluded = jax.lax.while_loop(
-                        cond, body, (jnp.int32(0), carry)
-                    )
-                    return occluded
-                _, best_t, best_tri, best_inst = jax.lax.while_loop(
-                    cond, body, (jnp.int32(0), *carry)
-                )
-                return (best_t, best_tri, best_inst)
-
-            return jax.lax.cond(touch, run_walk, lambda: carry)
-
-        if anyhit:
-            occ_ref, = out_refs
-            # Already-occluded rays are folded in by the wrapper (replaced
-            # with guaranteed-miss rays), so the walk starts all-clear
-            # (_bvh_anyhit_instanced).
-            occluded = jax.lax.fori_loop(
-                0, k_count, per_instance, jnp.zeros((1, block), jnp.float32)
-            )
-            occ_ref[:, :] = occluded
-        else:
-            t_ref, tri_ref, inst_out_ref = out_refs
-            init = (
-                tinit_ref[:, :],
-                jnp.zeros((1, block), jnp.int32),
-                jnp.zeros((1, block), jnp.int32),
-            )
-            # Walk the block's candidate instance FIRST: most lanes hit it,
-            # so the sweep below starts with tight per-lane best-t and the
-            # top-level cull rejects most of the remaining instances.
-            cand = cand_ref[0, pl.program_id(0)]
-            init = jax.lax.cond(
-                cand < k_count,
-                lambda: per_instance(cand, init),
-                lambda: init,
-            )
-            best_t, best_tri, best_inst = jax.lax.fori_loop(
-                0,
-                k_count,
-                lambda k, c: jax.lax.cond(
-                    k == cand, lambda: c, lambda: per_instance(k, c)
-                ),
-                init,
-            )
-            t_ref[:, :] = best_t
-            tri_ref[:, :] = best_tri
-            inst_out_ref[:, :] = best_inst
-
-    return kernel
 
 
 def _instance_table(rotation, translation, scale, bounds_min, bounds_max,
@@ -1702,32 +954,15 @@ def mesh_instance_table(mesh):
     )
 
 
-def _instanced_specs(inst_table, v0, e1, e2, bounds_min, bounds_max, n_nodes):
-    whole = lambda i: (0, 0)  # noqa: E731
-    flat = lambda i: (0,)  # noqa: E731
-    return [
-        pl.BlockSpec((3, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-        pl.BlockSpec((3, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM),
-        pl.BlockSpec(inst_table.shape, whole, memory_space=pltpu.SMEM),
-        pl.BlockSpec(v0.shape, whole, memory_space=pltpu.VMEM),
-        pl.BlockSpec(e1.shape, whole, memory_space=pltpu.VMEM),
-        pl.BlockSpec(e2.shape, whole, memory_space=pltpu.VMEM),
-        pl.BlockSpec(bounds_min.shape, whole, memory_space=pltpu.SMEM),
-        pl.BlockSpec(bounds_max.shape, whole, memory_space=pltpu.SMEM),
-        pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-        pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-        pl.BlockSpec((n_nodes,), flat, memory_space=pltpu.SMEM),
-    ]
-
-
 def instance_entry_candidates(origins, directions, lo_w, hi_w):
     """Per-ray broadphase: nearest-entry overlapped instance world AABB.
 
     One fused [R, K] slab-test pass; returns [R] int32 with K (= the
-    instance count) for rays overlapping nothing. Shared by the
-    integrator's coherence sort key and the nearest wrapper's per-block
-    candidates — a single copy so an epsilon change can't desynchronize
-    the sort from the kernel's walk order.
+    instance count) for rays overlapping nothing. The candidate of the
+    bounce-0 coherence sort key (``initial_mesh_sort_keys``), with the
+    semantics of the bounce kernel's epilogue walk — a single copy so an
+    epsilon change can't desynchronize the sort from the kernel's walk
+    order.
     """
     small = jnp.abs(directions) < 1e-12
     inv = 1.0 / jnp.where(
@@ -1746,129 +981,14 @@ def instance_entry_candidates(origins, directions, lo_w, hi_w):
     ).astype(jnp.int32)
 
 
-def _block_candidates(origins, directions, lo_w, hi_w):
-    """Nearest-entry overlapped instance AABB per ray block, from the
-    block's FIRST lane (the integrator sorts rays by candidate, so one
-    lane represents the block). K = no overlap. [1, n_blocks] int32.
-    """
-    rays = origins.shape[0]
-    n_blocks = -(-rays // BVH_BLOCK_R)
-    stride = jnp.arange(n_blocks) * BVH_BLOCK_R
-    first_lane = jnp.minimum(stride, rays - 1)
-    return instance_entry_candidates(
-        origins[first_lane], directions[first_lane], lo_w, hi_w
-    )[None, :]
-
-
-def _bvh_nearest_instanced(
-    origins, directions, t_init, block_candidate, rotation, translation,
-    scale, v0, e1, e2, bounds_min, bounds_max, skip, first, count,
-    *, interpret: bool,
-):
-    from tpu_render_cluster.render.mesh import LEAF_SIZE
-
-    o_t, d_t, rays, padded_rays = _pad_rays_to_miss(origins, directions)
-    t_init_t = jnp.full((1, padded_rays), INF, jnp.float32)
-    t_init_t = t_init_t.at[0, :rays].set(t_init)
-    inst_table = _instance_table(
-        rotation, translation, scale, bounds_min, bounds_max
-    )
-    n_nodes = skip.shape[0]
-    k_count = rotation.shape[0]
-    n_blocks = padded_rays // BVH_BLOCK_R
-    grid = (n_blocks,)
-    out_block = pl.BlockSpec(
-        (1, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM
-    )
-    in_specs = _instanced_specs(
-        inst_table, v0, e1, e2, bounds_min, bounds_max, n_nodes
-    )
-    # Seed-t rides a third ray-indexed block after origins/directions; the
-    # per-block candidate follows as a one-scalar SMEM block.
-    in_specs.insert(
-        2,
-        pl.BlockSpec(
-            (1, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM
-        ),
-    )
-    # The whole per-block candidate vector rides in SMEM as a [1, n] row
-    # (rank-2 sidesteps Pallas TPU's rank-1 block tiling constraint AND
-    # vmap's batching of rank-1 SMEM blocks); the kernel indexes it by
-    # program_id.
-    in_specs.insert(
-        3,
-        pl.BlockSpec(
-            (1, n_blocks), lambda i: (0, 0), memory_space=pltpu.SMEM
-        ),
-    )
-    t, tri, inst = pl.pallas_call(
-        _bvh_instanced_kernel_factory(n_nodes, LEAF_SIZE, k_count, anyhit=False),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[out_block, out_block, out_block],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, padded_rays), jnp.float32),
-            jax.ShapeDtypeStruct((1, padded_rays), jnp.int32),
-            jax.ShapeDtypeStruct((1, padded_rays), jnp.int32),
-        ],
-        interpret=interpret,
-    )(o_t, d_t, t_init_t, block_candidate, inst_table, v0, e1, e2,
-      bounds_min, bounds_max, skip, first, count)
-    return t[0, :rays], tri[0, :rays], inst[0, :rays]
-
-
-def _bvh_anyhit_instanced(
-    origins, directions, already, rotation, translation, scale,
-    v0, e1, e2, bounds_min, bounds_max, skip, first, count,
-    *, interpret: bool,
-):
-    from tpu_render_cluster.render.mesh import LEAF_SIZE
-
-    # Fold the `already` mask into the rays: an already-occluded ray is
-    # replaced by a guaranteed-miss ray (the kernel initializes occluded=0
-    # at k == 0, so a pre-set mask cannot ride the output buffer), and the
-    # mask is OR-ed back on afterwards.
-    masked_origins = jnp.where(already[:, None], 1e7, origins)
-    masked_directions = jnp.where(
-        already[:, None],
-        jnp.array([0.0, 1.0, 0.0], jnp.float32)[None, :],
-        directions,
-    )
-    o_t, d_t, rays, padded_rays = _pad_rays_to_miss(
-        masked_origins, masked_directions
-    )
-    inst_table = _instance_table(
-        rotation, translation, scale, bounds_min, bounds_max
-    )
-    n_nodes = skip.shape[0]
-    k_count = rotation.shape[0]
-    grid = (padded_rays // BVH_BLOCK_R,)
-    occ = pl.pallas_call(
-        _bvh_instanced_kernel_factory(n_nodes, LEAF_SIZE, k_count, anyhit=True),
-        grid=grid,
-        in_specs=_instanced_specs(
-            inst_table, v0, e1, e2, bounds_min, bounds_max, n_nodes
-        ),
-        out_specs=pl.BlockSpec(
-            (1, BVH_BLOCK_R), lambda i: (0, i), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((1, padded_rays), jnp.float32),
-        interpret=interpret,
-    )(o_t, d_t, inst_table, v0, e1, e2, bounds_min, bounds_max, skip, first,
-      count)
-    return (occ[0, :rays] > 0.0) | already
-
-
 # ---------------------------------------------------------------------------
 # Mesh megakernel: the WHOLE bounce loop for mesh scenes in one kernel.
 #
 # The sphere megakernel (_trace_kernel_factory) keeps path state
-# VMEM-resident across bounces; mesh scenes previously fell back to the
-# per-bounce XLA scan with 2 BVH kernel launches + HBM round trips of every
-# [R, 3] state buffer per bounce. This kernel subsumes both: per bounce it
-# runs the sphere/plane nearest hit, an IN-KERNEL instanced threaded-BVH
-# walk (fori over instances, while over nodes — same two-level TLAS/BLAS
-# shape as the standalone instanced kernels), sun NEE with both sphere and
+# VMEM-resident across bounces, and this kernel does the same for mesh
+# scenes: per bounce it runs the sphere/plane nearest hit, an IN-KERNEL
+# instanced threaded-BVH walk (fori over instances, while over nodes, or
+# a TLAS walk over instance groups above it), sun NEE with both sphere and
 # mesh any-hit occlusion, and the counter-based PCG resample. Per-lane
 # mesh normals/albedo are tracked through winner one-hots during the leaf
 # pass (TPU Pallas has no per-lane vector gather); shadow rays toward the
@@ -2503,9 +1623,9 @@ def _mesh_trace_kernel_factory(
             beats are culled, dead lanes never drive a packet, and a mesh
             miss returns t == seed_t (callers compare with a strict <).
             Returns (t [1,BR], world normal [3 x (1,BR)], albedo
-            [3 x (1,BR)]). Same walk as _bvh_instanced_kernel_factory with
-            the winning triangle's normal and the instance albedo tracked
-            in-kernel.
+            [3 x (1,BR)]). The walk of mesh.intersect_instances, all
+            instances in one launch, with the winning triangle's normal
+            and the instance albedo tracked in-kernel.
             """
             wox, woy, woz = o[0:1, :], o[1:2, :], o[2:3, :]
             wdx, wdy, wdz = d[0:1, :], d[1:2, :], d[2:3, :]
@@ -2705,8 +1825,9 @@ def _mesh_trace_kernel_factory(
 
             ``occluded0`` [1, BR] pre-marks lanes whose result cannot
             matter (sphere-shadowed, dead, backfacing): they stop driving
-            the walks via the best_t=-INF trick (same as
-            _bvh_anyhit_kernel_factory) and come back as 1.
+            the walks via the best_t=-INF trick (no box lies nearer
+            than -INF, so such a lane passes no packet test) and come
+            back as 1.
             """
             wox, woy, woz = o[0:1, :], o[1:2, :], o[2:3, :]
             # TRUE rank-0 scalars from SMEM: a [1,1] vector operand here
@@ -3640,9 +2761,9 @@ def mesh_bounce_pallas(
 
     The megakernel's bounce_step as a single launch with path state
     streamed in/out, so integrator.trace_paths can re-sort rays between
-    bounces (packet coherence) without paying per-bounce XLA glue —
-    separate sphere/shadow kernels, threefry RNG, and a dozen elementwise
-    HBM round trips. ``lane`` carries each ray's ORIGINAL lane id — the
+    bounces (packet coherence) and narrow the launch as rays die: the
+    sphere pass, the mesh walk, shading, the shadow test and the resample
+    of one bounce in one kernel. ``lane`` carries each ray's ORIGINAL lane id — the
     RNG counter, so a ray's stream survives the re-sort
     permutations; ``live_count`` is the number of leading live lanes
     (dead lanes must be sorted to the tail), letting all-dead tail
@@ -3693,10 +2814,18 @@ def trace_paths_fused_mesh(
 ):
     """Fused megakernel path trace for mesh scenes; drop-in for
     integrator.trace_paths with a MeshSet. Same physics as the XLA bounce
-    scan + per-pass kernels; different (in-kernel counter PCG) RNG stream.
+    loop; different (in-kernel counter PCG) RNG stream.
     ``use_tlas`` (None = env tier) selects the two-level kernel variant;
     ``quant`` (None = the ``TRC_BVH_QUANT`` tier) the node format.
+    Raises ``ValueError`` for a mesh ``mesh_megakernel_eligible`` refuses.
     """
+    if not mesh_megakernel_eligible(mesh):
+        raise ValueError(
+            "the mesh megakernel takes a resident BLAS of at most "
+            f"{MESH_MEGAKERNEL_MAX_WALK} nodes x instances "
+            "(mesh_megakernel_eligible); a deeper or streamed mesh is "
+            "mesh_bounce_pallas's (integrator.trace_paths picks by it)"
+        )
     bvh = mesh.bvh
     instances = mesh.instances
     return _trace_fused_mesh(
@@ -3716,59 +2845,3 @@ def trace_paths_fused_mesh(
         quant=bvh_quant_mode() if quant is None else int(quant),
     )
 
-
-def intersect_instances_pallas(bvh, instances, origins, directions, init_t=None):
-    """All-instance nearest hit in ONE kernel launch.
-
-    ``init_t`` seeds the per-lane best-t (e.g. the same bounce's
-    sphere/plane hit), culling instance walks that cannot beat it.
-    Returns (t [R], triangle_index [R], instance_index [R]).
-    """
-    if init_t is None:
-        init_t = jnp.full((origins.shape[0],), INF, jnp.float32)
-    # Front-to-back instance order (distance from the mean live ray
-    # origin): near instances set small best_t early, so the per-lane
-    # ``wnear < best_t`` top-level cull rejects most far instances before
-    # their walks start. Pure data reordering — results are order-
-    # invariant — computed per call in XLA (the transforms are traced
-    # values under jit, e.g. physics animation).
-    # Dead lanes arrive as guaranteed-miss rays parked at 1e7 (integrator)
-    # and must not drag the anchor off the scene.
-    valid = (jnp.abs(origins) < 1e6).all(axis=1)
-    anchor = jnp.sum(
-        jnp.where(valid[:, None], origins, 0.0), axis=0
-    ) / jnp.maximum(jnp.sum(valid), 1)
-    near_first = jnp.argsort(
-        jnp.sum((instances.translation - anchor[None, :]) ** 2, axis=1)
-    )
-    rotation = instances.rotation[near_first]
-    translation = instances.translation[near_first]
-    scale = instances.scale[near_first]
-    # Per-block candidate ids index the SAME permuted order the kernel
-    # sweeps (the table here is a [K, 22] recompute — trivial next to the
-    # walk).
-    table = _instance_table(
-        rotation, translation, scale, bvh.bounds_min, bvh.bounds_max
-    )
-    block_candidate = _block_candidates(
-        origins, directions, table[:, 13:16], table[:, 16:19]
-    )
-    t, tri, inst = _bvh_nearest_instanced(
-        origins, directions, init_t, block_candidate,
-        rotation, translation, scale,
-        bvh.v0, bvh.e1, bvh.e2, bvh.bounds_min, bvh.bounds_max,
-        bvh.skip, bvh.first, bvh.count,
-        interpret=_interpret(),
-    )
-    return t, tri, near_first[inst]
-
-
-def occluded_instances_pallas(bvh, instances, origins, directions, already):
-    """All-instance any-hit in ONE kernel launch."""
-    return _bvh_anyhit_instanced(
-        origins, directions, already,
-        instances.rotation, instances.translation, instances.scale,
-        bvh.v0, bvh.e1, bvh.e2, bvh.bounds_min, bvh.bounds_max,
-        bvh.skip, bvh.first, bvh.count,
-        interpret=_interpret(),
-    )

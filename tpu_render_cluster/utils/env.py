@@ -101,9 +101,6 @@ declare("TRC_SPEC_MAX_ACTIVE", "int", 2, "Concurrent speculative twins per job")
 declare("TRC_OBS_PORT", "port", None, "Master /metrics + /healthz + /clusterz port")
 declare("TRC_OBS_WORKER_PORT", "port", None, "Worker /metrics + /healthz port")
 declare("TRC_OBS_ROUTER_PORT", "port", None, "Shard router federated telemetry port")
-declare("TRC_OBS_PROFILING", "flag", 1, "Kernel roofline cost capture on/off")
-declare("TRC_PEAK_FLOPS", "float", None, "Roofline peak FLOP/s override")
-declare("TRC_PEAK_BYTES_PER_SECOND", "float", None, "Roofline peak bytes/s override")
 declare("TRC_SLO_SHORT_WINDOW_SECONDS", "float", 60.0, "SLO burn short window")
 declare("TRC_SLO_LONG_WINDOW_SECONDS", "float", 300.0, "SLO burn long window")
 declare("TRC_SLO_BURN_THRESHOLD", "float", 1.0, "Burn ratio that counts as breaching")

@@ -475,29 +475,6 @@ class TpuRaytraceBackend(RenderBackend):
             "bounce kernel's scratch",
         ).inc(fetches * fetch_bytes)
 
-    @staticmethod
-    def _observe_render_obs(
-        *, execute_seconds: float, kernel: str | None = None
-    ) -> None:
-        """Feed the process-global obs registry (one TPU per process).
-
-        The frame's own times are the phase and step histograms the
-        worker queue feeds; what is left here is what those cannot say:
-        the frames/s gauge bench.py shares, and the roofline pairing.
-        """
-        from tpu_render_cluster.obs import render_fps_gauge
-
-        if execute_seconds <= 0:
-            return
-        render_fps_gauge().set(1.0 / execute_seconds)
-        if kernel is not None:
-            # Roofline pairing: the whole frame is one fenced program
-            # execution (render + readback), keyed identically to the
-            # cost capture inside the renderer factory.
-            from tpu_render_cluster.obs.profiling import get_profiler
-
-            get_profiler().record_execute(kernel, execute_seconds)
-
     def _render_sync(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
     ) -> FrameRenderTime:
@@ -644,17 +621,6 @@ class TpuRaytraceBackend(RenderBackend):
             walk = [np.asarray(counts) for counts in walk]
         finished_rendering_at = time.time()
 
-        # Which roofline kernel this frame's fenced execute time pairs
-        # with: the programs keyed by a factory-side cost capture
-        # (sharded programs are per-device and not cost-captured).
-        kernel = None
-        if tier in ("region", "masked"):
-            from tpu_render_cluster.obs.profiling import kernel_key
-
-            dims = dict(w=width, h=height, s=samples, b=max_bounces)
-            if region is not None:
-                dims.update(th=region[2], tw=region[3])
-            kernel = kernel_key(tier, scene_name, **dims)
         return RenderedFrame(
             save=functools.partial(
                 self._save_stage, job, frame_index, tile, pixels,
@@ -663,7 +629,7 @@ class TpuRaytraceBackend(RenderBackend):
                     started_rendering_at, finished_rendering_at,
                 ),
                 device_steps=steps, tier=tier, scene_name=scene_name,
-                kernel=kernel, launches=launches, walk=walk,
+                launches=launches, walk=walk,
             )
         )
 
@@ -671,7 +637,7 @@ class TpuRaytraceBackend(RenderBackend):
         self, job: BlenderJob, frame_index: int, tile: int | None, pixels, *,
         points: tuple[float, float, float, float],
         device_steps: list[tuple[str, float, float]],
-        tier: str, scene_name: str, kernel: str | None, launches, walk: list,
+        tier: str, scene_name: str, launches, walk: list,
     ) -> FrameRenderTime:
         """The frame's file, from its pixels: on whichever thread the
         caller runs it, with that thread's own steps. What the frame
@@ -720,7 +686,6 @@ class TpuRaytraceBackend(RenderBackend):
             self._observe_launches(launches)
         for counts in walk:
             self._observe_walk(counts, scene_name)
-        self._observe_render_obs(execute_seconds=points[3] - points[2], kernel=kernel)
         self._family_frames.inc(family=scene_name)
         return FrameRenderTime(
             *points,

@@ -301,16 +301,7 @@ def main(argv: list[str] | None = None) -> int:
                 obs_directory / f"{worker_name}_trace-events.json",
                 [worker.span_tracer],
             )
-            # The roofline section (obs/profiling.py): per-kernel XLA
-            # cost analysis paired with this worker's measured execute
-            # times — the per-kernel achieved-vs-peak evidence the
-            # statistics.json fold consumes.
-            from tpu_render_cluster.obs.profiling import get_profiler
-
             extra = {}
-            roofline = get_profiler().view()
-            if roofline:
-                extra["roofline"] = roofline
             # Which device rendered (tpu-raytrace only): platform, kind and
             # index, stamped once when the backend was built.
             if device:
