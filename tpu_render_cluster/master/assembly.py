@@ -114,6 +114,75 @@ def assemble_frame_files(
     return frame_path
 
 
+def cut_writes_counter(metrics):
+    """``master_cut_writes_removed_total``: the scheduler service exposes
+    it at 0 from its start."""
+    return metrics.counter(
+        "master_cut_writes_removed_total",
+        "Temporary files of frame writes that a worker's death cut, removed "
+        "from their job's directory before the job was reported finished",
+    )
+
+
+def remove_cut_writes(
+    job: BlenderJob,
+    frame_indices: list[int],
+    *,
+    base_directory: str | Path | None = None,
+) -> int:
+    """Remove what a write that was cut left of the given frames (sync).
+
+    ``write_image`` writes ``.<file name>.<random>.tmp`` beside the frame
+    and renames it into place; a worker killed in between leaves the
+    temporary file. Only the named frames' (and their tiles') are touched:
+    a live worker's write of another frame is under way in the same
+    directory. Returns how many files went; 0 where this master cannot
+    see the directory."""
+    import os
+
+    from tpu_render_cluster.render.image_io import (
+        output_path_for_frame,
+        output_path_for_tile,
+    )
+
+    try:
+        output_directory = parse_with_base_directory_prefix(
+            job.output_directory_path, base_directory
+        )
+    except ValueError:
+        return 0  # %BASE% with no base directory on this master
+    names = set()
+    for frame_index in frame_indices:
+        names.add(output_path_for_frame(
+            output_directory, job.output_file_name_format, job.output_file_format, frame_index
+        ).name)
+        if job.tile_grid is not None:
+            rows, cols = job.tile_grid
+            names.update(
+                output_path_for_tile(
+                    output_directory, job.output_file_name_format,
+                    job.output_file_format, frame_index, tile, job.tile_grid,
+                ).name
+                for tile in range(rows * cols)
+            )
+    removed = 0
+    try:
+        entries = list(os.scandir(output_directory))
+    except OSError:
+        return 0
+    for entry in entries:
+        name = entry.name
+        if not (name.startswith(".") and name.endswith(".tmp")):
+            continue
+        if name[1:].rsplit(".", 2)[0] in names:
+            try:
+                os.unlink(entry.path)
+                removed += 1
+            except OSError:
+                pass
+    return removed
+
+
 class FrameAssemblyService:
     """Schedules and tracks per-frame assembly on the master's loop.
 
@@ -146,6 +215,34 @@ class FrameAssemblyService:
             name=f"assemble-{state.job.job_name}-{frame_index}",
             key=state.job.job_name,
         )
+
+    def schedule_cut_write_sweep(
+        self, state: ClusterManagerState, frame_indices: list[int]
+    ) -> None:
+        """A worker died holding these frames of the job: what a write it
+        was cut in left beside the frames goes, in the background, and the
+        job is not declared FINISHED before (``has_pending``)."""
+        self._tasks.spawn(
+            self._sweep_cut_writes(state.job, frame_indices),
+            name=f"sweep-{state.job.job_name}",
+            key=state.job.job_name,
+        )
+
+    async def _sweep_cut_writes(self, job: BlenderJob, frame_indices: list[int]) -> None:
+        try:
+            removed = await asyncio.to_thread(
+                remove_cut_writes, job, frame_indices, base_directory=self.base_directory
+            )
+        except Exception as e:  # noqa: BLE001 - account, don't kill the loop
+            logger.error("Sweep of cut writes of %r failed: %s", job.job_name, e)
+            return
+        if self.metrics is not None:
+            cut_writes_counter(self.metrics).inc(removed)
+        if removed:
+            logger.warning(
+                "Removed %d temporary file(s) a dead worker's cut write left in %r.",
+                removed, job.job_name,
+            )
 
     def has_pending(self, job_name: str) -> bool:
         """Stitches of ``job_name`` still in flight — a job must not be

@@ -31,7 +31,7 @@ from tpu_render_cluster.master.speculate import (
     SpeculationService,
     speculation_loop,
 )
-from tpu_render_cluster.master.state import ClusterManagerState, FrameStatus
+from tpu_render_cluster.master.state import ClusterManagerState
 from tpu_render_cluster.master.strategies import run_strategy
 from tpu_render_cluster.master.wakeup import DispatchWakeup
 from tpu_render_cluster.master.worker_handle import WorkerHandle
@@ -419,6 +419,17 @@ class ClusterManager:
     def live_workers(self) -> list[WorkerHandle]:
         return [w for w in self.workers.values() if not w.is_dead]
 
+    def serving_workers(self) -> list[WorkerHandle]:
+        """The live workers whose socket is up: those a pass may hand work
+        to or ask anything of. A silent one (``WorkerHandle.is_silent``)
+        is live, keeps its queue for the reconnect window, and is left be."""
+        return [w for w in self.live_workers() if not w.is_silent]
+
+    def _active_states(self) -> list[ClusterManagerState]:
+        """Every frame table a worker may hold units of (the scheduler
+        subclass: its running jobs')."""
+        return [self.state] if self.state is not None else []
+
     def _jobs_view(self) -> dict:
         """Per-job live view folded into ``cluster_view()['jobs']`` (and
         with it into ``metrics-live.json``). Single-job masters report
@@ -467,6 +478,7 @@ class ClusterManager:
                     pm.worker_id_to_string(w.worker_id): {
                         "queue_depth": len(w.queue),
                         "is_dead": w.is_dead,
+                        "state": w.state_name,
                         "frames_stolen": w.frames_stolen_count,
                     }
                     for w in self.workers.values()
@@ -622,12 +634,12 @@ class ClusterManager:
                 ws.abort()
                 return
             worker.connection.replace_inner_connection(ws)
-            self.metrics.counter(
-                "master_worker_reconnects_total",
-                "Reconnect handshakes accepted from known workers",
-                labels=("worker",),
-            ).inc(worker=pm.worker_id_to_string(response.worker_id))
+            self._reconnects_counter().inc(
+                worker=pm.worker_id_to_string(response.worker_id)
+            )
             worker.logger.info("Worker reconnected from %s", ws.peer_address())
+            if not worker.is_dead:
+                self._on_worker_reconnected(worker)
         else:
             raise WebSocketClosed(
                 f"Unknown handshake type: {response.handshake_type!r}"
@@ -669,6 +681,7 @@ class ClusterManager:
             on_job_ready=self._on_worker_job_ready,
         )
         self.workers[worker_id] = worker
+        self._reconnects_counter().inc(0.0, worker=pm.worker_id_to_string(worker_id))
         worker.start()
         self.dispatch_wakeup.set()
         logger.info(
@@ -692,8 +705,28 @@ class ClusterManager:
     ) -> None:
         """A worker reported a job ready (the scheduler service's hook)."""
 
+    def _reconnects_counter(self):
+        return self.metrics.counter(
+            "master_worker_reconnects_total",
+            "Reconnect handshakes accepted from known workers (0 from a "
+            "worker's first connection)",
+            labels=("worker",),
+        )
+
+    def _on_worker_reconnected(self, worker: WorkerHandle) -> None:
+        """A silent worker came back inside its window, with its id and
+        its queue (the scheduler service's hook)."""
+        self.dispatch_wakeup.set()
+
     async def _evict_worker(self, worker: WorkerHandle, reason: str) -> None:
-        """Return a dead worker's units to the pool so its jobs can finish."""
+        """Return a dead worker's units to the pool so its jobs can finish:
+        every unit whose LIVE assignment is with it, by the frame tables.
+        That is what its mirror holds (queued, rendering, saving: a unit is
+        mirrored until its result is taken) and, beyond the mirror, a unit
+        claimed for it whose queue-add was never acknowledged. Units its
+        mirror holds for another's assignment (a speculative twin, a ghost
+        copy from a superseded dispatch) stay where they are: requeueing
+        those would put a unit in play twice while its primary renders it."""
         logger.warning("Evicting worker %08x: %s", worker.worker_id, reason)
         self.flightrec.trigger(
             TRIGGER_WORKER_EVICTION,
@@ -703,26 +736,25 @@ class ClusterManager:
                 "queued_units": len(worker.queue),
             },
         )
-        for frame in worker.queue.all_frames():
-            state = self._state_for_job(frame.job_name)
-            if state is None:
-                continue  # the owning job is already gone
-            record = state.frames.get(frame.unit)
-            if (
-                record is not None
-                and record.status is not FrameStatus.FINISHED
-                and record.worker_id == worker.worker_id
-            ):
-                # Ownership check: this worker's mirror can hold units
-                # whose LIVE assignment is elsewhere (a speculative twin,
-                # a ghost copy from a superseded dispatch) — requeueing
-                # those would put a unit in play twice while its primary
-                # still renders it.
-                state.return_frame_to_pending(frame.unit, "eviction")
+        for state in self._active_states():
+            held = [
+                unit for unit, holder in state.in_flight_units().items()
+                if holder == worker.worker_id
+            ]
+            for unit in held:
+                state.return_frame_to_pending(unit, "eviction")
+            if held:
+                # A write the worker's death cut leaves its temporary file
+                # beside the frames; the job is not reported finished
+                # before those are gone (the assembly barrier).
+                self.assembly.schedule_cut_write_sweep(
+                    state, sorted({unit.frame_index for unit in held})
+                )
         # No ghost assignments: a dead worker's mirror must not keep
         # offering steal candidates (or claim queue depth) for frames that
         # just went back to the pool.
         worker.queue.clear()
+        self.dispatch_wakeup.set()
 
     # -- job execution ------------------------------------------------------
 

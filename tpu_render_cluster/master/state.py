@@ -62,6 +62,10 @@ class FrameRecord:
     # all) would otherwise requeue-and-error forever; the cap turns the
     # livelock into a job failure (worker_handle -> failed_reason).
     errored_count: int = 0
+    # The worker whose ok result finished the unit (the first one taken;
+    # ``worker_id`` is the live assignment's, which a late result from a
+    # superseded assignment does not move).
+    finished_by: int | None = None
 
     @property
     def frame_index(self) -> int:
@@ -164,6 +168,9 @@ class ClusterManagerState:
         # transitions the in-memory ledger meters.
         self.on_unit_finished = None
         self.on_frame_assembled = None
+        # Called with the cause of every entry of ``handbacks`` (the
+        # scheduler service's ``sched_units_handed_back_total``).
+        self.on_handback = None
         # Per-frame assembly ledger (tiled jobs): frame -> the set of tile
         # indices whose units reached FINISHED. A frame is assembly-ready
         # when the set reaches ``tiles_per_frame`` — each tile lands in it
@@ -320,7 +327,7 @@ class ClusterManagerState:
         if stolen_from is not None:
             record.stolen_from = stolen_from
             record.stolen_at = stolen_at
-            self.handbacks.append((unit, stolen_from, "steal", queued_at))
+            self._note_handback(unit, stolen_from, "steal", queued_at)
         if self._pending and self._pending[0] == unit:
             self._pending.popleft()
         self._retrack(record, old)
@@ -337,10 +344,13 @@ class ClusterManagerState:
         record.worker_id = worker_id
         self._retrack(record, old)
 
-    def mark_frame_as_finished(self, unit: "WorkUnit | int") -> bool:
+    def mark_frame_as_finished(
+        self, unit: "WorkUnit | int", by: int | None = None
+    ) -> bool:
         """Transition a unit to FINISHED; returns True when this call
         completed its whole FRAME (every tile landed) — the exactly-once
         assembly trigger. Idempotent: repeated calls return False.
+        ``by``: the worker whose result this is.
         """
         unit = self._as_unit(unit)
         record = self.frames[unit]
@@ -348,6 +358,7 @@ class ClusterManagerState:
             return False
         old = record.status
         record.status = FrameStatus.FINISHED
+        record.finished_by = by
         self._retrack(record, old)
         self._finished_count += 1
         self.last_finished_at = time.time()
@@ -367,6 +378,13 @@ class ClusterManagerState:
         if self.on_frame_assembled is not None:
             self.on_frame_assembled(frame_index)
 
+    def _note_handback(
+        self, unit: WorkUnit, worker_id: int | None, cause: str, at: float
+    ) -> None:
+        self.handbacks.append((unit, worker_id, cause, at))
+        if self.on_handback is not None:
+            self.on_handback(cause)
+
     def return_frame_to_pending(self, unit: "WorkUnit | int", cause: str) -> None:
         """Unit comes back to the pool (steal succeeded, render errored,
         or its worker died). Unlike the reference — where a dead worker's
@@ -381,7 +399,7 @@ class ClusterManagerState:
         record = self.frames[unit]
         if record.status in (FrameStatus.FINISHED, FrameStatus.PENDING):
             return
-        self.handbacks.append((unit, record.worker_id, cause, time.time()))
+        self._note_handback(unit, record.worker_id, cause, time.time())
         old = record.status
         record.status = FrameStatus.PENDING
         record.worker_id = None

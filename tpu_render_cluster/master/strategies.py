@@ -139,6 +139,23 @@ def claim_pending_unit(
     return unit
 
 
+def claim_is_to_return(
+    worker: "WorkerHandle", state: ClusterManagerState, unit: WorkUnit
+) -> bool:
+    """Whether a claim whose queue-add failed goes back to its pool here.
+    Not where the unit is no longer this claim's (its worker was evicted
+    meanwhile: the eviction returned its claims, and the unit may be
+    another's by now), and not while the worker is silent: a send fails
+    there only as the reconnect window ends, and the eviction that is due
+    returns everything that is with the worker, under its own cause."""
+    record = state.frames.get(unit)
+    return (
+        record is not None
+        and record.worker_id == worker.worker_id
+        and not worker.is_silent
+    )
+
+
 async def send_claimed_unit(
     worker: "WorkerHandle",
     job: BlenderJob,
@@ -155,9 +172,10 @@ async def send_claimed_unit(
         await worker.queue_frame(job, unit, job_id=job_id, trigger=trigger)
     except Exception as e:  # noqa: BLE001 - worker failure mid-RPC
         logger.warning(
-            "Failed to queue unit %s on %08x: %s", unit.label, worker.worker_id, e
+            "Failed to queue unit %s on %08x: %r", unit.label, worker.worker_id, e
         )
-        state.return_frame_to_pending(unit, "dispatch_failed")
+        if claim_is_to_return(worker, state, unit):
+            state.return_frame_to_pending(unit, "dispatch_failed")
         return False
     return True
 

@@ -104,12 +104,37 @@ def rendered_twice_counter(metrics: MetricsRegistry):
     )
 
 
+def silent_seconds_counter(metrics: MetricsRegistry):
+    """``master_worker_silent_seconds_total``: fed when a silence ends (a
+    reconnect or the eviction); the scheduler service exposes it at 0
+    from its start."""
+    return metrics.counter(
+        "master_worker_silent_seconds_total",
+        "Seconds workers spent with their socket lost, from the loss to the "
+        "reconnect or to the eviction at the reconnect window's end",
+    )
+
+
+def evictions_counter(metrics: MetricsRegistry):
+    return metrics.counter(
+        "master_worker_evictions_total", "Workers marked dead and evicted"
+    )
+
+
 class WorkerHandle:
-    """One connected worker, as seen by the master."""
+    """One connected worker, as seen by the master.
+
+    Its states: LIVE (``not is_dead``, socket up), SILENT (``is_silent``:
+    the socket is lost and the reconnect window runs; it keeps its queue
+    and is handed nothing new), DEAD (``is_dead``: evicted, its units are
+    back with their pools) and DRAINED (``is_dead and drained``: it said
+    goodbye and returned its units itself)."""
 
     # Class-level defaults so partially-constructed handles (tests build
     # them attribute-by-attribute) behave like epoch-less production ones.
     epoch: int | None = None
+    connection: ReconnectableServerConnection | None = None
+    ended_at: float | None = None
     _shutdown_started = False
     _on_protocol_event = None
     # The worker's handshake said it answers announced jobs with
@@ -167,6 +192,8 @@ class WorkerHandle:
         self.queue = WorkerQueueMirror()
         self.frames_stolen_count = 0
         self.is_dead = False
+        # Wall time it was declared dead (evicted) or said goodbye.
+        self.ended_at: float | None = None
         # True when is_dead was reached via the graceful goodbye path
         # (counted as a drain, not an eviction).
         self.drained = False
@@ -223,6 +250,7 @@ class WorkerHandle:
         self.router = MessageRouter(self._receive_message)
         self._heartbeat_task: asyncio.Task | None = None
         self._events_task: asyncio.Task | None = None
+        self._silence_task: asyncio.Task | None = None
         self._tasks_started = False
 
     # -- transport adapters -------------------------------------------------
@@ -285,6 +313,9 @@ class WorkerHandle:
         self._heartbeat_task = asyncio.create_task(
             self._maintain_heartbeat(), name=f"heartbeat-{self.worker_id:08x}"
         )
+        self._silence_task = asyncio.create_task(
+            self._watch_silence(), name=f"silence-{self.worker_id:08x}"
+        )
 
     def cancel_heartbeat(self) -> None:
         if self._heartbeat_task is not None:
@@ -296,8 +327,9 @@ class WorkerHandle:
         # must not count an eviction (or requeue frames) on the way out.
         self._shutdown_started = True
         self.cancel_heartbeat()
-        if self._events_task is not None:
-            self._events_task.cancel()
+        for task in (self._events_task, self._silence_task):
+            if task is not None:
+                task.cancel()
         await self.router.stop()
         await self.sender.stop()
         self.connection.close()
@@ -306,6 +338,7 @@ class WorkerHandle:
         if self.is_dead or self._shutdown_started:
             return
         self.is_dead = True
+        self.ended_at = time.time()
         self.logger.warning("Worker marked dead: %s", reason)
         if self._on_protocol_event is not None:
             self._on_protocol_event(
@@ -327,9 +360,7 @@ class WorkerHandle:
                 extra_args={"reason": reason},
             )
         if self.metrics is not None:
-            self.metrics.counter(
-                "master_worker_evictions_total", "Workers marked dead and evicted"
-            ).inc()
+            evictions_counter(self.metrics).inc()
             # Zero (don't leave stale) this worker's depth: its frames are
             # returned to pending and re-queue elsewhere, and a frozen
             # nonzero series would double-count them in the live view.
@@ -340,6 +371,73 @@ class WorkerHandle:
             ).set(0, worker=self._worker_label())
         if self._on_dead is not None:
             await self._on_dead(self, reason)
+
+    @property
+    def is_silent(self) -> bool:
+        """Live with its socket lost: it may reconnect for what is left of
+        the window, keeps its queue meanwhile, and is handed nothing new."""
+        return (
+            not self.is_dead
+            and self.connection is not None
+            and not self.connection.is_connected
+        )
+
+    @property
+    def silent_since(self) -> float | None:
+        """Wall time of the socket's loss while ``is_silent``, else None."""
+        return self.connection.silent_since if self.is_silent else None
+
+    @property
+    def state_name(self) -> str:
+        """``live``, ``silent``, ``dead`` or ``drained``: what ``status``
+        and the cluster view say of this worker."""
+        if self.is_dead:
+            return "drained" if self.drained else "dead"
+        return "silent" if self.is_silent else "live"
+
+    async def _watch_silence(self) -> None:
+        """From the socket's loss to the reconnect, or to the eviction at
+        the reconnect window's end: the one place that declares a worker
+        dead for having stayed away (an operation that runs into the
+        window's end only fails). One ``worker silent`` span a silence on
+        the worker's track of the master's timeline."""
+        while True:
+            await self.connection.wait_silent()
+            if self.is_dead or self._shutdown_started:
+                return
+            since = self.connection.silent_since or time.time()
+            units_held = len(self.queue)
+            # (a worker that leaves with its job is silent too, holding nothing)
+            self.logger.log(
+                logging.WARNING if units_held else logging.INFO,
+                "Worker's socket is lost; %d unit(s) stay with it for %.0f s.",
+                units_held,
+                self.connection.reconnect_window_left(),
+            )
+            back = await self.connection.wait_connected()
+            if self.is_dead or self._shutdown_started:
+                return
+            seconds = max(0.0, time.time() - since)
+            if self.metrics is not None:
+                silent_seconds_counter(self.metrics).inc(seconds)
+            if self.span_tracer is not None:
+                self.span_tracer.complete(
+                    "worker silent",
+                    cat="master",
+                    start_wall=since,
+                    duration=seconds,
+                    track=f"worker-{self._worker_label()}",
+                    args={
+                        "worker": self._worker_label(),
+                        "units_held": units_held,
+                        "ended": "reconnected" if back else "evicted",
+                    },
+                )
+            if not back:
+                await self._mark_dead(
+                    "did not reconnect within the wait window"
+                )
+                return
 
     # -- state routing --------------------------------------------------------
 
@@ -1151,7 +1249,7 @@ class WorkerHandle:
         speculation = state.speculations.get(unit)
         if speculation is not None and speculation.winner_worker_id is None:
             speculation.winner_worker_id = self.worker_id
-        frame_completed = state.mark_frame_as_finished(unit)
+        frame_completed = state.mark_frame_as_finished(unit, by=self.worker_id)
         if (
             frame_completed
             and state.job.tile_grid is not None
@@ -1170,6 +1268,7 @@ class WorkerHandle:
         if self.is_dead:
             return  # eviction won the race; frames are already requeued
         self.is_dead = True
+        self.ended_at = time.time()
         self.drained = True
         self.cancel_heartbeat()
         now = time.time()

@@ -19,6 +19,10 @@ scheduler with ``nc``. Operations:
   failed dispatch), oldest first, with ``since`` those after that wall time:
   what the master can say of a (job, frame) that two workers rendered. A
   job's are dropped when it leaves the list of ended jobs
+- ``{"op": "results", "job_id": "job-0001"}`` -> ``{"ok": true, "job_name": ...,
+  "results": [{"frame": 7, "worker": "0a1b2c3d"}, ...]}`` — whose result
+  finished each finished unit of the job: the master's record of what a
+  worker delivered, for one that died and left none of its own
 - ``{"op": "cancel", "job_id": "job-0001"}`` -> ``{"ok": true, "cancelled": bool}``
 - ``{"op": "drain"}`` -> stop admitting; the service exits when idle
 - ``{"op": "migrate_workers", "count": 2, "host": "...", "port": N}``
@@ -72,6 +76,12 @@ async def handle_request(manager: "JobManager", request: dict[str, Any]) -> dict
             return {"ok": True, "job": view}
         if op == "handbacks":
             return {"ok": True, "handbacks": manager.handbacks_view(request.get("since"))}
+        if op == "results":
+            job_id = str(request.get("job_id"))
+            results = manager.results_view(job_id)
+            if results is None:
+                return {"ok": False, "error": f"unknown job_id: {job_id!r}"}
+            return {"ok": True, **results}
         if op == "cancel":
             job_id = request.get("job_id")
             if job_id is None:

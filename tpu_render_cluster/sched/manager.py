@@ -22,6 +22,13 @@ preempted (its newest not-yet-rendering frame unqueued back to its own
 pending pool, via the same frame-queue-remove RPC steals use) when
 another job is starved by at least a whole slot.
 
+A worker whose socket is lost is SILENT for the reconnect window
+(``WorkerHandle.is_silent``): live, its queue kept, handed nothing new.
+A pass waits on no worker, silent or slow: announcements, queue-adds and
+a preemption's unqueue go out on tasks beside the loop, and a pass picks
+among ``serving_workers()`` (the cancel of a job that failed is the one
+RPC the loop still awaits, of serving workers alone).
+
 Lifecycle API (``submit`` / ``job_status`` / ``cancel_job`` /
 ``request_drain``) is exposed over a JSON-lines control socket
 (sched/control.py) consumed by ``python -m tpu_render_cluster.sched.submit``
@@ -38,19 +45,27 @@ from pathlib import Path
 from typing import Any
 
 from tpu_render_cluster.master.cluster import ClusterManager
-from tpu_render_cluster.master.state import ClusterManagerState, FrameStatus
+from tpu_render_cluster.master.assembly import cut_writes_counter
+from tpu_render_cluster.master.state import (
+    HANDBACK_CAUSES,
+    ClusterManagerState,
+    FrameStatus,
+)
 from tpu_render_cluster.master.strategies import (
+    claim_is_to_return,
     claim_pending_unit,
     preempt_frame,
     send_claimed_unit,
 )
 from tpu_render_cluster.master.worker_handle import (
     WorkerHandle,
+    evictions_counter,
     rendered_twice_counter,
+    silent_seconds_counter,
 )
 from tpu_render_cluster.obs import MetricsRegistry, Tracer
 from tpu_render_cluster.sched import fair_share
-from tpu_render_cluster.sched.tickprof import TickProfiler
+from tpu_render_cluster.sched.tickprof import TickProfiler, observe_dispatch_phase
 from tpu_render_cluster.sched.models import (
     JOB_CANCELLED,
     JOB_FINISHED,
@@ -184,10 +199,13 @@ class JobManager(ClusterManager):
         self._started_serving = time.time()
         # the newest ENDED_JOBS_LISTED ended job_ids, oldest first
         self._ended: list[str] = []
-        # Queue-adds under way (``_send_claims``): the tasks, and per
-        # worker the claimed units its mirror does not hold yet.
+        # What is asked of workers beside the loop (queue-adds, job
+        # announcements, preemptions' unqueues): the tasks, per worker the
+        # claimed units its mirror does not hold yet, and per worker the
+        # over-share job a frame of which it has been asked to give back.
         self._sends = BackgroundTasks()
         self._unacked: dict[int, int] = {}
+        self._preempting: dict[int, str] = {}
         # /metrics carries no process CPU otherwise: one Python loop admits,
         # dispatches, takes every result and answers every ``status``.
         self._process_cpu = self.metrics.counter(
@@ -198,6 +216,33 @@ class JobManager(ClusterManager):
         self._process_cpu_seen = 0.0
         self._note_process_cpu()
         rendered_twice_counter(self.metrics).inc(0.0, cause="none")
+        # What a lost worker moves, at 0 from the service's start: a scrape
+        # tells "none yet" from "no such counter".
+        evictions_counter(self.metrics).inc(0.0)
+        silent_seconds_counter(self.metrics).inc(0.0)
+        cut_writes_counter(self.metrics).inc(0.0)
+        self._handed_back = self.metrics.counter(
+            "sched_units_handed_back_total",
+            "Units that left the worker that held them without a result, by "
+            "cause (master/state.py::HANDBACK_CAUSES)",
+            labels=("cause",),
+        )
+        for cause in HANDBACK_CAUSES:
+            self._handed_back.inc(0.0, cause=cause)
+        self._blocked_on_silent_seconds = self.metrics.counter(
+            "sched_job_blocked_on_silent_worker_seconds_total",
+            "Job-seconds of running jobs with nothing pending and every unit "
+            "in flight with a silent worker: they wait for its reconnect or "
+            "its eviction, and hold no active slot meanwhile",
+        )
+        self._blocked_on_silent_seconds.inc(0.0)
+        self._barrier_unmet = self.metrics.gauge(
+            "sched_admission_barrier_unmet_job_units",
+            "Queued jobs whose wait_for_number_of_workers exceeds the live pool",
+        )
+        self._barrier_unmet.set(0)
+        # job_ids of running jobs blocked on a silent worker, as of this pass
+        self._blocked: set[str] = set()
 
     # -- ClusterManager hooks -------------------------------------------------
 
@@ -206,6 +251,16 @@ class JobManager(ClusterManager):
             return None
         run = self._active_by_name.get(job_name)
         return run.state if run is not None else None
+
+    def _active_states(self) -> list[ClusterManagerState]:
+        return [
+            run.state
+            for run in (self._runs[job_id] for job_id in self._running)
+            if run.state is not None
+        ]
+
+    def _count_handback(self, cause: str) -> None:
+        self._handed_back.inc(cause=cause)
 
     def _job_for_name(self, job_name: str | None):
         """Resolve an ACTIVE job for the cost model (scene key + tile
@@ -247,15 +302,38 @@ class JobManager(ClusterManager):
         for _trace_id, job_id, _job in self._active_job_announcements():
             await self._announce(self._runs[job_id], worker)
 
+    def _announce_beside(self, run: JobRun, workers: list[WorkerHandle]) -> None:
+        """Each worker's announcement on a task of its own, beside the
+        loop: one whose socket is slow to take it, or is lost under it,
+        holds back nobody (each prepares the job and reports it ready on
+        its own). A worker's sender keeps its messages in order, so the
+        announcement still precedes any frame of the job."""
+        for worker in workers:
+            self._sends.spawn(
+                self._announce(run, worker),
+                name=f"announce-{run.job_id}-{worker.worker_id:08x}",
+            )
+
+    def _on_worker_reconnected(self, worker: WorkerHandle) -> None:
+        """Back inside its window with its id and its queue: it is told of
+        the jobs admitted while it was silent."""
+        super()._on_worker_reconnected(worker)
+        for job_id in self._running:
+            run = self._runs[job_id]
+            if run.state is not None and worker.worker_id not in run.announce_sent:
+                self._announce_beside(run, [worker])
+
     async def _announce(self, run: JobRun, worker: WorkerHandle) -> None:
         assert run.state is not None
+        run.announce_sent.add(worker.worker_id)
         if worker.prepares_jobs and not run.announce_closed:
             run.announced_at[worker.worker_id] = time.time()
         try:
             await worker.send_job_started(
                 trace_id=run.state.trace_id, job_id=run.job_id, job=run.spec.job
             )
-        except Exception as e:  # noqa: BLE001 - heartbeat will evict it
+        except Exception as e:  # noqa: BLE001 - its silence will evict it
+            run.announce_sent.discard(worker.worker_id)
             run.announced_at.pop(worker.worker_id, None)
             logger.warning(
                 "job-started announce to %08x failed: %s", worker.worker_id, e
@@ -364,6 +442,27 @@ class JobManager(ClusterManager):
         out.sort(key=lambda entry: entry["at"])
         return out
 
+    def results_view(self, job_id: str) -> dict[str, Any] | None:
+        """Whose result finished each finished unit of a job the service has
+        had: ``{"job_name", "results": [{"frame", "worker"(, "tile")}]}``. A
+        worker that died leaves no record of its own; this is the master's
+        of what it delivered. None for an unknown job."""
+        run = self._runs.get(job_id)
+        if run is None:
+            return None
+        results = []
+        for unit, record in (run.state.frames.items() if run.state is not None else ()):
+            if record.status is not FrameStatus.FINISHED:
+                continue
+            entry: dict[str, Any] = {
+                "frame": unit.frame_index,
+                "worker": None if record.finished_by is None else f"{record.finished_by:08x}",
+            }
+            if unit.tile is not None:
+                entry["tile"] = unit.tile
+            results.append(entry)
+        return {"job_name": run.job_name, "results": results}
+
     # -- lifecycle API --------------------------------------------------------
 
     def submit(self, spec: JobSpec) -> str:
@@ -413,8 +512,26 @@ class JobManager(ClusterManager):
             "running": list(self._running),
             "total_slots": self._total_slots(),
             "rebalance": self.rebalance_view(),
+            "workers": self._workers_view(),
             "jobs": self._jobs_view(),
         }
+
+    def _workers_view(self) -> dict[str, Any]:
+        """Each worker the service has had, by id: ``state`` (``live``,
+        ``silent`` with ``silent_for_s`` and the seconds its reconnect
+        window has left, ``dead`` or ``drained`` with ``ended_at``) and the
+        units its queue holds."""
+        now = time.time()
+        out = {}
+        for worker in self.workers.values():
+            entry: dict[str, Any] = {"state": worker.state_name, "units": len(worker.queue)}
+            if worker.is_dead:
+                entry["ended_at"] = worker.ended_at
+            if worker.is_silent:
+                entry["silent_for_s"] = max(0.0, now - (worker.silent_since or now))
+                entry["window_left_s"] = worker.connection.reconnect_window_left()
+            out[f"{worker.worker_id:08x}"] = entry
+        return out
 
     def rebalance_view(self) -> dict[str, Any]:
         """This shard's load summary, as the router's rebalancer consumes
@@ -526,7 +643,9 @@ class JobManager(ClusterManager):
             self._wfq.remove(job_id)
             self._active_by_name.pop(run.job_name, None)
             self._finish_run(run, JOB_CANCELLED, now)
-            for worker in self.live_workers():
+            # A silent worker is asked nothing: what it holds of the job
+            # resolves to a defunct job when it is back, or goes with it.
+            for worker in self.serving_workers():
                 for frame in worker.queue.frames_for_job(run.job_name):
                     if frame.is_rendering:
                         continue  # its finished event will sweep the mirror
@@ -587,7 +706,9 @@ class JobManager(ClusterManager):
         while not self.cancellation.is_cancelled():
             now = time.time()
             dt, last = now - last, now
+            pass_started = time.perf_counter()
             self._note_process_cpu()
+            self._note_blocked_jobs(dt)
             await self._admit_ready_jobs(now)
             self._finalize_finished_jobs(now)
             # SLO tick inline (the single-job master runs a sidecar task
@@ -668,7 +789,7 @@ class JobManager(ClusterManager):
                     # the job's own pool; the dispatch pass above already
                     # consumed every globally-runnable frame this tick).
                     with self.tickprof.phase("speculation"):
-                        workers = self.live_workers()
+                        workers = self.serving_workers()
                         for job_id in list(self._running):
                             run = self._runs[job_id]
                             if run.state is not None:
@@ -680,6 +801,12 @@ class JobManager(ClusterManager):
                                 )
                 self._finalize_finished_jobs(time.time())
                 self.tickprof.end_tick()
+            # The whole pass, admission and finalization included, whether or
+            # not a job runs: a pass that waited on anything is one
+            # observation of that length.
+            observe_dispatch_phase(
+                self.metrics, "pass", time.perf_counter() - pass_started
+            )
             await self.dispatch_wakeup.wait(self.config.tick_seconds)
 
     def _cancel_unadmittable_queued_jobs(self, now: float) -> None:
@@ -708,25 +835,61 @@ class JobManager(ClusterManager):
             key=lambda job_id: (-self._runs[job_id].spec.priority, job_id),
         )
 
+    def _note_blocked_jobs(self, dt: float) -> None:
+        """Which running jobs wait on a silent worker alone: nothing
+        pending, and every unit in flight with a worker whose socket is
+        lost. Such a job can use no slot of the pool until that worker is
+        back or evicted, so it holds no active slot meanwhile
+        (``_admit_ready_jobs``); its wait is counted here."""
+        silent = {w.worker_id for w in self.live_workers() if w.is_silent}
+        blocked: set[str] = set()
+        if silent:
+            for job_id in self._running:
+                state = self._runs[job_id].state
+                if state is None or state.pending_count():
+                    continue
+                holders = set(state.in_flight_units().values())
+                if holders and holders <= silent:
+                    blocked.add(job_id)
+        for job_id in self._blocked | blocked:
+            self._runs[job_id].waiting_on = (
+                "silent_worker" if job_id in blocked else None
+            )
+        if blocked and dt > 0.0:
+            self._blocked_on_silent_seconds.inc(dt * len(blocked & self._blocked))
+        self._blocked = blocked
+
     async def _admit_ready_jobs(self, now: float) -> None:
+        """Admit queued jobs, highest priority first, while an active slot
+        is free. A job whose worker barrier exceeds the live pool is passed
+        over and SAID to be (``waiting_on`` in its view,
+        ``sched_admission_barrier_unmet_job_units``): a pool that lost a worker
+        must not park such a job in silence."""
         live = len(self.live_workers())
-        progressed = True
-        while progressed:
-            progressed = False
-            for job_id in self._admission_order():
-                if len(self._running) >= self.config.max_active_jobs:
-                    return
-                run = self._runs[job_id]
-                if run.spec.job.wait_for_number_of_workers > live:
-                    continue  # its worker barrier is not met yet
-                await self._admit(run, now)
-                progressed = True
-                break
+        unmet = 0
+        for job_id in self._admission_order():
+            run = self._runs[job_id]
+            if run.status != JOB_QUEUED:
+                continue  # cancelled under an admission's await (the ledger's)
+            if run.spec.job.wait_for_number_of_workers > live:
+                unmet += 1
+                run.waiting_on = (
+                    f"worker_barrier: wants {run.spec.job.wait_for_number_of_workers}, "
+                    f"{live} live"
+                )
+                continue  # its worker barrier is not met
+            if len(self._running) - len(self._blocked) >= self.config.max_active_jobs:
+                run.waiting_on = "active_slot"
+                continue
+            run.waiting_on = None
+            await self._admit(run, now)
+        self._barrier_unmet.set(unmet)
 
     async def _admit(self, run: JobRun, now: float) -> None:
         self._admission.remove(run.job_id)
         run.state = ClusterManagerState(run.spec.job)
         run.state.sched_job_id = run.job_id
+        run.state.on_handback = self._count_handback
         if self.ledger is not None:
             # WAL the admission + restore what a predecessor incarnation
             # already finished of this job (matched by job_name — the wire
@@ -775,12 +938,9 @@ class JobManager(ClusterManager):
                   "wait_s": round(now - run.submitted_at, 6)},
         )
         logger.info("Job %s admitted (%r).", run.job_id, run.job_name)
-        # To every live worker, all sends in flight together: a worker whose
-        # socket is slow to take the announcement does not hold back the
-        # others' (each prepares the job and reports it ready on its own).
-        await asyncio.gather(
-            *(self._announce(run, worker) for worker in self.live_workers())
-        )
+        # To every worker whose socket is up; a silent one is told when it
+        # is back (``_on_worker_reconnected``).
+        self._announce_beside(run, self.serving_workers())
 
     # -- completion / cancellation -------------------------------------------
 
@@ -986,7 +1146,7 @@ class JobManager(ClusterManager):
         assert run.state is not None
         if any(
             worker.is_ready_for(run.job_name, run.job_id)
-            for worker in self.live_workers()
+            for worker in self.serving_workers()
         ):
             return run.state.pending_count()
         return 0
@@ -1218,7 +1378,7 @@ class JobManager(ClusterManager):
         def depth(worker: WorkerHandle) -> int:
             return len(worker.queue) + self._unacked.get(worker.worker_id, 0)
 
-        workers = sorted(self.live_workers(), key=depth)
+        workers = sorted(self.serving_workers(), key=depth)
         # The kind of this pass goes with its claims: by the time a
         # queue-add is acknowledged the loop may be in its next pass.
         trigger = self.dispatch_wakeup.trigger
@@ -1292,7 +1452,8 @@ class JobManager(ClusterManager):
             assert run.state is not None
             try:
                 if failed:
-                    run.state.return_frame_to_pending(unit, "dispatch_failed")
+                    if claim_is_to_return(worker, run.state, unit):
+                        run.state.return_frame_to_pending(unit, "dispatch_failed")
                     continue
                 failed = not await send_claimed_unit(
                     worker, run.spec.job, run.state, unit, job_id=run.job_id,
@@ -1306,6 +1467,10 @@ class JobManager(ClusterManager):
                     self._unacked.pop(worker.worker_id, None)
 
     async def _preempt_tick(self) -> None:
+        """Pick this pass's preemptions; each one's unqueue RPC goes out on
+        a task beside the loop (``_preempt``), like the queue-adds: a
+        victim slow to answer, or dead and not yet known to be, holds back
+        its own frame and nobody's pass."""
         # 0 legitimately disables per-tick preemption without touching
         # TRC_SCHED_PREEMPTION.
         for _ in range(max(0, self.config.max_preemptions_per_tick)):
@@ -1323,33 +1488,46 @@ class JobManager(ClusterManager):
             if decision is None:
                 return
             over_id, starved_id = decision
+            if over_id in self._preempting.values():
+                return  # one of its frames is on its way back: look again after the answer
             run = self._runs[over_id]
             assert run.state is not None
             found = self._find_preemptible_frame(run.job_name, self._runs[starved_id])
             if found is None:
                 return  # everything the job holds is already rendering
             victim, frame = found
-            if not await preempt_frame(
-                run.spec.job, run.state, victim, frame.unit
-            ):
-                return
-            run.preemptions += 1
-            self.metrics.counter(
-                "sched_preemptions_total",
-                "Frames unqueued from over-share jobs back to their pool",
-                labels=("job",),
-            ).inc(job=over_id)
-            self.span_tracer.instant(
-                "preempt",
-                cat="sched",
-                track=f"job {over_id}",
-                args={
-                    "job_id": over_id,
-                    "for_job": starved_id,
-                    "frame": frame.frame_index,
-                    "worker": f"{victim.worker_id:08x}",
-                },
+            self._preempting[victim.worker_id] = over_id
+            self._sends.spawn(
+                self._preempt(run, starved_id, victim, frame),
+                name=f"preempt-{victim.worker_id:08x}",
             )
+
+    async def _preempt(
+        self, run: JobRun, starved_id: str, victim: WorkerHandle, frame: Any
+    ) -> None:
+        assert run.state is not None
+        try:
+            if not await preempt_frame(run.spec.job, run.state, victim, frame.unit):
+                return
+        finally:
+            self._preempting.pop(victim.worker_id, None)
+        run.preemptions += 1
+        self.metrics.counter(
+            "sched_preemptions_total",
+            "Frames unqueued from over-share jobs back to their pool",
+            labels=("job",),
+        ).inc(job=run.job_id)
+        self.span_tracer.instant(
+            "preempt",
+            cat="sched",
+            track=f"job {run.job_id}",
+            args={
+                "job_id": run.job_id,
+                "for_job": starved_id,
+                "frame": frame.frame_index,
+                "worker": f"{victim.worker_id:08x}",
+            },
+        )
 
     def _find_preemptible_frame(
         self, job_name: str, starved: JobRun
@@ -1359,7 +1537,9 @@ class JobManager(ClusterManager):
         the frame least likely to be picked up mid-RPC), on a worker that
         could take a frame of the ``starved`` job in its place."""
         best: tuple[WorkerHandle, Any] | None = None
-        for worker in self.live_workers():
+        for worker in self.serving_workers():
+            if worker.worker_id in self._preempting:
+                continue  # asked already, and has not answered
             if not worker.is_ready_for(starved.job_name, starved.job_id):
                 continue
             for frame in worker.queue.frames_for_job(job_name):
@@ -1368,3 +1548,4 @@ class JobManager(ClusterManager):
                 if best is None or frame.queued_at > best[1].queued_at:
                     best = (worker, frame)
         return best
+

@@ -96,6 +96,13 @@ class JobRun:
     announced_at: dict[int, float] = field(default_factory=dict)
     ready_at: dict[int, float] = field(default_factory=dict)
     announce_closed: bool = False
+    # The workers the job's announcement was handed to the sender of (a
+    # worker silent at the admission is told at its reconnect).
+    announce_sent: set[int] = field(default_factory=set)
+    # Why the job waits, while it does: ``worker_barrier: wants N, M live``
+    # or ``active_slot`` (queued), ``silent_worker`` (running, nothing
+    # pending and every unit in flight with a silent worker); else None.
+    waiting_on: str | None = None
     # An ended job's view as it ended: it never changes again, and a
     # long-lived service answers ``status`` from it.
     final_view: dict[str, Any] | None = None
@@ -151,6 +158,7 @@ class JobRun:
             "admission_wait_seconds": self.admission_wait_seconds(),
             "makespan_seconds": self.makespan_seconds(),
             "preemptions": self.preemptions,
+            "waiting_on": self.waiting_on,
             "share": {
                 "target": self.target_share(),
                 "achieved": self.achieved_share(),

@@ -14,6 +14,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import random
+import time
 from typing import TYPE_CHECKING, Awaitable, Callable
 
 from tpu_render_cluster.transport.ws import (
@@ -197,8 +198,6 @@ class ReconnectingClient:
         used to) would shorten every recorded outage window by however long
         the op queued behind its siblings.
         """
-        import time
-
         async with self._reconnect_lock:
             if self._generation != failed_generation:
                 return  # another task already reconnected
@@ -214,8 +213,6 @@ class ReconnectingClient:
             logger.info("Reconnected to master (generation %d).", self._generation)
 
     async def _with_retries(self, op: Callable[[WebSocketConnection], Awaitable]):
-        import time
-
         loop = asyncio.get_running_loop()
         deadline = loop.time() + op_deadline_seconds()
         reconnect_budget = max_reconnects_per_op()
@@ -273,6 +270,13 @@ class ReconnectableServerConnection:
     Send/receive operations block while the status is Disconnected and
     resume when the accept loop swaps a fresh socket in via
     ``replace_inner_connection`` (reference: master/src/cluster/mod.rs:61-231).
+
+    The wait is ONE window a disconnection, counted from the socket's loss
+    (``silent_since``): every operation that finds the connection down
+    waits for what is left of it, so a send that begins 9 s into the
+    silence gives up with the others at ``MAX_WAIT_FOR_RECONNECT`` and
+    not 9 s after them. ``wait_silent`` / ``wait_connected`` let the
+    owner watch the edges without an operation of its own in flight.
     """
 
     MAX_WAIT_FOR_RECONNECT = 30.0
@@ -286,9 +290,13 @@ class ReconnectableServerConnection:
         self._connection = connection
         self._connected = asyncio.Event()
         self._connected.set()
+        self._silent = asyncio.Event()
         self._closed = False
         self._metrics = metrics
         self.last_known_address = connection.peer_address()
+        # Wall time of the socket's loss while disconnected, else None.
+        self.silent_since: float | None = None
+        self._silent_since_loop = 0.0
 
     @property
     def is_connected(self) -> bool:
@@ -306,26 +314,49 @@ class ReconnectableServerConnection:
         self.last_known_address = connection.peer_address()
         if self._metrics is not None:
             self._metrics.reconnected()
+        self.silent_since = None
+        self._silent.clear()
         self._connected.set()
 
     def _mark_disconnected(self) -> None:
-        if not self._closed:
+        if not self._closed and self._connected.is_set():
+            self.silent_since = time.time()
+            self._silent_since_loop = asyncio.get_running_loop().time()
             self._connected.clear()
+            self._silent.set()
+
+    def reconnect_window_left(self) -> float:
+        """Seconds of the reconnect window that remain (0 when it is over)."""
+        if self._connected.is_set():
+            return self.MAX_WAIT_FOR_RECONNECT
+        elapsed = asyncio.get_running_loop().time() - self._silent_since_loop
+        return max(0.0, self.MAX_WAIT_FOR_RECONNECT - elapsed)
+
+    async def wait_silent(self) -> None:
+        """Until the socket is lost (returns at once while it is)."""
+        await self._silent.wait()
+
+    async def wait_connected(self) -> bool:
+        """Until a socket is swapped in, for what is left of the window;
+        False when the window ended (or the connection was closed) first."""
+        if not self._connected.is_set():
+            try:
+                await asyncio.wait_for(
+                    self._connected.wait(), self.reconnect_window_left()
+                )
+            except asyncio.TimeoutError:
+                return False
+        return not self._closed
 
     async def _await_connection(self) -> WebSocketConnection:
         if self._closed:
             raise WebSocketClosed("Connection is closed.")
-        if not self._connected.is_set():
-            try:
-                await asyncio.wait_for(
-                    self._connected.wait(), self.MAX_WAIT_FOR_RECONNECT
-                )
-            except asyncio.TimeoutError:
-                raise WebSocketClosed(
-                    "Worker did not reconnect within the wait window."
-                ) from None
-            if self._closed:
-                raise WebSocketClosed("Connection is closed.")
+        if not await self.wait_connected():
+            raise WebSocketClosed(
+                "Connection is closed."
+                if self._closed
+                else "Worker did not reconnect within the wait window."
+            )
         return self._connection
 
     async def send_text(self, text: str) -> None:
