@@ -23,6 +23,20 @@ class RenderedFrame:
     save: Callable[[], FrameRenderTime]
 
 
+@dataclass(frozen=True)
+class IssuedFrame:
+    """A frame whose device work has been issued and not waited for.
+
+    ``collect`` is the rest of the frame's device stage (the wait for the
+    device, the copy to the host): a plain blocking call for the thread
+    the worker's queue gives it, which returns the frame's
+    ``RenderedFrame``. The device runs what it was handed in the order it
+    was handed it, so the queue collects in the order it issued.
+    """
+
+    collect: Callable[[], RenderedFrame]
+
+
 class RenderBackend(abc.ABC):
     """Renders the frames of a job and reports 7-phase timing.
 
@@ -30,10 +44,16 @@ class RenderBackend(abc.ABC):
     the host; the **save stage** ends with the file renamed into place.
     The worker's queue asks for the device stage (``render_device_stage``)
     and, where the backend hands back a ``RenderedFrame``, runs that
-    frame's save stage while the next frame is in its device stage: up to
-    two frames of one backend are in hand at a time, never two in the
-    same stage. A backend with no separable save stage implements
+    frame's save stage while the next frame is in its device stage: never
+    two frames saving. A backend with no separable save stage implements
     ``render_frame`` alone and is asked for one whole frame at a time.
+
+    A backend that can also part the device stage into **issue** (hand
+    the device its work, return at once) and **collect** (wait, copy back)
+    defines ``issue_device_stage``; the queue then keeps up to two frames
+    issued and not yet collected, so that the device starts frame *i+1*
+    the moment frame *i* ends. One that leaves it ``None`` is never asked
+    for a frame before the one in its device stage has returned.
 
     Implementations must write the output file to the job's resolved output
     directory and return a ``FrameRenderTime`` whose phases satisfy the
@@ -79,3 +99,12 @@ class RenderBackend(abc.ABC):
         need not call it."""
         dispatched()
         return await self.render_frame(job, frame_index, tile=tile)
+
+    # ``issue_device_stage(job, frame_index, tile=None) -> IssuedFrame``:
+    # the frame's device work handed to the device, nothing waited for. A
+    # plain blocking call for the queue's issue thread (it may build what
+    # the job needs), one frame at a time and in the queue's order; what
+    # it raises is the frame's error. None: this backend cannot part the
+    # two (a subprocess a frame, a sleep), and ``render_device_stage`` is
+    # all it is asked for.
+    issue_device_stage: Callable[..., IssuedFrame] | None = None

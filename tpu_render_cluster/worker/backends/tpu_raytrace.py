@@ -12,12 +12,16 @@ backends apart (BASELINE.md north star). Phase mapping:
 
 A frame is two stages (``RenderBackend``): the **device stage**
 (``resolve``, ``dispatch``, ``device_wait``, ``readback``: the u8 pixels
-are on the host) and the **save stage** (``encode``, ``file_write``). Each
-is a blocking call on a thread the event loop does not run on, so
-heartbeats and queue RPCs stay responsive; the worker's queue runs the
-save stage of frame *i* beside the device stage of frame *i+1*, so the
-seven points of two consecutive frames overlap: frame *i*'s saving lies
-inside frame *i+1*'s rendering.
+are on the host) and the **save stage** (``encode``, ``file_write``), and
+the device stage parts into **issue** (``resolve``, ``dispatch``: nothing
+is waited for) and **collect** (``device_wait``, ``readback``). Each is a
+blocking call on a thread the event loop does not run on, so heartbeats
+and queue RPCs stay responsive; the worker's queue issues frame *i+2*
+while frame *i+1* is waited for and frame *i* is saved, so the device
+finds the next frame's program in its queue when one ends, and the seven
+points of consecutive frames overlap: frame *i*'s saving and the end of
+its rendering lie inside frame *i+1*'s rendering. No buffer is donated:
+frames issued back to back write the files of frames rendered one by one.
 
 A frame's program is picked per JOB: ``(scene family, width, height,
 samples, max_bounces)``, the shape from the job's ``[render]`` table over
@@ -37,12 +41,15 @@ import functools
 import threading
 import time
 from pathlib import Path
-from typing import Callable
 
 from tpu_render_cluster.jobs.models import BlenderJob
 from tpu_render_cluster.traces.worker_trace import FrameRenderTime
 from tpu_render_cluster.utils.paths import parse_with_base_directory_prefix
-from tpu_render_cluster.worker.backends.base import RenderBackend, RenderedFrame
+from tpu_render_cluster.worker.backends.base import (
+    IssuedFrame,
+    RenderBackend,
+    RenderedFrame,
+)
 
 
 # Linear bucket bounds for render_launch_occupancy: fractions live in
@@ -347,18 +354,6 @@ class TpuRaytraceBackend(RenderBackend):
                 space=space,
             )
 
-    async def render_device_stage(
-        self,
-        job: BlenderJob,
-        frame_index: int,
-        tile: int | None = None,
-        *,
-        dispatched: Callable[[], None],
-    ) -> RenderedFrame:
-        return await asyncio.to_thread(
-            self._device_stage, job, frame_index, tile, dispatched
-        )
-
     async def render_frame(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
     ) -> FrameRenderTime:
@@ -478,17 +473,18 @@ class TpuRaytraceBackend(RenderBackend):
     def _render_sync(
         self, job: BlenderJob, frame_index: int, tile: int | None = None
     ) -> FrameRenderTime:
-        """Both stages back to back on the calling thread: a whole frame,
-        for a caller with no next frame to render beside the save."""
-        return self._device_stage(job, frame_index, tile, lambda: None).save()
+        """Issue, collect and save back to back on the calling thread: a
+        whole frame, for a caller with no other frame to run beside it."""
+        return self.issue_device_stage(job, frame_index, tile).collect().save()
 
-    def _device_stage(
-        self, job: BlenderJob, frame_index: int, tile: int | None,
-        dispatched: Callable[[], None],
-    ) -> RenderedFrame:
-        """A frame up to its pixels on the host, on the render thread; its
-        exclusive steps (obs.step) ride on to the save stage, which adds
-        its own and hands all of them over beside the seven points."""
+    def issue_device_stage(
+        self, job: BlenderJob, frame_index: int, tile: int | None = None
+    ) -> IssuedFrame:
+        """The frame's ``resolve`` and ``dispatch``: its program fetched
+        and called, the copies asked for, nothing waited for. Its
+        exclusive steps (obs.step) ride on to ``collect`` and the save
+        stage, each of which adds its own thread's; all of them are handed
+        over beside the seven points."""
         from tpu_render_cluster.obs import frame_steps, step
 
         key = self.program_key(job)
@@ -506,24 +502,22 @@ class TpuRaytraceBackend(RenderBackend):
                     with step("resolve"):
                         done.wait()
             try:
-                rendered = self._render_pixels(
-                    job, frame_index, tile, steps, key, dispatched
-                )
+                issued = self._issue_pixels(job, frame_index, tile, steps, key)
             except BaseException:
                 if mine:
                     self._settle(key, done, built=False)
                 raise
         if mine:
+            # the executable exists and its first execute is in the
+            # device's queue: whatever is issued next runs behind it
             self._settle(key, done, built=True)
-        return rendered
+        return issued
 
-    def _render_pixels(
+    def _issue_pixels(
         self, job: BlenderJob, frame_index: int, tile: int | None,
         steps: list[tuple[str, float, float]], key: tuple,
-        dispatched: Callable[[], None],
-    ) -> RenderedFrame:
+    ) -> IssuedFrame:
         import jax.numpy as jnp
-        import numpy as np
 
         from tpu_render_cluster.obs import step
         from tpu_render_cluster.render.integrator import (
@@ -606,30 +600,49 @@ class TpuRaytraceBackend(RenderBackend):
             # a copy first asked for after the wait below would cost the
             # frame a second host round trip.
             display.copy_to_host_async()
-        # The device has this frame's work: the frame before may encode
-        # and write while this thread is blocked below, the GIL released.
-        dispatched()
-        # One device sync per frame, then (what is left of) the copy.
-        # Readback counts as rendering, like Blender's in-process
-        # compositing; "saving" is encode + disk only.
-        with step("device_wait"):
-            display.block_until_ready()
-        with step("readback"):
-            pixels = np.asarray(display)
-            if launches is not None:
-                launches = np.asarray(launches)
-            walk = [np.asarray(counts) for counts in walk]
+        # The device has this frame's work: nothing here waits for it, so
+        # the next frame can be issued behind it and the frame before may
+        # encode and write.
+        return IssuedFrame(
+            collect=functools.partial(
+                self._collect_pixels, job, frame_index, tile, display, launches, walk,
+                points=(started_process_at, finished_loading_at, started_rendering_at),
+                issue_steps=steps, tier=tier, scene_name=scene_name,
+            )
+        )
+
+    def _collect_pixels(
+        self, job: BlenderJob, frame_index: int, tile: int | None,
+        display, launches, walk: list, *,
+        points: tuple[float, float, float],
+        issue_steps: list[tuple[str, float, float]],
+        tier: str, scene_name: str,
+    ) -> RenderedFrame:
+        """The rest of an issued frame's device stage, on whichever thread
+        the caller runs it: one device sync, then (what is left of) the
+        copy. Readback counts as rendering, like Blender's in-process
+        compositing; "saving" is encode + disk only. A frame issued behind
+        another waits here for both."""
+        import numpy as np
+
+        from tpu_render_cluster.obs import frame_steps, step
+
+        with frame_steps() as collect_steps:
+            with step("device_wait"):
+                display.block_until_ready()
+            with step("readback"):
+                pixels = np.asarray(display)
+                if launches is not None:
+                    launches = np.asarray(launches)
+                walk = [np.asarray(counts) for counts in walk]
         finished_rendering_at = time.time()
 
         return RenderedFrame(
             save=functools.partial(
                 self._save_stage, job, frame_index, tile, pixels,
-                points=(
-                    started_process_at, finished_loading_at,
-                    started_rendering_at, finished_rendering_at,
-                ),
-                device_steps=steps, tier=tier, scene_name=scene_name,
-                launches=launches, walk=walk,
+                points=(*points, finished_rendering_at),
+                device_steps=[*issue_steps, *collect_steps], tier=tier,
+                scene_name=scene_name, launches=launches, walk=walk,
             )
         )
 

@@ -10,17 +10,25 @@ Three deliberate deviations:
 - a render failure emits ``event_frame-queue_item-finished`` with
   ``errored`` instead of silently dropping the frame (which would hang the
   reference master forever — worker/src/rendering/queue.rs:169-174);
-- **a frame is two stages, and two frames are in hand at a time**: the
-  device stage (to the pixels on the host) and the save stage (encode,
-  write, rename; ``worker/backends/base.py``). The loop starts the next
-  queued frame's device stage as soon as the one in hand has returned, and
-  that frame's save stage runs beside it on a thread of its own: at most
-  one frame in each stage, so up to two units are ``RENDERING``. A
+- **a frame is two stages, and up to three frames are in hand at a
+  time**: the device stage (to the pixels on the host) and the save stage
+  (encode, write, rename; ``worker/backends/base.py``). A frame's save
+  stage runs on a thread of its own beside the device stages of the
+  frames behind it, at most one frame saving. Where the backend parts the
+  device stage into issue and collect, up to ``DEVICE_FRAMES`` frames are
+  in it at once: a queued frame's device work is issued (on the issue
+  thread) as soon as fewer than two frames are issued and not yet handed
+  to their save, whether or not a wait for an earlier frame is under way
+  (on the collect thread), so the device finds frame *i+1* in its queue
+  the moment frame *i* ends. Frames are collected, saved and reported in
+  the order they were issued, so up to three units are ``RENDERING``. A
   finished event still leaves only after its frame's file has been
   renamed into place, and finished events leave in the order the frames
-  were rendered; the ``rendering`` event of frame *i+1* may precede the
-  finished event of frame *i*. A backend with no separable save stage
-  goes through the same loop one whole frame at a time.
+  were rendered; the ``rendering`` events of frames *i+1* and *i+2* may
+  precede the finished event of frame *i*. A backend that cannot part
+  issue from collect has one frame in its device stage at a time, and one
+  with no separable save stage goes through the same loop one whole frame
+  at a time.
 """
 
 from __future__ import annotations
@@ -30,6 +38,7 @@ import enum
 import logging
 import threading
 import time
+from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
@@ -53,8 +62,13 @@ QUEUE_POLL_SECONDS = 0.1  # reference: worker/src/rendering/queue.rs:74-96
 # post-hoc from trace gaps — here measured directly.
 FRAME_PHASES = ("queue_wait", "read", "render", "write")
 
+# Frames a backend that parts issue from collect may have in their device
+# stage at once: one running on the device and one in its queue behind
+# it. A constant of the loop, as "one frame saving" is.
+DEVICE_FRAMES = 2
+
 # The render loop's wall time, partitioned (worker_loop_seconds_total). The
-# loop is one coroutine with up to two frames in hand, and what it is
+# loop is one coroutine with up to three frames in hand, and what it is
 # charged is what IT waits for or does, not what a frame costs:
 #   no_work      nothing queued and no stage in hand; waiting for the
 #                master (draining excluded)
@@ -67,14 +81,18 @@ FRAME_PHASES = ("queue_wait", "read", "render", "write")
 #                bookkeeping, feeding the phase and step series
 #   save_wait    the pipeline is full: a frame's device stage has returned
 #                and the frame before it is still in its save stage, so
-#                neither that frame's save nor the next queued frame's
-#                device stage can start (save slower than render)
+#                that frame's save cannot start, nor a queued frame take
+#                its place in the device stage (save slower than render)
 LOOP_STATES = ("no_work", "render_call", "report", "save_wait")
 
 # The steps of the save stage (of obs.FRAME_STEPS): they and the ``write``
 # phase go on timeline tracks of their own, because frame i's lie under
-# frame i+1's device steps and a track's spans must not overlap.
+# frame i+1's device steps and a track's spans must not overlap. For the
+# same reason the device stage's phases and steps have two tracks each:
+# a frame issued while the frame before it was uncollected takes the
+# track that frame does not lie on.
 SAVE_STEPS = ("encode", "file_write")
+DEVICE_TRACKS = (("frames", "steps"), ("frames, second on device", "steps, second on device"))
 
 
 class FrameState(enum.Enum):
@@ -105,10 +123,28 @@ class QueuedFrame:
     epoch: int | None = None
     # Worker-local session generation at queue time (see reset_session).
     session: int = 0
+    # What the loop saw of the frame's way through the stages: its device
+    # work was issued while an earlier frame's had not been collected;
+    # which of DEVICE_TRACKS its device stage is drawn on; a later frame's
+    # device stage was open while its save ran.
+    issued_ahead: bool = False
+    device_track: int = 0
+    saved_beside_render: bool = False
 
     @property
     def unit(self) -> WorkUnit:
         return WorkUnit(self.frame_index, self.tile)
+
+
+@dataclass
+class _DeviceFrame:
+    """A frame in its device stage: issued, or being issued, and not yet
+    handed to its save."""
+
+    frame: QueuedFrame
+    # the stage's RenderedFrame (or the whole frame's FrameRenderTime from
+    # a backend that cannot part the two), or what the stage raised
+    future: asyncio.Future
 
 
 @dataclass
@@ -119,8 +155,6 @@ class _SavingFrame:
     future: asyncio.Future  # the frame's FrameRenderTime, or what the save raised
     # what the save waits behind: the next frame's dispatch, or nothing
     gate: threading.Event
-    # a later frame's device stage was open while this save ran
-    beside_render: bool = False
 
 
 def _outcome(future: asyncio.Future) -> object:
@@ -132,8 +166,9 @@ def _outcome(future: asyncio.Future) -> object:
 
 
 class WorkerAutomaticQueue:
-    """Two-stage render queue: one frame in its device stage, the frame
-    before it in its save stage; woken by events, polled every 100 ms."""
+    """Two-stage render queue: up to two frames in their device stage (one
+    where the backend cannot issue ahead), the frame before them in its
+    save stage; woken by events, polled every 100 ms."""
 
     def __init__(
         self,
@@ -190,17 +225,38 @@ class WorkerAutomaticQueue:
             if metrics is not None
             else None
         )
+        self._issued_ahead = (
+            metrics.counter(
+                "worker_frames_issued_ahead_total",
+                "Frames whose device work was issued while an earlier "
+                "frame's had not been collected",
+            )
+            if metrics is not None
+            else None
+        )
         if metrics is not None:
             # Exposed at 0 from the start: a scrape that finds no series
             # could not tell "never happened" from "not counted".
             self._saved_beside_render.inc(0.0)
+            self._issued_ahead.inc(0.0)
             for state in LOOP_STATES:
                 self._loop_seconds.inc(0.0, state=state)
         # The save stage's thread: one, so that at most one frame is
         # saving; idle until the backend hands back a RenderedFrame.
         self._saver = ThreadPoolExecutor(max_workers=1, thread_name_prefix="frame-save")
-        # The frame in its device stage and the frame in its save stage.
-        self._device_stage: tuple[QueuedFrame, asyncio.Task] | None = None
+        # One thread that issues and one that collects, for a backend that
+        # parts the two: each takes its frames in the queue's order, and a
+        # frame is issued while the wait for the one before it blocks the
+        # other thread (the GIL released). Never started for a backend
+        # that cannot.
+        self._issuer = ThreadPoolExecutor(max_workers=1, thread_name_prefix="frame-issue")
+        self._collector = ThreadPoolExecutor(max_workers=1, thread_name_prefix="frame-collect")
+        # (getattr: one that is no RenderBackend cannot part the two either)
+        self._issue = getattr(backend, "issue_device_stage", None)
+        self._device_frames = 1 if self._issue is None else DEVICE_FRAMES
+        # The frames in their device stage, in the order they were issued,
+        # and the frame in its save stage.
+        self._on_device: deque[_DeviceFrame] = deque()
         self._saving: _SavingFrame | None = None
         self._loop_state: str | None = None
         self._loop_state_since = time.perf_counter()
@@ -278,7 +334,7 @@ class WorkerAutomaticQueue:
     async def drain(self) -> list[tuple[str, int]]:
         """Graceful drain: finish the frames in hand, hand back the rest.
 
-        Stops the loop from starting new frames, waits for the one in its
+        Stops the loop from starting new frames, waits for the ones in their
         device stage and the one in its save stage to complete (their
         finished events go out normally), and returns the ``(job_name, frame_index)`` pairs that
         never started — the payload of the goodbye message the runtime
@@ -305,7 +361,7 @@ class WorkerAutomaticQueue:
         not-started frames belong to assignments the new master does not
         know about, so replaying them would render work nobody tracks.
         A frame currently RENDERING (in its device stage or in its save
-        stage: there may be one of each) is left to finish — its finished
+        stage: there may be two and one) is left to finish — its finished
         event carries the OLD epoch and the new master refuses it as
         stale, which is the fence working as designed. The already-
         finished index is cleared too: the new master may legitimately
@@ -336,8 +392,10 @@ class WorkerAutomaticQueue:
                 await self._task
             except asyncio.CancelledError:
                 pass
-        # a save under way ends on its own, as a render thread's always did
-        self._saver.shutdown(wait=False)
+        # a stage under way ends on its own, as a render thread's always
+        # did; one that has not begun never does
+        for threads in (self._issuer, self._collector, self._saver):
+            threads.shutdown(wait=False, cancel_futures=True)
 
     def _next_queued(self) -> QueuedFrame | None:
         for frame in self._frames:
@@ -361,15 +419,12 @@ class WorkerAutomaticQueue:
             await self._run_loop()
         finally:
             self._enter_loop_state(None)
-            if self._device_stage is not None:
-                self._device_stage[1].cancel()
+            for in_stage in self._on_device:
+                in_stage.future.cancel()
             if self._saving is not None:
                 self._saving.gate.set()  # no thread is left blocked behind it
 
     async def _run_loop(self) -> None:
-        # device stage done and save not begun: (frame, RenderedFrame), or
-        # (frame, FrameRenderTime | Exception) with nothing left to save
-        rendered: tuple[QueuedFrame, object] | None = None
         while not self._cancellation.is_cancelled():
             # Cleared before anything is looked at: whatever ends or
             # arrives from here on wakes the wait at the bottom.
@@ -378,39 +433,35 @@ class WorkerAutomaticQueue:
             # stage: finished events leave in the order of the frames.
             if self._saving is not None and self._saving.future.done():
                 saved, self._saving = self._saving, None
-                await self._report(
-                    saved.frame, _outcome(saved.future), saved.beside_render
-                )
+                await self._report(saved.frame, _outcome(saved.future))
                 continue
-            if self._device_stage is not None and self._device_stage[1].done():
-                (frame, task), self._device_stage = self._device_stage, None
-                rendered = (frame, _outcome(task))
-                continue
-            next_frame = (
-                None if self._draining or self._device_stage is not None
-                else self._next_queued()
-            )
-            if rendered is not None and self._saving is None:
-                (frame, outcome), rendered = rendered, None
+            # The oldest frame on the device is the only one looked at:
+            # whatever came of the ones behind it waits its turn.
+            rendered = bool(self._on_device) and self._on_device[0].future.done()
+            if rendered and self._saving is None:
+                head = self._on_device.popleft()
+                outcome = _outcome(head.future)
                 if not isinstance(outcome, RenderedFrame):
-                    await self._report(frame, outcome)
+                    await self._report(head.frame, outcome)
                     continue
                 # The hand-over, in this order: the next frame's device
                 # work is issued FIRST and this frame's save begins behind
                 # it (encoding holds the GIL the dispatch needs). With
                 # nothing queued there is nothing to wait behind.
-                self._saving = self._begin_save(frame, outcome)
+                self._saving = self._begin_save(head.frame, outcome)
+                next_frame = self._next_to_issue()
                 if next_frame is None:
                     self._saving.gate.set()
                 else:
                     await self._begin_device_stage(next_frame, self._saving.gate.set)
                 continue
-            if next_frame is not None and rendered is None:
+            next_frame = self._next_to_issue()
+            if next_frame is not None:
                 await self._begin_device_stage(next_frame, lambda: None)
                 continue
-            if rendered is not None:
+            if rendered:
                 self._enter_loop_state("save_wait")
-            elif self._device_stage is not None or self._saving is not None:
+            elif self._on_device or self._saving is not None:
                 self._enter_loop_state("render_call")
             else:
                 # Fed at every poll, so a scrape is never more than one
@@ -423,6 +474,12 @@ class WorkerAutomaticQueue:
             except asyncio.TimeoutError:
                 pass
 
+    def _next_to_issue(self) -> QueuedFrame | None:
+        """The queued frame whose device stage may begin now, if any."""
+        if self._draining or len(self._on_device) >= self._device_frames:
+            return None
+        return self._next_queued()
+
     def _wake(self, _ended: asyncio.Future) -> None:
         self._work_available.set()
 
@@ -430,25 +487,45 @@ class WorkerAutomaticQueue:
         self._enter_loop_state("report")
         frame.state = FrameState.RENDERING
         if self._saving is not None:
-            self._saving.beside_render = True
+            self._saving.frame.saved_beside_render = True
+        if any(not earlier.future.done() for earlier in self._on_device):
+            frame.issued_ahead = True
+            frame.device_track = 1 - self._on_device[-1].frame.device_track
         await self._sender.send_message(
             pm.WorkerFrameQueueItemRenderingEvent(
                 frame.job.job_name, frame.frame_index, trace=frame.trace,
                 job_id=frame.job_id, tile=frame.tile, epoch=frame.epoch,
             )
         )
-        task = asyncio.ensure_future(
-            self._backend.render_device_stage(
+        if self._issue is None:
+            stage = self._backend.render_device_stage(
                 frame.job, frame.frame_index, tile=frame.tile, dispatched=dispatched
             )
-        )
+        else:
+            stage = self._issue_and_collect(frame, dispatched)
+        future = asyncio.ensure_future(stage)
         # a stage that ends, however it ends, lets the save behind it go
-        task.add_done_callback(lambda _ended: dispatched())
-        task.add_done_callback(self._wake)
-        self._device_stage = (frame, task)
+        future.add_done_callback(lambda _ended: dispatched())
+        future.add_done_callback(self._wake)
+        self._on_device.append(_DeviceFrame(frame, future))
+
+    async def _issue_and_collect(self, frame: QueuedFrame, dispatched) -> RenderedFrame:
+        """A frame's device stage in its two parts, each on its thread."""
+
+        def issue():
+            try:
+                return self._issue(frame.job, frame.frame_index, frame.tile)
+            finally:
+                dispatched()  # from the issue thread: no turn of the loop between
+
+        loop = asyncio.get_running_loop()
+        issued = await loop.run_in_executor(self._issuer, issue)
+        return await loop.run_in_executor(self._collector, issued.collect)
 
     def _begin_save(self, frame: QueuedFrame, rendered: RenderedFrame) -> _SavingFrame:
         gate = threading.Event()
+        # a frame issued behind this one is in its device stage already
+        frame.saved_beside_render = bool(self._on_device)
 
         def save() -> FrameRenderTime:
             gate.wait()
@@ -458,9 +535,7 @@ class WorkerAutomaticQueue:
         future.add_done_callback(self._wake)
         return _SavingFrame(frame, future, gate)
 
-    async def _report(
-        self, frame: QueuedFrame, outcome: object, beside_render: bool = False
-    ) -> None:
+    async def _report(self, frame: QueuedFrame, outcome: object) -> None:
         """A frame's end: its file is in place (``outcome`` is its seven
         points) or one of its stages raised (``outcome`` is the error)."""
         self._enter_loop_state("report")
@@ -487,8 +562,11 @@ class WorkerAutomaticQueue:
             self._startup.finish()  # the first frame's file is in place
         self._tracer.trace_new_rendered_frame(frame.frame_index, timing)
         self._observe_frame_phases(frame, timing)
-        if beside_render and self._saved_beside_render is not None:
-            self._saved_beside_render.inc()
+        if self._metrics is not None:
+            if frame.saved_beside_render:
+                self._saved_beside_render.inc()
+            if frame.issued_ahead:
+                self._issued_ahead.inc()
         self._remove(frame)
         if frame.session == self._session_generation:
             # A frame queued under a PREVIOUS master session (failover hit
@@ -513,10 +591,13 @@ class WorkerAutomaticQueue:
         the legacy ``FrameRenderTime`` analysis agree exactly. A frame's
         ``write`` lies under the next frame's ``read`` and ``render``, so
         it has a track of its own (``saves``), and so have the save
-        stage's steps (``save steps``).
+        stage's steps (``save steps``); a frame issued behind an
+        uncollected one has its device stage on the second of
+        ``DEVICE_TRACKS``.
         """
         if self._metrics is None and self._span_tracer is None:
             return
+        frames_track, steps_track = DEVICE_TRACKS[frame.device_track]
         bounds = {
             "queue_wait": (frame.queued_at, timing.started_process_at),
             "read": (timing.started_process_at, timing.finished_loading_at),
@@ -536,7 +617,7 @@ class WorkerAutomaticQueue:
                     args["tile"] = frame.tile
                 if frame.trace is not None:
                     args["flow"] = frame.trace.flow_id
-                track = "saves" if phase == "write" else "frames"
+                track = "saves" if phase == "write" else frames_track
                 self._span_tracer.complete(
                     phase,
                     cat="worker",
@@ -574,7 +655,7 @@ class WorkerAutomaticQueue:
                     cat="worker.step",
                     start_wall=start_wall,
                     duration=seconds,
-                    track="save steps" if name in SAVE_STEPS else "steps",
+                    track="save steps" if name in SAVE_STEPS else steps_track,
                     args={"frame": frame.frame_index},
                 )
         if self._metrics is not None:
