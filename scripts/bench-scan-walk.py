@@ -11,9 +11,10 @@ three frames' times and picture hashes with each bounce launch's (live,
 width) and walk counts (``pallas_kernels.WALK_COUNTS``: steps, fetches,
 leaf tests, treelet entries, group tests, prefetches); then bounce 0 and bounce 1 alone
 at full width (one launch each, rays sorted as the frame program sorts
-them), each line with the steps split into box steps (top, wide) and leaf
-tests, the mean children hit per wide test, the share of fetches started
-one treelet ahead and the microseconds a step;
+them), each line with the steps split into box steps (the top's wide
+tests, the treelets' wide tests) and leaf tests, the top's tests for each
+treelet entered, the mean children hit per wide test inside a treelet, the
+share of fetches started one treelet ahead and the microseconds a step;
 then one round of the glue the other walk design would pay between launches
 (a sort of the ray keys, a packed gather of the ray state, a gather of node
 rows).
@@ -75,9 +76,10 @@ def timed(fn, *args, repeats: int = 3):
 
 
 def walk_shape(walk, seconds: float) -> dict:
-    """A launch's counts, split: a step is a box test (a node of the top, or
-    a wide node's eight children at once) or a leaf's triangles; a wide
-    test's children hit are the groups and leaves whose turn it caused."""
+    """A launch's counts, split: a step is a box test (a wide node's eight
+    children at once: of the resident top, a treelet's root or a group) or
+    a leaf's triangles; a wide test's children hit are the groups and
+    leaves whose turn it caused."""
     counts = dict(zip(WALK_COUNTS, (int(x) for x in walk)))
     steps = counts["node_visits"]
     if "leaf_tests" not in counts:
@@ -93,6 +95,7 @@ def walk_shape(walk, seconds: float) -> dict:
         **prefetched,
         box_steps=steps - leaf_tests,
         top_steps=steps - leaf_tests - wide_tests,
+        top_tests_per_entry=(steps - leaf_tests - wide_tests) / max(counts["treelet_entries"], 1),
         wide_tests=wide_tests,
         children_hit_per_wide_test=(counts["group_tests"] + leaf_tests) / max(wide_tests, 1),
         leaf_test_share=leaf_tests / max(steps, 1),
@@ -169,7 +172,7 @@ def main(argv: list[str]) -> int:
         # One round of design (b)'s glue at this width.
         keys32 = keys
         packed = jnp.concatenate([o2, d2, thr2, thr2], axis=1)
-        table = stream.top_bounds.reshape(-1, 6)
+        table = stream.top_boxes.reshape(-1, 8)
         node = (keys32 % table.shape[0]).astype(jnp.int32)
         _, sort_times = timed(jax.jit(jnp.argsort), keys32)
         _, gather_times = timed(jax.jit(lambda p, o: p[o]), packed, order)

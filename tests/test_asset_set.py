@@ -6,8 +6,8 @@ Small and seeded: three scan meshes (grids 16 / 24 / 32: 512, 1,152 and
 2,048 triangles, of three proportions), streaming forced by a small treelet
 budget, the Pallas interpreter on the CPU. What is held:
 
-- the set's tables are the models' tables end to end with links and treelet
-  numbers moved along, and the tables of a set of ONE model are that
+- the set's tables are the models' tables end to end with the wide tops'
+  links and treelet numbers moved along, and the tables of a set of ONE model are that
   model's own, bit for bit, as is the bounce over them;
 - a walk of the set never leaves its instance's nodes of the top: with
   every instance one model, the set's counts and hits are that model's
@@ -19,8 +19,8 @@ budget, the Pallas interpreter on the CPU. What is held:
   (``plain_tracer_assets``) by the check's own rule, a frame whose bodies
   are all model 0 does not, and that reference is ``plain_tracer_accel``
   where there is one model and refuses what is not the stated scene;
-- the build refuses what the top's 16-bit links or SMEM cannot hold, and a
-  set that mixes resident and streamed;
+- the build refuses a top past its share of VMEM or SMEM or deeper than the
+  walk's stack, and a set that mixes resident and streamed;
 - the accepted families keep their programs, and the backend says how many
   BLASes there are and splits the walk's steps.
 """
@@ -109,25 +109,28 @@ def test_the_sets_tables_are_the_models_tables_end_to_end():
     stream = bvh.stream
     singles = [small_single(name) for name in SMALL_MODELS]
     slabs = np.cumsum([0] + [s.stream.tri.shape[0] for s in singles])
-    nodes = np.cumsum([0] + [s.stream.top_meta.shape[0] for s in singles])
+    nodes = np.cumsum([0] + [s.stream.top_links.shape[0] // 8 for s in singles])
     rows = np.cumsum([0] + [s.v0.shape[0] for s in singles])
     np.testing.assert_array_equal(np.asarray(stream.top_first), nodes)
     np.testing.assert_array_equal(bvh.tri_first, rows)
     assert stream.root.shape == (3, 2, 3) and bvh.skip is None
+    boxes, links = scan_tests.top_tables(stream)
+    assert len(links) == nodes[-1] and np.diff(nodes).tolist() == [1, 3, 3]  # a top that is its root alone, and two of two levels
     for m, single in enumerate(singles):
         own = single.stream
         np.testing.assert_array_equal(np.asarray(stream.tri[slabs[m]:slabs[m + 1]]), np.asarray(own.tri))
-        np.testing.assert_array_equal(
-            np.asarray(stream.top_bounds[6 * nodes[m]:6 * nodes[m + 1]]), np.asarray(own.top_bounds)
-        )
         np.testing.assert_array_equal(np.asarray(stream.root[m]), np.asarray(own.root[0]))
-        meta, own_meta = (np.asarray(a).astype(np.int64) for a in (stream.top_meta[nodes[m]:nodes[m + 1]], own.top_meta))
-        # links count from the tables' start; the walk of model m is done where its last link points
-        np.testing.assert_array_equal(meta & 0xFFFF, (own_meta & 0xFFFF) + nodes[m])
-        assert (meta & 0xFFFF)[0] == nodes[m + 1]
-        leaf = own_meta >> 16 > 0
-        np.testing.assert_array_equal((meta >> 16)[leaf], (own_meta >> 16)[leaf] + slabs[m])
-        assert ((meta >> 16)[~leaf] == 0).all()
+        # the model's wide nodes, boxes to the bit, sixteen a tile wherever the model begins
+        np.testing.assert_array_equal(boxes[nodes[m]:nodes[m + 1]], scan_tests.top_tables(own)[0])
+        mine, own_links = links[nodes[m]:nodes[m + 1]], scan_tests.top_tables(own)[1]
+        # links count from the tables' start: a wide node's past the earlier models' nodes, a treelet's past their slabs
+        wide, treelet = own_links < 0, own_links > 0
+        np.testing.assert_array_equal(mine[wide], own_links[wide] - nodes[m])
+        np.testing.assert_array_equal(mine[treelet], own_links[treelet] + slabs[m])
+        assert not mine[~wide & ~treelet].any()
+        # a walk of model m begins at its first node and follows links, which stay among the model's own
+        assert (nodes[m] < -1 - mine[wide]).all() and (-1 - mine[wide] < nodes[m + 1]).all()
+        assert (slabs[m] < mine[treelet]).all() and (mine[treelet] <= slabs[m + 1]).all()
         for key in ("v0", "e1", "e2", "normal"):
             np.testing.assert_array_equal(getattr(bvh, key)[rows[m]:rows[m + 1]], np.asarray(getattr(single, key)))
         np.testing.assert_array_equal(bvh.bounds_min[m], np.asarray(single.bounds_min)[0])
@@ -154,9 +157,10 @@ def test_a_set_of_one_model_is_todays_single_blas_bit_for_bit(use_tlas, interpre
 @pytest.mark.parametrize("m, name", list(enumerate(SMALL_MODELS)))
 def test_a_walk_never_leaves_its_instances_nodes_of_the_top(m, name, interpreted_kernels):  # noqa: F811
     """Every instance model ``m`` of the set: the walk begins at that
-    model's first top node and is done at its last skip link, so its six
-    counts and every hit are those of the model's tree alone. A walk that
-    ran on into the next model's nodes would count more steps."""
+    model's root, its first wide node of the top, and follows links that
+    stay among the model's own nodes and slabs, so its six counts and
+    every hit are those of the model's tree alone. A walk that read
+    another model's nodes would count other steps."""
     _, instances, _, _ = scan_tests.bounce_inputs()
     k = instances.scale.shape[0]
     in_set = whole_bounce(small_set(), instances._replace(model=np.full(k, m, np.int32)))
@@ -401,35 +405,63 @@ def test_the_assets_reference_reads_the_models_its_configuration_states():
 
 
 def fake_tables(slabs, nodes):
-    """Tables of the right shapes with nothing in them."""
-    meta = np.arange(1, nodes + 1, dtype=np.int32)
-    meta[-1] |= 1 << 16  # one leaf, treelet 0
+    """Tables of the right shapes with nothing in them: ``nodes`` wide nodes
+    in a chain, the last one's first child treelet 0."""
+    links = np.zeros((nodes, 8), np.int32)
+    links[:-1, 0] = -1 - np.arange(1, nodes)
+    links[-1, 0] = 1
     return dict(
-        tri=np.zeros((slabs, 1, 1), np.float32), top_bounds=np.zeros(6 * nodes, np.float32), top_meta=meta,
-        root=np.zeros((1, 2, 3), np.float32), top_first=np.array([0, nodes], np.int32),
+        tri=np.zeros((slabs, 1, 1), np.float32), top_boxes=np.zeros((nodes, 8, 8), np.float32),
+        top_links=links.reshape(-1), root=np.zeros((1, 2, 3), np.float32), top_first=np.array([0, nodes], np.int32),
     )
 
 
 @pytest.mark.parametrize("case,match", [
-    ("too many treelets", "16-bit links"), ("too many top nodes", "16-bit links"),
-    ("more top than SMEM holds", "does not fit SMEM"), ("two treelet sizes", "one treelet size"),
+    ("a set's boxes past VMEM's share", "its share of VMEM"), ("one BLAS's boxes past VMEM's share", "its share of VMEM"),
+    ("a top deeper than the walk's stack", "outgrows the walk's stack"), ("two treelet sizes", "one treelet size"),
 ])
-def test_the_join_refuses_what_the_top_cannot_hold(case, match):
+def test_the_build_refuses_what_the_top_cannot_hold(case, match, monkeypatch):
+    """The limits as they stand since the top is wide (ISSUE 51): 256 B of
+    VMEM a wide node against the boxes' share of it, refused where every
+    build's tables become the kernel's operands (``blas_stream``: one BLAS
+    and a set alike), and one stack level a level of wide nodes. The 16-bit
+    links went with the binary top's packed words: a link is a whole word
+    now, and at the budget the links are 512 KiB of SMEM."""
     from tpu_render_cluster.render import mesh as mesh_module
 
-    models = {
-        "too many treelets": [fake_tables(20_000, 10), fake_tables(12_767, 10)],  # 32,767 slabs: treelet + 1 = 1 << 15
-        "too many top nodes": [fake_tables(10, 40_000), fake_tables(10, 25_536)],  # 65,536 nodes
-        "more top than SMEM holds": [fake_tables(10, 20_000), fake_tables(10, 12_769)],  # 917,532 B > 896 KiB
-        "two treelet sizes": [fake_tables(10, 10), {**fake_tables(10, 10), "tri": np.zeros((10, 2, 1), np.float32)}],
+    assert (mesh_module.TOP_NODE_BYTES, mesh_module.TOP_VMEM_BUDGET) == (8 * 8 * 4, 4 << 20)
+    if case == "a top deeper than the walk's stack":
+        # 128 leaves in treelets of 8: roots four levels down, a root of two and a level of two wide nodes
+        assert scan_tests.small_tree(SMALL_TREELET)[0].stream.top_links.shape[0] == 3 * 8
+        monkeypatch.setattr(mesh_module, "TOP_LEVELS", 1)
+        with pytest.raises(ValueError, match=match):
+            scan_tests.small_tree(SMALL_TREELET)
+        monkeypatch.setattr(mesh_module, "TOP_LEVELS", 2)
+        assert scan_tests.small_tree(SMALL_TREELET)[0].stream.top_links.shape[0] == 3 * 8
+        return
+    if case == "two treelet sizes":
+        with pytest.raises(ValueError, match=match):
+            mesh_module.join_treelet_tables([fake_tables(10, 10), {**fake_tables(10, 10), "tri": np.zeros((10, 2, 1), np.float32)}])
+        return
+    # 16,385 wide nodes are 4 MiB + 256 B; one fewer fits, far past the 32,767 treelets and 65,535 nodes of the 16-bit links
+    over, fits = {
+        "a set's boxes past VMEM's share": (
+            lambda: mesh_module.join_treelet_tables([fake_tables(10, 10_000), fake_tables(10, 6_385)]),
+            lambda: mesh_module.join_treelet_tables([fake_tables(40_000, 10_000), fake_tables(10, 6_384)]),
+        ),
+        "one BLAS's boxes past VMEM's share": (lambda: fake_tables(10, 16_385), lambda: fake_tables(40_010, 16_384)),
     }[case]
     with pytest.raises(ValueError, match=match):
-        mesh_module.join_treelet_tables(models)
-    if case == "more top than SMEM holds":  # one node fewer fits
-        joined = mesh_module.join_treelet_tables([fake_tables(10, 20_000), fake_tables(10, 12_768)])
-        assert joined["top_first"].tolist() == [0, 20_000, 32_768]
-        meta = joined["top_meta"].astype(np.int64)
-        assert meta[-1] & 0xFFFF == 32_768 and meta[-1] >> 16 == 1 + 10  # treelet 0 of the second model is slab 10
+        mesh_module.blas_stream(over())
+    stream = mesh_module.blas_stream(fits())
+    assert stream.top_boxes.shape == (1024 * 8, 128) and stream.top_links.shape == (16_384 * 8,)
+    assert mesh_module.geometry_bytes(mesh_module.traced_stream_bvh(stream)) == {
+        "hbm": 40_010 * 4, "vmem": 4 << 20, "smem": 512 << 10,
+    }
+    if case.startswith("a set"):
+        assert stream.top_first.tolist() == [0, 10_000, 16_384]
+        links = np.asarray(stream.top_links).reshape(-1, 8)
+        assert links[10_000, 0] == -1 - 10_001 and links[-1, 0] == 1 + 40_000  # treelet 0 of the second model is slab 40,000
 
 
 def test_a_set_that_mixes_resident_and_streamed_is_refused():

@@ -340,13 +340,13 @@ def tree(value):
     return jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), value)
 
 # The configuration's BLASes by their shapes alone; nothing is built:
-# models, treelets of 64 leaf slots, nodes of resident top.
+# models, treelets of 64 leaf slots, wide nodes of resident top.
 family = sys.argv[1]
 models, treelets, top = (int(word) for word in sys.argv[2:5])
 leaves = mesh_module.TREELET_LEAVES
 stream = mesh_module.BlasStream(
     tri=spec((treelets, 2 * leaves + mesh_module.WIDE, 128), jnp.float32),
-    top_bounds=spec((top * 6,), jnp.float32), top_meta=spec((top,), jnp.int32),
+    top_boxes=spec((-(-top // 16) * 8, 128), jnp.float32), top_links=spec((top * 8,), jnp.int32),
     root=spec((models, 2, 3), jnp.float32), top_first=spec((models + 1,), jnp.int32),
 )
 scene = tree(build_scene(family, 1))
@@ -373,22 +373,31 @@ for width in (widths[0], widths[-1]):
 
 
 @pytest.mark.parametrize("family, models, treelets, top", [
-    # 871,200 triangles: 1,024 treelets under a top of 2,047 nodes
-    pytest.param("03_physics-2-scan", 1, 1024, 2047, id="03_physics-2-scan"),
-    # three models, 1,286,504 triangles: 1,664 treelets, 3,325 nodes (93 KB of SMEM)
-    pytest.param("03_physics-2-assets", 3, 1664, 3325, id="03_physics-2-assets"),
+    # 871,200 triangles: 1,024 treelets ten levels down, under a top of
+    # 1 + 2 + 16 + 128 = 147 wide nodes (40 KB of VMEM, 5 KB of SMEM)
+    pytest.param("03_physics-2-scan", 1, 1024, 147, id="03_physics-2-scan"),
+    # three models, 1,286,504 triangles: 1,664 treelets, 19 + 73 + 147 wide nodes
+    pytest.param("03_physics-2-assets", 3, 1664, 239, id="03_physics-2-assets"),
     # the program is general in the number of models: a table of five
     # (with the happy buddha and the Asian dragon, 9,592,842 triangles;
     # rendered on the chip in PR 34 and cut for the benchmark run's time
-    # limit): 11,904 treelets, 23,803 nodes, two thirds of SMEM
-    pytest.param("03_physics-2-assets", 5, 11904, 23803, id="03_physics-2-assets-five-models"),
+    # limit): 11,904 treelets, 1,703 wide nodes (428 KB of VMEM, 53 KB of
+    # SMEM where the binary top took two thirds of it)
+    pytest.param("03_physics-2-assets", 5, 11904, 1703, id="03_physics-2-assets-five-models"),
+    # the largest top the build lets through (`mesh.TOP_VMEM_BUDGET`): 16,384
+    # wide nodes (4 MiB of VMEM, 512 KiB of SMEM) over about 114,000 treelets,
+    # 117 million triangles in 7.9 GB of HBM. Tried beside it, PR 51: 28,672
+    # wide nodes compile too, and 32,768 are refused for SMEM (1.06 of 1.00 MiB)
+    pytest.param("03_physics-2-assets", 5, 114_000, 16_384, id="the-tops-budget"),
 ])
 def test_the_streamed_bounce_kernel_compiles_with_mosaic(tmp_path, family, models, treelets, top):
     """The bounce kernel over a BLAS in HBM (ISSUE 32: a copy from HBM into
     VMEM scratch, a roll by a traced amount; ISSUE 33: eight boxes down
     the sublanes against a row of rays, their hits reduced to one scalar
-    mask; ISSUE 34: a walk that begins and ends at nodes read from the
-    instance table, over the resident tops of several models), at the
+    mask; ISSUE 34: a walk that begins at a node read from the instance
+    table, over the resident tops of several models; ISSUE 51: the top's
+    wide nodes out of a VMEM table by a traced row and roll, its links out
+    of SMEM, its stack in SMEM scratch), at the
     configurations' table shapes and the widest and narrowest rung of a
     1 spp frame, through the real compiler. A subprocess: it loads libtpu."""
     result = _run(

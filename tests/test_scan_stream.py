@@ -207,12 +207,105 @@ def test_the_treelets_partition_the_tree(treelet_leaves):
             seen += held
     # every leaf of the tree the child of exactly one wide node
     assert next(leaves, None) is None and seen == int((count > 0).sum()) == int(present[:, 1:].sum())
-    top_meta = np.asarray(stream.top_meta).astype(np.int64)
-    top_skip, top_treelet = top_meta & 0xFFFF, (top_meta >> 16) - 1
-    assert (top_skip > np.arange(len(top_meta))).all() and top_skip[0] == len(top_meta)
-    assert sorted(top_treelet[top_treelet >= 0]) == list(range(n_treelets))
+    _, links = top_tables(stream)
+    assert sorted(links[links > 0]) == list(range(1, n_treelets + 1))  # every treelet some top node's child, once
     np.testing.assert_array_equal(np.asarray(stream.root)[0], [np.asarray(bvh.bounds_min)[0], np.asarray(bvh.bounds_max)[0]])
-    np.testing.assert_array_equal(np.asarray(stream.top_first), [0, len(top_meta)])  # one model: its whole top
+    np.testing.assert_array_equal(np.asarray(stream.top_first), [0, len(links)])  # one model: its whole top
+
+
+def top_tables(stream):
+    """The resident top's wide nodes: boxes ``[wide node, child, 8]`` out of
+    their tiles of sixteen, links ``[wide node, child]``."""
+    links = np.asarray(stream.top_links).astype(np.int64).reshape(-1, 8)
+    boxes = np.asarray(stream.top_boxes).reshape(-1, 8, 16, 8).transpose(0, 2, 1, 3).reshape(-1, 8, 8)
+    assert boxes.shape[0] == -(-len(links) // 16) * 16
+    spare = boxes[len(links):]
+    assert (spare[..., 0:3] == 1e30).all() and (spare[..., 3:6] == -1e30).all() and not spare[..., 6:].any()
+    return boxes[:len(links)], links
+
+
+def binary_top(bvh, treelet_leaves):
+    """The tree above its treelets by plain recursion over the threaded
+    nodes: which nodes are treelet roots (a node of at most
+    ``treelet_leaves`` leaves under a parent of more), their numbers in
+    preorder, and the depth of the deepest."""
+    skip, count = np.asarray(bvh.skip), np.asarray(bvh.count)
+    held = np.cumsum(count > 0)
+    leaves = lambda node: held[skip[node] - 1] - (held[node - 1] if node else 0)  # noqa: E731
+    roots, deepest = {}, 0
+    pending = [(0, 0)]
+    while pending:
+        node, depth = pending.pop()
+        if leaves(node) <= treelet_leaves:
+            roots[node], deepest = len(roots), max(deepest, depth)
+        else:
+            pending += [(int(skip[node + 1]), depth + 1), (node + 1, depth + 1)]  # the left one first
+    return roots, deepest
+
+
+@pytest.mark.parametrize("grid, treelet_leaves, wide_nodes", [
+    (32, 64, 1), (32, 16, 1), (32, 8, 3),  # 128 leaves: 2, 8 and 16 treelets, one, three and four levels down
+    (33, 8, 5),  # 137 leaves: 25 treelets, their roots four AND five levels down: a root of four
+    (64, 8, 9),  # 512 leaves: 64 treelets six levels down, two full levels of wide nodes
+    (96, 8, 37),  # 1,152 leaves: 256 treelets eight levels down: a root of four and two full levels
+])
+def test_the_resident_top_is_the_binary_top_three_levels_a_wide_node(grid, treelet_leaves, wide_nodes):
+    """Every wide node of the top stands for a binary node and holds what
+    lies three levels below it (the model's root: what is left of the
+    deepest treelet root's depth after whole threes), a treelet root where
+    one is met sooner: in preorder, to the bit, each treelet and each wide
+    node but the root the child of exactly one."""
+    from tpu_render_cluster.render import mesh as mesh_module
+
+    vertices, faces = mesh_module.make_scan_mesh(grid=grid)
+    bvh = mesh_module.build_bvh(vertices, faces, builder="morton", treelet_leaves=treelet_leaves)
+    boxes, links = top_tables(bvh.stream)
+    roots, deepest = binary_top(bvh, treelet_leaves)
+    n_wide, n_treelets = len(links), bvh.stream.tri.shape[0]
+    assert (n_wide, n_treelets) == (wide_nodes, len(roots))
+    assert sorted(links[links > 0]) == list(range(1, n_treelets + 1))
+    assert sorted(-1 - links[links < 0]) == list(range(1, n_wide))  # node 0 is the root, nobody's child
+    skip = np.asarray(bvh.skip)
+    low, high = np.asarray(bvh.bounds_min), np.asarray(bvh.bounds_max)
+    pending, seen = [(0, 0, (deepest - 1) % 3 + 1 if deepest else 3)], 0
+    while pending:
+        wide, node, levels = pending.pop()
+        assert wide == seen  # numbered in the preorder of their binary nodes
+        seen += 1
+        slots = [node]
+        for _ in range(levels):  # a treelet root stands where it is met; what would lie under it is missing
+            slots = [
+                below for held in slots for below in (
+                    (held, None) if held is None or held in roots else (held + 1, int(skip[held + 1]))
+                )
+            ]
+        slots += [None] * (8 - len(slots))
+        inner = []
+        for child, held in enumerate(slots):
+            if held is None:
+                assert links[wide, child] == 0 and boxes[wide, child, 6] == 0
+                assert (boxes[wide, child, 0:3] == 1e30).all() and (boxes[wide, child, 3:6] == -1e30).all()
+                continue
+            # the binary node's box to the bit, and the child's own bit
+            np.testing.assert_array_equal(boxes[wide, child, 0:3], low[held])
+            np.testing.assert_array_equal(boxes[wide, child, 3:6], high[held])
+            assert boxes[wide, child, 6] == 1 << child and boxes[wide, child, 7] == 0
+            if held in roots:
+                assert links[wide, child] == roots[held] + 1
+            else:
+                inner.append((int(-1 - links[wide, child]), held, 3))
+        assert [w for w, _, _ in inner] == sorted(w for w, _, _ in inner) and all(w > wide for w, _, _ in inner)
+        pending += reversed(inner)
+    assert seen == n_wide
+    # lowest bit first, depth first, is the binary walk's order: the treelets as numbered
+    order, pending = [], [-1]  # the root's link
+    while pending:
+        link = pending.pop()
+        if link > 0:
+            order.append(link - 1)
+        else:
+            pending += [int(child) for child in links[-1 - link][::-1] if child]
+    assert order == list(range(n_treelets))
 
 
 def one_box_at_a_time(boxes, origins, directions, limit):
@@ -281,7 +374,8 @@ def test_resident_or_streamed_follows_the_tables_bytes():
     assert mesh_module.geometry_bytes(small)["hbm"] == 0 and mesh_module.geometry_bytes(small)["vmem"] > 0
     forced, _ = small_tree(SMALL_TREELET)
     where = mesh_module.geometry_bytes(forced)
-    assert where["hbm"] > 2048 * 64 and where["vmem"] == 0 and 0 < where["smem"] < 8192
+    # the slabs in HBM; of the top's three wide nodes the boxes one tile of VMEM, the links eight words of SMEM each
+    assert where["hbm"] > 2048 * 64 and where["vmem"] == 8 * 128 * 4 and where["smem"] == 3 * 8 * 4
     # the configuration's mesh, from its shapes: 871,200 rows, 108,899 nodes
     assert mesh_module.resident_table_bytes(871_200, 108_899) > 200 * mesh_module.RESIDENT_VMEM_BUDGET
     assert 2 * mesh_module.SCAN_GRID ** 2 == 871_200
@@ -391,41 +485,42 @@ def test_the_streamed_walk_is_the_resident_walk_bit_for_bit_and_brute_forces_hit
 
 
 def top_leaves_met(stream, origin, direction, limit, pad=0.0):
-    """The top leaves whose box (grown by ``pad``) a ray meets nearer than
-    ``limit``, in the walk's order over the skip links: (node, entry t)."""
-    bounds = np.asarray(stream.top_bounds, np.float64).reshape(-1, 6)
-    meta = np.asarray(stream.top_meta).astype(np.int64)
+    """The treelets whose box in the resident top (grown by ``pad``) a ray
+    meets nearer than ``limit``, in the walk's order over the wide nodes,
+    lowest child first and depth first: (treelet, entry t)."""
+    boxes, links = top_tables(stream)
+    boxes = boxes.astype(np.float64)
     inverse = 1.0 / np.where(np.abs(direction) < 1e-12, 1e-12, direction)
-    node, met = 0, []
-    while node < len(meta):
-        low = (bounds[node, :3] - pad - origin) * inverse
-        high = (bounds[node, 3:] + pad - origin) * inverse
-        near, far = np.minimum(low, high).max(), np.maximum(low, high).min()
-        hit, leaf = far >= max(near, 0.0) and near < limit, (meta[node] >> 16) > 0
-        if hit and leaf:
-            met.append((node, near))
-        node = node + 1 if (hit and not leaf) else int(meta[node] & 0xFFFF)
+    met, pending = [], [(-1, 0.0)]  # the root's link
+    while pending:
+        link, near = pending.pop()
+        if link > 0:
+            met.append((link - 1, near))
+            continue
+        node = boxes[-1 - link]
+        low = (node[:, 0:3] - pad - origin) * inverse
+        high = (node[:, 3:6] + pad - origin) * inverse
+        near, far = np.minimum(low, high).max(axis=1), np.maximum(low, high).min(axis=1)
+        hit = (far >= np.maximum(near, 0.0)) & (near < limit) & (node[:, 6] > 0)
+        pending += [(int(links[-1 - link, child]), near[child]) for child in np.flatnonzero(hit)[::-1]]
     return met
 
 
-@pytest.mark.parametrize("case", ["one treelet", "the second culled by the first's hit"])
-def test_a_fetch_started_ahead_is_waited_for_where_its_treelet_is_entered(case, interpreted_kernels):
-    """One packet of equal rays against one instance, no shadow walk (the
-    rays fly towards the sun, so what they hit faces away from it): the
-    launch is ONE walk. A walk that meets one treelet fetches it and has
-    nothing to look ahead to. A walk that meets two top leaves whose second
-    lies wholly behind the first's hit finds the second before it has walked
-    the first, with the cull distance of that moment: it fetches it, waits for
-    the copy where it enters it, meets no group there, and the bounce is the
-    resident walk's bit for bit."""
+def one_walk(treelet_leaves):
+    """One instance of the small mesh with its thin axis laid along the
+    sun's direction, and 400 seeded object-space rays along it from in
+    front of the mesh with brute force's nearest hit: what a launch of ONE
+    walk is made of (rays that fly towards the sun hit what faces away from
+    it, so no shadow walk follows). ``bounce(start)`` sends a block of 1,024
+    copies of the ray from ``start`` through the streamed and the resident
+    kernel and returns (streamed, resident, the walk's counts)."""
     import jax.numpy as jnp
 
     from tpu_render_cluster.render import mesh as mesh_module
     from tpu_render_cluster.render import pallas_kernels
     from tpu_render_cluster.render.scene import build_scene
 
-    bvh, _ = small_tree(64)  # 128 leaves: two treelets
-    assert bvh.stream.tri.shape[0] == 2
+    bvh, _ = small_tree(treelet_leaves)
     resident = bvh._replace(stream=None)
     scene = build_scene("03_physics-2-mesh", 295.0)
     scene = scene._replace(radii=jnp.zeros_like(scene.radii))
@@ -439,17 +534,49 @@ def test_a_fetch_started_ahead_is_waited_for_where_its_treelet_is_entered(case, 
         rotation=jnp.asarray(rotation[None], jnp.float32), translation=jnp.asarray(translation[None], jnp.float32),
         albedo=jnp.full((1, 3), 0.5, jnp.float32), scale=jnp.ones((1,), jnp.float32),
     )
-
-    # object-space rays along +z from in front of the mesh: the first that fits the case, with room
     rng = np.random.default_rng(36)
     low, high = np.asarray(bvh.bounds_min)[0], np.asarray(bvh.bounds_max)[0]
     starts = low + rng.random((400, 3)) * (high - low)
     starts[:, 2] = -2.0
     along = np.array([0.0, 0.0, 1.0])
-    nearest, _ = mesh_module.intersect_triangles_brute(
-        resident, jnp.asarray(starts, jnp.float32), jnp.asarray(np.tile(along, (400, 1)), jnp.float32)
-    )
-    nearest = np.asarray(nearest, np.float64)
+
+    def nearest(tree):
+        t, _ = mesh_module.intersect_triangles_brute(
+            tree, jnp.asarray(starts, jnp.float32), jnp.asarray(np.tile(along, (400, 1)), jnp.float32)
+        )
+        return np.asarray(t, np.float64)
+
+    def bounce(start, t):
+        n = 1024  # one block of the flat sweep
+        origins = jnp.asarray(np.tile(rotation @ start + translation, (n, 1)), jnp.float32)
+        directions = jnp.asarray(np.tile(sun, (n, 1)), jnp.float32)
+        streamed, plain = (
+            pallas_kernels.mesh_bounce_pallas(
+                scene, mesh_module.MeshSet(tree, instances), origins, directions,
+                jnp.ones((n, 3), jnp.float32), jnp.ones((n,), bool), 7, 0, total_bounces=4, use_tlas=False,
+            ) for tree in (bvh, resident)
+        )
+        for ours, theirs in zip(streamed[:5], plain[:5]):
+            np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
+        hit_point = np.asarray(origins[0], np.float64) + sun * t
+        assert abs(np.linalg.norm(np.asarray(streamed[1][0], np.float64) - hit_point) - 4e-3) < 1e-4  # brute force's t
+        return dict(zip(pallas_kernels.WALK_COUNTS, (int(x) for x in streamed[6])))
+
+    return bvh, resident, starts, along, nearest, bounce
+
+
+@pytest.mark.parametrize("case", ["one treelet", "the second culled by the first's hit"])
+def test_a_fetch_started_ahead_is_waited_for_where_its_treelet_is_entered(case, interpreted_kernels):
+    """One packet of equal rays against one instance, no shadow walk: the
+    launch is ONE walk (``one_walk``). A walk that meets one treelet fetches
+    it and has nothing to look ahead to. A walk that meets two treelets
+    whose second lies wholly behind the first's hit finds the second before
+    it has walked the first, with the cull distance of that moment: it
+    fetches it, waits for the copy where it enters it, meets no group there,
+    and the bounce is the resident walk's bit for bit."""
+    bvh, resident, starts, along, nearest, bounce = one_walk(64)  # 128 leaves: two treelets
+    assert bvh.stream.tri.shape[0] == 2
+    nearest = nearest(resident)
 
     def fits(start, t):
         met = top_leaves_met(bvh.stream, start, along, 1e30)
@@ -457,33 +584,60 @@ def test_a_fetch_started_ahead_is_waited_for_where_its_treelet_is_entered(case, 
             return False
         if case == "one treelet":
             return len(met) == 1
-        behind = [node for node, _ in top_leaves_met(bvh.stream, start, along, t)]
+        behind = [treelet for treelet, _ in top_leaves_met(bvh.stream, start, along, t)]
         return len(met) == 2 and behind == [met[0][0]] and met[1][1] > t + 1e-2
 
     chosen = next(i for i in range(400) if fits(starts[i], nearest[i]))
-    start, t = starts[chosen], nearest[chosen]
-    n = 1024  # one block of the flat sweep
-    origins = jnp.asarray(np.tile(rotation @ start + translation, (n, 1)), jnp.float32)
-    directions = jnp.asarray(np.tile(sun, (n, 1)), jnp.float32)
-
-    def bounce(tree):
-        return pallas_kernels.mesh_bounce_pallas(
-            scene, mesh_module.MeshSet(tree, instances), origins, directions,
-            jnp.ones((n, 3), jnp.float32), jnp.ones((n,), bool), 7, 0, total_bounces=4, use_tlas=False,
-        )
-
-    streamed, plain = bounce(bvh), bounce(resident)
-    for ours, theirs in zip(streamed[:5], plain[:5]):
-        np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
-    hit_point = np.asarray(origins[0], np.float64) + sun * t
-    assert abs(np.linalg.norm(np.asarray(streamed[1][0], np.float64) - hit_point) - 4e-3) < 1e-4  # brute force's t
-    counts = dict(zip(pallas_kernels.WALK_COUNTS, (int(x) for x in streamed[6])))
+    counts = bounce(starts[chosen], nearest[chosen])
     expected = 1 if case == "one treelet" else 2
     assert counts["treelet_entries"] == counts["treelet_fetches"] == expected, counts
     assert counts["treelet_prefetches"] == expected - 1, counts
     assert counts["leaf_tests"] > 0 and counts["group_tests"] > 0
-    # the treelet found too far ahead costs its fetch and its root's wide test, and nothing else
-    assert counts["node_visits"] > counts["leaf_tests"] + counts["group_tests"] + counts["treelet_entries"]
+    # the treelet found too far ahead costs its fetch and its root's wide test, and nothing else;
+    # the top is one wide node here, tested once
+    assert counts["node_visits"] == 1 + counts["leaf_tests"] + counts["group_tests"] + counts["treelet_entries"]
+
+
+@pytest.mark.parametrize("case", ["both behind the first's hit", "the second in front of it"])
+def test_a_treelet_left_in_its_parents_mask_is_entered_and_meets_no_group(case, interpreted_kernels):
+    """What the wide top changes besides the counts (ISSUE 51): a wide
+    node's mask is made when the node is entered, so a later child is met
+    under the limit of that moment and not of its own. One packet, ONE walk
+    (``one_walk``), eight treelets under the one wide node of the top, and
+    a ray whose box meets three of them, the first holding its hit and the
+    third wholly behind that hit (the second behind it too, or in front of
+    it and missed). A walk that tested the third's box when it came to it
+    (the binary top's; the look-ahead's one treelet of grace is spent on
+    the second) would cull it and enter two. This one enters all three: the
+    third is fetched, waited for, its root's wide test meets no group, and
+    the bounce is the resident walk's bit for bit."""
+    bvh, resident, starts, along, nearest, bounce = one_walk(16)  # 128 leaves: eight treelets of two groups
+    assert bvh.stream.tri.shape[0] == 8 and len(top_tables(bvh.stream)[1]) == 1
+    whole = nearest(resident)
+
+    def fits(start, t):
+        met = top_leaves_met(bvh.stream, start, along, 1e30)
+        if t > 1e29 or len(met) != 3 or len(top_leaves_met(bvh.stream, start, along, 1e30, pad=1e-3)) != 3:
+            return False
+        in_front = [treelet for treelet, _ in top_leaves_met(bvh.stream, start, along, t)]
+        wanted = [met[0][0]] if case == "both behind the first's hit" else [met[0][0], met[1][0]]
+        return in_front == wanted and all(entry > t + 1e-2 for treelet, entry in met if treelet not in wanted)
+
+    def first_treelets_hit(i):
+        """Ray ``i``'s hit among the triangles of the first treelet it meets alone: a treelet's 16 leaves are 256
+        rows of the leaf-ordered triangles."""
+        first = 256 * top_leaves_met(bvh.stream, starts[i], along, 1e30)[0][0]
+        alone = resident._replace(**{key: getattr(resident, key)[first:first + 256] for key in ("v0", "e1", "e2", "normal")})
+        return nearest(alone)[i]
+
+    chosen = next(i for i in range(400) if fits(starts[i], whole[i]) and first_treelets_hit(i) == whole[i])
+    counts = bounce(starts[chosen], whole[chosen])
+    assert counts["treelet_entries"] == counts["treelet_fetches"] == 3, counts
+    assert counts["treelet_prefetches"] == 2, counts  # all but the walk's first are started a treelet ahead
+    # one wide test of the top for three entries; a treelet holds two groups, and the third's are never tested
+    assert counts["node_visits"] == 1 + 3 + counts["group_tests"] + counts["leaf_tests"], counts
+    assert 0 < counts["group_tests"] <= (2 if case == "both behind the first's hit" else 4), counts
+    assert counts["leaf_tests"] > 0
 
 
 # -- the family, and the benchmark's reference ------------------------------------
@@ -547,19 +701,39 @@ def assert_the_parents_picture(image, family):
 
 def assert_the_parents_walk(walk, parent):
     """``walk`` [bounces, 6] against the five counts a bounce that the walk
-    that fetched a treelet where it entered it, and waited, gave on the same
-    frame (commit 81f8c34, before ISSUE 36). Looking one hit leaf ahead
-    culls by the limit as it stood a treelet earlier, so a treelet that
-    walk had culled may be fetched and entered: its root's wide test meets
-    no group, so NO leaf is tested that was not, and no group. Two slots
-    keep one treelet more, so a fetch or two may also be spared."""
+    that fetched a treelet where it entered it, and waited, over a binary
+    top gave on the same frame (commit 81f8c34, before ISSUE 36). A treelet
+    is now found under an older limit than that walk's: the look-ahead
+    culls by the limit as it stood a treelet earlier (ISSUE 36) and a wide
+    top node's mask by the limit as it stood when the node was entered
+    (ISSUE 51). So a treelet that walk had culled may be fetched and
+    entered: its root's wide test meets no group, so NO leaf is tested that
+    was not, and no group. Two slots keep one treelet more, so a fetch or
+    two may also be spared. The top's steps are wide tests now, eight
+    boxes each: far fewer than the binary top's.
+
+    What the stale masks cost here, read per bounce on the two 32 x 32
+    frames (PR 51; scan, then assets): entries 744 / 505 / 257 / 138 against
+    692 / 474 / 245 / 116 and 552 / 380 / 193 / 131 against 527 / 356 / 182
+    / 110, fetches 741 / 499 / 244 / 129 against 692 / 473 / 243 / 115 and
+    546 / 375 / 190 / 129 against 527 / 356 / 180 / 110. The first three
+    bounces stay inside the limits this helper had (at most 1.075 and
+    1.071); the last, a hundred-odd entries of a few scattered rays over
+    treelets of 8 leaves, where the eight children of one wide node are
+    neighbours a hit in the first would have culled one by one, reads 1.19
+    and 1.17. The chip's frames, treelets of 64 leaves, read + 2%."""
     steps, fetches, leaf_tests, entries, group_tests, prefetches = np.asarray(walk).T
     parent_steps, parent_fetches, parent_leaf_tests, parent_entries, parent_group_tests = np.array(parent).T
     np.testing.assert_array_equal(leaf_tests, parent_leaf_tests)
     np.testing.assert_array_equal(group_tests, parent_group_tests)
-    assert (entries >= parent_entries).all() and (entries <= 1.1 * parent_entries).all(), entries
-    assert (np.abs(fetches - parent_fetches) <= 0.1 * parent_fetches).all() and fetches.sum() >= parent_fetches.sum(), fetches
-    assert (steps >= parent_steps).all() and (steps <= 1.05 * parent_steps).all(), steps
+    more = np.where(np.arange(len(entries)) < len(entries) - 1, 0.1, 0.2)  # as it was, but for the last bounce
+    assert (entries >= parent_entries).all() and (entries <= (1 + more) * parent_entries).all(), entries
+    assert (np.abs(fetches - parent_fetches) <= more * parent_fetches).all() and fetches.sum() >= parent_fetches.sum(), fetches
+    top, parent_top = (s - l - e - g for s, l, e, g in (
+        (steps, leaf_tests, entries, group_tests), (parent_steps, parent_leaf_tests, parent_entries, parent_group_tests),
+    ))
+    assert (top > 0).all() and (top < 0.4 * parent_top).all(), (top, parent_top)
+    assert (top <= 1.5 * entries).all(), top / entries  # what the benchmark's walk_top_tests_per_entry reads
     # most fetches are started one treelet ahead; a walk's first cannot be
     assert (prefetches <= fetches).all() and (prefetches > 0.6 * fetches).all(), (prefetches, fetches)
 

@@ -116,15 +116,19 @@ class BlasStream(NamedTuple):
     staged to VMEM scratch by one DMA when a packet enters a treelet).
 
     The tree's top — every node that holds more leaves than one treelet,
-    with the treelet roots as its leaves — stays resident in SMEM; below
-    it treelet ``t`` is a subtree of at most ``treelet_leaves(stream)``
-    leaves, held as at most nine WIDE nodes of eight children in two
-    levels: a root whose children are the subtree's groups (its first
-    nodes of at most ``WIDE`` leaves, in the binary tree's preorder), and
-    a node a group whose children are the group's leaves. A wide node's
-    eight child boxes are tested in one ``[8, block]`` slab test, so a
-    leaf's box is tested in its group's step. Every static size is read
-    from a shape, so the pytree holds arrays only.
+    with the treelet roots as its leaves — stays resident for the whole
+    launch, and like everything below it is held as WIDE nodes of eight
+    children: the balanced binary tree collapsed three levels at a time
+    (``_wide_top``), a wide top node's children the binary nodes three
+    levels below it, or a treelet root where one is met sooner, in the
+    binary tree's preorder. Below the top, treelet ``t`` is a subtree of at
+    most ``treelet_leaves(stream)`` leaves, held as at most nine wide nodes
+    in two levels: a root whose children are the subtree's groups (its
+    first nodes of at most ``WIDE`` leaves, in preorder), and a node a
+    group whose children are the group's leaves. A wide node's eight child
+    boxes are tested in one ``[8, block]`` slab test, so a treelet's box is
+    tested in its parent's step and a leaf's in its group's. Every static
+    size is read from a shape, so the pytree holds arrays only.
 
     ``tri`` is one slab a treelet, what a fetch copies. Its first
     ``16 * L / 8`` rows pack a triangle as 12 floats (v0, e1, e2, unit
@@ -140,17 +144,26 @@ class BlasStream(NamedTuple):
     ``8w .. 8w + 7``: lo xyz, hi xyz, then the child's bit ``1 << c`` as
     a float (0 for an empty slot, whose box is inverted) and a spare.
 
+    The top's boxes lie in VMEM in that same layout, sixteen wide nodes
+    an ``[8, 128]`` tile: wide node ``w`` is rows ``8 (w // 16) ..`` of
+    ``top_boxes``, lanes ``8 (w % 16) ..``. What child ``c`` of ``w`` IS
+    sits where a scalar load reaches it, word ``8w + c`` of ``top_links``
+    in SMEM: ``-1 - v`` for wide top node ``v``, ``t + 1`` for treelet
+    ``t``, 0 for an empty slot.
+    Wide nodes are numbered in the preorder of their binary nodes, so a
+    model's root comes first of its nodes.
+
     Several BLASes lie end to end in the same tables: model ``m``'s slabs
-    follow model ``m - 1``'s in ``tri``, and its top is nodes
-    ``top_first[m] .. top_first[m + 1]`` of the one top, whose skip links
-    and treelet numbers count from the tables' start. A walk of model
-    ``m`` begins at ``top_first[m]`` and is done at ``top_first[m + 1]``,
-    where its last skip link points.
+    follow model ``m - 1``'s in ``tri``, and its top is wide nodes
+    ``top_first[m] .. top_first[m + 1]`` of the one top, whose links and
+    treelet numbers count from the tables' start. A walk of model ``m``
+    begins at its root, wide node ``top_first[m]``, and follows links
+    alone, which never leave the model's nodes.
     """
 
     tri: jnp.ndarray  # [NT, 16 * L/8 + WIDE, 128] f32
-    top_bounds: jnp.ndarray  # [NTOP * 6] f32
-    top_meta: jnp.ndarray  # [NTOP] int32: skip | (treelet + 1) << 16
+    top_boxes: jnp.ndarray  # [8 * ceil(NW / 16), 128] f32
+    top_links: jnp.ndarray  # [NW * 8] int32: -1 - wide node | treelet + 1 | 0
     root: jnp.ndarray  # [M, 2, 3] f32: each model's whole tree's bounds
     top_first: jnp.ndarray  # [M + 1] int32: where each model's top begins
 
@@ -166,16 +179,17 @@ def treelet_leaves(stream: BlasStream) -> int:
 def geometry_bytes(bvh: MeshBVH) -> dict[str, int]:
     """Bytes of a scene's BLAS tables (one BLAS or a set) by the memory
     they live in while a bounce kernel runs: streamed, the treelet tables
-    in HBM and the trees' tops in SMEM; resident, the padded triangle
-    tables in VMEM and the nodes in SMEM (``resident_table_bytes``)."""
+    in HBM, the boxes of the trees' wide tops in VMEM and their links in
+    SMEM; resident, the padded triangle tables in VMEM and the nodes in
+    SMEM (``resident_table_bytes``)."""
     stream = bvh.stream
     if stream is None:
         nodes = bvh.skip.shape[0] * 9 * 4 * (1 if bvh.octant is None else 8)
         return {"hbm": 0, "vmem": bvh.v0.shape[0] * 128 * 4 * 4, "smem": nodes}
     return {
         "hbm": int(stream.tri.size) * 4,
-        "vmem": 0,
-        "smem": sum(int(a.size) * 4 for a in (stream.top_bounds, stream.top_meta)),
+        "vmem": int(stream.top_boxes.size) * 4,
+        "smem": int(stream.top_links.size) * 4,
     }
 
 
@@ -294,8 +308,9 @@ class ScanModel(NamedTuple):
 # at the dragon because a benchmark run builds the set three times and
 # checks it against a plain reference inside a time limit (PERF.md §7: with
 # the happy buddha's 1,087,716 as a fourth model a run did not fit it), and
-# Thai statue (10,000,000) and Lucy (28,055,742) would besides put the
-# set's resident top past SMEM (``TOP_SMEM_BUDGET``).
+# Thai statue (10,000,000) and Lucy (28,055,742) are 39 million triangles
+# more, 2.5 GB of slabs: their wide tops would fit (``TOP_VMEM_BUDGET``), the
+# run's time limit would not.
 ASSET_MODELS: dict[str, ScanModel] = {
     "bunny": ScanModel("bun_zipper.ply", 69_451, 186, 69_451, 0.9),
     "armadillo": ScanModel("Armadillo.ply", 345_944, 416, 345_944, 0.45),
@@ -789,35 +804,97 @@ def _first_fitting(lo: np.ndarray, hi: np.ndarray, most: int) -> np.ndarray:
     return fits & (index >= covered_until)
 
 
+# Levels of wide nodes a tree's top may have: the walk keeps one (wide
+# node, bits left) a level in a stack of this depth. Six levels reach
+# treelet roots 18 binary levels down, 262,144 treelets a model, which is
+# more than twice what the top's bytes allow (``TOP_VMEM_BUDGET``).
+TOP_LEVELS = 6
+# Wide nodes of one ``[8, 128]`` tile of boxes, eight lanes each.
+TILE_NODES = 128 // 8
+
+
+def _wide_top(tree: dict, is_root: np.ndarray, treelet: np.ndarray):
+    """The tree above its treelet roots (``is_root``) as wide nodes, a
+    level of them at a time: a wide node stands for a binary node, and its
+    children are what three binary levels below that node hold, a treelet
+    root standing where it is met. The model's root alone takes one or two
+    levels where the deepest treelet root's depth is no multiple of three,
+    so the odd levels cost one narrow node at the top, not a narrow node
+    above every pair of treelets. A child's slot is its path's bits, so
+    the slots run in preorder, and a slot under a treelet root met sooner
+    is empty (inverted box, bit 0). Wide nodes are numbered in the
+    preorder of their binary nodes. Returns (boxes ``[NW, child, 8]``:
+    lo xyz, hi xyz, the child's bit ``1 << c``, a spare; links ``[NW * 8]``
+    int32: ``-1 - v`` for wide node ``v``, ``treelet + 1``, 0 for none)."""
+    skip = tree["skip"]
+
+    def below(nodes):
+        """(left, right) of each of ``nodes``, a treelet root or a
+        missing one (-1) standing for itself beside a missing one."""
+        node = np.maximum(nodes, 0)
+        inner = (nodes >= 0) & ~is_root[node]
+        return (
+            np.where(inner, nodes + 1, nodes),
+            np.where(inner, skip[np.minimum(node + 1, skip.size - 1)], -1),
+        )
+
+    depth, level = 0, np.zeros(1, np.int64)  # of the deepest treelet root
+    while not is_root[level].all():
+        level = np.concatenate(below(level))
+        level, depth = level[level >= 0], depth + 1
+    heads, slots = [], []
+    level = np.zeros(1, np.int64)
+    while level.size:
+        if len(heads) == TOP_LEVELS:
+            raise ValueError(
+                f"a resident top deeper than {TOP_LEVELS} levels of wide "
+                "nodes outgrows the walk's stack"
+            )
+        children = np.full((level.size, WIDE), -1, np.int64)
+        children[:, 0] = level
+        levels = 3 if heads else (depth - 1) % 3 + 1
+        for stride in (4, 2, 1)[3 - levels:]:
+            left, right = below(children[:, ::2 * stride])
+            children[:, ::2 * stride], children[:, stride::2 * stride] = left, right
+        heads.append(level)
+        slots.append(children)
+        level = children[(children >= 0) & ~is_root[np.maximum(children, 0)]]
+    heads, slots = np.concatenate(heads), np.concatenate(slots)
+    order = np.argsort(heads)
+    heads, slots = heads[order], slots[order]
+    held = slots >= 0
+    node = np.maximum(slots, 0)
+    links = np.where(
+        held,
+        np.where(is_root[node], treelet[node] + 1, -1 - np.searchsorted(heads, node)),
+        0,
+    ).astype(np.int32)
+    boxes = np.zeros((heads.size, WIDE, 8), np.float32)
+    boxes[..., 0:3] = np.where(held[..., None], tree["bounds_min"][node], INF)
+    boxes[..., 3:6] = np.where(held[..., None], tree["bounds_max"][node], -INF)
+    boxes[..., 6] = np.where(held, 1 << np.arange(WIDE), 0)
+    return boxes, links.reshape(-1)
+
+
 def partition_treelets(tree: dict, max_leaves: int = TREELET_LEAVES) -> dict:
     """Cut a ``_build_bvh_morton`` tree into treelets of at most
     ``max_leaves`` leaves (8, 16, 32 or 64: a byte budget of
     ``max_leaves`` KiB of triangle rows): host arrays under
-    ``BlasStream``'s field names. A treelet root is a node that fits the
-    budget whose parent does not; what lies above is the resident top.
-    Inside a treelet the same rule at ``WIDE`` leaves finds its groups:
-    the balanced tree has at most ``max_leaves / WIDE`` of them, which
-    become the children of the treelet's wide root, and a group's leaves
-    the children of its wide node."""
+    ``BlasStream``'s field names, the top's boxes still a wide node a row
+    (``blas_stream`` tiles them). A treelet root is a node that fits the
+    budget whose parent does not; what lies above is the resident top
+    (``_wide_top``). Inside a treelet the same rule at ``WIDE`` leaves
+    finds its groups: the balanced tree has at most ``max_leaves / WIDE``
+    of them, which become the children of the treelet's wide root, and a
+    group's leaves the children of its wide node."""
     if max_leaves not in (8, 16, 32, 64):
         raise ValueError(f"treelet size {max_leaves}: want 8, 16, 32 or 64")
     lo, hi = tree["leaf_lo"], tree["leaf_hi"]
     is_root = _first_fitting(lo, hi, max_leaves)
     is_group = _first_fitting(lo, hi, WIDE)
-    in_top = (hi - lo > max_leaves) | is_root
     n_treelets = int(is_root.sum())
-    if n_treelets + 1 >= 1 << 15 or int(in_top.sum()) >= 1 << 16:
-        raise ValueError("the resident top outgrows its 16-bit links")
     treelet = np.cumsum(is_root) - 1  # of a root, and of every node under it
-    tops = np.flatnonzero(in_top)
-    # skip of a top node, in top numbering: the top nodes before its target
-    top_skip = np.cumsum(in_top)[tree["skip"][tops] - 1]
-    top_meta = (
-        top_skip | np.where(is_root[tops], treelet[tops] + 1, 0) << 16
-    ).astype(np.int32)
-    top_bounds = np.concatenate(
-        [tree["bounds_min"][tops], tree["bounds_max"][tops]], axis=1
-    ).reshape(-1).astype(np.float32)
+    top_boxes, top_links = _wide_top(tree, is_root, treelet)
 
     # Wide nodes. Groups and leaves come in preorder, which is left to
     # right: a group's place under its root and a leaf's under its group
@@ -831,7 +908,7 @@ def partition_treelets(tree: dict, max_leaves: int = TREELET_LEAVES) -> dict:
     assert group_child.max() < max_leaves // WIDE and leaf_child.max() < WIDE
     # [treelet, child, wide node, 8 words]: an empty slot's box is
     # inverted and its bit 0, so no mask holds it.
-    wide = np.zeros((n_treelets, WIDE, 128 // 8, 8), np.float32)
+    wide = np.zeros((n_treelets, WIDE, TILE_NODES, 8), np.float32)
     wide[..., 0:3], wide[..., 3:6] = INF, -INF
     for treelets, child, node, members in (
         (group_treelet, group_child, 0, groups),
@@ -854,53 +931,76 @@ def partition_treelets(tree: dict, max_leaves: int = TREELET_LEAVES) -> dict:
     ).reshape(n_treelets, max_leaves * 2, 128)
     return dict(
         tri=np.concatenate([tri, wide.reshape(n_treelets, WIDE, 128)], axis=1),
-        top_bounds=top_bounds, top_meta=top_meta,
+        top_boxes=top_boxes, top_links=top_links,
         root=np.stack([tree["bounds_min"][0], tree["bounds_max"][0]])[None],
-        top_first=np.array([0, top_meta.shape[0]], np.int32),
+        top_first=np.array([0, top_boxes.shape[0]], np.int32),
     )
-
-
-# What the resident tops of a scene's BLASes may take of a core's 1 MiB
-# of SMEM, at six bounds and one meta word a node: the bounce kernel's
-# other SMEM operands and scratch are a few kilobytes, and the compiler
-# refuses the launch at 1.00 MiB (1.13 MiB asked for at 40,000 nodes).
-TOP_SMEM_BUDGET = 896 << 10
-TOP_NODE_BYTES = 7 * 4
 
 
 def join_treelet_tables(models: list[dict]) -> dict:
     """Several ``partition_treelets`` results as one set of tables: the
-    slabs and the tops end to end, a later model's skip links and treelet
-    numbers moved past the earlier models' nodes and slabs. The tables of
-    one model come back as they are."""
-    nodes = np.cumsum([0] + [m["top_meta"].shape[0] for m in models])
+    slabs and the tops end to end, a later model's links and treelet
+    numbers moved past the earlier models' wide nodes and slabs. The
+    tables of one model come back as they are."""
+    nodes = np.cumsum([0] + [m["top_links"].shape[0] // WIDE for m in models])
     slabs = np.cumsum([0] + [m["tri"].shape[0] for m in models])
-    if slabs[-1] + 1 >= 1 << 15 or nodes[-1] >= 1 << 16:
-        raise ValueError(
-            f"{nodes[-1]} top nodes over {slabs[-1]} treelets: the resident "
-            "top outgrows its 16-bit links"
-        )
-    if int(nodes[-1]) * TOP_NODE_BYTES > TOP_SMEM_BUDGET:
-        raise ValueError(
-            f"a resident top of {nodes[-1]} nodes "
-            f"({int(nodes[-1]) * TOP_NODE_BYTES} B) does not fit SMEM "
-            f"({TOP_SMEM_BUDGET} B)"
-        )
     if len({m["tri"].shape[1] for m in models}) != 1:
         raise ValueError("the BLASes of one set share one treelet size")
-    top_meta = np.concatenate([
-        m["top_meta"] + np.where(
-            m["top_meta"] >> 16 > 0, int(slab) << 16, 0
-        ).astype(np.int32) + np.int32(node)
+    top_links = np.concatenate([
+        m["top_links"] + np.where(
+            m["top_links"] > 0, slab, np.where(m["top_links"] < 0, -node, 0)
+        ).astype(np.int32)
         for m, node, slab in zip(models, nodes, slabs)
     ])
     return dict(
         tri=np.concatenate([m["tri"] for m in models]),
-        top_bounds=np.concatenate([m["top_bounds"] for m in models]),
-        top_meta=top_meta,
+        top_boxes=np.concatenate([m["top_boxes"] for m in models]),
+        top_links=top_links,
         root=np.concatenate([m["root"] for m in models]),
         top_first=nodes.astype(np.int32),
     )
+
+
+# What the boxes of a scene's resident tops may take of VMEM while a bounce
+# kernel runs, at 8 x 8 words a wide node: 16,384 wide nodes, about 114,000
+# treelets, 117 million triangles (7.9 GB of slabs, half the chip's HBM).
+# Their links, 8 words a node, are then 512 KiB of a core's 1 MiB of SMEM.
+# Tried at the compiler: a launch over a top of this size compiles
+# (tests/test_bringup.py), and so does one of 28,672 wide nodes (7 MiB of
+# boxes, 896 KiB of links); at 32,768 the links and the kernel's other
+# scalar operands are 1.06 MiB and the launch is refused for SMEM. So the
+# budget is half of what the compiler was seen to take, and past it the
+# links bind before the boxes do.
+TOP_VMEM_BUDGET = 4 << 20
+TOP_NODE_BYTES = WIDE * 8 * 4
+
+
+def blas_stream(tables: dict) -> BlasStream:
+    """``partition_treelets``' or ``join_treelet_tables``' host tables as
+    the bounce kernel's operands on the device. Every streamed build
+    passes here, so here a top past ``TOP_VMEM_BUDGET`` is refused and
+    the top's ``[NW, child, 8 words]`` boxes are laid in ``[8, 128]``
+    tiles of sixteen (``BlasStream.top_boxes``): child down the sublanes,
+    wide node ``w`` at lanes ``8 (w % 16) ..`` of tile ``w // 16``, the
+    last tile's spare nodes empty slots (inverted boxes, no bit)."""
+    boxes = tables["top_boxes"]
+    if boxes.shape[0] * TOP_NODE_BYTES > TOP_VMEM_BUDGET:
+        raise ValueError(
+            f"a resident top of {boxes.shape[0]} wide nodes "
+            f"({boxes.shape[0] * TOP_NODE_BYTES} B of boxes) does not fit "
+            f"its share of VMEM ({TOP_VMEM_BUDGET} B)"
+        )
+    tiles = -(-boxes.shape[0] // TILE_NODES)
+    padded = np.zeros((tiles * TILE_NODES, WIDE, 8), np.float32)
+    padded[..., 0:3], padded[..., 3:6] = INF, -INF
+    padded[:boxes.shape[0]] = boxes
+    tiled = padded.reshape(tiles, TILE_NODES, WIDE, 8).transpose(
+        0, 2, 1, 3
+    ).reshape(tiles * WIDE, 128)
+    return BlasStream(**{
+        name: jnp.asarray(value)
+        for name, value in {**tables, "top_boxes": tiled}.items()
+    })
 
 
 def morton_bvh(
@@ -921,7 +1021,7 @@ def morton_bvh(
         stream = None
         if streamed or treelet_leaves is not None:
             tables = partition_treelets(tree, treelet_leaves or TREELET_LEAVES)
-            stream = BlasStream(**{k: jnp.asarray(v) for k, v in tables.items()})
+            stream = blas_stream(tables)
         if not streamed:
             fields = {name: jnp.asarray(value) for name, value in fields.items()}
         return MeshBVH(**fields, stream=stream)
@@ -973,7 +1073,7 @@ def morton_bvh_set(
             **triangles,
             bounds_min=joined["root"][:, 0], bounds_max=joined["root"][:, 1],
             skip=None, first=None, count=None,
-            stream=BlasStream(**{k: jnp.asarray(v) for k, v in joined.items()}),
+            stream=blas_stream(joined),
             tri_first=np.cumsum(
                 [0] + [t["v0"].shape[0] for t in trees]
             ).astype(np.int32),

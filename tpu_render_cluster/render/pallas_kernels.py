@@ -894,9 +894,9 @@ def _instance_table(rotation, translation, scale, bounds_min, bounds_max,
     Over streamed BLASes (``top_first``: ``mesh.BlasStream.top_first``)
     row ``m`` of ``bounds_min`` / ``bounds_max`` is model ``m``'s root
     box, instance ``k`` is model ``model[k]`` (None: model 0), and the
-    table is [K, 24]: columns 22 and 23 are the node of the resident top
-    where the instance's walk begins and the node at which it is done
-    (whole numbers under 65,536, exact in f32).
+    table is [K, 23]: column 22 is the wide node of the resident top
+    where the instance's walk begins, its model's root (a whole number
+    far under 2**24, exact in f32).
     """
     k = rotation.shape[0]
 
@@ -915,17 +915,14 @@ def _instance_table(rotation, translation, scale, bounds_min, bounds_max,
         return jnp.concatenate([center_w - half_w, center_w + half_w], axis=1)
 
     box = world_box(0)  # the root node
-    top_range = []
+    top_root = []
     if top_first is not None:
         if model is None:
             model = jnp.zeros((k,), jnp.int32)
         # one box per model by the one expression, each instance its own's
         for m in range(1, top_first.shape[0] - 1):
             box = jnp.where((model == m)[:, None], world_box(m), box)
-        top_range = [
-            top_first[model].astype(jnp.float32)[:, None],
-            top_first[model + 1].astype(jnp.float32)[:, None],
-        ]
+        top_root = [top_first[model].astype(jnp.float32)[:, None]]
     if albedo is None:
         albedo = jnp.zeros((k, 3), jnp.float32)
     return jnp.concatenate(
@@ -935,7 +932,7 @@ def _instance_table(rotation, translation, scale, bounds_min, bounds_max,
             (1.0 / scale)[:, None],
             box,
             albedo,
-            *top_range,
+            *top_root,
         ],
         axis=1,
     )
@@ -1055,12 +1052,13 @@ def _mesh_trace_kernel_factory(
 
     ``stream`` = the leaf slots of a treelet makes the BLAS operands HBM
     tables (``mesh.BlasStream``: one BLAS or a set of them) in place of the
-    resident triangle and node blocks: the walk runs over the resident top
-    of the instance's tree, whose node range the instance table carries,
-    and copies a treelet's slab (triangle rows and wide nodes) into one
-    of two scratch slots, one treelet ahead of the one the packet walks
-    inside (``stream_walk``): a wide node's eight child boxes in one
-    ``[8, block]`` test (``slab_mask``), a leaf's box in its group's. The
+    resident triangle and node blocks: the walk runs over the wide nodes
+    of the resident top of the instance's tree, whose root the instance
+    table carries, and copies a treelet's slab (triangle rows and wide
+    nodes) into one of two scratch slots, one treelet ahead of the one the
+    packet walks inside (``stream_walk``): top or treelet, a wide node's
+    eight child boxes in one ``[8, block]`` test (``slab_mask``), a
+    treelet's box in its parent's and a leaf's in its group's. The
     leaves come in the resident walk's order over the same tree, the
     boxes, the triangles and the arithmetic on them are the same, and a
     wide test culls with the best-t it had before its children ran: it
@@ -1095,12 +1093,13 @@ def _mesh_trace_kernel_factory(
              albedo_ref, emission_ref, dcsun_ref, params_ref, sunsm_ref,
              inst_ref) = take(13)
         if stream is not None:
-            # The BLAS in HBM and its resident top; scratch comes last:
-            # two slots for staged treelets, which treelet each holds,
-            # and a semaphore a slot.
-            (tri_hbm, topb_ref, topm_ref) = take(3)
-            (tri_buf, staged_ref, dma_sem) = refs[-3:]
-            del refs[-3:]
+            # The BLAS in HBM and its resident top (wide nodes: boxes in
+            # VMEM, links in SMEM); scratch comes last: two slots for
+            # staged treelets, which treelet each holds, a semaphore a
+            # slot, and the top walk's stack.
+            (tri_hbm, top_ref, link_ref) = take(3)
+            (tri_buf, staged_ref, dma_sem, stack_ref) = refs[-4:]
+            del refs[-4:]
         else:
             (v0_ref, e1_ref, e2_ref, nrm_ref) = take(4)
             if quant:
@@ -1405,10 +1404,6 @@ def _mesh_trace_kernel_factory(
             leaf_slots = stream
             no_counts = (jnp.int32(0),) * len(WALK_COUNTS)
 
-            def six(ref, node, stride):
-                base = node * stride
-                return tuple(ref[base + i] for i in range(6))
-
             def slab_copy(treelet, slot):
                 return pltpu.make_async_copy(
                     tri_hbm.at[treelet], tri_buf.at[slot], dma_sem.at[slot]
@@ -1451,37 +1446,39 @@ def _mesh_trace_kernel_factory(
                 k, touch, ox, oy, oz, invx, invy, invz, limit_of, on_leaf,
                 carry, stats,
             ):
-                """The threaded walk of instance ``k``'s BLAS over HBM
-                tables: its nodes of the resident top (from the instance
-                table's column 22 to its column 23, where the tree's last
-                skip link points), and under a top leaf the treelet it
-                names, staged and walked in place. The top is walked one
-                hit leaf ahead: the copy of the next treelet the packet
-                will enter is started before the current one is walked,
-                and waited for where that one is entered, so every copy
-                started is waited for once and none outlives the walk.
+                """The walk of instance ``k``'s BLAS over HBM tables: the
+                wide nodes of its resident top from its root (the instance
+                table's column 22) down, and under a treelet child the
+                treelet it names, staged and walked in place. The top is
+                walked one treelet ahead: the copy of the next treelet the
+                packet will enter is started before the current one is
+                walked, and waited for where that one is entered, so every
+                copy started is waited for once and none outlives the walk.
                 ``carry`` is the walk's tuple of [1, BR] rows,
                 ``limit_of(carry)`` the per-lane cull distance,
                 ``on_leaf(carry, rows)`` the update by a leaf's 16 staged
                 rows; ``stats`` = the walk's counts so far
                 (``WALK_COUNTS``). Returns (carry, stats)."""
                 width = len(carry)
-                done = inst_ref[k, 23].astype(jnp.int32)
+
+                def lowest(mask):
+                    """(The lowest set bit of ``mask``, its index): scalar
+                    arithmetic alone (the index by halving), no vector is
+                    waited for."""
+                    low = mask & -mask
+                    return low, (
+                        jnp.where((low & 0xAA) != 0, 1, 0)
+                        + jnp.where((low & 0xCC) != 0, 2, 0)
+                        + jnp.where((low & 0xF0) != 0, 4, 0)
+                    )
 
                 def children(mask, step, state):
                     """``step(child, state)`` for each set bit of ``mask``,
                     lowest first: a turn a child met, none for the others,
-                    and scalar arithmetic alone between turns (the bit's
-                    index by halving: no vector is waited for)."""
+                    and scalar arithmetic alone between turns."""
                     def turn(walk):
-                        mask = walk[0]
-                        low = mask & -mask
-                        child = (
-                            jnp.where((low & 0xAA) != 0, 1, 0)
-                            + jnp.where((low & 0xCC) != 0, 2, 0)
-                            + jnp.where((low & 0xF0) != 0, 4, 0)
-                        )
-                        return (mask ^ low, *step(child, tuple(walk[1:])))
+                        low, child = lowest(walk[0])
+                        return (walk[0] ^ low, *step(child, tuple(walk[1:])))
 
                     return jax.lax.while_loop(
                         lambda walk: walk[0] != 0, turn, (mask, *state)
@@ -1491,9 +1488,11 @@ def _mesh_trace_kernel_factory(
                     """The two levels of wide nodes of the treelet in
                     ``slot``: the root's children are its groups, a
                     group's its leaves, both in the binary tree's order.
-                    The root's own box is the top leaf's, tested already,
-                    by a limit no sharper than the one the root's test
-                    reads: a treelet the look-ahead found and the hits
+                    The root's own box was a child's of the top, tested
+                    already, by a limit no sharper than the one the root's
+                    test reads: a treelet found under an older limit (its
+                    parent's mask is made when the parent is entered, and
+                    the look-ahead runs a treelet early) that the hits
                     since have culled meets no group here. A leaf's rows
                     are tested whole: its padding rows are zero and meet
                     no ray. Returns (carry, leaves tested, groups
@@ -1530,45 +1529,71 @@ def _mesh_trace_kernel_factory(
                     )
                     return tuple(carry), leaves, groups
 
-                def find_next(node, limit, visits):
-                    """The scalar walk of the resident top from ``node`` to
-                    the next top leaf whose box the packet hits nearer
-                    than ``limit``. Returns (the node to go on from, that
-                    leaf's treelet or -1 where the walk is done, the
-                    steps counted)."""
-                    def top_step(find):
-                        node, _, visits = find
-                        meta = topm_ref[node]
-                        treelet = (meta >> 16) - 1
-                        hit_any = slab_any(
-                            six(topb_ref, node, 6), ox, oy, oz, invx, invy,
-                            invz, limit,
+                def next_child(depth):
+                    """Pop the next child the top walk is to meet: the
+                    lowest bit left of the deepest level's mask; a level
+                    with none left is dropped. Scalar loads, stores and
+                    arithmetic alone. Returns (the child's link, or 0
+                    where the stack is empty; the depth after)."""
+                    top = jnp.maximum(depth - 1, 0)
+                    live = depth > 0
+                    # an empty stack holds another walk's words or none
+                    wide = jnp.where(live, stack_ref[2 * top], 0)
+                    mask = jnp.where(live, stack_ref[2 * top + 1], 0)
+                    low, child = lowest(mask)
+                    stack_ref[2 * top + 1] = mask ^ low
+                    return (
+                        jnp.where(live, link_ref[wide * 8 + child], 0),
+                        depth - jnp.where(live & (mask == low), 1, 0),
+                    )
+
+                def find_next(link, depth, limit, visits):
+                    """The walk of the resident top from where it stands
+                    (``link``: the child to meet now, ``-1 - v`` for wide
+                    node ``v``; the stack's ``depth`` levels: the children
+                    left after it) to the next treelet the packet meets
+                    nearer than ``limit``. A wide child's eight boxes are
+                    tested at once, by the limit as it stands now, and
+                    what they leave is pushed as one mask: its later
+                    children are met under that limit, not their own
+                    moment's (never too sharp, so none is lost; a treelet
+                    a nearer hit has since culled is entered and meets no
+                    group, ``inside``). Returns (the depth, the treelet or
+                    -1 where the walk is done, the wide tests counted)."""
+                    def enter(find):
+                        link, depth, visits = find
+                        wide = -1 - link
+                        tile = top_ref[
+                            pl.ds(pl.multiple_of((wide >> 4) * 8, 8), 8), :
+                        ]
+                        mask = slab_mask(
+                            pltpu.roll(tile, (128 - (wide & 15) * 8) & 127, 1),
+                            ox, oy, oz, invx, invy, invz, limit,
                         )
-                        is_leaf = treelet >= 0
-                        next_node = jnp.where(
-                            hit_any & jnp.logical_not(is_leaf),
-                            node + 1, meta & 0xFFFF,
-                        )
+                        # a node none of whose children is met is pushed
+                        # above the stack's top, where the next push lands
+                        stack_ref[2 * depth] = wide
+                        stack_ref[2 * depth + 1] = mask
                         return (
-                            next_node,
-                            jnp.where(is_leaf & hit_any, treelet, -1),
+                            *next_child(depth + jnp.where(mask != 0, 1, 0)),
                             visits + 1,
                         )
 
-                    return jax.lax.while_loop(
-                        lambda find: (find[0] < done) & (find[1] < 0),
-                        top_step, (node, jnp.int32(-1), visits),
+                    link, depth, visits = jax.lax.while_loop(
+                        lambda find: find[0] < 0, enter,
+                        (link, depth, visits),
                     )
+                    return depth, link - 1, visits
 
                 def per_treelet(walk):
-                    node, treelet, slot, waits = walk[:4]
+                    depth, treelet, slot, waits = walk[:4]
                     carry = tuple(walk[4:4 + width])
                     (visits, fetches, leaf_tests, entries, group_tests,
                      prefetches) = walk[4 + width:]
                     # The look-ahead culls by the limit as it stands
                     # before this treelet is walked: never too sharp.
-                    node, ahead, visits = find_next(
-                        node, limit_of(carry), visits
+                    depth, ahead, visits = find_next(
+                        *next_child(depth), limit_of(carry), visits
                     )
                     ahead_slot, ahead_waits = start_treelet(ahead, slot)
 
@@ -1578,20 +1603,20 @@ def _mesh_trace_kernel_factory(
 
                     carry, leaves, groups = inside(slot, carry)
                     return (
-                        node, ahead, ahead_slot, ahead_waits, *carry,
+                        depth, ahead, ahead_slot, ahead_waits, *carry,
                         visits + 1 + groups + leaves, fetches + ahead_waits,
                         leaf_tests + leaves, entries + 1,
                         group_tests + groups, prefetches + ahead_waits,
                     )
 
-                node, treelet, visits = find_next(
-                    jnp.where(touch, inst_ref[k, 22].astype(jnp.int32), done),
-                    limit_of(carry), stats[0],
+                depth, treelet, visits = find_next(
+                    jnp.where(touch, -1 - inst_ref[k, 22].astype(jnp.int32), 0),
+                    jnp.int32(0), limit_of(carry), stats[0],
                 )
                 slot, waits = start_treelet(treelet, jnp.int32(1))
                 walk = jax.lax.while_loop(
                     lambda walk: walk[1] >= 0, per_treelet,
-                    (node, treelet, slot, waits, *carry, visits,
+                    (depth, treelet, slot, waits, *carry, visits,
                      stats[1] + waits, *stats[2:]),
                 )
                 return tuple(walk[4:4 + width]), tuple(walk[4 + width:])
@@ -2638,24 +2663,27 @@ def _mesh_bounce_io(
         (1, block), lambda i: (0, i), memory_space=pltpu.VMEM
     )
     if stream is not None:
-        from tpu_render_cluster.render.mesh import treelet_leaves
+        from tpu_render_cluster.render.mesh import TOP_LEVELS, treelet_leaves
 
         # The BLASes stay in HBM: the kernel copies a treelet's slab
         # (triangle rows, wide nodes) into one of this scratch's two slots
         # before a packet enters it, while the packet walks the treelet in
-        # the other. Only the trees' tops sit in SMEM for the whole launch
-        # (mesh.TOP_SMEM_BUDGET).
+        # the other. Only the trees' wide tops sit on the core for the
+        # whole launch: their boxes in VMEM, their links in SMEM
+        # (mesh.TOP_VMEM_BUDGET); the top walk's stack is a (wide node, bits
+        # left) a level.
         ordered = False
-        geometry_operands = (stream.tri, stream.top_bounds, stream.top_meta)
+        geometry_operands = (stream.tri, stream.top_boxes, stream.top_links)
         geometry_specs = [
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(stream.top_bounds.shape, flat, memory_space=pltpu.SMEM),
-            pl.BlockSpec(stream.top_meta.shape, flat, memory_space=pltpu.SMEM),
+            pl.BlockSpec(stream.top_boxes.shape, whole, memory_space=pltpu.VMEM),
+            pl.BlockSpec(stream.top_links.shape, flat, memory_space=pltpu.SMEM),
         ]
         scratch_shapes = [
             pltpu.VMEM((2, *stream.tri.shape[1:]), jnp.float32),
             pltpu.SMEM((2,), jnp.int32),
             pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2 * TOP_LEVELS,), jnp.int32),
         ]
         stream_shape = treelet_leaves(stream)
         stats_specs = [row_block]
