@@ -182,6 +182,14 @@ class Histogram(_Metric):
             series.min = min(series.min, value)
             series.max = max(series.max, value)
 
+    def expose(self, **labels: Any) -> None:
+        """The series with no observation yet: count and sum at 0 in every
+        export from here on (a counter's ``inc(0.0)``), so that a scrape
+        can tell "never happened" from "not counted"."""
+        key = _label_key(self.label_names, labels)
+        with self._lock:
+            self._series.setdefault(key, _HistogramSeries(len(self.buckets)))
+
     def series(self, **labels: Any) -> _HistogramSeries | None:
         key = _label_key(self.label_names, labels)
         with self._lock:

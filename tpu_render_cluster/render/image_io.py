@@ -3,12 +3,32 @@
 The ``#####`` placeholder convention matches the reference's render script
 (reference: scripts/render-timing-script.py:69-79): the run of ``#`` is
 replaced by the zero-padded frame number.
+
+Which format is written how (``write_image``; every one from the same
+[H, W, 3] uint8 pixels the frame program's tone map produced, 8 bits a
+channel, RGB, nothing between device and disk quantised again but by a
+lossy format's own encoder):
+
+- ``PNG``: Pillow's encoder at its default compression, zlib level 6, no
+  ``optimize``: lossless, the file decodes to the pixels bit for bit. What
+  the source's demo jobs and every tile intermediate write. Several
+  times the bytes and the host time of the same frame's JPEG (PERF.md §5,
+  `04vs-1w-png` beside `04vs-1w-coarse`);
+- ``JPEG`` (``JPG`` is the same): quality 90, as the reference's render
+  script sets it; Pillow's default 4:2:0 chroma subsampling;
+- ``BMP``, ``TIFF``: Pillow's defaults (uncompressed);
+- anything else: **written as PNG under a ``.png`` name**, silently. The
+  fall-back is a PNG file in every respect, and is counted as one.
+
+``tests/test_steps.py::test_write_image_writes_the_parents_bytes`` holds the
+first two byte for byte.
 """
 
 from __future__ import annotations
 
 import re
 from pathlib import Path
+from typing import NamedTuple
 
 import numpy as np
 
@@ -72,11 +92,37 @@ def output_path_for_tile(
     )
 
 
-def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> None:
-    """Write a [H, W, 3] uint8 array; falls back to PNG for unknown formats.
+def written_format(file_format: str) -> str:
+    """The format ``write_image`` writes for a job's ``file_format``: its
+    upper case, ``JPG`` as ``JPEG``, and ``PNG`` for one it does not know."""
+    image_format = file_format.upper()
+    if image_format == "JPG":
+        image_format = "JPEG"
+    return image_format if image_format in _FORMAT_EXTENSIONS else "PNG"
+
+
+# The formats ``written_format`` can answer: the label values of
+# ``worker_frame_file_bytes_total{format}``.
+WRITTEN_FORMATS = tuple(sorted({written_format(name) for name in _FORMAT_EXTENSIONS}))
+
+
+class WrittenImage(NamedTuple):
+    """What one ``write_image`` call turned into what."""
+
+    image_format: str  # of WRITTEN_FORMATS
+    pixel_bytes: int  # the raw u8 bytes handed to the encoder
+    file_bytes: int  # the encoder's bytes, all of them renamed into place
+
+
+def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> WrittenImage:
+    """Write a [H, W, 3] uint8 array in ``written_format(file_format)``:
+    PNG at Pillow's default compression (zlib level 6), JPEG at quality
+    90, and PNG for a format nobody here knows (the module's docstring).
 
     Two frame steps (obs.step): ``encode`` turns the pixels into the
-    format's bytes in memory, ``file_write`` puts them on disk.
+    format's bytes in memory, ``file_write`` puts them on disk. Returns
+    the bytes that went into ``encode`` and came out of it: the worker's
+    queue counts them and writes them on the two steps' events.
 
     Atomic (write-temp-then-rename): a reader never sees a torn file.
     Load-bearing for tile assembly — a duplicate assignment of the same
@@ -92,13 +138,10 @@ def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> Non
 
     from tpu_render_cluster.obs import step
 
-    image_format = file_format.upper()
-    if image_format == "JPG":
-        image_format = "JPEG"
-    if image_format not in _FORMAT_EXTENSIONS:
-        image_format = "PNG"
+    image_format = written_format(file_format)
     with step("encode"):
-        image = Image.fromarray(np.asarray(pixels))
+        pixels = np.asarray(pixels)
+        image = Image.fromarray(pixels)
         encoded = io.BytesIO()
         if image_format == "JPEG":
             # reference script: quality=90
@@ -120,3 +163,4 @@ def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> Non
             except OSError:
                 pass
             raise
+    return WrittenImage(image_format, pixels.nbytes, encoded.getbuffer().nbytes)

@@ -35,6 +35,12 @@ class FrameRenderTime:
     steps: tuple[tuple[str, float, float], ...] = field(
         default=(), compare=False, repr=False
     )
+    # What the save stage turned into what, as ``write_image`` returned it:
+    # (written format, raw pixel bytes, file bytes). Worker-local like the
+    # steps; None from a backend that writes no image itself.
+    saved: tuple[str, int, int] | None = field(
+        default=None, compare=False, repr=False
+    )
 
     def total_execution_time(self) -> float:
         duration = self.exited_process_at - self.started_process_at

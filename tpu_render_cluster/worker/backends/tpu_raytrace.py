@@ -689,7 +689,7 @@ class TpuRaytraceBackend(RenderBackend):
                         tile,
                         job.tile_grid,
                     )
-            write_image(
+            saved = write_image(
                 path, pixels, "PNG" if tile is not None else job.output_file_format
             )
         file_saving_finished_at = time.time()
@@ -706,4 +706,5 @@ class TpuRaytraceBackend(RenderBackend):
             file_saving_finished_at=file_saving_finished_at,
             exited_process_at=time.time(),
             steps=(*device_steps, *save_steps),
+            saved=saved,
         )

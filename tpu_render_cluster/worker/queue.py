@@ -94,6 +94,25 @@ LOOP_STATES = ("no_work", "render_call", "report", "save_wait")
 SAVE_STEPS = ("encode", "file_write")
 DEVICE_TRACKS = (("frames", "steps"), ("frames, second on device", "steps, second on device"))
 
+# ``held``: the one stretch of a frame's life that is no step and no phase.
+# From the end of its ``readback`` (``finished_rendering_at``: its pixels
+# are on the host) to the start of its save stage
+# (``file_saving_started_at``), WHERE THE FRAME BEFORE WAS STILL SAVING when
+# the pixels arrived; 0 where the save slot was free by then (the hand-over
+# to the save thread and the wait behind the next frame's dispatch are the
+# loop's ``report`` and no hold). One observation a frame
+# (worker_frame_held_seconds) and, where it is not 0, one span. A frame is
+# held under the ``write`` of the frame before it, and where two frames
+# are on the device the frame behind it is held at the same time, so the
+# spans have two tracks of their own, taken in turn.
+HELD_TRACKS = ("held", "held, second frame")
+
+# The label values of worker_frame_file_bytes_total{format}, each at 0 from
+# the worker's start: what render/image_io.py::written_format can answer
+# (written out here because importing the render package imports JAX, and a
+# worker with another backend never does; a test holds the two equal).
+FILE_FORMATS = ("BMP", "JPEG", "PNG", "TIFF")
+
 
 class FrameState(enum.Enum):
     QUEUED = "queued"
@@ -130,6 +149,11 @@ class QueuedFrame:
     issued_ahead: bool = False
     device_track: int = 0
     saved_beside_render: bool = False
+    # Wall time at which the save slot became free for this frame (the
+    # frame before it was taken in, or nothing had saved yet), and which
+    # of HELD_TRACKS its hold is drawn on.
+    save_free_at: float = 0.0
+    held_track: int = 0
 
     @property
     def unit(self) -> WorkUnit:
@@ -234,6 +258,33 @@ class WorkerAutomaticQueue:
             if metrics is not None
             else None
         )
+        self._held_histogram = (
+            metrics.histogram(
+                "worker_frame_held_seconds",
+                "Per frame, from its pixels on the host (end of readback) "
+                "to the start of its save stage where the frame before was "
+                "still saving; 0 where the save slot was free",
+            )
+            if metrics is not None
+            else None
+        )
+        self._pixel_bytes = (
+            metrics.counter(
+                "worker_frame_pixel_bytes_total",
+                "Raw u8 pixel bytes handed to the encode step",
+            )
+            if metrics is not None
+            else None
+        )
+        self._file_bytes = (
+            metrics.counter(
+                "worker_frame_file_bytes_total",
+                "Encoded bytes renamed into place, by the format written",
+                labels=("format",),
+            )
+            if metrics is not None
+            else None
+        )
         if metrics is not None:
             # Exposed at 0 from the start: a scrape that finds no series
             # could not tell "never happened" from "not counted".
@@ -241,6 +292,10 @@ class WorkerAutomaticQueue:
             self._issued_ahead.inc(0.0)
             for state in LOOP_STATES:
                 self._loop_seconds.inc(0.0, state=state)
+            self._held_histogram.expose()
+            self._pixel_bytes.inc(0.0)
+            for image_format in FILE_FORMATS:
+                self._file_bytes.inc(0.0, format=image_format)
         # The save stage's thread: one, so that at most one frame is
         # saving; idle until the backend hands back a RenderedFrame.
         self._saver = ThreadPoolExecutor(max_workers=1, thread_name_prefix="frame-save")
@@ -258,6 +313,10 @@ class WorkerAutomaticQueue:
         # and the frame in its save stage.
         self._on_device: deque[_DeviceFrame] = deque()
         self._saving: _SavingFrame | None = None
+        # Wall time since which no frame is saving (``held`` above), and
+        # how many saves have begun (the holds' tracks, in turn).
+        self._save_free_at = 0.0
+        self._saves_begun = 0
         self._loop_state: str | None = None
         self._loop_state_since = time.perf_counter()
         self._startup = get_startup()
@@ -433,6 +492,7 @@ class WorkerAutomaticQueue:
             # stage: finished events leave in the order of the frames.
             if self._saving is not None and self._saving.future.done():
                 saved, self._saving = self._saving, None
+                self._save_free_at = time.time()
                 await self._report(saved.frame, _outcome(saved.future))
                 continue
             # The oldest frame on the device is the only one looked at:
@@ -526,6 +586,9 @@ class WorkerAutomaticQueue:
         gate = threading.Event()
         # a frame issued behind this one is in its device stage already
         frame.saved_beside_render = bool(self._on_device)
+        frame.save_free_at = self._save_free_at
+        frame.held_track = self._saves_begun % len(HELD_TRACKS)
+        self._saves_begun += 1
 
         def save() -> FrameRenderTime:
             gate.wait()
@@ -593,7 +656,8 @@ class WorkerAutomaticQueue:
         it has a track of its own (``saves``), and so have the save
         stage's steps (``save steps``); a frame issued behind an
         uncollected one has its device stage on the second of
-        ``DEVICE_TRACKS``.
+        ``DEVICE_TRACKS``; its hold (``HELD_TRACKS``) lies between its
+        ``render`` and its ``write``.
         """
         if self._metrics is None and self._span_tracer is None:
             return
@@ -642,10 +706,36 @@ class WorkerAutomaticQueue:
                         track=track,
                         args=flow_args,
                     )
-        # The frame's steps enter the registry and the timeline together
-        # with its phases, so a scrape never sees half a frame. A category
-        # and tracks of their own: readers of the phase spans (cat
-        # "worker") see the spans they always saw.
+        # The frame's steps, its hold and its bytes enter the registry and
+        # the timeline together with its phases, so a scrape never sees
+        # half a frame. A category and tracks of their own: readers of the
+        # phase spans (cat "worker") see the spans they always saw.
+        held = 0.0
+        if frame.save_free_at > timing.finished_rendering_at:
+            held = max(0.0, timing.file_saving_started_at - timing.finished_rendering_at)
+        if self._held_histogram is not None:
+            self._held_histogram.observe(held)
+        if held > 0.0 and self._span_tracer is not None:
+            self._span_tracer.complete(
+                "held",
+                cat="worker.step",
+                start_wall=timing.finished_rendering_at,
+                duration=held,
+                track=HELD_TRACKS[frame.held_track],
+                args={"frame": frame.frame_index},
+            )
+        step_bytes = {}
+        if timing.saved is not None:
+            image_format, pixel_bytes, file_bytes = timing.saved
+            if self._metrics is not None:
+                self._pixel_bytes.inc(pixel_bytes)
+                self._file_bytes.inc(file_bytes, format=image_format)
+            # what each save step took in and gave out: the encoder's
+            # bytes are all written, and all renamed into place
+            step_bytes = {
+                "encode": {"bytes_in": pixel_bytes, "bytes_out": file_bytes},
+                "file_write": {"bytes_in": file_bytes, "bytes_out": file_bytes},
+            }
         for name, start_wall, seconds in timing.steps:
             if self._step_histogram is not None:
                 self._step_histogram.observe(seconds, step=name)
@@ -656,7 +746,7 @@ class WorkerAutomaticQueue:
                     start_wall=start_wall,
                     duration=seconds,
                     track="save steps" if name in SAVE_STEPS else steps_track,
-                    args={"frame": frame.frame_index},
+                    args={"frame": frame.frame_index, **step_bytes.get(name, {})},
                 )
         if self._metrics is not None:
             self._metrics.counter(
