@@ -13,10 +13,77 @@ rehearsal starts processes and stays where it is, outside tier-1, as the
 other cells' rehearsals do.
 """
 
+import os
+from pathlib import Path
+
+from benchmark.drivers import backlog
+from benchmark.lib import manifest
 from benchmark.tests.test_png_cell import (  # noqa: F401
+    CELL,
+    JPEG_CELL,
+    NEW_METRICS,
+    ROOT,
     test_the_cell_is_data_and_says_what_the_issue_says,
     test_the_check_reads_a_lossless_file_without_a_codec_and_tighter_than_jpegs,
     test_the_readers_give_nothing_for_a_program_without_the_series_and_the_value_with_them,
-    test_the_three_readers_are_data_and_read_the_series_the_issue_names,
-    test_the_two_04vs_one_worker_cells_differ_by_the_output_format_alone,
 )
+
+
+def test_the_two_04vs_one_worker_cells_differ_by_the_output_format_alone():
+    """The benchmark's case of this name, line for line, but for where it
+    holds PR 52's three metrics to be the last three of the cell's
+    (`test_the_three_readers_...` below has why): they are the three before
+    PR 53's one, in both cells."""
+    png, jpeg = manifest.load_cell(CELL, ROOT), manifest.load_cell(JPEG_CELL, ROOT)
+    assert png.traffic == jpeg.traffic
+    for kept in ("render", "frames", "workers", "trace_slice_s", "frame_range_from", "holds_frames_per_s", "reduced"):
+        assert png.config[kept] == jpeg.config[kept], kept
+    assert png.config["deployment"]["scene_family"] == jpeg.config["deployment"]["scene_family"] == "04_very-simple"
+    assert png.config["output"] == {"file_format": "PNG", "file_name_format": "rendered-######", "extension": ".png"}
+    assert jpeg.config["output"]["file_format"] == "JPEG" and jpeg.config["output"]["jpeg_quality"] == 90
+    # the guarantees: the JPEG configuration's three, word for word, and the fourth
+    assert {k: v for k, v in png.config["guarantees"].items() if k in jpeg.config["guarantees"]} == jpeg.config["guarantees"]
+    assert set(png.config["guarantees"]) - set(jpeg.config["guarantees"]) == {"the_file_is_the_programs_pixels"}
+    assert {"output_format_on_the_measuring_job", "bit_depth_and_compression", "render"} == set(png.config["assumed"])
+    # the same metrics, PR 52's three and PR 53's one in both
+    assert [m["name"] for m in png.per_layer] == [m["name"] for m in jpeg.per_layer]
+    assert [m["name"] for m in png.per_layer][-4:] == [*NEW_METRICS, "save_beside_save_frame_share"]
+    # the job files: one line of the job itself differs (its name and description besides)
+    png_lines, jpeg_lines = (
+        set((cell.config_dir / cell.config["job_template"]).read_text().splitlines()) for cell in (png, jpeg)
+    )
+    differing = {line.split(" = ")[0] for line in png_lines ^ jpeg_lines if not line.startswith("#")}
+    assert differing == {"job_name", "job_description", "output_file_format"}
+    with_seed = {}
+    for cell in (png, jpeg):
+        with_seed[cell.name] = backlog.render_job_file(cell, 5200001212, Path(os.devnull))
+    assert with_seed[CELL][1:] == with_seed[JPEG_CELL][1:]  # the same seed draws the same frames
+    assert with_seed[CELL][0] == "04vs_measuring_14400f-1w-png"
+
+
+def test_the_three_readers_are_data_and_read_the_series_the_issue_names():
+    """The benchmark's case of this name, line for line on `BENCHMARK.json`
+    as it is, but for its counts. That case holds PR 52's three entries to
+    be the LAST three of `per_layer` and the lists that name the JPEG cell
+    to be 14 + 3, and the driver has every PR put its new entry last: PR
+    53's `save_beside_save_frame_share` follows them and names the JPEG
+    cell too, so as committed those two lines fail, and only a `benchmark`
+    PR may edit the benchmark's file (PERF.md §7). What they were there to
+    hold is held here by the entries' places: the 68th to the 70th, after
+    `walk_top_tests_per_entry`, with nothing before them come or gone,
+    whatever later PRs append."""
+    benchmark = manifest.load_benchmark(ROOT)
+    entries = benchmark["per_layer"][67:70]
+    assert [m["name"] for m in entries] == list(NEW_METRICS)
+    assert benchmark["per_layer"][66]["name"] == "walk_top_tests_per_entry"
+    for entry in entries:
+        assert (entry["layer"], entry["moves"], entry["workloads"]) == ("result plane", "frames_per_s", [JPEG_CELL, CELL])
+        spec, directory = manifest.layer_metric_spec(entry["name"], ROOT)
+        assert spec["reader"] == "delta_ratio" and spec["from"] == "workers"
+        assert not (directory / f"{entry['name']}.py").exists(), "data, no reader code"
+    assert [(m["unit"], m["better"]) for m in entries] == [("MB/s", "higher"), ("ms", "lower"), ("%", "lower")]
+    # every list that named the JPEG cell at PR 52 names the new cell too, last; PR 53's does as well
+    named = [m for m in benchmark["per_layer"][:70] if JPEG_CELL in m.get("workloads", [])]
+    assert len(named) == 14 + 3 and all(m["workloads"][-1] == CELL for m in named)
+    later = [m for m in benchmark["per_layer"][70:] if JPEG_CELL in m.get("workloads", [])]
+    assert [m["name"] for m in later] == ["save_beside_save_frame_share"] and later[0]["workloads"][-1] == CELL

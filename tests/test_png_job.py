@@ -5,11 +5,14 @@ On the CPU at 64x64: the `tpu-raytrace` backend through the worker's own
 two-stage loop writes PNG files that decode bit for bit to the pixels the
 frame program returned, in render order, one finished event a file and none
 before its rename; the same frames as JPEG are the same image to q90's round
-trip. On fake stages that sleep, with the save six times the device stage:
-the loop's states add up to its wall time with `save_wait` the largest, the
-frames' `held` seconds agree with it where one frame is held at a time, at
-most three units are `rendering`, and a drain and a cancel in that state
-lose no frame and leave no temporary file. The series and spans this PR
+trip. On fake stages that sleep, with the save six times the device stage
+(the cell's proportions): six or seven frames save at once, a slot is always
+free, `save_wait` and the holds read 0 and the device sets the pace. With
+the saves, `SAVE_FRAMES` at once, still slower than the device: the loop's
+states add up to its wall time with `save_wait` the largest, the frames'
+`held` seconds agree with it where one frame is held at a time, at most
+`DEVICE_FRAMES + SAVE_FRAMES` units are `rendering`, and a drain and a cancel
+in that state lose no frame and leave no temporary file. The series and spans this PR
 adds (`worker_frame_pixel_bytes_total`, `worker_frame_file_bytes_total`,
 `worker_frame_held_seconds`, the `held` span, `bytes_in` / `bytes_out` on
 the save steps' events) are at 0 from the worker's start and pass the trace
@@ -36,10 +39,10 @@ from tpu_render_cluster.obs.prometheus import render_prometheus
 from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.render.image_io import WRITTEN_FORMATS, WrittenImage, write_image
 from tpu_render_cluster.worker.backends.mock import MockBackend
-from tpu_render_cluster.worker.queue import FILE_FORMATS, FrameState, HELD_TRACKS, LOOP_STATES
+from tpu_render_cluster.worker.queue import FILE_FORMATS, FrameState, HELD_TRACKS, LOOP_STATES, SAVE_FRAMES
 
 from tests.test_frame_pipeline import (
-    Driven, IssueAheadBackend, RecordingSender, TwoStageBackend, drive, make_job, render_all, until,
+    Driven, IssueAheadBackend, RecordingSender, TwoStageBackend, drive, make_job, most_rendering, render_all, until,
 )
 
 FRAMES = 5
@@ -122,7 +125,10 @@ def test_the_png_files_decode_bit_for_bit_to_the_pixels_the_frame_program_return
     assert all(event.result == pm.FRAME_QUEUE_ITEM_FINISHED_OK for event in finished)
     for frame, (there, beside) in sender.at_finish.items():
         assert there, f"finished event of frame {frame} left before its file was in place"
-        assert beside == names[:frame]  # the frames so far, whole; the next save begins behind this event
+        # the frames so far, whole; what else is there is a later frame's, whole or being written beside it
+        assert [name for name in beside if name in names[:frame]] == names[:frame]
+        later = set(names[frame:])
+        assert all(name in later or name.lstrip(".").split(".png.")[0] + ".png" in later for name in beside if name not in names[:frame])
     assert [trace.frame_index for trace in driven.traces._frame_render_traces] == list(range(1, FRAMES + 1))
     # the bytes are the stated encoder's at its stated level: Pillow's default, zlib level 6
     again = directory.parent / "again.png"
@@ -180,7 +186,7 @@ def test_write_image_says_what_it_wrote_and_the_queue_knows_every_format_it_can_
             assert image.format == image_format  # the fall-back is a PNG file, and is named as one
 
 
-# -- the loop with the save six times the device stage -----------------------------------
+# -- the loop with the save slower than the device stage ---------------------------------
 
 
 class WritesImages:
@@ -205,11 +211,14 @@ class TwoOnDevice(WritesImages, IssueAheadBackend):
 SAVE_BOUND = pytest.mark.parametrize(
     "make_backend,on_device", [(OneOnDevice, 1), (TwoOnDevice, 2)], ids=["one on the device", "two on the device"],
 )
-DEVICE_SECONDS, SAVE_SECONDS = 0.03, 0.18  # the PNG cell's proportions: save six times the device stage
+# the PNG cell's proportions (save six times the device stage: fewer saves at once than slots), and a
+# save that all the slots together cannot keep up with (three device stages a slot)
+DEVICE_SECONDS, PNG_SAVE_SECONDS, SAVE_SECONDS = 0.03, 0.18, 0.03 * SAVE_FRAMES * 3
+FULL = 2 * SAVE_FRAMES + 4  # frames that fill every slot twice over
 
 
-def save_bound(make_backend, directory: Path, **stages):
-    return make_backend(directory, dispatch_seconds=0.002, device_seconds=DEVICE_SECONDS, save_seconds=SAVE_SECONDS, **stages)
+def save_bound(make_backend, directory: Path, save_seconds: float = SAVE_SECONDS, **stages):
+    return make_backend(directory, dispatch_seconds=0.002, device_seconds=DEVICE_SECONDS, save_seconds=save_seconds, **stages)
 
 
 def whole_files(directory: Path) -> dict[int, np.ndarray]:
@@ -223,38 +232,62 @@ def whole_files(directory: Path) -> dict[int, np.ndarray]:
 
 
 @SAVE_BOUND
-def test_with_the_save_six_times_the_device_stage_the_states_add_up_and_save_wait_is_the_largest(
+def test_with_the_save_six_times_the_device_stage_the_saves_overlap_and_the_device_sets_the_pace(
     tmp_path, make_backend, on_device,
 ):
-    frames = 7
+    """What PR 53 is for: the cell's proportions no longer fill the pipeline."""
+    frames = 20
+    backend = save_bound(make_backend, tmp_path, PNG_SAVE_SECONDS)
+    driven = render_all(backend, frames)
+    by_state = {state: driven.counter("worker_loop_seconds_total", state=state) for state in LOOP_STATES}
+    assert sum(by_state.values()) == pytest.approx(driven.wall, abs=1e-6)
+    # (a slot is always free, or nearly: a loaded machine may keep the loop from its turn for two device stages)
+    assert by_state["save_wait"] < 0.1 * driven.wall and max(by_state, key=by_state.get) == "render_call"
+    # six device stages a save: five to seven frames saving at once
+    assert 4 <= backend.most_at_once("save") <= SAVE_FRAMES
+    assert on_device + 4 <= most_rendering(driven) <= on_device + SAVE_FRAMES
+    assert driven.counter("worker_frames_saved_beside_save_total") >= frames - 2
+    # the frames land at the device's pace, not the save's: one save at a time would take frames x 0.18 s
+    landed = backend.times("save_end")
+    assert landed[frames] - landed[1] < 0.5 * (frames - 1) * PNG_SAVE_SECONDS
+    held = driven.metrics.snapshot()["worker_frame_held_seconds"]["series"][""]
+    assert held["count"] == frames and held["sum"] < 0.1 * driven.wall  # no frame's pixels found every slot taken
+    assert [event.frame_index for event in driven.sender.finished()] == list(range(1, frames + 1))
+    assert sorted(whole_files(tmp_path)) == list(range(1, frames + 1))
+    assert driven.counter("worker_frame_pixel_bytes_total") == frames * 8 * 8 * 3
+
+
+@SAVE_BOUND
+def test_with_every_save_slot_taken_the_states_add_up_and_save_wait_is_what_the_saves_leave(
+    tmp_path, make_backend, on_device,
+):
+    frames = FULL
     backend = save_bound(make_backend, tmp_path)
     driven = render_all(backend, frames)
     by_state = {state: driven.counter("worker_loop_seconds_total", state=state) for state in LOOP_STATES}
     assert sum(by_state.values()) == pytest.approx(driven.wall, abs=1e-6)
-    assert max(by_state, key=by_state.get) == "save_wait"
-    # all but the first save (nothing ahead of it) have a frame waiting behind them for the difference
-    assert by_state["save_wait"] > (frames - 2) * (SAVE_SECONDS - DEVICE_SECONDS) * 0.9
-    assert by_state["save_wait"] > 0.5 * driven.wall
+    # the saves of a round began one device stage apart and end so: the second and the third round wait
+    # for the first save of the round before, less the time it took to start that round, and go on at the
+    # device's pace (`render_call`) as its slots come free one by one; the last save is `render_call`'s too
+    assert by_state["save_wait"] > 2 * (SAVE_SECONDS - (SAVE_FRAMES + 2) * DEVICE_SECONDS) * 0.8
+    assert by_state["save_wait"] > 0.25 * driven.wall and by_state["save_wait"] > by_state["report"] + by_state["no_work"]
     # order kept, every file whole, one finished event a file
     assert [event.frame_index for event in driven.sender.finished()] == list(range(1, frames + 1))
     assert sorted(whole_files(tmp_path)) == list(range(1, frames + 1))
-    # a frame is RENDERING from its rendering event to its finished event: the device's frames and the one saving
-    rendering = most = 0
-    for _, message, _ in driven.sender.sent:
-        rendering += 1 if isinstance(message, pm.WorkerFrameQueueItemRenderingEvent) else -1
-        most = max(most, rendering)
-    assert most == on_device + 1 <= 3 and backend.on_device() == on_device
-    # the hold: one observation a frame; the first frame found the save slot free
+    # the device's frames and the ones saving
+    assert most_rendering(driven) == on_device + SAVE_FRAMES and backend.on_device() == on_device
+    assert backend.most_at_once("save") == SAVE_FRAMES
+    # the hold: one observation a frame; the first SAVE_FRAMES frames found a save slot free
     held = driven.metrics.snapshot()["worker_frame_held_seconds"]["series"][""]
     assert held["count"] == frames and held["min"] == 0.0
     if on_device == 1:
         # one frame held at a time: the frames' held seconds are the loop's save_wait seconds, and the
-        # hand-over besides (the frame before taken in, the next frame's dispatch, the save thread's start)
-        assert by_state["save_wait"] <= held["sum"] <= by_state["save_wait"] + (frames - 1) * 0.05
+        # hand-over besides (the oldest save taken in, the next frame's dispatch, the save thread's start)
+        assert by_state["save_wait"] <= held["sum"] <= by_state["save_wait"] + (frames - SAVE_FRAMES) * 0.05
     else:
         # two at a time: the frame behind the held one is held under it, so up to twice
-        assert by_state["save_wait"] <= held["sum"] <= 2 * (by_state["save_wait"] + (frames - 1) * 0.05)
-        assert held["max"] > SAVE_SECONDS  # a frame that waited out the rest of one save and all of the next
+        assert by_state["save_wait"] <= held["sum"] <= 2 * (by_state["save_wait"] + (frames - SAVE_FRAMES) * 0.05)
+    assert held["max"] > 0.5 * SAVE_SECONDS  # a frame that waited out most of a save
     assert driven.counter("worker_frame_pixel_bytes_total") == frames * 8 * 8 * 3
     assert driven.counter("worker_frame_file_bytes_total", format="PNG") == sum(p.stat().st_size for p in tmp_path.iterdir())
 
@@ -263,7 +296,7 @@ def test_with_the_save_six_times_the_device_stage_the_states_add_up_and_save_wai
 def test_the_holds_lie_between_render_and_write_on_tracks_of_their_own_and_the_timeline_is_valid(
     tmp_path, make_backend, on_device,
 ):
-    frames = 6
+    frames = FULL
     driven = render_all(save_bound(make_backend, tmp_path / "frames"), frames)
     assert validate_trace_file(driven.tracer.export(tmp_path / "worker-test_trace-events.json")) == []
     tracks = {m["args"]["name"]: m["tid"] for m in driven.tracer.metadata_events() if m["name"] == "thread_name"}
@@ -271,15 +304,24 @@ def test_the_holds_lie_between_render_and_write_on_tracks_of_their_own_and_the_t
     holds = {e["args"]["frame"]: e for e in events if e["name"] == "held"}
     renders = {e["args"]["frame"]: e for e in events if e["name"] == "render"}
     writes = {e["args"]["frame"]: e for e in events if e["name"] == "write"}
-    assert set(holds) == set(range(2, frames + 1))  # every frame but the first waited for the save before it
+    # no frame of the first SAVE_FRAMES found every slot taken; the first frames of each later round did,
+    # and waited out most of a save (the frames behind them find a slot as their pixels arrive, or nearly)
+    assert set(holds) <= set(range(SAVE_FRAMES + 1, frames + 1))
+    waited_a_save = {frame for frame, hold in holds.items() if hold["dur"] > 0.25e6 * SAVE_SECONDS}
+    assert waited_a_save >= {first + n for first in (SAVE_FRAMES + 1, 2 * SAVE_FRAMES + 1) for n in range(on_device)}
     assert {e["tid"] for e in holds.values()} <= {tracks[name] for name in HELD_TRACKS}
     assert all(e["cat"] == "worker.step" for e in holds.values())
     for frame, hold in holds.items():
         # from the end of the frame's render to the start of its write, to the clocks' rounding
         assert abs(hold["ts"] - (renders[frame]["ts"] + renders[frame]["dur"])) < 2000.0
         assert abs(hold["ts"] + hold["dur"] - writes[frame]["ts"]) < 2000.0
-        # under the write of the frame before it: why it cannot lie on the `saves` track
-        assert hold["ts"] < writes[frame - 1]["ts"] + writes[frame - 1]["dur"] <= hold["ts"] + hold["dur"] + 2000.0
+        # it takes the slot of the frame SAVE_FRAMES ahead of it, and was held until that frame was taken in
+        ahead = writes[frame - SAVE_FRAMES]
+        assert writes[frame]["tid"] == ahead["tid"]
+        assert ahead["ts"] + ahead["dur"] <= hold["ts"] + hold["dur"] + 2000.0
+        if frame in waited_a_save:
+            # under that frame's write: why a hold cannot lie on a save slot's track
+            assert hold["ts"] < ahead["ts"] + ahead["dur"]
     for tid in {e["tid"] for e in holds.values()}:
         on_track = sorted((e["ts"], e["ts"] + e["dur"]) for e in holds.values() if e["tid"] == tid)
         assert all(later[0] >= earlier[1] - 20000.0 for earlier, later in zip(on_track, on_track[1:]))
@@ -296,16 +338,15 @@ async def until_save_wait(driven: Driven) -> None:
 @SAVE_BOUND
 def test_a_drain_in_save_wait_loses_no_frame_and_leaves_no_temporary_file(tmp_path, make_backend, on_device):
     backend = save_bound(make_backend, tmp_path)
-    job = make_job("drained-full", 8)
+    job = make_job("drained-full", FULL)
     returned = []
 
     async def body(driven: Driven) -> None:
-        for frame in range(1, 9):
+        for frame in range(1, FULL + 1):
             driven.queue.queue_frame(job, frame)
-        await until(lambda: 2 in backend.times("save_start"))  # the pipeline has been full for a frame
-        await until_save_wait(driven)
+        await until_save_wait(driven)  # every slot taken and a frame's pixels in hand
         in_hand = [f.frame_index for f in driven.queue._frames if f.state is FrameState.RENDERING]
-        assert len(in_hand) == on_device + 1
+        assert len(in_hand) == on_device + SAVE_FRAMES
         returned.extend(await driven.queue.drain())
         assert [event.frame_index for event in driven.sender.finished()] == list(range(1, in_hand[-1] + 1))
 
@@ -313,20 +354,19 @@ def test_a_drain_in_save_wait_loses_no_frame_and_leaves_no_temporary_file(tmp_pa
     finished = [event.frame_index for event in driven.sender.finished()]
     handed_back = [unit.frame_index for _, unit in returned]
     assert all(event.result == pm.FRAME_QUEUE_ITEM_FINISHED_OK for event in driven.sender.finished())
-    assert finished + handed_back == list(range(1, 9))  # every frame finished or handed back, in order, none twice
+    assert finished + handed_back == list(range(1, FULL + 1))  # every frame finished or handed back, in order, none twice
     assert sorted(whole_files(tmp_path)) == finished  # a file a finished frame, every one whole, nothing else
 
 
 @SAVE_BOUND
 def test_a_cancel_in_save_wait_loses_no_frame_and_leaves_no_temporary_file(tmp_path, make_backend, on_device):
     backend = save_bound(make_backend, tmp_path)
-    job = make_job("cancelled-full", 8)
+    job = make_job("cancelled-full", FULL)
     left = {}
 
     async def body(driven: Driven) -> None:
-        for frame in range(1, 9):
+        for frame in range(1, FULL + 1):
             driven.queue.queue_frame(job, frame)
-        await until(lambda: 2 in backend.times("save_start"))
         await until_save_wait(driven)
         left["frames"] = driven.queue._frames  # drive() joins the queue now: the loop's task is cancelled
 
@@ -337,14 +377,14 @@ def test_a_cancel_in_save_wait_loses_no_frame_and_leaves_no_temporary_file(tmp_p
         assert time.perf_counter() < deadline, "a stage's thread is still blocked"
         time.sleep(0.01)
     finished = [event.frame_index for event in driven.sender.finished()]
-    assert finished == list(range(1, len(finished) + 1)) and len(finished) >= 1
+    assert finished == list(range(1, len(finished) + 1))
     # every frame is either finished or still the queue's (queued, or cut in a stage): none is gone
     still_held = [f.frame_index for f in left["frames"]]
-    assert finished + still_held == list(range(1, 9))
-    # a save under way ended on its own, with its rename: whole files, those of the finished frames and of at
-    # most the one frame that was saving, and no temporary file
+    assert finished + still_held == list(range(1, FULL + 1))
+    # the saves under way ended on their own, each with its rename: whole files, those of the finished
+    # frames and of at most the SAVE_FRAMES frames that were saving, and no temporary file
     files = sorted(whole_files(tmp_path))
-    assert set(finished) <= set(files) and len(files) <= len(finished) + 1
+    assert set(finished) <= set(files) and len(finished) + 1 <= len(files) <= len(finished) + SAVE_FRAMES
     assert all(event.result == pm.FRAME_QUEUE_ITEM_FINISHED_OK for event in driven.sender.finished())
 
 

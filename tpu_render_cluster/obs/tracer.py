@@ -44,12 +44,14 @@ MAX_EVENTS = 300_000
 # THREAD (the sink below is thread-local): the first two are the frame's
 # issue on the worker queue's issue thread, the next two its collect on
 # the collect thread (together the device stage), the last two its save
-# stage on the save thread, and on any of them at most one step is open at
+# stage on a save thread, and on any of them at most one step is open at
 # an instant. Across threads they overlap: the worker's queue saves frame
 # i and issues frame i+2 while it waits for frame i+1 (worker/queue.py),
 # so frame i's ``encode`` and ``file_write`` and frame i+2's ``dispatch``
-# lie under frame i+1's ``device_wait``. A frame's own steps never overlap
-# each other and are handed over together, in the order they ended:
+# lie under frame i+1's ``device_wait``, and several frames' ``encode``
+# lie beside each other where a save outlasts a device stage. A frame's
+# own steps never overlap each other and are handed over together, in the
+# order they ended:
 #   resolve      scene name, tile region, unit shape, compiled-renderer fetch
 #   dispatch     host time issuing device work that does not block (asking
 #                for the copy back included)

@@ -17,7 +17,9 @@ class RenderedFrame:
     ``save`` is the frame's save stage (encode, temporary file, write,
     close, rename): a plain blocking call for the thread the worker's
     queue gives it, which returns the frame's seven points once the file
-    is in place. The queue runs it beside the NEXT frame's device stage.
+    is in place. The queue runs it beside the NEXT frames' device stages,
+    and beside the saves of the frames ahead of it that have not ended:
+    what a save touches besides its own frame is shared between threads.
     """
 
     save: Callable[[], FrameRenderTime]
@@ -44,9 +46,12 @@ class RenderBackend(abc.ABC):
     the host; the **save stage** ends with the file renamed into place.
     The worker's queue asks for the device stage (``render_device_stage``)
     and, where the backend hands back a ``RenderedFrame``, runs that
-    frame's save stage while the next frame is in its device stage: never
-    two frames saving. A backend with no separable save stage implements
-    ``render_frame`` alone and is asked for one whole frame at a time.
+    frame's save stage while the next frames are in their device stage:
+    up to ``worker/queue.py::SAVE_FRAMES`` frames saving at once, each on
+    a thread of its own, a second one only where a frame's pixels arrive
+    while a save is under way. A backend with no separable save stage
+    implements ``render_frame`` alone and is asked for one whole frame at
+    a time.
 
     A backend that can also part the device stage into **issue** (hand
     the device its work, return at once) and **collect** (wait, copy back)

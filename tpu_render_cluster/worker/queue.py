@@ -10,25 +10,33 @@ Three deliberate deviations:
 - a render failure emits ``event_frame-queue_item-finished`` with
   ``errored`` instead of silently dropping the frame (which would hang the
   reference master forever — worker/src/rendering/queue.rs:169-174);
-- **a frame is two stages, and up to three frames are in hand at a
-  time**: the device stage (to the pixels on the host) and the save stage
-  (encode, write, rename; ``worker/backends/base.py``). A frame's save
-  stage runs on a thread of its own beside the device stages of the
-  frames behind it, at most one frame saving. Where the backend parts the
-  device stage into issue and collect, up to ``DEVICE_FRAMES`` frames are
-  in it at once: a queued frame's device work is issued (on the issue
-  thread) as soon as fewer than two frames are issued and not yet handed
-  to their save, whether or not a wait for an earlier frame is under way
-  (on the collect thread), so the device finds frame *i+1* in its queue
-  the moment frame *i* ends. Frames are collected, saved and reported in
-  the order they were issued, so up to three units are ``RENDERING``. A
-  finished event still leaves only after its frame's file has been
-  renamed into place, and finished events leave in the order the frames
-  were rendered; the ``rendering`` events of frames *i+1* and *i+2* may
-  precede the finished event of frame *i*. A backend that cannot part
-  issue from collect has one frame in its device stage at a time, and one
-  with no separable save stage goes through the same loop one whole frame
-  at a time.
+- **a frame is two stages, and up to ``DEVICE_FRAMES + SAVE_FRAMES``
+  frames are in hand at a time**: the device stage (to the pixels on the
+  host) and the save stage (encode, write, rename;
+  ``worker/backends/base.py``). A frame's save stage runs on a save
+  thread beside the device stages of the frames behind it, and beside the
+  saves of the frames ahead of it where those have not ended: up to
+  ``SAVE_FRAMES`` frames are saving at once, each on a thread that is
+  started only when a save is handed over and no save thread is free (a
+  worker whose saves are shorter than its device stages has one). Where
+  the backend parts the device stage into issue and collect, up to
+  ``DEVICE_FRAMES`` frames are in it at once: a queued frame's device
+  work is issued (on the issue thread) as soon as fewer than two frames
+  are issued and not yet handed to their save, whether or not a wait for
+  an earlier frame is under way (on the collect thread), so the device
+  finds frame *i+1* in its queue the moment frame *i* ends. Frames are
+  collected, handed to their save and reported in the order they were
+  issued, so up to ``DEVICE_FRAMES + SAVE_FRAMES`` units are
+  ``RENDERING``. A finished event still leaves only after its frame's
+  file has been renamed into place, and finished events leave in the
+  order the frames were rendered: only the oldest saving frame is ever
+  taken in, and a save that ends before an earlier frame's waits its
+  turn (so FILES may appear on disk out of frame order, each whole:
+  ``write_image`` renames). The ``rendering`` events of the frames
+  behind frame *i* may precede its finished event. A backend that cannot
+  part issue from collect has one frame in its device stage at a time,
+  and one with no separable save stage goes through the same loop one
+  whole frame at a time.
 """
 
 from __future__ import annotations
@@ -64,25 +72,37 @@ FRAME_PHASES = ("queue_wait", "read", "render", "write")
 
 # Frames a backend that parts issue from collect may have in their device
 # stage at once: one running on the device and one in its queue behind
-# it. A constant of the loop, as "one frame saving" is.
+# it. A constant of the loop, as SAVE_FRAMES is.
 DEVICE_FRAMES = 2
 
+# Frames that may be in their save stage at once, each on a save thread of
+# its own: a save is handed over as soon as fewer than this many frames
+# are saving (a save that has ended and waits behind an earlier frame's
+# to be taken in still counts). Eight: a lossless 512x512 frame takes one
+# encoder 86 ms and the device 13 ms (ledger PR 52, `04vs-1w-png`), so
+# the device's pace needs six or seven encoders busy at once. Pixels in
+# hand are then at most DEVICE_FRAMES + SAVE_FRAMES frames'.
+SAVE_FRAMES = 8
+
 # The render loop's wall time, partitioned (worker_loop_seconds_total). The
-# loop is one coroutine with up to three frames in hand, and what it is
-# charged is what IT waits for or does, not what a frame costs:
+# loop is one coroutine with up to DEVICE_FRAMES + SAVE_FRAMES frames in
+# hand, and what it is charged is what IT waits for or does, not what a
+# frame costs:
 #   no_work      nothing queued and no stage in hand; waiting for the
 #                master (draining excluded)
 #   render_call  waiting for a backend stage with nothing else to start:
 #                a frame's device stage (thread hop included), or, with
-#                nothing queued, the save stage of the last frame. The
+#                nothing queued, the save stages of the last frames. The
 #                frames' steps (obs.FRAME_STEPS) lie in it; the save of
-#                frame i mostly under the device stage of frame i+1
+#                frame i mostly under the device stages of the frames
+#                behind it
 #   report       the loop's own work: the rendering/finished events, trace
 #                bookkeeping, feeding the phase and step series
 #   save_wait    the pipeline is full: a frame's device stage has returned
-#                and the frame before it is still in its save stage, so
-#                that frame's save cannot start, nor a queued frame take
-#                its place in the device stage (save slower than render)
+#                and SAVE_FRAMES frames are in their save stage, so that
+#                frame's save cannot start, nor a queued frame take its
+#                place in the device stage (the saves, all of them at
+#                once, slower than render)
 LOOP_STATES = ("no_work", "render_call", "report", "save_wait")
 
 # The steps of the save stage (of obs.FRAME_STEPS): they and the ``write``
@@ -90,19 +110,25 @@ LOOP_STATES = ("no_work", "render_call", "report", "save_wait")
 # frame i+1's device steps and a track's spans must not overlap. For the
 # same reason the device stage's phases and steps have two tracks each:
 # a frame issued while the frame before it was uncollected takes the
-# track that frame does not lie on.
+# track that frame does not lie on. And the save stage's have a pair of
+# tracks a save slot: a frame keeps the lowest slot no saving frame has
+# from the hand-over to the moment it is taken in, and its ``write`` lies
+# between the two.
 SAVE_STEPS = ("encode", "file_write")
 DEVICE_TRACKS = (("frames", "steps"), ("frames, second on device", "steps, second on device"))
+SAVE_TRACKS = (("saves", "save steps"),) + tuple(
+    (f"saves, slot {slot}", f"save steps, slot {slot}") for slot in range(2, SAVE_FRAMES + 1)
+)
 
 # ``held``: the one stretch of a frame's life that is no step and no phase.
 # From the end of its ``readback`` (``finished_rendering_at``: its pixels
 # are on the host) to the start of its save stage
-# (``file_saving_started_at``), WHERE THE FRAME BEFORE WAS STILL SAVING when
-# the pixels arrived; 0 where the save slot was free by then (the hand-over
-# to the save thread and the wait behind the next frame's dispatch are the
-# loop's ``report`` and no hold). One observation a frame
+# (``file_saving_started_at``), WHERE EVERY SAVE SLOT WAS TAKEN when the
+# pixels arrived; 0 where a slot was free by then (the hand-over to a save
+# thread and the wait behind the next frame's dispatch are the loop's
+# ``report`` and no hold). One observation a frame
 # (worker_frame_held_seconds) and, where it is not 0, one span. A frame is
-# held under the ``write`` of the frame before it, and where two frames
+# held under the ``write`` of the frames ahead of it, and where two frames
 # are on the device the frame behind it is held at the same time, so the
 # spans have two tracks of their own, taken in turn.
 HELD_TRACKS = ("held", "held, second frame")
@@ -145,13 +171,17 @@ class QueuedFrame:
     # What the loop saw of the frame's way through the stages: its device
     # work was issued while an earlier frame's had not been collected;
     # which of DEVICE_TRACKS its device stage is drawn on; a later frame's
-    # device stage was open while its save ran.
+    # device stage was open while its save ran; an earlier frame's save
+    # had not ended when its own was handed over, and which of SAVE_TRACKS
+    # (its save slot) its save stage is drawn on.
     issued_ahead: bool = False
     device_track: int = 0
     saved_beside_render: bool = False
-    # Wall time at which the save slot became free for this frame (the
-    # frame before it was taken in, or nothing had saved yet), and which
-    # of HELD_TRACKS its hold is drawn on.
+    saved_beside_save: bool = False
+    save_slot: int = 0
+    # Wall time since which a save slot was free for this frame (a frame
+    # was taken in while all of them were taken, or they never all were),
+    # and which of HELD_TRACKS its hold is drawn on.
     save_free_at: float = 0.0
     held_track: int = 0
 
@@ -173,12 +203,15 @@ class _DeviceFrame:
 
 @dataclass
 class _SavingFrame:
-    """A frame in its save stage."""
+    """A frame in its save stage, or past it and behind an earlier frame
+    that is not: it keeps its save slot until it is taken in."""
 
     frame: QueuedFrame
-    future: asyncio.Future  # the frame's FrameRenderTime, or what the save raised
+    # the frame's FrameRenderTime, or what the save raised (or, already
+    # there, what came of the device stage of a frame that has no save)
+    future: asyncio.Future
     # what the save waits behind: the next frame's dispatch, or nothing
-    gate: threading.Event
+    gate: threading.Event = field(default_factory=threading.Event)
 
 
 def _outcome(future: asyncio.Future) -> object:
@@ -191,8 +224,8 @@ def _outcome(future: asyncio.Future) -> object:
 
 class WorkerAutomaticQueue:
     """Two-stage render queue: up to two frames in their device stage (one
-    where the backend cannot issue ahead), the frame before them in its
-    save stage; woken by events, polled every 100 ms."""
+    where the backend cannot issue ahead), up to ``SAVE_FRAMES`` frames
+    before them in their save stage; woken by events, polled every 100 ms."""
 
     def __init__(
         self,
@@ -258,12 +291,21 @@ class WorkerAutomaticQueue:
             if metrics is not None
             else None
         )
+        self._saved_beside_save = (
+            metrics.counter(
+                "worker_frames_saved_beside_save_total",
+                "Frames whose save stage began while an earlier frame's "
+                "save had not ended",
+            )
+            if metrics is not None
+            else None
+        )
         self._held_histogram = (
             metrics.histogram(
                 "worker_frame_held_seconds",
                 "Per frame, from its pixels on the host (end of readback) "
-                "to the start of its save stage where the frame before was "
-                "still saving; 0 where the save slot was free",
+                "to the start of its save stage where every save slot was "
+                "taken; 0 where a save slot was free",
             )
             if metrics is not None
             else None
@@ -290,15 +332,18 @@ class WorkerAutomaticQueue:
             # could not tell "never happened" from "not counted".
             self._saved_beside_render.inc(0.0)
             self._issued_ahead.inc(0.0)
+            self._saved_beside_save.inc(0.0)
             for state in LOOP_STATES:
                 self._loop_seconds.inc(0.0, state=state)
             self._held_histogram.expose()
             self._pixel_bytes.inc(0.0)
             for image_format in FILE_FORMATS:
                 self._file_bytes.inc(0.0, format=image_format)
-        # The save stage's thread: one, so that at most one frame is
-        # saving; idle until the backend hands back a RenderedFrame.
-        self._saver = ThreadPoolExecutor(max_workers=1, thread_name_prefix="frame-save")
+        # The save stage's threads: a thread is started only when a save
+        # is handed over and none of them is free, so there is one where
+        # saves are shorter than device stages, and none until the backend
+        # hands back a RenderedFrame.
+        self._saver = ThreadPoolExecutor(max_workers=SAVE_FRAMES, thread_name_prefix="frame-save")
         # One thread that issues and one that collects, for a backend that
         # parts the two: each takes its frames in the queue's order, and a
         # frame is issued while the wait for the one before it blocks the
@@ -309,11 +354,11 @@ class WorkerAutomaticQueue:
         # (getattr: one that is no RenderBackend cannot part the two either)
         self._issue = getattr(backend, "issue_device_stage", None)
         self._device_frames = 1 if self._issue is None else DEVICE_FRAMES
-        # The frames in their device stage, in the order they were issued,
-        # and the frame in its save stage.
+        # The frames in their device stage and the frames in their save
+        # stage, each in the order they were issued.
         self._on_device: deque[_DeviceFrame] = deque()
-        self._saving: _SavingFrame | None = None
-        # Wall time since which no frame is saving (``held`` above), and
+        self._saving: deque[_SavingFrame] = deque()
+        # Wall time since which a save slot is free (``held`` above), and
         # how many saves have begun (the holds' tracks, in turn).
         self._save_free_at = 0.0
         self._saves_begun = 0
@@ -394,7 +439,7 @@ class WorkerAutomaticQueue:
         """Graceful drain: finish the frames in hand, hand back the rest.
 
         Stops the loop from starting new frames, waits for the ones in their
-        device stage and the one in its save stage to complete (their
+        device stage and the ones in their save stage to complete (their
         finished events go out normally), and returns the ``(job_name, frame_index)`` pairs that
         never started — the payload of the goodbye message the runtime
         sends so the master can requeue them without waiting for a
@@ -420,10 +465,11 @@ class WorkerAutomaticQueue:
         not-started frames belong to assignments the new master does not
         know about, so replaying them would render work nobody tracks.
         A frame currently RENDERING (in its device stage or in its save
-        stage: there may be two and one) is left to finish — its finished
-        event carries the OLD epoch and the new master refuses it as
-        stale, which is the fence working as designed. The already-
-        finished index is cleared too: the new master may legitimately
+        stage: there may be ``DEVICE_FRAMES`` and ``SAVE_FRAMES``) is left
+        to finish — its finished event carries the OLD epoch and the new
+        master refuses it as stale, which is the fence working as
+        designed. The already-finished index is cleared too: the new
+        master may legitimately
         re-assign a unit this worker rendered for the predecessor, and an
         ``already-finished`` answer to a later remove RPC would lie about
         the NEW assignment. Returns how many queued frames were dropped.
@@ -480,8 +526,8 @@ class WorkerAutomaticQueue:
             self._enter_loop_state(None)
             for in_stage in self._on_device:
                 in_stage.future.cancel()
-            if self._saving is not None:
-                self._saving.gate.set()  # no thread is left blocked behind it
+            for saving in self._saving:
+                saving.gate.set()  # no thread is left blocked behind it
 
     async def _run_loop(self) -> None:
         while not self._cancellation.is_cancelled():
@@ -489,31 +535,40 @@ class WorkerAutomaticQueue:
             # arrives from here on wakes the wait at the bottom.
             self._work_available.clear()
             # What has ended is taken in first, the save before the device
-            # stage: finished events leave in the order of the frames.
-            if self._saving is not None and self._saving.future.done():
-                saved, self._saving = self._saving, None
-                self._save_free_at = time.time()
+            # stage, and of the saving frames the oldest alone: finished
+            # events leave in the order of the frames, and a save that
+            # ended before an earlier frame's waits its turn.
+            if self._saving and self._saving[0].future.done():
+                if len(self._saving) == SAVE_FRAMES:
+                    self._save_free_at = time.time()
+                saved = self._saving.popleft()
                 await self._report(saved.frame, _outcome(saved.future))
                 continue
             # The oldest frame on the device is the only one looked at:
             # whatever came of the ones behind it waits its turn.
             rendered = bool(self._on_device) and self._on_device[0].future.done()
-            if rendered and self._saving is None:
+            if rendered and len(self._saving) < SAVE_FRAMES:
                 head = self._on_device.popleft()
                 outcome = _outcome(head.future)
                 if not isinstance(outcome, RenderedFrame):
-                    await self._report(head.frame, outcome)
+                    # No save to begin (the device stage raised, or gave
+                    # the whole frame): it is reported when its turn comes
+                    # behind the saving frames, and nothing is issued
+                    # ahead of that.
+                    ended = asyncio.get_running_loop().create_future()
+                    ended.set_result(outcome)
+                    self._enter_save_stage(_SavingFrame(head.frame, ended))
                     continue
                 # The hand-over, in this order: the next frame's device
                 # work is issued FIRST and this frame's save begins behind
                 # it (encoding holds the GIL the dispatch needs). With
                 # nothing queued there is nothing to wait behind.
-                self._saving = self._begin_save(head.frame, outcome)
+                saving = self._begin_save(head.frame, outcome)
                 next_frame = self._next_to_issue()
                 if next_frame is None:
-                    self._saving.gate.set()
+                    saving.gate.set()
                 else:
-                    await self._begin_device_stage(next_frame, self._saving.gate.set)
+                    await self._begin_device_stage(next_frame, saving.gate.set)
                 continue
             next_frame = self._next_to_issue()
             if next_frame is not None:
@@ -521,7 +576,7 @@ class WorkerAutomaticQueue:
                 continue
             if rendered:
                 self._enter_loop_state("save_wait")
-            elif self._on_device or self._saving is not None:
+            elif self._on_device or self._saving:
                 self._enter_loop_state("render_call")
             else:
                 # Fed at every poll, so a scrape is never more than one
@@ -546,8 +601,9 @@ class WorkerAutomaticQueue:
     async def _begin_device_stage(self, frame: QueuedFrame, dispatched) -> None:
         self._enter_loop_state("report")
         frame.state = FrameState.RENDERING
-        if self._saving is not None:
-            self._saving.frame.saved_beside_render = True
+        for saving in self._saving:
+            if not saving.future.done():
+                saving.frame.saved_beside_render = True
         if any(not earlier.future.done() for earlier in self._on_device):
             frame.issued_ahead = True
             frame.device_track = 1 - self._on_device[-1].frame.device_track
@@ -586,6 +642,7 @@ class WorkerAutomaticQueue:
         gate = threading.Event()
         # a frame issued behind this one is in its device stage already
         frame.saved_beside_render = bool(self._on_device)
+        frame.saved_beside_save = any(not ahead.future.done() for ahead in self._saving)
         frame.save_free_at = self._save_free_at
         frame.held_track = self._saves_begun % len(HELD_TRACKS)
         self._saves_begun += 1
@@ -596,7 +653,15 @@ class WorkerAutomaticQueue:
 
         future = asyncio.get_running_loop().run_in_executor(self._saver, save)
         future.add_done_callback(self._wake)
-        return _SavingFrame(frame, future, gate)
+        return self._enter_save_stage(_SavingFrame(frame, future, gate))
+
+    def _enter_save_stage(self, saving: _SavingFrame) -> _SavingFrame:
+        """Behind the saving frames, on the lowest save slot none of them
+        has (there is one: fewer than ``SAVE_FRAMES`` are saving)."""
+        taken = {ahead.frame.save_slot for ahead in self._saving}
+        saving.frame.save_slot = min(set(range(SAVE_FRAMES)) - taken)
+        self._saving.append(saving)
+        return saving
 
     async def _report(self, frame: QueuedFrame, outcome: object) -> None:
         """A frame's end: its file is in place (``outcome`` is its seven
@@ -630,6 +695,8 @@ class WorkerAutomaticQueue:
                 self._saved_beside_render.inc()
             if frame.issued_ahead:
                 self._issued_ahead.inc()
+            if frame.saved_beside_save:
+                self._saved_beside_save.inc()
         self._remove(frame)
         if frame.session == self._session_generation:
             # A frame queued under a PREVIOUS master session (failover hit
@@ -652,9 +719,10 @@ class WorkerAutomaticQueue:
         The spans reuse the 7-point wall-clock timestamps the backend
         already measured (the trace of record), so the Perfetto view and
         the legacy ``FrameRenderTime`` analysis agree exactly. A frame's
-        ``write`` lies under the next frame's ``read`` and ``render``, so
-        it has a track of its own (``saves``), and so have the save
-        stage's steps (``save steps``); a frame issued behind an
+        ``write`` lies under the next frame's ``read`` and ``render`` and
+        beside the ``write`` of other frames, so it has a track of its
+        save slot's (``SAVE_TRACKS``), and so have the save stage's
+        steps; a frame issued behind an
         uncollected one has its device stage on the second of
         ``DEVICE_TRACKS``; its hold (``HELD_TRACKS``) lies between its
         ``render`` and its ``write``.
@@ -662,6 +730,7 @@ class WorkerAutomaticQueue:
         if self._metrics is None and self._span_tracer is None:
             return
         frames_track, steps_track = DEVICE_TRACKS[frame.device_track]
+        saves_track, save_steps_track = SAVE_TRACKS[frame.save_slot]
         bounds = {
             "queue_wait": (frame.queued_at, timing.started_process_at),
             "read": (timing.started_process_at, timing.finished_loading_at),
@@ -681,7 +750,7 @@ class WorkerAutomaticQueue:
                     args["tile"] = frame.tile
                 if frame.trace is not None:
                     args["flow"] = frame.trace.flow_id
-                track = "saves" if phase == "write" else frames_track
+                track = saves_track if phase == "write" else frames_track
                 self._span_tracer.complete(
                     phase,
                     cat="worker",
@@ -745,7 +814,7 @@ class WorkerAutomaticQueue:
                     cat="worker.step",
                     start_wall=start_wall,
                     duration=seconds,
-                    track="save steps" if name in SAVE_STEPS else steps_track,
+                    track=save_steps_track if name in SAVE_STEPS else steps_track,
                     args={"frame": frame.frame_index, **step_bytes.get(name, {})},
                 )
         if self._metrics is not None:
