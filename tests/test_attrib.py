@@ -44,9 +44,13 @@ from tpu_render_cluster.jobs.models import BlenderJob, DistributionStrategy
 from tpu_render_cluster.obs import FlightRecorder, MetricsRegistry, Tracer
 from tpu_render_cluster.obs.dashboard import render_dashboard
 from tpu_render_cluster.obs.loopmon import (
+    BLOCKED_CAUSES,
+    BLOCKED_SECONDS_METRIC,
     EPISODES_METRIC,
     LAG_METRIC,
     LoopLagMonitor,
+    blocked_cause,
+    run_delay_seconds,
 )
 from tpu_render_cluster.obs.validate import validate_trace_document
 from tpu_render_cluster.sched.tickprof import (
@@ -415,6 +419,113 @@ def test_blocked_loop_detected_and_flight_recorded(monkeypatch):
     assert blocked and all(e["ph"] == "X" for e in blocked)
     document = {"traceEvents": tracer.metadata_events() + tracer.events()}
     assert validate_trace_document(document) == []
+
+
+def scripted(values):
+    """A reading that gives `values` in turn, then the last of them for
+    ever: every sample behind the scripted ones reads a clock that stands
+    still and a process that does nothing, and is on time."""
+    remaining = list(values)
+    return lambda: remaining.pop(0) if len(remaining) > 1 else remaining[0]
+
+
+def late_sample(monkeypatch, *, clock, process_time, schedstat, samples=2):
+    """One monitor over injected readings: a sample on time, then one that
+    the scripted clock makes late. Returns the monitor, its registry and
+    its tracer once `samples` samples are in."""
+    monkeypatch.setenv("TRC_OBS_LOOPMON_INTERVAL", "0.001")
+    monkeypatch.setenv("TRC_OBS_LOOPMON_THRESHOLD", "0.1")
+    registry, tracer = MetricsRegistry(), Tracer("loop-cause-test", pid=3)
+
+    async def scenario():
+        monitor = LoopLagMonitor(registry, role="worker", span_tracer=tracer)
+        monitor.clock = scripted(clock)
+        monitor.process_time = scripted(process_time)
+        monitor.read_schedstat = scripted(schedstat)
+        monitor.start()
+        at_start = {
+            cause: registry.counter(BLOCKED_SECONDS_METRIC, labels=("role", "cause")).value(role="worker", cause=cause)
+            for cause in BLOCKED_CAUSES
+        }
+        while monitor.samples < samples:
+            await asyncio.sleep(0.001)
+        await monitor.stop()
+        return monitor, at_start
+
+    monitor, at_start = asyncio.run(asyncio.wait_for(scenario(), 30))
+    assert at_start == dict.fromkeys(BLOCKED_CAUSES, 0.0)  # every cause, for the role, from the start
+    return monitor, registry, tracer
+
+
+# A sample on time (0 -> 0.001), then one half a second late (1.0 -> 1.501): per sample the clock is
+# read twice, the process's CPU once and once more for an episode (three readings in all), schedstat likewise
+# and once by start() (four).
+LATE_CLOCK = (0.0, 0.001, 1.0, 1.501)
+SECONDS_LATE = 0.5
+
+
+@pytest.mark.parametrize("cause,process_time,schedstat", [
+    # the loop's thread stood on a run queue for 0.3 s of the 0.5: the host gave it no CPU
+    ("not_scheduled", (5.0, 5.0, 5.01), ("9 1000000000 3", "9 1000000000 3", "9 1000000000 3", "9 1300000000 4")),
+    # ... for at least half the lag, whatever our own threads did meanwhile: the first cause that holds
+    ("not_scheduled", (5.0, 5.0, 5.45), ("9 1000000000 3", "9 1000000000 3", "9 1000000000 3", "9 1250000000 4")),
+    # runnable for 0.1 s only, and the process used 0.4 s of CPU in 0.501 s: our own threads crowded the loop
+    ("process_busy", (5.0, 5.0, 5.4), ("9 1000000000 3", "9 1000000000 3", "9 1000000000 3", "9 1100000000 4")),
+    # the same where schedstat cannot be read: the CPU clock alone decides
+    ("process_busy", (5.0, 5.0, 5.4), (None,)),
+    # nobody of ours ran and the loop was not runnable: the whole process stood still
+    ("process_idle", (5.0, 5.0, 5.02), ("9 1000000000 3", "9 1000000000 3", "9 1000000000 3", "9 1010000000 4")),
+    ("process_idle", (5.0, 5.0, 5.02), (None,)),
+    # a schedstat that turns to something else under the monitor's hands is as good as none, and raises nothing
+    ("process_idle", (5.0, 5.0, 5.02), ("9 1000000000 3", "9 1000000000 3", "9 1000000000 3", "no numbers here")),
+])
+def test_a_late_sample_is_counted_under_the_one_cause_the_processes_readings_give(monkeypatch, cause, process_time, schedstat):
+    monitor, registry, tracer = late_sample(monkeypatch, clock=LATE_CLOCK, process_time=process_time, schedstat=schedstat)
+    assert monitor.blocked_episodes == 1
+    seconds = registry.counter(BLOCKED_SECONDS_METRIC, labels=("role", "cause"))
+    by_cause = {name: seconds.value(role="worker", cause=name) for name in BLOCKED_CAUSES}
+    assert by_cause[cause] == pytest.approx(SECONDS_LATE) and sum(by_cause.values()) == pytest.approx(SECONDS_LATE)
+    assert registry.snapshot()[EPISODES_METRIC]["series"]["role=worker"] == 1
+    (span,) = [e for e in tracer.events() if e.get("name") == "loop blocked"]
+    assert span["args"]["cause"] == cause and span["args"]["lag_s"] == pytest.approx(SECONDS_LATE)
+    assert span["args"]["process_cpu_s"] == pytest.approx(process_time[-1] - process_time[-2])
+    readable = schedstat[0] is not None and schedstat[-1][-1].isdigit()
+    if readable:
+        delay = (int(schedstat[-1].split()[1]) - int(schedstat[-2].split()[1])) / 1e9
+        assert span["args"]["run_delay_s"] == pytest.approx(delay)
+    else:
+        assert span["args"]["run_delay_s"] is None
+    assert validate_trace_document({"traceEvents": tracer.metadata_events() + tracer.events()}) == []
+
+
+@pytest.mark.parametrize("schedstat", [None, "", "12345", "one two three", "1 -x 3"])
+def test_a_schedstat_that_cannot_be_read_never_raises_and_never_says_not_scheduled(monkeypatch, schedstat):
+    from tpu_render_cluster.obs import loopmon
+
+    assert run_delay_seconds(schedstat) is None
+    assert run_delay_seconds("4034530 997551 12") == pytest.approx(0.000997551)
+    monkeypatch.setattr(loopmon, "SCHEDSTAT_PATH", "/proc/self/no-such-file")
+    assert loopmon.read_schedstat() is None
+    # whatever the other readings: with no run-queue delay to read, the first cause cannot be given
+    for process_cpu_s in (0.0, 0.2, 5.0):
+        assert blocked_cause(0.5, 0.75, process_cpu_s, None) in ("process_busy", "process_idle")
+    monitor, registry, _ = late_sample(
+        monkeypatch, clock=LATE_CLOCK, process_time=(5.0, 5.0, 5.0), schedstat=(schedstat,),
+    )
+    assert monitor.blocked_episodes == 1
+    seconds = registry.counter(BLOCKED_SECONDS_METRIC, labels=("role", "cause"))
+    assert seconds.value(role="worker", cause="not_scheduled") == 0.0
+    assert seconds.value(role="worker", cause="process_idle") == pytest.approx(SECONDS_LATE)
+    # probed once at start(): a host that hides schedstat is not asked again
+    assert monitor.read_schedstat() is None
+
+
+def test_the_causes_edges_are_half_the_lag_and_half_a_core():
+    assert blocked_cause(0.4, 0.65, 0.0, 0.2) == "not_scheduled"  # at least half the lag
+    assert blocked_cause(0.4, 0.65, 0.0, 0.19) == "process_idle"
+    assert blocked_cause(0.4, 0.65, 0.325, 0.19) == "process_busy"  # at least half a core over the sample
+    assert blocked_cause(0.4, 0.65, 0.32, 0.19) == "process_idle"
+    assert blocked_cause(0.4, 0.65, 9.0, 0.3) == "not_scheduled"  # the first that holds
 
 
 # ---------------------------------------------------------------------------

@@ -45,9 +45,12 @@ def test_the_two_04vs_one_worker_cells_differ_by_the_output_format_alone():
     assert {k: v for k, v in png.config["guarantees"].items() if k in jpeg.config["guarantees"]} == jpeg.config["guarantees"]
     assert set(png.config["guarantees"]) - set(jpeg.config["guarantees"]) == {"the_file_is_the_programs_pixels"}
     assert {"output_format_on_the_measuring_job", "bit_depth_and_compression", "render"} == set(png.config["assumed"])
-    # the same metrics, PR 52's three and PR 53's one in both
+    # the same metrics, PR 52's three and PR 53's one in both: those four directly before the eight
+    # that PR 54 appended to every cell's list, whatever later PRs append behind them
     assert [m["name"] for m in png.per_layer] == [m["name"] for m in jpeg.per_layer]
-    assert [m["name"] for m in png.per_layer][-4:] == [*NEW_METRICS, "save_beside_save_frame_share"]
+    names = [m["name"] for m in png.per_layer]
+    first_of_pr54 = names.index("host_cpu_ms_per_frame")
+    assert names[first_of_pr54 - 4:first_of_pr54] == [*NEW_METRICS, "save_beside_save_frame_share"]
     # the job files: one line of the job itself differs (its name and description besides)
     png_lines, jpeg_lines = (
         set((cell.config_dir / cell.config["job_template"]).read_text().splitlines()) for cell in (png, jpeg)
@@ -71,7 +74,8 @@ def test_the_three_readers_are_data_and_read_the_series_the_issue_names():
     PR may edit the benchmark's file (PERF.md §7). What they were there to
     hold is held here by the entries' places: the 68th to the 70th, after
     `walk_top_tests_per_entry`, with nothing before them come or gone,
-    whatever later PRs append."""
+    whatever later PRs append (PR 54 appended eight that name both cells,
+    so PR 53's entry is held by its place too: the 71st)."""
     benchmark = manifest.load_benchmark(ROOT)
     entries = benchmark["per_layer"][67:70]
     assert [m["name"] for m in entries] == list(NEW_METRICS)
@@ -85,5 +89,7 @@ def test_the_three_readers_are_data_and_read_the_series_the_issue_names():
     # every list that named the JPEG cell at PR 52 names the new cell too, last; PR 53's does as well
     named = [m for m in benchmark["per_layer"][:70] if JPEG_CELL in m.get("workloads", [])]
     assert len(named) == 14 + 3 and all(m["workloads"][-1] == CELL for m in named)
-    later = [m for m in benchmark["per_layer"][70:] if JPEG_CELL in m.get("workloads", [])]
-    assert [m["name"] for m in later] == ["save_beside_save_frame_share"] and later[0]["workloads"][-1] == CELL
+    # (the 71st entry alone: the entries behind it are later PRs' own, and PR 54's eight name every cell)
+    later = benchmark["per_layer"][70]
+    assert later["name"] == "save_beside_save_frame_share" and JPEG_CELL in later["workloads"]
+    assert later["workloads"][-1] == CELL

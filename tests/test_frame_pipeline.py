@@ -951,7 +951,7 @@ def test_a_pipelined_frames_timing_holds_all_six_steps_and_feeds_the_series(rayt
     assert len(traces) == 7
     for trace in traces:
         timing = trace.details
-        names = [name for name, _, _ in timing.steps]
+        names = [name for name, _, _, _ in timing.steps]
         assert names == list(FRAME_STEPS[:4]) + ["file_write", "encode", "file_write"]
         points = [
             timing.started_process_at, timing.finished_loading_at, timing.started_rendering_at,
@@ -960,7 +960,7 @@ def test_a_pipelined_frames_timing_holds_all_six_steps_and_feeds_the_series(rayt
         ]
         assert points == sorted(points)
         # the save stage's steps lie inside the write phase, the others before it
-        for name, start, seconds in timing.steps:
+        for name, start, seconds, _cpu in timing.steps:
             if name in ("encode", "file_write"):
                 assert timing.file_saving_started_at <= start
                 assert start + seconds <= timing.file_saving_finished_at + 1e-3
@@ -1011,7 +1011,7 @@ def test_four_frames_issued_back_to_back_are_the_files_of_four_frames_rendered_o
     assert len({(tmp_path / "sync" / "out" / name).read_bytes() for name in names}) == 4
     # a frame's resolve and dispatch were timed where it was issued, its wait and copy where it was collected
     for trace in driven.traces._frame_render_traces:
-        assert [name for name, _, _ in trace.details.steps][:4] == list(FRAME_STEPS[:4])
+        assert [name for name, _, _, _ in trace.details.steps][:4] == list(FRAME_STEPS[:4])
     frames = [trace.details for trace in driven.traces._frame_render_traces]
     assert any(later.started_rendering_at < earlier.finished_rendering_at for earlier, later in zip(frames, frames[1:]))
 
@@ -1068,7 +1068,7 @@ def test_eight_frames_saved_at_once_are_the_files_of_eight_frames_saved_one_by_o
     assert {e["tid"] for e in spans if e["name"] == "encode"} == {tracks[steps] for _, steps in SAVE_TRACKS}
     # each frame's own steps, in its own order, though eight threads took steps at once
     for trace in driven.traces._frame_render_traces:
-        assert [name for name, _, _ in trace.details.steps] == list(FRAME_STEPS[:4]) + ["file_write", "encode", "file_write"]
+        assert [name for name, _, _, _ in trace.details.steps] == list(FRAME_STEPS[:4]) + ["file_write", "encode", "file_write"]
 
 
 # -- the trace of record under overlap ---------------------------------------------------

@@ -5,6 +5,7 @@ master/worker/transport spans plus nonzero frame-phase histograms, and
 ``analysis/`` loads both files without errors).
 """
 
+import itertools
 import json
 import math
 import os
@@ -452,6 +453,53 @@ def test_validator_accepts_real_tracer_output(tmp_path):
     tracer.flow_end("frame", id="f1", ts=101.25, track="t2")
     path = tracer.export(tmp_path / "ok_trace-events.json")
     assert validate_trace_file(path) == []
+
+
+@pytest.mark.parametrize("args,dur_us,problem", [
+    ({"frame": 1, "cpu_s": 0.0104}, 10_000.0, None),  # the two clocks tick apart by under a millisecond
+    ({"frame": 1}, 10_000.0, None),  # a step handed over without its CPU seconds
+    ({"frame": 1, "cpu_s": 0.0121}, 10_000.0, "cpu_s"),  # more CPU than wall: another thread's, or another clock's
+    ({"frame": 1, "cpu_s": -0.001}, 10_000.0, "cpu_s"),
+    ({"frame": 1, "cpu_s": "much"}, 10_000.0, "cpu_s"),
+    ({"mkdir_ms": 1.0, "create_ms": 2.0, "write_ms": 3.0, "close_ms": 0.5, "rename_ms": 3.5}, 10_000.0, None),
+    ({"mkdir_ms": 1.0, "create_ms": 2.0, "write_ms": 3.0, "close_ms": 0.5, "rename_ms": 3.6}, 10_000.0, "file operations"),
+    ({"mkdir_ms": 1.0, "create_ms": None, "write_ms": 3.0, "close_ms": 0.5, "rename_ms": 1.0}, 10_000.0, "no number"),
+])
+def test_validator_holds_a_steps_cpu_seconds_and_a_file_writes_operations_inside_its_wall(args, dur_us, problem):
+    """Invariant 8: `cpu_s` at most `dur` + 1 ms on every `worker.step`
+    event, the five operations at most `dur` on a `file_write` one."""
+    event = {"name": "file_write", "cat": "worker.step", "ph": "X", "pid": 1, "tid": 1, "ts": 5.0, "dur": dur_us, "args": args}
+    problems = validate_trace_document({"traceEvents": [event]})
+    if problem is None:
+        assert problems == []
+    else:
+        assert len(problems) == 1 and problem in problems[0]
+    # the same keys on a span of another category are nobody's business
+    assert validate_trace_document({"traceEvents": [{**event, "cat": "worker"}]}) == []
+
+
+def test_validator_allows_a_coarse_cpu_clock_one_tick_and_no_more():
+    """The chip's host counts a thread's CPU in hundredths of a second: a
+    2 ms step that a tick's edge falls in reads 0.01 s. Where a process's
+    readings are all multiples of their smallest, that is the tolerance;
+    a clock as fine as Linux's keeps the millisecond."""
+    def step(dur_us, cpu_s, pid=1):  # (each on a track of its own: their order in time is another invariant's)
+        return {"name": "encode", "cat": "worker.step", "ph": "X", "pid": pid, "tid": next(tids), "ts": 5.0, "dur": dur_us,
+                "args": {"frame": 1, "cpu_s": cpu_s}}
+
+    tids = itertools.count(1)
+
+    ticking = [step(2000.0 + i, (0.0, 0.01, 0.01, 0.0, 0.0)[i % 5]) for i in range(60)] + [step(12600.0, 0.02) for _ in range(3)]
+    assert validate_trace_document({"traceEvents": ticking}) == []
+    two_ticks_in_five_ms = validate_trace_document({"traceEvents": ticking + [step(5000.0, 0.02)]})
+    assert len(two_ticks_in_five_ms) == 1 and "tick of 10000us" in two_ticks_in_five_ms[0]
+    # another process of the same file with a fine clock is held to the millisecond
+    fine = [step(6000.0, round(0.0011 + 0.000137 * i, 6), pid=2) for i in range(30)]
+    assert validate_trace_document({"traceEvents": ticking + fine}) == []
+    over = validate_trace_document({"traceEvents": ticking + fine + [step(6000.0, 0.0075, pid=2)]})
+    assert len(over) == 1 and "tick of 1000us" in over[0]
+    # too few readings to tell a tick from a number: the millisecond
+    assert len(validate_trace_document({"traceEvents": [step(2000.0, 0.01) for _ in range(5)]})) == 5
 
 
 def test_validator_catches_negative_and_missing_timestamps():

@@ -25,6 +25,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import functools
+import os
 import threading
 import time
 from pathlib import Path
@@ -34,12 +35,14 @@ import pytest
 from PIL import Image
 
 from benchmark.lib import check, manifest
-from tpu_render_cluster.obs import MetricsRegistry, validate_trace_file
+from tpu_render_cluster.obs import CPU_TIMED_STEPS, FILE_WRITE_OPS, FRAME_STEPS, MetricsRegistry, validate_trace_file
 from tpu_render_cluster.obs.prometheus import render_prometheus
 from tpu_render_cluster.protocol import messages as pm
-from tpu_render_cluster.render.image_io import WRITTEN_FORMATS, WrittenImage, write_image
+from tpu_render_cluster.render.image_io import WRITTEN_FORMATS, write_image
 from tpu_render_cluster.worker.backends.mock import MockBackend
-from tpu_render_cluster.worker.queue import FILE_FORMATS, FrameState, HELD_TRACKS, LOOP_STATES, SAVE_FRAMES
+from tpu_render_cluster.worker.queue import (
+    FILE_FORMATS, FrameState, HELD_TRACKS, LOOP_STATES, PROCESS_CPU_MODES, SAVE_FRAMES,
+)
 
 from tests.test_frame_pipeline import (
     Driven, IssueAheadBackend, RecordingSender, TwoStageBackend, drive, make_job, most_rendering, render_all, until,
@@ -132,7 +135,7 @@ def test_the_png_files_decode_bit_for_bit_to_the_pixels_the_frame_program_return
     assert [trace.frame_index for trace in driven.traces._frame_render_traces] == list(range(1, FRAMES + 1))
     # the bytes are the stated encoder's at its stated level: Pillow's default, zlib level 6
     again = directory.parent / "again.png"
-    assert write_image(again, backend.pixels[1], "PNG") == WrittenImage("PNG", 64 * 64 * 3, again.stat().st_size)
+    assert write_image(again, backend.pixels[1], "PNG")[:3] == ("PNG", 64 * 64 * 3, again.stat().st_size)
     assert again.read_bytes() == (directory / names[0]).read_bytes()
 
 
@@ -175,13 +178,55 @@ def test_the_bytes_of_a_jobs_frames_are_counted_and_written_on_its_save_steps(pn
     assert png_bytes > jpeg_job[1].counter("worker_frame_file_bytes_total", format="JPEG")
 
 
+def test_a_jobs_steps_carry_their_cpu_seconds_and_its_file_writes_their_five_operations(png_job, jpeg_job):
+    from tpu_render_cluster.obs import validate_trace_document
+
+    for _backend, driven, _ in (png_job, jpeg_job):
+        steps = [e for e in driven.tracer.events() if e.get("cat") == "worker.step" and e["name"] in FRAME_STEPS]
+        assert len(steps) == FRAMES * 7 and {e["name"] for e in steps} == set(FRAME_STEPS)
+        # every stretch of the three steps that have a CPU clock: its thread's CPU seconds, never more than its
+        # wall (to the clocks' 1 ms); the other three steps' events carry none
+        timed = [e for e in steps if e["name"] in CPU_TIMED_STEPS]
+        assert len(timed) == FRAMES * 4 and all("cpu_s" not in e["args"] for e in steps if e not in timed)
+        assert all(0.0 <= e["args"]["cpu_s"] <= e["dur"] / 1e6 + 1e-3 for e in timed)
+        snapshot = driven.metrics.snapshot()
+        assert set(snapshot["worker_frame_step_cpu_seconds_total"]["series"]) == {f"step={name}" for name in CPU_TIMED_STEPS}
+        by_step = {name: driven.counter("worker_frame_step_cpu_seconds_total", step=name) for name in CPU_TIMED_STEPS}
+        for name in CPU_TIMED_STEPS:
+            assert by_step[name] == pytest.approx(sum(e["args"]["cpu_s"] for e in timed if e["name"] == name), abs=1e-4)
+        wall = {k.removeprefix("step="): v["sum"] for k, v in snapshot["worker_frame_step_seconds"]["series"].items()}
+        assert all(by_step[name] <= wall[name] + FRAMES * 1e-3 for name in CPU_TIMED_STEPS)
+        assert by_step["encode"] > 0.0  # the encoder computes
+        # the five operations: on the frame's LAST file_write stretch (write_image's own), in ms, adding
+        # up to that stretch and to no more; the stretch before `encode` found the path and has none
+        by_op = {op: driven.counter("worker_file_write_op_seconds_total", op=op) for op in FILE_WRITE_OPS}
+        assert all(seconds > 0.0 for seconds in by_op.values())
+        with_ops = [e for e in steps if "rename_ms" in e["args"]]
+        assert len(with_ops) == FRAMES and all(e["name"] == "file_write" for e in with_ops)
+        assert {e["args"]["frame"] for e in with_ops} == set(range(1, FRAMES + 1))
+        for frame in range(1, FRAMES + 1):
+            writes = [e for e in steps if e["name"] == "file_write" and e["args"]["frame"] == frame]
+            assert [("rename_ms" in e["args"]) for e in sorted(writes, key=lambda e: e["ts"])] == [False, True]
+        in_events = sum(e["args"][f"{op}_ms"] for e in with_ops for op in FILE_WRITE_OPS) / 1000.0
+        assert in_events == pytest.approx(sum(by_op.values()), abs=FRAMES * 5e-7)
+        written = sum(e["dur"] for e in with_ops) / 1e6
+        assert sum(by_op.values()) <= written  # edge to edge inside the step
+        assert sum(by_op.values()) == pytest.approx(written, rel=0.10)  # and all of it but the clock reads
+        assert sum(by_op.values()) <= wall["file_write"]
+        # the process's CPU, both modes, brought up to date with every frame: never behind the steps' own
+        process = sum(driven.counter("worker_process_cpu_seconds_total", mode=mode) for mode in PROCESS_CPU_MODES)
+        assert process >= sum(by_step.values()) - 0.02  # (os.times ticks in hundredths of a second)
+        document = {"traceEvents": driven.tracer.metadata_events() + driven.tracer.events()}
+        assert validate_trace_document(document) == []
+
+
 def test_write_image_says_what_it_wrote_and_the_queue_knows_every_format_it_can_say(tmp_path):
     assert set(FILE_FORMATS) == set(WRITTEN_FORMATS) == {"PNG", "JPEG", "BMP", "TIFF"}
     pixels = np.arange(8 * 8 * 3, dtype=np.uint8).reshape(8, 8, 3)
     for file_format, image_format in (("png", "PNG"), ("JPG", "JPEG"), ("jpeg", "JPEG"), ("EXR", "PNG"), ("tiff", "TIFF")):
         path = tmp_path / f"f-{file_format}"
         written = write_image(path, pixels, file_format)
-        assert written == (image_format, 192, path.stat().st_size) and written.image_format in FILE_FORMATS
+        assert written[:3] == (image_format, 192, path.stat().st_size) and written.image_format in FILE_FORMATS
         with Image.open(path) as image:
             assert image.format == image_format  # the fall-back is a PNG file, and is named as one
 
@@ -402,6 +447,18 @@ def test_the_new_series_are_exposed_at_zero_from_the_workers_start():
     assert "worker_frame_pixel_bytes_total 0" in text
     assert 'worker_frame_file_bytes_total{format="PNG"} 0' in text and 'worker_frame_file_bytes_total{format="JPEG"} 0' in text
     assert "worker_frame_held_seconds_count 0" in text and "worker_frame_held_seconds_sum 0" in text
+    # PR 54's three counter families with every label value, and the gauge, from the worker's start
+    assert snapshot["worker_frame_step_cpu_seconds_total"]["series"] == {f"step={name}": 0.0 for name in CPU_TIMED_STEPS}
+    assert snapshot["worker_file_write_op_seconds_total"]["series"] == {f"op={op}": 0.0 for op in FILE_WRITE_OPS}
+    assert FILE_WRITE_OPS == ("mkdir", "create", "write", "close", "rename")
+    process_cpu = snapshot["worker_process_cpu_seconds_total"]["series"]
+    assert set(process_cpu) == {"mode=user", "mode=system"} == {f"mode={mode}" for mode in PROCESS_CPU_MODES}
+    # (the process's CPU since ITS start, as the master's counter reads: this test process has used some)
+    assert process_cpu["mode=user"] > 0.0 and process_cpu["mode=system"] >= 0.0
+    assert snapshot["worker_host_cpu_units"]["series"] == {"": float(len(os.sched_getaffinity(0)))}
+    for line in ('worker_frame_step_cpu_seconds_total{step="device_wait"} 0', 'worker_file_write_op_seconds_total{op="rename"} 0',
+                 'worker_process_cpu_seconds_total{mode="system"} ', "worker_host_cpu_units "):
+        assert line in text, line
     # a backend that writes no image itself feeds the hold and leaves the bytes at 0
     driven = render_all(MockBackend(load_seconds=0.001, render_seconds=0.005, save_seconds=0.001), 3)
     assert driven.metrics.snapshot()["worker_frame_held_seconds"]["series"][""]["count"] == 3

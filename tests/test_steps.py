@@ -59,7 +59,7 @@ def make_job(name: str, frames: int, output: str = "%BASE%/out", file_format: st
 
 def seconds_by_step(steps) -> dict[str, float]:
     totals: dict[str, float] = {}
-    for name, _start, seconds in steps:
+    for name, _start, seconds, _cpu in steps:
         totals[name] = totals.get(name, 0.0) + seconds
     return totals
 
@@ -86,7 +86,7 @@ def test_an_inner_step_suspends_the_outer_one():
             with step("device_wait"):
                 time.sleep(0.05)
             time.sleep(0.01)
-    assert [name for name, _, _ in steps] == ["dispatch", "device_wait", "dispatch"]
+    assert [name for name, _, _, _ in steps] == ["dispatch", "device_wait", "dispatch"]
     totals = seconds_by_step(steps)
     # the inner step's 50 ms are not in the outer's 30
     assert 0.03 <= totals["dispatch"] < 0.045
@@ -112,8 +112,8 @@ def test_a_frames_steps_add_up_to_its_wall_time():
         with step("file_write"):
             time.sleep(0.002)
     wall = time.perf_counter() - start
-    assert abs(sum(seconds for _, _, seconds in steps) - wall) < 0.001
-    assert sum(1 for name, _, _ in steps if name == "device_wait") == 5
+    assert abs(sum(seconds for _, _, seconds, _ in steps) - wall) < 0.001
+    assert sum(1 for name, _, _, _ in steps if name == "device_wait") == 5
 
 
 def test_segments_do_not_overlap_and_are_in_the_order_they_ended():
@@ -123,10 +123,121 @@ def test_segments_do_not_overlap_and_are_in_the_order_they_ended():
                 time.sleep(0.002)
             with step("readback"):
                 time.sleep(0.002)
-    ends = [start + seconds for _, start, seconds in steps]
+    ends = [start + seconds for _, start, seconds, _ in steps]
     assert ends == sorted(ends)
-    for (_, _, _), (_, next_start, _), end in zip(steps, steps[1:], ends):
+    for (_, next_start, _, _), end in zip(steps[1:], ends):
         assert next_start >= end - 1e-4  # wall clock against the monotonic one
+
+
+# -- the CPU clock beside the wall clock ----------------------------------------
+
+
+def spin_cpu(seconds: float) -> None:
+    """Work until THIS thread's CPU clock has run `seconds`: however loaded
+    the machine, the thread has then used that much CPU and no less."""
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def test_a_step_that_computes_has_its_cpu_seconds_within_its_wall_and_over_half_of_it():
+    # At least 30 ms of CPU by construction, and never more than the wall (the CPU clock is
+    # read inside the wall clock's reads; the two tick apart by far less than a millisecond).
+    # Over half of the wall on a machine that gives the thread a core: three goes, because
+    # a shared machine may take the core away for longer than the step once.
+    shares = []
+    for _ in range(3):
+        with frame_steps() as steps:
+            with step("encode"):
+                spin_cpu(0.03)
+        ((name, _start, seconds, cpu_seconds),) = steps
+        assert name == "encode" and 0.03 <= cpu_seconds <= seconds + 1e-3
+        shares.append(cpu_seconds / seconds)
+        if shares[-1] > 0.5:
+            break
+    assert max(shares) > 0.5, shares
+
+
+def test_a_step_that_sleeps_has_hardly_any_cpu_seconds():
+    with frame_steps() as steps:
+        with step("device_wait"):
+            time.sleep(0.03)
+    ((_, _, seconds, cpu_seconds),) = steps
+    assert seconds >= 0.03 and 0.0 <= cpu_seconds < 0.005
+
+
+def test_nested_steps_keep_their_cpu_seconds_apart_as_they_keep_their_wall_seconds():
+    before = time.thread_time()
+    with frame_steps() as steps:
+        with step("encode"):
+            spin_cpu(0.02)
+            with step("device_wait"):
+                time.sleep(0.03)
+            spin_cpu(0.01)
+    used = time.thread_time() - before
+    assert [name for name, _, _, _ in steps] == ["encode", "device_wait", "encode"]
+    (_, _, _, first), (_, _, waited, waiting), (_, _, _, last) = steps
+    # the outer step's CPU is not the inner's, and the other way round
+    assert first >= 0.02 and last >= 0.01 and waiting < 0.005 and waited >= 0.03
+    # exclusive: the three add up to no more than the thread used from end to end
+    assert first + waiting + last <= used
+    for _, _, seconds, cpu_seconds in steps:
+        assert cpu_seconds <= seconds + 1e-3
+
+
+def test_the_cpu_clock_is_read_for_the_three_steps_a_metric_reads_and_for_no_other():
+    """A read of the thread's CPU clock is a call into the sentry on the
+    chip's host (6 us where the wall clock's is 0.09; PERF.md §5): the
+    steps nobody reads a CPU metric of carry None, and cost no read."""
+    from tpu_render_cluster.obs import CPU_TIMED_STEPS
+
+    assert CPU_TIMED_STEPS == ("device_wait", "encode", "file_write")
+    reads = []
+    real = time.thread_time
+    with frame_steps() as steps:
+        tracer_module.time.thread_time = lambda: reads.append(1) or real()
+        try:
+            for name in FRAME_STEPS:
+                with step(name):
+                    pass
+        finally:
+            tracer_module.time.thread_time = real
+    assert len(reads) == 2 * len(CPU_TIMED_STEPS)  # a segment's open and its close
+    for name, _start, seconds, cpu_seconds in steps:
+        if name in CPU_TIMED_STEPS:
+            assert 0.0 <= cpu_seconds <= seconds + 1e-3
+        else:
+            assert cpu_seconds is None
+
+
+def test_only_the_steps_own_thread_counts():
+    """Another thread at work while a step sleeps is not the step's CPU:
+    what several save threads use beside each other stays each one's own."""
+    busy = threading.Thread(target=spin_cpu, args=(0.03,))
+    with frame_steps() as steps:
+        with step("file_write"):
+            busy.start()
+            busy.join(timeout=30)
+    assert not busy.is_alive()
+    ((_, _, seconds, cpu_seconds),) = steps
+    assert seconds >= 0.03 and cpu_seconds < 0.01
+
+
+def test_a_cpu_clock_that_steps_back_gives_a_step_no_cpu_seconds_and_not_fewer():
+    """The queue feeds a counter with a step's CPU seconds, and a counter
+    refuses a negative amount: one backward step of a host's thread clock
+    would end the worker's loop."""
+    readings = iter([5.0, 4.99])
+    real = time.thread_time
+    with frame_steps() as steps:
+        tracer_module.time.thread_time = lambda: next(readings)
+        try:
+            with step("encode"):
+                pass
+        finally:
+            tracer_module.time.thread_time = real
+    ((_, _, _, cpu_seconds),) = steps
+    assert cpu_seconds == 0.0
 
 
 def test_nothing_is_kept_outside_a_frame():
@@ -152,8 +263,8 @@ def test_a_frames_steps_are_the_threads_own():
         with step("dispatch"):
             time.sleep(0.004)
         thread.join()
-    assert [name for name, _, _ in mine] == ["dispatch"]
-    assert [name for name, _, _ in seen["other"]] == ["encode"]
+    assert [name for name, _, _, _ in mine] == ["dispatch"]
+    assert [name for name, _, _, _ in seen["other"]] == ["encode"]
 
 
 def test_a_failed_step_is_closed_and_the_outer_one_resumes():
@@ -163,7 +274,7 @@ def test_a_failed_step_is_closed_and_the_outer_one_resumes():
                 with step("device_wait"):
                     raise RuntimeError("device lost")
             time.sleep(0.001)
-    assert [name for name, _, _ in steps] == ["dispatch", "device_wait", "dispatch"]
+    assert [name for name, _, _, _ in steps] == ["dispatch", "device_wait", "dispatch"]
     assert not tracer_module._steps_local.stack
 
 
@@ -295,7 +406,7 @@ def test_every_tier_names_all_six_steps_and_they_add_up_to_the_phases(
     # and to the frame: what has no step is the bookkeeping after the file
     frame = timing.exited_process_at - timing.started_process_at
     assert frame - (in_render + in_write) < 0.002
-    ends = [start + seconds for _, start, seconds in timing.steps]
+    ends = [start + seconds for _, start, seconds, _ in timing.steps]
     assert ends == sorted(ends)
     # a whole frame in the job's format; a tile always as PNG, for the master to stitch
     written = sorted(path.name for path in (tmp_path / "out").iterdir())
@@ -308,7 +419,7 @@ def test_every_tier_waits_for_the_device_once(tier, tmp_path, interpreted_kernel
     """One program a frame, whatever the unit: the render thread blocks on
     the device once (host_syncs_per_frame 1.0), then copies, then writes."""
     _backend, _job, timing = render_one(tier, tmp_path)
-    names = [name for name, _, _ in timing.steps]
+    names = [name for name, _, _, _ in timing.steps]
     assert names == list(FRAME_STEPS[:4]) + ["file_write", "encode", "file_write"]
 
 
@@ -399,7 +510,7 @@ def test_the_one_program_tier_reports_the_occupancy_of_its_bounce_launches(
     timing = backend._render_sync(job, 1)
     count, total, launched, live = (b - a for a, b in zip(before, read()))
     rays = 32 * 32 * 2
-    assert [name for name, _, _ in timing.steps].count("device_wait") == 1
+    assert [name for name, _, _, _ in timing.steps].count("device_wait") == 1
     assert count == launches
     if launches:
         # The backend's own (cached) program, asked again for the same frame.
@@ -488,7 +599,7 @@ def test_write_image_writes_the_parents_bytes(tmp_path, file_format, image_forma
         write_image(ours, pixels, file_format)
     parents_write_image(theirs, pixels, image_format)
     assert ours.read_bytes() == theirs.read_bytes()
-    assert [name for name, _, _ in steps] == ["encode", "file_write"]
+    assert [name for name, _, _, _ in steps] == ["encode", "file_write"]
     assert [p.name for p in ours.parent.iterdir()] == [ours.name]  # no temporary file left
 
 
@@ -531,7 +642,7 @@ class SteppedMockBackend(MockBackend):
             ("resolve", 0.001), ("dispatch", 0.002), ("device_wait", 0.004), ("dispatch", 0.001),
             ("device_wait", 0.003), ("readback", 0.001), ("encode", 0.002), ("file_write", 0.001),
         ):
-            steps.append((name, at, seconds))
+            steps.append((name, at, seconds, seconds / 4))
             at += seconds
         return dataclasses.replace(timing, steps=tuple(steps))
 

@@ -108,7 +108,7 @@ def test_every_scene_family_renders_end_to_end(family, tmp_path):
         timing.exited_process_at,
     ]
     assert points == sorted(points)
-    assert {name for name, _, _ in timing.steps} == set(FRAME_STEPS)
+    assert {name for name, _, _, _ in timing.steps} == set(FRAME_STEPS)
     after = {tier: backend._tier_frames.value(tier=tier) for tier in before}
     assert {tier: after[tier] - before[tier] for tier in before} == {
         "masked": 1, "region": 0, "sharded": 0,

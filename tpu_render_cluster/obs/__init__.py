@@ -48,6 +48,8 @@ from tpu_render_cluster.obs.timeline import (
     tracer_process,
 )
 from tpu_render_cluster.obs.tracer import (
+    CPU_TIMED_STEPS,
+    FILE_WRITE_OPS,
     FRAME_STEPS,
     Tracer,
     export_chrome_trace,
@@ -60,7 +62,9 @@ from tpu_render_cluster.obs.validate import (
 )
 
 __all__ = [
+    "CPU_TIMED_STEPS",
     "DEFAULT_BUCKETS",
+    "FILE_WRITE_OPS",
     "FRAME_STEPS",
     "ClockOffsetEstimator",
     "Counter",

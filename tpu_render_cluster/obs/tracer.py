@@ -27,7 +27,15 @@ from contextlib import contextmanager
 from pathlib import Path
 from typing import Any, Iterable, Iterator, Mapping
 
-__all__ = ["FRAME_STEPS", "Tracer", "export_chrome_trace", "frame_steps", "step"]
+__all__ = [
+    "CPU_TIMED_STEPS",
+    "FILE_WRITE_OPS",
+    "FRAME_STEPS",
+    "Tracer",
+    "export_chrome_trace",
+    "frame_steps",
+    "step",
+]
 
 logger = logging.getLogger(__name__)
 
@@ -61,20 +69,38 @@ MAX_EVENTS = 300_000
 #   file_write   output path, mkdir, temporary file, write, close, rename
 FRAME_STEPS = ("resolve", "dispatch", "device_wait", "readback", "encode", "file_write")
 
+# The steps whose own thread's CPU clock (``time.thread_time``) is read at
+# a segment's two ends, beside the wall clock: the three a metric reads.
+# Not all six: on the chip's host (gVisor) the read is a call into the
+# sentry of 6 us, where the wall clock's is 0.09 (PERF.md §5), and a
+# frame's other four segments would add eight of them to the issue and
+# collect threads for numbers nobody reads.
+CPU_TIMED_STEPS = ("device_wait", "encode", "file_write")
+
+# What the ``file_write`` step does to the file system, in the order it
+# does it (render/image_io.py::write_image times each, edge to edge):
+# ``mkdir``, ``create`` (``mkstemp``) and ``rename`` work on the output
+# directory, ``write`` and ``close`` on the file's bytes.
+FILE_WRITE_OPS = ("mkdir", "create", "write", "close", "rename")
+
 _steps_local = threading.local()
 
 
 class _Segment:
     """One uninterrupted stretch of a step on this thread."""
 
-    __slots__ = ("name", "start_wall", "start_mono", "annotation")
+    __slots__ = ("name", "start_wall", "start_mono", "start_cpu", "annotation")
 
     def __init__(self, name: str) -> None:
         self.name = name
         # The clocks are read first and last, so that a frame's segments
-        # leave none of its time between them.
+        # leave none of its time between them. This thread's CPU clock,
+        # for the steps that have one, is read inside the wall clock's two
+        # reads, at both ends, so a segment's CPU seconds never pass its
+        # wall seconds by more than a tick of the CPU clock.
         self.start_wall = time.time()
         self.start_mono = time.perf_counter()
+        self.start_cpu = time.thread_time() if name in CPU_TIMED_STEPS else None
         # Only a process that already imported JAX gets the annotation (the
         # master never imports it). Outside a profiler session a
         # TraceAnnotation is a check of one atomic.
@@ -90,8 +116,19 @@ class _Segment:
             self.annotation.__exit__(None, None, None)
         sink = getattr(_steps_local, "sink", None)
         if sink is not None:
+            # (never below 0: a counter is fed with it, and a host's CPU
+            # clock that stepped back once would take the worker with it)
+            cpu_seconds = (
+                None if self.start_cpu is None
+                else max(0.0, time.thread_time() - self.start_cpu)
+            )
             sink.append(
-                (self.name, self.start_wall, time.perf_counter() - self.start_mono)
+                (
+                    self.name,
+                    self.start_wall,
+                    time.perf_counter() - self.start_mono,
+                    cpu_seconds,
+                )
             )
 
 
@@ -104,8 +141,12 @@ def step(name: str) -> Iterator[None]:
     the steps one thread takes for a frame never overlap and add up to
     that thread's stage of the frame. Each
     uninterrupted stretch (a suspended step resumes as a new one) is
-    remembered as ``(name, start_wall, seconds)`` for the frame in hand
-    (``frame_steps``; nothing is kept outside one), and lies inside a
+    remembered as ``(name, start_wall, seconds, cpu_seconds)`` for the
+    frame in hand (``frame_steps``; nothing is kept outside one), the
+    last being what this thread's CPU clock (``time.thread_time``) ran
+    between the two reads of the wall clock, and None for a step outside
+    ``CPU_TIMED_STEPS``: wall less CPU is time the thread held the step
+    and did not run. It lies inside a
     ``jax.profiler.TraceAnnotation("trc:<name>")`` so a profile taken by
     anyone carries the program's steps on the profile's own clock.
     """
@@ -126,11 +167,11 @@ def step(name: str) -> Iterator[None]:
 
 
 @contextmanager
-def frame_steps() -> Iterator[list[tuple[str, float, float]]]:
+def frame_steps() -> Iterator[list[tuple[str, float, float, float | None]]]:
     """Collect this thread's steps for one frame; yields the list they
     land in, in the order they ended."""
     previous = getattr(_steps_local, "sink", None)
-    sink: list[tuple[str, float, float]] = []
+    sink: list[tuple[str, float, float, float | None]] = []
     _steps_local.sink = sink
     try:
         yield sink

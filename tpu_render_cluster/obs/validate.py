@@ -36,6 +36,17 @@ strings (empty = valid):
     means a mark was set twice or a merge mixed two processes' start-ups.
     A worker that died before its first frame exports a prefix of them.
 
+8.  A worker's steps keep their two clocks and their parts apart: a
+    ``worker.step`` event's ``args.cpu_s`` (its thread's CPU seconds,
+    read inside the wall clock's reads), where present, is at most its
+    ``dur`` plus 1 ms — or plus one tick of the CPU clock where the
+    process's own readings show a coarser one (the chip's host counts a
+    thread's CPU in hundredths of a second: every reading is a multiple
+    of 0.01, and a step that a tick's edge falls in reads a whole tick)
+    — and a ``file_write`` event's file operations (``args.<op>_ms``,
+    obs/tracer.py's step of that name parted by ``FILE_WRITE_OPS``) add
+    up to at most its ``dur`` (plus the rounding of the five).
+
 ``scripts/validate_trace.py`` is the CLI wrapper; tests call these
 functions directly on every artifact they export.
 """
@@ -49,6 +60,7 @@ from pathlib import Path
 from typing import Any, Iterable
 
 from tpu_render_cluster.obs.startup import STARTUP_STAGES
+from tpu_render_cluster.obs.tracer import FILE_WRITE_OPS
 
 __all__ = [
     "validate_trace_events",
@@ -70,6 +82,15 @@ END_ORDER_TOLERANCE_US = 5000.0
 STARTUP_GAP_TOLERANCE_US = 1000.0
 STARTUP_OVERLAP_TOLERANCE_US = 1.0
 
+# A step's CPU seconds lie inside its wall seconds by the order the clocks
+# are read in; the two clocks tick apart by less than this, or by one tick
+# of a CPU clock that is coarser (``_cpu_clock_tick_us``).
+STEP_CPU_TOLERANCE_US = 1000.0
+CPU_TICK_MIN_READINGS = 8
+# The five operations' milliseconds are each rounded to four places.
+FILE_OPS_ROUNDING_US = 1.0
+FILE_WRITE_OP_KEYS = tuple(f"{op}_ms" for op in FILE_WRITE_OPS)
+
 
 def _finite_nonneg(value: Any) -> bool:
     return (
@@ -78,6 +99,56 @@ def _finite_nonneg(value: Any) -> bool:
         and math.isfinite(value)
         and value >= 0
     )
+
+
+def _cpu_clock_tick_us(readings: list[Any]) -> float:
+    """The tick of a CPU clock that counts in ticks, as its readings show
+    it: the smallest of them that is not 0, where there are enough of them
+    and every one is a multiple of it; 0 for a clock as fine as Linux's,
+    whose readings share no such measure."""
+    counted = [r * 1e6 for r in readings if _finite_nonneg(r) and r > 0]
+    if len(counted) < CPU_TICK_MIN_READINGS:
+        return 0.0
+    tick = min(counted)
+    in_ticks = all(abs(r / tick - round(r / tick)) * tick <= 1.0 for r in counted)
+    return tick if in_ticks else 0.0
+
+
+def _step_problems(steps: list[tuple[int, dict[str, Any]]]) -> list[str]:
+    """Invariant 8 on one process's ``worker.step`` events."""
+    problems: list[str] = []
+    readings = [
+        (event.get("args") or {}).get("cpu_s") for _, event in steps
+    ]
+    tolerance = max(STEP_CPU_TOLERANCE_US, _cpu_clock_tick_us(readings))
+    for (i, event), cpu_s in zip(steps, readings):
+        dur = float(event["dur"])
+        if cpu_s is not None and (
+            not _finite_nonneg(cpu_s) or cpu_s * 1e6 > dur + tolerance
+        ):
+            problems.append(
+                f"event #{i} ({event.get('name')!r}): cpu_s {cpu_s!r} is more "
+                f"than the step's {dur:.1f}us of wall time (and a tick of "
+                f"{tolerance:.0f}us)"
+            )
+        problems.extend(_file_operation_problems(i, event))
+    return problems
+
+
+def _file_operation_problems(i: int, event: dict[str, Any]) -> list[str]:
+    problems: list[str] = []
+    args = event.get("args") or {}
+    dur = float(event["dur"])
+    operations = [args[key] for key in FILE_WRITE_OP_KEYS if key in args]
+    if operations and event.get("name") == "file_write":
+        if not all(_finite_nonneg(ms) for ms in operations):
+            problems.append(f"event #{i} ('file_write'): a file operation's ms is no number")
+        elif sum(operations) * 1e3 > dur + FILE_OPS_ROUNDING_US:
+            problems.append(
+                f"event #{i} ('file_write'): its file operations add up to "
+                f"{sum(operations) * 1e3:.1f}us, more than the step's {dur:.1f}us"
+            )
+    return problems
 
 
 def validate_trace_events(events: Iterable[Any]) -> list[str]:
@@ -89,6 +160,7 @@ def validate_trace_events(events: Iterable[Any]) -> list[str]:
     flow_events: list[dict[str, Any]] = []
     phases_by_track: dict[tuple[Any, Any], set[str]] = {}
     startup_by_pid: dict[Any, list[dict[str, Any]]] = {}
+    steps_by_pid: dict[Any, list[tuple[int, dict[str, Any]]]] = {}
 
     for i, event in enumerate(events):
         if not isinstance(event, dict) or "ph" not in event:
@@ -131,6 +203,8 @@ def validate_trace_events(events: Iterable[Any]) -> list[str]:
             spans_by_track.setdefault(track, []).append(event)
             if event.get("cat") == "worker.startup":
                 startup_by_pid.setdefault(event.get("pid"), []).append(event)
+            elif event.get("cat") == "worker.step":
+                steps_by_pid.setdefault(event.get("pid"), []).append((i, event))
         elif ph == "B":
             open_stacks.setdefault(track, []).append(str(event.get("name")))
         elif ph == "E":
@@ -174,6 +248,10 @@ def validate_trace_events(events: Iterable[Any]) -> list[str]:
                     f"earlier-appended span's end (non-monotonic track)"
                 )
             high_water = max(high_water, end)
+
+    # Invariant 8: each process's steps, CPU inside wall and parts inside whole.
+    for steps in steps_by_pid.values():
+        problems.extend(_step_problems(steps))
 
     # Invariant 7: each process's start-up stages, in order and edge to edge.
     for pid, stages in startup_by_pid.items():

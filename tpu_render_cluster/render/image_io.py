@@ -112,6 +112,10 @@ class WrittenImage(NamedTuple):
     image_format: str  # of WRITTEN_FORMATS
     pixel_bytes: int  # the raw u8 bytes handed to the encoder
     file_bytes: int  # the encoder's bytes, all of them renamed into place
+    # seconds of each of obs.FILE_WRITE_OPS (mkdir, create, write, close,
+    # rename), edge to edge from the ``file_write`` step's start to the
+    # rename's return
+    write_op_seconds: tuple[float, float, float, float, float]
 
 
 def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> WrittenImage:
@@ -121,7 +125,8 @@ def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> Wri
 
     Two frame steps (obs.step): ``encode`` turns the pixels into the
     format's bytes in memory, ``file_write`` puts them on disk. Returns
-    the bytes that went into ``encode`` and came out of it: the worker's
+    the bytes that went into ``encode`` and came out of it, and what each
+    of ``obs.FILE_WRITE_OPS`` took of the ``file_write`` step: the worker's
     queue counts them and writes them on the two steps' events.
 
     Atomic (write-temp-then-rename): a reader never sees a torn file.
@@ -133,6 +138,7 @@ def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> Wri
     import io
     import os
     import tempfile
+    import time
 
     from PIL import Image
 
@@ -149,18 +155,29 @@ def write_image(path: Path, pixels: np.ndarray, file_format: str = "PNG") -> Wri
         else:
             image.save(encoded, image_format)
     with step("file_write"):
+        edges = [time.perf_counter()]
         path.parent.mkdir(parents=True, exist_ok=True)
+        edges.append(time.perf_counter())
         fd, tmp_name = tempfile.mkstemp(
             prefix=f".{path.name}.", suffix=".tmp", dir=path.parent
         )
+        edges.append(time.perf_counter())
         try:
             with os.fdopen(fd, "wb") as f:
                 f.write(encoded.getbuffer())
+                edges.append(time.perf_counter())
+            edges.append(time.perf_counter())
             os.replace(tmp_name, path)
+            edges.append(time.perf_counter())
         except BaseException:
             try:
                 os.unlink(tmp_name)
             except OSError:
                 pass
             raise
-    return WrittenImage(image_format, pixels.nbytes, encoded.getbuffer().nbytes)
+    return WrittenImage(
+        image_format,
+        pixels.nbytes,
+        encoded.getbuffer().nbytes,
+        tuple(end - start for start, end in zip(edges, edges[1:])),
+    )

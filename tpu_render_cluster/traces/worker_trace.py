@@ -29,16 +29,19 @@ class FrameRenderTime:
     file_saving_started_at: float
     file_saving_finished_at: float
     exited_process_at: float
-    # The frame's exclusive steps as (name, start_wall, seconds), in the
-    # order they ended (obs.step / obs.frame_steps). Worker-local: beside
-    # the seven points, never on the wire or in the raw trace.
-    steps: tuple[tuple[str, float, float], ...] = field(
+    # The frame's exclusive steps as (name, start_wall, seconds,
+    # cpu_seconds), in the order they ended (obs.step / obs.frame_steps);
+    # cpu_seconds is None for a step outside obs.CPU_TIMED_STEPS.
+    # Worker-local: beside the seven points, never on the wire or in the
+    # raw trace.
+    steps: tuple[tuple[str, float, float, float | None], ...] = field(
         default=(), compare=False, repr=False
     )
     # What the save stage turned into what, as ``write_image`` returned it:
-    # (written format, raw pixel bytes, file bytes). Worker-local like the
-    # steps; None from a backend that writes no image itself.
-    saved: tuple[str, int, int] | None = field(
+    # (written format, raw pixel bytes, file bytes, seconds of each file
+    # operation). Worker-local like the steps; None from a backend that
+    # writes no image itself.
+    saved: tuple[str, int, int, tuple[float, ...]] | None = field(
         default=None, compare=False, repr=False
     )
 

@@ -515,7 +515,7 @@ class TpuRaytraceBackend(RenderBackend):
 
     def _issue_pixels(
         self, job: BlenderJob, frame_index: int, tile: int | None,
-        steps: list[tuple[str, float, float]], key: tuple,
+        steps: list[tuple[str, float, float, float | None]], key: tuple,
     ) -> IssuedFrame:
         import jax.numpy as jnp
 
@@ -615,7 +615,7 @@ class TpuRaytraceBackend(RenderBackend):
         self, job: BlenderJob, frame_index: int, tile: int | None,
         display, launches, walk: list, *,
         points: tuple[float, float, float],
-        issue_steps: list[tuple[str, float, float]],
+        issue_steps: list[tuple[str, float, float, float | None]],
         tier: str, scene_name: str,
     ) -> RenderedFrame:
         """The rest of an issued frame's device stage, on whichever thread
@@ -649,7 +649,7 @@ class TpuRaytraceBackend(RenderBackend):
     def _save_stage(
         self, job: BlenderJob, frame_index: int, tile: int | None, pixels, *,
         points: tuple[float, float, float, float],
-        device_steps: list[tuple[str, float, float]],
+        device_steps: list[tuple[str, float, float, float | None]],
         tier: str, scene_name: str, launches, walk: list,
     ) -> FrameRenderTime:
         """The frame's file, from its pixels: on whichever thread the

@@ -44,6 +44,7 @@ from __future__ import annotations
 import asyncio
 import enum
 import logging
+import os
 import threading
 import time
 from collections import deque
@@ -52,7 +53,7 @@ from dataclasses import dataclass, field
 
 from tpu_render_cluster.jobs.models import BlenderJob
 from tpu_render_cluster.jobs.tiles import WorkUnit
-from tpu_render_cluster.obs import MetricsRegistry, Tracer
+from tpu_render_cluster.obs import CPU_TIMED_STEPS, FILE_WRITE_OPS, MetricsRegistry, Tracer
 from tpu_render_cluster.obs.startup import get_startup
 from tpu_render_cluster.protocol import messages as pm
 from tpu_render_cluster.transport.actors import SenderHandle
@@ -138,6 +139,11 @@ HELD_TRACKS = ("held", "held, second frame")
 # (written out here because importing the render package imports JAX, and a
 # worker with another backend never does; a test holds the two equal).
 FILE_FORMATS = ("BMP", "JPEG", "PNG", "TIFF")
+
+# The label values of worker_process_cpu_seconds_total{mode}: the two
+# fields of ``os.times()`` that are this process's own (its children's are
+# left out), all of its threads together.
+PROCESS_CPU_MODES = ("user", "system")
 
 
 class FrameState(enum.Enum):
@@ -263,6 +269,40 @@ class WorkerAutomaticQueue:
             if metrics is not None
             else None
         )
+        self._step_cpu_seconds = (
+            metrics.counter(
+                "worker_frame_step_cpu_seconds_total",
+                "CPU seconds of the step's own thread (time.thread_time) "
+                "inside the steps of worker_frame_step_seconds that have a "
+                "CPU clock (obs.CPU_TIMED_STEPS: device_wait/encode/"
+                "file_write): a step's wall seconds less these are time "
+                "its thread did not run",
+                labels=("step",),
+            )
+            if metrics is not None
+            else None
+        )
+        self._file_write_op_seconds = (
+            metrics.counter(
+                "worker_file_write_op_seconds_total",
+                "Wall seconds of the file_write step by file system "
+                "operation (obs.FILE_WRITE_OPS: mkdir/create/write/close/rename)",
+                labels=("op",),
+            )
+            if metrics is not None
+            else None
+        )
+        self._process_cpu = (
+            metrics.counter(
+                "worker_process_cpu_seconds_total",
+                "CPU seconds of the worker's process, all its threads and "
+                "none of its children, by mode (user/system; os.times), "
+                "brought up to date as each frame is reported",
+                labels=("mode",),
+            )
+            if metrics is not None
+            else None
+        )
         self._loop_seconds = (
             metrics.counter(
                 "worker_loop_seconds_total",
@@ -339,6 +379,17 @@ class WorkerAutomaticQueue:
             self._pixel_bytes.inc(0.0)
             for image_format in FILE_FORMATS:
                 self._file_bytes.inc(0.0, format=image_format)
+            for name in CPU_TIMED_STEPS:
+                self._step_cpu_seconds.inc(0.0, step=name)
+            for op in FILE_WRITE_OPS:
+                self._file_write_op_seconds.inc(0.0, op=op)
+            self._note_process_cpu()
+            # What worker_process_cpu_seconds_total can rise by a second:
+            # the CPUs this process may run on.
+            metrics.gauge(
+                "worker_host_cpu_units",
+                "CPUs the worker's process may run on (sched_getaffinity)",
+            ).set(len(os.sched_getaffinity(0)))
         # The save stage's threads: a thread is started only when a save
         # is handed over and none of them is free, so there is one where
         # saves are shorter than device stages, and none until the backend
@@ -519,11 +570,23 @@ class WorkerAutomaticQueue:
         self._loop_state = state
         self._loop_state_since = now
 
+    def _note_process_cpu(self) -> None:
+        """Bring the process's CPU counter up to ``os.times()``: the counter
+        is its own memory, so a second queue on the same registry (a worker
+        that moved to another master) goes on where the first one stopped."""
+        if self._process_cpu is None:
+            return
+        times = os.times()
+        for mode in PROCESS_CPU_MODES:
+            counted = self._process_cpu.value(mode=mode)
+            self._process_cpu.inc(max(0.0, getattr(times, mode) - counted), mode=mode)
+
     async def _run(self) -> None:
         try:
             await self._run_loop()
         finally:
             self._enter_loop_state(None)
+            self._note_process_cpu()
             for in_stage in self._on_device:
                 in_stage.future.cancel()
             for saving in self._saving:
@@ -794,29 +857,54 @@ class WorkerAutomaticQueue:
                 args={"frame": frame.frame_index},
             )
         step_bytes = {}
+        write_ops_ms = {}
         if timing.saved is not None:
-            image_format, pixel_bytes, file_bytes = timing.saved
+            image_format, pixel_bytes, file_bytes, write_op_seconds = timing.saved
             if self._metrics is not None:
                 self._pixel_bytes.inc(pixel_bytes)
                 self._file_bytes.inc(file_bytes, format=image_format)
+                for op, seconds in zip(FILE_WRITE_OPS, write_op_seconds):
+                    self._file_write_op_seconds.inc(seconds, op=op)
             # what each save step took in and gave out: the encoder's
             # bytes are all written, and all renamed into place
             step_bytes = {
                 "encode": {"bytes_in": pixel_bytes, "bytes_out": file_bytes},
                 "file_write": {"bytes_in": file_bytes, "bytes_out": file_bytes},
             }
-        for name, start_wall, seconds in timing.steps:
-            if self._step_histogram is not None:
+            write_ops_ms = {
+                f"{op}_ms": round(seconds * 1000.0, 4)
+                for op, seconds in zip(FILE_WRITE_OPS, write_op_seconds)
+            }
+        # the operations lie in the frame's last ``file_write`` stretch
+        # (``write_image``'s; the one before ``encode`` found the path)
+        written_in = max(
+            (i for i, timed in enumerate(timing.steps) if timed[0] == "file_write"), default=None
+        )
+        # (a step outside obs.CPU_TIMED_STEPS has None for its CPU seconds,
+        # and one handed over as the three of before PR 54 has none at all:
+        # either is counted and drawn without them)
+        for index, (name, start_wall, seconds, *cpu) in enumerate(timing.steps):
+            cpu_seconds = cpu[0] if cpu else None
+            if self._metrics is not None:
                 self._step_histogram.observe(seconds, step=name)
+                if cpu_seconds is not None:
+                    self._step_cpu_seconds.inc(cpu_seconds, step=name)
             if self._span_tracer is not None:
+                args = {"frame": frame.frame_index}
+                if cpu_seconds is not None:
+                    args["cpu_s"] = round(cpu_seconds, 6)
+                args.update(step_bytes.get(name, {}))
+                if index == written_in:
+                    args.update(write_ops_ms)
                 self._span_tracer.complete(
                     name,
                     cat="worker.step",
                     start_wall=start_wall,
                     duration=seconds,
                     track=save_steps_track if name in SAVE_STEPS else steps_track,
-                    args={"frame": frame.frame_index, **step_bytes.get(name, {})},
+                    args=args,
                 )
+        self._note_process_cpu()
         if self._metrics is not None:
             self._metrics.counter(
                 "worker_frames_rendered_total", "Frames rendered successfully"
