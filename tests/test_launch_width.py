@@ -82,8 +82,8 @@ def test_the_rung_holds_the_live_rays_and_never_widens(n):
 
 # frame_program's jitted closures, by what shapes the trace: the frame is an
 # operand, so the cases that differ by the frame alone share a program. The
-# ladder function and the unsort in force are part of the key: a patched one
-# is another program, traced when first asked for, under that patch.
+# ladder function, the unsort and the re-pack in force are part of the key: a
+# patched one is another program, traced when first asked for, under that patch.
 _FRAME_PROGRAMS: dict[tuple, object] = {}
 
 
@@ -108,7 +108,10 @@ def frame_program(scene_name, frame_index, *, size, samples, bounces):
             use_tlas=use_tlas, quant=quant, with_live=True,
         )
 
-    key = (scene_name, size, samples, bounces, integrator.launch_width_ladder, integrator._unsort)
+    key = (
+        scene_name, size, samples, bounces, integrator.launch_width_ladder,
+        integrator._unsort, integrator._repack,
+    )
     program = _FRAME_PROGRAMS.setdefault(key, jax.jit(render))
     image, launches = program(jnp.asarray(frame_index, jnp.float32))
     return np.asarray(image), None if launches is None else np.asarray(launches)

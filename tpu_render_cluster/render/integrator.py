@@ -410,6 +410,36 @@ def _unsort(radiance, lane):
         return jnp.stack(columns, axis=1)
 
 
+def _repack(order, arrays):
+    """Each of ``arrays`` ([n] or [n, 3]) with row ``order[i]`` moved to
+    row i — ``array[order]`` exactly — for ``order`` a permutation of
+    0..n-1, with nothing gathered: a gather pays for every row it fetches
+    where a sort streams its operands (on a v5e, a ray's twelve columns
+    and its lane over 2,097,152 rows: 19.4 ms against 67.9, PERF.md §6
+    PR 55). Row j goes to row ``rank[j]``, the inverse of ``order`` and
+    itself a sort (``argsort``), so an [n, 3] array is ``_unsort`` by the
+    rank and an [n] one a two-operand sort on it.
+
+    One sort carrying everything would read the rank once and not once an
+    array, but the TPU compiler's time for a sort grows faster than its
+    operands (fourteen: 396 s; four: 28 s; two: 10 s, PERF.md §6 PR 55)
+    while sorts of one shape are compiled once. So every sort here has a
+    shape the program has already or one as small (``argsort``'s,
+    ``_unsort``'s; the rank has no ties, so a payload's sort need not be
+    stable), and the barrier keeps XLA from merging the sorts that share
+    a key into the one of fourteen."""
+    ranks = jax.lax.optimization_barrier((jnp.argsort(order),) * len(arrays))
+    return [
+        _unsort(array, rank) if array.ndim == 2
+        else jax.lax.sort((rank, array), num_keys=1, is_stable=False)[1]
+        for array, rank in zip(arrays, ranks)
+    ]
+
+
+# What a ray owns beside its lanes; the first three travel into a launch.
+_RAY_COLUMNS = ("origins", "directions", "throughput", "radiance")
+
+
 def _trace_paths_deep(
     scene, mesh, origins, directions, seed, *, max_bounces, rng_lanes,
     use_tlas, quant, live_counts, walk_counts=None,
@@ -422,7 +452,7 @@ def _trace_paths_deep(
     with dead lanes compacted to the tail, so the walks cull on tight
     coherent packets.
 
-    The width of a bounce — of its gathers, layout changes and launch,
+    The width of a bounce — of its re-pack, layout changes and launch,
     and of the sort that follows it — is the narrowest rung of
     ``launch_width_ladder`` that holds the bounce's live rays, picked
     inside the program by ``lax.switch`` on the live count (every rung a
@@ -430,16 +460,22 @@ def _trace_paths_deep(
     The sort key's dead flag puts every live ray before every dead one,
     so the first ``width`` entries of the sort order hold them all.
 
-    A full-width bounce permutes everything in ONE packed [n, 12] gather
-    incl. the accumulated radiance (separate [n, 3] gathers measured ~3x
-    slower: random-access cost is per-row, so packing amortizes it) and
-    carries the unsort lane with it. A narrow bounce leaves radiance and
-    the unsort lane where the last full-width permutation put them — a
-    ray that died keeps its place, its radiance is final — and moves
-    only the travelling state ([width, 9]) with ``slot``, each row's
-    place in that order, through which its contribution is added and its
-    RNG lane read. Widths never grow again (rays only die), and a scene
-    whose rays do not die takes the widest rung on every bounce. Per-ray
+    A full-width bounce permutes everything a ray owns — the travelling
+    state, the accumulated radiance, the unsort lane, the RNG lane where
+    it is carried — by sorts on the order's inverse (``_repack``): random
+    access costs per row and a sort streams, so n rows are cheapest moved
+    as a sort's payload, then as one packed gather, and dearest as a
+    gather a column (twelve columns of 2,097,152 rows on a v5e: 17 ms,
+    53 ms, and about three times that; PERF.md §6 PR 55).
+    Before the first bounce throughput and radiance are constants and
+    stay where they are. A narrow bounce leaves radiance and the unsort
+    lane where the last full-width permutation put them — a ray that
+    died keeps its place, its radiance is final — and gathers only the
+    travelling state of its rows ([width, 9]: few rows, a few
+    milliseconds) with ``slot``, each row's place in that order, through
+    which its contribution is added and its RNG lane read. Widths never
+    grow again (rays only die), and a scene whose rays do not die takes
+    the widest rung on every bounce. Per-ray
     arithmetic is the same at every width: same kernel, same RNG lane,
     the same four additions in the same order.
 
@@ -476,17 +512,24 @@ def _trace_paths_deep(
         full = width == n
         with jax.named_scope("resort"):
             order = state["order"][:width]
-            columns = [state[k] for k in ("origins", "directions", "throughput")]
             if full:
-                columns.append(state["radiance"])
-            packed = jnp.concatenate(columns, axis=1)[order]
-            if full:
-                # The RNG counter rides separately from the unsort index
-                # when the caller supplies full-frame lane ids (region
-                # rendering); with positional lanes the two are one array.
-                lane = state["lane"][order]
-                rng = state["rng"][order] if "rng" in state else lane
+                # Before the first bounce throughput and radiance are
+                # constants: a permutation of them is them. The RNG
+                # counter rides separately from the unsort index when the
+                # caller supplies full-frame lane ids (region rendering);
+                # with positional lanes the two are one array.
+                columns = _RAY_COLUMNS[:2] if bounce == 0 else _RAY_COLUMNS
+                names = [k for k in ("lane", "rng", *columns) if k in state]
+                moved = _repack(order, [state[k] for k in names])
+                ray = {**state, **dict(zip(names, moved))}
+                lane = ray["lane"]
+                rng = ray.get("rng", lane)
+                travelling = [ray[k] for k in _RAY_COLUMNS[:3]]
             else:
+                packed = jnp.concatenate(
+                    [state[k] for k in _RAY_COLUMNS[:3]], axis=1
+                )[order]
+                travelling = [packed[:, 0:3], packed[:, 3:6], packed[:, 6:9]]
                 lane = state["lane"]
                 slot = state["slot"][order]
                 rng = state.get("rng", lane)[slot]
@@ -500,14 +543,14 @@ def _trace_paths_deep(
         with jax.named_scope("bounce"):
             # a streamed BLAS's launch also returns its walk's counts
             contribution, origins, directions, throughput, alive, keys, *walk = launch(
-                scene, mesh, packed[:, 0:3], packed[:, 3:6], packed[:, 6:9],
-                alive, seed, jnp.int32(bounce), total_bounces=max_bounces,
+                scene, mesh, *travelling, alive, seed, jnp.int32(bounce),
+                total_bounces=max_bounces,
                 lane=rng, live_count=state["live"], use_tlas=tlas,
                 quant=quant,
             )
         with jax.named_scope("accumulate"):
             if full:
-                radiance = packed[:, 9:12] + contribution
+                radiance = ray["radiance"] + contribution
             else:
                 radiance = state["radiance"].at[slot].add(
                     contribution, unique_indices=True

@@ -95,6 +95,8 @@ class TpuRaytraceBackend(RenderBackend):
         self._tier_frames = self._tier_frames_counter()
         for tier in ("masked", "region", "sharded"):
             self._tier_frames.inc(0.0, tier=tier)
+        for by in ("sort", "gather"):
+            self._repacks_counter().inc(0.0, by=by)
         from tpu_render_cluster.obs import get_registry
 
         # program key -> set once what the key needs is resident. A key
@@ -405,18 +407,36 @@ class TpuRaytraceBackend(RenderBackend):
             "over launches",
         )
 
+    @staticmethod
+    def _repacks_counter():
+        from tpu_render_cluster.obs import get_registry
+
+        return get_registry().counter(
+            "render_bounce_repacks_total",
+            "Bounce launches of deep mesh frames, by how their rays were "
+            "put in the launch's order: sort (the widest rung: the rays' "
+            "state rides sorts on the order's inverse) or gather (a narrower "
+            "rung: the travelling state gathered through the key order)",
+            labels=("by",),
+        )
+
     @classmethod
     def _observe_launches(cls, launches) -> None:
         """Launch occupancy of a whole frame of a deep mesh scene: every
         bounce is one kernel launch, ``launches[b]`` its (live rays,
         width) — the width the program picked for that bounce from its
         live count (integrator.launch_width_ladder), dead lanes sorted to
-        the tail and skipped by blocks."""
+        the tail and skipped by blocks. The first bounce runs at the
+        widest rung, and a launch at that width got its rays by a sort."""
         occupancy = cls._launch_occupancy_histogram()
         for live, width in launches:
             occupancy.observe(int(live) / int(width))
         cls._launched_lanes_counter().inc(float(launches[:, 1].sum()))
         cls._live_lanes_counter().inc(float(launches[:, 0].sum()))
+        by_sort = int((launches[:, 1] == launches[0, 1]).sum())
+        repacks = cls._repacks_counter()
+        repacks.inc(float(by_sort), by="sort")
+        repacks.inc(float(len(launches) - by_sort), by="gather")
 
     @staticmethod
     def _observe_walk(walk, scene_name: str) -> None:
