@@ -12,6 +12,9 @@ Since PR 52 the backlog driver runs a sixth configuration,
 `benchmark` PR may edit it (PERF.md §7), so the case that holds the list is
 held here on the list as it is now, and the new configuration goes through
 the file's own two cases under names of its own.
+
+Since PR 56 it runs a seventh, `02phmesh-240f-1w` (its two cases are in
+`tests/test_shallow_mesh_job.py`, with the rest of what holds that job).
 """
 
 from benchmark.lib import manifest
@@ -20,6 +23,7 @@ from benchmark.tests.test_backlog_rule import *  # noqa: F401,F403
 from benchmark.tests.test_backlog_rule import BACKLOG_CONFIGS, BENCHMARK, ROOT
 
 PNG_CONFIG = "04vs-14400f-1w-png"
+SHALLOW_MESH_CONFIG = "02phmesh-240f-1w"
 # the JPEG configuration's job and span, so its backlog and its floor; the rate is the one
 # PERF.md §5 reads in `04vs-1w-png` (my chip runs, PR 52), 1/26 of what the backlog holds
 PNG_ROW = (13681, 290.0, [11.21])
@@ -31,7 +35,7 @@ def test_these_are_the_configurations_the_backlog_driver_runs():  # noqa: F811
         for cell in (manifest.load_cell(workload["name"], ROOT) for workload in BENCHMARK["workloads"])
         if cell.traffic["driver"] == "backlog"
     }
-    assert driven == set(BACKLOG_CONFIGS) | {PNG_CONFIG}
+    assert driven == set(BACKLOG_CONFIGS) | {PNG_CONFIG, SHALLOW_MESH_CONFIG}
 
 
 def test_the_png_configurations_smallest_backlog_holds_the_rate_it_states(monkeypatch):

@@ -6,7 +6,7 @@ operation, and the late loop's seconds by cause. Held here: the eight
 entries are the LAST eight of `per_layer`, in the issue's order, with
 nothing before them come or gone; each finds its file (five are data for
 the accepted `delta_ratio` reader, three have a module in
-`pool_master_cpu_share.py`'s form) in every one of the ten cells; each
+`pool_master_cpu_share.py`'s form) in every cell (ten at PR 54); each
 gives, on two scrapes written out by hand, the number worked out by hand,
 and `None` (no exception) on a scrape without its series, which is the
 parent's side of this PR's pairs; and the program feeds every series the
@@ -42,6 +42,10 @@ METRICS = [
     ("process_stopped_s", "s", "worker runtime", True),
 ]
 NAMES = [name for name, _, _, _ in METRICS]
+CELLS_AT_PR_54 = [
+    "04vs-1w-coarse", "04vs-4w-batch", "03ph2mesh-1w-queued", "03ph2mesh-1w-fine", "03ph2scan-1w-queued",
+    "03ph2assets-1w-queued", "svc2fam-1w-closed3", "svc2fam-4w-closed12", "svc2fam-4w-kill1", "04vs-1w-png",
+]
 
 # Two workers at the two edges of a window of 20 s, as their `/metrics` say it. Worker 0 rendered
 # 1000 frames (100 -> 1100), worker 1 500 (0 -> 500, its series new since the first edge).
@@ -133,8 +137,10 @@ def test_the_eight_entries_are_the_last_of_per_layer_and_nothing_before_them_has
     assert names[BEFORE - 4:BEFORE - 1] == ["encode_MB_per_s", "held_ms_per_frame", "save_bound_share"]
     assert names[65] == "dispatch_ahead_frame_share" and names[66] == "walk_top_tests_per_entry"
     assert names[0] == "assign_ms_mean" and len(names[:BEFORE]) == 71
+    # every cell, then and since: the ten of PR 54 by their places, and whatever later PRs appended
+    # behind them (PR 56: `04vs-1w-fine`, `02phmesh-1w-queued`) appended to these eight lists too
     every_cell = [w["name"] for w in benchmark["workloads"]]
-    assert len(every_cell) == 10
+    assert every_cell[:10] == CELLS_AT_PR_54 and len(every_cell) == len(set(every_cell))
     layers_before = {m["layer"] for m in benchmark["per_layer"][:BEFORE]}
     for entry, (name, unit, layer, _module) in zip(benchmark["per_layer"][BEFORE:BEFORE + 8], METRICS):
         assert entry == {

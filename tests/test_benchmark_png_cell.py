@@ -1,8 +1,9 @@
 """The cell `04vs-1w-png`, counted in tier-1.
 
 `benchmark/tests/test_png_cell.py` holds the cell to what ISSUE 52 names
-(configuration, traffic, chips, the counts from this PR on: ten cells,
-three on four chips, nine configurations with nine sources and files), to
+(configuration, traffic, chips, the counts at PR 52: ten cells, three on
+four chips, nine configurations with nine sources and files; held here by
+the entries' places since PR 56 appended two cells and a configuration), to
 differing from `04vs-1w-coarse` by the output format alone, its check to a
 lossless file's limits, and its three metrics to being data whose reader
 gives nothing for a program without their series. The driver's tier-1
@@ -14,6 +15,8 @@ other cells' rehearsals do.
 """
 
 import os
+import subprocess
+import sys
 from pathlib import Path
 
 from benchmark.drivers import backlog
@@ -23,10 +26,46 @@ from benchmark.tests.test_png_cell import (  # noqa: F401
     JPEG_CELL,
     NEW_METRICS,
     ROOT,
-    test_the_cell_is_data_and_says_what_the_issue_says,
     test_the_check_reads_a_lossless_file_without_a_codec_and_tighter_than_jpegs,
     test_the_readers_give_nothing_for_a_program_without_the_series_and_the_value_with_them,
 )
+
+# the ten cells of PR 52, in their order: what `workloads` began with then and begins with since
+CELLS_AT_PR_52 = [
+    "04vs-1w-coarse", "04vs-4w-batch", "03ph2mesh-1w-queued", "03ph2mesh-1w-fine", "03ph2scan-1w-queued",
+    "03ph2assets-1w-queued", "svc2fam-1w-closed3", "svc2fam-4w-closed12", "svc2fam-4w-kill1", CELL,
+]
+
+
+def test_the_cell_is_data_and_says_what_the_issue_says():
+    """The benchmark's case of this name, line for line, but for where it
+    holds the counts of PR 52 (ten cells, nine configurations) and the
+    cell and its configuration to be the LAST of their lists: later PRs
+    append behind them (PR 56: two cells and a configuration), so the entry
+    is held by its place, the tenth cell and the ninth configuration with
+    nothing before them come or gone, and the counts by `BENCHMARK.json`
+    as it stands."""
+    assert manifest.validate(ROOT) == []
+    listing = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--list"], cwd=ROOT, capture_output=True, text=True, timeout=60,
+    )
+    assert listing.returncode == 0 and listing.stderr == ""
+    assert "04vs-1w-png            config 04vs-14400f-1w-png   traffic backlog-coarse100    chips 1" in listing.stdout
+    benchmark = manifest.load_benchmark(ROOT)
+    assert [w["name"] for w in benchmark["workloads"][:10]] == CELLS_AT_PR_52
+    assert sum(w["chips"] == 4 for w in benchmark["workloads"][:10]) == 3
+    configs = benchmark["configs"]
+    assert len({c["source"] for c in configs}) == len({c["file"] for c in configs}) == len(configs) >= 9
+    assert benchmark["workloads"][9]["name"] == CELL and configs[8]["name"] == "04vs-14400f-1w-png"
+    entry = configs[8]
+    assert entry["reduced"] == ["frame_range_from"] and len(entry["source"]) <= 200
+    assert "04_very-simple_demo_60f-1w.toml" in entry["source"] and "14400f-1w" in entry["source"]
+    cell = manifest.load_cell(CELL, ROOT)
+    assert cell.chips == 1 and cell.config_name == "04vs-14400f-1w-png"
+    assert cell.traffic["name"] == "backlog-coarse100" and cell.traffic["driver"] == "backlog"
+    assert cell.traffic["strategy"] == {"strategy_type": "eager-naive-coarse", "target_queue_size": 100}
+    assert cell.traffic["warmup_frames_per_worker"] == 3
+    assert {metric["name"] for metric in cell.end_to_end} == {"frames_per_s", "setup_s"}
 
 
 def test_the_two_04vs_one_worker_cells_differ_by_the_output_format_alone():
@@ -81,15 +120,19 @@ def test_the_three_readers_are_data_and_read_the_series_the_issue_names():
     assert [m["name"] for m in entries] == list(NEW_METRICS)
     assert benchmark["per_layer"][66]["name"] == "walk_top_tests_per_entry"
     for entry in entries:
-        assert (entry["layer"], entry["moves"], entry["workloads"]) == ("result plane", "frames_per_s", [JPEG_CELL, CELL])
+        # (its two cells first; `04vs-1w-fine`, PR 56's, runs the JPEG cell's job and follows them)
+        assert (entry["layer"], entry["moves"], entry["workloads"][:2]) == ("result plane", "frames_per_s", [JPEG_CELL, CELL])
+        assert not set(entry["workloads"][2:]) & set(CELLS_AT_PR_52)
         spec, directory = manifest.layer_metric_spec(entry["name"], ROOT)
         assert spec["reader"] == "delta_ratio" and spec["from"] == "workers"
         assert not (directory / f"{entry['name']}.py").exists(), "data, no reader code"
     assert [(m["unit"], m["better"]) for m in entries] == [("MB/s", "higher"), ("ms", "lower"), ("%", "lower")]
-    # every list that named the JPEG cell at PR 52 names the new cell too, last; PR 53's does as well
+    # every list that named the JPEG cell at PR 52 names the new cell too, last of the cells there were
+    # then (a later PR's cells follow it: PR 56's `04vs-1w-fine`); PR 53's does as well
     named = [m for m in benchmark["per_layer"][:70] if JPEG_CELL in m.get("workloads", [])]
-    assert len(named) == 14 + 3 and all(m["workloads"][-1] == CELL for m in named)
+    assert len(named) == 14 + 3
+    assert all([w for w in m["workloads"] if w in CELLS_AT_PR_52][-1] == CELL for m in named)
     # (the 71st entry alone: the entries behind it are later PRs' own, and PR 54's eight name every cell)
     later = benchmark["per_layer"][70]
     assert later["name"] == "save_beside_save_frame_share" and JPEG_CELL in later["workloads"]
-    assert later["workloads"][-1] == CELL
+    assert [w for w in later["workloads"] if w in CELLS_AT_PR_52][-1] == CELL

@@ -4,7 +4,9 @@ There is one way to render; the backend chooses the program's shape from
 what it can observe: a tile unit is a `region`, a whole frame is `sharded`
 where the worker shards across its local mesh and `masked` everywhere
 else. Neither the scene nor what else the worker's queue holds changes
-that, and the choice never builds the scene's mesh set on the host.
+that, and the choice never builds the scene's mesh set on the host (the
+backend names the program's trace kernel from the set's SHAPES, asked for
+under `jax.eval_shape`: nothing is built, and that call is not counted).
 """
 
 from __future__ import annotations
@@ -28,6 +30,7 @@ TIERS = ("masked", "region", "sharded")
 def routed(monkeypatch):
     """Pallas on, the three renderer factories replaced by recorders, and
     every host-side build of a mesh set counted."""
+    import jax
     import jax.numpy as jnp
 
     from tpu_render_cluster.parallel import sharded_render
@@ -35,8 +38,11 @@ def routed(monkeypatch):
 
     monkeypatch.setenv("TRC_PALLAS", "1")
     seen = {"rendered": [], "mesh_sets": 0}
+    shapes_of_mesh_set = mesh.scene_mesh_set
 
-    def mesh_set(*_args, **_kwargs):
+    def mesh_set(scene_name, frame, *args, **kwargs):
+        if isinstance(frame, jax.core.Tracer):
+            return shapes_of_mesh_set(scene_name, frame, *args, **kwargs)
         seen["mesh_sets"] += 1
 
     def masked(*_args, **_kwargs):

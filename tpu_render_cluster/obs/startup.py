@@ -51,6 +51,8 @@ process's start is known on, and an edge read once cannot leave a gap.
 and misses) into the process registry, and each phase of 10 ms or more on
 the timeline as a ``cat: "render.compile"`` span through the same buffer:
 at start-up and after it, so a compile inside a job is a named span.
+Inside ``compile_span_args(**args)`` those spans carry ``args`` as well:
+the backend says there which trace kernel the program in hand holds.
 """
 
 from __future__ import annotations
@@ -65,6 +67,7 @@ __all__ = [
     "COMPILE_SPAN_FLOOR_SECONDS",
     "STARTUP_STAGES",
     "StartupRecorder",
+    "compile_span_args",
     "get_startup",
     "process_start_time",
     "reset_startup",
@@ -350,6 +353,20 @@ def _counter(name: str):
 
 
 _open_phases = threading.local()
+_span_args = threading.local()
+
+
+@contextmanager
+def compile_span_args(**args: Any) -> Iterator[None]:
+    """Whatever JAX builds on this thread inside carries ``args`` on its
+    ``render.compile`` spans, beside ``fun_name``: what the caller knows
+    of the program and JAX's event does not."""
+    previous = getattr(_span_args, "args", {})
+    _span_args.args = {**previous, **args}
+    try:
+        yield
+    finally:
+        _span_args.args = previous
 
 
 def _on_compile_phase_entered(event: str, value: float, **kwargs: Any) -> None:
@@ -382,7 +399,10 @@ def _on_compile_phase(event: str, start_time: float, end_time: float, **kwargs: 
         get_startup().span(
             phase, cat="render.compile", track="compile",
             start_wall=start_time, duration=seconds,
-            args={"fun_name": str(kwargs.get("fun_name", ""))},
+            args={
+                "fun_name": str(kwargs.get("fun_name", "")),
+                **getattr(_span_args, "args", {}),
+            },
         )
 
 

@@ -630,6 +630,61 @@ def _trace_paths_deep(
     return _unsort(radiance, lane)
 
 
+# What traces a frame's rays, by the name the backend counts its frames
+# under (``render_trace_kernel_frames_total{kernel}``): the sphere
+# megakernel, the mesh megakernel, one ``mesh_bounce_pallas`` launch a
+# bounce over a resident BLAS or over one streamed from HBM, and, where the
+# Pallas kernels are off, the XLA bounce loop.
+TRACE_KERNELS = (
+    "sphere_fused", "mesh_fused", "mesh_bounce", "mesh_stream", "xla_loop",
+)
+
+
+def trace_kernel_name(mesh, rng_lanes=None) -> str:
+    """Which of ``TRACE_KERNELS`` traces rays over ``mesh`` (None: spheres
+    alone) with or without explicit lane ids. ``trace_paths`` dispatches by
+    this name and by nothing else, so what a counter or a span says of a
+    frame's program is the road its rays took.
+
+    A mesh goes to the megakernel (the whole bounce loop with the
+    instanced BVH walk in one kernel) where it is resident and shallow
+    (nodes x instances <= MESH_MEGAKERNEL_MAX_WALK, the kernel's
+    precondition) and no lane ids are given; everything deeper, streamed
+    or with explicit lanes takes one bounce kernel a bounce. The
+    megakernel's in-walk normal / albedo tracking adds work to EVERY leaf
+    visit; where the gate should lie against today's bounce kernel is
+    ROADMAP D19's."""
+    from tpu_render_cluster.render import pallas_kernels
+
+    if not pallas_kernels.pallas_enabled():
+        return "xla_loop"
+    if mesh is None:
+        return "sphere_fused"
+    if mesh.bvh.stream is not None:
+        return "mesh_stream"
+    if rng_lanes is None and pallas_kernels.mesh_megakernel_eligible(mesh):
+        return "mesh_fused"
+    return "mesh_bounce"
+
+
+def scene_trace_kernel(scene_name: str, *, region: bool = False) -> str:
+    """``trace_kernel_name`` of a scene family's programs: the whole
+    frame's, or a region's (which hands its rays' full-frame lane ids
+    over). Outside any trace and without a device operation: the name
+    function reads shapes alone, so the family's mesh set is asked for as
+    shapes (the BLAS is the cached one the renderer factories close over;
+    the instances' count is the same on every frame)."""
+    from tpu_render_cluster.render.mesh import scene_blas_stream, scene_mesh_set
+
+    _tlas, _quant, builder, wide = resolve_bvh_config()
+    blas = scene_blas_stream(scene_name, builder, wide)
+    mesh = jax.eval_shape(
+        lambda frame: scene_mesh_set(scene_name, frame, builder, wide, stream=blas),
+        jax.ShapeDtypeStruct((), jnp.float32),
+    )
+    return trace_kernel_name(mesh, rng_lanes=True if region else None)
+
+
 def trace_paths(
     scene: Scene, origins, directions, key, *, max_bounces: int = 4, mesh=None,
     rng_lanes=None, use_tlas=None, quant=None, live_counts=None,
@@ -638,9 +693,9 @@ def trace_paths(
     """Trace one sample per ray; returns radiance [R, 3].
 
     Where ``pallas_enabled()`` (on the chip, or ``TRC_PALLAS=1``) one of
-    three Pallas kernels traces the rays, chosen by scene class: the
-    sphere megakernel (``trace_paths_fused``: the whole bounce loop in one
-    kernel, path state VMEM-resident, counter-based in-kernel RNG), the
+    three Pallas kernels traces the rays, chosen by ``trace_kernel_name``:
+    the sphere megakernel (``trace_paths_fused``: the whole bounce loop in
+    one kernel, path state VMEM-resident, counter-based in-kernel RNG), the
     mesh megakernel for a shallow resident mesh
     (``trace_paths_fused_mesh``, ``mesh_megakernel_eligible``), or one
     ``mesh_bounce_pallas`` launch a bounce with the rays re-sorted between
@@ -681,38 +736,27 @@ def trace_paths(
     """
     from tpu_render_cluster.render import pallas_kernels
 
-    if pallas_kernels.pallas_enabled():
-        seed = trace_seed(key)
-        if mesh is None and rng_lanes is None:
-            return pallas_kernels.trace_paths_fused(
-                scene, origins, directions, seed, max_bounces=max_bounces
-            )
-        if mesh is None:
-            # Explicit lane ids: the SAME fused megakernel, with the RNG
-            # counters read from the caller's lane row instead of the
-            # launch position — a cropped region launch therefore runs
-            # bitwise-identical per-lane math to the whole-frame render.
-            return pallas_kernels.trace_paths_fused(
-                scene, origins, directions, seed, max_bounces=max_bounces,
-                lane=jnp.asarray(rng_lanes, jnp.int32),
-            )
-        # Mesh scenes: the megakernel (whole bounce loop incl. the
-        # instanced BVH walk in one kernel) takes a shallow resident mesh
-        # (nodes x instances <= MESH_MEGAKERNEL_MAX_WALK, the kernel's
-        # precondition); everything deeper, streamed or with explicit
-        # lanes takes one bounce kernel a bounce. Its in-walk normal /
-        # albedo tracking adds work to EVERY leaf visit; where the gate
-        # should lie against today's bounce kernel is not measured
-        # (ROADMAP D19).
-        if rng_lanes is None and pallas_kernels.mesh_megakernel_eligible(mesh):
-            return pallas_kernels.trace_paths_fused_mesh(
-                scene, mesh, origins, directions, seed,
-                max_bounces=max_bounces, use_tlas=use_tlas, quant=quant,
-            )
+    kernel = trace_kernel_name(mesh, rng_lanes)
+    if kernel == "sphere_fused":
+        # With explicit lane ids: the SAME fused megakernel, with the RNG
+        # counters read from the caller's lane row instead of the launch
+        # position — a cropped region launch therefore runs
+        # bitwise-identical per-lane math to the whole-frame render.
+        return pallas_kernels.trace_paths_fused(
+            scene, origins, directions, trace_seed(key),
+            max_bounces=max_bounces,
+            lane=None if rng_lanes is None else jnp.asarray(rng_lanes, jnp.int32),
+        )
+    if kernel == "mesh_fused":
+        return pallas_kernels.trace_paths_fused_mesh(
+            scene, mesh, origins, directions, trace_seed(key),
+            max_bounces=max_bounces, use_tlas=use_tlas, quant=quant,
+        )
+    if kernel in ("mesh_bounce", "mesh_stream"):
         return _trace_paths_deep(
-            scene, mesh, origins, directions, seed, max_bounces=max_bounces,
-            rng_lanes=rng_lanes, use_tlas=use_tlas, quant=quant,
-            live_counts=live_counts, walk_counts=walk_counts,
+            scene, mesh, origins, directions, trace_seed(key),
+            max_bounces=max_bounces, rng_lanes=rng_lanes, use_tlas=use_tlas,
+            quant=quant, live_counts=live_counts, walk_counts=walk_counts,
         )
     if mesh is not None and mesh.bvh.stream is not None:
         raise NotImplementedError(

@@ -44,6 +44,10 @@ class FrameRenderTime:
     saved: tuple[str, int, int, tuple[float, ...]] | None = field(
         default=None, compare=False, repr=False
     )
+    # Which trace kernel the frame's program holds
+    # (``integrator.TRACE_KERNELS``): the ``dispatch`` step's event says
+    # it. Worker-local too; None from a backend that traces no rays.
+    kernel: str | None = field(default=None, compare=False, repr=False)
 
     def total_execution_time(self) -> float:
         duration = self.exited_process_at - self.started_process_at

@@ -98,6 +98,15 @@ class TpuRaytraceBackend(RenderBackend):
         for by in ("sort", "gather"):
             self._repacks_counter().inc(0.0, by=by)
         from tpu_render_cluster.obs import get_registry
+        from tpu_render_cluster.render.integrator import TRACE_KERNELS
+
+        self._kernel_frames = self._kernel_frames_counter()
+        for kernel in TRACE_KERNELS:
+            self._kernel_frames.inc(0.0, kernel=kernel)
+        # "family@WxHxSxB tier" -> the trace kernel that program holds,
+        # for every program this backend has built or fetched: the
+        # worker's exit snapshot carries it
+        self.trace_kernels: dict[str, str] = {}
 
         # program key -> set once what the key needs is resident. A key
         # is claimed by whoever meets it first (an announcement's thread,
@@ -263,17 +272,39 @@ class TpuRaytraceBackend(RenderBackend):
         self._prepare_seconds.observe(seconds, family=scene_name)
 
     def _first_call(self, key: tuple):
-        """The first call of the key's whole-frame program: builds it."""
+        """The first call of the key's whole-frame program: builds it,
+        its ``render.compile`` spans named with the trace kernel it holds."""
+        from tpu_render_cluster.obs.startup import compile_span_args
+
         scene_name, *shape = key
-        if self.sharding in ("tile", "spp"):
-            from tpu_render_cluster.parallel.sharded_render import sharded_frame_renderer
+        sharded = self.sharding in ("tile", "spp")
+        kernel = self._trace_kernel(key, "sharded" if sharded else "masked")
+        with compile_span_args(kernel=kernel):
+            if sharded:
+                from tpu_render_cluster.parallel.sharded_render import sharded_frame_renderer
 
-            return sharded_frame_renderer(scene_name, *shape, self.sharding)(1)
-        from tpu_render_cluster.render.integrator import fused_frame_renderer
+                return sharded_frame_renderer(scene_name, *shape, self.sharding)(1)
+            from tpu_render_cluster.render.integrator import fused_frame_renderer
 
-        # The program _render_pixels runs: with the live counts.
-        display, *_ = fused_frame_renderer(scene_name, *shape, with_live=True)(1)
-        return display
+            # The program _render_pixels runs: with the live counts.
+            display, *_ = fused_frame_renderer(scene_name, *shape, with_live=True)(1)
+            return display
+
+    def _trace_kernel(self, key: tuple, tier: str) -> str:
+        """Which of ``integrator.TRACE_KERNELS`` the key's program of that
+        unit shape holds: asked of the name function ``trace_paths``
+        itself dispatches by, once a program."""
+        from tpu_render_cluster.render.integrator import scene_trace_kernel
+
+        program = "{}@{}x{}x{}x{} {}".format(*key, tier)
+        kernel = self.trace_kernels.get(program)
+        if kernel is None:
+            # (an announcement's thread and a frame that meet a program at
+            # once both ask, and store the same name)
+            kernel = self.trace_kernels[program] = scene_trace_kernel(
+                key[0], region=tier == "region"
+            )
+        return kernel
 
     def _build_geometry(self, scene_name: str) -> None:
         """Build the scene's BLAS or its set of BLASes (once a process:
@@ -371,6 +402,20 @@ class TpuRaytraceBackend(RenderBackend):
             "whole frame on one device), region (a tile), sharded (a whole "
             "frame across the local mesh)",
             labels=("tier",),
+        )
+
+    @staticmethod
+    def _kernel_frames_counter():
+        from tpu_render_cluster.obs import get_registry
+
+        return get_registry().counter(
+            "render_trace_kernel_frames_total",
+            "Frames rendered, by the trace kernel their program holds "
+            "(integrator.trace_kernel_name, which trace_paths dispatches "
+            "by): sphere_fused, mesh_fused (the two megakernels), "
+            "mesh_bounce, mesh_stream (one bounce kernel a bounce, the BLAS "
+            "resident or streamed from HBM), xla_loop (Pallas off)",
+            labels=("kernel",),
         )
 
     # The three launch series keep the names the benchmark's per-layer
@@ -540,6 +585,7 @@ class TpuRaytraceBackend(RenderBackend):
         import jax.numpy as jnp
 
         from tpu_render_cluster.obs import step
+        from tpu_render_cluster.obs.startup import compile_span_args
         from tpu_render_cluster.render.integrator import (
             fused_frame_renderer,
             fused_region_renderer,
@@ -606,11 +652,13 @@ class TpuRaytraceBackend(RenderBackend):
 
                 def render():
                     return frame_renderer(frame_index)
+            kernel = self._trace_kernel(key, tier)
         finished_loading_at = time.time()
 
         started_rendering_at = time.time()
-        with step("dispatch"):
+        with step("dispatch"), compile_span_args(kernel=kernel):
             # a frame whose BLAS is streamed also returns its walk's counts
+            # (a program first built here, inside a job, is a named span)
             display, launches, *walk = render()
             for counts in (launches, *walk):
                 if counts is not None:
@@ -628,6 +676,7 @@ class TpuRaytraceBackend(RenderBackend):
                 self._collect_pixels, job, frame_index, tile, display, launches, walk,
                 points=(started_process_at, finished_loading_at, started_rendering_at),
                 issue_steps=steps, tier=tier, scene_name=scene_name,
+                kernel=kernel,
             )
         )
 
@@ -636,7 +685,7 @@ class TpuRaytraceBackend(RenderBackend):
         display, launches, walk: list, *,
         points: tuple[float, float, float],
         issue_steps: list[tuple[str, float, float, float | None]],
-        tier: str, scene_name: str,
+        tier: str, scene_name: str, kernel: str,
     ) -> RenderedFrame:
         """The rest of an issued frame's device stage, on whichever thread
         the caller runs it: one device sync, then (what is left of) the
@@ -663,6 +712,7 @@ class TpuRaytraceBackend(RenderBackend):
                 points=(*points, finished_rendering_at),
                 device_steps=[*issue_steps, *collect_steps], tier=tier,
                 scene_name=scene_name, launches=launches, walk=walk,
+                kernel=kernel,
             )
         )
 
@@ -670,12 +720,12 @@ class TpuRaytraceBackend(RenderBackend):
         self, job: BlenderJob, frame_index: int, tile: int | None, pixels, *,
         points: tuple[float, float, float, float],
         device_steps: list[tuple[str, float, float, float | None]],
-        tier: str, scene_name: str, launches, walk: list,
+        tier: str, scene_name: str, launches, walk: list, kernel: str,
     ) -> FrameRenderTime:
         """The frame's file, from its pixels: on whichever thread the
         caller runs it, with that thread's own steps. What the frame
-        counts (tier, launches, walk, family) is counted once its file is
-        in place, as it always was."""
+        counts (tier, trace kernel, launches, walk, family) is counted
+        once its file is in place, as it always was."""
         from tpu_render_cluster.obs import frame_steps, step
         from tpu_render_cluster.render.image_io import (
             output_path_for_frame,
@@ -715,6 +765,7 @@ class TpuRaytraceBackend(RenderBackend):
         file_saving_finished_at = time.time()
 
         self._tier_frames.inc(tier=tier)
+        self._kernel_frames.inc(kernel=kernel)
         if launches is not None:
             self._observe_launches(launches)
         for counts in walk:
@@ -727,4 +778,5 @@ class TpuRaytraceBackend(RenderBackend):
             exited_process_at=time.time(),
             steps=(*device_steps, *save_steps),
             saved=saved,
+            kernel=kernel,
         )

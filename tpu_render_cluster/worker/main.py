@@ -306,6 +306,10 @@ def main(argv: list[str] | None = None) -> int:
             # index, stamped once when the backend was built.
             if device:
                 extra["device"] = device
+            # Which trace kernel each program it built holds (tpu-raytrace).
+            kernels = getattr(backend, "trace_kernels", None)
+            if kernels:
+                extra["trace_kernels"] = dict(kernels)
             write_metrics_snapshot(
                 obs_directory / f"{worker_name}_metrics.json",
                 worker.metrics,

@@ -894,6 +894,8 @@ class WorkerAutomaticQueue:
                 if cpu_seconds is not None:
                     args["cpu_s"] = round(cpu_seconds, 6)
                 args.update(step_bytes.get(name, {}))
+                if name == "dispatch" and timing.kernel is not None:
+                    args["kernel"] = timing.kernel
                 if index == written_in:
                     args.update(write_ops_ms)
                 self._span_tracer.complete(
